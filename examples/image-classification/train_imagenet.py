@@ -7,7 +7,10 @@ KVStore('device') semantics (on TPU: psum over the mesh inside one
 compiled step).
 
     python train_imagenet.py --network resnet --num-layers 50 \
-        --benchmark 1 --batch-size 32
+        --benchmark 1 --batch-size 32 --gpus 0
+
+The device is asked for, as in the reference: ``--gpus 0,1,2,3`` (or its
+alias ``--tpus``) trains on those chips, and without it on ``mx.cpu()``.
 """
 import argparse
 import logging
@@ -40,6 +43,9 @@ def main():
     parser.add_argument('--num-epochs', type=int, default=1)
     parser.add_argument('--lr', type=float, default=0.1)
     parser.add_argument('--kv-store', default='device')
+    parser.add_argument('--gpus', '--tpus', dest='gpus', default=None,
+                        help='list of chips to run on, e.g. 0 or 0,2,5. '
+                             'empty means using cpu')
     parser.add_argument('--benchmark', type=int, default=0,
                         help='use synthetic data (no dataset needed)')
     parser.add_argument('--samples', type=int, default=256)
@@ -66,7 +72,9 @@ def main():
     sym = get_symbol(num_classes=args.num_classes,
                      num_layers=args.num_layers,
                      image_shape=args.image_shape, dtype=args.dtype)
-    mod = mx.mod.Module(symbol=sym, context=mx.current_context())
+    devs = ([mx.tpu(int(i)) for i in args.gpus.split(',')]
+            if args.gpus else mx.cpu())
+    mod = mx.mod.Module(symbol=sym, context=devs)
     mod.fit(train,
             eval_metric=['acc'],
             kvstore=args.kv_store,
@@ -80,6 +88,9 @@ def main():
             epoch_end_callback=(mx.callback.do_checkpoint(args.model_prefix)
                                 if args.model_prefix else None),
             num_epoch=args.num_epochs)
+    arg_params, _ = mod.get_params()
+    logging.info('parameters live on %s', sorted(
+        {str(d) for v in arg_params.values() for d in v._data.devices()}))
     return mod
 
 

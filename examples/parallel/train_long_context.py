@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from mxnet_tpu.parallel import shard_map  # version-stable kwarg spelling
+from mxnet_tpu.parallel import shard_map
 
 from mxnet_tpu import parallel as par
 from mxnet_tpu.parallel.ring_attention import (ring_attention,

@@ -67,9 +67,8 @@ from . import telemetry
 # flag read and nothing else.
 telemetry.enabled()
 
-# Persistent XLA compilation cache (MXTPU_COMPILE_CACHE): wired at
-# import, before the first compile, so warm starts skip the 20-40s
-# XLA compiles entirely. Off (empty) by default — one flag read.
+# Persistent XLA compilation cache: placed at import, before the first
+# compile (config.enable_compile_cache decides where; no device is touched).
 from .config import enable_compile_cache as _enable_compile_cache
 _enable_compile_cache()
 del _enable_compile_cache

@@ -3,16 +3,28 @@
 Reference: python/mxnet/context.py (Context, mx.cpu()/mx.gpu(), current_context)
 and include/mxnet/base.h (Context struct, dev_type/dev_id).
 
-Design: a Context names a JAX device. ``tpu(i)`` maps to the i-th TPU chip;
-``cpu(i)`` maps to the i-th host CPU device (with
-``--xla_force_host_platform_device_count=N`` this gives the multi-device-
-without-a-cluster testing story the reference got from ``mx.cpu(1..n)``,
-tests/python/unittest/test_multi_device_exec.py). ``gpu(i)`` is accepted for
-API compatibility and resolves to the best available accelerator.
+Design: a Context names a JAX device, and it names exactly the device that
+was asked for or raises :class:`MXNetError`. It never wraps an index around and
+never gives another platform's device in place of the one named:
+
+- ``cpu(i)`` is the i-th host CPU device (with
+  ``--xla_force_host_platform_device_count=N`` this gives the multi-device-
+  without-a-cluster testing story the reference got from ``mx.cpu(1..n)``,
+  tests/python/unittest/test_multi_device_exec.py).
+- ``tpu(i)`` is the i-th TPU chip. ``gpu(i)`` is accepted for API
+  compatibility with reference scripts and names the same chip.
+- THE CPU MESH (the one exception, and an explicit one): when the caller has
+  pinned JAX's platform list to exactly ``cpu`` (``JAX_PLATFORMS=cpu`` or
+  ``jax.config.update('jax_platforms', 'cpu')``, as tests/conftest.py does),
+  ``tpu(i)``/``gpu(i)`` name the i-th virtual CPU device, so that code written
+  for chips can be rehearsed without one. A chip that is merely missing is
+  not that mode: without the pin, ``tpu(0)`` on a host with no chip raises.
 """
 import threading
 
 import jax
+
+from .base import MXNetError
 
 __all__ = ['Context', 'cpu', 'gpu', 'tpu', 'cpu_pinned', 'current_context', 'num_gpus', 'num_tpus']
 
@@ -88,18 +100,30 @@ def _platform_devices(platform):
         return []
 
 
+def cpu_mesh_mode():
+    """True when the caller pinned JAX's platform list to exactly ``cpu``:
+    the explicit rehearsal mode in which accelerator contexts name virtual
+    CPU devices (see the module docstring; this is the one place that
+    decides it)."""
+    return (jax.config.jax_platforms or '').strip().lower() == 'cpu'
+
+
 def _resolve_device(device_type, device_id):
-    if device_type == 'cpu' or device_type == 'cpu_pinned':
-        devs = _platform_devices('cpu')
-        if not devs:  # TPU-only runtime: fall back to default devices
-            devs = jax.devices()
-        return devs[device_id % len(devs)]
-    # accelerator request: prefer tpu, then gpu, then cpu (so tests run anywhere)
-    for plat in ('tpu', 'gpu', 'cpu'):
-        devs = _platform_devices(plat)
-        if devs:
-            return devs[device_id % len(devs)]
-    raise RuntimeError('no jax devices available')
+    if device_type in ('cpu', 'cpu_pinned') or cpu_mesh_mode():
+        platform = 'cpu'
+    else:
+        platform = 'tpu'
+    devs = _platform_devices(platform)
+    if not devs:
+        raise MXNetError(
+            '%s(%d): no %s device is visible to JAX (platforms: %r)'
+            % (device_type, device_id, platform,
+               jax.config.jax_platforms or 'default'))
+    if not 0 <= device_id < len(devs):
+        raise MXNetError(
+            '%s(%d): only %d %s device(s) here'
+            % (device_type, device_id, len(devs), platform))
+    return devs[device_id]
 
 
 def cpu(device_id=0):
@@ -111,7 +135,7 @@ def cpu_pinned(device_id=0):
 
 
 def gpu(device_id=0):
-    """Compatibility alias: resolves to the best available accelerator."""
+    """Compatibility alias for :func:`tpu` (reference scripts say gpu)."""
     return Context('gpu', device_id)
 
 
@@ -120,7 +144,7 @@ def tpu(device_id=0):
 
 
 def num_gpus():
-    return len(_platform_devices('gpu')) or len(_platform_devices('tpu'))
+    return num_tpus()
 
 
 def num_tpus():

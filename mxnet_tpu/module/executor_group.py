@@ -405,11 +405,8 @@ class SPMDExecutorGroup:
             return False  # unequal workloads need explicit slices
         if batch_size % len(contexts):
             return False  # NamedSharding needs an even batch split
-        try:
-            devs = {c.jax_device() for c in contexts}
-        except Exception:  # noqa: BLE001 — unresolvable device → fallback
-            return False
-        return len(devs) == len(contexts)
+        # a context that names no device raises here (context.py's rule)
+        return len({c.jax_device() for c in contexts}) == len(contexts)
 
     def __init__(self, symbol, contexts, workload, data_shapes, label_shapes,
                  param_names, for_training, inputs_need_grad,

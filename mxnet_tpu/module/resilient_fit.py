@@ -36,7 +36,8 @@ _BACKOFF_CAP_S = 60.0
 
 # error families worth a restore-and-retry: health incidents, injected
 # faults, runtime/backend failures (XlaRuntimeError subclasses
-# RuntimeError), lost connections to a tunneled runtime. User/shape
+# RuntimeError), lost connections (the coordination service, a
+# remote filesystem). User/shape
 # errors (ValueError/TypeError/AssertionError) re-raise immediately.
 _RETRYABLE = (TrainingHealthError, FaultInjected, RuntimeError,
               ConnectionError, TimeoutError, OSError)

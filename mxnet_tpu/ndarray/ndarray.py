@@ -38,9 +38,8 @@ def waitall():
     Reference: MXNDArrayWaitAll / Engine::WaitForAll (engine.h:180).
     XLA devices execute programs in submission order, so dispatching a
     trivial program on each local device and fetching its result to
-    the host drains everything queued before it (a host fetch, not
-    block_until_ready: through tunneled runtimes only the device→host
-    copy is a reliable fence)."""
+    the host drains everything queued before it (the device→host copy
+    is the fence)."""
     import numpy as _np
     for dev in jax.local_devices():
         try:

@@ -194,7 +194,7 @@ def _conv2d_stem_s2d(data, weight, stride, pad):
 def _conv2d_patches_bwd(data, weight, stride, dilate, pad):
     """Conv2d whose WEIGHT gradient is an explicit patches-matmul.
 
-    The measured MFU gap (docs/perf.md:34) is XLA's grad-weight conv at
+    The measured MFU gap is XLA's grad-weight conv at
     small spatial sizes: conv_backprop_filter becomes a long skinny
     contraction the MXU tiles poorly. im2col + dot_general instead
     turns it into one large (C*kh*kw, N*H'*W') x (N*H'*W', O) matmul —
@@ -470,16 +470,22 @@ def _instance_norm(attrs, x, gamma, beta):
 def _layer_norm(attrs, x, gamma, beta):
     ax = int(attrs.get('axis', -1)) % x.ndim
     eps = attrs.get('eps', 1e-5)
+
+    def plain(x, gamma, beta):
+        x32 = x.astype(jnp.float32)
+        mean = jnp.mean(x32, axis=ax, keepdims=True)
+        var = jnp.var(x32, axis=ax, keepdims=True)
+        y = (x32 - mean) * jax.lax.rsqrt(var + eps)
+        bshape = tuple(x.shape[ax] if i == ax else 1 for i in range(x.ndim))
+        return (y.astype(x.dtype) * gamma.reshape(bshape)
+                + beta.reshape(bshape)).astype(x.dtype)
+
     if ax == x.ndim - 1:
         from . import pallas_kernels as pk
-        if pk.use_fused():
-            return pk.fused_layernorm(x, gamma, beta, eps)
-    x32 = x.astype(jnp.float32)
-    mean = jnp.mean(x32, axis=ax, keepdims=True)
-    var = jnp.var(x32, axis=ax, keepdims=True)
-    y = (x32 - mean) * jax.lax.rsqrt(var + eps)
-    bshape = tuple(x.shape[ax] if i == ax else 1 for i in range(x.ndim))
-    return (y.astype(x.dtype) * gamma.reshape(bshape) + beta.reshape(bshape))
+        return pk.dispatch(
+            lambda x, g, b: pk.fused_layernorm(x, g, b, eps), plain,
+            x, gamma, beta)
+    return plain(x, gamma, beta)
 
 
 @register('L2Normalization', param_defaults={'eps': 1e-10, 'mode': 'instance'})
@@ -540,8 +546,8 @@ def _softmax(attrs, x):
     ax = int(attrs.get('axis', -1)) % x.ndim
     if ax == x.ndim - 1:
         from . import pallas_kernels as pk
-        if pk.use_fused():
-            return pk.fused_softmax(x)
+        return pk.dispatch(pk.fused_softmax,
+                           lambda x: jax.nn.softmax(x, axis=-1), x)
     return jax.nn.softmax(x, axis=ax)
 
 
@@ -562,14 +568,18 @@ def _softmax_activation(attrs, x):
 
 @register('softmax_cross_entropy', input_names=['data', 'label'])
 def _softmax_cross_entropy(attrs, data, label):
-    lab = label.astype(jnp.int32)
     from . import pallas_kernels as pk
-    if pk.use_fused():
+
+    def fused(data, lab):
         # fused logsumexp+gather — never materializes softmax in HBM
         return pk.softmax_xent(data, lab).sum().astype(data.dtype)
-    logp = jax.nn.log_softmax(data, axis=-1)
-    picked = jnp.take_along_axis(logp, lab[:, None], axis=-1)
-    return -jnp.sum(picked)
+
+    def plain(data, lab):
+        logp = jax.nn.log_softmax(data, axis=-1)
+        picked = jnp.take_along_axis(logp, lab[:, None], axis=-1)
+        return -jnp.sum(picked)
+
+    return pk.dispatch(fused, plain, data, label.astype(jnp.int32))
 
 
 @register('SoftmaxOutput', input_names=['data', 'label'],

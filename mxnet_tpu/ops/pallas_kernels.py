@@ -12,10 +12,16 @@ optimally on the MXU/VMEM hierarchy:
 - :func:`softmax_xent` — fused logsumexp + gather loss for LM heads,
   avoiding the [N, V] softmax materialization.
 
-Every kernel runs `interpret=True` off-TPU, so the same code path is
-exercised by the CPU test mesh (tests/unittest/test_pallas.py) and
-compiled for real on TPU. Backward passes use jax.custom_vjp with a
-recompute strategy (jax.checkpoint-style), keeping kernels forward-only.
+Where a kernel runs is decided by where its operands live, never by the
+process: concrete arrays are asked for their device, and under a trace
+``jax.lax.platform_dependent`` leaves the choice to the lowering, which
+knows the platform it compiles for. Operands on a TPU get the compiled
+kernel; anywhere else the same kernel body runs through the Pallas
+interpreter (tests/unittest/test_pallas.py on the CPU mesh). A kernel the
+chip's compiler would refuse raises here, with its shapes; nothing gives
+way to the jnp formulation quietly. Backward passes use jax.custom_vjp
+with a recompute strategy (jax.checkpoint-style), keeping kernels
+forward-only.
 """
 import functools
 
@@ -27,20 +33,45 @@ __all__ = ['flash_attention', 'flash_attention_lse', 'fused_rmsnorm',
            'fused_layernorm', 'fused_softmax', 'softmax_xent']
 
 
-def use_fused():
-    """Dispatch policy for the registry ops: real kernels on TPU; on CPU
-    the jnp formulations are faster than interpret-mode pallas, so the
-    fused path is opt-in there (MXTPU_FORCE_PALLAS=1, used in tests)."""
-    from ..config import flags as _flags
-    _flags.reload('MXTPU_FORCE_PALLAS')  # tests toggle it per-case
-    return (jax.default_backend() == 'tpu'
-            or _flags.get('MXTPU_FORCE_PALLAS'))
-
 _NEG = -1e30
 
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB (the rehearsal
+# compiles of tests/unittest/test_tpu_compile.py hold these to it).
+# Row kernels keep one f32 [blk, D] working tile under _ROW_TILE_BYTES:
+# the pipeline double-buffers the input and output blocks and the body
+# holds a few f32 temporaries of the same shape, ~8 tiles in all.
+_ROW_TILE_BYTES = 1 << 20
+# flash forward holds whole-axis K and V blocks, double-buffered
+_FLASH_KV_BYTES = 10 << 20
 
-def _interpret():
-    return jax.default_backend() != 'tpu'
+
+def _by_platform(operands, on_tpu, elsewhere):
+    """``on_tpu(*operands)`` where the operands live on a TPU,
+    ``elsewhere(*operands)`` on any other platform."""
+    for x in operands:
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            tpu = all(d.platform == 'tpu' for d in x.devices())
+            return (on_tpu if tpu else elsewhere)(*operands)
+    return jax.lax.platform_dependent(*operands, tpu=on_tpu,
+                                      default=elsewhere)
+
+
+def dispatch(fused, plain, *operands):
+    """Policy for the registry ops (ops/nn.py): the fused kernel for
+    operands on a TPU, the plain jnp formulation elsewhere (faster there
+    than interpreted Pallas) unless MXTPU_FORCE_PALLAS=1, the tests'
+    explicit switch, routes every platform through the kernel."""
+    from ..config import flags as _flags
+    _flags.reload('MXTPU_FORCE_PALLAS')  # tests toggle it per-case
+    if _flags.get('MXTPU_FORCE_PALLAS'):
+        return fused(*operands)
+    return _by_platform(operands, fused, plain)
+
+
+def run_kernel(build, *operands):
+    """Run ``build(interpret)(*operands)``: compiled for operands on a
+    TPU, interpreted elsewhere."""
+    return _by_platform(operands, build(False), build(True))
 
 
 def _block_ok(blk, dim):
@@ -81,6 +112,22 @@ def _pad_and_block(want, n):
     want = max(want, 8)
     pad = (-n) % 8 if (n > want and _pick_block(want, n) == n) else 0
     return pad, _pick_block(want, n + pad)
+
+
+def _row_block(name, want, x2):
+    """Rows per block for a row kernel over ``x2`` [N, D]: ``want``, cut
+    down until one f32 [blk, D] tile fits _ROW_TILE_BYTES (a [128, 32000]
+    f32 xent block is 16 MB before double buffering — all of VMEM). A
+    row so wide that even the 8-row minimum does not fit is refused."""
+    D = x2.shape[-1]
+    rows = _ROW_TILE_BYTES // (4 * D)
+    if rows < 8:
+        raise ValueError(
+            '%s: rows of %d elements (operand %s %s) do not fit VMEM even '
+            'at the minimum block of 8 rows (%d bytes against %d)'
+            % (name, D, tuple(x2.shape), x2.dtype.name, 8 * 4 * D,
+               _ROW_TILE_BYTES))
+    return min(want, rows - rows % 8)
 
 
 # ---------------------------------------------------------------------------
@@ -161,6 +208,17 @@ def _flash_fwd_impl(q, k, v, causal, scale, blk_q, blk_k):
     # and the score tile stays bounded by blk_q rows.
     pad_q, blk_q = _pad_and_block(min(blk_q, Tq), Tq)
     blk_k = _pick_block(blk_k, Tk)
+    kv_bytes = 2 * 2 * Tk * D * k.dtype.itemsize
+    if kv_bytes > _FLASH_KV_BYTES:
+        # the same answer on every platform: the interpreter would take
+        # it, the chip's compiler would not ("Ran out of memory in memory
+        # space vmem"); a blockwise K loop is ROADMAP S7
+        raise ValueError(
+            'flash_attention: keys/values %s %s need %d bytes of VMEM as '
+            'whole-axis blocks (q %s), more than the %d this kernel '
+            'allows; shorten Tk or shard the sequence (ring_attention)'
+            % (tuple(k.shape), k.dtype.name, kv_bytes, tuple(q.shape),
+               _FLASH_KV_BYTES))
     # [B, T, H, D] -> [B*H, T, D] for a clean 2-d grid
     qh = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
     kh = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
@@ -175,7 +233,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, blk_q, blk_k):
 
     kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
                                blk_q=blk_q, blk_k=blk_k, offset=Tk - Tq)
-    out, lse = pl.pallas_call(
+    out, lse = run_kernel(lambda interpret: pl.pallas_call(
         kernel,
         grid=(B * H, Tq_p // blk_q),
         in_specs=[
@@ -187,8 +245,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, blk_q, blk_k):
                    pl.BlockSpec((1, blk_q, 1), lambda b, i: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, Tq_p, 1), jnp.float32)],
-        interpret=_interpret(),
-    )(qh, kh, vh)
+        interpret=interpret, name='flash_attention_fwd'), qh, kh, vh)
     out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
     lse = lse[:, :Tq].reshape(B, H, Tq)
     return out, lse
@@ -296,25 +353,24 @@ def _layernorm_kernel(x_ref, g_ref, b_ref, o_ref, eps):
                 b_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _norm_call(kernel, arrs, x, block_rows=256):
+def _norm_call(name, kernel, arrs, x, block_rows=256):
     lead = x.shape[:-1]
     D = x.shape[-1]
     x2 = x.reshape(-1, D)
     N = x2.shape[0]
     if N == 0:                       # empty batch: nothing to launch
         return x2.reshape(lead + (D,))
-    pad, blk = _pad_and_block(block_rows, N)
+    pad, blk = _pad_and_block(_row_block(name, block_rows, x2), N)
     if pad:
         x2 = jnp.concatenate([x2, jnp.zeros((pad, D), x2.dtype)])
-    out = pl.pallas_call(
+    out = run_kernel(lambda interpret: pl.pallas_call(
         kernel,
         grid=((N + pad) // blk,),
         in_specs=[pl.BlockSpec((blk, D), lambda i: (i, 0))] +
                  [pl.BlockSpec((D,), lambda i: (0,))] * len(arrs),
         out_specs=pl.BlockSpec((blk, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N + pad, D), x.dtype),
-        interpret=_interpret(),
-    )(x2, *arrs)
+        interpret=interpret, name=name), x2, *arrs)
     return out[:N].reshape(lead + (D,))
 
 
@@ -323,7 +379,7 @@ def fused_rmsnorm(x, gamma, eps=1e-6):
     """RMSNorm in one VMEM pass over the feature dim."""
     def kern(x_ref, g_ref, o_ref):
         _rmsnorm_kernel(x_ref, g_ref, o_ref, eps)
-    return _norm_call(kern, (gamma,), x)
+    return _norm_call('fused_rmsnorm', kern, (gamma,), x)
 
 
 def _rms_ref(x, gamma, eps):
@@ -350,7 +406,7 @@ def fused_layernorm(x, gamma, beta, eps=1e-5):
     """LayerNorm in one VMEM pass over the feature dim."""
     def kern(x_ref, g_ref, b_ref, o_ref):
         _layernorm_kernel(x_ref, g_ref, b_ref, o_ref, eps)
-    return _norm_call(kern, (gamma, beta), x)
+    return _norm_call('fused_layernorm', kern, (gamma, beta), x)
 
 
 def _ln_ref(x, gamma, beta, eps):
@@ -389,7 +445,7 @@ def _softmax_kernel(x_ref, o_ref):
 @jax.custom_vjp
 def fused_softmax(x):
     """Last-axis softmax in one VMEM pass (max+exp+sum+div fused)."""
-    return _norm_call(_softmax_kernel, (), x)
+    return _norm_call('fused_softmax', _softmax_kernel, (), x)
 
 
 def _softmax_fwd(x):
@@ -430,19 +486,19 @@ def softmax_xent(logits, labels):
     N, V = logits.shape
     if N == 0:                       # empty batch: nothing to launch
         return jnp.zeros((0,), jnp.float32)
-    pad, blk = _pad_and_block(128, N)
+    pad, blk = _pad_and_block(_row_block('softmax_xent', 128, logits), N)
     if pad:
         logits = jnp.concatenate([logits, jnp.zeros((pad, V), logits.dtype)])
         labels = jnp.concatenate([labels, jnp.zeros((pad,), labels.dtype)])
-    return pl.pallas_call(
+    return run_kernel(lambda interpret: pl.pallas_call(
         _xent_kernel,
         grid=((N + pad) // blk,),
         in_specs=[pl.BlockSpec((blk, V), lambda i: (i, 0)),
                   pl.BlockSpec((blk, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((blk, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N + pad, 1), jnp.float32),
-        interpret=_interpret(),
-    )(logits, labels[:, None])[:N, 0]
+        interpret=interpret, name='softmax_xent'),
+        logits, labels[:, None])[:N, 0]
 
 
 def _xent_fwd(logits, labels):

@@ -15,7 +15,7 @@ distribution story (SURVEY.md §2.3, §5.8):
   context parallelism via ring attention over ``ppermute``
   (:mod:`ring_attention`) — new capability.
 """
-from ._compat import shard_map  # noqa: F401  (version-stable spelling)
+from jax import shard_map  # noqa: F401  (re-exported: mx.parallel.shard_map)
 from .mesh import DeviceMesh, make_mesh, local_mesh
 from .collectives import (allreduce, allgather, reduce_scatter, ring_permute,
                           alltoall, axis_index, axis_size, pbroadcast)

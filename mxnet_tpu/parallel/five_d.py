@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .mesh import DeviceMesh

@@ -190,11 +190,15 @@ _AGREE_TIMEOUT_S = 60.0
 def _client():
     """The jax.distributed coordination-service client, or None when no
     multi-process job is up (single-process: every agreement is local)."""
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client
-    except Exception:  # noqa: BLE001 — internal layout moved
+    import jax
+    if not jax.distributed.is_initialized():
         return None
+    # jax 0.9.0 has no public accessor for the coordination client; the
+    # experimental multihost_utils module carries the handle it uses
+    # itself. (Its own barriers are device collectives with no timeout,
+    # which is exactly what these agreements must not be.)
+    from jax.experimental import multihost_utils
+    return multihost_utils.distributed.global_state.client
 
 
 def is_primary():

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 __all__ = ['PipelineStage', 'pipeline_apply', 'stack_stage_params']
 

@@ -205,7 +205,7 @@ def make_ring_attention(mesh, axis='sp', causal=False, impl='ring', scale=None):
     sequence dim over `axis`, runs the chosen kernel, unshards nothing
     (output stays sequence-sharded, matching the input layout)."""
     from jax.sharding import PartitionSpec as P
-    from ._compat import shard_map
+    from jax import shard_map
     fn = {'ring': ring_attention, 'ulysses': ulysses_attention,
           'striped': striped_attention}[impl]
     spec = P(None, axis, None, None)

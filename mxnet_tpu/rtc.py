@@ -73,11 +73,14 @@ class Rtc:
         kern = env['kernel']
         out_spec = [jax.ShapeDtypeStruct(s, d)
                     for s, d in zip(self._out_shapes, self._out_dtypes)]
-        interpret = jax.default_backend() != 'tpu'
+        from .ops.pallas_kernels import run_kernel
 
         def run(*arrays):
-            outs = pl.pallas_call(kern, out_shape=out_spec,
-                                  interpret=interpret)(*arrays)
+            # compiled where the inputs live on a TPU, interpreted elsewhere
+            outs = run_kernel(
+                lambda interpret: pl.pallas_call(
+                    kern, out_shape=out_spec, interpret=interpret),
+                *arrays)
             return outs if isinstance(outs, (tuple, list)) else (outs,)
         return jax.jit(run)
 

@@ -10,7 +10,7 @@ outputs before they leave the engine. After :meth:`ServingEngine.warmup`
 the steady state performs zero compiles — each program registers
 through ``telemetry/programs.register``, so the existing
 ``xla.compiles`` counter is the proof (asserted in
-tests/unittest/test_serving.py), and ``MXTPU_COMPILE_CACHE`` makes even
+tests/unittest/test_serving.py), and the persistent compile cache makes even
 the warmup itself warm across restarts.
 
 The forward program is the read-only single-step twin of
@@ -165,23 +165,24 @@ class ServingEngine(_SingleExecutorEngine):
 
     # -- checkpoint -> engine ----------------------------------------------
     @classmethod
-    def from_checkpoint(cls, prefix, epoch, data_shapes, context=None,
+    def from_checkpoint(cls, prefix, epoch, data_shapes, context,
                         max_batch=None, logger=logging, **module_kwargs):
         """``Module.load`` + inference bind + engine in one step.
 
         ``data_shapes``: [(name, per_example_shape)] WITHOUT the batch
-        dimension — the engine owns batching. Label variables a
+        dimension — the engine owns batching. ``context`` is the device
+        (or device list) to serve from and has no default: a checkpoint
+        trained on the chip is not quietly served from the host. Label variables a
         training graph carries (e.g. ``softmax_label``) are bound as
         plain zero arrays, exactly like a predict-bound module
         (``label_names=[]``); the ``is_train=False`` forward never
         reads them."""
-        from .. import context as ctx_mod
         from ..module.module import Module
         data_shapes = [(n, tuple(s)) for n, s in data_shapes]
         max_b = int(max_batch) if max_batch else _serve_max_batch()
         mod = Module.load(prefix, epoch,
                           data_names=[n for n, _ in data_shapes],
-                          label_names=[], context=context or ctx_mod.cpu(),
+                          label_names=[], context=context,
                           logger=logger, **module_kwargs)
         mod.bind(data_shapes=[(n, (max_b,) + s) for n, s in data_shapes],
                  for_training=False)
@@ -321,7 +322,7 @@ class ServingEngine(_SingleExecutorEngine):
 
     # -- warmup ------------------------------------------------------------
     def warmup(self, buckets=None):
-        """Compile (or load from ``MXTPU_COMPILE_CACHE``) every bucket's
+        """Compile (or load from the persistent compile cache) every bucket's
         program and run each once, so the serving steady state performs
         zero compiles — the `xla.compiles` counter is flat afterwards.
         Returns the number of programs warmed."""

@@ -103,7 +103,7 @@ eval window loop), ``executor.forward|backward``,
 ``io.prefetch_wait`` + counter ``io.batches``, ``kvstore.push|pull``
 spans + ``kvstore.push_bytes`` / ``kvstore.pull_bytes`` counters,
 gauge ``speedometer.samples_per_sec``, the ``xla.*`` compile/memory
-metrics, and — with MXTPU_COMPILE_CACHE set — ``xla.cache_hits`` /
+metrics, and — with the persistent compile cache on — ``xla.cache_hits`` /
 ``xla.cache_saved_secs`` for compiles served from the persistent
 cache. The serving plane (mxnet_tpu/serving) reports through the same
 registry: ``serve.request_latency`` histogram + ``serve.requests`` /

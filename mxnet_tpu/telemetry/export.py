@@ -15,7 +15,7 @@ MXTPU_TELEMETRY_PATH in docs/env_vars.md. Records buffer in memory and flush eve
 on a per-batch fsync.
 
 ``summary_table`` renders a registry snapshot as the end-of-run table
-docs/perf.md documents ("Reading the telemetry summary").
+(docs/observability.md).
 """
 import json
 import logging

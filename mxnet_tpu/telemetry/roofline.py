@@ -74,8 +74,8 @@ CLASS_MEMORY = 'memory-bound'
 CLASS_OVERHEAD = 'overhead-bound'
 CLASS_UNKNOWN = 'unknown'  # no peak table entry for this device
 
-# class -> the concrete lever to pull (the docs/perf.md "Closing the
-# MFU gap" guide, kept next to the classifier so the two never drift):
+# class -> the concrete lever to pull (kept next to the classifier so
+# the two never drift):
 # which knob in THIS codebase reclaims a layer of that class
 RECLAIM_ACTIONS = {
     CLASS_MEMORY: 'cut HBM traffic: MXTPU_BN_ONEPASS=1 one-pass stats, '
@@ -91,7 +91,7 @@ RECLAIM_ACTIONS = {
 
 def suggest_action(cls):
     """The lever string for a bottleneck class ('' for unknown): what
-    docs/perf.md's class->action guide says to pull, machine-readable
+    the class->action table above says to pull, machine-readable
     so the worst layer's record/gauge names its remedy directly."""
     return RECLAIM_ACTIONS.get(cls, '')
 

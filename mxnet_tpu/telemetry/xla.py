@@ -4,7 +4,7 @@ Compile observability comes from jax.monitoring: XLA emits
 ``/jax/core/compile/backend_compile_duration`` once per backend
 compile, which feeds the ``xla.compiles`` counter, the accumulated
 ``xla.compile_secs``, and a per-compile JSONL record. With the
-persistent compilation cache on (MXTPU_COMPILE_CACHE), the cache's
+persistent compilation cache on (config.enable_compile_cache), the cache's
 ``cache_hits`` / ``compile_time_saved_sec`` events feed
 ``xla.cache_hits`` and ``xla.cache_saved_secs`` — how many compiles a
 warm start was served from disk, and the seconds it refunded. The
@@ -16,7 +16,7 @@ programs (Executor construction, the fused-fit window builder) call
 :func:`note_retrace` with a value key identifying the graph; the same
 key arriving more than ``MXTPU_TELEMETRY_RETRACE_WARN`` times is the
 classic retrace storm (a shape/attr leaking into the program key every
-batch — the 49.8 img/s pathology of docs/perf.md) and logs one loud
+batch) and logs one loud
 warning plus a ``retrace_storm`` JSONL record.
 
 Memory gauges read ``device.memory_stats()`` (live/peak bytes on TPU;
@@ -36,7 +36,7 @@ __all__ = ['install', 'note_retrace', 'note_step_flops', 'sample_memory',
            'device_peak_flops', 'device_peaks', 'mfu_estimate']
 
 _COMPILE_EVENT_SUFFIX = 'backend_compile_duration'
-# persistent-compilation-cache events (MXTPU_COMPILE_CACHE): a hit
+# persistent-compilation-cache events: a hit
 # means a compile request was served from disk instead of XLA
 _CACHE_HIT_EVENT = '/jax/compilation_cache/cache_hits'
 _CACHE_SAVED_SUFFIX = 'compile_time_saved_sec'
@@ -50,12 +50,9 @@ _PEAK_TABLE = [
     ('v6', 918e12, 1640e9), ('v5p', 459e12, 2765e9), ('v5', 197e12, 819e9),
     ('v4', 275e12, 1228e9), ('v3', 123e12, 900e9), ('v2', 45e12, 700e9),
 ]
-# CPU fallback: NOMINAL host ceilings (order-of-magnitude: one modern
-# core's FMA throughput and stream bandwidth) so a CPU run still gets a
-# best-effort roofline classification. Marked nominal — the MFU
-# estimate ignores nominal peaks (a "29% MFU" against a guessed CPU
-# peak would be noise presented as signal).
-_NOMINAL_CPU_PEAKS = (1e11, 5e10)
+# A host CPU has no entry and gets none: a roofline share or an MFU
+# against a guessed peak would be a CPU number under a device metric's
+# name. MXTPU_PEAK_TFLOPS / MXTPU_PEAK_HBM_GBS name one explicitly.
 
 _installed = False
 _install_lock = threading.Lock()
@@ -246,9 +243,10 @@ def device_peaks(device=None, warn=True):
     as a dict: ``flops`` (peak dense bf16 FLOP/s), ``hbm_bytes_s``
     (peak HBM bytes/s), ``kind``, and per-component
     ``flops_source``/``hbm_source`` — 'table' (a known chip),
-    'override' (MXTPU_PEAK_TFLOPS/MXTPU_PEAK_HBM_GBS), 'nominal' (the
-    best-effort CPU guess), or 'unknown' (no entry: zero, warned once,
-    ``roofline.peaks_unknown`` published). ``source`` is the combined
+    'override' (MXTPU_PEAK_TFLOPS/MXTPU_PEAK_HBM_GBS), 'none' (a host
+    CPU: zero, and no share of a peak is computed), or 'unknown' (no
+    entry: zero, warned once, ``roofline.peaks_unknown`` published; for
+    a device whose platform is 'tpu' that is an MXNetError). ``source`` is the combined
     label ('a+b' when the components disagree). ``warn=False``
     suppresses the unknown-kind warn + gauge write — the read-only
     scrape path's contract (a /summary request must not write the
@@ -267,9 +265,9 @@ def device_peaks(device=None, warn=True):
             flops, hbm = f, b
             flops_src = hbm_src = 'table'
             break
-    if flops_src == 'unknown' and (not kind or 'cpu' in kind):
-        flops, hbm = _NOMINAL_CPU_PEAKS
-        flops_src = hbm_src = 'nominal'
+    on_cpu = getattr(device, 'platform', '') == 'cpu'
+    if flops_src == 'unknown' and on_cpu:
+        flops_src = hbm_src = 'none'       # by design, not a lookup miss
     # Overrides replace only the component they set — a lone
     # MXTPU_PEAK_HBM_GBS must not promote a nominal/unknown FLOP/s
     # value to trusted-for-MFU status (device_peak_flops keys on the
@@ -279,8 +277,17 @@ def device_peaks(device=None, warn=True):
         flops, flops_src = ov_f, 'override'
     if ov_b:
         hbm, hbm_src = ov_b, 'override'
-    if warn and 'unknown' in (flops_src, hbm_src):
-        _warn_peaks_unknown(kind)
+    if 'unknown' in (flops_src, hbm_src):
+        if getattr(device, 'platform', '') == 'tpu':
+            # the chip path: a device that is not in the table is an
+            # error, not a default
+            from ..base import MXNetError
+            raise MXNetError(
+                'TPU device kind %r has no entry in telemetry.xla.'
+                '_PEAK_TABLE; add its published peaks there (or set '
+                'MXTPU_PEAK_TFLOPS and MXTPU_PEAK_HBM_GBS)' % kind)
+        if warn:
+            _warn_peaks_unknown(kind)
     source = (flops_src if flops_src == hbm_src
               else flops_src + '+' + hbm_src)
     return {'flops': flops, 'hbm_bytes_s': hbm, 'kind': kind,
@@ -289,11 +296,9 @@ def device_peaks(device=None, warn=True):
 
 
 def device_peak_flops(device=None):
-    """(peak_bf16_flops, device_kind) for the MFU denominator. Nominal
-    (guessed-CPU) peaks report 0.0 here — MFU against a guessed peak
-    would be noise — while the roofline keeps them via
-    :func:`device_peaks`. Unknown kinds also report 0.0, after the
-    warn-once + ``roofline.peaks_unknown`` publication."""
+    """(peak_bf16_flops, device_kind) for the MFU denominator. A host
+    CPU reports 0.0 (no MFU there); unknown kinds also report 0.0, after
+    the warn-once + ``roofline.peaks_unknown`` publication."""
     p = device_peaks(device)
     if p['flops_source'] in ('table', 'override'):
         return p['flops'], p['kind']

@@ -1,4 +1,4 @@
-"""Convergence gate: bucketing LM perplexity (VERDICT item 10).
+"""Convergence gate: bucketing LM perplexity.
 
 Reference: tests/python/train/test_bucketing.py — train a small bucketed
 LSTM LM and assert the final perplexity beats a threshold. Data is a

@@ -1,4 +1,4 @@
-"""Convergence gate: MLP + conv accuracy thresholds (VERDICT item 10).
+"""Convergence gate: MLP + conv accuracy thresholds.
 
 Reference: tests/python/train/test_mlp.py + test_conv.py — train a small
 net on MNIST for a couple of epochs and assert an accuracy floor. Runs

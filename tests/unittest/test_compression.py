@@ -35,7 +35,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.config import flags
 from mxnet_tpu.parallel import compression as C
-from mxnet_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 _FLAGS = ('MXTPU_GRAD_COMPRESS', 'MXTPU_GRAD_COMPRESS_BLOCK',
           'MXTPU_SHARDED_UPDATE', 'MXTPU_FUSED_FIT', 'MXTPU_TELEMETRY',
@@ -276,7 +276,7 @@ def test_compressed_psum_matches_psum(mode):
         return C.compressed_psum(xs, 'dp', mode=mode, block=16)
 
     fn = shard_map(body, mesh=mesh, in_specs=P('dp', None),
-                   out_specs=P('dp', None), check_rep=False)
+                   out_specs=P('dp', None), check_vma=False)
     xg = jax.device_put(x, NamedSharding(mesh, P('dp', None)))
     got = np.asarray(jax.jit(fn)(xg))
     want = x.sum(axis=0)

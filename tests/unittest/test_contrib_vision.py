@@ -1,4 +1,4 @@
-"""Contrib vision/quantization ops (VERDICT item 9).
+"""Contrib vision/quantization ops.
 
 Reference: tests/python/unittest/test_operator.py (deformable conv /
 PSROIPooling entries), tests/python/unittest/test_contrib_operator.py

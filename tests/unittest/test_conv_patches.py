@@ -1,6 +1,6 @@
 """MXTPU_CONV_BWD_PATCHES=1 parity: the patches-matmul weight gradient
 equals the default conv_backprop_filter to numerical precision
-(ops/nn.py _conv2d_patches_bwd; motivation in docs/perf.md:34).
+(ops/nn.py _conv2d_patches_bwd).
 
 The flag is parsed once per process, so each mode runs in ONE fresh
 subprocess computing every case (2 jax startups total)."""

@@ -18,21 +18,6 @@ from mxnet_tpu.parallel.five_d import (TransformerConfig, full_mesh,
 
 CFG = TransformerConfig(vocab=61, d_model=16, n_heads=4, ffn=16, experts=2)
 
-# jax 0.4.x ships the old jax.experimental.shard_map whose
-# check_rep=False transpose mis-specs scalar cotangents through the
-# GPipe schedule (the 5-D pipeline LOSS runs; its gradient does not —
-# noted in CHANGES.md since PR 1). Newer jax fixes the transpose, so
-# the mark is version-gated and non-strict: on an upgraded jax the
-# test simply passes.
-OLD_SHARD_MAP = tuple(int(x) for x in jax.__version__.split('.')[:2]) < (0, 5)
-_PIPELINE_GRAD_XFAIL = pytest.mark.xfail(
-    condition=OLD_SHARD_MAP,
-    reason='jax 0.4.x shard_map check_rep=False transpose mis-specs '
-           'scalar cotangents through the pipeline loss gradient '
-           '(needs newer jax)',
-    strict=False)
-
-
 def _data(n_micro=3, batch=4, seq=8, seed=0):
     rng = np.random.RandomState(seed)
     toks = rng.randint(0, CFG.vocab, (n_micro, batch, seq)).astype(np.int32)
@@ -64,7 +49,6 @@ def test_pipeline_matches_serial():
         assert np.isclose(serial, par, rtol=2e-4), (axes, serial, par)
 
 
-@_PIPELINE_GRAD_XFAIL
 def test_train_step_learns_and_syncs():
     mesh = full_mesh({'pp': 2, 'dp': 2, 'tp': 2})
     init_state, step = make_5d_train_step(CFG, mesh, lr=0.5)

@@ -429,9 +429,9 @@ def test_eval_telemetry_gauge(tmp_path, monkeypatch):
 
 
 def test_compile_cache_round_trip(tmp_path):
-    """MXTPU_COMPILE_CACHE: a second process compiling the same program
-    is served from the persistent cache (telemetry counts the hits) —
-    the warm-start path that skips the 20-40s XLA compiles."""
+    """JAX_COMPILATION_CACHE_DIR: a second process compiling the same
+    program is served from the persistent cache at that directory
+    (telemetry counts the hits) — the warm-start path."""
     import subprocess
     import sys
     code = r'''
@@ -447,7 +447,7 @@ print(json.dumps({'val': float(y.asnumpy()),
 '''
     import json
     env = dict(os.environ)
-    env['MXTPU_COMPILE_CACHE'] = str(tmp_path / 'xla_cache')
+    env['JAX_COMPILATION_CACHE_DIR'] = str(tmp_path / 'xla_cache')
     env['MXTPU_TELEMETRY'] = '1'
     env['MXTPU_TELEMETRY_PATH'] = str(tmp_path / 't.jsonl')
     env['JAX_PLATFORMS'] = 'cpu'

@@ -212,7 +212,7 @@ def test_fused_scheduler_no_recompile_and_exact_equality(step_kind):
     it lands on a window edge or MID-window (round-5: per-step lr
     sampling), with one compiled program despite the lr changing."""
     import mxnet_tpu.module.fused_fit as ff
-    W = ff._window_size()
+    W = ff._window_size(_mlp_mod()[0])
     step = W if step_kind == 'aligned' else max(2, W - 1)
     results = {}
     for fused in (True, False):
@@ -518,7 +518,7 @@ def test_fused_exactly_one_window_epoch_completes():
     import mxnet_tpu.module.fused_fit as ff
     os.environ['MXTPU_FUSED_FIT'] = '1'
     try:
-        W = ff._window_size()
+        W = ff._window_size(_mlp_mod()[0])
         cb_count = []
         mod, it = _mlp_mod(n=8 * W, batch=8)   # exactly W batches
         mod.fit(it, num_epoch=1, optimizer='sgd',

@@ -217,7 +217,12 @@ def test_cpu_fit_buckets_sum_to_wall_within_5pct(tele_on):
     assert abs(total - wall) <= 0.05 * wall + 0.01
     attributed = total - g['buckets']['overhead']
     assert attributed <= 1.05 * wall
-    assert g['buckets']['step'] > 0          # the fit trained
+    # the fit trained. Its step bucket may be clamped to 0: compute()
+    # takes compile seconds outside fused_fit.build out of the step
+    # spans, and on a loaded machine this tiny fit's eager compiles
+    # (outside any step span) outweigh its eight MLP steps
+    assert telemetry.snapshot()['counters']['fit.steps'] == 8
+    assert g['buckets']['step'] >= 0
     assert g['buckets']['compile'] > 0       # ... and compiled
     assert 0.0 <= g['goodput_pct'] <= 100.0
     assert g['badput_top'] in BUCKETS

@@ -1,4 +1,4 @@
-"""im2rec tool + ImageDetRecordIter (VERDICT item 9, detection IO).
+"""im2rec tool + ImageDetRecordIter.
 
 Reference: tools/im2rec.{py,cc} + src/io/iter_image_det_recordio.cc +
 tests/python/unittest/test_io.py patterns.

@@ -576,7 +576,7 @@ def test_registered_host_codec_ops_execute(tmp_path):
     """The ops the execution gate flagged as never-invoked: each of the
     _cv* host codecs, round, _slice_like_getitem, and _CustomFunction
     executes through its registered surface (nd.* / invoke), not just
-    a name mention (VERDICT r3 weak #4)."""
+    a name mention."""
     import io as _pyio
     import numpy as np
     import mxnet_tpu as mx

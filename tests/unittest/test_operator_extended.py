@@ -1,4 +1,4 @@
-"""Extended operator coverage (VERDICT item 7).
+"""Extended operator coverage.
 
 Reference: tests/python/unittest/test_operator.py (4,010 LoC) — the
 numeric-gradient + numpy-oracle pattern applied across the registered

@@ -1,4 +1,4 @@
-"""Optimizer-vs-python-reference checks (VERDICT item 7).
+"""Optimizer-vs-python-reference checks.
 
 Reference: tests/python/unittest/test_optimizer.py — every optimizer is
 stepped alongside an independent numpy implementation of its published

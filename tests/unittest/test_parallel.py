@@ -238,7 +238,7 @@ def test_ring_attention_gradients_match_reference():
 
 @pytest.mark.parametrize('causal', [False, True])
 def test_ulysses_attention_gradients_match_reference(causal):
-    """Ulysses backward parity (VERDICT r3 #7): grads through the two
+    """Ulysses backward parity: grads through the two
     all_to_alls (heads<->seq transposes) must match the single-device
     oracle — an SP mode you cannot backprop through is inference-only."""
     import jax

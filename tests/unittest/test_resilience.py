@@ -14,8 +14,7 @@ module/resilient_fit.py, mxnet_tpu/faults.py, tools/train_supervisor):
   thread, no armed fault, empty registry;
 - every fault kind drills its recovery path: checkpoint-corrupt falls
   back to an older step, dispatch-exception exercises restart backoff
-  without a health incident, slow-host delays the step counter,
-  backend-probe-timeout drives bench's reprobe;
+  without a health incident, slow-host delays the step counter;
 - restart budget/retryability in resilient_fit, restart records in the
   JSONL stream, and the whole-process supervisor's relaunch loop.
 """
@@ -395,19 +394,6 @@ def test_fault_parse_rejects_garbage(all_off, monkeypatch):
     _reload()
     faults._reset_for_tests()
     assert not faults.enabled()   # warn + disabled, never raises
-
-
-def test_backend_probe_timeout_parse(all_off, monkeypatch):
-    """bench.py parses backend-probe-timeout without importing the
-    framework (its backend decision precedes any mxnet_tpu import)."""
-    import importlib
-    import bench
-    monkeypatch.setenv('MXTPU_FAULT_INJECT', 'backend-probe-timeout:2')
-    assert bench._fault_probe_timeouts() == 2
-    monkeypatch.setenv('MXTPU_FAULT_INJECT', 'nan-grad:5')
-    assert bench._fault_probe_timeouts() == 0
-    monkeypatch.delenv('MXTPU_FAULT_INJECT')
-    assert bench._fault_probe_timeouts() == 0
 
 
 # ---------------------------------------------------------------------------

@@ -268,6 +268,8 @@ def test_fit_acceptance_on_off(roof, tmp_path, monkeypatch):
     monkeypatch.setenv('MXTPU_TELEMETRY', '1')
     monkeypatch.setenv('MXTPU_TELEMETRY_PATH', str(path))
     monkeypatch.setenv('MXTPU_ROOFLINE', roof)
+    # a host CPU has no peaks of its own: the test names them
+    _set_peaks(monkeypatch, 0.1, 50.0)
     _reload_flags()
     telemetry._reset_for_tests()
     try:
@@ -419,10 +421,10 @@ def test_partial_override_keeps_mfu_contract(roof_on, monkeypatch):
     monkeypatch.setenv('MXTPU_PEAK_HBM_GBS', '456.0')
     flags.reload('MXTPU_PEAK_TFLOPS')
     flags.reload('MXTPU_PEAK_HBM_GBS')
-    # CPU: hbm overridden, flops still the nominal guess -> no MFU
+    # CPU: hbm overridden, flops still not named -> no MFU
     p = tele_xla.device_peaks()
     assert p['hbm_source'] == 'override'
-    assert p['flops_source'] == 'nominal'
+    assert p['flops_source'] == 'none'
     assert p['hbm_bytes_s'] == pytest.approx(456e9)
     peak, _ = tele_xla.device_peak_flops()
     assert peak == 0.0                     # never MFU against a guess
@@ -435,14 +437,24 @@ def test_partial_override_keeps_mfu_contract(roof_on, monkeypatch):
         .gauge('roofline.peaks_unknown').value == 1
 
 
-def test_cpu_peaks_nominal_but_no_mfu():
-    """CPU gets best-effort roofline denominators, but never an MFU
-    against a guessed peak."""
+def test_cpu_has_no_peaks_and_no_mfu():
+    """A host CPU gets no guessed denominators: no roofline share and
+    no MFU is computed against it."""
     p = tele_xla.device_peaks()            # conftest pins the CPU mesh
-    assert p['source'] == 'nominal'
-    assert p['flops'] > 0 and p['hbm_bytes_s'] > 0
+    assert p['source'] == 'none'
+    assert p['flops'] == 0.0 and p['hbm_bytes_s'] == 0.0
     peak, _ = tele_xla.device_peak_flops()
     assert peak == 0.0
+
+
+def test_unlisted_tpu_kind_is_an_error():
+    """On the chip path a device that is not in the table is an error,
+    not a default."""
+    class _NewTpu:
+        device_kind = 'TPU v99'
+        platform = 'tpu'
+    with pytest.raises(mx.base.MXNetError, match='no entry in'):
+        tele_xla.device_peaks(_NewTpu())
 
 
 # ---------------------------------------------------------------------------

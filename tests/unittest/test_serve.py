@@ -586,7 +586,9 @@ def test_jsonl_sink_size_cap(tmp_path, caplog):
     assert len(warns) == 1                # warned once, not per drop
     # post-cap emits are dropped silently (no growth, no raise)
     sink2 = tele_export.JsonlSink(str(path), max_bytes=256)
-    sink2.emit({'type': 'event', 'name': 'late'})
+    # (padded past the cap: a short record can still fit under it when
+    # the first sink's variable-width timestamps left a few bytes free)
+    sink2.emit({'type': 'event', 'name': 'late', 'pad': 'x' * 256})
     sink2.close()
     assert os.path.getsize(path) == size
 

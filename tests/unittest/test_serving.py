@@ -540,7 +540,7 @@ def test_http_serve_and_query_end_to_end(tele_on, tmp_path):
     mod.save_checkpoint(prefix, 1)
     eng = ServingEngine.from_checkpoint(prefix, 1,
                                         data_shapes=[('data', (10,))],
-                                        max_batch=8)
+                                        context=mx.cpu(), max_batch=8)
     eng.warmup()
     compiles0 = telemetry.snapshot()['counters'].get('xla.compiles', 0)
     srv = start_server(eng, DynamicBatcher(eng, max_wait_ms=100), port=0)
@@ -730,7 +730,7 @@ def test_serve_model_cli_whole_process(tmp_path):
     proc = subprocess.Popen(
         [sys.executable, os.path.join(repo, 'tools', 'serve_model.py'),
          prefix, '--epoch', '1', '--data-shape', '10', '--port', '0',
-         '--max-batch', '8', '--max-wait-ms', '100'],
+         '--context', 'cpu', '--max-batch', '8', '--max-wait-ms', '100'],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         env=env)
     try:

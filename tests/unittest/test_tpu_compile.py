@@ -1,0 +1,132 @@
+"""Rehearsal compiles: every Pallas kernel of the main path, compiled (not
+interpreted, not run) by the TPU compiler installed here for a v5e that is
+described and not attached, at the widths the repo talks about.
+
+What the interpreter accepts and the chip's compiler refuses (a block that
+does not fit VMEM, a slice off the tiling) fails here at no chip time.
+
+Discipline (the on-chip-measurement guide, section 2): the topology is
+described inside the module-scoped fixture below and nowhere else: not
+at import, not in conftest.py, not in a skipif or parametrize argument,
+not in a child process. Only the worker that is handed this file loads
+the TPU's library, and all such compiles live in this ONE file.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope='module')
+def topo():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        jax.config.update('jax_enable_compilation_cache', was)
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    yield desc
+    jax.config.update('jax_enable_compilation_cache', was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *specs):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    # the kernel itself, not the interpreter's while loop and not jnp
+    assert 'tpu_custom_call' in text, text[:2000]
+    return compiled
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+KERNELS = [
+    ('flash_fwd_b8_t1024', lambda q, k, v: pk.flash_attention(q, k, v, True),
+     [((8, 1024, 8, 128), BF16)] * 3),
+    ('flash_fwd_b1_t8192', lambda q, k, v: pk.flash_attention(q, k, v, True),
+     [((1, 8192, 8, 128), BF16)] * 3),
+    ('layernorm_8192x1024', pk.fused_layernorm,
+     [((8192, 1024), BF16), ((1024,), F32), ((1024,), F32)]),
+    ('rmsnorm_8192x4096', pk.fused_rmsnorm,
+     [((8192, 4096), BF16), ((4096,), F32)]),
+    ('softmax_8192x1024', pk.fused_softmax, [((8192, 1024), BF16)]),
+    ('softmax_32x1000', pk.fused_softmax, [((32, 1000), F32)]),
+    ('xent_32x1000', pk.softmax_xent, [((32, 1000), F32), ((32,), I32)]),
+    # the repo's own decoder (bench.py build_decoder: vocab 16384, 8x1024
+    # tokens a step) and a 32000-word LM head: refused before the row
+    # block was taken from the row width
+    ('xent_8192x16384', pk.softmax_xent,
+     [((8192, 16384), BF16), ((8192,), I32)]),
+    ('xent_8192x32000', pk.softmax_xent,
+     [((8192, 32000), F32), ((8192,), I32)]),
+]
+
+
+@pytest.mark.parametrize('name,fn,specs', KERNELS,
+                         ids=[k[0] for k in KERNELS])
+def test_kernel_compiles_for_v5e(one_chip, name, fn, specs):
+    compiled = _compile(fn, one_chip, *specs)
+    mem = compiled.memory_analysis()
+    assert mem is not None and mem.temp_size_in_bytes >= 0
+
+
+def test_registry_ops_take_the_kernel_when_lowered_for_tpu(one_chip):
+    """ops/nn.py picks by the platform being lowered for, not by
+    jax.default_backend() (the CPU here): the same registry function is
+    the Pallas kernel in a TPU program and plain jnp in a CPU one."""
+    from mxnet_tpu.ops.registry import get
+    ln = get('LayerNorm').fn
+    fn = lambda x, g, b: ln({}, x, g, b)  # noqa: E731
+    _compile(fn, one_chip, ((256, 1024), BF16), ((1024,), F32),
+             ((1024,), F32))
+    x = jnp.ones((8, 32), jnp.float32)
+    cpu_text = jax.jit(fn).lower(x, jnp.ones(32), jnp.zeros(32)) \
+        .compile().as_text()
+    assert 'tpu_custom_call' not in cpu_text
+
+
+def test_flash_forward_past_vmem_raises_with_shapes(one_chip):
+    """[1, 32768, 8, 128]: whole-axis K/V blocks cannot fit; a clear
+    error at trace time, on every platform, not a compiler dump."""
+    spec = jax.ShapeDtypeStruct((1, 32768, 8, 128), BF16, sharding=one_chip)
+    with pytest.raises(ValueError, match=r'flash_attention: keys/values '
+                                         r'\(1, 32768, 8, 128\) bfloat16'):
+        jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, True)) \
+            .lower(spec, spec, spec)
+    q = jnp.zeros((1, 32768, 8, 128), BF16)      # and eagerly, on the CPU
+    with pytest.raises(ValueError, match='more than the'):
+        pk.flash_attention(q, q, q, True)
+
+
+def test_row_too_wide_for_vmem_raises_with_shapes():
+    x = jax.ShapeDtypeStruct((16, 1 << 16), F32)
+    with pytest.raises(ValueError, match=r'softmax_xent: rows of 65536'):
+        jax.eval_shape(pk.softmax_xent, x,
+                       jax.ShapeDtypeStruct((16,), I32))
+
+
+def test_row_block_follows_width():
+    blk = lambda d: pk._row_block('t', 256, np.zeros((0, d), np.float32))  # noqa: E731
+    assert blk(1024) == 256 and blk(4096) == 64
+    assert blk(16384) == 16 and blk(32000) == 8

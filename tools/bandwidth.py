@@ -23,7 +23,7 @@ def measure_collectives(sizes, iters, dtype='float32'):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     n = len(devs)
@@ -49,7 +49,7 @@ def measure_collectives(sizes, iters, dtype='float32'):
             'psum': (shard_map(allreduce, mesh=mesh, in_specs=P('x'),
                                out_specs=P('x')), 2 * (n - 1) / n),
             'all_gather': (shard_map(allgather, mesh=mesh, in_specs=P('x'),
-                                     out_specs=P(), check_rep=False),
+                                     out_specs=P(), check_vma=False),
                            (n - 1) / n),
             'reduce_scatter': (shard_map(reducescatter, mesh=mesh,
                                          in_specs=P('x'), out_specs=P('x')),

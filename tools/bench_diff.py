@@ -3,8 +3,7 @@
 
 The bench history is the repo's perf ledger; nothing so far CHECKED it
 — a throughput or MFU slide between rounds only surfaced when a human
-re-read the numbers. This is the post-bench gate ("Benchmarking as a
-gate", docs/perf.md)::
+re-read the numbers. This is the post-bench gate::
 
     python tools/bench_diff.py BENCH_r05.json BENCH_r06.json
 
@@ -291,7 +290,7 @@ def main(argv=None):
                     'MFU, XLA temp bytes, per-device opt-state bytes, '
                     'cold compile time) with per-metric tolerance; '
                     'non-zero exit on regression — the post-bench CI '
-                    'gate (docs/perf.md).')
+                    'gate.')
     ap.add_argument('old', help='baseline bench artifact')
     ap.add_argument('new', help='candidate bench artifact')
     ap.add_argument('--tol-pct', type=float, default=None,

@@ -54,6 +54,8 @@ a liveness kill whose child exited 0 reports 1, CLI misuse (2) never
 retries. Budget/backoff/liveness/record code is shared with
 tools/train_supervisor.py.
 """
+# This supervisor stays off JAX (stdlib imports only): it never holds the
+# chip, so the children it starts are free to take it.
 import argparse
 import os
 import signal

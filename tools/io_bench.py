@@ -1,4 +1,4 @@
-"""ImageRecordIter streaming-scale bench (VERDICT r3 #3 done-criterion).
+"""ImageRecordIter streaming-scale bench .
 
 Generates a synthetic JPEG .rec of the requested size, then streams it
 through ImageRecordIter with full augmentation, reporting throughput

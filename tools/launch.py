@@ -23,6 +23,8 @@ relaunch the whole job on a fresh coordinator port against a restart
 budget, optionally shrink the worker set after a host loss — wrap the
 job in ``tools/gang_supervisor.py`` instead.
 """
+# This supervisor stays off JAX (stdlib imports only): it never holds the
+# chip, so the children it starts are free to take it.
 import argparse
 import os
 import socket

@@ -17,8 +17,7 @@ Layers are matched by name. For each: time delta, headroom delta
 (positive ``reclaimed`` = the after-run sits closer to its roofline),
 and the class transition when one happened. Ranked by headroom
 reclaimed, worst regression last, with step-time and whole-program
-totals — the "re-measure" step of docs/perf.md's "Closing the MFU
-gap" worked example. Layers present on only one side are listed (a
+totals — the "re-measure" step after pulling a lever. Layers present on only one side are listed (a
 renamed scope or a remat-policy flip can legitimately add/remove
 layers); ``--json`` dumps the raw diff for scripting.
 """
@@ -161,7 +160,7 @@ def main(argv=None):
         description='Diff two roofline records (telemetry JSONL or '
                     'BENCH json): per-layer headroom reclaimed, class '
                     'transitions, step-time movement — the re-measure '
-                    'step of the MFU-gap workflow (docs/perf.md).')
+                    'step of the MFU-gap workflow.')
     ap.add_argument('old', help='baseline artifact (JSONL or BENCH json)')
     ap.add_argument('new', help='candidate artifact (JSONL or BENCH json)')
     ap.add_argument('--top', type=int, default=16,

@@ -3,15 +3,16 @@
 
 The checkpoint -> endpoint path (docs/serving.md)::
 
-    python tools/serve_model.py mymodel --epoch 3 --data-shape 3,224,224
+    python tools/serve_model.py mymodel --epoch 3 --data-shape 3,224,224 \
+        --context tpu:0
     python tools/serve_model.py mymodel --epoch 3 --data-shape 10 \
-        --port 8500 --max-batch 64 --max-wait-ms 3
+        --context cpu --port 8500 --max-batch 64 --max-wait-ms 3
 
 Loads ``<prefix>-symbol.json`` + ``<prefix>-<epoch>.params``
 (``Module.save_checkpoint`` artifacts) via ``Module.load``, binds for
 inference, pre-compiles the bucket ladder (power-of-two batch shapes up
-to --max-batch; warm instantly across restarts with
-``MXTPU_COMPILE_CACHE`` set), and serves:
+to --max-batch; warm across restarts through the persistent compile
+cache, config.enable_compile_cache), and serves:
 
 - ``POST /predict`` — JSON ``{"data": [[...], ...]}`` (or
   ``{"inputs": {...}}`` for multi-input graphs, or a raw .npy body);
@@ -44,6 +45,14 @@ def _parse_shape(text):
             'shape must be comma-separated ints, e.g. 3,224,224')
 
 
+def _parse_context(text):
+    kind, _, dev_id = text.partition(':')
+    if kind not in ('cpu', 'tpu') or (dev_id and not dev_id.isdigit()):
+        raise argparse.ArgumentTypeError(
+            'context must be cpu, cpu:N, tpu or tpu:N (got %r)' % text)
+    return kind, int(dev_id or 0)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description='Serve a Module checkpoint over HTTP with dynamic '
@@ -69,8 +78,10 @@ def main(argv=None):
     ap.add_argument('--max-wait-ms', type=float, default=None,
                     help='batcher coalescing deadline (default '
                          'MXTPU_SERVE_MAX_WAIT_MS)')
-    ap.add_argument('--context', default='cpu', choices=['cpu', 'tpu'],
-                    help='device to serve from (default cpu)')
+    ap.add_argument('--context', required=True, type=_parse_context,
+                    help='device to serve from, named and not guessed: '
+                         'tpu:0, tpu:1, ... or cpu (a device that is not '
+                         'there is an error)')
     ap.add_argument('--no-warmup', action='store_true',
                     help='skip pre-compiling the bucket ladder (first '
                          'requests then pay the compiles)')
@@ -87,7 +98,7 @@ def main(argv=None):
     from mxnet_tpu.serving import ServingEngine, DynamicBatcher
     from mxnet_tpu.serving.http import start_server
 
-    ctx = mx.tpu() if args.context == 'tpu' else mx.cpu()
+    ctx = mx.Context(*args.context)
     engine = ServingEngine.from_checkpoint(
         args.prefix, args.epoch,
         data_shapes=list(zip(names, args.data_shapes)),
@@ -98,8 +109,9 @@ def main(argv=None):
                           DynamicBatcher(engine,
                                          max_wait_ms=args.max_wait_ms),
                           port=args.port)
-    print('serving %s on port %d (buckets %s)'
-          % (engine.name, server.port, engine.buckets), flush=True)
+    print('serving %s on port %d from %s (buckets %s)'
+          % (engine.name, server.port, ctx.jax_device(), engine.buckets),
+          flush=True)
 
     # an Event has no check-then-wait window: a SIGTERM landing at any
     # point sets it and wait() returns — never a signal consumed just

@@ -43,6 +43,8 @@ worker wedge in collectives that can never complete — so it needs
 ``tools/gang_supervisor.py``, which launches and relaunches the W
 workers as a gang on this module's budget/backoff/liveness policy.
 """
+# This supervisor stays off JAX (stdlib imports only): it never holds the
+# chip, so the children it starts are free to take it.
 import argparse
 import json
 import os
