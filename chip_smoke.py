@@ -19,8 +19,8 @@ batch 32, bf16 compute with fp32 master weights):
 
 With ``--four-chips`` it runs ONLY data-parallel ``Module.fit`` over
 ``[mx.tpu(i) for i in range(4)]`` with ``kvstore='device'`` at global batch
-128 and the same seed and batch on ``mx.tpu(0)`` alone, and compares the
-per-window losses.
+128 and the same seed and batch on ``mx.tpu(0)`` alone, both in float32, and
+compares the losses: the first four steps tightly, then two whole windows.
 
 It fails at once, non-zero, unless ``jax.devices()[0].platform == 'tpu'``
 (with the option: unless there are four such devices); any failed phase or
@@ -46,15 +46,26 @@ import urllib.request
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# Per-window mean cross-entropy of the four-chip fit against the one-chip fit
-# of the same seed and global batch: same math, another reduction order, in
-# bf16. How far rounding alone moves the loss was measured on one chip by
-# swapping BatchNorm's one-pass statistics for the two-pass form (equal in
-# exact arithmetic): at lr 0.1 the windows moved by 7.1% and 9.9%, at lr 0.02
-# by 0.15% and 1.4% (my chip run, PR 23). So the comparison trains at lr 0.02
-# and allows 5%; at lr 0.1 it would have compared noise.
+# The four-chip fit against the one-chip fit of the same seed and global
+# batch: same math, another reduction order. Both arms run in float32 with
+# full-precision matmuls, so that rounding starts at 1e-7 and not at bf16's
+# 4e-3. Training then amplifies whatever difference there is: over two
+# 32-step windows the float32 arms still ended 0.25% and 1.2% apart (bf16:
+# 0.09% and 3.7%; my chip runs, PR 23), as far as two bf16 runs on ONE chip
+# that differ only in BatchNorm's one-pass or two-pass statistics (0.15%,
+# 1.4%). A whole window's loss therefore cannot tell a fault from rounding
+# in any precision, and is held to 5%, which catches a gross fault (a wrong
+# learning-rate scale, a shard left out). The tight comparison is the first
+# FOUR_CHIP_EARLY_STEPS steps, before amplification: set before its first
+# run, expecting under 1e-4 there (1e-6 of reduction-order noise, times e per
+# step at the worst), where BatchNorm statistics taken per shard or a
+# gradient summed and not averaged move the loss by 1e-2 or more. Measured
+# then: 4.7e-4, which is how far one-pass BatchNorm statistics on ONE chip sit
+# from the two-pass form; four chips agree with that form to 4e-6 (CHANGES.md).
 FOUR_CHIP_LR = 0.02
 FOUR_CHIP_LOSS_RTOL = 0.05
+FOUR_CHIP_EARLY_STEPS = 4
+FOUR_CHIP_EARLY_RTOL = 1e-3
 # served logits against Module.predict on the same rows (both bf16 on the chip)
 SERVE_ATOL = 2e-2
 
@@ -120,7 +131,9 @@ def phase_fit(mx, sym, contexts, image_shape, num_classes, batch, windows,
     mx.random.seed(seed)
     np.random.seed(seed)
     mod = mx.mod.Module(sym, context=contexts)
-    W = steps_per_window or window_size(mod)
+    if steps_per_window:     # the user's knob; unset: 32 on a TPU, 4 on CPU
+        os.environ['MXTPU_FIT_STEPS_PER_CALL'] = str(steps_per_window)
+    W = window_size(mod)
     train = synthetic_iter(mx, np, batch, image_shape, num_classes, W, seed)
     log('fit: %d windows of %d steps, batch %d, lr %g, contexts %s, '
         'kvstore %r' % (windows, W, batch, lr, contexts, kvstore))
@@ -139,6 +152,7 @@ def phase_fit(mx, sym, contexts, image_shape, num_classes, batch, windows,
             initializer=mx.init.Xavier(rnd_type='gaussian',
                                        factor_type='in', magnitude=2),
             batch_end_callback=note, num_epoch=windows)
+    os.environ.pop('MXTPU_FIT_STEPS_PER_CALL', None)
     params = live_params(mod)
     for a in params:
         a.block_until_ready()
@@ -165,9 +179,11 @@ def phase_fit(mx, sym, contexts, image_shape, num_classes, batch, windows,
                                        for d in a.devices()})))
     assert where == [platform], where
     assert all(np.isfinite(v) for v in losses), losses
-    assert losses[-1] < losses[0], \
-        'loss did not go down: %s' % losses
     return mod, losses, W
+
+
+def assert_learned(losses):
+    assert losses[-1] < losses[0], 'loss did not go down: %s' % losses
 
 
 # ---------------------------------------------------------------------------
@@ -467,29 +483,56 @@ def collectives_of(mod):
     return found
 
 
-def phase_four_chips(mx, sym, image_shape, num_classes, global_batch, n_dev,
-                     windows, seed, platform, steps_per_window=None):
+def full_precision_ops(mod):
+    return sum(exe.as_text().count('operand_precision={highest,highest}')
+               for exe in window_programs(mod))
+
+
+def four_chip_arms(mx, sym, image_shape, num_classes, global_batch, n_dev,
+                   windows, seed, platform, steps_per_window):
+    """The same fit over `n_dev` chips and on chip 0 alone. Returns the two
+    lists of per-window losses and the steps per window."""
+    fit = dict(kvstore='device', lr=FOUR_CHIP_LR)
     many, many_losses, W = phase_fit(
         mx, sym, [mx.tpu(i) for i in range(n_dev)], image_shape,
         num_classes, global_batch, windows, seed, platform,
-        kvstore='device', steps_per_window=steps_per_window,
-        lr=FOUR_CHIP_LR)
+        steps_per_window=steps_per_window, **fit)
     phase_spmd_facts(many, n_dev, global_batch, image_shape, W)
     found = collectives_of(many)
     log('four chips: collectives in the compiled window: %s' % found)
     assert found, 'no collective in the compiled data-parallel window'
+    full = full_precision_ops(many)
     del many
     gc.collect()
-    _, one_losses, _ = phase_fit(
+    one, one_losses, _ = phase_fit(
         mx, sym, mx.tpu(0), image_shape, num_classes, global_batch,
-        windows, seed, platform, kvstore='device', steps_per_window=W,
-        lr=FOUR_CHIP_LR)
-    for w, (a, b) in enumerate(zip(many_losses, one_losses)):
-        rel = abs(a - b) / max(abs(b), 1e-6)
-        log('four chips: window %d loss %.5f on %d chips, %.5f on one '
-            '(relative difference %.3g, allowed %.3g)'
-            % (w, a, n_dev, b, rel, FOUR_CHIP_LOSS_RTOL))
-        assert rel <= FOUR_CHIP_LOSS_RTOL, (w, a, b)
+        windows, seed, platform, steps_per_window=W, **fit)
+    full = (full, full_precision_ops(one))
+    log('four chips: matmuls and convolutions at full float32 precision '
+        'in the compiled windows: %d on %d chips, %d on one'
+        % (full[0], n_dev, full[1]))
+    assert min(full) > 0, full
+    return many_losses, one_losses, W
+
+
+def phase_four_chips(mx, sym, image_shape, num_classes, global_batch, n_dev,
+                     windows, seed, platform):
+    import jax
+    args = (mx, sym, image_shape, num_classes, global_batch, n_dev)
+    # a float32 matmul takes one bf16 pass through the MXU unless asked
+    with jax.default_matmul_precision('highest'):
+        for n, W, rtol in ((1, FOUR_CHIP_EARLY_STEPS, FOUR_CHIP_EARLY_RTOL),
+                           (windows, None, FOUR_CHIP_LOSS_RTOL)):
+            many_losses, one_losses, W = four_chip_arms(
+                *args, n, seed, platform, W)
+            for w, (a, b) in enumerate(zip(many_losses, one_losses)):
+                rel = abs(a - b) / max(abs(b), 1e-6)
+                log('four chips: steps %d-%d loss %.7f on %d chips, %.7f on '
+                    'one (relative difference %.3g, allowed %.3g)'
+                    % (w * W, (w + 1) * W - 1, a, n_dev, b, rel, rtol))
+                assert rel <= rtol, (w, a, b)
+    assert_learned(many_losses)
+    assert_learned(one_losses)
 
 
 # ---------------------------------------------------------------------------
@@ -530,11 +573,13 @@ def main(argv=None):
     try:
         import mxnet_tpu as mx
         if args.four_chips:
-            phase_four_chips(mx, resnet50(), (3, 224, 224), 1000, 128, 4,
-                             2, args.seed, 'tpu')
+            phase_four_chips(mx, resnet50('float32'), (3, 224, 224), 1000,
+                             128, 4, 2, args.seed, 'tpu')
         else:
-            mod, _, _ = phase_fit(mx, resnet50(), mx.tpu(0), (3, 224, 224),
-                                  1000, 32, 3, args.seed, 'tpu')
+            mod, losses, _ = phase_fit(mx, resnet50(), mx.tpu(0),
+                                       (3, 224, 224), 1000, 32, 3,
+                                       args.seed, 'tpu')
+            assert_learned(losses)
             engine = phase_serve(mx, mod, mx.tpu(0), workdir, (3, 224, 224),
                                  32, (1, 3, 32), args.seed, 'tpu')
             del mod, engine

@@ -9,8 +9,8 @@ compiled step).
     python train_imagenet.py --network resnet --num-layers 50 \
         --benchmark 1 --batch-size 32 --gpus 0
 
-The device is asked for, as in the reference: ``--gpus 0,1,2,3`` (or its
-alias ``--tpus``) trains on those chips, and without it on ``mx.cpu()``.
+The device is asked for, as in the reference: ``--gpus 0,1,2,3``
+trains on those chips, and without it on ``mx.cpu()``.
 """
 import argparse
 import logging
@@ -43,7 +43,7 @@ def main():
     parser.add_argument('--num-epochs', type=int, default=1)
     parser.add_argument('--lr', type=float, default=0.1)
     parser.add_argument('--kv-store', default='device')
-    parser.add_argument('--gpus', '--tpus', dest='gpus', default=None,
+    parser.add_argument('--gpus', default=None,
                         help='list of chips to run on, e.g. 0 or 0,2,5. '
                              'empty means using cpu')
     parser.add_argument('--benchmark', type=int, default=0,

@@ -105,8 +105,8 @@ def nd_sync_copy_from_bytes(handle, buf, dtype_code):
                          % (len(buf), expect))
     arr = np.frombuffer(buf, dtype=np_dtype).reshape(handle.shape)
     if dtype == 'bfloat16':
-        import jax.numpy as jnp
-        handle._set_data(jnp.asarray(arr))
+        import jax
+        handle._set_data(jax.device_put(arr, handle._data.sharding))
         return 0
     handle[:] = arr if handle.ndim else _nd_mod.array(arr.reshape(()))
     return 0

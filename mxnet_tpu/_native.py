@@ -40,10 +40,20 @@ def _stale():
 
 
 def _build():
+    # several processes of one checkout may build at once (test workers):
+    # each links its own file and renames it into place, so no process
+    # ever loads a library that another is still writing
     srcs = [os.path.join(_SRC, f) for f in _SOURCES]
+    tmp = '%s.%d.tmp' % (_SO, os.getpid())
     cmd = ['g++', '-std=c++17', '-O2', '-fPIC', '-Wall', '-pthread',
-           '-shared', '-o', _SO] + srcs
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+           '-shared', '-o', tmp] + srcs
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True,
+                       timeout=300)
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _bind(lib):

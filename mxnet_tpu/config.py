@@ -700,7 +700,9 @@ flags.declare('MXTPU_GANG_MIN_HOSTS', int, 0,
 
 # The one default place of the persistent compilation cache: a fixed path
 # inside the checkout (listed in .gitignore). The path is part of the
-# cache key, so it never holds a temp dir, a pid or a time.
+# cache key, so it never holds a temp dir, a pid or a time. It assumes the
+# package is run from a source checkout, as everything in this repo is;
+# installed elsewhere, name the directory with JAX_COMPILATION_CACHE_DIR.
 COMPILE_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     '.jax_compile_cache')
@@ -717,7 +719,8 @@ def enable_compile_cache():
     - not set, platform list pinned to ``cpu`` (the tests' CPU mesh,
       context.cpu_mesh_mode): off.
     - not set, otherwise (a process that may use the chip): on, at
-      :data:`COMPILE_CACHE_DIR`.
+      :data:`COMPILE_CACHE_DIR`; off with a warning where that directory
+      cannot be made (a package installed outside a writable checkout).
 
     Every executable is cached, not only the slow-to-compile ones;
     telemetry counts served compiles under ``xla.cache_hits``."""
@@ -728,6 +731,13 @@ def enable_compile_cache():
         if cpu_mesh_mode():
             return None
         path = COMPILE_CACHE_DIR
+        try:
+            os.makedirs(path, exist_ok=True)
+        except OSError as e:
+            import logging
+            logging.warning('compile cache off: %s cannot be made (%s); '
+                            'set JAX_COMPILATION_CACHE_DIR', path, e)
+            return None
         jax.config.update('jax_compilation_cache_dir', path)
     jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
     jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)

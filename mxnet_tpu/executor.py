@@ -296,10 +296,12 @@ class Executor:
     def _forward_impl(self, is_train=False, **kwargs):
         for k, v in kwargs.items():
             if k in self.arg_dict:
-                if isinstance(v, NDArray):
-                    self.arg_dict[k]._data = v._data
-                else:
-                    self.arg_dict[k]._data = jnp.asarray(np.asarray(v))
+                # onto the device this argument was bound on, not the
+                # process's default one
+                bound = self.arg_dict[k]
+                bound._data = jax.device_put(
+                    v._data if isinstance(v, NDArray) else np.asarray(v),
+                    bound._data.sharding)
         self._partial = None  # a full forward invalidates any stepping pass
         if self._use_staged():
             return self._forward_staged(is_train)

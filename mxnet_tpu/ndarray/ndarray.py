@@ -238,7 +238,11 @@ class NDArray:
         else:
             value = jnp.asarray(np.asarray(value), dtype=self._data.dtype)
         if key is None or key == slice(None):
-            self._set_data(jnp.broadcast_to(value, self.shape).astype(self._data.dtype))
+            # the new value goes where this array lives (its device, or its
+            # sharding over a mesh), not where jnp put the operand: an
+            # uncommitted operand sits on the process's default device
+            new = jnp.broadcast_to(value, self.shape).astype(self._data.dtype)
+            self._set_data(jax.device_put(new, self._data.sharding))
         else:
             self._set_data(self._data.at[key].set(value))
 

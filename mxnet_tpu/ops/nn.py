@@ -135,8 +135,7 @@ def _conv2d_stem_s2d(data, weight, stride, pad):
     The image-network stem (ResNet 7x7/s2, AlexNet 11x11/s4,
     Inception 3x3/s2 — all cin=3) is the worst conv shape on the MXU:
     3 input channels leave the 128x128 systolic array ~98% idle and the
-    stride-2 footprint defeats XLA's tiling (measured 11-13% MFU,
-    docs/tpu_artifacts/conv_breakdown_*.json). Re-expressing it over
+    stride-2 footprint defeats XLA's tiling. Re-expressing it over
     the s-strided phase decomposition x2[qh, qw, c*s^2 + rh*s + rw] =
     x[s*qh+rh, s*qw+rw] turns it into a dense stride-1 conv with
     cin*s^2 channels — exactly the MLPerf-ResNet space-to-depth trick,

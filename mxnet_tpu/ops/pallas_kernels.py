@@ -18,8 +18,11 @@ process: concrete arrays are asked for their device, and under a trace
 knows the platform it compiles for. Operands on a TPU get the compiled
 kernel; anywhere else the same kernel body runs through the Pallas
 interpreter (tests/unittest/test_pallas.py on the CPU mesh). A kernel the
-chip's compiler would refuse raises here, with its shapes; nothing gives
-way to the jnp formulation quietly. Backward passes use jax.custom_vjp
+chip's compiler would refuse raises here, with its shapes, where it is
+bound for a TPU: at once for operands on one, and under a trace when the
+program is lowered for one (a program lowered for the CPU never meets
+the limit, and the interpreter has none). Nothing gives way to the jnp
+formulation quietly. Backward passes use jax.custom_vjp
 with a recompute strategy (jax.checkpoint-style), keeping kernels
 forward-only.
 """
@@ -28,6 +31,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.extend.core import Primitive
+from jax.interpreters import batching, mlir
 
 __all__ = ['flash_attention', 'flash_attention_lse', 'fused_rmsnorm',
            'fused_layernorm', 'fused_softmax', 'softmax_xent']
@@ -39,8 +44,10 @@ _NEG = -1e30
 # compiles of tests/unittest/test_tpu_compile.py hold these to it).
 # Row kernels keep one f32 [blk, D] working tile under _ROW_TILE_BYTES:
 # the pipeline double-buffers the input and output blocks and the body
-# holds a few f32 temporaries of the same shape, ~8 tiles in all.
-_ROW_TILE_BYTES = 1 << 20
+# holds a few f32 temporaries of the same shape. At 2 MiB every row
+# kernel still compiles at the widest row that leaves the 8-row minimum
+# (65536 elements, f32 and bf16); a 50k-word LM head fits with room.
+_ROW_TILE_BYTES = 2 << 20
 # flash forward holds whole-axis K and V blocks, double-buffered
 _FLASH_KV_BYTES = 10 << 20
 
@@ -68,10 +75,49 @@ def dispatch(fused, plain, *operands):
     return _by_platform(operands, fused, plain)
 
 
-def run_kernel(build, *operands):
+def run_kernel(build, *operands, too_big=None):
     """Run ``build(interpret)(*operands)``: compiled for operands on a
-    TPU, interpreted elsewhere."""
-    return _by_platform(operands, build(False), build(True))
+    TPU, interpreted elsewhere. ``too_big`` is the error for a kernel
+    whose blocks the chip's compiler would refuse: it takes the compiled
+    kernel's place, so only a TPU ever meets it."""
+    interp = build(True)
+    on_tpu = (build(False) if too_big is None
+              else functools.partial(_refused, interp, too_big))
+    return _by_platform(operands, on_tpu, interp)
+
+
+def _refused(interp, msg, *operands):
+    """Stands where a compiled kernel would: raises ``msg`` for concrete
+    operands, and under a trace stages a primitive that raises it when
+    (and only when) the branch is lowered, which platform_dependent does
+    for a TPU alone."""
+    if not any(isinstance(x, jax.core.Tracer) for x in operands):
+        raise ValueError(msg)
+    outs, tree = jax.tree.flatten(jax.eval_shape(interp, *operands))
+    return jax.tree.unflatten(tree, _refuse_p.bind(
+        *operands, msg=msg,
+        outs=tuple((o.shape, o.dtype) for o in outs)))
+
+
+_refuse_p = Primitive('mxtpu_kernel_refused')
+_refuse_p.multiple_results = True
+_refuse_p.def_abstract_eval(lambda *_, msg, outs: [
+    jax.core.ShapedArray(shape, dtype) for shape, dtype in outs])
+
+
+def _refuse_lowering(ctx, *_, msg, outs):
+    raise ValueError(msg)
+
+
+def _refuse_batch(args, dims, *, msg, outs):
+    n = next(a.shape[d] for a, d in zip(args, dims) if d is not None)
+    res = _refuse_p.bind(*args, msg=msg, outs=tuple(
+        ((n,) + shape, dtype) for shape, dtype in outs))
+    return res, [0] * len(res)
+
+
+mlir.register_lowering(_refuse_p, _refuse_lowering)
+batching.primitive_batchers[_refuse_p] = _refuse_batch
 
 
 def _block_ok(blk, dim):
@@ -115,19 +161,20 @@ def _pad_and_block(want, n):
 
 
 def _row_block(name, want, x2):
-    """Rows per block for a row kernel over ``x2`` [N, D]: ``want``, cut
-    down until one f32 [blk, D] tile fits _ROW_TILE_BYTES (a [128, 32000]
-    f32 xent block is 16 MB before double buffering — all of VMEM). A
-    row so wide that even the 8-row minimum does not fit is refused."""
+    """(rows per block, too_big) for a row kernel over ``x2`` [N, D]:
+    ``want`` rows, cut down until one f32 [blk, D] tile fits
+    _ROW_TILE_BYTES (a [128, 32000] f32 xent block is 16 MB before double
+    buffering — all of VMEM). For a row so wide that even the 8-row
+    minimum does not fit, ``too_big`` is run_kernel's error."""
     D = x2.shape[-1]
     rows = _ROW_TILE_BYTES // (4 * D)
     if rows < 8:
-        raise ValueError(
+        return 8, (
             '%s: rows of %d elements (operand %s %s) do not fit VMEM even '
             'at the minimum block of 8 rows (%d bytes against %d)'
             % (name, D, tuple(x2.shape), x2.dtype.name, 8 * 4 * D,
                _ROW_TILE_BYTES))
-    return min(want, rows - rows % 8)
+    return min(want, rows - rows % 8), None
 
 
 # ---------------------------------------------------------------------------
@@ -209,11 +256,11 @@ def _flash_fwd_impl(q, k, v, causal, scale, blk_q, blk_k):
     pad_q, blk_q = _pad_and_block(min(blk_q, Tq), Tq)
     blk_k = _pick_block(blk_k, Tk)
     kv_bytes = 2 * 2 * Tk * D * k.dtype.itemsize
+    too_big = None
     if kv_bytes > _FLASH_KV_BYTES:
-        # the same answer on every platform: the interpreter would take
-        # it, the chip's compiler would not ("Ran out of memory in memory
-        # space vmem"); a blockwise K loop is ROADMAP S7
-        raise ValueError(
+        # the chip's compiler would answer "Ran out of memory in memory
+        # space vmem"; a blockwise K loop is ROADMAP S7
+        too_big = (
             'flash_attention: keys/values %s %s need %d bytes of VMEM as '
             'whole-axis blocks (q %s), more than the %d this kernel '
             'allows; shorten Tk or shard the sequence (ring_attention)'
@@ -245,7 +292,8 @@ def _flash_fwd_impl(q, k, v, causal, scale, blk_q, blk_k):
                    pl.BlockSpec((1, blk_q, 1), lambda b, i: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, Tq_p, 1), jnp.float32)],
-        interpret=interpret, name='flash_attention_fwd'), qh, kh, vh)
+        interpret=interpret, name='flash_attention_fwd'), qh, kh, vh,
+        too_big=too_big)
     out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
     lse = lse[:, :Tq].reshape(B, H, Tq)
     return out, lse
@@ -360,7 +408,8 @@ def _norm_call(name, kernel, arrs, x, block_rows=256):
     N = x2.shape[0]
     if N == 0:                       # empty batch: nothing to launch
         return x2.reshape(lead + (D,))
-    pad, blk = _pad_and_block(_row_block(name, block_rows, x2), N)
+    want, too_big = _row_block(name, block_rows, x2)
+    pad, blk = _pad_and_block(want, N)
     if pad:
         x2 = jnp.concatenate([x2, jnp.zeros((pad, D), x2.dtype)])
     out = run_kernel(lambda interpret: pl.pallas_call(
@@ -370,7 +419,7 @@ def _norm_call(name, kernel, arrs, x, block_rows=256):
                  [pl.BlockSpec((D,), lambda i: (0,))] * len(arrs),
         out_specs=pl.BlockSpec((blk, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N + pad, D), x.dtype),
-        interpret=interpret, name=name), x2, *arrs)
+        interpret=interpret, name=name), x2, *arrs, too_big=too_big)
     return out[:N].reshape(lead + (D,))
 
 
@@ -486,7 +535,8 @@ def softmax_xent(logits, labels):
     N, V = logits.shape
     if N == 0:                       # empty batch: nothing to launch
         return jnp.zeros((0,), jnp.float32)
-    pad, blk = _pad_and_block(_row_block('softmax_xent', 128, logits), N)
+    want, too_big = _row_block('softmax_xent', 128, logits)
+    pad, blk = _pad_and_block(want, N)
     if pad:
         logits = jnp.concatenate([logits, jnp.zeros((pad, V), logits.dtype)])
         labels = jnp.concatenate([labels, jnp.zeros((pad,), labels.dtype)])
@@ -498,7 +548,7 @@ def softmax_xent(logits, labels):
         out_specs=pl.BlockSpec((blk, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N + pad, 1), jnp.float32),
         interpret=interpret, name='softmax_xent'),
-        logits, labels[:, None])[:N, 0]
+        logits, labels[:, None], too_big=too_big)[:N, 0]
 
 
 def _xent_fwd(logits, labels):

@@ -11,8 +11,11 @@ i-th virtual CPU device. MXTPU_TEST_TPU=1 (the tests/tpu consistency tier,
 run on the machine with the chip) leaves the chip visible beside the host
 CPU instead. Nothing here touches a device while it is imported.
 """
+import faulthandler
 import os
 import re
+
+import pytest
 
 _flags = os.environ.get('XLA_FLAGS', '')
 _flags = re.sub(r'--xla_force_host_platform_device_count=\d+', '', _flags)
@@ -40,6 +43,26 @@ def pytest_configure(config):
         'markers', 'chaos: fault-injection / recovery test '
         '(MXTPU_FAULT_INJECT harness; tier-1-safe, CPU-only, each '
         'under 30s) — select with -m chaos to drill the restart paths')
+
+
+# A test that hangs (a deadlocked thread, a child that never answers) must
+# cost the run one failure, not its whole time limit: under `--dist
+# loadfile` a stuck worker is otherwise only ended by the driver's clock,
+# and every test queued behind it is lost. Past this many seconds the
+# worker dumps every thread's stack to stderr and exits; xdist reports the
+# test as failed and hands the rest of the file to a new worker. Above
+# every limit a test sets itself (the longest is 300 s), far above the
+# slowest test under six workers (about 100 s).
+TEST_HARD_LIMIT_S = 420
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    faulthandler.dump_traceback_later(TEST_HARD_LIMIT_S, exit=True)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 def pytest_sessionstart(session):

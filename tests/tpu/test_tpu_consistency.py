@@ -172,8 +172,7 @@ def test_bf16_tpu_vs_fp32_cpu(chip, name):
 # ---------------------------------------------------------------------------
 # Pallas kernels compiled FOR REAL on the chip vs their jnp oracles.
 # Interpret mode on the CPU mesh does not enforce Mosaic's block rules
-# (the round-3 transformer bench failed lowering on a CPU-green kernel:
-# docs/tpu_artifacts/bench_transformer_20260731T111706Z.log), so these
+# (a transformer bench once failed lowering on a CPU-green kernel), so these
 # cases make every tier capture a hardware-lowering proof — including
 # the awkward shapes that take the _pad_and_block padding paths.
 # ---------------------------------------------------------------------------

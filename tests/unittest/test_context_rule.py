@@ -25,6 +25,32 @@ def test_cpu_mesh_mode_names_virtual_cpu_devices():
     assert mx.context.num_tpus() == 0 and mx.context.num_gpus() == 0
 
 
+@pytest.mark.parametrize('value', ['numpy', 'scalar', 'ndarray_elsewhere'])
+def test_whole_array_assignment_stays_on_the_arrays_device(value):
+    """`arr[:] = v`, the reference idiom for filling a bound argument:
+    the array stays on the device its context names. jnp puts an
+    uncommitted operand on the process's default device (cpu(0) here,
+    the chip beside an mx.cpu() array on the machine that has one)."""
+    import numpy as np
+    dev = jax.devices('cpu')[3]
+    a = mx.nd.zeros((2, 2), ctx=mx.cpu(3))
+    a[:] = {'numpy': np.ones((2, 2)), 'scalar': 1.0,
+            'ndarray_elsewhere': mx.nd.ones((2, 2), ctx=mx.cpu(5))}[value]
+    assert a._data.devices() == {dev} and a.context == mx.cpu(3)
+    assert a.asnumpy().sum() == 4
+
+
+def test_forward_kwargs_land_on_the_bound_device():
+    import numpy as np
+    dev = jax.devices('cpu')[3]
+    x = mx.sym.Variable('x')
+    ex = (x * 2).simple_bind(mx.cpu(3), x=(2, 2))
+    for v in (np.ones((2, 2), np.float32), mx.nd.ones((2, 2), ctx=mx.cpu(5))):
+        out = ex.forward(x=v)[0]
+        assert ex.arg_dict['x']._data.devices() == {dev}
+        assert out._data.devices() == {dev} and out.asnumpy().sum() == 8
+
+
 @pytest.mark.parametrize('ctor', [mx.tpu, mx.gpu, mx.cpu, mx.cpu_pinned])
 @pytest.mark.parametrize('dev_id', [9, 8, -1])
 def test_device_that_is_not_there_raises(ctor, dev_id):
@@ -99,3 +125,19 @@ def test_compile_cache_default_is_a_fixed_path_in_the_checkout(
     assert config.enable_compile_cache() == path      # and it stays there
     with open(os.path.join(REPO, '.gitignore')) as f:
         assert '.jax_compile_cache/' in f.read().split()
+
+
+def test_compile_cache_goes_off_with_a_warning_where_it_cannot_be_made(
+        monkeypatch, cache_config, tmp_path, caplog):
+    """A package installed outside a writable checkout: the default
+    directory cannot be made, so the cache stays off and says why."""
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    monkeypatch.setattr(context, 'cpu_mesh_mode', lambda: False)
+    blocker = tmp_path / 'a_file'
+    blocker.write_text('')
+    monkeypatch.setattr(config, 'COMPILE_CACHE_DIR',
+                        str(blocker / '.jax_compile_cache'))
+    before = jax.config.jax_compilation_cache_dir
+    assert config.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    assert 'JAX_COMPILATION_CACHE_DIR' in caplog.text

@@ -416,8 +416,8 @@ def test_fused_loop_reused_across_fit_calls():
     tools/fed_fit_bench.py) must NOT retrace + recompile the window on
     every call: the loop and its compiled programs are cached on the
     module and reused while the executor/optimizer/metric/window
-    signature is unchanged (round-5 fix for the 49.8 img/s fed-fit
-    pathology, docs/tpu_artifacts/fed_modulefit_20260802T061223Z).
+    signature is unchanged (a retrace per call was the fed-fit
+    pathology).
     The epoch-at-a-time trajectory equals one fit(num_epoch=2)."""
     os.environ['MXTPU_FUSED_FIT'] = '1'
     try:

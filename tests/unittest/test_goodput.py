@@ -200,7 +200,18 @@ def test_prior_lost_separates_job_books():
 # the acceptance run: instrumented CPU fit
 # ---------------------------------------------------------------------------
 
-def test_cpu_fit_buckets_sum_to_wall_within_5pct(tele_on):
+@pytest.fixture
+def warmed():
+    """The eager per-op compiles of a first fit (initializers, metric,
+    iterator slices) block outside every step span, so compute() takes
+    their seconds out of the step bucket; on a loaded machine they
+    outweigh eight MLP steps and clamp it to 0. One fit before telemetry
+    comes on leaves only the module's own programs to compile, inside
+    the first fit.dispatch, where the carve-out is exact."""
+    _fit(num_epoch=1)
+
+
+def test_cpu_fit_buckets_sum_to_wall_within_5pct(warmed, tele_on):
     """Real fit: the goodput record's buckets + overhead sum to
     measured wall-clock, the attributed (non-overhead) share never
     exceeds wall by more than 5%, and every surface carries the same
@@ -217,12 +228,7 @@ def test_cpu_fit_buckets_sum_to_wall_within_5pct(tele_on):
     assert abs(total - wall) <= 0.05 * wall + 0.01
     attributed = total - g['buckets']['overhead']
     assert attributed <= 1.05 * wall
-    # the fit trained. Its step bucket may be clamped to 0: compute()
-    # takes compile seconds outside fused_fit.build out of the step
-    # spans, and on a loaded machine this tiny fit's eager compiles
-    # (outside any step span) outweigh its eight MLP steps
-    assert telemetry.snapshot()['counters']['fit.steps'] == 8
-    assert g['buckets']['step'] >= 0
+    assert g['buckets']['step'] > 0          # the fit trained
     assert g['buckets']['compile'] > 0       # ... and compiled
     assert 0.0 <= g['goodput_pct'] <= 100.0
     assert g['badput_top'] in BUCKETS

@@ -319,3 +319,35 @@ def test_empty_and_tiny_block_requests():
     ref = attention_reference(q, q, q, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize('force', [False, True], ids=['jnp', 'interpreted'])
+def test_rows_wider_than_the_chips_vmem_bind_on_the_cpu(monkeypatch, force):
+    """The row limit (32768 f32 elements) is the chip's: softmax,
+    softmax_cross_entropy and LayerNorm at width 40000 bind and run in a
+    jitted executor on the CPU mesh, through the jnp formulation and
+    (MXTPU_FORCE_PALLAS=1) through the interpreted kernel, and agree."""
+    import mxnet_tpu as mx
+    monkeypatch.setenv('MXTPU_FORCE_PALLAS', '1' if force else '0')
+    rng = np.random.RandomState(0)
+    V = 40000
+    x_np = rng.randn(4, V).astype(np.float32)
+    lab = rng.randint(0, V, (4,)).astype(np.float32)
+    x, y = mx.sym.Variable('x'), mx.sym.Variable('y')
+    ex = mx.sym.softmax(x).simple_bind(mx.cpu(), x=(4, V))
+    ex.arg_dict['x'][:] = x_np
+    np.testing.assert_allclose(ex.forward()[0].asnumpy(),
+                               np.asarray(jax.nn.softmax(x_np)), atol=1e-7)
+    ex = mx.sym.softmax_cross_entropy(x, y).simple_bind(
+        mx.cpu(), x=(4, V), y=(4,))
+    ex.arg_dict['x'][:] = x_np
+    ex.arg_dict['y'][:] = lab
+    want = -np.asarray(jax.nn.log_softmax(x_np))[
+        np.arange(4), lab.astype(int)].sum()
+    np.testing.assert_allclose(ex.forward()[0].asnumpy(), want, rtol=1e-5)
+    ex = mx.sym.LayerNorm(x, name='ln').simple_bind(mx.cpu(), x=(4, V))
+    ex.arg_dict['x'][:] = x_np
+    ex.arg_dict['ln_gamma'][:] = 1
+    out = ex.forward()[0].asnumpy()
+    np.testing.assert_allclose(out.mean(-1), 0, atol=1e-5)
+    np.testing.assert_allclose(out.std(-1), 1, rtol=1e-3)

@@ -80,6 +80,12 @@ KERNELS = [
      [((8192, 16384), BF16), ((8192,), I32)]),
     ('xent_8192x32000', pk.softmax_xent,
      [((8192, 32000), F32), ((8192,), I32)]),
+    # the widest row that leaves the 8-row minimum block, through the
+    # kernel with the most temporaries, and a 50k-word LM head
+    ('layernorm_4096x65536', pk.fused_layernorm,
+     [((4096, 65536), F32), ((65536,), F32), ((65536,), F32)]),
+    ('xent_4096x50304', pk.softmax_xent,
+     [((4096, 50304), BF16), ((4096,), I32)]),
 ]
 
 
@@ -108,25 +114,34 @@ def test_registry_ops_take_the_kernel_when_lowered_for_tpu(one_chip):
 
 def test_flash_forward_past_vmem_raises_with_shapes(one_chip):
     """[1, 32768, 8, 128]: whole-axis K/V blocks cannot fit; a clear
-    error at trace time, on every platform, not a compiler dump."""
+    error when the program is lowered for the chip, not a compiler dump.
+    A program lowered for the CPU never meets the chip's limit."""
+    fn = jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, True))
     spec = jax.ShapeDtypeStruct((1, 32768, 8, 128), BF16, sharding=one_chip)
     with pytest.raises(ValueError, match=r'flash_attention: keys/values '
                                          r'\(1, 32768, 8, 128\) bfloat16'):
-        jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, True)) \
-            .lower(spec, spec, spec)
-    q = jnp.zeros((1, 32768, 8, 128), BF16)      # and eagerly, on the CPU
-    with pytest.raises(ValueError, match='more than the'):
-        pk.flash_attention(q, q, q, True)
+        fn.lower(spec, spec, spec)
+    cpu = jax.ShapeDtypeStruct((1, 32768, 8, 128), BF16)
+    assert 'tpu_custom_call' not in fn.lower(cpu, cpu, cpu).as_text()
 
 
-def test_row_too_wide_for_vmem_raises_with_shapes():
-    x = jax.ShapeDtypeStruct((16, 1 << 16), F32)
-    with pytest.raises(ValueError, match=r'softmax_xent: rows of 65536'):
-        jax.eval_shape(pk.softmax_xent, x,
-                       jax.ShapeDtypeStruct((16,), I32))
+def test_row_too_wide_for_vmem_raises_with_shapes(one_chip):
+    """Rows of 131072 f32 do not fit even an 8-row block: refused when
+    lowered for the chip, through the kernel and through the registry op
+    that routes to it; the same symbol binds and runs on the CPU mesh
+    (an LM head with a 50k vocabulary trains there)."""
+    from mxnet_tpu.ops.registry import get
+    sm = get('softmax').fn
+    specs = [((16, 1 << 17), F32), ((16,), I32)]
+    for fn, sp in ((pk.softmax_xent, specs),
+                   (lambda x: sm({}, x), specs[:1])):
+        with pytest.raises(ValueError, match=r'rows of 131072 elements'):
+            _compile(fn, one_chip, *sp)
+        args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in sp]
+        assert 'tpu_custom_call' not in jax.jit(fn).lower(*args).as_text()
 
 
 def test_row_block_follows_width():
-    blk = lambda d: pk._row_block('t', 256, np.zeros((0, d), np.float32))  # noqa: E731
-    assert blk(1024) == 256 and blk(4096) == 64
-    assert blk(16384) == 16 and blk(32000) == 8
+    blk = lambda d: pk._row_block('t', 256, np.zeros((0, d), np.float32))[0]  # noqa: E731
+    assert blk(1024) == 256 and blk(4096) == 128
+    assert blk(16384) == 32 and blk(32000) == 16 and blk(65536) == 8

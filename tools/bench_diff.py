@@ -5,7 +5,7 @@ The bench history is the repo's perf ledger; nothing so far CHECKED it
 — a throughput or MFU slide between rounds only surfaced when a human
 re-read the numbers. This is the post-bench gate::
 
-    python tools/bench_diff.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_diff.py old_bench.json new_bench.json
 
 Accepts either the harness wrapper format (the ``parsed`` key holds
 the authoritative metric dict) or raw bench stdout (JSON lines — the
