@@ -577,8 +577,7 @@ def run_fused_window_ab(platform):
     MXTPU_BN_ONEPASS=0 — undonated carry, two-pass stats), the 'tuned'
     arm runs the shipped defaults. Per arm: one warm fit (compiles the
     window), two timed epochs, then the window program's
-    temp/live/alias bytes off the registrar gauges and the
-    update/upload overlap off the fused_fit.overlap_ms histogram."""
+    temp/live/alias bytes off the registrar gauges."""
     import mxnet_tpu as mx
     from mxnet_tpu import telemetry as _tele
     from mxnet_tpu.config import flags as _flags
@@ -634,18 +633,14 @@ def run_fused_window_ab(platform):
             snap = _tele.snapshot() if _tele.enabled() else {}
             g = snap.get('gauges', {})
             pfx = 'program.fused_fit.window[%s].' % name
-            hist = snap.get('histograms', {}).get('fused_fit.overlap_ms')
             res[arm] = {
                 'img_s': round(2 * n / dt, 2),
                 'temp_bytes': int(g.get(pfx + 'temp_bytes', 0)) or None,
                 'live_bytes': int(g.get(pfx + 'live_bytes', 0)) or None,
-                'alias_bytes': int(g.get(pfx + 'alias_bytes', 0)) or None,
-                'overlap_ms_p50': round(hist['p50'], 3)
-                if hist and hist.get('count') else None}
-            _log('fused-window A/B %s: %.2f img/s, temp=%s live=%s '
-                 'overlap_p50=%s ms'
+                'alias_bytes': int(g.get(pfx + 'alias_bytes', 0)) or None}
+            _log('fused-window A/B %s: %.2f img/s, temp=%s live=%s'
                  % (arm, res[arm]['img_s'], res[arm]['temp_bytes'],
-                    res[arm]['live_bytes'], res[arm]['overlap_ms_p50']))
+                    res[arm]['live_bytes']))
     finally:
         for var, old in saved.items():
             if old is None:
@@ -1170,7 +1165,7 @@ def main():
             _log('sharded-update A/B failed (headline unaffected): %s' % e)
     # donation + BN-one-pass A/B (ISSUE 12): real Module.fit fused
     # window, pre-PR program vs shipped defaults — temp/live bytes,
-    # overlap evidence, throughput. Runs after the telemetry fold for
+    # throughput. Runs after the telemetry fold for
     # the same contamination rule.
     fused_ab = None
     if os.environ.get('MXTPU_BENCH_FUSED_AB', '1') != '0':
@@ -1180,10 +1175,6 @@ def main():
             _log('fused-window A/B failed (headline unaffected): %s' % e)
     if fused_ab:
         out['fused_window_ab'] = fused_ab
-        if fused_ab['tuned'].get('overlap_ms_p50') is not None:
-            # update/upload overlap per window, the ledger's evidence
-            # that the optimizer host tail hides under the transfer
-            out['overlap_ms'] = fused_ab['tuned']['overlap_ms_p50']
     # serving bench (ISSUE 13): closed-loop load against the in-process
     # continuous-batching plane; same contamination/failure rules as
     # the A/Bs above — the headline number is never at risk

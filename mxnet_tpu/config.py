@@ -538,10 +538,6 @@ flags.declare('MXTPU_FUSED_FIT_PREFETCH', bool, True,
               'and the transfer release the GIL, so the overlap holds '
               'even on a one-core host). 0 restores the serial '
               'stack/put/dispatch/fetch order')
-flags.declare('MXTPU_FUSED_FIT_TIMING', bool, False,
-              'Log a per-epoch host-stage breakdown of the fused fit '
-              'loop (draw / stack+put / dispatch / stats-fetch) — the '
-              'diagnosis knob for fed-path throughput work')
 flags.declare('MXTPU_DEVICE_AUGMENT', bool, False,
               'ImageRecordIter ships fixed-size uint8 batches and runs '
               'crop/mirror/normalize as one jitted device call per '

@@ -242,16 +242,22 @@ class BaseModule:
         # decide telemetry before bind: the XLA compile listener must be
         # live before this fit's first compile so warmups are counted
         _tele.enabled()
-        self.bind(data_shapes=train_data.provide_data,
-                  label_shapes=train_data.provide_label,
-                  for_training=True, force_rebind=force_rebind)
+        # the set-up spans (with fused_fit.build): where a job's time to
+        # its first step goes, as a tree in the log
+        with _tele.span('fit.bind', 'fit'):
+            self.bind(data_shapes=train_data.provide_data,
+                      label_shapes=train_data.provide_label,
+                      for_training=True, force_rebind=force_rebind)
         if monitor is not None:
             self.install_monitor(monitor)
-        self.init_params(initializer=initializer, arg_params=arg_params,
-                         aux_params=aux_params, allow_missing=allow_missing,
-                         force_init=force_init)
-        self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
-                            optimizer_params=optimizer_params)
+        with _tele.span('fit.init_params', 'fit'):
+            self.init_params(initializer=initializer,
+                             arg_params=arg_params, aux_params=aux_params,
+                             allow_missing=allow_missing,
+                             force_init=force_init)
+        with _tele.span('fit.init_optimizer', 'fit'):
+            self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
+                                optimizer_params=optimizer_params)
 
         if validation_metric is None:
             validation_metric = eval_metric

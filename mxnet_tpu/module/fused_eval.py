@@ -328,9 +328,10 @@ class FusedEvalLoop:
     # -- the shared window drive -------------------------------------------
     def _drive(self, eval_data, num_batch, snap_labels=False):
         """Drive the pipelined window loop once for score AND predict:
-        yields ('window', pieces, win_snaps, labels_snap) per resolved
-        window and ('tail', rebuilt_batch, snap, None) per remaining
-        batch. Window results surface ONE WINDOW LATE by design — the
+        yields ('window', pieces, win_snaps, labels_snap, win) per
+        resolved window (win: the number its spans carry) and ('tail',
+        rebuilt_batch, snap, None, None) per remaining batch. Window
+        results surface ONE WINDOW LATE by design — the
         consumer's host fetch at the yield point overlaps the next
         window's device compute and side-thread upload; values and
         per-batch cadence are unchanged."""
@@ -343,12 +344,12 @@ class FusedEvalLoop:
         def collect():
             nonlocal drawn
             lim = None if num_batch is None else num_batch - drawn
-            batches, snaps = pipe.collect(it, limit=lim)
+            batches, snaps, win = pipe.collect(it, limit=lim)
             drawn += len(batches)
-            return batches, snaps
+            return batches, snaps, win
 
-        batches, snaps = collect()
-        fut = pipe.start_put(snaps, pool) \
+        batches, snaps, win = collect()
+        fut = pipe.start_put(snaps, pool, win) \
             if len(batches) == self.window else None
         try:
             while len(batches) == self.window:
@@ -361,9 +362,10 @@ class FusedEvalLoop:
                     labels_snap = [[from_jax(l, self._exec._ctx)
                                     for l in ls] for _, ls, _, _ in snaps]
                 fixed, aux = self._snapshot(fixed_names)
-                with _tele.span('fused_eval.put', 'fused_eval'):
+                with _tele.span('fused_eval.put', 'fused_eval', win=win):
                     data_stack, label_stack = fut()
-                with _tele.span('fused_eval.dispatch', 'fused_eval'):
+                with _tele.span('fused_eval.dispatch', 'fused_eval',
+                                win=win):
                     pieces = window_fn(fixed, aux, data_stack, label_stack,
                                        _random.next_key())
                 _tele.counter('fused_eval.windows').inc()
@@ -375,13 +377,13 @@ class FusedEvalLoop:
                 # transfer start on the side thread), then hand the
                 # PREVIOUS window to the consumer while this one
                 # computes
-                win_snaps = snaps
-                batches, snaps = collect()
-                fut = pipe.start_put(snaps, pool) \
+                dispatched = (pieces, snaps, labels_snap, win)
+                batches, snaps, win = collect()
+                fut = pipe.start_put(snaps, pool, win) \
                     if len(batches) == self.window else None
                 if pending is not None:
                     yield ('window',) + pending
-                pending = (pieces, win_snaps, labels_snap)
+                pending = dispatched
         except Exception as e:
             # RESOURCE_EXHAUSTED in the upload/dispatch drive: dump the
             # per-program memory breakdown (no-op otherwise)
@@ -398,7 +400,7 @@ class FusedEvalLoop:
         for snap in snaps:
             # tail (< window, or a num_batch remainder): reference
             # per-batch path on snapshot-rebuilt batches
-            yield ('tail', self._rebuild_batch(snap), snap, None)
+            yield ('tail', self._rebuild_batch(snap), snap, None, None)
 
     def _note_window_health(self, hrows, win_snaps, nbatch):
         """Check a fetched (W, k) sentinel matrix (no-op when the
@@ -434,7 +436,7 @@ class FusedEvalLoop:
                 for cb in _as_list(batch_end_callback):
                     cb(p)
 
-        for kind, a, b, labels_w in self._drive(
+        for kind, a, b, labels_w, win in self._drive(
                 eval_data, num_batch, snap_labels=self.stat_fns is None):
             if kind == 'tail':
                 sb = a
@@ -453,7 +455,7 @@ class FusedEvalLoop:
             hmat = None
             if self._health_fn is not None:
                 pieces, hrows = pieces
-            with _tele.span('fused_eval.fetch', 'fused_eval'):
+            with _tele.span('fused_eval.fetch', 'fused_eval', win=win):
                 if self.stat_fns is not None:
                     host = np.asarray(pieces)      # (W, 2 * n_metrics)
                     steps = host.shape[0]
@@ -491,7 +493,7 @@ class FusedEvalLoop:
         _tele.gauge('fused_eval.steps_per_call').set(self.window)
         host_nd = host_wrap(_cpu())
         nbatch = 0
-        for kind, a, b, _ in self._drive(eval_data, num_batch):
+        for kind, a, b, _, win in self._drive(eval_data, num_batch):
             if kind == 'tail':
                 sb = a
                 with _tele.span('eval.dispatch', 'eval'):
@@ -512,7 +514,7 @@ class FusedEvalLoop:
                 pieces, hrows = pieces
             # one host fetch for the window's stacked outputs, then
             # per-batch pad trim + wrap
-            with _tele.span('fused_eval.fetch', 'fused_eval'):
+            with _tele.span('fused_eval.fetch', 'fused_eval', win=win):
                 outs_host = [np.asarray(o) for o in pieces]   # (W, ...)
                 if self._health_fn is not None:
                     hmat = np.asarray(hrows)

@@ -1299,14 +1299,17 @@ class FusedFitLoop:
         # step_time deltas)
         _stats_t = [None]
 
-        def apply_stats(pieces, labels_w, nbatch, win_snaps=None):
+        def apply_stats(pending, nbatch):
             """One host fetch for the window's results, then exact
-            per-batch metric application + callbacks. Stats mode feeds
+            per-batch metric application + callbacks. ``pending`` is
+            the dispatched window as the loop kept it: (pieces, labels,
+            snapshots or None, win). Stats mode feeds
             the packed sufficient-statistic sums into the metric
             children; host-metric mode replays eval_metric.update with
             each step's outputs against the window's own labels
             (snapshotted at collection time — see below), the way the
             reference loop's update_metric would."""
+            pieces, labels_w, win_snaps, win = pending
             hrows = drows = None
             if self._health_fn is not None or self._dyn_fn is not None:
                 parts = list(pieces)
@@ -1315,7 +1318,7 @@ class FusedFitLoop:
                     hrows = parts.pop(0)
                 if self._dyn_fn is not None:
                     drows = parts.pop(0)
-            with _tele.span('fused_fit.fetch', 'fused_fit'):
+            with _tele.span('fused_fit.fetch', 'fused_fit', win=win):
                 # the window's one device->host fetch (everything
                 # after is host math) —
                 # the (W, k) sentinel AND dynamics matrices ride the
@@ -1457,15 +1460,8 @@ class FusedFitLoop:
         nbatch = ckpt.epoch_nbatch_base if ckpt is not None else 0
         pending = None
         it = iter(train_data)
-        # MXTPU_FUSED_FIT_TIMING=1: per-epoch host-stage breakdown
-        # (draw / stack+put / dispatch / stats-fetch) — the fed-path
-        # diagnosis knob; wall beyond these stages is device compute
-        # the host successfully hid
         from ..config import flags as _flags
-        _timing = bool(_flags.get('MXTPU_FUSED_FIT_TIMING'))
-        _tm = {'draw': 0.0, 'put': 0.0, 'dispatch': 0.0, 'fetch': 0.0}
         _clk = time.perf_counter
-        _ep_t0 = _clk() if _timing else 0.0
         pipe = self._pipe
         pool = pipe.pool() \
             if _flags.get('MXTPU_FUSED_FIT_PREFETCH') else None
@@ -1477,29 +1473,27 @@ class FusedFitLoop:
             # iterators may legally reuse their DataBatch/NDArray
             # buffers for the next batch; the draw-time jax-array
             # references stay valid while the window is collected and
-            # the apply is deferred.
-            _t = _clk() if _timing else 0.0
-            batches, snaps = pipe.collect(it)
+            # the apply is deferred. `win` numbers the window for its
+            # spans, from this draw to the fetch one window later.
+            batches, snaps, win = pipe.collect(it)
             if faults_on:
                 # nan-grad draw seam: training batches counted in step
                 # order, the armed one poisoned before stack/upload
                 snaps = [_faults.maybe_poison_snap(s) for s in snaps]
-            if _timing:
-                _tm['draw'] += _clk() - _t
-            return batches, snaps
+            return batches, snaps, win
 
-        def start_put(win_snaps):
+        def start_put(win_snaps, win):
             # with the prefetch pool, window k+1's stack + put run on
             # the side thread while window k computes on device and
             # k-1's stats fetch waits
-            return pipe.start_put(win_snaps, pool)
+            return pipe.start_put(win_snaps, pool, win)
 
         health_on = self._health_fn is not None
         cluster_on = _tele.cluster.enabled()
         mem_on = _tele.memory.enabled()
         tl_on = _tele.timeline.enabled()
         _t_win = _clk()   # wall clock per dispatched window (health)
-        batches, snaps = collect()
+        batches, snaps, win = collect()
         if not batches:
             if ckpt is not None and ckpt.allow_empty_epoch(epoch):
                 # checkpoint-resume landed exactly on this epoch's
@@ -1515,7 +1509,8 @@ class FusedFitLoop:
                 'training iterator is exhausted at epoch start — '
                 'reset() it (a score()/predict pass leaves the '
                 'iterator drained, matching the reference fit loop)')
-        fut = start_put(snaps) if len(batches) == self.window else None
+        fut = start_put(snaps, win) \
+            if len(batches) == self.window else None
         try:
             while len(batches) == self.window:
                 # one program per (static attrs, shapes); lr/wd enter
@@ -1559,20 +1554,14 @@ class FusedFitLoop:
                 # walks + lr/wd sampling, plus the snapshot above —
                 # runs BEFORE the put wait, so it hides under window
                 # k+1's side-thread transfer instead of serializing
-                # after it (the update/upload overlap; the resolver's
-                # hidden_ms below is the evidence)
+                # after it (the update/upload overlap: the side
+                # thread's .stack/.upload spans against this .put wait
+                # of the same win are the evidence)
                 lr_arr, wd_arr = self._sample_window_lr()
-                _t = _clk() if _timing else 0.0
-                with _tele.span('fused_fit.put', 'fused_fit'):
+                with _tele.span('fused_fit.put', 'fused_fit', win=win):
                     data_stack, label_stack = fut()
-                if pool is not None:
-                    _tele.histogram('fused_fit.overlap_ms').observe(
-                        fut.hidden_ms)
-                if _timing:
-                    _now = _clk()
-                    _tm['put'] += _now - _t
-                    _t = _now
-                with _tele.span('fused_fit.dispatch', 'fused_fit'):
+                with _tele.span('fused_fit.dispatch', 'fused_fit',
+                                win=win):
                     self._base_key = _random.next_key()
                     if cmode != 'off':
                         resids = self._ensure_resids()
@@ -1600,24 +1589,20 @@ class FusedFitLoop:
                     _tele.cluster.note_step(self.window)
                 # MXTPU_XPROF step window (quantized to whole windows)
                 _profiler.note_step(self.window)
-                if _timing:
-                    _now = _clk()
-                    _tm['dispatch'] += _now - _t
-                    _t = _now
                 # dispatch is async: while this window computes, draw
                 # the NEXT window (its stack + transfer start on the
                 # side thread) and fetch the PREVIOUS window's stats —
                 # both the transfer and the fetch RTT disappear behind
                 # device time (callbacks run one window late; values
                 # and cadence are unchanged)
-                win_snaps = snaps if health_on else None
-                batches, snaps = collect()
-                fut = start_put(snaps) \
+                dispatched = (pieces, labels_snap,
+                              snaps if health_on else None, win)
+                batches, snaps, win = collect()
+                fut = start_put(snaps, win) \
                     if len(batches) == self.window else None
                 if pending is not None:
-                    nbatch = apply_stats(pending[0], pending[1], nbatch,
-                                         pending[2])
-                pending = (pieces, labels_snap, win_snaps)
+                    nbatch = apply_stats(pending, nbatch)
+                pending = dispatched
                 # one wall observation per window (window-edge to
                 # window-edge): in steady state the loop is device-
                 # bound, so wall / W IS the per-step time — health's
@@ -1639,8 +1624,7 @@ class FusedFitLoop:
                         # capture's eval-metric state covers every step
                         # the checkpoint claims (and a NaN in this
                         # window raises BEFORE a poisoned capture)
-                        nbatch = apply_stats(pending[0], pending[1],
-                                             nbatch, pending[2])
+                        nbatch = apply_stats(pending, nbatch)
                         pending = None
                         lag = 0   # health checked through this window
                     # otherwise the health plane has only processed the
@@ -1659,19 +1643,13 @@ class FusedFitLoop:
                     # window of steps for the phase ledger's per-step
                     # normalization — one clock read
                     _tele.timeline.note_step(self.window)
-                if _timing:
-                    _tm['fetch'] += _clk() - _t
         finally:
             # drain an in-flight prefetch before run_epoch's cache
             # teardown (or an exception unwind) can race the side thread
             if pool is not None:
                 WindowPipeline.drain(fut)
-        _t = _clk() if _timing else 0.0
         if pending is not None:
-            nbatch = apply_stats(pending[0], pending[1], nbatch,
-                                 pending[2])
-        if _timing:
-            _tm['fetch'] += _clk() - _t
+            nbatch = apply_stats(pending, nbatch)
         if snaps:
             # tail batches run the imperative per-batch update: ZeRO
             # leaves materialize to canonical shapes and the kvstore-
@@ -1723,10 +1701,4 @@ class FusedFitLoop:
                 for cb in _as_list(batch_end_callback):
                     cb(p)
             nbatch += 1
-        if _timing:
-            logging.info(
-                'fused_fit timing epoch=%d wall=%.3fs draw=%.3fs '
-                'put=%.3fs dispatch=%.3fs fetch=%.3fs', epoch,
-                _clk() - _ep_t0, _tm['draw'], _tm['put'],
-                _tm['dispatch'], _tm['fetch'])
         return nbatch

@@ -289,8 +289,15 @@ class WindowPipeline:
     ``device_fn`` resolves the target jax device lazily (the bound
     executor's context); ``mesh`` switches placement to dp-sharded
     window stacks. ``span_prefix`` names the telemetry spans
-    ('fused_fit' / 'fused_eval'). The owning loop object lives across
-    fit()/score() calls, so the upload pool it carries does too.
+    ('fused_fit' / 'fused_eval'): ``.draw`` around a window's draws with
+    one ``.next`` per batch inside it, ``.stack`` around the host-side
+    ``np.stack`` and ``.upload`` around the ``device_put`` calls, the
+    last two on the thread that does the work (the side thread with the
+    pool). Each carries ``win``, the window's sequence number since this
+    object was built, which the owning loop hands on to its own
+    ``.put`` / ``.dispatch`` / ``.fetch`` spans. The owning loop object
+    lives across fit()/score() calls, so the upload pool it carries
+    does too.
     """
 
     def __init__(self, window, device_fn, mesh=None, span_prefix='window',
@@ -299,6 +306,11 @@ class WindowPipeline:
         self.mesh = mesh
         self._device_fn = device_fn
         self._span = span_prefix
+        self._span_draw = span_prefix + '.draw'
+        self._span_next = span_prefix + '.next'
+        self._span_stack = span_prefix + '.stack'
+        self._span_upload = span_prefix + '.upload'
+        self._windows_drawn = 0
         self._dev_cache_key = None
         self._dev_cache = None
         self._pool_obj = None
@@ -316,14 +328,20 @@ class WindowPipeline:
         """Draw up to ``window`` batches (further bounded by ``limit``,
         the eval loops' num_batch remainder), snapshotting each batch's
         underlying jax arrays, pad, and index AT DRAW TIME. Returns
-        (batches, snaps) with snaps a list of (data_arrays,
-        label_arrays, pad, index) tuples."""
+        (batches, snaps, win) with snaps a list of (data_arrays,
+        label_arrays, pad, index) tuples and win the window's sequence
+        number, the ``win`` attribute of its spans."""
         n = self.window if limit is None else min(self.window, limit)
         batches, snaps = [], []
-        with _tele.span(self._span + '.draw', self._span):
+        win = self._windows_drawn
+        self._windows_drawn += 1
+        with _tele.span(self._span_draw, self._span, win=win):
             while len(batches) < n:
                 try:
-                    b = next(it)
+                    # the iterator's own cost per batch, apart from the
+                    # snapshotting below
+                    with _tele.span(self._span_next, self._span, win=win):
+                        b = next(it)
                 except StopIteration:
                     break
                 batches.append(b)
@@ -331,10 +349,10 @@ class WindowPipeline:
                               tuple(l._data for l in (b.label or ())),
                               getattr(b, 'pad', None),
                               getattr(b, 'index', None)))
-        return batches, snaps
+        return batches, snaps, win
 
     # -- stack + upload ----------------------------------------------------
-    def device_batches(self, snaps):
+    def device_batches(self, snaps, win=None):
         """Stack W draw-time snapshots into device (W, ...) arrays.
         Identity-cached: synthetic/benchmark iterators yield the same
         arrays every batch, so the transfer happens once. The cache key
@@ -347,16 +365,30 @@ class WindowPipeline:
         device-resident sources, the unstacked parts) instead and
         re-runs the device transfer per window (the prefetch pool hides
         it behind window k's compute) — returning a cached device array
-        would hand the program an already-deleted donated buffer."""
+        would hand the program an already-deleted donated buffer.
+
+        The ``.stack`` and ``.upload`` spans open here, on the thread
+        that runs this (the side thread under :meth:`start_put`'s
+        pool): ``.stack`` not on a cache hit, ``.upload`` wherever
+        ``device_put`` is called. ``.upload`` ends when the calls
+        return and adds no synchronisation: a backend that copies on
+        behind the call (the TPU's does) ends the transfer later, by
+        the time the window's program starts on the device."""
         arrays = [a for ds, ls, _, _ in snaps for a in ds + ls]
+        nbytes = sum(a.nbytes for a in arrays)
+
+        def upload(data_e, label_e):
+            with _tele.span(self._span_upload, self._span, win=win,
+                            bytes=nbytes):
+                return (tuple(self._realize(e) for e in data_e),
+                        tuple(self._realize(e) for e in label_e))
+
         if self._dev_cache_key is not None and \
                 len(arrays) == len(self._dev_cache_key) and \
                 all(a is c for a, c in zip(arrays, self._dev_cache_key)):
             if not self.donate:
                 return self._dev_cache
-            data_e, label_e = self._dev_cache
-            return (tuple(self._realize(e) for e in data_e),
-                    tuple(self._realize(e) for e in label_e))
+            return upload(*self._dev_cache)
         key = arrays
 
         def _on_host(a):
@@ -378,12 +410,13 @@ class WindowPipeline:
                 return ('host', np.stack([np.asarray(p) for p in parts]))
             return ('dev', tuple(parts))
 
-        data_e = [build([ds[i] for ds, _, _, _ in snaps])
-                  for i in range(len(snaps[0][0]))]
-        label_e = [build([ls[i] for _, ls, _, _ in snaps])
-                   for i in range(len(snaps[0][1]))]
-        data_stack = tuple(self._realize(e) for e in data_e)
-        label_stack = tuple(self._realize(e) for e in label_e)
+        with _tele.span(self._span_stack, self._span, win=win,
+                        bytes=nbytes):
+            data_e = [build([ds[i] for ds, _, _, _ in snaps])
+                      for i in range(len(snaps[0][0]))]
+            label_e = [build([ls[i] for _, ls, _, _ in snaps])
+                       for i in range(len(snaps[0][1]))]
+        data_stack, label_stack = upload(data_e, label_e)
         self._dev_cache_key = key
         self._dev_cache = (data_e, label_e) if self.donate \
             else (data_stack, label_stack)
@@ -417,46 +450,21 @@ class WindowPipeline:
                 max_workers=1, thread_name_prefix='mxtpu-window-put')
         return self._pool_obj
 
-    def start_put(self, snaps, pool):
+    def start_put(self, snaps, pool, win=None):
         """Begin the window's host-stack + device transfer; returns a
         no-arg resolver. With a pool, the stack + put for window k+1
         run on the side thread while window k computes on device, the
         previous window's stats fetch waits, and the optimizer's
         host-side window bookkeeping runs — the update/upload overlap.
-
-        The resolver carries ``hidden_ms`` after it is called: the
-        share of the side thread's stack+put wall time the main thread
-        did NOT wait for (put duration minus blocked time) — the
-        ``fused_fit.overlap_ms`` evidence that the transfer actually
-        hid under host work rather than serializing in front of the
-        dispatch."""
-        import time
+        How much of it hid is in the spans: the side thread's
+        ``.stack`` and ``.upload`` against the loop's ``.put`` wait of
+        the same ``win``. The resolver refers to nothing but the
+        result, so a window's device stack is freed with the last
+        reference to it."""
         if pool is None:
-            res = self.device_batches(snaps)
-
-            def resolver():
-                return res
-            resolver.hidden_ms = 0.0   # serial mode hides nothing
-            return resolver
-        done = {}
-
-        def task():
-            t0 = time.perf_counter()
-            try:
-                return self.device_batches(snaps)
-            finally:
-                done['dur'] = time.perf_counter() - t0
-        fut = pool.submit(task)
-
-        def resolver():
-            t0 = time.perf_counter()
-            out = fut.result()
-            waited = time.perf_counter() - t0
-            resolver.hidden_ms = max(
-                0.0, done.get('dur', 0.0) - waited) * 1e3
-            return out
-        resolver.hidden_ms = 0.0
-        return resolver
+            res = self.device_batches(snaps, win)
+            return lambda: res
+        return pool.submit(self.device_batches, snaps, win).result
 
     @staticmethod
     def drain(fut):

@@ -7,9 +7,13 @@ instrumentation the hot paths report through:
 - a metrics registry (:mod:`.registry`): counters, gauges, histograms
   with recent-window p50/p95;
 - a low-overhead span tracer (:func:`span`): times a host-side region
-  into a histogram AND — whenever the chrome-trace profiler is running
-  — into the same trace file ``profiler.py`` writes, so telemetry
-  spans and engine op spans land on one timeline;
+  into a histogram and a JSONL record (name, path, start, duration,
+  thread, attributes), opens a ``jax.profiler.TraceAnnotation`` so that
+  during any ``jax.profiler`` capture the span is an event of the
+  capture's ``/host:CPU`` plane, on the device events' clock and on the
+  line of its thread — and, whenever the chrome-trace profiler is
+  running, writes into the same trace file ``profiler.py`` writes, so
+  telemetry spans and engine op spans land on one timeline;
 - XLA gauges (:mod:`.xla`): compile count/seconds via jax.monitoring,
   retrace-storm detection, live/peak device bytes, an MFU estimate;
 - per-program cost attribution (:mod:`.programs`): every compile site
@@ -91,12 +95,19 @@ shared no-op object — zero I/O, no registry writes, one cached-bool
 check per call site (asserted by tests/unittest/test_telemetry.py).
 
 Instrumented sites (the names to grep for in the log):
-``fit.batch`` / ``fit.dispatch`` / ``fit.metric`` / ``fit.callback``
-(reference per-batch loop), ``fused_fit.draw|put|dispatch|fetch|build``
-+ gauge ``fused_fit.steps_per_call`` (compiled window loop),
-``eval.dispatch|metric|fetch`` + counter ``eval.batches`` + gauge
-``eval_samples_per_sec`` (per-batch score/predict loops),
-``fused_eval.draw|put|dispatch|fetch|build`` + counter
+``fit.bind`` / ``fit.init_params`` / ``fit.init_optimizer`` (set-up, at
+the head of ``Module.fit``), ``fit.batch`` / ``fit.dispatch`` /
+``fit.metric`` / ``fit.callback`` (reference per-batch loop),
+``fused_fit.draw|next|stack|upload|put|dispatch|fetch`` each with the
+attribute ``win`` (the window's sequence number: the spans of one
+window share it; ``.next`` is one ``next(iterator)`` inside ``.draw``;
+``.stack`` and ``.upload``, with ``bytes``, run on the side thread
+``mxtpu-window-put`` when the prefetch pool is on) + ``fused_fit.build``
++ counter ``fused_fit.windows`` + gauge ``fused_fit.steps_per_call``
+(compiled window loop), ``eval.dispatch|metric|fetch`` + counter
+``eval.batches`` + gauge ``eval_samples_per_sec`` (per-batch
+score/predict loops), ``fused_eval.draw|next|stack|upload|put|dispatch|
+fetch|build`` (the same set, from the shared window pipeline) + counter
 ``fused_eval.windows`` + gauge ``fused_eval.steps_per_call`` (compiled
 eval window loop), ``executor.forward|backward``,
 ``exec_group.forward|backward``, ``module.update``, histogram
@@ -116,6 +127,8 @@ import logging
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .registry import (Registry, NULL_COUNTER, NULL_GAUGE, NULL_HISTOGRAM)
 from . import export as _export
@@ -263,26 +276,37 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """Times a host region: histogram (ms) + JSONL record, and a
-    chrome-trace event whenever the profiler is running. Nesting is
-    tracked per-thread; the JSONL record carries the full path
-    ('fit.batch/fit.dispatch') so traces reconstruct the tree."""
+    """Times a host region: histogram (ms) + JSONL record, an event of
+    the ``/host:CPU`` plane of any ``jax.profiler`` capture that is
+    running (a ``TraceAnnotation``: on the device's clock, on the line
+    of the thread the span ran on, the attributes among its statistics;
+    a no-op of the profiler's own outside a capture), and a chrome-trace
+    event whenever profiler.py's tracer is running. Nesting is tracked
+    per-thread; the JSONL record carries the full path
+    ('fit.batch/fit.dispatch') so traces reconstruct the tree, the
+    thread's name (``tid``) and the attributes. A span on a side thread
+    says what caused it through an attribute (the window pipeline's
+    ``win``), not through ``path``."""
 
-    __slots__ = ('name', 'cat', 't0', 'path')
+    __slots__ = ('name', 'cat', 'attrs', 't0', 'path', '_ann')
 
-    def __init__(self, name, category):
+    def __init__(self, name, category, attrs):
         self.name = name
         self.cat = category
+        self.attrs = attrs
 
     def __enter__(self):
         stack = _stack()
         self.path = (stack[-1].path + '/' + self.name) if stack else self.name
         stack.append(self)
+        self._ann = _TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
         self.t0 = time.time()
         return self
 
     def __exit__(self, *a):
         t1 = time.time()
+        self._ann.__exit__(*a)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -297,28 +321,35 @@ class _Span:
             if timeline.enabled():
                 timeline.note_span(self.name, dur_ms)
             if st.sink is not None:
-                st.sink.emit({'type': 'span', 'name': self.name,
-                              'path': self.path, 't': self.t0,
-                              'dur_ms': round(dur_ms, 4)})
+                rec = {'type': 'span', 'name': self.name,
+                       'path': self.path, 't': self.t0,
+                       'dur_ms': round(dur_ms, 4),
+                       'tid': threading.current_thread().name}
+                for k, v in self.attrs.items():
+                    rec.setdefault(k, v)
+                st.sink.emit(rec)
         from .. import profiler as _profiler
         if _profiler.is_running():
             _profiler.record_event(self.name, int(self.t0 * 1e6),
                                    int(t1 * 1e6), self.cat)
 
 
-def span(name, category='telemetry'):
+def span(name, category='telemetry', **attrs):
     """Context manager timing a host-side region.
 
     Enabled telemetry: records a histogram observation (ms) under
-    ``name`` and appends a JSONL span record. Running profiler: emits a
-    chrome-trace event into profiler.py's timeline (this works even
-    with telemetry off, replacing profiler.maybe_span at call sites).
-    Neither: returns the shared no-op."""
+    ``name``, appends a JSONL span record (with the thread's name and
+    ``attrs``) and, during a ``jax.profiler`` capture, is an event of
+    the capture's ``/host:CPU`` plane beside the device's operations.
+    Running profiler.py tracer: emits a chrome-trace event into its
+    timeline (this works even with telemetry off, replacing
+    profiler.maybe_span at call sites). Neither: returns the shared
+    no-op."""
     if enabled():
-        return _Span(name, category)
+        return _Span(name, category, attrs)
     from .. import profiler as _profiler
     if _profiler.is_running():
-        return _Span(name, category)   # chrome-trace only; exit skips st
+        return _Span(name, category, attrs)   # exit skips st
     return _NULL_SPAN
 
 

@@ -198,9 +198,12 @@ class _RegisteredProgram:
     argument signature runs ``lower().compile()`` explicitly (one
     compile total — the lazy path would have compiled here anyway),
     hands the executable to :func:`note_program`, then dispatches
-    through it. Any lower/compile/dispatch surprise falls back to the
-    wrapped lazy jit for that signature — attribution is best-effort,
-    execution is not."""
+    through it. What the ahead-of-time path itself cannot take (the
+    TypeError/ValueError of an argument layout, an unhashable leaf)
+    falls back to the wrapped lazy jit for that signature —
+    attribution is best-effort, execution is not. An error of the
+    backend (a compile or a run out of memory) is raised as it is,
+    once: the lazy jit would only repeat it."""
 
     __slots__ = ('name', 'jitted', 'static_argnums', 'step_flops',
                  '_compiled')
@@ -243,7 +246,13 @@ class _RegisteredProgram:
         t0 = time.time()
         try:
             compiled = self.jitted.lower(*args).compile()
-        except Exception as e:  # noqa: BLE001 — fall back, never kill
+        except (TypeError, ValueError) as e:
+            # what the ahead-of-time path itself cannot take (an
+            # argument layout): the lazy jit handles it. An error of the
+            # backend (RESOURCE_EXHAUSTED among them) is the program's
+            # own and reaches the caller as it is, after this one
+            # attempt: compiling the same program again through the
+            # lazy jit would only fail a second time.
             logging.debug('telemetry: AOT compile of %s failed (%s); '
                           'using lazy jit for this signature',
                           self.name, e)

@@ -515,8 +515,9 @@ def test_auto_flip_rebuilds_and_emits_one_record(clean_flags, tmp_path):
     assert rec['mode'] == 'int8' and rec['prev_mode'] == 'off'
     assert rec['auto'] is True
     assert rec['before_step_ms'] > 0 and rec['after_step_ms'] > 0
+    # each of the three is rounded to a microsecond on its own
     assert rec['delta_step_ms'] == pytest.approx(
-        rec['after_step_ms'] - rec['before_step_ms'], abs=1e-6)
+        rec['after_step_ms'] - rec['before_step_ms'], abs=1.5e-3)
     g = telemetry.snapshot()['gauges']
     assert g['comm.mode'] == 'int8'
 

@@ -9,7 +9,8 @@ device run. Numerics are bit-exact against the undonated reference
 program (MXTPU_FUSED_DONATE=0), a rebuilt window never re-uses a
 donated buffer, the identity cache never hands a consumed stack back
 to a donating program, the optimizer host tail overlaps the upload
-(``fused_fit.overlap_ms``), and MXTPU_REMAT_POLICY threads a
+(the side thread's ``fused_fit.stack``/``.upload`` spans against the
+loop's ``.put`` wait), and MXTPU_REMAT_POLICY threads a
 checkpoint policy into the window build.
 
 Backend note (measured, not assumed): XLA:CPU's ``memory_analysis``
@@ -33,7 +34,8 @@ from mxnet_tpu.config import flags
 
 _FLAGS = ('MXTPU_FUSED_DONATE', 'MXTPU_REMAT_POLICY', 'MXTPU_FUSED_FIT',
           'MXTPU_FUSED_FIT_PREFETCH', 'MXTPU_FIT_STEPS_PER_CALL',
-          'MXTPU_TELEMETRY', 'MXTPU_BN_ONEPASS', 'MXTPU_SHARDED_UPDATE')
+          'MXTPU_TELEMETRY', 'MXTPU_TELEMETRY_PATH', 'MXTPU_BN_ONEPASS',
+          'MXTPU_SHARDED_UPDATE')
 
 
 def _reload():
@@ -242,26 +244,116 @@ def test_identity_cache_is_donation_safe(clean_flags):
         assert np.all(np.isfinite(v))
 
 
-def test_overlap_histogram_populated(clean_flags):
-    """The update/upload overlap evidence: with the prefetch pool on
-    (default), every pool-resolved window records a
-    fused_fit.overlap_ms observation — the share of the side-thread
-    stack+put that hid under the host tail."""
-    clean_flags.setenv('MXTPU_TELEMETRY', '1')
-    _reload()
-    telemetry._reset_for_tests()
-    _fit(num_epoch=2)
-    h = telemetry.snapshot()['histograms'].get('fused_fit.overlap_ms')
-    assert h and h['count'] >= 2
-    # serial mode records nothing (there is no overlap to claim)
-    telemetry._reset_for_tests()
+def _window_spans(path):
+    """{win: {span name: record}} of the window spans in a JSONL log
+    (one record per name and window but .next)."""
+    import json
+    out = {}
+    with open(path) as f:
+        for line in f:
+            r = json.loads(line)
+            if r.get('type') == 'span' and 'win' in r \
+                    and r['name'] != 'fused_fit.next':
+                assert r['name'] not in out.setdefault(r['win'], {}), r
+                out[r['win']][r['name']] = r
+    return out
+
+
+def test_overlap_histogram_populated(clean_flags, tmp_path):
+    """The update/upload overlap evidence, read from the spans: with
+    the prefetch pool on (default) every window's stack + upload run
+    on the side thread and end before the loop's .put wait for the
+    same `win` does, so what hid is their duration less that wait;
+    serial mode runs them on the loop's own thread, before the .put,
+    and hides nothing."""
+    import threading
+
+    def fit_and_read(name, epochs):
+        clean_flags.setenv('MXTPU_TELEMETRY', '1')
+        clean_flags.setenv('MXTPU_TELEMETRY_PATH', str(tmp_path / name))
+        _reload()
+        telemetry._reset_for_tests()
+        _fit(num_epoch=epochs)
+        telemetry.shutdown()
+        wins = _window_spans(tmp_path / name)
+        return [w for w in wins.values() if 'fused_fit.put' in w]
+
+    loop_tid = threading.current_thread().name
+    pooled = fit_and_read('pool.jsonl', 2)
+    assert len(pooled) >= 2
+    for w in pooled:
+        stack, upload = w['fused_fit.stack'], w['fused_fit.upload']
+        put = w['fused_fit.put']
+        assert stack['tid'].startswith('mxtpu-window-put')
+        assert upload['tid'] == stack['tid'] != put['tid']
+        assert put['tid'] == loop_tid
+        done = upload['t'] + upload['dur_ms'] / 1e3
+        assert stack['t'] <= upload['t']
+        assert done <= put['t'] + put['dur_ms'] / 1e3 + 1e-3
+
     clean_flags.setenv('MXTPU_FUSED_FIT_PREFETCH', '0')
+    serial = fit_and_read('serial.jsonl', 1)
+    assert serial
+    for w in serial:
+        upload, put = w['fused_fit.upload'], w['fused_fit.put']
+        assert w['fused_fit.stack']['tid'] == loop_tid
+        assert upload['tid'] == loop_tid
+        # nothing left to wait for: the work was over before the put
+        assert upload['t'] + upload['dur_ms'] / 1e3 <= put['t'] + 1e-3
+
+
+def test_identity_cache_hit_opens_no_stack_span(clean_flags, tmp_path):
+    """A window served by the identity cache stacks nothing: no .stack
+    span, and with donation on (the device stack is consumed) still one
+    .upload for the fresh placement."""
+    clean_flags.setenv('MXTPU_FUSED_DONATE', '1')
     clean_flags.setenv('MXTPU_TELEMETRY', '1')
+    clean_flags.setenv('MXTPU_TELEMETRY_PATH', str(tmp_path / 't.jsonl'))
     _reload()
     telemetry._reset_for_tests()
-    _fit(num_epoch=1)
-    h = telemetry.snapshot()['histograms'].get('fused_fit.overlap_ms')
-    assert not h or not h.get('count')
+    mx.random.seed(9)
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.fit(_SameBatchIter(batches=8), num_epoch=1, optimizer='sgd',
+            optimizer_params=(('learning_rate', 0.01),),
+            eval_metric='acc')
+    telemetry.shutdown()
+    wins = _window_spans(tmp_path / 't.jsonl')
+    first, second = [wins[k] for k in sorted(wins)
+                     if 'fused_fit.put' in wins[k]]
+    assert 'fused_fit.stack' in first and 'fused_fit.upload' in first
+    assert 'fused_fit.stack' not in second
+    assert 'fused_fit.upload' in second
+
+
+@pytest.mark.parametrize('pooled', [True, False])
+def test_upload_resolver_freed_by_refcount(pooled):
+    """start_put's resolver refers to nothing but its result: once the
+    loop drops it, it and the window's stacks go by reference counting
+    alone, without waiting for a pass of the cycle collector (a window's
+    device stack is gigabytes on the chip)."""
+    import gc
+    import weakref
+    import jax
+    from mxnet_tpu.module.window_pipeline import WindowPipeline
+
+    pipe = WindowPipeline(2, lambda: jax.devices('cpu')[0])
+    snaps = [((np.ones((2, 3), np.float32) * i,),
+              (np.zeros((2,), np.float32),), 0, None) for i in range(2)]
+    pool = pipe.pool() if pooled else None
+    gc.collect()
+    gc.disable()
+    try:
+        resolver = pipe.start_put(snaps, pool, 0)
+        data_stack, label_stack = resolver()
+        assert data_stack[0].shape == (2, 2, 3)
+        gone = [weakref.ref(resolver), weakref.ref(data_stack[0])]
+        pipe.drop_cache()
+        del resolver, data_stack, label_stack
+        assert [r() for r in gone] == [None, None]
+    finally:
+        gc.enable()
+        if pool is not None:
+            pool.shutdown(wait=True)
 
 
 def test_remat_policy_unit_and_rebuild(clean_flags):

@@ -184,8 +184,77 @@ def test_span_noop_when_disabled(tele_off):
     assert s is telemetry._NULL_SPAN
     with s:
         pass
+    # attributes change nothing: the same shared object, no annotation
+    assert telemetry.span('x', win=1) is telemetry._NULL_SPAN
+    assert telemetry.span('x', 'cat', win=1, bytes=2) is \
+        telemetry._NULL_SPAN
     # nothing registered anywhere
     assert telemetry.get_registry().get('anything') is None
+    assert telemetry.get_registry().get('x') is None
+
+
+def _host_plane_events(trace_dir, prefix):
+    """{line index: [(name, stats)]} of the events of a capture's
+    /host:CPU plane whose names start with `prefix`."""
+    import glob
+    from jax.profiler import ProfileData
+    files = glob.glob(os.path.join(str(trace_dir), 'plugins', 'profile',
+                                   '*', '*.xplane.pb'))
+    assert files
+    profile = ProfileData.from_file(max(files, key=os.path.getmtime))
+    out = {}
+    for plane in profile.planes:
+        if plane.name != '/host:CPU':
+            continue
+        for i, line in enumerate(plane.lines):
+            hits = [(e.name, {str(k): v for k, v in e.stats})
+                    for e in line.events if e.name.startswith(prefix)]
+            if hits:
+                out[i] = hits
+    return out
+
+
+def test_span_is_a_profiler_event_on_its_thread(tele_path, tmp_path):
+    """A span opened during a jax.profiler capture is an event of the
+    capture's /host:CPU plane (the device's clock), its attribute among
+    the statistics, on the line of the thread it ran on; the JSONL
+    record carries the thread's name and the attributes."""
+    import threading
+    import jax
+
+    def side():
+        with telemetry.span('tspan.side', win=3, bytes=7):
+            pass
+
+    t = threading.Thread(target=side, name='tspan-side-thread')
+    jax.profiler.start_trace(str(tmp_path / 'trace'))
+    try:
+        with telemetry.span('tspan.loop', win=3):
+            t.start()
+            t.join(timeout=30)
+    finally:
+        jax.profiler.stop_trace()
+    assert not t.is_alive()
+    lines = _host_plane_events(tmp_path / 'trace', 'tspan.')
+    where = {name: (i, stats) for i, hits in lines.items()
+             for name, stats in hits}
+    assert set(where) == {'tspan.loop', 'tspan.side'}
+    assert where['tspan.loop'][1]['win'] == 3
+    assert where['tspan.side'][1]['win'] == 3
+    assert where['tspan.side'][1]['bytes'] == 7
+    assert where['tspan.loop'][0] != where['tspan.side'][0]
+
+    telemetry.shutdown()
+    recs = {r['name']: r for r in _records(tele_path)
+            if r['type'] == 'span'}
+    assert recs['tspan.loop']['win'] == 3
+    assert recs['tspan.loop']['tid'] == threading.current_thread().name
+    assert recs['tspan.side']['tid'] == 'tspan-side-thread'
+    assert recs['tspan.side']['bytes'] == 7
+    # a span on a side thread has a path of its own
+    assert recs['tspan.side']['path'] == 'tspan.side'
+    for r in recs.values():
+        assert {'name', 'path', 't', 'dur_ms'} <= set(r)
 
 
 # ---------------------------------------------------------------------------
@@ -225,11 +294,16 @@ def test_jsonl_append_only(tmp_path):
 # zero-overhead no-op path
 # ---------------------------------------------------------------------------
 
-def test_disabled_fit_zero_telemetry_io(tele_off, tmp_path):
+def test_disabled_fit_zero_telemetry_io(tele_off, tmp_path, monkeypatch):
     """MXTPU_TELEMETRY unset: a fit run writes no file and makes zero
     telemetry I/O calls (the acceptance criterion's negative half)."""
+    # the log's default path is relative: a file would land here
+    monkeypatch.chdir(tmp_path)
     io_before = tele_export._io_calls
     _mlp_fit(num_epoch=1)
+    with telemetry.span('x', win=1):
+        pass
+    assert os.listdir(str(tmp_path)) == []
     assert tele_export._io_calls == io_before
     assert telemetry._state.sink is None
     assert not telemetry._state.active
@@ -300,6 +374,47 @@ def test_fit_telemetry_fused_loop(tele_path):
     assert any(r['type'] == 'span' and r['name'] == 'fused_fit.dispatch'
                for r in recs)
     assert any(r['type'] == 'compile' for r in recs)
+
+
+def test_fused_fit_window_spans_share_win(tele_path):
+    """A fused fit of two windows gives, per window, one .draw with
+    `window` many .next inside it, one .stack and one .upload on the
+    side thread, one .put, one .dispatch and one .fetch on the loop's,
+    all with the window's `win`; and the set-up spans around them."""
+    import threading
+    _mlp_fit(num_epoch=1, n=64)         # 8 batches: two windows of 4
+    telemetry.shutdown()
+    spans = [r for r in _records(tele_path) if r['type'] == 'span']
+    dispatched = sorted(r['win'] for r in spans
+                        if r['name'] == 'fused_fit.dispatch')
+    assert len(dispatched) == 2 and dispatched[1] == dispatched[0] + 1
+    loop_tid = threading.current_thread().name
+    for win in dispatched:
+        mine = [r for r in spans if r.get('win') == win]
+        count = {}
+        for r in mine:
+            count[r['name']] = count.get(r['name'], 0) + 1
+        assert count == {
+            'fused_fit.draw': 1, 'fused_fit.next': 4,
+            'fused_fit.stack': 1, 'fused_fit.upload': 1,
+            'fused_fit.put': 1, 'fused_fit.dispatch': 1,
+            'fused_fit.fetch': 1}, (win, count)
+        for r in mine:
+            side = r['name'] in ('fused_fit.stack', 'fused_fit.upload')
+            assert r['tid'].startswith('mxtpu-window-put') if side \
+                else r['tid'] == loop_tid, r
+            if side:
+                assert r['bytes'] == 4 * (8 * 10 + 8) * 4
+                assert r['path'] == r['name']
+        assert all(r['path'] == 'fused_fit.draw/fused_fit.next'
+                   for r in mine if r['name'] == 'fused_fit.next')
+    # the draw that found the iterator at its end: numbered, no upload
+    last = [r['name'] for r in spans if r.get('win') == dispatched[1] + 1]
+    assert sorted(last) == ['fused_fit.draw', 'fused_fit.next']
+    names = [r['name'] for r in spans]
+    for setup in ('fit.bind', 'fit.init_params', 'fit.init_optimizer',
+                  'fused_fit.build'):
+        assert names.count(setup) == 1, setup
 
 
 def test_fit_results_identical_with_telemetry(tele_path, monkeypatch):
@@ -536,6 +651,47 @@ def test_registered_program_numerics_match_lazy_jit(tele_path):
     progs = telemetry.programs.snapshot_programs()
     assert progs['test.prog']['compiles'] == 2
     assert progs['test.prog']['dispatches'] == 4
+
+
+def test_backend_compile_error_raised_once(tele_path):
+    """A compile that fails in the backend (RESOURCE_EXHAUSTED among
+    them) reaches the caller as it is after ONE attempt: the lazy jit
+    is not asked to compile the same program again. What the
+    ahead-of-time path itself cannot take still falls back."""
+    class XlaRuntimeError(RuntimeError):
+        pass
+
+    class _Lowered:
+        def __init__(self, exc):
+            self.exc = exc
+
+        def compile(self):
+            raise self.exc
+
+    class _Jitted:
+        def __init__(self, exc):
+            self.exc = exc
+            self.lowered = self.called = 0
+
+        def lower(self, *args):
+            self.lowered += 1
+            return _Lowered(self.exc)
+
+        def __call__(self, *args):
+            self.called += 1
+            return 'lazy'
+
+    oom = _Jitted(XlaRuntimeError('RESOURCE_EXHAUSTED: out of memory'))
+    prog = telemetry.programs.register('test.oom', oom)
+    with pytest.raises(XlaRuntimeError, match='RESOURCE_EXHAUSTED'):
+        prog(np.zeros(3, np.float32))
+    assert (oom.lowered, oom.called) == (1, 0)
+
+    layout = _Jitted(TypeError('argument layout'))
+    prog = telemetry.programs.register('test.layout', layout)
+    assert prog(np.zeros(3, np.float32)) == 'lazy'
+    assert prog(np.zeros(3, np.float32)) == 'lazy'
+    assert (layout.lowered, layout.called) == (1, 2)
 
 
 def test_step_flops_keeps_max_across_recompiles(tele_path):
