@@ -758,25 +758,18 @@ class ImageRecordIter(DataIter):
             # critical path — defer mode leaves zero of them
             import jax
             from ..context import current_context
-            from ..ndarray.ndarray import from_jax
+            from ..ndarray.ndarray import _build
             ctx = current_context()
             try:
                 host = jax.local_devices(backend='cpu')[0]
-            except RuntimeError:   # no cpu backend: plain jnp arrays
-                host = None
+            except RuntimeError:   # no cpu backend: the context's device
+                host = ctx.jax_device()
 
             def host_nd(a):
-                # one host copy per batch (cpu-backend device_put);
-                # the window stack's np.asarray may copy again — the
-                # alternative (numpy inside NDArray._data) would break
-                # the wrapper's jax-array invariant for ~2 ms/batch,
-                # noise next to the 65-85 ms dispatches defer removes
-                if host is not None:
-                    arr = jax.device_put(np.ascontiguousarray(a), host)
-                else:
-                    import jax.numpy as jnp
-                    arr = jnp.asarray(a)
-                return from_jax(arr, ctx)
+                # one host copy per batch, into memory the cpu backend
+                # adopts as the array's own (it would else take its own
+                # behind the call); the window's stack reads it there
+                return NDArray(_build(host, a, a.dtype), ctx)
 
             return DataBatch(data=[host_nd(data)], label=[host_nd(label)],
                              pad=pad, index=None,

@@ -14,7 +14,10 @@ ThreadedVar dependency-queue machinery (threaded_engine.h:111-213) with no
 loss of semantics.
 """
 import functools
+import math
+import os
 import re
+import weakref
 
 import numpy as np
 
@@ -75,16 +78,13 @@ class NDArray:
         return {'data': npy, 'ctx': self._ctx, 'bf16': False}
 
     def __setstate__(self, state):
-        import jax.numpy as jnp
-        import jax
-        dtype = jnp.bfloat16 if state.get('bf16') else None
-        data = jnp.asarray(state['data'], dtype=dtype)
         ctx = state['ctx']
         try:
-            data = jax.device_put(data, ctx.jax_device())
-        except Exception:
-            pass  # device unavailable in this process: keep default placement
-        self.__init__(data, ctx=ctx)
+            dev = ctx.jax_device()
+        except MXNetError:
+            dev = jax.devices()[0]  # no such device in this process
+        dtype = jnp.bfloat16 if state.get('bf16') else state['data'].dtype
+        self.__init__(_build(dev, state['data'], _narrowed(dtype)), ctx=ctx)
 
     # -- basic properties -------------------------------------------------
     @property
@@ -231,20 +231,26 @@ class NDArray:
         self._out_idx = out_idx
 
     def __setitem__(self, key, value):
+        # the new value is built where this array lives (its device, or
+        # its sharding over a mesh), never on the process's default
+        # device, which beside an mx.cpu() array is the chip
+        here, dtype = self._data.sharding, self._data.dtype
+        whole = key is None or key == slice(None)
         if isinstance(value, NDArray):
             value = value._data
-        elif isinstance(value, numeric_types):
-            value = jnp.asarray(value, dtype=self._data.dtype)
+            if whole:
+                value = jnp.broadcast_to(value, self.shape).astype(dtype)
+        elif whole and isinstance(value, numeric_types):
+            value = _build(here, functools.partial(
+                jnp.full, self.shape, value, dtype))
         else:
-            value = jnp.asarray(np.asarray(value), dtype=self._data.dtype)
-        if key is None or key == slice(None):
-            # the new value goes where this array lives (its device, or its
-            # sharding over a mesh), not where jnp put the operand: an
-            # uncommitted operand sits on the process's default device
-            new = jnp.broadcast_to(value, self.shape).astype(self._data.dtype)
-            self._set_data(jax.device_put(new, self._data.sharding))
+            value = _host_copy(value, dtype, self.shape if whole else None)
+        if whole:
+            self._set_data(jax.device_put(value, here))
         else:
-            self._set_data(self._data.at[key].set(value))
+            # .at[] makes its index arrays on the default device too
+            with jax.default_device(_first_device(here)):
+                self._set_data(self._data.at[key].set(value))
 
     def __getitem__(self, key):
         if isinstance(key, NDArray):
@@ -642,12 +648,117 @@ def from_jax(data, ctx=None):
     return NDArray(data, ctx)
 
 
+def _first_device(where):
+    """`where` if it is a device, else the first device of that sharding."""
+    if isinstance(where, jax.Device):
+        return where
+    return min(where.device_set, key=lambda d: d.id)
+
+
+def _narrowed(dtype):
+    """`dtype` as jax will hold it: it silently truncates 64-bit dtypes
+    when x64 is off, so ask for the narrow one up front and keep the
+    conversion warning-free."""
+    d = np.dtype(np_dtype(dtype))
+    if not jax.config.jax_enable_x64:
+        if d == np.int64:
+            d = np.dtype(np.int32)
+        elif d == np.float64:
+            d = np.dtype(np.float32)
+    return d
+
+
+# Host memory of dead NDArrays, by size in bytes, kept to be written again.
+# First touch of new memory, not the copy into it, is what a large host
+# array costs (a 77 MB batch on the v5e's host: 70 ms of page faults, 4-30
+# ms of copying; PERF.md, PR 26), and what the allocator gives back to the
+# system it has to fault in again for the next batch. The reference pools
+# its storage for the same reason (src/storage/pooled_storage_manager.h).
+_idle_buffers = {}
+_POOLED_FROM = 1 << 20      # smaller buffers cost under a millisecond new
+_IDLE_LIMIT = os.sysconf('SC_PHYS_PAGES') * os.sysconf('SC_PAGE_SIZE') // 8
+
+
+def _keep_for_reuse(raw):
+    """Called when the last user of `raw`'s memory has let go of it (on
+    whichever thread that happens: list and dict operations only)."""
+    if raw.nbytes > _IDLE_LIMIT:
+        return
+    idle = sum(n * len(bufs) for n, bufs in list(_idle_buffers.items()))
+    if idle + raw.nbytes > _IDLE_LIMIT:
+        # start again, so that sizes nobody asks for any more cannot
+        # hold the room for good
+        _idle_buffers.clear()
+    _idle_buffers.setdefault(raw.nbytes, []).append(raw)
+
+
+def _host_copy(src, dtype, shape=None):
+    """A copy of host data `src` as `dtype` (broadcast to `shape`), taken
+    now, in numpy memory that nothing else refers to.
+
+    The copy is ours because the backend's is not taken at the call:
+    ``jax.device_put`` of a numpy array returns at once and reads the
+    source afterwards, or on the CPU backend keeps a 64-byte-aligned
+    source as the array's own memory. The buffer is therefore aligned
+    so: onto a cpu device it is adopted without a second copy, and for
+    a chip it is what the one transfer reads. When nothing reads a large
+    buffer any more it is kept for the next copy of its size."""
+    src = np.asarray(src)
+    dtype = np.dtype(dtype)
+    shape = src.shape if shape is None else tuple(shape)
+    nbytes = math.prod(shape) * dtype.itemsize
+    try:
+        raw = _idle_buffers[nbytes + 64].pop()
+    except (KeyError, IndexError):
+        raw = np.empty(nbytes + 64, np.uint8)
+    off = -raw.ctypes.data % 64
+    # numpy hands a view of a view the first array as its base, and stops
+    # at one whose own base is no array: so whatever reads this memory,
+    # through whichever view of `out`, holds `flat`, and nothing but the
+    # list of idle buffers holds `raw`
+    flat = np.frombuffer(memoryview(raw)[off:off + nbytes], dtype)
+    if nbytes >= _POOLED_FROM:
+        weakref.finalize(flat, _keep_for_reuse, raw).atexit = False
+    out = flat.reshape(shape)
+    np.copyto(out, src, casting='unsafe')
+    return out
+
+
+def _build(where, value, dtype=None):
+    """The backing ``jax.Array`` of an NDArray on `where` (a device, or
+    the sharding of an array that is being refilled). Every constructor
+    builds here, by one rule: building an NDArray touches no device but
+    the one its context names.
+
+    `value` from the host (anything numpy reads) is converted with numpy
+    on the host and crosses once, straight to `where`; for a cpu device
+    nothing leaves the host. `value` as a function generates the array
+    with jnp on `where` itself. jnp without this puts its result on the
+    process's default device, which on a machine with a chip is the chip
+    whatever the context says."""
+    if callable(value):
+        with jax.default_device(_first_device(where)):
+            data = value()
+    else:
+        data = _host_copy(value, dtype)
+    # for a generated array this commits it where it is and moves nothing
+    return jax.device_put(data, where)
+
+
 def array(source_array, ctx=None, dtype=None):
-    """Reference ndarray.py:1988 mx.nd.array."""
+    """Reference ndarray.py:1988 mx.nd.array.
+
+    The data is converted on the host and put on `ctx`'s device in one
+    transfer; for a cpu context it never leaves the host. The values are
+    copied at the call (the reference's _sync_copyfrom): the source may
+    be written to as soon as this returns."""
     ctx = ctx if ctx is not None else current_context()
     keep_dtype = isinstance(source_array, (np.ndarray, NDArray))
     if isinstance(source_array, NDArray):
-        src = source_array.asnumpy()
+        # by way of the host: a view of a cpu array, a fetch from a chip
+        src = np.asarray(source_array._data)
+        if src.dtype == jnp.bfloat16:
+            src = src.astype(np.float32)
     else:
         src = np.asarray(source_array)
     if dtype is None:
@@ -661,16 +772,7 @@ def array(source_array, ctx=None, dtype=None):
                 dtype = np.float32
             elif dtype == np.int64:
                 dtype = np.int32
-    d = np_dtype(dtype)
-    if not jax.config.jax_enable_x64 and d is not None:
-        # jax silently truncates 64-bit dtypes when x64 is off; request
-        # the narrowed dtype up front to keep the conversion warning-free
-        if np.dtype(d) == np.int64:
-            d = np.int32
-        elif np.dtype(d) == np.float64:
-            d = np.float32
-    data = jax.device_put(jnp.asarray(src, dtype=d), ctx.jax_device())
-    return NDArray(data, ctx)
+    return NDArray(_build(ctx.jax_device(), src, _narrowed(dtype)), ctx)
 
 
 def empty(shape, ctx=None, dtype='float32'):
@@ -678,39 +780,32 @@ def empty(shape, ctx=None, dtype='float32'):
 
 
 def zeros(shape, ctx=None, dtype='float32', **kwargs):
-    ctx = ctx if ctx is not None else current_context()
-    if isinstance(shape, int):
-        shape = (shape,)
-    data = jax.device_put(jnp.zeros(shape, dtype=np_dtype(dtype)), ctx.jax_device())
-    return NDArray(data, ctx)
+    return full(shape, 0, ctx, dtype)
 
 
 def ones(shape, ctx=None, dtype='float32', **kwargs):
-    ctx = ctx if ctx is not None else current_context()
-    if isinstance(shape, int):
-        shape = (shape,)
-    data = jax.device_put(jnp.ones(shape, dtype=np_dtype(dtype)), ctx.jax_device())
-    return NDArray(data, ctx)
+    return full(shape, 1, ctx, dtype)
 
 
 def full(shape, val, ctx=None, dtype='float32', out=None):
     ctx = ctx if ctx is not None else current_context()
     if isinstance(shape, int):
         shape = (shape,)
-    data = jax.device_put(jnp.full(shape, val, dtype=np_dtype(dtype)), ctx.jax_device())
-    res = NDArray(data, ctx)
+    data = _build(ctx.jax_device(),
+                  lambda: jnp.full(shape, val, dtype=np_dtype(dtype)))
     if out is not None:
-        out._set_data(res._data)
+        out._set_data(data)
         return out
-    return res
+    return NDArray(data, ctx)
 
 
 def arange(start, stop=None, step=1.0, repeat=1, ctx=None, dtype='float32'):
     ctx = ctx if ctx is not None else current_context()
-    arr = jnp.arange(start, stop, step, dtype=np_dtype(dtype))
-    if repeat > 1:
-        arr = jnp.repeat(arr, repeat)
-    return NDArray(jax.device_put(arr, ctx.jax_device()), ctx)
+
+    def make():
+        arr = jnp.arange(start, stop, step, dtype=np_dtype(dtype))
+        return jnp.repeat(arr, repeat) if repeat > 1 else arr
+    return NDArray(_build(ctx.jax_device(), make), ctx)
 
 
 def concatenate(arrays, axis=0, always_copy=True):

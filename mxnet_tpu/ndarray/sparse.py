@@ -26,7 +26,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..context import current_context
-from .ndarray import NDArray, array as _dense_array
+from .ndarray import NDArray, _build, array as _dense_array
 
 __all__ = ['RowSparseNDArray', 'CSRNDArray', 'row_sparse_array', 'csr_matrix',
            'BaseSparseNDArray', 'cast_storage', 'retain', 'sparse_retain',
@@ -100,6 +100,15 @@ class BaseSparseNDArray:
     __rmul__ = __mul__
 
 
+def _scatter_into_zeros(values, shape, index):
+    """Zeros of `shape` with `values` at `index`, built where `values`
+    live: jnp.zeros and .at[] alone start on the process's default
+    device, which is not the context's on a machine with a chip."""
+    def make():
+        return jnp.zeros(shape, values.dtype).at[index].set(values)
+    return _build(values.sharding, make)
+
+
 class RowSparseNDArray(BaseSparseNDArray):
     """rows `indices` hold `data`; all other rows are zero
     (reference sparse.py:780, aux layout ndarray.h:82-87)."""
@@ -117,10 +126,9 @@ class RowSparseNDArray(BaseSparseNDArray):
         if stype != 'default':
             raise ValueError('cast from row_sparse to %s is not supported'
                              % stype)
-        dense = jnp.zeros(self._shape, dtype=self.data._data.dtype)
-        dense = dense.at[self.indices._data.astype(jnp.int32)].set(
-            self.data._data)
-        return NDArray(dense, self._ctx)
+        return NDArray(_scatter_into_zeros(
+            self.data._data, self._shape,
+            (self.indices._data.astype(jnp.int32),)), self._ctx)
 
     def copyto(self, other):
         if isinstance(other, NDArray):
@@ -183,19 +191,19 @@ class CSRNDArray(BaseSparseNDArray):
                                     dtype=self.data.asnumpy().dtype)
         if stype != 'default':
             raise ValueError(stype)
-        dense = jnp.zeros(self._shape, dtype=self.data._data.dtype)
-        rows = self._row_ids()
-        dense = dense.at[rows, self.indices._data.astype(jnp.int32)].set(
-            self.data._data)
-        return NDArray(dense, self._ctx)
+        return NDArray(_scatter_into_zeros(
+            self.data._data, self._shape,
+            (self._row_ids(), self.indices._data.astype(jnp.int32))),
+            self._ctx)
 
     def _row_ids(self):
         """nnz-length row id per value, from indptr (host-side: aux
         indices are concrete metadata, exactly like the reference's
         aux_data on CPU)."""
         ptr = self.indptr.asnumpy().astype(np.int64)
-        return jnp.asarray(np.repeat(np.arange(len(ptr) - 1),
-                                     np.diff(ptr)), jnp.int32)
+        return _build(self.data._data.sharding,
+                      np.repeat(np.arange(len(ptr) - 1), np.diff(ptr)),
+                      np.int32)
 
     def copyto(self, other):
         if isinstance(other, NDArray):
@@ -360,7 +368,7 @@ def dot(lhs, rhs, transpose_a=False, transpose_b=False):
                                   '(unsupported in the reference too)')
     if isinstance(lhs, CSRNDArray):
         rows = lhs._row_ids()
-        cols = jnp.asarray(lhs.indices.asnumpy().astype(np.int64), jnp.int32)
+        cols = lhs.indices._data.astype(jnp.int32)
         vals = lhs.data._data
         dense_rhs = (rhs.tostype('default')
                      if isinstance(rhs, BaseSparseNDArray) else rhs)._data
@@ -379,7 +387,7 @@ def dot(lhs, rhs, transpose_a=False, transpose_b=False):
                                   num_segments=lhs.shape[1])
         nz = np.unique(lhs.indices.asnumpy().astype(np.int64))
         return RowSparseNDArray(
-            NDArray(out[jnp.asarray(nz, jnp.int32)], lhs._ctx),
+            NDArray(out[_build(out.sharding, nz, np.int32)], lhs._ctx),
             _dense_array(nz, lhs._ctx, dtype='int64'),
             (lhs.shape[1], dense_rhs.shape[1]), lhs._ctx)
     if isinstance(rhs, BaseSparseNDArray) or isinstance(lhs,
@@ -412,9 +420,9 @@ def square_sum(rsp, axis=None, keepdims=False):
             data = NDArray(row_sums[:, None], rsp._ctx)
             return RowSparseNDArray(data, rsp.indices,
                                     (rsp.shape[0], 1), rsp._ctx)
-        dense = jnp.zeros((rsp.shape[0],), jnp.float32)
-        dense = dense.at[rsp.indices._data.astype(jnp.int32)].set(row_sums)
-        return NDArray(dense, rsp._ctx)
+        return NDArray(_scatter_into_zeros(
+            row_sums, (rsp.shape[0],),
+            (rsp.indices._data.astype(jnp.int32),)), rsp._ctx)
     # axis == 0: reduce over rows → dense row vector
     out = sq.sum(axis=0)
     return NDArray(out[None] if keepdims else out, rsp._ctx)
