@@ -360,6 +360,21 @@ inline Symbol FullyConnected(const std::string &symbol_name,
   return op.CreateSymbol(symbol_name);
 }
 
+inline Symbol GatedMLP(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &w1_weight,
+    const Symbol &w3_weight,
+    const Symbol &w2_weight,
+    int hidden = 0) {
+  Operator op("GatedMLP");
+  op.SetInput("data", data);
+  op.SetInput("w1_weight", w1_weight);
+  op.SetInput("w3_weight", w3_weight);
+  op.SetInput("w2_weight", w2_weight);
+  op.SetParam("hidden", detail::ToParam(hidden));
+  return op.CreateSymbol(symbol_name);
+}
+
 inline Symbol GridGenerator(const std::string &symbol_name,
     const Symbol &data,
     const std::string &transform_type = "affine",
@@ -368,6 +383,27 @@ inline Symbol GridGenerator(const std::string &symbol_name,
   op.SetInput("data", data);
   if (!transform_type.empty()) op.SetParam("transform_type", detail::ToParam(transform_type));
   if (!target_shape.empty()) op.SetParam("target_shape", detail::ToParam(target_shape));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol GroupedQueryAttention(const std::string &symbol_name,
+    const Symbol &query,
+    const Symbol &key,
+    const Symbol &value,
+    const Symbol &gate,
+    int num_heads = 1,
+    int num_kv_heads = 1,
+    int window = 0,
+    bool gated = false) {
+  Operator op("GroupedQueryAttention");
+  op.SetInput("query", query);
+  op.SetInput("key", key);
+  op.SetInput("value", value);
+  op.SetInput("gate", gate);
+  op.SetParam("num_heads", detail::ToParam(num_heads));
+  op.SetParam("num_kv_heads", detail::ToParam(num_kv_heads));
+  op.SetParam("window", detail::ToParam(window));
+  op.SetParam("gated", detail::ToParam(gated));
   return op.CreateSymbol(symbol_name);
 }
 
@@ -503,6 +539,45 @@ inline Symbol MakeLoss(const std::string &symbol_name,
   return op.CreateSymbol(symbol_name);
 }
 
+inline Symbol MoE(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &router_weight,
+    const Symbol &experts_w1_weight,
+    const Symbol &experts_w3_weight,
+    const Symbol &experts_w2_weight,
+    const Symbol &shared_w1_weight,
+    const Symbol &shared_w3_weight,
+    const Symbol &shared_w2_weight,
+    const Symbol &stats,
+    int num_experts = 0,
+    int num_experts_per_tok = 1,
+    int experts_held = 0,
+    int expert_offset = 0,
+    bool norm_topk_prob = true,
+    mx_float routed_scaling = 1.0f,
+    int hidden = 0,
+    int shared_hidden = 0) {
+  Operator op("MoE");
+  op.SetInput("data", data);
+  op.SetInput("router_weight", router_weight);
+  op.SetInput("experts_w1_weight", experts_w1_weight);
+  op.SetInput("experts_w3_weight", experts_w3_weight);
+  op.SetInput("experts_w2_weight", experts_w2_weight);
+  op.SetInput("shared_w1_weight", shared_w1_weight);
+  op.SetInput("shared_w3_weight", shared_w3_weight);
+  op.SetInput("shared_w2_weight", shared_w2_weight);
+  op.SetInput("stats", stats);
+  op.SetParam("num_experts", detail::ToParam(num_experts));
+  op.SetParam("num_experts_per_tok", detail::ToParam(num_experts_per_tok));
+  op.SetParam("experts_held", detail::ToParam(experts_held));
+  op.SetParam("expert_offset", detail::ToParam(expert_offset));
+  op.SetParam("norm_topk_prob", detail::ToParam(norm_topk_prob));
+  op.SetParam("routed_scaling", detail::ToParam(routed_scaling));
+  op.SetParam("hidden", detail::ToParam(hidden));
+  op.SetParam("shared_hidden", detail::ToParam(shared_hidden));
+  return op.CreateSymbol(symbol_name);
+}
+
 inline Symbol Pad(const std::string &symbol_name,
     const Symbol &data,
     const std::string &mode = "constant",
@@ -555,6 +630,17 @@ inline Symbol Pooling_v1(const std::string &symbol_name,
   op.SetParam("global_pool", detail::ToParam(global_pool));
   if (!pooling_convention.empty()) op.SetParam("pooling_convention", detail::ToParam(pooling_convention));
   op.SetParam("cudnn_off", detail::ToParam(cudnn_off));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol RMSNorm(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &gamma,
+    mx_float eps = 1e-06f) {
+  Operator op("RMSNorm");
+  op.SetInput("data", data);
+  op.SetInput("gamma", gamma);
+  op.SetParam("eps", detail::ToParam(eps));
   return op.CreateSymbol(symbol_name);
 }
 
@@ -612,6 +698,31 @@ inline Symbol Reshape(const std::string &symbol_name,
   op.SetParam("reverse", detail::ToParam(reverse));
   if (!target_shape.empty()) op.SetParam("target_shape", detail::ToParam(target_shape));
   op.SetParam("keep_highest", detail::ToParam(keep_highest));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol RotaryEmbedding(const std::string &symbol_name,
+    const Symbol &data,
+    int num_heads = 1,
+    mx_float base = 10000.0f,
+    int rotary_dim = 0,
+    const std::string &scaling = "default",
+    mx_float factor = 1.0f,
+    int original_max_position = 0,
+    mx_float beta_fast = 32.0f,
+    mx_float beta_slow = 1.0f,
+    mx_float attention_factor = 0.0f) {
+  Operator op("RotaryEmbedding");
+  op.SetInput("data", data);
+  op.SetParam("num_heads", detail::ToParam(num_heads));
+  op.SetParam("base", detail::ToParam(base));
+  op.SetParam("rotary_dim", detail::ToParam(rotary_dim));
+  if (!scaling.empty()) op.SetParam("scaling", detail::ToParam(scaling));
+  op.SetParam("factor", detail::ToParam(factor));
+  op.SetParam("original_max_position", detail::ToParam(original_max_position));
+  op.SetParam("beta_fast", detail::ToParam(beta_fast));
+  op.SetParam("beta_slow", detail::ToParam(beta_slow));
+  op.SetParam("attention_factor", detail::ToParam(attention_factor));
   return op.CreateSymbol(symbol_name);
 }
 

@@ -97,59 +97,139 @@ class _GraphProgram:
 
     def make_runner(self):
         """Build run(arg_arrays, aux_arrays, key, is_train) ->
-        (outputs, new_aux). Pure; jit-compiled by the executor."""
+        (outputs, new_aux). Pure; jit-compiled by the executor.
+
+        Nodes that carry the reference's mirroring attribute
+        (``mx.AttrScope(__force_mirroring__=<stage>)``, graph_executor.cc's
+        per-node mirror mark) are recomputed in the backward pass instead
+        of stored: consecutive marked op nodes with the same value form one
+        stage, run under ``jax.checkpoint`` when training, so that only
+        what the stage reads and what leaves it is kept. A builder gives
+        each block of a deep network its own value."""
         topo = self.topo
         arg_index = {n: i for i, n in enumerate(self.arg_names)}
         aux_index = {n: i for i, n in enumerate(self.aux_names)}
         outputs = self.outputs
-
         scope_names = self.scope_names
+
+        def exec_node(env, ni, key, is_train, new_aux):
+            node = topo[ni]
+            op = node.opdef()
+            _reg.record(op)
+            attrs = dict(node.attrs)
+            if op.train_aware:
+                attrs['__is_train__'] = is_train
+            ins = [env[_entry_key(p, i)] for p, i in node.inputs]
+            if op.needs_rng:
+                ins.append(jax.random.fold_in(key, ni))
+            # named_scope threads the symbol's layer name into the
+            # HLO metadata of everything this node lowers to —
+            # trace-time only, zero cost in the compiled program
+            with jax.named_scope(scope_names[ni]):
+                if op.host:
+                    # pure_callback bridge: host python at execution
+                    # time, traceable (and differentiable via legacy
+                    # backward)
+                    outs = _reg.host_bridge(op, attrs)(*ins)
+                else:
+                    outs = op.fn(attrs, *ins)
+            if not isinstance(outs, (tuple, list)):
+                outs = (outs,)
+            for i, o in enumerate(outs):
+                env[_entry_key(node, i)] = o
+            # collect aux updates (BatchNorm moving stats)
+            for in_idx, out_idx in op.mutate_inputs.items():
+                if in_idx < len(node.inputs):
+                    src, _ = node.inputs[in_idx]
+                    if src.is_variable() and src.name in aux_index:
+                        new_aux[aux_index[src.name]] = outs[out_idx]
+
+        plan = self._mirror_plan()
 
         def run(arg_arrays, aux_arrays, key, is_train):
             env = {}
             new_aux = dict()
-            for ni, node in enumerate(topo):
+            for node in topo:
                 if node.is_variable():
                     if node.name in aux_index:
                         env[_entry_key(node, 0)] = aux_arrays[aux_index[node.name]]
                     else:
                         env[_entry_key(node, 0)] = arg_arrays[arg_index[node.name]]
+            for nis, reads, leaves in plan:
+                if reads is None or not is_train:
+                    for ni in nis:
+                        exec_node(env, ni, key, is_train, new_aux)
                     continue
-                op = node.opdef()
-                _reg.record(op)
-                attrs = dict(node.attrs)
-                if op.train_aware:
-                    attrs['__is_train__'] = is_train
-                ins = [env[_entry_key(p, i)] for p, i in node.inputs]
-                if op.needs_rng:
-                    ins.append(jax.random.fold_in(key, ni))
-                # named_scope threads the symbol's layer name into the
-                # HLO metadata of everything this node lowers to —
-                # trace-time only, zero cost in the compiled program
-                with jax.named_scope(scope_names[ni]):
-                    if op.host:
-                        # pure_callback bridge: host python at execution
-                        # time, traceable (and differentiable via legacy
-                        # backward)
-                        outs = _reg.host_bridge(op, attrs)(*ins)
-                    else:
-                        outs = op.fn(attrs, *ins)
-                if not isinstance(outs, (tuple, list)):
-                    outs = (outs,)
-                for i, o in enumerate(outs):
-                    env[_entry_key(node, i)] = o
-                # collect aux updates (BatchNorm moving stats)
-                for in_idx, out_idx in op.mutate_inputs.items():
-                    if in_idx < len(node.inputs):
-                        src, _ = node.inputs[in_idx]
-                        if src.is_variable() and src.name in aux_index:
-                            new_aux[aux_index[src.name]] = outs[out_idx]
+
+                def stage(vals, key, nis=nis, reads=reads, leaves=leaves):
+                    local, aux_up = dict(zip(reads, vals)), {}
+                    for ni in nis:
+                        exec_node(local, ni, key, is_train, aux_up)
+                    return [local[k] for k in leaves], aux_up
+
+                outs, aux_up = jax.checkpoint(stage)(
+                    [env[k] for k in reads], key)
+                env.update(zip(leaves, outs))
+                new_aux.update(aux_up)
             out_arrays = tuple(env[_entry_key(n, i)] for n, i in outputs)
             aux_out = tuple(new_aux.get(i, aux_arrays[i])
                             for i in range(len(self.aux_names)))
             return out_arrays, aux_out
 
         return run
+
+    def _mirror_plan(self):
+        """[(op node indices, reads, leaves)] in execution order: a node of
+        its own (reads None), or a mirrored stage with the entries it
+        reads from outside and the entries that leave it (read by a later
+        node, or outputs of the graph)."""
+        topo = self.topo
+
+        def stage_of(node):
+            tag = str(node.attr_dict.get(
+                '__force_mirroring__',
+                node.attr_dict.get('force_mirroring', '')))
+            return '' if tag in ('', '0', 'False', 'false') else tag
+
+        runs = []       # [tag, [ni, ...]]
+        for ni, node in enumerate(topo):
+            if node.is_variable():
+                continue
+            tag = stage_of(node)
+            if tag and runs and runs[-1][0] == tag:
+                runs[-1][1].append(ni)
+            else:
+                runs.append([tag, [ni]])
+        graph_outs = {_entry_key(n, i) for n, i in self.outputs}
+        plan = []
+        for tag, nis in runs:
+            if not tag:
+                plan.append((nis, None, None))
+                continue
+            inside = set(nis)
+            made = {id(topo[ni]) for ni in nis}
+            reads, seen = [], set()
+            for ni in nis:
+                for p, i in topo[ni].inputs:
+                    k = _entry_key(p, i)
+                    if id(p) not in made and k not in seen:
+                        seen.add(k)
+                        reads.append(k)
+            leaves, seen = [], set()
+            for nj, node in enumerate(topo):
+                if node.is_variable() or nj in inside:
+                    continue
+                for p, i in node.inputs:
+                    k = _entry_key(p, i)
+                    if id(p) in made and k not in seen:
+                        seen.add(k)
+                        leaves.append(k)
+            for k in graph_outs:
+                if k[0] in made and k not in seen:
+                    seen.add(k)
+                    leaves.append(k)
+            plan.append((nis, reads, leaves))
+        return plan
 
 
 class Executor:

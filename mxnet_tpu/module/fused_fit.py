@@ -63,8 +63,9 @@ from ..kvstore import _updater_key
 from ..ndarray.ndarray import from_jax
 from ..ops import registry as _reg
 from .window_pipeline import (WindowPipeline, dynamics_sentinel,
-                              health_sentinel, host_wrap,
-                              registered_jit, window_bisect, window_size)
+                              health_sentinel, host_wrap, moe_sentinel,
+                              note_moe_window, registered_jit,
+                              window_bisect, window_size)
 from .window_pipeline import plan_metric as _metric_plan
 
 __all__ = ['FusedFitLoop']
@@ -492,6 +493,9 @@ class FusedFitLoop:
         # contract — captured at build, traced into the window, rides
         # the existing single fetch; None = byte-identical program
         self._dyn_fn = dynamics_sentinel()
+        # what the routed expert layers did, step by step (same contract:
+        # None without telemetry or without such a layer)
+        self._moe_fn = moe_sentinel(module._symbol, self._aux_names)
         self._out_names = list(module._symbol.list_outputs())
         self._last_lr = None   # last sampled lr (run-ledger scalars)
         self._upd_keys = updater_keys(module, self._grad_names)
@@ -633,7 +637,9 @@ class FusedFitLoop:
                        # calls must rebuild the loop
                        bool(_tele.health.enabled()),
                        # ...and so is the per-layer dynamics matrix
-                       bool(_tele.dynamics.enabled()))
+                       bool(_tele.dynamics.enabled()),
+                       # ...and the expert layers' statistics
+                       bool(_tele.enabled()))
         cached = module.__dict__.get('_fused_fit_cache')
         if cached is not None and sig is not None and cached[0] == sig:
             loop = cached[1]
@@ -791,6 +797,7 @@ class FusedFitLoop:
         stat_fns = self.stat_fns
         health_fn = self._health_fn
         dyn_fn = self._dyn_fn
+        moe_fn = self._moe_fn
         accum = self._accum
         W = self.window
         mesh = self._mesh
@@ -962,6 +969,8 @@ class FusedFitLoop:
                         params=tuple(params[i] for i in grad_carry_idx),
                         new_params=tuple(new_params[i]
                                          for i in grad_carry_idx)))
+                if moe_fn is not None:
+                    extras.append(moe_fn(new_aux))
                 if extras:
                     ys = (ys, *extras)
                 if compress:
@@ -1310,14 +1319,17 @@ class FusedFitLoop:
             (snapshotted at collection time — see below), the way the
             reference loop's update_metric would."""
             pieces, labels_w, win_snaps, win = pending
-            hrows = drows = None
-            if self._health_fn is not None or self._dyn_fn is not None:
+            hrows = drows = mrows = None
+            if self._health_fn is not None or self._dyn_fn is not None \
+                    or self._moe_fn is not None:
                 parts = list(pieces)
                 pieces = parts.pop(0)
                 if self._health_fn is not None:
                     hrows = parts.pop(0)
                 if self._dyn_fn is not None:
                     drows = parts.pop(0)
+                if self._moe_fn is not None:
+                    mrows = parts.pop(0)
             with _tele.span('fused_fit.fetch', 'fused_fit', win=win):
                 # the window's one device->host fetch (everything
                 # after is host math) —
@@ -1333,6 +1345,10 @@ class FusedFitLoop:
                     hmat = np.asarray(hrows)
                 if drows is not None:
                     dmat = np.asarray(drows)
+                if mrows is not None:
+                    mmat = np.asarray(mrows)
+            if mrows is not None:
+                note_moe_window(mmat, win=win)
             if hrows is not None:
                 # mid-window NaN -> exact step attribution + (first
                 # incident) staged-path first-bad-layer bisect on the

@@ -157,6 +157,12 @@ class Module(BaseModule):
                 if name in cache:
                     cache_arr = cache[name]
                     if cache_arr is not arr:
+                        # into the bound array's own dtype: a float32
+                        # checkpoint handed to a float16 symbol must not
+                        # leave a second, float32 copy of every parameter
+                        # on the device beside the bound one
+                        if cache_arr.dtype != arr.dtype:
+                            cache_arr = cache_arr.astype(arr.dtype)
                         cache_arr.copyto(arr)
                 else:
                     if not allow_missing:

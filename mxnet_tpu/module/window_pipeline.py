@@ -96,6 +96,44 @@ def dynamics_sentinel():
     return _dynamics.step_stats if _dynamics.enabled() else None
 
 
+def moe_sentinel(symbol, aux_names):
+    """For a compiled window body whose graph holds routed expert layers:
+    ``fn(new_aux) -> (layers, len(MOE_STATS))``, this step's statistics as
+    each ``MoE`` node left them in its auxiliary state, stacked by the scan
+    so that the (W, layers, k) block rides the window's single host fetch;
+    :func:`note_moe_window` turns it into the ``moe.*`` counters. None
+    while telemetry is off or the graph has no such layer, leaving the
+    traced window byte-identical to the plain form."""
+    if not _tele.enabled():
+        return None
+    from ..ops.transformer import moe_stat_names
+    idx = [aux_names.index(n) for n in moe_stat_names(symbol)
+           if n in aux_names]
+    if not idx:
+        return None
+    return lambda new_aux: jnp.stack(
+        [new_aux[i].astype(jnp.float32) for i in idx])
+
+
+def note_moe_window(rows, win=None):
+    """The host side of :func:`moe_sentinel`: `rows` (W, layers, k) as
+    fetched. Counters ``moe.pairs`` (token-expert pairs computed by the
+    experts held here), ``moe.tokens`` (tokens routed, per layer) and
+    ``moe.dropped``; gauge ``moe.load_max_over_mean`` (the fullest held
+    expert's rows over the mean, worst layer and step of the window); and
+    one ``moe.window`` event with each step's pairs per layer."""
+    from ..ops.transformer import MOE_STATS
+    col = {n: rows[..., i] for i, n in enumerate(MOE_STATS)}
+    _tele.counter('moe.pairs').inc(int(col['pairs'].sum()))
+    _tele.counter('moe.tokens').inc(int(col['tokens'].sum()))
+    _tele.counter('moe.dropped').inc(int(col['dropped'].sum()))
+    _tele.gauge('moe.load_max_over_mean').set(
+        float(col['load_max_over_mean'].max()))
+    _tele.event('moe.window', win=win,
+                pairs=col['pairs'].astype(int).tolist(),
+                dropped=int(col['dropped'].sum()))
+
+
 def window_bisect(executor, data_names, label_names, snaps, is_train,
                   defer_fn=None):
     """First-bad-layer driver for a fused-window incident: returns

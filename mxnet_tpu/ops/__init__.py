@@ -14,6 +14,7 @@ from . import optimizer_ops       # noqa: F401
 from . import rnn_ops             # noqa: F401
 from . import contrib_ops         # noqa: F401
 from . import sparse_ops          # noqa: F401
+from . import transformer         # noqa: F401
 from . import legacy_ops          # noqa: F401  (alias/legacy names last)
 
 from .registry import register, get, list_ops, exists

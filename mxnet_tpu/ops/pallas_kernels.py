@@ -22,9 +22,11 @@ chip's compiler would refuse raises here, with its shapes, where it is
 bound for a TPU: at once for operands on one, and under a trace when the
 program is lowered for one (a program lowered for the CPU never meets
 the limit, and the interpreter has none). Nothing gives way to the jnp
-formulation quietly. Backward passes use jax.custom_vjp
-with a recompute strategy (jax.checkpoint-style), keeping kernels
-forward-only.
+formulation quietly. The row kernels (norms, softmax, cross-entropy) are
+forward-only: their backward passes are jax.custom_vjp rules in plain jnp.
+Attention has kernels in both directions (``attention_forward``,
+``attention_backward``): no [Tq, Tk] array exists in either, and
+``flash_attention`` / ``flash_attention_lse`` take the same backward.
 """
 import functools
 
@@ -326,8 +328,7 @@ def flash_attention_lse(q, k, v, causal=False, scale=None, block_q=128,
 
 
 def _flash_lse_ref(q, k, v, causal, scale):
-    """(out, lse) in plain jnp — the differentiable oracle for the
-    kernel's backward."""
+    """(out, lse) in plain jnp: the dense oracle of the tests."""
     s = jnp.einsum('bqhd,bkhd->bhqk', q * scale, k)
     if causal:
         Tq, Tk = q.shape[1], k.shape[1]
@@ -340,15 +341,15 @@ def _flash_lse_ref(q, k, v, causal, scale):
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k):
     s = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash_fwd_impl(q, k, v, causal, s, block_q, block_k), (q, k, v)
+    out, lse = _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)
+    return (out, lse), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(causal, scale, block_q, block_k, res, g):
-    q, k, v = res
+    q, k, v, out, lse = res
     s = scale if scale is not None else q.shape[-1] ** -0.5
-    _, vjp = jax.vjp(lambda q, k, v: _flash_lse_ref(q, k, v, causal, s),
-                     q, k, v)
-    return vjp(g)
+    return _flash_bwd_blockwise(q, k, v, out, lse, g[0], g[1], causal, s,
+                                block_q, block_k)
 
 
 flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -366,17 +367,39 @@ def _flash_ref(q, k, v, causal, scale):
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
     s = scale if scale is not None else q.shape[-1] ** -0.5
-    out, _ = _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)
-    return out, (q, k, v)
+    out, lse = _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, res, g):
-    # recompute-based backward (the flash paper's strategy; here via jax
-    # autodiff of the reference formulation — XLA fuses it blockwise)
-    q, k, v = res
+    q, k, v, out, lse = res
     s = scale if scale is not None else q.shape[-1] ** -0.5
-    _, vjp = jax.vjp(lambda q, k, v: _flash_ref(q, k, v, causal, s), q, k, v)
-    return vjp(g)
+    return _flash_bwd_blockwise(q, k, v, out, lse, g, None, causal, s,
+                                block_q, block_k)
+
+
+def _flash_bwd_blockwise(q, k, v, out, lse, g_out, g_lse, causal, scale,
+                         block_q, block_k):
+    """The backward of both flash entry points: the blockwise kernels of
+    :func:`attention_backward` (no [Tq, Tk] array in HBM), each head a
+    row of the batch. A cotangent of the log-sum-exp (ring attention's
+    merge weights depend on it) enters as a shift of the rows' ``delta``:
+    d lse_i / d s_ij = p_ij."""
+    B, Tq, H, D = q.shape
+    Tk = k.shape[1]
+    if B * H == 0 or Tq == 0:
+        return jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
+    rows = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
+        B * H, x.shape[1], D)
+    dq, dk, dv = attention_backward(
+        rows(q), rows(k), rows(v), rows(out), lse.reshape(B * H, 1, Tq),
+        rows(g_out), heads=1, kv_heads=1, causal=causal, window=0,
+        scale=scale, block_q=block_q, block_k=block_k,
+        g_lse=None if g_lse is None else g_lse.reshape(B * H, 1, Tq),
+        name='flash_attention')
+    back = lambda x, T: x.reshape(B, H, T, D).transpose(  # noqa: E731
+        0, 2, 1, 3)
+    return back(dq, Tq), back(dk, Tk), back(dv, Tk)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -563,3 +586,450 @@ def _xent_bwd(res, g):
 
 
 softmax_xent.defvjp(_xent_fwd, _xent_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Blockwise attention: forward and backward, causal, windowed, grouped-query
+# ---------------------------------------------------------------------------
+# q is [B, Tq, H * D] and k, v are [B, Tk, KV * D], the layout a projection
+# leaves them in: a block (1, blk, D) of head h is the column block h, so
+# no transpose is made and query head h reads key/value head h // (H / KV).
+# The grid's last axis walks only the key blocks a query block can see
+# (those under the diagonal, and inside the window): a key block outside is
+# neither fetched (the index map stays on the last block that was) nor
+# computed. The backward is two kernels of the same shape, one per output:
+# dq over the key blocks of a query block, dk/dv over the query blocks (and
+# the query heads of the group) of a key block. Nothing of size Tq x Tk
+# exists in either direction.
+
+def _attn_geometry(Tq, Tk, blk_q, blk_k, causal, window):
+    """Block counts, and for each side the (first, last) block of the other
+    side that it touches, as functions of a (traced or Python) block index;
+    `steps_*` is the static length of the grid's walking axis."""
+    offset = Tk - Tq
+    nq, nk = Tq // blk_q, Tk // blk_k
+
+    def keys_of(i):                         # key blocks of query block i
+        hi = ((i + 1) * blk_q - 1 + offset) // blk_k if causal else nk - 1
+        lo = (i * blk_q + offset - window + 1) if window else 0
+        lo = _imax(lo, 0) // blk_k
+        return lo, _imin(_imax(hi, 0), nk - 1)
+
+    def queries_of(j):                      # query blocks of key block j
+        lo = _imax(j * blk_k - offset, 0) // blk_q if causal else 0
+        hi = ((j + 1) * blk_k - 1 - offset + window - 1) // blk_q \
+            if window else nq - 1
+        return _imin(lo, nq - 1), _imin(_imax(hi, 0), nq - 1)
+
+    span = lambda f, n: max(  # noqa: E731
+        1, max(f(i)[1] - f(i)[0] + 1 for i in range(n)))
+    return nq, nk, keys_of, queries_of, span(keys_of, nq), \
+        span(queries_of, nk)
+
+
+def _attn_walk(Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window):
+    """_attn_geometry of the lengths as the kernels see them. Where an
+    axis had to be padded (a length that is no multiple of 8: tests and odd
+    shapes) every block walks every block of the other side; the mask,
+    which keys off the true lengths, stays exact."""
+    if not (pad_q or pad_k):
+        return _attn_geometry(Tq, Tk, blk_q, blk_k, causal, window)
+    nq, nk = (Tq + pad_q) // blk_q, (Tk + pad_k) // blk_k
+    return (nq, nk, lambda i: (0, nk - 1), lambda j: (0, nq - 1), nk, nq)
+
+
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
+def _imin(a, b):
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _visible(qi, kj, blk_q, blk_k, Tk, offset, causal, window):
+    """[blk_q, blk_k] mask of query block qi against key block kj. Every
+    block pays for it: taking the mask only where the diagonal or the
+    window's edge crosses a block (``lax.cond``) made the kernels a
+    quarter slower on the chip, not faster (PERF.md, PR 27)."""
+    rows = qi * blk_q + offset + jax.lax.broadcasted_iota(
+        jnp.int32, (blk_q, blk_k), 0)
+    cols = kj * blk_k + jax.lax.broadcasted_iota(
+        jnp.int32, (blk_q, blk_k), 1)
+    seen = cols < Tk            # keys past the end are padding
+    if causal:
+        seen &= cols <= rows
+    if window:
+        seen &= cols > rows - window
+    return seen
+
+
+def _dot(a, b, contract):
+    # the operands' own precision (one bf16 pass for bf16), whatever
+    # default the process has set: Mosaic refuses 'highest' on bf16
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
+
+
+def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
+                     geo, scale):
+    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    qi, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = keys_of(qi)
+
+    @pl.when(step == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
+                        window)
+        s = jnp.where(seen, _dot(q, k, ((1,), (1,))) * scale, _NEG)
+        m = m_s[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + _dot(p.astype(v.dtype), v,
+                                              ((1,), (0,)))
+        m_s[...] = m_new
+
+    @pl.when(step == steps - 1)
+    def _():
+        l = jnp.maximum(l_s[...], 1e-30)
+        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
+
+
+def _attn_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    acc_s, *, geo, scale):
+    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    qi, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = keys_of(qi)
+
+    @pl.when(step == 0)
+    def _():
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
+                        window)
+        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = p * (dp - delta_ref[0, 0])
+        acc_s[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
+
+    @pl.when(step == steps - 1)
+    def _():
+        dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
+
+
+def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dk_ref, dv_ref, dk_s, dv_s, *, geo, scale, group):
+    blk_q, blk_k, Tk, offset, causal, window, queries_of, steps = geo
+    kj, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = queries_of(kj)
+    walk = step % steps
+
+    @pl.when(step == 0)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(lo + walk <= hi)
+    def _():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        seen = _visible(lo + walk, kj, blk_q, blk_k, Tk, offset, causal,
+                        window)
+        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dv_s[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = p * (dp - delta_ref[0, 0])
+        dk_s[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
+
+    @pl.when(step == group * steps - 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _attn_blocks(Tq, Tk, block_q, block_k):
+    """(blk_q, blk_k, pad_q, pad_k): advisory sizes coerced to Mosaic-legal
+    ones; an axis with no legal divisor near the request is padded to a
+    multiple of its block (padded keys are masked, padded queries sliced
+    off)."""
+    def one(want, n):
+        blk = max(8, min(want, -(-n // 8) * 8))
+        blk -= blk % 8
+        return blk, (-n) % blk
+    blk_q, pad_q = one(block_q, Tq)
+    blk_k, pad_k = one(block_k, Tk)
+    return blk_q, blk_k, pad_q, pad_k
+
+
+def _pad_rows(x, pad):
+    return x if not pad else jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+
+
+def _attn_params(n_parallel):
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=(
+        ('parallel',) * n_parallel + ('arbitrary',)))
+
+
+def _vmem(shape):
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.VMEM(shape, jnp.float32)
+
+
+def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
+                      scale=None, block_q=512, block_k=512,
+                      name='attention'):
+    """(out [B, Tq, H * D], lse [B, H, Tq]) of grouped-query attention;
+    q [B, Tq, H * D], k and v [B, Tk, KV * D]. `window` w > 0: a query sees
+    only the w keys up to its own position. The kernel is named
+    ``<name>_fwd`` in a device trace."""
+    B, Tq, HD = q.shape
+    Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
+    scale = D ** -0.5 if scale is None else scale
+    blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    q, k, v = _pad_rows(q, pad_q), _pad_rows(k, pad_k), _pad_rows(v, pad_k)
+    nq, _, keys_of, _, steps, _ = _attn_walk(
+        Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
+    geo = (blk_q, blk_k, Tk, Tk - Tq, causal, window, keys_of, steps)
+
+    def kv_index(b, h, i, s):
+        lo, hi = keys_of(i)
+        return b, _imin(lo + s, hi), h // group
+
+    kernel = functools.partial(_attn_fwd_kernel, geo=geo, scale=scale)
+    out, lse = run_kernel(lambda interpret: pl.pallas_call(
+        kernel,
+        grid=(B, heads, nq, steps),
+        in_specs=[pl.BlockSpec((1, blk_q, D), lambda b, h, i, s: (b, i, h)),
+                  pl.BlockSpec((1, blk_k, D), kv_index),
+                  pl.BlockSpec((1, blk_k, D), kv_index)],
+        out_specs=[pl.BlockSpec((1, blk_q, D), lambda b, h, i, s: (b, i, h)),
+                   pl.BlockSpec((1, 1, blk_q, 1),
+                                lambda b, h, i, s: (b, h, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((B, heads, Tq + pad_q, 1),
+                                        jnp.float32)],
+        scratch_shapes=[_vmem((blk_q, 1)), _vmem((blk_q, 1)),
+                        _vmem((blk_q, D))],
+        compiler_params=_attn_params(3),
+        interpret=interpret, name=name + '_fwd'), q, k, v)
+    return out[:, :Tq], lse[:, :, :Tq, 0]
+
+
+def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
+                       causal=True, window=0, scale=None, block_q=512,
+                       block_k=512, g_lse=None, name='attention'):
+    """(dq, dk, dv) of :func:`attention_forward` from its output, its
+    log-sum-exp [B, H, Tq] and the output's cotangent. The kernels are
+    named ``<name>_dq`` and ``<name>_dkv`` in a device trace."""
+    B, Tq, HD = q.shape
+    Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
+    scale = D ** -0.5 if scale is None else scale
+    blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    # delta_i = sum_d dO_id O_id, per head: the softmax's own term
+    delta = jnp.sum((g_out.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(B, Tq, heads, D), axis=-1).transpose(0, 2, 1)
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)
+    col = lambda x: jnp.pad(  # noqa: E731
+        x.astype(jnp.float32), ((0, 0), (0, 0), (0, pad_q)))[..., None]
+    lse, delta = col(lse), col(delta)
+    q, g_out = _pad_rows(q, pad_q), _pad_rows(g_out, pad_q)
+    k, v = _pad_rows(k, pad_k), _pad_rows(v, pad_k)
+    nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
+        Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
+    base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
+
+    def kv_index(b, h, i, s):
+        lo, hi = keys_of(i)
+        return b, _imin(lo + s, hi), h // group
+
+    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, h, i, s: (b, i, h))
+    col_spec = pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, i, s: (b, h, i, 0))
+    dq = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_attn_dq_kernel, geo=base + (keys_of, ksteps),
+                          scale=scale),
+        grid=(B, heads, nq, ksteps),
+        in_specs=[q_spec, pl.BlockSpec((1, blk_k, D), kv_index),
+                  pl.BlockSpec((1, blk_k, D), kv_index), q_spec, col_spec,
+                  col_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[_vmem((blk_q, D))],
+        compiler_params=_attn_params(3),
+        interpret=interpret, name=name + '_dq'),
+        q, k, v, g_out, lse, delta)
+
+    def q_block(j, s):
+        lo, hi = queries_of(j)
+        return _imin(lo + s % qsteps, hi)
+
+    def q_index(b, g, j, s):
+        return b, q_block(j, s), g * group + s // qsteps
+
+    def qcol_index(b, g, j, s):
+        return b, g * group + s // qsteps, q_block(j, s), 0
+
+    k_spec = pl.BlockSpec((1, blk_k, D), lambda b, g, j, s: (b, j, g))
+    qw_spec = pl.BlockSpec((1, blk_q, D), q_index)
+    qcol_spec = pl.BlockSpec((1, 1, blk_q, 1), qcol_index)
+    dk, dv = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_attn_dkv_kernel,
+                          geo=base + (queries_of, qsteps), scale=scale,
+                          group=group),
+        grid=(B, kv_heads, nk, group * qsteps),
+        in_specs=[qw_spec, k_spec, k_spec, qw_spec, qcol_spec, qcol_spec],
+        out_specs=[k_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[_vmem((blk_k, D)), _vmem((blk_k, D))],
+        compiler_params=_attn_params(3),
+        interpret=interpret, name=name + '_dkv'),
+        q, k, v, g_out, lse, delta)
+    return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def blockwise_attention(q, k, v, heads, kv_heads, causal=True, window=0,
+                        scale=None, block_q=512, block_k=512,
+                        name='attention'):
+    """Grouped-query attention, causal and optionally windowed, forward
+    and backward by the blockwise kernels above. q [B, Tq, H * D], k and
+    v [B, Tk, KV * D]; returns [B, Tq, H * D]."""
+    return attention_forward(q, k, v, heads, kv_heads, causal, window,
+                             scale, block_q, block_k, name)[0]
+
+
+def _blockwise_fwd(q, k, v, heads, kv_heads, causal, window, scale, block_q,
+                   block_k, name):
+    out, lse = attention_forward(q, k, v, heads, kv_heads, causal, window,
+                                 scale, block_q, block_k, name)
+    return out, (q, k, v, out, lse)
+
+
+def _blockwise_bwd(heads, kv_heads, causal, window, scale, block_q, block_k,
+                   name, res, g):
+    q, k, v, out, lse = res
+    return attention_backward(q, k, v, out, lse, g, heads, kv_heads, causal,
+                              window, scale, block_q, block_k, name=name)
+
+
+blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matrix product: rows sorted by expert, one weight per expert
+# ---------------------------------------------------------------------------
+# The rows of x come sorted by group, each group padded to whole tiles of
+# GROUP_TILE rows (at least one), in a buffer of a static worst-case length.
+# `tile_group[t]` is tile t's group and `n_tiles[0]` how many tiles hold
+# rows: the tiles past them are neither fetched nor computed (their index
+# maps stay on the last tile that was), so the work follows the rows
+# present, not the buffer. Their rows of the output are not written.
+
+GROUP_TILE = 128
+
+
+def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, transpose_w):
+    @pl.when(pl.program_id(1) < n_tiles[0])
+    def _():
+        contract = ((1,), (1,)) if transpose_w else ((1,), (0,))
+        o_ref[...] = _dot(x_ref[...], w_ref[0], contract).astype(o_ref.dtype)
+
+
+def _tgmm_kernel(tile_group, n_tiles, x_ref, y_ref, o_ref):
+    t = pl.program_id(1)
+    live = t < n_tiles[0]
+    first = (t == 0) | (tile_group[t] != tile_group[jnp.maximum(t - 1, 0)])
+
+    @pl.when(live & first)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(live)
+    def _():
+        o_ref[0] += _dot(x_ref[...], y_ref[...], ((0,), (0,)))
+
+
+def _col_block(n, want=512):
+    for b in (want, 256, 128):
+        if n % b == 0:
+            return b
+    return n
+
+
+def _gmm_grid(num_scalars, grid, in_specs, out_specs):
+    from jax.experimental.pallas import tpu as pltpu
+    return dict(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=num_scalars, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary')))
+
+
+def grouped_matmul(x, w, tile_group, n_tiles, transpose_w=False,
+                   name='grouped_matmul'):
+    """out[r] = x[r] @ w[group of r's tile] (or its transpose): x [R, K],
+    w [G, K, N] ([G, N, K] with `transpose_w`), out [R, N] in x's dtype.
+    Rows of tiles past ``n_tiles[0]`` are left unwritten."""
+    R, K = x.shape
+    N = w.shape[1] if transpose_w else w.shape[2]
+    tm, tn = GROUP_TILE, _col_block(N)
+
+    def tile(t, n_tiles):
+        return jnp.minimum(t, n_tiles[0] - 1)
+
+    w_block = (1, tn, K) if transpose_w else (1, K, tn)
+
+    def w_index(n, t, tile_group, n_tiles):
+        g = tile_group[tile(t, n_tiles)]
+        return (g, n, 0) if transpose_w else (g, 0, n)
+
+    kernel = functools.partial(_gmm_kernel, transpose_w=transpose_w)
+    return run_kernel(lambda interpret: pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
+        interpret=interpret, name=name,
+        **_gmm_grid(2, (N // tn, R // tm), [
+            pl.BlockSpec((tm, K), lambda n, t, tg, nt: (tile(t, nt), 0)),
+            pl.BlockSpec(w_block, w_index)],
+            pl.BlockSpec((tm, tn), lambda n, t, tg, nt: (tile(t, nt), n)))),
+        tile_group, n_tiles, x, w)
+
+
+def grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
+                      name='grouped_matmul_dw'):
+    """out[g] = sum over the rows r of group g of x[r]^T y[r]: x [R, K],
+    y [R, N], out [G, K, N] float32. Every group owns at least one tile."""
+    R, K = x.shape
+    N = y.shape[1]
+    tm, tn = GROUP_TILE, _col_block(N, 256)
+
+    def tile(t, n_tiles):
+        return jnp.minimum(t, n_tiles[0] - 1)
+
+    return run_kernel(lambda interpret: pl.pallas_call(
+        _tgmm_kernel,
+        out_shape=jax.ShapeDtypeStruct((groups, K, N), jnp.float32),
+        interpret=interpret, name=name,
+        **_gmm_grid(2, (N // tn, R // tm), [
+            pl.BlockSpec((tm, K), lambda n, t, tg, nt: (tile(t, nt), 0)),
+            pl.BlockSpec((tm, tn), lambda n, t, tg, nt: (tile(t, nt), n))],
+            pl.BlockSpec((1, K, tn),
+                         lambda n, t, tg, nt: (tg[tile(t, nt)], 0, n)))),
+        tile_group, n_tiles, x, y)

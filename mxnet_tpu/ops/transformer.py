@@ -1,0 +1,410 @@
+"""Decoder-block ops: RMSNorm, rotary positions, grouped-query attention
+(causal, optionally windowed, with a per-head output gate), the gated MLP
+and a routed expert layer with a shared expert.
+
+Each is a pure JAX function like every op of the registry; gradients come
+from autodiff or, where a kernel runs, from a ``custom_vjp``. Activations
+are ``[batch, sequence, features]``. Weights of projections are the
+registry's ``FullyConnected`` layout, ``(out, in)``; the experts held are
+one array per projection, ``(experts_held, in, out)``.
+
+Precision: matrix products take their operands as they come (bfloat16 in
+a ``float16`` symbol under MXTPU_F16_AS_BF16) and accumulate in float32;
+RMSNorm statistics, rotary angles, attention's softmax, the gate's
+sigmoid, router logits and router softmax are float32.
+
+Where an op has a kernel (``ops/pallas_kernels.py``: attention forward and
+backward by blocks, the grouped product of the experts), operands on a TPU
+take it and others take the plain jnp form, unless MXTPU_FORCE_PALLAS=1
+routes every platform through the kernel (interpreted off the TPU), as for
+the other registry ops.
+"""
+import math
+
+import jax
+import jax.numpy as jnp
+
+from . import pallas_kernels as pk
+from .registry import register
+
+__all__ = ['MOE_STATS', 'moe_stat_names']
+
+
+def _matmul(x, w):
+    """x [..., in] times w (out, in): float32 accumulation, float32 out."""
+    return jax.lax.dot_general(x, w, (((x.ndim - 1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm
+# ---------------------------------------------------------------------------
+
+@register('RMSNorm', input_names=['data', 'gamma'],
+          param_defaults={'eps': 1e-6})
+def _rms_norm(attrs, x, gamma):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis; statistics in
+    float32, x's dtype out."""
+    eps = float(attrs.get('eps', 1e-6))
+
+    def plain(x, gamma):
+        x32 = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+        return (x32 * inv * gamma.astype(jnp.float32)).astype(x.dtype)
+
+    return pk.dispatch(lambda x, g: pk.fused_rmsnorm(x, g, eps), plain,
+                       x, gamma)
+
+
+# ---------------------------------------------------------------------------
+# Rotary positions
+# ---------------------------------------------------------------------------
+
+def rope_inv_freq(head_dim, base, rotary_dim, scaling, factor, original_len,
+                  beta_fast, beta_slow, attention_factor):
+    """(inverse frequencies of the rotated pairs, factor on cos and sin).
+    ``scaling`` 'default': the plain form; 'yarn': interpolated and
+    extrapolated frequencies blended between the two correction
+    dimensions (Peng et al., arXiv:2309.00071)."""
+    dim = int(rotary_dim) or int(head_dim)
+    pos = base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    if scaling == 'default':
+        return 1.0 / pos, 1.0
+    if scaling != 'yarn':
+        raise ValueError('RotaryEmbedding: scaling %r' % (scaling,))
+
+    def correction_dim(rotations):
+        return dim * math.log(original_len / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    inv = ramp / (factor * pos) + (1.0 - ramp) / pos
+    if not attention_factor:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return inv, float(attention_factor)
+
+
+@register('RotaryEmbedding',
+          param_defaults={'num_heads': 1, 'base': 10000.0, 'rotary_dim': 0,
+                          'scaling': 'default', 'factor': 1.0,
+                          'original_max_position': 0, 'beta_fast': 32.0,
+                          'beta_slow': 1.0, 'attention_factor': 0.0})
+def _rotary(attrs, x):
+    """Rotary positions 0..T-1 on x [B, T, num_heads * D]: the first
+    ``rotary_dim`` dimensions of each head (all of them if 0) are rotated,
+    half against half; the rest pass through."""
+    B, T, HD = x.shape
+    H = int(attrs.get('num_heads', 1))
+    D = HD // H
+    inv, scale = rope_inv_freq(
+        D, float(attrs.get('base', 10000.0)), int(attrs.get('rotary_dim', 0)),
+        str(attrs.get('scaling', 'default')), float(attrs.get('factor', 1.0)),
+        float(attrs.get('original_max_position', 0) or 1),
+        float(attrs.get('beta_fast', 32.0)),
+        float(attrs.get('beta_slow', 1.0)),
+        float(attrs.get('attention_factor', 0.0)))
+    half = inv.shape[0]
+    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+    cos = (jnp.cos(angle) * scale)[None, :, None, :]
+    sin = (jnp.sin(angle) * scale)[None, :, None, :]
+    x4 = x.reshape(B, T, H, D).astype(jnp.float32)
+    x1, x2, rest = x4[..., :half], x4[..., half:2 * half], x4[..., 2 * half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
+                          axis=-1)
+    return out.astype(x.dtype).reshape(B, T, HD)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention
+# ---------------------------------------------------------------------------
+
+def _dense_attention(q, k, v, heads, kv_heads, window):
+    """The plain form: one dense masked product. For small shapes off the
+    TPU."""
+    B, T, HD = q.shape
+    D, group = HD // heads, heads // kv_heads
+    q5 = q.reshape(B, T, kv_heads, group, D).astype(jnp.float32)
+    k4 = k.reshape(B, T, kv_heads, D).astype(jnp.float32)
+    v4 = v.reshape(B, T, kv_heads, D).astype(jnp.float32)
+    s = jnp.einsum('bqkgd,bskd->bkgqs', q5, k4) * D ** -0.5
+    rows, cols = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+    seen = cols <= rows
+    if window:
+        seen &= cols > rows - window
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    out = jnp.einsum('bkgqs,bskd->bqkgd', p, v4)
+    return out.reshape(B, T, HD).astype(q.dtype)
+
+
+@register('GroupedQueryAttention',
+          input_names=['query', 'key', 'value', 'gate'],
+          param_defaults={'num_heads': 1, 'num_kv_heads': 1, 'window': 0,
+                          'gated': False},
+          optional_inputs={'gate': 'gated'})
+def _gqa(attrs, q, k, v, gate=None):
+    """Causal attention of query [B, T, H * D] over key and value
+    [B, T, KV * D]: query head i reads key/value head i // (H / KV),
+    scores q k^T / sqrt(D), position t sees s <= t and, with ``window``
+    w > 0, s > t - w. ``gated``: head i's output is multiplied by
+    sigmoid(gate[..., i]), gate [B, T, H]. Returns [B, T, H * D].
+
+    On a TPU the blockwise kernels run it, forward and backward, named
+    ``attention_window_*`` or ``attention_full_*`` in a device trace; no
+    [T, T] array exists and a windowed layer walks only the blocks inside
+    its window."""
+    H, KV = int(attrs['num_heads']), int(attrs['num_kv_heads'])
+    window = int(attrs.get('window', 0))
+    # blocks: a window is walked in blocks of half its size (so that the
+    # blocks outside it are at most a third of those walked), full
+    # attention in blocks of 512
+    block = max(128, min(512, window // 2)) if window else 512
+    name = 'attention_window' if window else 'attention_full'
+
+    def fused(q, k, v):
+        return pk.blockwise_attention(q, k, v, H, KV, True, window, None,
+                                      block, block, name)
+
+    def plain(q, k, v):
+        return _dense_attention(q, k, v, H, KV, window)
+
+    out = pk.dispatch(fused, plain, q, k, v)
+    if gate is not None and attrs.get('gated', False):
+        B, T, HD = out.shape
+        g = jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+        out = (out.reshape(B, T, H, HD // H).astype(jnp.float32) * g) \
+            .astype(out.dtype).reshape(B, T, HD)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Gated MLP
+# ---------------------------------------------------------------------------
+
+def _gated_mlp(x, w1, w3, w2):
+    """(silu(x w1^T) * (x w3^T)) w2^T, weights (out, in)."""
+    a = jax.nn.silu(_matmul(x, w1)) * _matmul(x, w3)
+    return _matmul(a.astype(x.dtype), w2).astype(x.dtype)
+
+
+@register('GatedMLP', input_names=['data', 'w1_weight', 'w3_weight',
+                                   'w2_weight'],
+          param_defaults={'hidden': 0})
+def _gated_mlp_op(attrs, x, w1, w3, w2):
+    """The SwiGLU feed-forward layer: (silu(x W1) * (x W3)) W2 with W1, W3
+    of (hidden, in) and W2 of (in, hidden); no bias."""
+    return _gated_mlp(x, w1, w3, w2)
+
+
+# ---------------------------------------------------------------------------
+# Routed experts
+# ---------------------------------------------------------------------------
+
+# what the layer writes into its ``stats`` auxiliary state each step
+MOE_STATS = ('pairs', 'tokens', 'dropped', 'load_max', 'load_max_over_mean')
+
+
+def moe_stat_names(symbol):
+    """Names of the auxiliary states that the MoE nodes of `symbol` write
+    their per-step statistics into, in graph order."""
+    out = []
+    for node in symbol._topo():
+        if not node.is_variable() and node.op == 'MoE':
+            src, _ = node.inputs[-1]
+            out.append(src.name)
+    return out
+
+
+def _gmm(x, w, tile_group, n_tiles, transpose_w=False):
+    def plain(x, w, tile_group, n_tiles):
+        rows = jnp.repeat(tile_group, pk.GROUP_TILE)
+        form = 'rk,rnk->rn' if transpose_w else 'rk,rkn->rn'
+        return jnp.einsum(form, x, w[rows],
+                          preferred_element_type=jnp.float32).astype(x.dtype)
+
+    def fused(x, w, tile_group, n_tiles):
+        return pk.grouped_matmul(x, w, tile_group, n_tiles, transpose_w,
+                                 name='moe_expert_matmul')
+
+    return pk.dispatch(fused, plain, x, w, tile_group, n_tiles)
+
+
+def _gmm_dw(x, y, tile_group, n_tiles, groups):
+    def plain(x, y, tile_group, n_tiles):
+        rows = jnp.repeat(tile_group, pk.GROUP_TILE)
+        live = jnp.arange(x.shape[0]) < n_tiles[0] * pk.GROUP_TILE
+        onehot = (rows[:, None] == jnp.arange(groups)[None]) & live[:, None]
+        return jnp.einsum('rk,rn,rg->gkn', x, y, onehot.astype(x.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def fused(x, y, tile_group, n_tiles):
+        return pk.grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
+                                    name='moe_expert_matmul_dw')
+
+    return pk.dispatch(fused, plain, x, y, tile_group, n_tiles)
+
+
+def _dispatch_plan(idx, held, offset):
+    """Where each token-expert pair that lands on an expert held here goes
+    in the sorted buffer. idx [T, k] expert ids. Returns (dest [T, k] row
+    of each pair, R for a pair routed elsewhere; row_pair [R] flat pair of
+    each row, T * k for a padding row; tile_group [R / tile]; n_tiles [1];
+    counts [held])."""
+    T, k = idx.shape
+    tm = pk.GROUP_TILE
+    P = T * k
+    R = -(-(T * min(k, held) + held * tm) // tm) * tm
+    local = idx.reshape(-1) - offset
+    here = (local >= 0) & (local < held)
+    local = jnp.where(here, local, 0)
+    onehot = (local[:, None] == jnp.arange(held)[None]) & here[:, None]
+    rank = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    counts = rank[-1]
+    rank = jnp.take_along_axis(rank, local[:, None], axis=1)[:, 0] - 1
+    size = jnp.maximum(-(-counts // tm), 1) * tm    # whole tiles, one at least
+    end = jnp.cumsum(size)
+    dest = jnp.where(here, (end - size)[local] + rank, R)
+    row_pair = jnp.full((R,), P, jnp.int32).at[dest].set(
+        jnp.arange(P, dtype=jnp.int32), mode='drop')
+    tile_group = jnp.clip(jnp.searchsorted(
+        end, jnp.arange(R // tm, dtype=jnp.int32) * tm, side='right'),
+        0, held - 1).astype(jnp.int32)
+    n_tiles = (end[-1:] // tm).astype(jnp.int32)
+    return dest.reshape(T, k), row_pair, tile_group, n_tiles, counts
+
+
+def _rows(x, index):
+    """x[index] with 0 rows for an index past the end."""
+    return jnp.take(x, index, axis=0, mode='fill', fill_value=0)
+
+
+def _live_rows(R, n_tiles):
+    return (jnp.arange(R) < n_tiles[0] * pk.GROUP_TILE)[:, None]
+
+
+def _experts_forward(x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
+                     n_tiles):
+    k = dest.shape[1]
+    xs = _rows(x, row_pair // k)
+    live = _live_rows(xs.shape[0], n_tiles)
+    h1 = _gmm(xs, w1, tile_group, n_tiles)
+    h3 = _gmm(xs, w3, tile_group, n_tiles)
+    act = jnp.where(live, jax.nn.silu(h1.astype(jnp.float32))
+                    * h3.astype(jnp.float32), 0.0).astype(x.dtype)
+    ys = jnp.where(live, _gmm(act, w2, tile_group, n_tiles), 0)
+    out = jnp.zeros(x.shape, jnp.float32)
+    for j in range(k):      # a token's pairs, one gather each
+        out += w_pairs[:, j:j + 1] * _rows(ys, dest[:, j]).astype(jnp.float32)
+    return out.astype(x.dtype), (xs, h1, h3, act, ys)
+
+
+@jax.custom_vjp
+def _experts(x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
+    """sum over a token's pairs held here of w_pair * E(x): x [T, d],
+    w_pairs [T, k] float32, the rest from :func:`_dispatch_plan`. Both
+    directions gather; nothing scatters activations."""
+    return _experts_forward(x, w_pairs, w1, w3, w2, dest, row_pair,
+                            tile_group, n_tiles)[0]
+
+
+def _experts_fwd(x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
+                 n_tiles):
+    out, kept = _experts_forward(x, w_pairs, w1, w3, w2, dest, row_pair,
+                                 tile_group, n_tiles)
+    return out, (kept, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
+                 n_tiles)
+
+
+def _experts_bwd(res, g):
+    (xs, h1, h3, act, ys), w_pairs, w1, w3, w2, dest, row_pair, \
+        tile_group, n_tiles = res
+    T, k = dest.shape
+    held = w1.shape[0]
+    live = _live_rows(xs.shape[0], n_tiles)
+    g32 = g.astype(jnp.float32)
+    d_pairs = jnp.stack(
+        [jnp.sum(_rows(ys, dest[:, j]).astype(jnp.float32) * g32, axis=-1)
+         for j in range(k)], axis=1)
+    w_row = _rows(w_pairs.reshape(-1), row_pair)
+    dys = (w_row[:, None] * _rows(g32, row_pair // k)).astype(g.dtype)
+    dact = _gmm(dys, w2, tile_group, n_tiles, transpose_w=True)
+    dw2 = _gmm_dw(act, dys, tile_group, n_tiles, held)
+    h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
+    dactf = jnp.where(live, dact.astype(jnp.float32), 0.0)
+    sig = jax.nn.sigmoid(h1f)
+    dh1 = jnp.where(live, dactf * h3f * sig * (1.0 + h1f * (1.0 - sig)),
+                    0.0).astype(g.dtype)
+    dh3 = jnp.where(live, dactf * h1f * sig, 0.0).astype(g.dtype)
+    dxs = _gmm(dh1, w1, tile_group, n_tiles, transpose_w=True) \
+        .astype(jnp.float32) \
+        + _gmm(dh3, w3, tile_group, n_tiles, transpose_w=True) \
+        .astype(jnp.float32)
+    dxs = jnp.where(live, dxs, 0.0)
+    dw1 = _gmm_dw(xs, dh1, tile_group, n_tiles, held)
+    dw3 = _gmm_dw(xs, dh3, tile_group, n_tiles, held)
+    dx = jnp.zeros((T, xs.shape[1]), jnp.float32)
+    for j in range(k):
+        dx += _rows(dxs, dest[:, j])
+    return (dx.astype(g.dtype), d_pairs.astype(w_pairs.dtype),
+            dw1.astype(w1.dtype), dw3.astype(w3.dtype), dw2.astype(w2.dtype),
+            None, None, None, None)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+@register('MoE',
+          input_names=['data', 'router_weight', 'experts_w1_weight',
+                       'experts_w3_weight', 'experts_w2_weight',
+                       'shared_w1_weight', 'shared_w3_weight',
+                       'shared_w2_weight', 'stats'],
+          param_defaults={'num_experts': 0, 'num_experts_per_tok': 1,
+                          'experts_held': 0, 'expert_offset': 0,
+                          'norm_topk_prob': True, 'routed_scaling': 1.0,
+                          'hidden': 0, 'shared_hidden': 0},
+          num_outputs=2, num_visible_outputs=1, mutate_inputs={8: 1},
+          aux_inputs=('stats',))
+def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats):
+    """A routed expert layer that holds ``experts_held`` of ``num_experts``
+    experts, those from ``expert_offset`` on, and a shared expert.
+
+    The router scores every token over all ``num_experts`` (softmax,
+    float32), takes the ``num_experts_per_tok`` largest, divides their
+    weights by their sum (``norm_topk_prob``) and multiplies by
+    ``routed_scaling``. Of a token's pairs only those on an expert held
+    here are computed: they are sorted by expert into a buffer of the
+    static worst-case length (every token on every expert held), each
+    expert's rows padded to whole tiles, and a grouped product whose work
+    follows the tiles present runs the gated MLP of each expert on its
+    rows. No pair is dropped, whatever the imbalance. What the experts
+    held elsewhere would add is left out; the shared expert is added once.
+
+    Weights of the experts held: w1, w3 (held, in, hidden), w2 (held,
+    hidden, in). ``stats`` is an auxiliary state that receives this step's
+    MOE_STATS: pairs computed here, tokens routed, pairs dropped (the
+    pairs routed here less the rows placed: 0), the fullest expert's rows
+    and that over the mean.
+    """
+    held, offset = int(attrs['experts_held']), int(attrs['expert_offset'])
+    k = int(attrs['num_experts_per_tok'])
+    lead, d = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, d)
+    probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
+    w_pairs, idx = jax.lax.top_k(probs, k)
+    if attrs.get('norm_topk_prob', True):
+        w_pairs = w_pairs / jnp.sum(w_pairs, axis=-1, keepdims=True)
+    w_pairs = w_pairs * float(attrs.get('routed_scaling', 1.0))
+    dest, row_pair, tile_group, n_tiles, counts = _dispatch_plan(
+        idx, held, offset)
+    out = _experts(x2, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
+                   n_tiles)
+    out = out + _gated_mlp(x2, s1, s3, s2)
+    pairs = jnp.sum(counts).astype(jnp.float32)
+    placed = jnp.sum(row_pair < dest.size).astype(jnp.float32)
+    load_max = jnp.max(counts).astype(jnp.float32)
+    new_stats = jnp.stack([
+        pairs, jnp.float32(x2.shape[0]), pairs - placed, load_max,
+        load_max * held / jnp.maximum(pairs, 1.0)]).astype(stats.dtype)
+    return out.reshape(lead + (d,)), jax.lax.stop_gradient(new_stats)

@@ -109,6 +109,38 @@ def _ln_params(attrs, in_shapes):
     return {'gamma': (data[ax],), 'beta': (data[ax],)}
 
 
+@param_shape_hook('RMSNorm')
+def _rms_params(attrs, in_shapes):
+    data = in_shapes[0]
+    return {'gamma': (data[-1],)} if data else {}
+
+
+@param_shape_hook('GatedMLP')
+def _gated_mlp_params(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None or not attrs.get('hidden'):
+        return {}
+    d, h = data[-1], int(attrs['hidden'])
+    return {'w1_weight': (h, d), 'w3_weight': (h, d), 'w2_weight': (d, h)}
+
+
+@param_shape_hook('MoE')
+def _moe_params(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None or not attrs.get('hidden'):
+        return {}
+    from ..ops.transformer import MOE_STATS
+    d, h = data[-1], int(attrs['hidden'])
+    sh = int(attrs.get('shared_hidden') or h)
+    held = int(attrs['experts_held'])
+    return {'router_weight': (int(attrs['num_experts']), d),
+            'experts_w1_weight': (held, d, h),
+            'experts_w3_weight': (held, d, h),
+            'experts_w2_weight': (held, h, d),
+            'shared_w1_weight': (sh, d), 'shared_w3_weight': (sh, d),
+            'shared_w2_weight': (d, sh), 'stats': (len(MOE_STATS),)}
+
+
 @param_shape_hook('Embedding')
 def _emb_params(attrs, in_shapes):
     return {'weight': (int(attrs['input_dim']), int(attrs['output_dim']))}

@@ -61,6 +61,19 @@ def _compile(fn, one_chip, *specs):
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
+def _attention(q, k, v, g, heads, window, block):
+    out, lse = pk.attention_forward(q, k, v, heads, 8, True, window, None,
+                                    block, block, 'attention')
+    return pk.attention_backward(q, k, v, out, lse, g, heads, 8, True,
+                                 window, None, block, block,
+                                 name='attention')
+
+
+def _attention_specs(heads):
+    return [((1, 8192, heads * 128), BF16), ((1, 8192, 1024), BF16),
+            ((1, 8192, 1024), BF16), ((1, 8192, heads * 128), BF16)]
+
+
 KERNELS = [
     ('flash_fwd_b8_t1024', lambda q, k, v: pk.flash_attention(q, k, v, True),
      [((8, 1024, 8, 128), BF16)] * 3),
@@ -86,6 +99,26 @@ KERNELS = [
      [((4096, 65536), F32), ((65536,), F32), ((65536,), F32)]),
     ('xent_4096x50304', pk.softmax_xent,
      [((4096, 50304), BF16), ((4096,), I32)]),
+    # the decoder block's kernels at Laguna-S-2.1's widths (head 128, 8
+    # key/value heads, one 8192-token sequence): sliding layers have 72
+    # query heads and a window of 512, full layers 48
+    ('attention_window_fwd_bwd', lambda q, k, v, g: _attention(
+        q, k, v, g, 72, 512, 256), _attention_specs(72)),
+    ('attention_full_fwd_bwd', lambda q, k, v, g: _attention(
+        q, k, v, g, 48, 0, 512), _attention_specs(48)),
+    # the held experts' grouped product: 8 experts of 3072 x 1024, the
+    # static worst-case buffer of 8192 x 8 + 8 x 128 rows
+    ('moe_expert_matmul', lambda x, w, t, n: pk.grouped_matmul(x, w, t, n),
+     [((66560, 3072), BF16), ((8, 3072, 1024), BF16), ((520,), I32),
+      ((1,), I32)]),
+    ('moe_expert_matmul_transposed', lambda x, w, t, n: pk.grouped_matmul(
+        x, w, t, n, transpose_w=True),
+     [((66560, 3072), BF16), ((8, 1024, 3072), BF16), ((520,), I32),
+      ((1,), I32)]),
+    ('moe_expert_matmul_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
+        x, y, t, n, 8),
+     [((66560, 3072), BF16), ((66560, 1024), BF16), ((520,), I32),
+      ((1,), I32)]),
 ]
 
 

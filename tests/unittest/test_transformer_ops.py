@@ -1,0 +1,512 @@
+"""Decoder-block ops (ops/transformer.py), their kernels and the Laguna
+builder against the plain reference (benchmark/reference/laguna.py, loaded
+by path: there is one copy), at small widths on the CPU in float32:
+
+- every op, forward and gradient, through the plain form and through the
+  kernels (MXTPU_FORCE_PALLAS=1: the Pallas interpreter);
+- windowed and full attention by blocks at lengths that are no multiple
+  of the block;
+- the share test: the parts that four shares of four experts give, the
+  shared expert counted once, add up to the uncut layer;
+- no pair dropped when every token picks the same experts;
+- the whole model through ``Module.fit`` takes the fused window and after
+  three steps matches the reference's losses and parameter change;
+- telemetry off leaves the lowered window unchanged, on yields ``moe.*``.
+"""
+import importlib.util
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import telemetry
+from mxnet_tpu.config import flags
+from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops import registry
+from mxnet_tpu.ops.transformer import MOE_STATS, moe_stat_names
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, path))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load('benchmark/reference/laguna.py', 'laguna_reference')
+builder = _load('examples/transformer/symbols/laguna.py', 'laguna_symbol')
+
+ROPE = {
+    'full_attention': {
+        'rope_theta': 500000, 'rope_type': 'yarn', 'factor': 128,
+        'original_max_position_embeddings': 16, 'beta_slow': 1,
+        'beta_fast': 32, 'attention_factor': 1.4852030263919618,
+        'partial_rotary_factor': 0.5},
+    'sliding_attention': {'rope_type': 'default', 'rope_theta': 10000,
+                          'partial_rotary_factor': 1}}
+CFG = dict(
+    hidden_size=64, head_dim=16, num_key_value_heads=2, vocab_size=96,
+    num_hidden_layers=5, num_attention_heads=4,
+    num_attention_heads_per_layer=[4, 6, 6, 6, 4],
+    layer_types=['full_attention'] + ['sliding_attention'] * 3
+    + ['full_attention'],
+    mlp_layer_types=['dense'] + ['sparse'] * 4, sliding_window=8,
+    rms_norm_eps=1e-6, intermediate_size=128, moe_intermediate_size=32,
+    shared_expert_intermediate_size=32, num_experts=16,
+    num_experts_per_tok=3, experts_held=16, expert_offset=0,
+    norm_topk_prob=True, moe_routed_scaling_factor=2.5,
+    rope_parameters=ROPE)
+T, D, KV, d = 32, 16, 2, 64
+PATHS = ['plain', 'kernel']
+
+
+@pytest.fixture
+def path(request, monkeypatch):
+    """'plain': the jnp form the CPU takes; 'kernel': the Pallas kernels,
+    interpreted."""
+    if request.param == 'kernel':
+        monkeypatch.setenv('MXTPU_FORCE_PALLAS', '1')
+    else:
+        monkeypatch.delenv('MXTPU_FORCE_PALLAS', raising=False)
+    flags.reload('MXTPU_FORCE_PALLAS')
+    yield request.param
+    monkeypatch.delenv('MXTPU_FORCE_PALLAS', raising=False)
+    flags.reload('MXTPU_FORCE_PALLAS')
+
+
+def _rand(seed, *shape, scale=1.0):
+    return jnp.asarray(np.random.RandomState(seed).randn(*shape) * scale,
+                       jnp.float32)
+
+
+def _close(got, want, tol=2e-5):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=tol,
+                               atol=tol * max(1.0, np.abs(want).max()))
+
+
+def _both(f, g, *args):
+    """Outputs and gradients (of a fixed random cotangent) of f and g."""
+    (of, vf), (og, vg) = jax.vjp(f, *args), jax.vjp(g, *args)
+    _close(of, og)
+    w = _rand(99, *og.shape)
+    for a, b in zip(vf(w), vg(w)):
+        _close(a, b)
+
+
+def op(name, **attrs):
+    fn = registry.get(name).fn
+    return lambda *arrays: fn(attrs, *arrays)
+
+
+# -- the ops against the reference ------------------------------------------
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+def test_rms_norm(path):
+    x, g = _rand(0, 2, T, d), 1.0 + 0.1 * _rand(1, d)
+    _both(op('RMSNorm', eps=1e-6), lambda x, g: ref.rms_norm(x, g, 1e-6),
+          x, g)
+
+
+@pytest.mark.parametrize('kind', sorted(ROPE))
+def test_rotary_embedding(kind):
+    x = _rand(2, 1, T, 6 * D)
+    cos, sin = ref.rope_tables(ROPE[kind], D, T)
+    attrs = builder._rope_attrs(ROPE[kind], D)
+    _both(op('RotaryEmbedding', num_heads=6, **attrs),
+          lambda x: ref.apply_rope(x.reshape(T, 6, D), cos, sin)
+          .reshape(1, T, 6 * D), x)
+
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+@pytest.mark.parametrize('heads,window', [(4, 0), (6, 8)])
+def test_grouped_query_attention(path, heads, window):
+    q, k, v = (_rand(3, 1, T, heads * D), _rand(4, 1, T, KV * D),
+               _rand(5, 1, T, KV * D))
+    gate = _rand(6, 1, T, heads)
+
+    def want(q, k, v, gate):
+        o = ref.attention(q[0].reshape(T, heads, D), k[0].reshape(T, KV, D),
+                          v[0].reshape(T, KV, D), window)
+        return (o * jax.nn.sigmoid(gate[0])[:, :, None]) \
+            .reshape(1, T, heads * D)
+
+    _both(op('GroupedQueryAttention', num_heads=heads, num_kv_heads=KV,
+             window=window, gated=True), want, q, k, v, gate)
+
+
+@pytest.mark.parametrize('length,window,block', [
+    (37, 8, 8), (37, 0, 16), (50, 8, 16), (64, 0, 16), (33, 16, 32),
+    (64, 8, 16), (40, 0, 8)])
+def test_blockwise_attention_against_the_dense_mask(length, window, block):
+    """Forward and backward kernels, lengths that are and are not a
+    multiple of the block."""
+    H = 6
+    q, k, v = (_rand(7, 1, length, H * D), _rand(8, 1, length, KV * D),
+               _rand(9, 1, length, KV * D))
+    _both(lambda q, k, v: pk.blockwise_attention(
+        q, k, v, H, KV, True, window, None, block, block, 'test'),
+        lambda q, k, v: ref.attention(
+            q[0].reshape(length, H, D), k[0].reshape(length, KV, D),
+            v[0].reshape(length, KV, D), window).reshape(1, length, H * D),
+        q, k, v)
+
+
+@pytest.mark.parametrize('length,window,block', [
+    (300, 8, 64), (130, 100, 16), (97, 40, 16), (256, 8, 64)])
+def test_reference_attention_over_the_span_is_the_dense_masked_one(
+        length, window, block):
+    """On a windowed layer the reference multiplies a block of queries
+    with the `window + block` keys that end with the block; against every
+    key (one block of queries as long as the sequence) it gives the same
+    output and gradients."""
+    H = 6
+    q, k, v, c = (_rand(11, length, H, D), _rand(12, length, KV, D),
+                  _rand(13, length, KV, D), _rand(14, length, H, D))
+    assert window + block < length      # the span path is the one taken
+
+    def loss(q_block):
+        return lambda q, k, v: jnp.sum(
+            ref.attention(q, k, v, window, q_block=q_block) * c)
+
+    with jax.default_matmul_precision('highest'):
+        got = jax.value_and_grad(loss(block), (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(loss(length), (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_windowed_attention_walks_only_its_window():
+    """The grid's walking axis is as long as the window needs, not as the
+    sequence: blocks outside are never visited."""
+    *_, full_steps, _ = pk._attn_geometry(8192, 8192, 512, 512, True, 0)
+    *_, win_steps, win_q = pk._attn_geometry(8192, 8192, 256, 256, True, 512)
+    assert full_steps == 16 and win_steps == 3 and win_q == 3
+
+
+def test_gated_mlp():
+    x = _rand(10, 2, T, d)
+    w1, w3, w2 = (_rand(11, 128, d, scale=0.1), _rand(12, 128, d, scale=0.1),
+                  _rand(13, d, 128, scale=0.1))
+    _both(op('GatedMLP'), lambda x, a, b, c: ref.gated_mlp(x, a.T, b.T, c.T),
+          x, w1, w3, w2)
+
+
+def _moe_params(seed, held):
+    return {
+        'm_router_weight': _rand(seed, 16, d, scale=0.3),
+        'm_experts_w1_weight': _rand(seed + 1, held, d, 32, scale=0.1),
+        'm_experts_w3_weight': _rand(seed + 2, held, d, 32, scale=0.1),
+        'm_experts_w2_weight': _rand(seed + 3, held, 32, d, scale=0.1),
+        'm_shared_w1_weight': _rand(seed + 4, 32, d, scale=0.1),
+        'm_shared_w3_weight': _rand(seed + 5, 32, d, scale=0.1),
+        'm_shared_w2_weight': _rand(seed + 6, d, 32, scale=0.1)}
+
+
+_MOE_ORDER = ('router', 'experts_w1', 'experts_w3', 'experts_w2',
+              'shared_w1', 'shared_w3', 'shared_w2')
+
+
+def _moe_op(held, offset):
+    fn = op('MoE', num_experts=16, num_experts_per_tok=3, experts_held=held,
+            expert_offset=offset, norm_topk_prob=True, routed_scaling=2.5)
+    stats = jnp.zeros((len(MOE_STATS),), jnp.float32)
+    return lambda x, *w: fn(x, *w, stats)
+
+
+def _moe_weights(p):
+    return [p['m_%s_weight' % n] for n in _MOE_ORDER]
+
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+@pytest.mark.parametrize('held,offset', [(16, 0), (4, 4)])
+def test_moe_layer(path, held, offset):
+    x, p = _rand(20, T, d), _moe_params(21, held)
+    names = ['m_%s_weight' % n for n in _MOE_ORDER]
+
+    def want(x, *w):
+        return ref.moe_layer(dict(zip(names, w)), 'm', x, CFG, held,
+                             offset)[0]
+
+    _both(lambda x, *w: _moe_op(held, offset)(x, *w)[0], want, x,
+          *_moe_weights(p))
+    stats = dict(zip(MOE_STATS, np.asarray(
+        _moe_op(held, offset)(x, *_moe_weights(p))[1])))
+    assert stats['pairs'] == int(ref.moe_layer(p, 'm', x, CFG, held,
+                                               offset)[1])
+    assert stats['tokens'] == T and stats['dropped'] == 0
+
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+def test_shares_of_the_expert_layer_add_up(path):
+    """model-configs guide, section 4: the partial results of all 4 shares
+    of 4 experts each, the shared expert counted once, are the uncut
+    reference's layer; and their pairs are all T * top_k of them."""
+    x, whole = _rand(30, T, d), _moe_params(31, 16)
+    shared = op('GatedMLP')(x, *_moe_weights(whole)[4:])
+    total, pairs = -3 * shared, 0       # every share adds it: once is owed
+    for share in range(4):
+        p = dict(whole)
+        for n in ('w1', 'w3', 'w2'):
+            key = 'm_experts_%s_weight' % n
+            p[key] = whole[key][4 * share:4 * share + 4]
+        out, stats = _moe_op(4, 4 * share)(x, *_moe_weights(p))
+        total = total + out
+        pairs += int(stats[0])
+    _close(total, ref.moe_layer(whole, 'm', x, CFG, 16, 0)[0])
+    assert pairs == T * 3
+
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+def test_no_pair_dropped_when_every_token_picks_the_same_experts(path):
+    x = jnp.abs(_rand(40, T, d)) + 0.1
+    p = _moe_params(41, 4)
+    router = np.zeros((16, d), np.float32)
+    router[0], router[1], router[2] = 3.0, 2.0, 1.0    # all rows: 0, 1, 2
+    p['m_router_weight'] = jnp.asarray(router)
+    out, stats = _moe_op(4, 0)(x, *_moe_weights(p))
+    stats = dict(zip(MOE_STATS, np.asarray(stats)))
+    assert stats['pairs'] == 3 * T and stats['dropped'] == 0
+    assert stats['load_max'] == T
+    _close(out, ref.moe_layer(p, 'm', x, CFG, 4, 0)[0])
+
+
+def test_dispatch_plan_buffer_holds_the_worst_case():
+    """Every token on every expert held: the sorted buffer is exactly
+    full, every pair has a row of its own."""
+    idx = jnp.tile(jnp.arange(3)[None], (T, 1))
+    dest, row_pair, tile_group, n_tiles, counts = \
+        mx.ops.transformer._dispatch_plan(idx, 3, 0)
+    assert int(counts.sum()) == 3 * T
+    rows = np.asarray(dest).reshape(-1)
+    assert len(set(rows.tolist())) == 3 * T and rows.max() < len(row_pair)
+    assert int(n_tiles[0]) == 3 and sorted(set(
+        np.asarray(tile_group)[:3].tolist())) == [0, 1, 2]
+
+
+# -- the whole model ---------------------------------------------------------
+
+def _model(cfg, seed=0):
+    shapes = ref.param_shapes(cfg)
+    rng = np.random.RandomState(seed)
+    return {n: np.ones(s, np.float32) if n.endswith('gamma') else
+            (rng.randn(*s) / np.sqrt(s[1])).astype(np.float32)
+            for n, s in shapes.items()}
+
+
+def test_builder_shapes_are_the_references():
+    sym = builder.get_symbol(CFG)
+    args, outs, auxs = sym.infer_shape(data=(2, T), softmax_label=(2, T))
+    shapes = dict(zip(sym.list_arguments(), args))
+    want = ref.param_shapes(CFG)
+    assert set(shapes) - {'data', 'softmax_label'} == set(want)
+    assert all(tuple(shapes[n]) == tuple(s) for n, s in want.items())
+    assert outs == [(2 * T, CFG['vocab_size'])]
+    assert moe_stat_names(sym) == sym.list_auxiliary_states()
+    assert auxs == [(len(MOE_STATS),)] * 4
+
+
+@pytest.mark.parametrize('remat', [True, False])
+def test_model_forward_and_gradient(remat):
+    cfg = dict(CFG, experts_held=8, expert_offset=4)
+    sym = builder.get_symbol(cfg, remat=remat)
+    p = _model(cfg)
+    rng = np.random.RandomState(1)
+    tok, lab = rng.randint(0, 96, (2, T)), rng.randint(0, 96, (2, T))
+    ex = sym.simple_bind(mx.cpu(), data=(2, T), softmax_label=(2, T))
+    for n, v in p.items():
+        ex.arg_dict[n][:] = v
+    ex.arg_dict['data'][:] = tok.astype(np.float32)
+    ex.arg_dict['softmax_label'][:] = lab.astype(np.float32)
+    out = ex.forward(is_train=True)[0].asnumpy()
+    ex.backward()
+    loss = -np.log(out[np.arange(2 * T), lab.reshape(-1)]).mean()
+    want, pairs, g = ref.loss_and_grad(
+        {k: jnp.asarray(v) for k, v in p.items()}, tok, lab, cfg)
+    assert abs(loss - float(want)) < 1e-5
+    for n in p:
+        _close(ex.grad_dict[n].asnumpy(), g[n], tol=1e-4)
+    got = [int(ex.aux_dict[n].asnumpy()[0])
+           for n in sym.list_auxiliary_states()]
+    assert got == [int(v) for v in pairs]
+
+
+def test_mirrored_blocks_are_stages_of_their_own():
+    """Each block's nodes are one recomputed stage; the embedding, the
+    last norm, the head and the loss are not mirrored."""
+    from mxnet_tpu.executor import _GraphProgram
+    plan = _GraphProgram(builder.get_symbol(CFG))._mirror_plan()
+    stages = [p for p in plan if p[1] is not None]
+    assert len(stages) == CFG['num_hidden_layers']
+    assert all(len(leaves) <= 2 for _, _, leaves in stages)
+    assert not [p for p in _GraphProgram(
+        builder.get_symbol(CFG, remat=False))._mirror_plan()
+        if p[1] is not None]
+
+
+def _fit(cfg, steps, monkeypatch, lr=0.05):
+    monkeypatch.setenv('MXTPU_FIT_STEPS_PER_CALL', str(steps))
+    sym = builder.get_symbol(cfg)
+    p = _model(cfg, seed=3)
+    rng = np.random.RandomState(4)
+    toks = rng.randint(0, 96, (steps, T + 1))
+    it = mx.io.NDArrayIter(toks[:, :T].astype(np.float32),
+                           toks[:, 1:].astype(np.float32), batch_size=1,
+                           label_name='softmax_label')
+    sums = []
+
+    def note(param):
+        ce = param.eval_metric.metrics[0]
+        sums.append(float(ce.sum_metric))
+
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod.fit(it, eval_metric=['ce', 'acc'], optimizer='sgd',
+            optimizer_params={'learning_rate': lr, 'momentum': 0.9,
+                              'wd': 0.0},
+            arg_params={k: mx.nd.array(v) for k, v in p.items()},
+            aux_params={n: mx.nd.zeros((len(MOE_STATS),))
+                        for n in sym.list_auxiliary_states()},
+            num_epoch=1, batch_end_callback=note)
+    return mod, p, toks, np.diff([0.0] + sums) / T
+
+
+def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
+    cfg = dict(CFG, experts_held=4)
+    mod, p, toks, losses = _fit(cfg, 3, monkeypatch)
+    assert mod.__dict__.get('_fused_fit_cache') is not None
+    assert mod.__dict__['_fused_fit_cache'][1].window == 3
+    w = {k: jnp.asarray(v) for k, v in p.items()}
+    mom = {k: jnp.zeros_like(v) for k, v in w.items()}
+    want = []
+    for i in range(3):
+        loss, _, g = ref.loss_and_grad(w, toks[i:i + 1, :T],
+                                       toks[i:i + 1, 1:], cfg)
+        want.append(float(loss))
+        w, mom = ref.sgd_momentum_step(w, mom, g, 0.05, 0.9)
+    np.testing.assert_allclose(losses, want, rtol=1e-4)
+    got = mod.get_params()[0]
+    for n in p:
+        _close(got[n].asnumpy() - p[n], np.asarray(w[n]) - p[n], tol=2e-3)
+
+
+def _window_text(mod):
+    """The lowered text of the module's fused window."""
+    from mxnet_tpu import random as _random
+    loop = mod.__dict__['_fused_fit_cache'][1]
+    fn = loop._build_program(loop._static_attrs(), None)
+    params, states, aux, gaccs = loop._snapshot()
+    lr, wd = loop._sample_window_lr()
+    stack = jnp.zeros((loop.window, 1, T), jnp.float32)
+    return fn.lower(params, states, aux, gaccs, (stack,), (stack,),
+                    _random.next_key(), lr, wd).as_text()
+
+
+def _reload_telemetry():
+    for f in ('MXTPU_TELEMETRY', 'MXTPU_TELEMETRY_PATH'):
+        flags.reload(f)
+    telemetry._reset_for_tests()
+
+
+def test_telemetry_off_leaves_the_window_unchanged_on_yields_moe_counters(
+        tmp_path, monkeypatch):
+    cfg = dict(CFG, experts_held=4)
+
+    def run(on):
+        telemetry._reset_for_tests()
+        if on:
+            monkeypatch.setenv('MXTPU_TELEMETRY', '1')
+            monkeypatch.setenv('MXTPU_TELEMETRY_PATH',
+                               str(tmp_path / 't.jsonl'))
+        else:
+            monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
+        _reload_telemetry()
+        mod = _fit(cfg, 3, monkeypatch)[0]
+        return _window_text(mod), telemetry.snapshot()
+
+    try:
+        off, snap_off = run(False)
+        on, snap_on = run(True)
+        off_again, _ = run(False)
+        assert off == off_again and on != off
+        assert not [k for k in snap_off['counters'] if k.startswith('moe.')]
+        c = snap_on['counters']
+        assert c['moe.tokens'] == 3 * T * 4 and c['moe.dropped'] == 0
+        assert 0 < c['moe.pairs'] <= 3 * T * 3 * 4
+        assert snap_on['gauges']['moe.load_max_over_mean'] >= 1.0
+    finally:
+        monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
+        _reload_telemetry()
+
+
+def test_flash_attention_backward_is_the_blockwise_one():
+    """flash_attention and flash_attention_lse differentiate through the
+    same kernels (no dense vjp): gradients match the dense oracle, the
+    log-sum-exp's cotangent included."""
+    q, k, v = _rand(50, 2, 24, 3, D), _rand(51, 2, 40, 3, D), \
+        _rand(52, 2, 40, 3, D)
+
+    def f(q, k, v):
+        o, lse = pk.flash_attention_lse(q, k, v, True, None, 8, 8)
+        return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+
+    def g(q, k, v):
+        o, lse = pk._flash_lse_ref(q, k, v, True, D ** -0.5)
+        return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+
+    for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v),
+                    jax.grad(g, (0, 1, 2))(q, k, v)):
+        _close(a, b, tol=1e-4)
+
+
+def test_seeded_weights_are_bfloat16_values_whatever_the_threads():
+    """``benchmark/weights_lm.py``: every leaf from its own generator (the
+    number of threads changes nothing), std 1/sqrt(fan_in), rounded to
+    the nearest bfloat16 as ``lax.reduce_precision`` rounds."""
+    weights_lm = _load('benchmark/weights_lm.py', 'weights_lm')
+    shapes = {'a_weight': (64, 256), 'b_experts_w1_weight': (4, 256, 32),
+              'embed_weight': (96, 64), 'n_gamma': (64,), 'm_stats': (5,)}
+    one = weights_lm.make_params(shapes, 4000000007, threads=1)
+    three = weights_lm.make_params(shapes, 4000000007, threads=3)
+    other = weights_lm.make_params(shapes, 4000000008)
+    raw = weights_lm.make_params(shapes, 4000000007, round_bf16=False)
+    for n in shapes:
+        np.testing.assert_array_equal(one[n], three[n])
+        np.testing.assert_array_equal(one[n], np.asarray(
+            jax.lax.reduce_precision(jnp.asarray(raw[n]), 8, 7)))
+    assert not np.array_equal(one['a_weight'], other['a_weight'])
+    assert abs(one['a_weight'].std() * 16 - 1) < 0.05
+    assert abs(one['b_experts_w1_weight'].std() * 16 - 1) < 0.05
+    assert abs(one['embed_weight'].std() - 1) < 0.05
+    assert np.all(one['n_gamma'] == 1) and not one['m_stats'].any()
+
+
+def test_threaded_gaps_are_the_convnet_comparisons_numbers(monkeypatch):
+    """``compare_lm_training.gaps`` takes its norms and distances a chunk
+    and a leaf at a time on threads; the numbers are those of
+    ``compare_training.gaps``."""
+    monkeypatch.syspath_prepend(REPO)
+    from benchmark import compare_lm_training, compare_training
+    monkeypatch.setattr(compare_lm_training, 'CHUNK', 1000)
+    rng = np.random.default_rng(3)
+    want = {'w%d' % i: rng.standard_normal((37, 11 * (i + 1)))
+            .astype(np.float32) * 10.0 ** -i for i in range(5)}
+    near = {n: v * (1 + 0.01 * i) + 1e-3 * rng.standard_normal(v.shape)
+            .astype(np.float32) for i, (n, v) in enumerate(want.items())}
+    got = ([1.0, 2.0, 3.0, 4.0], near, want)
+    ref_side = ([1.0, 2.0, 3.003, 4.0], want, near)
+    mine, my_leaves = compare_lm_training.gaps(got, ref_side)
+    theirs, their_leaves = compare_training.gaps(got, ref_side)
+    assert my_leaves == their_leaves
+    for k, v in theirs.items():
+        assert mine[k] == pytest.approx(v, rel=1e-9), k
+
