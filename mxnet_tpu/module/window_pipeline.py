@@ -18,12 +18,23 @@ consumers instead of two private copies:
   window crosses in a single ``device_put`` (not W per-batch
   transfers, each a host dispatch of its own); on an SPMD
   mesh the stacks land dp-sharded over the batch axis
-  (:meth:`executor_group.SPMDExecutorGroup.window_sharding`). An
-  identity cache short-circuits synthetic/benchmark iterators that
-  yield the same arrays every batch;
+  (:meth:`executor_group.SPMDExecutorGroup.window_sharding`). The host
+  stack lives in a window-sized buffer of
+  :func:`~mxnet_tpu.ndarray.ndarray._host_buffer`, each batch written
+  into its slot: memory that an earlier window's stack was written to
+  wherever one of its size is idle, because the first touch of new
+  pages, not the copy, is what a 2.5 GB stack costs. A stack's memory
+  is idle, and may be written again, when nothing refers to it any
+  more: this pipeline's identity cache, a transfer still reading it, a
+  cpu-backed array that adopted it. That is decided by reference, not
+  by counting windows: one buffer serves every window where the
+  transfer has ended by the next stack, two rotate where something
+  holds the stack a window longer; they outlive an epoch. An identity
+  cache short-circuits synthetic/benchmark iterators that yield the
+  same arrays every batch;
 - a one-thread upload pool (:meth:`WindowPipeline.start_put`): window
   k+1's stack + transfer run on a side thread while window k computes
-  on device — np.stack's memcpy and the transfer both release the
+  on device — the stack's copies and the transfer both release the
   GIL, so the overlap is real even on a one-core host;
 - the in-graph metric plans (:func:`plan_metric`): sufficient
   statistics for Accuracy / TopKAccuracy / CrossEntropy (and
@@ -37,7 +48,7 @@ import jax.numpy as jnp
 
 from .. import metric as metric_mod
 from .. import telemetry as _tele
-from ..ndarray.ndarray import from_jax
+from ..ndarray.ndarray import _POOLED_FROM, _host_buffer, from_jax
 
 __all__ = ['WindowPipeline', 'window_size', 'module_platform', 'plan_metric', 'host_wrap',
            'registered_jit', 'health_sentinel', 'dynamics_sentinel',
@@ -329,13 +340,17 @@ class WindowPipeline:
     window stacks. ``span_prefix`` names the telemetry spans
     ('fused_fit' / 'fused_eval'): ``.draw`` around a window's draws with
     one ``.next`` per batch inside it, ``.stack`` around the host-side
-    ``np.stack`` and ``.upload`` around the ``device_put`` calls, the
+    stacking (``reused``: how many of its ``bytes`` went into memory
+    written before) and ``.upload`` around the ``device_put`` calls, the
     last two on the thread that does the work (the side thread with the
     pool). Each carries ``win``, the window's sequence number since this
     object was built, which the owning loop hands on to its own
-    ``.put`` / ``.dispatch`` / ``.fetch`` spans. The owning loop object
-    lives across fit()/score() calls, so the upload pool it carries
-    does too.
+    ``.put`` / ``.dispatch`` / ``.fetch`` spans. Counters
+    ``<prefix>.stacks_reused`` / ``.stacks_new``: the windows whose large
+    host stacks all went into memory written before, and those of which
+    one at least went into new memory (a window with no host stack of a
+    pooled size counts in neither). The owning loop object lives across
+    fit()/score() calls, so the upload pool it carries does too.
     """
 
     def __init__(self, window, device_fn, mesh=None, span_prefix='window',
@@ -405,6 +420,15 @@ class WindowPipeline:
         it behind window k's compute) — returning a cached device array
         would hand the program an already-deleted donated buffer.
 
+        A host stack is written, batch by batch, into a buffer of
+        :func:`_host_buffer`'s: an idle one of its size where there is
+        one, else new memory. It becomes idle again by itself when the
+        last reference to it goes: the cache's (when the next window
+        misses, or at :meth:`drop_cache`), the transfer's, a cpu-backed
+        array's whose memory it is. Nothing here counts windows to
+        decide that. What is too large to be kept idle (`_idle_limit`)
+        is new memory every time, as ``np.stack``'s result was.
+
         The ``.stack`` and ``.upload`` spans open here, on the thread
         that runs this (the side thread under :meth:`start_put`'s
         pool): ``.stack`` not on a cache hit, ``.upload`` wherever
@@ -427,6 +451,11 @@ class WindowPipeline:
             if not self.donate:
                 return self._dev_cache
             return upload(*self._dev_cache)
+        # a miss: what the cache holds can never hit again, so it lets go
+        # before this window's stacks take their memory and not after, and
+        # the stack of the window before is idle as soon as its transfer
+        # has ended (one buffer then serves every window)
+        self.drop_cache()
         key = arrays
 
         def _on_host(a):
@@ -437,23 +466,39 @@ class WindowPipeline:
             except Exception:  # noqa: BLE001 — tracer/abstract array
                 return False
 
-        def build(parts):
-            # host-resident parts (defer-mode uint8 batches and their
-            # labels) stack on the host so the whole window crosses to
-            # the device in _realize()'s ONE device_put, not W per-batch
-            # transfers. Device-resident parts stay unstacked in the
-            # cache entry (the stacked device buffer is donate-consumed,
-            # but the sources remain valid to restack from).
-            if all(_on_host(p) for p in parts):
-                return ('host', np.stack([np.asarray(p) for p in parts]))
-            return ('dev', tuple(parts))
-
+        # host-resident parts (defer-mode uint8 batches and their
+        # labels) stack on the host so the whole window crosses to the
+        # device in _realize()'s ONE device_put, not W per-batch
+        # transfers. Device-resident parts stay unstacked in the cache
+        # entry (the stacked device buffer is donate-consumed, but the
+        # sources remain valid to restack from). A host stack's memory
+        # is taken here and nothing of it touched, so that the span can
+        # say how much of it has been written before.
+        n_data = len(snaps[0][0])
+        columns = [[ds[i] for ds, _, _, _ in snaps] for i in range(n_data)] \
+            + [[ls[i] for _, ls, _, _ in snaps]
+               for i in range(len(snaps[0][1]))]
+        entries, reuse = [], []     # reuse: of each stack of a pooled size
+        for parts in columns:
+            if not all(_on_host(p) for p in parts):
+                entries.append(('dev', tuple(parts)))
+                continue
+            out, reused = _host_buffer(
+                (len(parts),) + tuple(parts[0].shape),
+                np.result_type(*(p.dtype for p in parts)))
+            entries.append(('host', out))
+            if out.nbytes >= _POOLED_FROM:
+                reuse.append(out.nbytes if reused else 0)
         with _tele.span(self._span_stack, self._span, win=win,
-                        bytes=nbytes):
-            data_e = [build([ds[i] for ds, _, _, _ in snaps])
-                      for i in range(len(snaps[0][0]))]
-            label_e = [build([ls[i] for _, ls, _, _ in snaps])
-                       for i in range(len(snaps[0][1]))]
+                        bytes=nbytes, reused=sum(reuse)):
+            for (kind, out), parts in zip(entries, columns):
+                if kind == 'host':
+                    # each batch into its slot of the window's buffer
+                    np.stack([np.asarray(p) for p in parts], out=out)
+        if reuse:
+            _tele.counter(self._span + ('.stacks_reused' if all(reuse)
+                                        else '.stacks_new')).inc()
+        data_e, label_e = entries[:n_data], entries[n_data:]
         data_stack, label_stack = upload(data_e, label_e)
         self._dev_cache_key = key
         self._dev_cache = (data_e, label_e) if self.donate \

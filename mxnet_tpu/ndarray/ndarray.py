@@ -14,6 +14,7 @@ ThreadedVar dependency-queue machinery (threaded_engine.h:111-213) with no
 loss of semantics.
 """
 import functools
+import itertools
 import math
 import os
 import re
@@ -668,58 +669,108 @@ def _narrowed(dtype):
     return d
 
 
-# Host memory of dead NDArrays, by size in bytes, kept to be written again.
-# First touch of new memory, not the copy into it, is what a large host
-# array costs (a 77 MB batch on the v5e's host: 70 ms of page faults, 4-30
-# ms of copying; PERF.md, PR 26), and what the allocator gives back to the
-# system it has to fault in again for the next batch. The reference pools
-# its storage for the same reason (src/storage/pooled_storage_manager.h).
+# Host memory of dead host arrays, by size in bytes, kept to be written
+# again: {bytes: [(stamp, buffer), ...]}, the one idle longest first. First
+# touch of new memory, not the copy into it, is what a large host array
+# costs (a 77 MB batch on the v5e's host: 70 ms of page faults, 4-30 ms of
+# copying; a window's 2.47 GB stack: 2.7 s new, 0.12 s written before;
+# PERF.md, PRs 26 and 28), and what the allocator gives back to the system
+# it has to fault in again for the next batch. The reference pools its
+# storage for the same reason (src/storage/pooled_storage_manager.h).
 _idle_buffers = {}
+_idle_stamp = itertools.count()
 _POOLED_FROM = 1 << 20      # smaller buffers cost under a millisecond new
-_IDLE_LIMIT = os.sysconf('SC_PHYS_PAGES') * os.sysconf('SC_PAGE_SIZE') // 8
+
+
+def _idle_bytes():
+    return sum(n * len(bufs) for n, bufs in list(_idle_buffers.items()))
+
+
+def _idle_limit(idle):
+    """How many bytes the idle buffers may hold, `idle` of which they hold
+    now: half of what the machine could hand out if they held nothing. It
+    is read when a buffer comes back, so a machine that has filled up
+    meanwhile keeps less. Like the reference's pool, which keeps what it
+    has until the device runs short, and unlike a share of the installed
+    memory, it fits what a job really cycles through: a fit holds two
+    windows' batches and a window's stack or two (7.4-10 GB of the 47 GB
+    beside one v5e), all of them idle at an epoch's end."""
+    try:
+        with open('/proc/meminfo', 'rb') as f:
+            for line in f:
+                if line.startswith(b'MemAvailable:'):
+                    return (int(line.split()[1]) * 1024 + idle) // 2
+    except OSError:
+        pass
+    return os.sysconf('SC_PHYS_PAGES') * os.sysconf('SC_PAGE_SIZE') // 8
 
 
 def _keep_for_reuse(raw):
     """Called when the last user of `raw`'s memory has let go of it (on
-    whichever thread that happens: list and dict operations only)."""
-    if raw.nbytes > _IDLE_LIMIT:
+    whichever thread that happens, inside any allocation: so no lock, and
+    each step one list or dict operation that may find another thread has
+    been there first). Over the limit the buffers idle longest go, one at
+    a time: a size nobody asks for any more cannot hold the room for
+    good, and a working set that fits is never let go of as a whole."""
+    limit = _idle_limit(_idle_bytes())
+    if raw.nbytes > limit:
         return
-    idle = sum(n * len(bufs) for n, bufs in list(_idle_buffers.items()))
-    if idle + raw.nbytes > _IDLE_LIMIT:
-        # start again, so that sizes nobody asks for any more cannot
-        # hold the room for good
-        _idle_buffers.clear()
-    _idle_buffers.setdefault(raw.nbytes, []).append(raw)
+    while _idle_bytes() + raw.nbytes > limit:
+        waiting = [(bufs[0][0], n) for n, bufs in list(_idle_buffers.items())
+                   if bufs]
+        if not waiting:
+            break
+        n = min(waiting)[1]
+        try:
+            bufs = _idle_buffers[n]
+            bufs.pop(0)
+            if not bufs:
+                del _idle_buffers[n]
+        except (KeyError, IndexError):
+            pass                # another thread took it meanwhile
+    _idle_buffers.setdefault(raw.nbytes, []).append((next(_idle_stamp), raw))
+
+
+def _host_buffer(shape, dtype):
+    """``(array, reused)``: an uninitialised host array of `shape` and
+    `dtype` in memory that nothing else refers to, 64-byte aligned, and
+    whether that memory has been written before (an idle buffer of its
+    size) or is new.
+
+    Aligned, because ``jax.device_put`` onto a cpu device then adopts it
+    as the array's own memory without a second copy; for a chip it is what
+    the one transfer reads, behind the call. So the memory may be written
+    again only when nothing reads it any more, and that is decided by
+    reference, never by counting: when the last view of a large buffer is
+    gone (the caller's, a cpu-backed array's, a transfer's that has ended)
+    it goes to the idle buffers for the next array of its size."""
+    dtype = np.dtype(dtype)
+    nbytes = math.prod(shape) * dtype.itemsize
+    try:
+        raw, reused = _idle_buffers[nbytes + 64].pop()[1], True
+    except (KeyError, IndexError):
+        raw, reused = np.empty(nbytes + 64, np.uint8), False
+    off = -raw.ctypes.data % 64
+    # numpy hands a view of a view the first array as its base, and stops
+    # at one whose own base is no array: so whatever reads this memory,
+    # through whichever view of the result, holds `flat`, and nothing but
+    # the list of idle buffers holds `raw`
+    flat = np.frombuffer(memoryview(raw)[off:off + nbytes], dtype)
+    if nbytes >= _POOLED_FROM:
+        weakref.finalize(flat, _keep_for_reuse, raw).atexit = False
+    return flat.reshape(shape), reused
 
 
 def _host_copy(src, dtype, shape=None):
     """A copy of host data `src` as `dtype` (broadcast to `shape`), taken
-    now, in numpy memory that nothing else refers to.
+    now, in memory of :func:`_host_buffer`'s.
 
     The copy is ours because the backend's is not taken at the call:
     ``jax.device_put`` of a numpy array returns at once and reads the
     source afterwards, or on the CPU backend keeps a 64-byte-aligned
-    source as the array's own memory. The buffer is therefore aligned
-    so: onto a cpu device it is adopted without a second copy, and for
-    a chip it is what the one transfer reads. When nothing reads a large
-    buffer any more it is kept for the next copy of its size."""
+    source as the array's own memory."""
     src = np.asarray(src)
-    dtype = np.dtype(dtype)
-    shape = src.shape if shape is None else tuple(shape)
-    nbytes = math.prod(shape) * dtype.itemsize
-    try:
-        raw = _idle_buffers[nbytes + 64].pop()
-    except (KeyError, IndexError):
-        raw = np.empty(nbytes + 64, np.uint8)
-    off = -raw.ctypes.data % 64
-    # numpy hands a view of a view the first array as its base, and stops
-    # at one whose own base is no array: so whatever reads this memory,
-    # through whichever view of `out`, holds `flat`, and nothing but the
-    # list of idle buffers holds `raw`
-    flat = np.frombuffer(memoryview(raw)[off:off + nbytes], dtype)
-    if nbytes >= _POOLED_FROM:
-        weakref.finalize(flat, _keep_for_reuse, raw).atexit = False
-    out = flat.reshape(shape)
+    out, _ = _host_buffer(src.shape if shape is None else tuple(shape), dtype)
     np.copyto(out, src, casting='unsafe')
     return out
 

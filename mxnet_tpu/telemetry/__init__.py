@@ -102,9 +102,12 @@ the head of ``Module.fit``), ``fit.batch`` / ``fit.dispatch`` /
 attribute ``win`` (the window's sequence number: the spans of one
 window share it; ``.next`` is one ``next(iterator)`` inside ``.draw``;
 ``.stack`` and ``.upload``, with ``bytes``, run on the side thread
-``mxtpu-window-put`` when the prefetch pool is on) + ``fused_fit.build``
-+ counter ``fused_fit.windows`` + gauge ``fused_fit.steps_per_call``
-(compiled window loop), ``eval.dispatch|metric|fetch`` + counter
+``mxtpu-window-put`` when the prefetch pool is on; ``.stack`` also says
+how many of them were ``reused``, written into host memory written
+before) + ``fused_fit.build`` + counters ``fused_fit.windows``,
+``fused_fit.stacks_reused`` and ``fused_fit.stacks_new`` (windows whose
+large host stacks all reused memory, or not) + gauge
+``fused_fit.steps_per_call`` (compiled window loop), ``eval.dispatch|metric|fetch`` + counter
 ``eval.batches`` + gauge ``eval_samples_per_sec`` (per-batch
 score/predict loops), ``fused_eval.draw|next|stack|upload|put|dispatch|
 fetch|build`` (the same set, from the shared window pipeline) + counter

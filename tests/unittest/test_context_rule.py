@@ -174,23 +174,45 @@ def test_memory_of_a_dead_host_array_is_written_again(monkeypatch):
     assert idle() == 0 and c._data.unsafe_buffer_pointer() == where
     np.testing.assert_array_equal(c.asnumpy(), src + 2)
     np.testing.assert_array_equal(b.asnumpy(), src + 1)
-    # a small array's memory is not kept; at the limit the idle memory
-    # is let go, and what is larger than the limit is never kept
+    # a small array's memory is not kept; at the limit the buffer idle
+    # longest goes and the others stay, and what is larger than the limit
+    # is never kept and costs the idle ones nothing
     small = mx.nd.array(src[:1000])
     del small, c
     settle()
     assert idle() == 1
-    monkeypatch.setattr(nda, '_IDLE_LIMIT', 3 << 20)
-    kept = nda._idle_buffers[src.nbytes + 64][0]
+    monkeypatch.setattr(nda, '_idle_limit', lambda idle: 5 << 20)
+    e, f = mx.nd.array(src + 3), mx.nd.array(src + 4)
+    assert idle() == 0
     del b
     settle()
-    assert idle() == 1 and nda._idle_buffers[src.nbytes + 64][0] is not kept
-    monkeypatch.setattr(nda, '_IDLE_LIMIT', 1 << 20)
+    oldest = nda._idle_buffers[src.nbytes + 64][0][1]
+    del e
+    settle()
+    assert idle() == 2
+    del f
+    settle()
+    kept = [raw for _, raw in nda._idle_buffers[src.nbytes + 64]]
+    assert len(kept) == 2 and not any(raw is oldest for raw in kept)
+    monkeypatch.setattr(nda, '_idle_limit', lambda idle: 1 << 20)
     d = mx.nd.array(src)
-    assert idle() == 0
+    assert idle() == 1
     del d
     settle()
-    assert idle() == 0
+    assert idle() == 1
+
+
+def test_the_idle_limit_follows_what_the_machine_has_left():
+    """Half of what the machine could hand out if the idle buffers held
+    nothing: what they hold is part of that, so holding more does not by
+    itself shrink the limit, and it never passes the installed memory."""
+    import os
+    from mxnet_tpu.ndarray import ndarray as nda
+    installed = os.sysconf('SC_PHYS_PAGES') * os.sysconf('SC_PAGE_SIZE')
+    empty = nda._idle_limit(0)
+    assert 0 < empty <= installed // 2
+    # two readings a moment apart, on a machine that other tests share
+    assert abs(nda._idle_limit(1 << 30) - (1 << 29) - empty) < installed // 20
 
 
 def test_recycled_memory_is_never_shared_by_live_arrays():
