@@ -13,7 +13,8 @@ batch 32, bf16 compute with fp32 master weights):
 2. ``save_checkpoint`` -> ``ServingEngine.from_checkpoint(..., mx.tpu(0))``
    -> ``warmup`` -> ``DynamicBatcher`` -> ``serving.http.start_server`` and
    HTTP requests of 1, 3 and 32 rows, compared with ``Module.predict``;
-3. the five Pallas kernels that ops/nn.py and bench.py route to on a TPU,
+3. the five Pallas kernels that ops/nn.py, ops/transformer.py and
+   parallel/ring_attention.py route to on a TPU,
    compiled (not interpreted), against their jnp oracles;
 4. one program compiled twice, to show the persistent compile cache.
 

@@ -1,5 +1,6 @@
 """Train ImageNet-class networks (ResNet) with Module + KVStore —
-BASELINE config #2 and the bench.py headline workload.
+BASELINE config #2; the benchmark's `resnet50_v1` configuration builds
+the same symbol (`symbols/resnet.py`).
 
 Mirrors example/image-classification/train_imagenet.py: symbolic ResNet,
 RecordIO/synthetic data, data-parallel fit over all local devices via

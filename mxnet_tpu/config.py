@@ -405,7 +405,7 @@ flags.declare('MXTPU_ROOFLINE_TRACE', str, '',
               'roofline-minimum times (source: modeled)')
 flags.declare('MXTPU_PEAK_TFLOPS', float, 0.0,
               'Override the device peak dense bf16 TFLOP/s used by the '
-              'MFU estimate and the roofline denominators (for chips '
+              'roofline denominators (for chips '
               'missing from the telemetry/xla.py table — the '
               'warn-once path names this flag). 0 = use the table',
               min_value=0.0)
@@ -490,14 +490,11 @@ flags.declare('MXTPU_BN_ONEPASS', bool, True,
               'BatchNorm training stats via one-pass moments '
               '(sum/sum-of-squares in one fused HBM read of the '
               'activation) instead of jnp.var\'s two-pass mean-then-'
-              'centered-square. Default ON since the fused-window '
-              'donation round: with the window\'s buffer economics '
-              'fixed the one HBM read wins where the round-5 A/B '
-              '(2406 vs 2535 img/s, bench_bn_*_20260802T061225Z) '
-              'measured it 5% slower against the pre-donation '
-              'program. 0 is the escape hatch back to the two-pass '
-              'jnp.var form (byte-identical to the old default '
-              'lowering); numerics are parity-tested both ways '
+              'centered-square. Default ON; no chip run on record '
+              'supports the default (ROADMAP S9, D10: one paired '
+              'measurement on the benchmark decides it). 0 is the '
+              'two-pass jnp.var form; numerics are parity-tested both '
+              'ways '
               '(tests/unittest/test_bn_onepass.py)')
 flags.declare('MXTPU_FUSED_DONATE', bool, True,
               'Donate the fused-fit window\'s inputs to XLA: the '
@@ -584,8 +581,8 @@ flags.declare('MXTPU_SCALARS_EVERY', int, 25,
               'Run-ledger scalar cadence (telemetry/ledger.py, requires '
               'MXTPU_TELEMETRY=1): every N trained steps one `scalars` '
               'JSONL record banks the step\'s loss, learning rate, '
-              'throughput, global + worst-layer gradient statistics and '
-              'MFU — the bounded per-step timeseries tools/'
+              'throughput and global + worst-layer gradient statistics '
+              '— the bounded per-step timeseries tools/'
               'run_compare.py diffs across runs — and the per-layer '
               'dynamics plane (MXTPU_DYNAMICS) publishes its gauges at '
               'the same decimated cadence. With MXTPU_TFEVENTS_DIR set '

@@ -326,7 +326,7 @@ class Executor:
             # registrar — an explicit lower().compile() whose executable
             # yields XLA's cost/memory analysis (program.* gauges, the
             # per-program summary table). fwd_bwd is THE train step of
-            # the per-batch loop, so its FLOPs feed the MFU estimate.
+            # the per-batch loop: its FLOPs are the xla.step_flops gauge.
             gname = _tele.programs.scope_name(
                 getattr(symbol, 'name', None) or 'graph')
             self._fwd = _tele.programs.register(

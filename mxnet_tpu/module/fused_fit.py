@@ -1012,8 +1012,8 @@ class FusedFitLoop:
                 return p, s, a, g, ys
 
         # the train-step program of the fused path: its XLA cost
-        # analysis (scan body counted once = per-step FLOPs) feeds the
-        # framework-computed MFU through the registrar. Donation
+        # analysis (scan body counted once = per-step FLOPs) is the
+        # xla.step_flops gauge, through the registrar. Donation
         # (MXTPU_FUSED_DONATE): the param/state/aux/gacc carry aliases
         # in place onto the matching outputs, and the input/label
         # stacks are donated for their lifetime — the runtime frees

@@ -78,8 +78,9 @@ def registered_jit(name, fn, step_flops=False, **jit_kwargs):
     returned callable compiles via an explicit ``lower().compile()``
     and the executable's XLA cost/memory analysis lands in the
     per-program table (telemetry.programs); ``step_flops=True`` marks
-    the program whose FLOPs define a training step (feeds the MFU
-    estimate). With telemetry off this is exactly ``jax.jit(fn)``."""
+    the program whose FLOPs define a training step (the
+    ``xla.step_flops`` gauge). With telemetry off this is exactly
+    ``jax.jit(fn)``."""
     return _tele.programs.register(name, jax.jit(fn, **jit_kwargs),
                                    step_flops=step_flops)
 

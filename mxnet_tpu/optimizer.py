@@ -23,7 +23,7 @@ from .base import normalize_value
 def _is_half(dtype):
     """True for the half-precision dtypes multi_precision applies to —
     float16 (reference optimizer.py:338) and bfloat16, the TPU half
-    type the bench's mp path trains in."""
+    type both of the benchmark's configurations train in."""
     return str(dtype) in ('float16', 'bfloat16')
 
 

@@ -258,7 +258,8 @@ def note_round_verdict(verdict):
 
 
 def publish_gauges(n_elems, mode, src, block=None, itemsize=4):
-    """Publish the comm.* gauges bench banks and bench_diff gates.
+    """Publish the comm.* gauges (bytes on the wire a step, the
+    compression ratio, the mode and where the bytes come from).
 
     ``src`` is the provenance: 'measured' (real bytes counted on the
     kvstore TCP wire) or 'modeled' (wire_bytes arithmetic for the

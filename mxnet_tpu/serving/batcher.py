@@ -38,8 +38,9 @@ ONE dispatch span id. Completed requests also feed the SLO plane
 (telemetry/slo.py): latency per request, and dispatch/fetch failures
 as the 5xx the error budget measures (client-side rejects in submit
 never burn budget). Telemetry off = no trace object, no SLO state —
-the host-side queue_wait/stage logs (plain deques, like dispatch_log)
-are the only unconditional bookkeeping, and the bench reads those.
+the host-side queue_wait log (a plain deque, like dispatch_log) is
+the only unconditional bookkeeping, and the benchmark's serving driver
+reads both.
 """
 import collections
 import logging
@@ -102,11 +103,10 @@ class DynamicBatcher:
         # (rows, bucket_rows, n_requests) per dispatch — the test/debug
         # ledger proving requests actually coalesced
         self.dispatch_log = collections.deque(maxlen=1024)
-        # per-request queue waits (ms) + per-dispatch stage timings —
-        # host clock reads only, kept unconditionally like dispatch_log
-        # so the bench can bank the breakdown without telemetry
+        # per-request queue waits (ms) — host clock reads only, kept
+        # unconditionally like dispatch_log so that the benchmark reads
+        # them without telemetry (benchmark/drivers/serve_http.py)
         self.queue_wait_log = collections.deque(maxlen=4096)
-        self.stage_log = collections.deque(maxlen=1024)
 
     # -- client API --------------------------------------------------------
     def submit(self, arrays, trace_id=None):
@@ -286,9 +286,6 @@ class DynamicBatcher:
             r.future.set_result([o[off:off + r.rows] for o in outs])
             off += r.rows
         timings['split_ms'] = (time.perf_counter() - t0) * 1e3
-        self.stage_log.append(dict(timings, rows=sum(r.rows
-                                                     for r in batch),
-                                   requests=len(batch)))
         dispatch_span = timings.get('dispatch_span')
         now = time.monotonic()
         for r in batch:

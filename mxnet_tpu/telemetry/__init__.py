@@ -15,11 +15,11 @@ instrumentation the hot paths report through:
   running, writes into the same trace file ``profiler.py`` writes, so
   telemetry spans and engine op spans land on one timeline;
 - XLA gauges (:mod:`.xla`): compile count/seconds via jax.monitoring,
-  retrace-storm detection, live/peak device bytes, an MFU estimate;
+  retrace-storm detection, live/peak device bytes, per-chip peaks;
 - per-program cost attribution (:mod:`.programs`): every compile site
   routes through a registrar that captures XLA's cost/memory analysis
   per compiled program (``program.*`` gauges, a per-program summary
-  table, the automatic step-FLOPs feed behind the MFU gauge, and an
+  table, the automatic ``xla.step_flops`` feed, and an
   on-RESOURCE_EXHAUSTED memory-breakdown report);
 - training-health sentinels (:mod:`.health`, MXTPU_HEALTH=1): in-graph
   NaN/Inf detection with exact-step attribution through the fused
@@ -76,11 +76,11 @@ instrumentation the hot paths report through:
 - the run ledger (:mod:`.ledger`, ``MXTPU_SCALARS_EVERY``): a
   ``manifest`` JSONL record (resolved flags, jax version, device kind,
   mesh, git sha) plus a bounded per-step ``scalars`` timeseries (loss,
-  lr, throughput, grad stats, eval metrics, MFU), mirrored as native
+  lr, throughput, grad stats, eval metrics), mirrored as native
   TensorBoard event files through a dependency-free TFRecord/Event
   writer when ``MXTPU_TFEVENTS_DIR`` is set —
-  ``tools/run_compare.py`` diffs two runs' ledgers with
-  bench_diff-style verdicts;
+  ``tools/run_compare.py`` diffs two runs' ledgers and exits 1 on a
+  regression past tolerance;
 - the hang watchdog (:mod:`.watchdog`, ``MXTPU_WATCHDOG_SECS``):
   a daemon-thread progress monitor fed by the hot loops' dispatch /
   sync / kvstore / checkpoint sites; a stall dumps all-thread stacks
@@ -402,9 +402,6 @@ def write_summary(log=True):
     if not enabled():
         return None
     xla.sample_memory()
-    mfu = xla.mfu_estimate()
-    if mfu is not None:
-        _state.registry.gauge('xla.mfu').set(round(mfu, 4))
     # run-health roll-up: publishes the derived fit.input_bound_pct
     # gauge and (with MXTPU_HEALTH=1) returns the "Run health" block's
     # input + the summary record's 'health' key

@@ -176,8 +176,8 @@ def step_stats(outs, grads=None, params=None, new_params=None):
     - ``[4:]`` one finite flag per output.
 
     A handful of full-array reductions — XLA fuses them into the
-    surrounding step; the bench's sentinel-overhead probe keeps the cost
-    measured (<2% on the train step).
+    surrounding step. Their cost on the device is not measured
+    (ROADMAP S9).
     """
     import jax.numpy as jnp
 

@@ -11,7 +11,7 @@ three things no other plane records:
 - a **scalar timeseries** (`scalars` JSONL records, every
   ``MXTPU_SCALARS_EVERY`` trained steps): loss, learning rate,
   throughput, global + worst-layer gradient statistics
-  (telemetry/dynamics.py), MFU and eval metrics — the bounded
+  (telemetry/dynamics.py) and eval metrics — the bounded
   per-step ledger ``tools/run_compare.py`` diffs across runs;
 - a **tfevents mirror** (``MXTPU_TFEVENTS_DIR``): every scalar also
   lands as a native TensorBoard event through
@@ -35,8 +35,8 @@ import collections
 
 __all__ = ['enabled', 'ensure_manifest', 'begin_run', 'note_train_step',
            'note_eval',
-           'snapshot_ledger', 'final_loss', 'time_to_loss',
-           'progress_target', 'TfEventsWriter', 'read_tfevents',
+           'snapshot_ledger', 'final_loss',
+           'TfEventsWriter', 'read_tfevents',
            'crc32c', 'masked_crc', 'MANIFEST_KEYS']
 
 # the manifest fields rolled up by snapshot_ledger, the crashed-run
@@ -546,7 +546,6 @@ def _build_record(step, now, loss, lr, extra=None):
         rec['steps_per_sec'] = round(
             (step - _state.last_emit_step) / (now - _state.last_emit_t), 3)
     for field, gauge in (('grad_norm', 'health.grad_norm'),
-                         ('mfu', 'xla.mfu'),
                          ('samples_per_sec',
                           'speedometer.samples_per_sec')):
         v = _gauge(gauge)
@@ -579,10 +578,11 @@ def _mirror_tfevents(scalars, step, now):
 
 
 def _emit_scalars(rec, now):
-    # stamp the CALLER's timestamp: bench's feed() banks post-barrier
-    # with amortized per-step times, and run_compare's step_time /
+    # stamp the CALLER's timestamp: the fused window banks its steps in
+    # one burst after the fetch, with per-step times spread over the
+    # window (note_train_step's ``t``), and run_compare's step_time /
     # time_to_loss read the record's 't' — the sink's emit-time default
-    # would bunch every fed point at one instant
+    # would bunch every point of a window at one instant
     rec['t'] = now
     _emit(rec)
     _mirror_tfevents({k: float(v) for k, v in rec.items()
@@ -655,57 +655,15 @@ def note_eval(name_values, epoch=None):
                       if k.startswith('eval_')}, step, now)
 
 
-def feed(step, loss, t=None):
-    """Direct feed for drivers that own their loop (bench.py): bank one
-    (step, loss) point with an explicit timestamp — emitted as a
-    `scalars` record and entered into the in-memory series
-    final_loss/time_to_loss read."""
-    if not enabled():
-        return
-    now = time.time() if t is None else float(t)
-    with _state.lock:
-        _state.step = max(_state.step, int(step))
-    _emit_scalars(_build_record(int(step), now, loss, None), now)
-
-
-# -- derived metrics (bench + run_compare) -----------------------------------
-
-def _series():
-    with _state.lock:
-        return list(_state.records)
-
+# -- derived metrics ---------------------------------------------------------
 
 def final_loss():
     """The last banked loss, or None."""
-    for _, _, loss in reversed(_series()):
+    with _state.lock:
+        records = list(_state.records)
+    for _, _, loss in reversed(records):
         if loss is not None:
             return loss
-    return None
-
-
-def progress_target(frac=0.9):
-    """The loss value ``frac`` of the way from the first banked loss to
-    the best one — a self-scaling time-to-loss target comparable across
-    re-runs of the same job."""
-    losses = [l for _, _, l in _series() if l is not None]
-    if len(losses) < 2:
-        return None
-    first, best = losses[0], min(losses)
-    if best >= first:
-        return None     # never improved: no meaningful target
-    return first - frac * (first - best)
-
-
-def time_to_loss(target):
-    """Seconds from the first banked point to the first point at or
-    below ``target`` loss — None when the run never got there."""
-    if target is None:
-        return None
-    pts = _series()
-    t0 = pts[0][1] if pts else None
-    for _, t, loss in pts:
-        if loss is not None and loss <= target:
-            return round(t - t0, 3)
     return None
 
 

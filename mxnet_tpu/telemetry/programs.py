@@ -2,9 +2,8 @@
 
 Everything XLA runs for this framework is built at a handful of compile
 sites (the executor's fwd / fwd+bwd programs, the fused fit/eval window
-programs, bench.py's raw train step). PR 1's telemetry could time those
-dispatches but the programs themselves stayed anonymous blobs — FLOPs
-for the MFU gauge were hand-computed in bench.py and memory gauges were
+programs, the serving bucket ladder). Spans time those dispatches but
+the programs themselves would stay anonymous blobs and memory gauges
 whole-device totals. This module makes every compiled program
 self-describing, following the compiler-stack practice of making
 per-program cost a first-class primitive (TVM, arXiv:1802.04799; the
@@ -14,13 +13,12 @@ Julia->TPU arXiv:1810.09868):
 - :func:`analyze_compiled` — pure: XLA's own ``cost_analysis()`` /
   ``memory_analysis()`` of a compiled executable as a plain dict
   (FLOPs, bytes accessed, temp/argument/output/generated-code bytes).
-  Works with telemetry off — bench.py computes its headline numbers
-  through it either way;
+  Works with telemetry off;
 - :func:`note_program` — publish one program's analysis: ``program.*``
   gauges in the registry, a ``program`` JSONL record, a row in the
   end-of-run per-program summary table, and (for programs marked as
-  the train step) :func:`telemetry.xla.note_step_flops`, so the MFU
-  estimate is framework-computed instead of bench-only;
+  the train step) :func:`telemetry.xla.note_step_flops`, the
+  ``xla.step_flops`` gauge;
 - :func:`register` — the compile-site interceptor. Wraps a
   ``jax.jit``-ed callable so its lazy compile becomes an explicit
   ``lower().compile()`` whose executable this module can analyze; the
@@ -66,7 +64,7 @@ def analyze_compiled(compiled):
     """XLA's own cost + memory analysis of a compiled executable, as a
     plain dict (zeros where a backend doesn't report). Pure — no
     registry writes, no I/O — so callers that need the numbers with
-    telemetry off (bench.py's MFU math) can use it directly."""
+    telemetry off can use it directly."""
     rec = _empty_analysis()
     try:
         cost = compiled.cost_analysis()
@@ -154,14 +152,14 @@ def note_program(name, compiled=None, analysis=None, step_flops=False,
     reg.gauge('program.%s.alias_bytes' % name).set(merged['alias_bytes'])
     reg.gauge('program.%s.live_bytes' % name).set(merged['live_bytes'])
     if step_flops and analysis['flops']:
-        # the train-step program: its FLOPs feed the MFU estimate. XLA
+        # the train-step program: its FLOPs are xla.step_flops. XLA
         # counts a scan (while-loop) body ONCE regardless of trip
         # count, so a W-step fused window reports per-step FLOPs
         # already — exactly what note_step_flops wants. Feed the MAX
         # across ALL step-marked programs so far: neither a tail-batch
         # shape variant nor the tail's executor.fwd_bwd (compiled after
         # the fused window, without the update math) may shrink the
-        # per-step FLOPs the whole run's MFU is computed from.
+        # per-step FLOPs the run reports.
         with _lock:
             _step_flops_seen[name] = max(_step_flops_seen.get(name, 0.0),
                                          analysis['flops'])
@@ -304,7 +302,7 @@ def register(name, jitted, static_argnums=(), step_flops=False):
     ``static_argnums`` must mirror the ``jax.jit`` declaration (AOT
     executables take only the dynamic arguments). ``step_flops=True``
     marks the program whose FLOPs define a training step — it feeds
-    the framework-computed MFU estimate."""
+    the ``xla.step_flops`` gauge."""
     from . import enabled
     if not enabled():
         return jitted

@@ -43,8 +43,8 @@ lowered HLO is byte-identical with the flag on or off):
 Surfacing: a ranked bottleneck block in the end-of-run summary table
 ("layer, class, achieved/peak %, est. headroom ms"), a ``roofline``
 JSONL record carrying the full analysis, ``roofline.*`` gauges on
-/metrics and /summary, a ``roofline`` section in BENCH json, and
-``tools/roofline_report.py`` offline (byte-identical block).
+/metrics and /summary, and ``tools/roofline_report.py`` offline
+(byte-identical block).
 
 Gating: ``MXTPU_ROOFLINE=1`` *and* ``MXTPU_TELEMETRY=1``. Off = the
 zero-overhead no-op contract of the rest of the plane: no HLO text is
@@ -60,7 +60,7 @@ import threading
 
 __all__ = ['enabled', 'note_compiled', 'note_hlo', 'hlo_layer_costs',
            'load_trace_events', 'analyze', 'summarize', 'republish',
-           'snapshot_roofline', 'comm_bytes_by_op', 'comm_share',
+           'snapshot_roofline', 'comm_share',
            'comm_pct_of_step', 'suggest_action',
            'RECLAIM_ACTIONS', 'TOP_N',
            'OVERHEAD_UTIL_PCT', 'CLASS_COMPUTE', 'CLASS_MEMORY',
@@ -530,9 +530,7 @@ def _default_trace_path():
 def _registry_step_ms(reg):
     """Best per-step milliseconds from the registry (the modeled path's
     denominator): fused window dispatch p50 / W, else the per-batch
-    dispatch p50, else the bench dispatch p50 normalized by bench's
-    steps-per-dispatch (one bench.dispatch span covers STEPS_PER_CALL
-    steps — fit.steps counts them per dispatch)."""
+    dispatch p50."""
     h = reg.get('fused_fit.dispatch')
     if h is not None and h.count:
         p50 = h.percentile(50)
@@ -543,16 +541,6 @@ def _registry_step_ms(reg):
     if h is not None and h.count:
         p50 = h.percentile(50)
         if p50:
-            return float(p50)
-    h = reg.get('bench.dispatch')
-    if h is not None and h.count:
-        p50 = h.percentile(50)
-        if p50:
-            steps_c = reg.get('fit.steps')
-            if steps_c is not None and steps_c.value:
-                per_dispatch = float(steps_c.value) / h.count
-                if per_dispatch >= 1.0:
-                    return float(p50) / per_dispatch
             return float(p50)
     return None
 
@@ -716,25 +704,6 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
     }
 
 
-def comm_bytes_by_op(name_prefix=None):
-    """{collective opcode: per-step bytes} summed over every ingested
-    program (optionally filtered to names starting with
-    ``name_prefix``), or {} when roofline is off / nothing matched.
-    The per-opcode view of the communication accounting: the sharded
-    weight update's reduce-scatter + all-gather traffic reads straight
-    off it (bench.py's ``update_comm_bytes``)."""
-    if not enabled():
-        return {}
-    with _lock:
-        progs = [p for n, p in _programs.items()
-                 if name_prefix is None or str(n).startswith(name_prefix)]
-    out = {}
-    for p in progs:
-        for op, b in (p.get('comm_ops') or {}).items():
-            out[op] = out.get(op, 0.0) + float(b)
-    return out
-
-
 def comm_share():
     """``(pct, source)`` — the collective share of the step (%) with
     its provenance attached: ``'measured'`` when the number comes from
@@ -783,11 +752,11 @@ def summarize(step_time_ms=None):
     ``roofline`` JSONL record, and return the analysis dict (None when
     off/empty). Called from telemetry.write_summary.
 
-    A measured ``step_time_ms`` (bench feeds its wall-clock mean) is
+    A measured ``step_time_ms`` (a caller that timed its own loop) is
     remembered: a later summarize() with none — the atexit
-    write_summary after a bench run — reuses it instead of falling
-    back to the registry-derived time, so the run's roofline records
-    never disagree about the step-time denominator."""
+    write_summary — reuses it instead of falling back to the
+    registry-derived time, so the run's roofline records never
+    disagree about the step-time denominator."""
     global _last, _explicit_step_ms
     if step_time_ms is not None:
         _explicit_step_ms = step_time_ms

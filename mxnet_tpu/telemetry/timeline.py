@@ -51,7 +51,7 @@ import numpy as np
 __all__ = ['PHASES', 'PHASE_SPANS', 'SLOTS', 'CLOCK_RING', 'enabled',
            'note_span', 'note_step', 'note_sync_exit', 'local_slots',
            'estimate_offsets', 'decompose', 'attribute', 'publish_round',
-           'summarize', 'snapshot_timeline', 'phase_breakdown']
+           'summarize', 'snapshot_timeline']
 
 # the ledger's phases, in sync-vector slot order (SLOTS[2 + k] carries
 # PHASES[k]); 'collective' and 'compute' are DERIVED per round from the
@@ -71,7 +71,6 @@ PHASE_SPANS = {
     'fit.draw': 'draw', 'fused_fit.draw': 'draw',
     'fused_fit.put': 'put',
     'fit.dispatch': 'dispatch', 'fused_fit.dispatch': 'dispatch',
-    'bench.dispatch': 'dispatch',
     'fused_fit.fetch': 'fetch', 'fit.metric': 'fetch',
     'ckpt.save': 'checkpoint', 'ckpt.capture': 'checkpoint',
     'kvstore.push': 'kvstore', 'kvstore.pull': 'kvstore',
@@ -518,28 +517,6 @@ def snapshot_timeline():
     — the /summary key and the summary table's block input."""
     with _state.lock:
         return dict(_state.last) if _state.last else None
-
-
-def phase_breakdown():
-    """{compute,collective,io,host}_pct of the step for bench.py's
-    ``step_phase_breakdown`` BENCH field (host_overhead_pct is what
-    bench_diff gates). Reads the last attribution, else derives a
-    local one read-only. None while off / before any counted step."""
-    if not enabled():
-        return None
-    out = snapshot_timeline() or _local_attribution()
-    if not out or not out.get('per_host'):
-        return None
-    rows = out['per_host']
-    # the slowest host's row is the pod's step (bench runs are
-    # single-host, where the only row is it)
-    crit = out.get('critical_host')
-    row = next((r for r in rows if r.get('host') == crit), rows[0])
-    step = row.get('step_time_ms')
-    if not step:
-        return None
-    return {k + '_pct': round(100.0 * (row.get(k + '_ms') or 0.0) / step, 2)
-            for k in ('compute', 'collective', 'io', 'host')}
 
 
 def _reset_for_tests():

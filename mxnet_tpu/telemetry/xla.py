@@ -1,4 +1,4 @@
-"""XLA-side telemetry: compile events, device memory, retraces, MFU.
+"""XLA-side telemetry: compile events, device memory, retraces, peaks.
 
 Compile observability comes from jax.monitoring: XLA emits
 ``/jax/core/compile/backend_compile_duration`` once per backend
@@ -22,18 +22,18 @@ warning plus a ``retrace_storm`` JSONL record.
 Memory gauges read ``device.memory_stats()`` (live/peak bytes on TPU;
 None on CPU — sampled best-effort, with ONE process-wide warning the
 first time no device reports stats so empty gauges are explained). The
-MFU estimate needs the step FLOPs: the program registrar
-(:mod:`.programs`) feeds :func:`note_step_flops` automatically from
-whichever train-step program the fit loop compiles (bench.py feeds the
-same way through ``note_program``), and the summary divides observed
-step rate * FLOPs by the device's peak.
+program registrar (:mod:`.programs`) feeds :func:`note_step_flops` from
+whichever train-step program the fit loop compiles: XLA's count of the
+compiled step's FLOPs (recomputation included), published as the
+``xla.step_flops`` gauge. :func:`device_peaks` holds the per-chip
+ceilings the roofline plane divides by.
 """
 import logging
 import threading
 import time
 
 __all__ = ['install', 'note_retrace', 'note_step_flops', 'sample_memory',
-           'device_peak_flops', 'device_peaks', 'mfu_estimate']
+           'device_peak_flops', 'device_peaks']
 
 _COMPILE_EVENT_SUFFIX = 'backend_compile_duration'
 # persistent-compilation-cache events: a hit
@@ -44,8 +44,7 @@ _CACHE_SAVED_SUFFIX = 'compile_time_saved_sec'
 # Per-chip hardware ceilings, by device_kind substring (order matters:
 # 'v5p' must match before 'v5'). Columns: peak dense bf16 FLOP/s and
 # peak HBM bytes/s — the two roofline denominators (telemetry/roofline
-# classifies each layer by which ceiling bounds it). The MFU estimate
-# uses only the FLOP/s column.
+# classifies each layer by which ceiling bounds it).
 _PEAK_TABLE = [
     ('v6', 918e12, 1640e9), ('v5p', 459e12, 2765e9), ('v5', 197e12, 819e9),
     ('v4', 275e12, 1228e9), ('v3', 123e12, 900e9), ('v2', 45e12, 700e9),
@@ -142,10 +141,10 @@ def _short(key, limit=200):
 
 
 def note_step_flops(flops):
-    """Record the per-training-step model FLOPs (enables the MFU
-    estimate). Fed automatically by telemetry.programs when a
-    step-marked program (executor fwd+bwd, fused fit window) compiles;
-    bench.py feeds XLA's own cost analysis the same way."""
+    """Record the compiled training step's FLOPs (the
+    ``xla.step_flops`` gauge). Fed automatically by telemetry.programs
+    when a step-marked program (executor fwd+bwd, fused fit window)
+    compiles."""
     st = _state()
     if st.active and flops:
         st.registry.gauge('xla.step_flops').set(float(flops))
@@ -308,24 +307,3 @@ def device_peak_flops(device=None):
 def _reset_peaks_warned_for_tests():
     global _peaks_unknown_warned
     _peaks_unknown_warned = False
-
-
-def mfu_estimate():
-    """step_flops * observed steps / elapsed / peak — or None when any
-    ingredient (FLOPs, a step count, a known chip) is missing. Reads
-    metrics with registry.get (never create-on-read: a missing
-    fit.steps must not plant a zero counter in the summary)."""
-    st = _state()
-    if not st.active:
-        return None
-    flops_g = st.registry.get('xla.step_flops')
-    steps_c = st.registry.get('fit.steps')
-    flops = flops_g.value if flops_g is not None else None
-    steps = steps_c.value if steps_c is not None else 0
-    elapsed = time.time() - st.t_start
-    if not flops or not steps or elapsed <= 0:
-        return None
-    peak, _ = device_peak_flops()
-    if not peak:
-        return None
-    return flops * steps / elapsed / peak

@@ -19,9 +19,7 @@ liveness-packed ``temp_size_in_bytes`` barely moves — the registrar's
 ``live_bytes`` (args + temp + outputs - alias: what one dispatch makes
 XLA hold beyond caller-owned buffers) is therefore the CPU-measurable
 donation metric, and ``temp_bytes`` is gated against regression here
-and at 10% in tools/bench_diff.py (device backends move it — the
-BENCH ledger's 1.41 GB fused-window record is the number under
-attack).
+(device backends move it).
 """
 import os
 

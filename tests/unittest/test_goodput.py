@@ -14,8 +14,8 @@ wall-clock attribution from the step loop to the supervised fleet.
   relaunches and the relaunched child reports prior_lost_s /
   job_goodput_pct;
 - satellites: per-fit manifest re-emit with run_seq (run_compare keys
-  on the latest), the bench_diff goodput_pct gate, the watch line and
-  the offline report's crashed-run reconstruction.
+  on the latest), the watch line and the offline report's crashed-run
+  reconstruction.
 """
 import json
 import math
@@ -654,41 +654,3 @@ def test_watch_renders_goodput_line():
         {'snapshot': {'counters': {}, 'gauges': {}, 'histograms': {}}}))
     assert 'goodput' not in frame
 
-
-# ---------------------------------------------------------------------------
-# satellite: the bench_diff goodput_pct gate
-# ---------------------------------------------------------------------------
-
-def _bench_rec(goodput_pct):
-    rec = {'metric': 'm', 'value': 100.0, 'platform': 'cpu',
-           'batch': 8, 'steps_per_call': 1}
-    if goodput_pct is not None:
-        rec['goodput_pct'] = goodput_pct
-    return rec
-
-
-def test_bench_diff_gates_goodput_pct(tmp_path, capsys):
-    import bench_diff
-    old = tmp_path / 'old.json'
-    for name, pct, rc_want, verdict in (
-            ('flat.json', 79.0, 0, 'ok'),          # -1.25% within 5%
-            ('worse.json', 70.0, 1, 'REGRESSION'),  # -12.5%
-            ('better.json', 95.0, 0, 'ok')):        # improvements pass
-        old.write_text(json.dumps(_bench_rec(80.0)))
-        new = tmp_path / name
-        new.write_text(json.dumps(_bench_rec(pct)))
-        rc = bench_diff.main([str(old), str(new)])
-        out = capsys.readouterr().out
-        assert rc == rc_want, (name, out)
-        row = [ln for ln in out.splitlines()
-               if ln.strip().startswith('goodput_pct')]
-        assert row and verdict in row[0], out
-    # missing on either side: a visible skip, never a silent pass
-    old.write_text(json.dumps(_bench_rec(None)))
-    new = tmp_path / 'new.json'
-    new.write_text(json.dumps(_bench_rec(80.0)))
-    rc = bench_diff.main([str(old), str(new)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert 'goodput_pct' in out and 'no baseline' in out
-    assert 'ungated this round' in out

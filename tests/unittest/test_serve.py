@@ -124,7 +124,8 @@ def test_prometheus_golden():
     summary quantiles carrying the histogram p50/p95."""
     snap = {
         'counters': {'fit.steps': 8},
-        'gauges': {'xla.mfu': 0.25, 'cluster.straggler_class': 'input_bound'},
+        'gauges': {'fit.input_bound_pct': 0.25,
+                   'cluster.straggler_class': 'input_bound'},
         'histograms': {'fit.batch': {
             'count': 2, 'sum': 3.0, 'mean': 1.5, 'min': 1.0, 'max': 2.0,
             'p50': 1.0, 'p95': 2.0}},
@@ -137,9 +138,10 @@ def test_prometheus_golden():
         'cluster.straggler_class\n'
         '# TYPE mxtpu_cluster_straggler_class gauge\n'
         'mxtpu_cluster_straggler_class{host="3",value="input_bound"} 1\n'
-        '# HELP mxtpu_xla_mfu mxnet_tpu gauge xla.mfu\n'
-        '# TYPE mxtpu_xla_mfu gauge\n'
-        'mxtpu_xla_mfu{host="3"} 0.25\n'
+        '# HELP mxtpu_fit_input_bound_pct mxnet_tpu gauge '
+        'fit.input_bound_pct\n'
+        '# TYPE mxtpu_fit_input_bound_pct gauge\n'
+        'mxtpu_fit_input_bound_pct{host="3"} 0.25\n'
         '# HELP mxtpu_fit_batch_ms mxnet_tpu span histogram fit.batch '
         '(milliseconds; quantiles over the recent window)\n'
         '# TYPE mxtpu_fit_batch_ms summary\n'

@@ -28,7 +28,8 @@ def test_telemetry_report_golden(tmp_path, capsys):
         {'type': 'summary', 't': 101.5, 'elapsed_s': 1.5,
          'snapshot': {
              'counters': {'fit.steps': 8},
-             'gauges': {'xla.mfu': 0.125, 'program.p.flops': 1000.0},
+             'gauges': {'fit.input_bound_pct': 0.125,
+                        'program.p.flops': 1000.0},
              'histograms': {'fit.batch': {
                  'count': 1, 'sum': 2.0, 'mean': 2.0, 'min': 2.0,
                  'max': 2.0, 'p50': 2.0, 'p95': 2.0}}},
@@ -49,7 +50,7 @@ def test_telemetry_report_golden(tmp_path, capsys):
         '-- counters --\n'
         '  fit.steps  8\n'
         '-- gauges --\n'
-        '  xla.mfu  0.125\n'
+        '  fit.input_bound_pct  0.125\n'
         '-- programs --\n'
         '  name  compiles      calls      flops  bytes_acc  temp_MiB'
         '   arg_MiB   out_MiB\n'
@@ -223,15 +224,14 @@ def test_telemetry_report_multi_host_communication_bound(tmp_path,
 
 
 def test_telemetry_watch_render():
-    """The watch CLI's frame renderer (pure function): throughput, MFU,
+    """The watch CLI's frame renderer (pure function): throughput,
     health and per-host spread all land in the frame."""
     import telemetry_watch
     summary = {
         'elapsed_s': 120.0, 'host': 0,
         'snapshot': {
             'counters': {'fit.steps': 640},
-            'gauges': {'xla.mfu': 0.42,
-                       'speedometer.samples_per_sec': 1234.5,
+            'gauges': {'speedometer.samples_per_sec': 1234.5,
                        'fit.input_bound_pct': 12.5},
             'histograms': {'fit.batch': {
                 'count': 640, 'sum': 6400.0, 'mean': 10.0, 'min': 9.0,
@@ -251,7 +251,7 @@ def test_telemetry_watch_render():
     frame = '\n'.join(telemetry_watch.render(summary, steps_per_s=5.25))
     assert 'host 0' in frame and 'up 120s' in frame
     assert 'steps 640' in frame and '5.25 steps/s' in frame
-    assert 'mfu          42.0%' in frame
+    assert '1.23e+03 samples/s' in frame and 'mfu' not in frame
     assert 'p50 10 ms' in frame
     assert 'DEGRADED (1 non-finite steps)' in frame
     assert 'last_anomaly loss=9 (baseline 2)' in frame
@@ -271,91 +271,10 @@ def test_telemetry_watch_fetch_jsonl(tmp_path):
     assert any('throughput' in ln for ln in lines)
 
 
-def _bench_rec(**kw):
-    rec = {'metric': 'resnet50_train_throughput_bf16', 'value': 2561.42,
-           'unit': 'images/sec', 'batch': 32, 'device': 'TPU v5 lite',
-           'platform': 'tpu', 'steps_per_call': 32, 'mfu': 0.2908,
-           'xla_temp_bytes': 1412014080,
-           'compile_cache': {'cold_s': 26.3, 'warm_s': 5.4}}
-    rec.update(kw)
-    return rec
-
-
-def test_bench_diff_ok_and_regression(tmp_path, capsys):
-    """tools/bench_diff compares two BENCH artifacts: within tolerance
-    exits 0; a throughput/MFU drop or a temp-bytes rise past tolerance
-    prints REGRESSION and exits 1 — the post-bench gate."""
-    import json
-    import bench_diff
-    a = tmp_path / 'a.json'
-    b = tmp_path / 'b.json'
-    a.write_text(json.dumps(_bench_rec()))
-    # 1% slide: inside the 5% default tolerance
-    b.write_text(json.dumps(_bench_rec(value=2536.44, mfu=0.288)))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    out = capsys.readouterr().out
-    assert 'ok' in out and 'REGRESSION' not in out
-    # 10% throughput drop + temp-bytes growth: both named, exit 1
-    b.write_text(json.dumps(_bench_rec(value=2300.0,
-                                       xla_temp_bytes=1700000000)))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    out = capsys.readouterr().out
-    assert 'REGRESSION: throughput, xla_temp_bytes' in out
-    # the same slide passes with a loosened per-metric tolerance
-    assert bench_diff.main([str(a), str(b), '--tol', 'throughput=15',
-                            '--tol', 'xla_temp_bytes=25']) == 0
-    capsys.readouterr()
-    # improvements never fail, whatever the tolerance
-    b.write_text(json.dumps(_bench_rec(value=9999.0, mfu=0.9,
-                                       xla_temp_bytes=1)))
-    assert bench_diff.main([str(a), str(b), '--tol-pct', '0.1']) == 0
-    capsys.readouterr()
-
-
-def test_bench_diff_gates_opt_state_bytes(tmp_path, capsys):
-    """opt_state_bytes_per_device (the sharded weight update's
-    per-device footprint) is in the gated set at a 10% tolerance:
-    a regrowth past it — e.g. the ZeRO layout silently disengaging —
-    fails the gate; a drop (more sharding) never does."""
-    import json
-    import bench_diff
-    a = tmp_path / 'a.json'
-    b = tmp_path / 'b.json'
-    a.write_text(json.dumps(_bench_rec(opt_state_bytes_per_device=12800)))
-    # +8%: inside the 10% tolerance
-    b.write_text(json.dumps(_bench_rec(opt_state_bytes_per_device=13824)))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    capsys.readouterr()
-    # 8x regrowth (the replicated footprint coming back): exit 1
-    b.write_text(json.dumps(_bench_rec(
-        opt_state_bytes_per_device=102400)))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    assert 'REGRESSION: opt_state_bytes_per_device' \
-        in capsys.readouterr().out
-    # a drop is an improvement, never a failure
-    b.write_text(json.dumps(_bench_rec(opt_state_bytes_per_device=1600)))
-    assert bench_diff.main([str(a), str(b), '--tol-pct', '0.1']) == 0
-    capsys.readouterr()
-    # absent on one side: skipped, not a verdict — and recapped in the
-    # trailing ungated-metrics note (never a silent pass)
-    b.write_text(json.dumps(_bench_rec()))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    out = capsys.readouterr().out
-    assert 'skipped (missing in new run)' in out
-    assert 'note: ungated this round' in out
-    # the symmetric case: the baseline predates the metric entirely
-    a2 = tmp_path / 'a2.json'
-    a2.write_text(json.dumps(_bench_rec()))
-    b.write_text(json.dumps(_bench_rec(opt_state_bytes_per_device=12800)))
-    assert bench_diff.main([str(a2), str(b)]) == 0
-    assert 'skipped (no baseline)' in capsys.readouterr().out
-
-
 def test_telemetry_watch_renders_opt_state_line():
     """The watch frame shows the sharded-update engagement: per-device
     opt-state MiB, layout, dp, and the step's whole collective share
-    (labeled as such — the update-only split is bench's
-    update_comm_bytes)."""
+    (labeled as such)."""
     import telemetry_watch
     summary = {
         'elapsed_s': 10.0, 'host': 0,
@@ -375,31 +294,6 @@ def test_telemetry_watch_renders_opt_state_line():
     assert 'replicated' in frame
 
 
-def test_bench_diff_formats_and_comparability(tmp_path, capsys):
-    """Accepts the harness wrapper ({'parsed': ...}) AND raw bench
-    stdout (JSON lines, last line authoritative); a CPU-fallback round
-    is 'not config-comparable' — reported, exit 0 (3 under --strict),
-    never a fake regression verdict."""
-    import json
-    import bench_diff
-    wrapped = tmp_path / 'wrapped.json'
-    wrapped.write_text(json.dumps({'n': 5, 'rc': 0,
-                                   'parsed': _bench_rec()}))
-    lines = tmp_path / 'lines.json'
-    lines.write_text('not json\n'
-                     + json.dumps({'metric': 'other'}) + '\n'
-                     + json.dumps(_bench_rec(value=2600.0)) + '\n')
-    assert bench_diff.main([str(wrapped), str(lines)]) == 0
-    capsys.readouterr()
-    cpu = tmp_path / 'cpu.json'
-    cpu.write_text(json.dumps(_bench_rec(
-        value=12.0, platform='cpu(fallback)', batch=8, steps_per_call=1)))
-    assert bench_diff.main([str(wrapped), str(cpu)]) == 0
-    assert 'not config-comparable' in capsys.readouterr().out
-    assert bench_diff.main([str(wrapped), str(cpu), '--strict']) == 3
-    capsys.readouterr()
-
-
 def test_every_report_and_diff_cli_smokes(tmp_path):
     """CI floor: every tools/*_report.py and tools/*_diff.py answers
     --help (argparse wiring + imports) — a new CLI cannot land without
@@ -414,7 +308,7 @@ def test_every_report_and_diff_cli_smokes(tmp_path):
     assert clis, 'no report/diff CLIs found'
     names = {os.path.basename(p) for p in clis}
     assert {'telemetry_report.py', 'roofline_report.py',
-            'memory_report.py', 'bench_diff.py', 'run_compare.py',
+            'memory_report.py', 'run_compare.py',
             'telemetry_watch.py'} <= names
     for cli in clis:
         out = subprocess.run([sys.executable, cli, '--help'],
@@ -483,10 +377,8 @@ def _roof_dict(step_ms, conv_ms, conv_head, fc_ms, fc_head,
 
 def test_roofline_diff_headroom_reclaimed(tmp_path, capsys):
     """tools/roofline_diff matches layers by name across two roofline
-    records and ranks headroom reclaimed — the re-measure step of the
-    MFU-gap workflow. Accepts a telemetry JSONL on one side and a
-    BENCH json (telemetry.roofline, harness wrapper form) on the
-    other; layers present on only one side are listed, never
+    records (the last one of each telemetry JSONL) and ranks headroom
+    reclaimed; layers present on only one side are listed, never
     silently dropped."""
     import json
     import roofline_diff
@@ -495,12 +387,13 @@ def test_roofline_diff_headroom_reclaimed(tmp_path, capsys):
         f.write(json.dumps(dict(_roof_dict(10.0, 4.0, 3.0, 2.0, 0.5,
                                            extra_layer='bn1'),
                                 type='roofline', t=1.0)) + '\n')
-    after = tmp_path / 'after.json'
-    after.write_text(json.dumps(
-        {'n': 1, 'rc': 0,
-         'parsed': {'metric': 'x', 'value': 1.0,
-                    'telemetry': {'roofline': _roof_dict(
-                        7.0, 1.5, 0.5, 2.0, 0.5)}}}))
+    after = tmp_path / 'after.jsonl'
+    with open(after, 'w') as f:
+        # an earlier record of the same log loses to the last one
+        f.write(json.dumps(dict(_roof_dict(9.0, 3.0, 2.0, 2.0, 0.5),
+                                type='roofline', t=1.0)) + '\n')
+        f.write(json.dumps(dict(_roof_dict(7.0, 1.5, 0.5, 2.0, 0.5),
+                                type='roofline', t=2.0)) + '\n')
     assert roofline_diff.main([str(before), str(after)]) == 0
     out = capsys.readouterr().out
     assert 'step_time_ms      10 -> 7' in out
@@ -519,27 +412,6 @@ def test_roofline_diff_headroom_reclaimed(tmp_path, capsys):
     empty.write_text(json.dumps({'type': 'start', 'pid': 1}) + '\n')
     with pytest.raises(SystemExit, match='no roofline record'):
         roofline_diff.main([str(empty), str(after)])
-
-
-def test_bench_diff_gates_live_bytes(tmp_path, capsys):
-    """xla_live_bytes (steady-state per-dispatch footprint, the
-    donation ledger) is gated at 10%: a donation regression — the
-    aliased carry coming back as fresh outputs — fails the gate;
-    a drop never does."""
-    import json
-    import bench_diff
-    a = tmp_path / 'a.json'
-    b = tmp_path / 'b.json'
-    a.write_text(json.dumps(_bench_rec(xla_live_bytes=500000000)))
-    b.write_text(json.dumps(_bench_rec(xla_live_bytes=540000000)))
-    assert bench_diff.main([str(a), str(b)]) == 0   # +8% < 10%
-    capsys.readouterr()
-    b.write_text(json.dumps(_bench_rec(xla_live_bytes=900000000)))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    assert 'REGRESSION: xla_live_bytes' in capsys.readouterr().out
-    b.write_text(json.dumps(_bench_rec(xla_live_bytes=100000000)))
-    assert bench_diff.main([str(a), str(b), '--tol-pct', '0.1']) == 0
-    capsys.readouterr()
 
 
 def test_telemetry_report_renders_roofline_block(tmp_path, capsys):
@@ -652,62 +524,6 @@ def test_executor_manager_trains():
 # ---------------------------------------------------------------------------
 # run ledger satellites (ISSUE 15)
 # ---------------------------------------------------------------------------
-
-def test_bench_diff_gates_final_loss(tmp_path, capsys):
-    """final_loss (the run ledger's last banked loss) is in the gated
-    set at 5%: a higher candidate loss fails, a lower one never does,
-    a NaN candidate — a diverged run — fails outright, and a missing
-    side is a visible skip."""
-    import json
-    import bench_diff
-    a = tmp_path / 'a.json'
-    b = tmp_path / 'b.json'
-    a.write_text(json.dumps(_bench_rec(final_loss=0.693)))
-    # +3%: inside tolerance
-    b.write_text(json.dumps(_bench_rec(final_loss=0.713)))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    capsys.readouterr()
-    # +12%: the run converged worse — exit 1
-    b.write_text(json.dumps(_bench_rec(final_loss=0.776)))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    assert 'REGRESSION: final_loss' in capsys.readouterr().out
-    # improvement never fails
-    b.write_text(json.dumps(_bench_rec(final_loss=0.3)))
-    assert bench_diff.main([str(a), str(b), '--tol-pct', '0.1']) == 0
-    capsys.readouterr()
-    # a nan candidate can never sneak through a tolerance comparison
-    b.write_text(json.dumps(_bench_rec(final_loss=float('nan'))))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    assert 'non-finite' in capsys.readouterr().out
-    # missing on the candidate side: skipped with the trailing note
-    b.write_text(json.dumps(_bench_rec()))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    out = capsys.readouterr().out
-    assert 'skipped (missing in new run)' in out
-    # a nan BASELINE (a diverged run got banked) can't gate anything:
-    # a visible skip, never an 'ok' from a nan delta
-    a.write_text(json.dumps(_bench_rec(final_loss=float('nan'))))
-    b.write_text(json.dumps(_bench_rec(final_loss=0.5)))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    out = capsys.readouterr().out
-    assert 'skipped (baseline non-finite)' in out
-    assert ' ok' not in [l for l in out.splitlines()
-                         if 'final_loss' in l][0]
-    # different trained step counts (bench scales steps to measured
-    # throughput): a loss delta would conflate convergence with speed
-    a.write_text(json.dumps(_bench_rec(final_loss=0.5,
-                                       final_loss_step=600)))
-    b.write_text(json.dumps(_bench_rec(final_loss=0.9,
-                                       final_loss_step=300)))
-    assert bench_diff.main([str(a), str(b)]) == 0
-    out = capsys.readouterr().out
-    assert 'skipped (trained 600 vs 300 steps)' in out
-    # equal step counts still gate
-    b.write_text(json.dumps(_bench_rec(final_loss=0.9,
-                                       final_loss_step=600)))
-    assert bench_diff.main([str(a), str(b)]) == 1
-    assert 'REGRESSION: final_loss' in capsys.readouterr().out
-
 
 def test_telemetry_watch_renders_dynamics_and_sparkline():
     """The watch frame shows the per-layer dynamics roll-up (worst

@@ -86,8 +86,8 @@ KERNELS = [
     ('softmax_8192x1024', pk.fused_softmax, [((8192, 1024), BF16)]),
     ('softmax_32x1000', pk.fused_softmax, [((32, 1000), F32)]),
     ('xent_32x1000', pk.softmax_xent, [((32, 1000), F32), ((32,), I32)]),
-    # the repo's own decoder (bench.py build_decoder: vocab 16384, 8x1024
-    # tokens a step) and a 32000-word LM head: refused before the row
+    # a decoder's head (vocab 16384, 8x1024 tokens a step, the shape
+    # chip_smoke.py runs) and a 32000-word LM head: refused before the row
     # block was taken from the row width
     ('xent_8192x16384', pk.softmax_xent,
      [((8192, 16384), BF16), ((8192,), I32)]),

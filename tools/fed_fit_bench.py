@@ -20,7 +20,7 @@ eligible). Reference roles: example/image-classification/train_imagenet
 + src/io/iter_image_recordio_2.cc:122-130 (inline augment).
 
 Prints ONE json line: {"metric": "fed_modulefit_resnet50", ...}.
-Budget: MXTPU_BENCH_BUDGET seconds (default 600).
+Budget: MXTPU_FED_BUDGET seconds (default 600).
 """
 import json
 import os
@@ -35,8 +35,8 @@ N_IMAGES = int(os.environ.get('MXTPU_FED_IMAGES', 2048))
 SRC = int(os.environ.get('MXTPU_FED_SRC', 256))
 CROP = int(os.environ.get('MXTPU_FED_CROP', 224))
 assert CROP <= SRC, 'crop %d exceeds source %d' % (CROP, SRC)
-BATCH = int(os.environ.get('MXTPU_BENCH_BATCH', 32))
-BUDGET = float(os.environ.get('MXTPU_BENCH_BUDGET', 600))
+BATCH = int(os.environ.get('MXTPU_FED_BATCH', 32))
+BUDGET = float(os.environ.get('MXTPU_FED_BUDGET', 600))
 REC = os.environ.get('MXTPU_FED_REC',
                      '/tmp/fed_rawrnd_%dx%d_%d.rec' % (SRC, SRC, N_IMAGES))
 

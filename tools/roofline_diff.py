@@ -7,11 +7,8 @@ round by diffing a before/after pair::
 
     python tools/roofline_diff.py before.jsonl after.jsonl
 
-Each argument is either a telemetry JSONL log (the LAST ``roofline``
-record wins, like tools/roofline_report.py) or a BENCH_r*.json
-artifact (the ``telemetry.roofline`` section, harness wrapper or raw
-JSON-lines form — bench truncates its ``layers`` list to the summary
-top-N, so a JSONL log is the complete view).
+Each argument is a telemetry JSONL log (the LAST ``roofline`` record
+wins, like tools/roofline_report.py).
 
 Layers are matched by name. For each: time delta, headroom delta
 (positive ``reclaimed`` = the after-run sits closer to its roofline),
@@ -35,18 +32,9 @@ if TOOLS not in sys.path:
 
 
 def load_roofline(path):
-    """The authoritative roofline analysis dict out of one artifact:
-    a telemetry JSONL's last roofline/summary record, or a bench
-    artifact's telemetry.roofline section."""
-    with open(path) as f:
-        text = f.read()
-    # bench artifact first: one JSON dict (harness wrapper or bare
-    # metric dict), or bench stdout JSON lines
-    for candidate in _json_candidates(text):
-        roof = _bench_roofline(candidate)
-        if roof is not None:
-            return roof
-    # telemetry JSONL: reuse the report tools' loader conventions
+    """The authoritative roofline analysis dict out of one telemetry
+    JSONL: its last roofline/summary record."""
+    # reuse the report tools' loader conventions
     from telemetry_report import load
     from roofline_report import roofline_records
     recs = roofline_records(load(path))
@@ -54,35 +42,7 @@ def load_roofline(path):
         return recs[-1][1]
     raise SystemExit(
         'roofline_diff: %s holds no roofline record (need a telemetry '
-        'JSONL from MXTPU_ROOFLINE=1 or a BENCH json with a '
-        'telemetry.roofline section)' % path)
-
-
-def _json_candidates(text):
-    try:
-        data = json.loads(text)
-        if isinstance(data, dict):
-            yield data
-            if isinstance(data.get('parsed'), dict):
-                yield data['parsed']
-    except ValueError:
-        pass
-    for line in reversed(text.strip().splitlines()):
-        try:
-            d = json.loads(line)
-        except ValueError:
-            continue
-        if isinstance(d, dict):
-            yield d
-
-
-def _bench_roofline(rec):
-    tel = rec.get('telemetry')
-    if isinstance(tel, dict) and isinstance(tel.get('roofline'), dict):
-        return tel['roofline']
-    if isinstance(rec.get('roofline'), dict):   # bare telemetry section
-        return rec['roofline']
-    return None
+        'JSONL from MXTPU_ROOFLINE=1)' % path)
 
 
 def diff(old, new):
@@ -157,12 +117,11 @@ def render(d, old_path, new_path, top=None):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(
-        description='Diff two roofline records (telemetry JSONL or '
-                    'BENCH json): per-layer headroom reclaimed, class '
-                    'transitions, step-time movement — the re-measure '
-                    'step of the MFU-gap workflow.')
-    ap.add_argument('old', help='baseline artifact (JSONL or BENCH json)')
-    ap.add_argument('new', help='candidate artifact (JSONL or BENCH json)')
+        description='Diff two roofline records (telemetry JSONL): '
+                    'per-layer headroom reclaimed, class transitions, '
+                    'step-time movement.')
+    ap.add_argument('old', help='baseline telemetry JSONL')
+    ap.add_argument('new', help='candidate telemetry JSONL')
     ap.add_argument('--top', type=int, default=16,
                     help='rows rendered (default 16; 0 = all)')
     ap.add_argument('--json', action='store_true',

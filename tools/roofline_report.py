@@ -14,7 +14,7 @@ Uses the SAME renderer as the live end-of-run summary
 is byte-identical to the one the run logged — the round-trip the
 roofline tests pin. ``--json`` dumps the raw analysis dict instead
 (for scripting: jq over layers/classes/headroom). Multiple records
-(several write_summary calls, or several bench rounds appending to one
+(several write_summary calls, or several runs appending to one
 log) keep the LAST one — the end-of-run view — unless ``--all`` lists
 every one with its timestamp.
 """

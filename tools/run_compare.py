@@ -7,8 +7,8 @@ why? Each run's telemetry JSONL (MXTPU_TELEMETRY_PATH, with
 ``MXTPU_SCALARS_EVERY`` banking the `scalars` timeseries and
 ``MXTPU_DYNAMICS`` the per-layer `dynamics` records) is a complete
 ledger: manifest, loss curve, step times, per-layer dynamics. This
-tool diffs them with the same verdict/exit-code discipline as
-``tools/bench_diff.py``::
+tool diffs them, with a verdict per metric and exit code 1 on a
+regression::
 
     python tools/run_compare.py baseline.jsonl candidate.jsonl
 
@@ -375,8 +375,7 @@ def main(argv=None):
         description='Diff two or more runs by their telemetry ledgers '
                     '(manifest, scalars timeseries, per-layer dynamics) '
                     'with per-metric tolerance; non-zero exit on a '
-                    'regressed or diverged candidate — the run-level '
-                    'sibling of tools/bench_diff.py '
+                    'regressed or diverged candidate '
                     '(docs/observability.md, "Comparing runs").')
     ap.add_argument('baseline', help='baseline telemetry JSONL')
     ap.add_argument('candidates', nargs='+',

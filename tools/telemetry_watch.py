@@ -3,7 +3,7 @@
 
 Polls the live endpoint a run exposes with ``MXTPU_TELEMETRY=1
 MXTPU_TELEMETRY_PORT=<p>`` (telemetry/serve.py) — or tails a JSONL log
-when given a file path — and renders throughput, MFU, run health and
+when given a file path — and renders throughput, run health and
 the per-host cluster spread, refreshing in place::
 
     python tools/telemetry_watch.py http://tpu-host:9100
@@ -133,8 +133,6 @@ def render(summary, steps_per_s=None, reqs_per_s=None):
     if sps is not None:
         rate_bits.append('%s samples/s' % _fmt(float(sps)))
     lines.append('  throughput   %s' % (', '.join(rate_bits) or '-'))
-    if g.get('xla.mfu') is not None:
-        lines.append('  mfu          %.1f%%' % (100.0 * float(g['xla.mfu'])))
     fb = h.get('fit.batch')
     if fb and fb.get('count'):
         lines.append('  step_time    p50 %s ms  p95 %s ms'
@@ -222,8 +220,7 @@ def render(summary, steps_per_s=None, reqs_per_s=None):
         # per device. The comm share is the STEP's whole collective
         # share (roofline accounting — grad sync + the update's
         # reduce-scatter/all-gather + any tp/pp traffic), labeled as
-        # such: the update-only split lives in bench's
-        # update_comm_bytes
+        # such
         bits = ['%.1f MiB/device'
                 % (g['update.opt_state_bytes_per_device'] / 2.0**20),
                 'sharded' if g.get('update.sharded')
