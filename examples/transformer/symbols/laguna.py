@@ -30,6 +30,11 @@ MXTPU_F16_AS_BF16) and a ``multi_precision`` optimizer keeps float32
 masters, as for the image-classification symbols. ``remat``: each block is
 one mirrored stage (``__force_mirroring__``), recomputed in the backward
 pass, so that a step keeps one block's activations and not all of them.
+Of a block a stage keeps what it reads, what leaves it, and what an op
+inside named as dear to recompute: the attention kernel's output and
+log-sum-exp (``ops/pallas_kernels.py``), so the kernel's forward pass
+runs once a step; projections, rotary positions, norms, the MLP and the
+expert layer are computed again.
 """
 import mxnet_tpu as mx
 

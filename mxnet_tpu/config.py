@@ -124,8 +124,10 @@ flags.declare('MXTPU_NO_NATIVE', bool, False,
               'Skip loading/building the native runtime library '
               '(pure-python fallbacks for engine/recordio/profiler)')
 flags.declare('MXTPU_BACKWARD_DO_MIRROR', str, '0',
-              "Gradient-memory tradeoff: '1' (or any truthy value) = full "
+              "Gradient-memory tradeoff: '1' (or any truthy value) = "
               "rematerialization of the forward under jax.checkpoint, "
+              "all but the values an op named as dear to recompute "
+              "(an attention kernel's output and log-sum-exp), "
               "'dots' = keep matmul results (checkpoint_dots policy), "
               "'0'/''/'false' = off (legacy spellings honored)",
               aliases=('MXNET_BACKWARD_DO_MIRROR',))

@@ -34,13 +34,23 @@ from .ops import registry as _reg
 __all__ = ['Executor', 'simple_bind']
 
 
+def _note_kept(kept):
+    """What the mirrored stages of the training program just traced keep
+    for its backward pass (0 where it was traced without one)."""
+    from . import telemetry as _tele
+    _tele.gauge('executor.mirror_kept').set(len(kept))
+    _tele.gauge('executor.mirror_kept_bytes').set(sum(kept))
+
+
 def mirror_wrap(f):
     """Gradient-memory tradeoff ≙ XLA rematerialization.
 
     Reference: MXNET_BACKWARD_DO_MIRROR (graph_executor.cc:273-287) marks
     cheap forward nodes for recompute in backward. Here the same knob is a
     jax.checkpoint policy applied to the whole traced forward:
-      MXTPU_BACKWARD_DO_MIRROR=1     full remat (max memory saving)
+      MXTPU_BACKWARD_DO_MIRROR=1     recompute all but what an op named as
+                                     dear (``ops.registry.mirrored``, as
+                                     a symbol's mirrored stages)
       MXTPU_BACKWARD_DO_MIRROR=dots  keep matmul results, recompute the rest
                                      (closest to the reference's heuristic
                                      of mirroring everything but convolution
@@ -56,7 +66,14 @@ def mirror_wrap(f):
     if val == 'dots':
         policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         return jax.checkpoint(f, policy=policy)
-    return jax.checkpoint(f)
+
+    def g(*args):
+        kept = []
+        out = _reg.mirrored(f, kept)(*args)
+        _note_kept(kept)
+        return out
+
+    return g
 
 
 def _align_head(g, sharding):
@@ -103,9 +120,12 @@ class _GraphProgram:
         (``mx.AttrScope(__force_mirroring__=<stage>)``, graph_executor.cc's
         per-node mirror mark) are recomputed in the backward pass instead
         of stored: consecutive marked op nodes with the same value form one
-        stage, run under ``jax.checkpoint`` when training, so that only
-        what the stage reads and what leaves it is kept. A builder gives
-        each block of a deep network its own value."""
+        stage, run as ``ops.registry.mirrored`` when training. It keeps
+        what it reads, what leaves it, and the values an op inside it named
+        as dear to recompute (``ops.registry.dear``); everything else it
+        computes again. The gauges ``executor.mirror_kept`` and ``_bytes`` say how
+        many such values the traced program keeps, and their size. A
+        builder gives each block of a deep network its own value."""
         topo = self.topo
         arg_index = {n: i for i, n in enumerate(self.arg_names)}
         aux_index = {n: i for i, n in enumerate(self.aux_names)}
@@ -149,6 +169,7 @@ class _GraphProgram:
         def run(arg_arrays, aux_arrays, key, is_train):
             env = {}
             new_aux = dict()
+            kept = []
             for node in topo:
                 if node.is_variable():
                     if node.name in aux_index:
@@ -167,10 +188,12 @@ class _GraphProgram:
                         exec_node(local, ni, key, is_train, aux_up)
                     return [local[k] for k in leaves], aux_up
 
-                outs, aux_up = jax.checkpoint(stage)(
+                outs, aux_up = _reg.mirrored(stage, kept)(
                     [env[k] for k in reads], key)
                 env.update(zip(leaves, outs))
                 new_aux.update(aux_up)
+            if is_train:
+                _note_kept(kept)
             out_arrays = tuple(env[_entry_key(n, i)] for n, i in outputs)
             aux_out = tuple(new_aux.get(i, aux_arrays[i])
                             for i in range(len(self.aux_names)))

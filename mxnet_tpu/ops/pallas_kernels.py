@@ -36,6 +36,8 @@ from jax.experimental import pallas as pl
 from jax.extend.core import Primitive
 from jax.interpreters import batching, mlir
 
+from .registry import dear
+
 __all__ = ['flash_attention', 'flash_attention_lse', 'fused_rmsnorm',
            'fused_layernorm', 'fused_softmax', 'softmax_xent']
 
@@ -918,6 +920,9 @@ def _blockwise_fwd(q, k, v, heads, kv_heads, causal, window, scale, block_q,
                    block_k, name):
     out, lse = attention_forward(q, k, v, heads, kv_heads, causal, window,
                                  scale, block_q, block_k, name)
+    # what attention_backward needs of the forward kernel: a mirrored
+    # stage keeps the two and recomputes q, k and v, not the kernel
+    out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
     return out, (q, k, v, out, lse)
 
 

@@ -22,9 +22,11 @@ Conventions:
 """
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..base import MXNetError, normalize_attrs, attr_key
 
@@ -168,6 +170,57 @@ def _flush_invoked():
 if _COVERING:
     import atexit
     atexit.register(_flush_invoked)
+
+
+# -- values dear to recompute -------------------------------------------------
+# A mirrored stage recomputes in the backward pass everything but what an
+# op has named here: a value that costs far more to make again than to
+# hold (a kernel's output against a projection's).
+
+_DEAR = set()                   # every name `dear` was given
+_MIRROR = threading.local()     # .kept: the list of the stage being traced
+
+
+def dear(x, name):
+    """Name ``x`` as dear to recompute: the identity, except that a
+    mirrored stage keeps the value for its backward pass. Inside a
+    ``jax.custom_vjp`` forward rule, name the residuals themselves: the
+    policy judges each value where it is made, and a value left unnamed
+    there brings back the whole computation behind it."""
+    _DEAR.add(name)
+    kept = getattr(_MIRROR, 'kept', None)
+    if kept is not None:
+        kept.append(x.size * x.dtype.itemsize)
+    return checkpoint_name(x, name)
+
+
+def keeps_dear(prim, *avals, **params):
+    """The ``jax.checkpoint`` policy of a mirrored stage: keep what
+    :func:`dear` named, recompute the rest. It reads the names when it is
+    asked, which is after the stage has been traced; a stage in which no
+    op named anything keeps nothing."""
+    return jax.checkpoint_policies.save_only_these_names(*_DEAR)(
+        prim, *avals, **params)
+
+
+def mirrored(f, kept):
+    """``f`` as a mirrored stage: its backward pass recomputes ``f`` from
+    its inputs, except the values an op inside named as :func:`dear`
+    (an attention kernel's output and log-sum-exp), which are kept. With
+    no value named that is a bare ``jax.checkpoint``. Called under
+    ``jax.vjp``, the result appends the bytes of each value it keeps to
+    ``kept``."""
+    stage = jax.checkpoint(f, policy=keeps_dear)
+
+    def g(*args):
+        outer = getattr(_MIRROR, 'kept', None)
+        _MIRROR.kept = kept
+        try:
+            return stage(*args)
+        finally:
+            _MIRROR.kept = outer
+
+    return g
 
 
 @functools.lru_cache(maxsize=None)

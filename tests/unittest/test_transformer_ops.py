@@ -15,6 +15,7 @@ by path: there is one copy), at small widths on the CPU in float32:
 """
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -352,6 +353,211 @@ def test_mirrored_blocks_are_stages_of_their_own():
     assert not [p for p in _GraphProgram(
         builder.get_symbol(CFG, remat=False))._mirror_plan()
         if p[1] is not None]
+
+
+# -- what a mirrored stage keeps ----------------------------------------------
+
+def _one_block(kind, **kw):
+    """The model cut to one block of `kind` attention and a dense MLP."""
+    cfg = dict(CFG, num_hidden_layers=1, layer_types=[kind],
+               mlp_layer_types=['dense'], num_attention_heads_per_layer=[6])
+    return builder.get_symbol(cfg, **kw), cfg
+
+
+def _resnet_unit(mirror):
+    resnet = _load('examples/image-classification/symbols/resnet.py',
+                   'resnet_symbol')
+    with mx.AttrScope(**({'__force_mirroring__': 'unit'} if mirror else {})):
+        body = resnet.residual_unit(mx.sym.Variable('data'), 8, (1, 1),
+                                    False, 'unit', True)
+    return mx.sym.MakeLoss(mx.sym.sum(body))
+
+
+def _training_step(sym, seed=1, **input_shapes):
+    """(step, parameters): step(parameters) -> (outputs, gradients) of the
+    symbol's runner, traced as the executor and the fused window trace it."""
+    from mxnet_tpu.executor import _GraphProgram, mirror_wrap
+    prog = _GraphProgram(sym)
+    run = prog.make_runner()
+    shapes, _, aux_shapes = sym.infer_shape(**input_shapes)
+    rng = np.random.RandomState(seed)
+    args = [jnp.asarray(rng.randint(0, 90, s).astype(np.float32))
+            if n in input_shapes and len(s) == 2 else
+            jnp.asarray((rng.randn(*s) / np.sqrt(s[-1])).astype(np.float32))
+            for n, s in zip(prog.arg_names, shapes)]
+    aux = tuple(jnp.ones(s, jnp.float32) for s in aux_shapes)
+    wrt_at = [i for i, n in enumerate(prog.arg_names)
+              if n not in input_shapes]
+
+    def step(wrt):
+        def f(wrt):
+            full = list(args)
+            for i, w in zip(wrt_at, wrt):
+                full[i] = w
+            return run(tuple(full), aux, jnp.zeros((2,), jnp.uint32),
+                       True)[0]
+
+        outs, vjp = jax.vjp(mirror_wrap(f), wrt)
+        return outs, vjp(tuple(jnp.ones_like(o) for o in outs))[0]
+
+    return step, tuple(args[i] for i in wrt_at)
+
+
+def _kernel_calls(text, name):
+    return {k: text.count('name=%s_%s' % (name, k))
+            for k in ('fwd', 'dq', 'dkv')}
+
+
+def _bare_checkpoint(monkeypatch):
+    """The stage as it was before an op could name a value."""
+    monkeypatch.setattr(registry, 'mirrored',
+                        lambda f, kept: jax.checkpoint(f))
+
+
+KINDS = {'full': ('full_attention', 'attention_full'),
+         'window': ('sliding_attention', 'attention_window')}
+LM_IN = dict(data=(2, T), softmax_label=(2, T))
+
+
+def _forward_kernel_runs_once(kind, monkeypatch):
+    """(a) in the gradient of a mirrored block the forward kernel is there
+    as often as each backward kernel, once; under a bare checkpoint twice."""
+    kind, name = KINDS[kind]
+    step, wrt = _training_step(_one_block(kind)[0], **LM_IN)
+    calls = _kernel_calls(str(jax.make_jaxpr(step)(wrt)), name)
+    assert calls['fwd'] == calls['dq'] == calls['dkv'] > 0, calls
+    _bare_checkpoint(monkeypatch)
+    step, wrt = _training_step(_one_block(kind)[0], **LM_IN)
+    bare = _kernel_calls(str(jax.make_jaxpr(step)(wrt)), name)
+    assert bare['fwd'] == 2 * calls['fwd'] and bare['dq'] == calls['dq']
+
+
+def _gradients_are_the_bare_checkpoints(kind, monkeypatch):
+    """(b) the kept values are the ones a recomputation makes: loss and
+    every gradient bit-equal."""
+    sym = _one_block(KINDS[kind][0])[0]
+    step, wrt = _training_step(sym, **LM_IN)
+    outs, grads = jax.jit(step)(wrt)
+    _bare_checkpoint(monkeypatch)
+    step, wrt = _training_step(sym, **LM_IN)
+    outs_bare, grads_bare = jax.jit(step)(wrt)
+    for a, b in zip(outs + grads, outs_bare + grads_bare):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert all(np.abs(np.asarray(g)).max() > 0 for g in grads)
+
+
+def _no_stage_lowers_as_before(which, monkeypatch):
+    """(c) a symbol with no mirrored stage meets no checkpoint and no
+    policy, and a name outside a stage lowers to nothing."""
+    if which == 'resnet_unit':
+        sym, shapes = _resnet_unit(False), dict(data=(2, 8, 6, 6))
+    else:
+        sym = _one_block('full_attention', remat=False)[0]
+        shapes = LM_IN
+
+    def refuse(*_):
+        raise AssertionError('a mirrored stage in an unmirrored symbol')
+
+    monkeypatch.setattr(registry, 'mirrored', refuse)
+    monkeypatch.setattr(registry, 'keeps_dear', refuse)
+    step, wrt = _training_step(sym, **shapes)
+    jaxpr = str(jax.make_jaxpr(step)(wrt))
+    assert 'checkpoint' not in jaxpr and 'remat' not in jaxpr
+    assert ('name[' in jaxpr) == (which != 'resnet_unit')
+    text = jax.jit(step).lower(wrt).as_text()
+    monkeypatch.setattr(pk, 'dear', lambda x, name: x)
+    step, wrt = _training_step(sym, **shapes)
+    assert 'name[' not in str(jax.make_jaxpr(step)(wrt))
+    unnamed = jax.jit(step).lower(wrt).as_text()
+    if which != 'resnet_unit':
+        # a name is no operation of the lowered program; it does take a
+        # number from the counter behind the private functions' names
+        text, unnamed = (re.sub(r'(@\w+?)_\d+\b', r'\1', t)
+                         for t in (text, unnamed))
+    assert unnamed == text
+
+
+def _a_stage_with_no_name_keeps_nothing(_, monkeypatch):
+    """(d) no op of the stage named a value: the policy is asked and saves
+    nothing, and the gradient is the bare checkpoint's."""
+    asked, policy = [], registry.keeps_dear
+
+    def spy(prim, *avals, **params):
+        asked.append(policy(prim, *avals, **params))
+        return asked[-1]
+
+    monkeypatch.setattr(registry, 'keeps_dear', spy)
+    step, wrt = _training_step(_resnet_unit(True), data=(2, 8, 6, 6))
+    jaxpr = str(jax.make_jaxpr(step)(wrt))
+    assert 'remat' in jaxpr and 'name[' not in jaxpr
+    assert asked and not any(asked)
+    outs, grads = jax.jit(step)(wrt)
+    _bare_checkpoint(monkeypatch)
+    step, wrt = _training_step(_resnet_unit(True), data=(2, 8, 6, 6))
+    for a, b in zip(outs + grads, sum(jax.jit(step)(wrt), ())):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _the_whole_forward_mirror_keeps_them_too(kind, monkeypatch):
+    """MXTPU_BACKWARD_DO_MIRROR=1 over a symbol without stages: the same
+    policy, so the forward kernel runs once there too."""
+    kind, name = KINDS[kind]
+    monkeypatch.setenv('MXTPU_BACKWARD_DO_MIRROR', '1')
+    try:
+        step, wrt = _training_step(_one_block(kind, remat=False)[0], **LM_IN)
+        jaxpr = str(jax.make_jaxpr(step)(wrt))
+    finally:
+        monkeypatch.delenv('MXTPU_BACKWARD_DO_MIRROR')
+        flags.reload('MXTPU_BACKWARD_DO_MIRROR')
+    calls = _kernel_calls(jaxpr, name)
+    assert 'remat' in jaxpr
+    assert calls['fwd'] == calls['dq'] == calls['dkv'] > 0, calls
+
+
+def _the_gauge_is_the_bytes_of_out_and_lse(_, monkeypatch):
+    """(e) executor.mirror_kept_bytes: each block's attention output in the
+    model's dtype and its log-sum-exp in float32, from the shapes."""
+    monkeypatch.setenv('MXTPU_TELEMETRY', '1')
+    monkeypatch.setenv('MXTPU_TELEMETRY_PATH', os.devnull)
+    _reload_telemetry()
+    try:
+        step, wrt = _training_step(builder.get_symbol(CFG), **LM_IN)
+        jax.make_jaxpr(step)(wrt)
+        gauges = telemetry.snapshot()['gauges']
+        heads = CFG['num_attention_heads_per_layer']
+        assert gauges['executor.mirror_kept'] == 2 * len(heads)
+        assert gauges['executor.mirror_kept_bytes'] == sum(
+            2 * T * H * D * 4 + 2 * H * T * 4 for H in heads)
+        # a symbol without a mirrored stage keeps nothing
+        step, wrt = _training_step(builder.get_symbol(CFG, remat=False),
+                                   **LM_IN)
+        jax.make_jaxpr(step)(wrt)
+        gauges = telemetry.snapshot()['gauges']
+        assert gauges['executor.mirror_kept'] == 0
+        assert gauges['executor.mirror_kept_bytes'] == 0
+    finally:
+        monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
+        _reload_telemetry()
+
+
+@pytest.mark.parametrize('path', ['kernel'], indirect=True)
+@pytest.mark.parametrize('case,arg', [
+    (_forward_kernel_runs_once, 'full'),
+    (_forward_kernel_runs_once, 'window'),
+    (_gradients_are_the_bare_checkpoints, 'full'),
+    (_gradients_are_the_bare_checkpoints, 'window'),
+    (_no_stage_lowers_as_before, 'resnet_unit'),
+    (_no_stage_lowers_as_before, 'laguna_without_remat'),
+    (_a_stage_with_no_name_keeps_nothing, 'resnet_unit'),
+    (_the_whole_forward_mirror_keeps_them_too, 'full'),
+    (_the_gauge_is_the_bytes_of_out_and_lse, 'five_blocks'),
+], ids=lambda v: v if isinstance(v, str) else v.__name__.strip('_'))
+def test_a_mirrored_stage_keeps_what_an_op_named_as_dear(
+        path, case, arg, monkeypatch):
+    """A mirrored stage recomputes its block in the backward pass except
+    the values an op named as dear (``registry.dear``: the attention
+    kernel's output and log-sum-exp), on the kernels' path."""
+    case(arg, monkeypatch)
 
 
 def _fit(cfg, steps, monkeypatch, lr=0.05):
