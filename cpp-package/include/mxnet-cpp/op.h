@@ -461,6 +461,23 @@ inline Symbol LRN(const std::string &symbol_name,
   return op.CreateSymbol(symbol_name);
 }
 
+inline Symbol LatentAttention(const std::string &symbol_name,
+    const Symbol &q_nope,
+    const Symbol &q_rope,
+    const Symbol &k_nope,
+    const Symbol &k_rope,
+    const Symbol &value,
+    int num_heads = 1) {
+  Operator op("LatentAttention");
+  op.SetInput("q_nope", q_nope);
+  op.SetInput("q_rope", q_rope);
+  op.SetInput("k_nope", k_nope);
+  op.SetInput("k_rope", k_rope);
+  op.SetInput("value", value);
+  op.SetParam("num_heads", detail::ToParam(num_heads));
+  return op.CreateSymbol(symbol_name);
+}
+
 inline Symbol LayerNorm(const std::string &symbol_name,
     const Symbol &data,
     const Symbol &gamma,
@@ -549,6 +566,7 @@ inline Symbol MoE(const std::string &symbol_name,
     const Symbol &shared_w3_weight,
     const Symbol &shared_w2_weight,
     const Symbol &stats,
+    const Symbol &select_bias,
     int num_experts = 0,
     int num_experts_per_tok = 1,
     int experts_held = 0,
@@ -556,7 +574,8 @@ inline Symbol MoE(const std::string &symbol_name,
     bool norm_topk_prob = true,
     mx_float routed_scaling = 1.0f,
     int hidden = 0,
-    int shared_hidden = 0) {
+    int shared_hidden = 0,
+    const std::string &scoring = "softmax") {
   Operator op("MoE");
   op.SetInput("data", data);
   op.SetInput("router_weight", router_weight);
@@ -567,6 +586,7 @@ inline Symbol MoE(const std::string &symbol_name,
   op.SetInput("shared_w3_weight", shared_w3_weight);
   op.SetInput("shared_w2_weight", shared_w2_weight);
   op.SetInput("stats", stats);
+  op.SetInput("select_bias", select_bias);
   op.SetParam("num_experts", detail::ToParam(num_experts));
   op.SetParam("num_experts_per_tok", detail::ToParam(num_experts_per_tok));
   op.SetParam("experts_held", detail::ToParam(experts_held));
@@ -575,6 +595,7 @@ inline Symbol MoE(const std::string &symbol_name,
   op.SetParam("routed_scaling", detail::ToParam(routed_scaling));
   op.SetParam("hidden", detail::ToParam(hidden));
   op.SetParam("shared_hidden", detail::ToParam(shared_hidden));
+  if (!scoring.empty()) op.SetParam("scoring", detail::ToParam(scoring));
   return op.CreateSymbol(symbol_name);
 }
 
@@ -711,7 +732,8 @@ inline Symbol RotaryEmbedding(const std::string &symbol_name,
     int original_max_position = 0,
     mx_float beta_fast = 32.0f,
     mx_float beta_slow = 1.0f,
-    mx_float attention_factor = 0.0f) {
+    mx_float attention_factor = 0.0f,
+    bool interleaved = false) {
   Operator op("RotaryEmbedding");
   op.SetInput("data", data);
   op.SetParam("num_heads", detail::ToParam(num_heads));
@@ -723,6 +745,7 @@ inline Symbol RotaryEmbedding(const std::string &symbol_name,
   op.SetParam("beta_fast", detail::ToParam(beta_fast));
   op.SetParam("beta_slow", detail::ToParam(beta_slow));
   op.SetParam("attention_factor", detail::ToParam(attention_factor));
+  op.SetParam("interleaved", detail::ToParam(interleaved));
   return op.CreateSymbol(symbol_name);
 }
 

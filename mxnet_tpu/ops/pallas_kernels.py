@@ -1038,3 +1038,290 @@ def grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
             pl.BlockSpec((1, K, tn),
                          lambda n, t, tg, nt: (tg[tile(t, nt)], 0, n)))),
         tile_group, n_tiles, x, y)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention: keys wider than values, one rotary key head for all heads
+# ---------------------------------------------------------------------------
+# A head's score is the sum of two products: its own ``q_nope k_nope^T`` and
+# ``q_rope k_rope^T`` against the one rotary key head that every head shares
+# (k_rope [B, T, Dr], never broadcast). Values are narrower than keys
+# (Dn + Dr against Dv), so the kernels above, which take one head size for
+# all three, do not fit; these are a family of their own over the same walk
+# (`_attn_walk`), mask (`_visible`) and blocks (`_attn_blocks`), and the
+# grouped-query kernels stay as they were. q_nope, k_nope and v keep the
+# projections' layout [B, T, H * D]; a head's 64 rotary query columns are no
+# legal block of [B, T, H * 64] (the minor block is 128 lanes or the whole
+# axis), so q_rope and its gradient cross as [B, H, T, Dr]. The shared key's
+# gradient is a sum over heads: the dkv kernel writes each head's part in
+# float32 and the sum is one reduction outside.
+
+def _latent_scores(qn, qr, kn, kr, scale):
+    return (_dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))) * scale
+
+
+def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
+                       m_s, l_s, acc_s, *, geo, scale):
+    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    qi, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = keys_of(qi)
+
+    @pl.when(step == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        v = v_ref[0]
+        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
+                        window)
+        s = jnp.where(seen, _latent_scores(qn_ref[0], qr_ref[0, 0],
+                                           kn_ref[0], kr_ref[0], scale), _NEG)
+        m = m_s[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + _dot(p.astype(v.dtype), v,
+                                              ((1,), (0,)))
+        m_s[...] = m_new
+
+    @pl.when(step == steps - 1)
+    def _():
+        l = jnp.maximum(l_s[...], 1e-30)
+        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
+
+
+def _latent_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                      delta_ref, dqn_ref, dqr_ref, dqn_s, dqr_s, *, geo,
+                      scale):
+    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    qi, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = keys_of(qi)
+
+    @pl.when(step == 0)
+    def _():
+        dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
+        dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        kn, kr, v, do = kn_ref[0], kr_ref[0], v_ref[0], do_ref[0]
+        s = _latent_scores(qn_ref[0], qr_ref[0, 0], kn, kr, scale)
+        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
+                        window)
+        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = (p * (dp - delta_ref[0, 0])).astype(kn.dtype)
+        dqn_s[...] += _dot(ds, kn, ((1,), (0,))) * scale
+        dqr_s[...] += _dot(ds, kr, ((1,), (0,))) * scale
+
+    @pl.when(step == steps - 1)
+    def _():
+        dqn_ref[0] = dqn_s[...].astype(dqn_ref.dtype)
+        dqr_ref[0, 0] = dqr_s[...].astype(dqr_ref.dtype)
+
+
+def _latent_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
+                       lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref, dkn_s,
+                       dkr_s, dv_s, *, geo, scale):
+    blk_q, blk_k, Tk, offset, causal, window, queries_of, steps = geo
+    kj, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = queries_of(kj)
+
+    @pl.when(step == 0)
+    def _():
+        dkn_s[...] = jnp.zeros(dkn_s.shape, jnp.float32)
+        dkr_s[...] = jnp.zeros(dkr_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        qn, qr, do = qn_ref[0], qr_ref[0, 0], do_ref[0]
+        s = _latent_scores(qn, qr, kn_ref[0], kr_ref[0], scale)
+        seen = _visible(lo + step, kj, blk_q, blk_k, Tk, offset, causal,
+                        window)
+        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dv_s[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
+        dp = _dot(do, v_ref[0], ((1,), (1,)))
+        ds = (p * (dp - delta_ref[0, 0])).astype(qn.dtype)
+        dkn_s[...] += _dot(ds, qn, ((0,), (0,))) * scale
+        dkr_s[...] += _dot(ds, qr, ((0,), (0,))) * scale
+
+    @pl.when(step == steps - 1)
+    def _():
+        dkn_ref[0] = dkn_s[...].astype(dkn_ref.dtype)
+        dkr_ref[0, 0] = dkr_s[...]
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _by_head(x, heads):
+    """[B, T, H * D] as [B, H, T, D]."""
+    B, T, HD = x.shape
+    return x.reshape(B, T, heads, HD // heads).transpose(0, 2, 1, 3)
+
+
+def _latent_dims(q_nope, k_rope, v, heads):
+    """(Dn, Dr, Dv, scale) from the operands' widths."""
+    Dn, Dr, Dv = q_nope.shape[2] // heads, k_rope.shape[2], \
+        v.shape[2] // heads
+    return Dn, Dr, Dv, (Dn + Dr) ** -0.5
+
+
+def _latent_specs(blk_q, blk_k, q_block, k_block):
+    """Block specs of a grid (batch, head, i, s) whose last two axes pick
+    the query block ``q_block(i, s)`` and the key block ``k_block(i, s)``:
+    (q, k) of width D for [B, T, H * D], (qh, kh) for [B, H, T, D], kr for
+    the shared rotary key [B, T, D]."""
+    def q(D):
+        return pl.BlockSpec((1, blk_q, D),
+                            lambda b, h, i, s: (b, q_block(i, s), h))
+
+    def k(D):
+        return pl.BlockSpec((1, blk_k, D),
+                            lambda b, h, i, s: (b, k_block(i, s), h))
+
+    def qh(D):
+        return pl.BlockSpec((1, 1, blk_q, D),
+                            lambda b, h, i, s: (b, h, q_block(i, s), 0))
+
+    def kh(D):
+        return pl.BlockSpec((1, 1, blk_k, D),
+                            lambda b, h, i, s: (b, h, k_block(i, s), 0))
+
+    def kr(D):
+        return pl.BlockSpec((1, blk_k, D),
+                            lambda b, h, i, s: (b, k_block(i, s), 0))
+
+    return q, k, qh, kh, kr
+
+
+def _walked(blocks_of):
+    """Index of the s-th block a block i walks, held on its last one."""
+    def block(i, s):
+        lo, hi = blocks_of(i)
+        return _imin(lo + s, hi)
+    return block
+
+
+def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
+                             block_q=512, block_k=512,
+                             name='attention_latent'):
+    """(out [B, T, H * Dv], lse [B, H, T]) of causal attention whose score
+    is ``(q_nope k_nope^T + q_rope k_rope^T) / sqrt(Dn + Dr)``: q_nope and
+    k_nope [B, T, H * Dn], q_rope [B, T, H * Dr], k_rope [B, T, Dr] (one
+    head, read by all), v [B, T, H * Dv]. The kernel is named
+    ``<name>_fwd`` in a device trace."""
+    B, T, _ = q_nope.shape
+    Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads)
+    blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
+    nq, _, keys_of, _, steps, _ = _attn_walk(
+        T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
+    geo = (blk_q, blk_k, T, 0, True, 0, keys_of, steps)
+    q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
+                                    _walked(keys_of))
+    out, lse = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_latent_fwd_kernel, geo=geo, scale=scale),
+        grid=(B, heads, nq, steps),
+        in_specs=[q(Dn), qh(Dr), k(Dn), kr(Dr), k(Dv)],
+        out_specs=[q(Dv), qh(1)],
+        out_shape=[jax.ShapeDtypeStruct((B, T + pad_q, heads * Dv), v.dtype),
+                   jax.ShapeDtypeStruct((B, heads, T + pad_q, 1),
+                                        jnp.float32)],
+        scratch_shapes=[_vmem((blk_q, 1)), _vmem((blk_q, 1)),
+                        _vmem((blk_q, Dv))],
+        compiler_params=_attn_params(3),
+        interpret=interpret, name=name + '_fwd'),
+        _pad_rows(q_nope, pad_q), _by_head(_pad_rows(q_rope, pad_q), heads),
+        _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
+        _pad_rows(v, pad_k))
+    return out[:, :T], lse[:, :, :T, 0]
+
+
+def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
+                              g_out, heads, block_q=512, block_k=512,
+                              name='attention_latent'):
+    """(dq_nope, dq_rope, dk_nope, dk_rope, dv) of
+    :func:`latent_attention_forward` from its output, its log-sum-exp and
+    the output's cotangent: ``<name>_dq`` over the key blocks of a query
+    block, ``<name>_dkv`` over the query blocks of a key block and head;
+    the shared rotary key's gradient is the sum of the heads' parts."""
+    B, T, _ = q_nope.shape
+    Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads)
+    blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
+    Tq, Tk = T + pad_q, T + pad_k
+    # delta_i = sum_d dO_id O_id, per head: the softmax's own term
+    delta = jnp.sum((g_out.astype(jnp.float32) * out.astype(jnp.float32))
+                    .reshape(B, T, heads, Dv), axis=-1).transpose(0, 2, 1)
+    col = lambda x: jnp.pad(  # noqa: E731
+        x.astype(jnp.float32), ((0, 0), (0, 0), (0, pad_q)))[..., None]
+    nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
+        T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
+    base = (blk_q, blk_k, T, 0, True, 0)
+    operands = (_pad_rows(q_nope, pad_q),
+                _by_head(_pad_rows(q_rope, pad_q), heads),
+                _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
+                _pad_rows(v, pad_k), _pad_rows(g_out, pad_q), col(lse),
+                col(delta))
+
+    def in_specs(q, k, qh, kr):
+        return [q(Dn), qh(Dr), k(Dn), kr(Dr), k(Dv), q(Dv), qh(1), qh(1)]
+
+    q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
+                                    _walked(keys_of))
+    dqn, dqr = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_latent_dq_kernel, geo=base + (keys_of, ksteps),
+                          scale=scale),
+        grid=(B, heads, nq, ksteps),
+        in_specs=in_specs(q, k, qh, kr), out_specs=[q(Dn), qh(Dr)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tq, heads * Dn), q_nope.dtype),
+                   jax.ShapeDtypeStruct((B, heads, Tq, Dr), q_rope.dtype)],
+        scratch_shapes=[_vmem((blk_q, Dn)), _vmem((blk_q, Dr))],
+        compiler_params=_attn_params(3),
+        interpret=interpret, name=name + '_dq'), *operands)
+
+    q, k, qh, kh, kr = _latent_specs(blk_q, blk_k, _walked(queries_of),
+                                     lambda j, s: j)
+    dkn, dkr, dv = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_latent_dkv_kernel,
+                          geo=base + (queries_of, qsteps), scale=scale),
+        grid=(B, heads, nk, qsteps),
+        in_specs=in_specs(q, k, qh, kr), out_specs=[k(Dn), kh(Dr), k(Dv)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tk, heads * Dn), k_nope.dtype),
+                   jax.ShapeDtypeStruct((B, heads, Tk, Dr), jnp.float32),
+                   jax.ShapeDtypeStruct((B, Tk, heads * Dv), v.dtype)],
+        scratch_shapes=[_vmem((blk_k, Dn)), _vmem((blk_k, Dr)),
+                        _vmem((blk_k, Dv))],
+        compiler_params=_attn_params(3),
+        interpret=interpret, name=name + '_dkv'), *operands)
+    dqr = dqr.transpose(0, 2, 1, 3).reshape(B, Tq, heads * Dr)
+    dkr = jnp.sum(dkr, axis=1).astype(k_rope.dtype)
+    return dqn[:, :T], dqr[:, :T], dkn[:, :T], dkr[:, :T], dv[:, :T]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads, block_q=512,
+                     block_k=512, name='attention_latent'):
+    """Causal latent attention in its expanded (training) form, forward
+    and backward by the kernels above; returns [B, T, H * Dv]."""
+    return latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
+                                    block_q, block_k, name)[0]
+
+
+def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, heads, block_q, block_k,
+                name):
+    out, lse = latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v,
+                                        heads, block_q, block_k, name)
+    # as _blockwise_fwd: a mirrored stage keeps the kernel's two outputs
+    out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
+    return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse)
+
+
+def _latent_bwd(heads, block_q, block_k, name, res, g):
+    return latent_attention_backward(*res, g, heads, block_q, block_k, name)
+
+
+latent_attention.defvjp(_latent_fwd, _latent_bwd)

@@ -65,7 +65,8 @@ class OpDef:
         self.shape_fn = shape_fn
         # {input_name: gate_attr}: the input exists only when the gate
         # attr is truthy (CTCLoss lengths, Sequence* sequence_length) —
-        # keeps symbol compose from fabricating variables for them
+        # keeps symbol compose from fabricating variables for them; a
+        # callable gate is asked with the attrs (MoE's select_bias)
         self.optional_inputs = dict(optional_inputs or {})
         self.doc = doc or (fn.__doc__ or '')
 
@@ -89,6 +90,8 @@ class OpDef:
         if self.optional_inputs:
             attrs = attrs or {}
             def _on(gate):
+                if callable(gate):
+                    return gate(attrs)
                 v = attrs.get(gate, self.param_defaults.get(gate, False))
                 return v not in (False, 'False', '0', 0, None, 'false')
             names = [n for n in names

@@ -1,6 +1,8 @@
 """Decoder-block ops: RMSNorm, rotary positions, grouped-query attention
-(causal, optionally windowed, with a per-head output gate), the gated MLP
-and a routed expert layer with a shared expert.
+(causal, optionally windowed, with a per-head output gate), latent
+attention in its expanded form (keys wider than values, one rotary key
+head for all heads), the gated MLP and a routed expert layer with a shared
+expert (softmax router, or sigmoid scores with a selection bias).
 
 Each is a pure JAX function like every op of the registry; gradients come
 from autodiff or, where a kernel runs, from a ``custom_vjp``. Activations
@@ -25,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from . import pallas_kernels as pk
-from .registry import register
+from .registry import get as registry_get, register
 
 __all__ = ['MOE_STATS', 'moe_stat_names']
 
@@ -91,11 +93,13 @@ def rope_inv_freq(head_dim, base, rotary_dim, scaling, factor, original_len,
           param_defaults={'num_heads': 1, 'base': 10000.0, 'rotary_dim': 0,
                           'scaling': 'default', 'factor': 1.0,
                           'original_max_position': 0, 'beta_fast': 32.0,
-                          'beta_slow': 1.0, 'attention_factor': 0.0})
+                          'beta_slow': 1.0, 'attention_factor': 0.0,
+                          'interleaved': False})
 def _rotary(attrs, x):
     """Rotary positions 0..T-1 on x [B, T, num_heads * D]: the first
     ``rotary_dim`` dimensions of each head (all of them if 0) are rotated,
-    half against half; the rest pass through."""
+    half against half, or with ``interleaved`` dimension 2i against 2i + 1;
+    the rest pass through."""
     B, T, HD = x.shape
     H = int(attrs.get('num_heads', 1))
     D = HD // H
@@ -111,9 +115,16 @@ def _rotary(attrs, x):
     cos = (jnp.cos(angle) * scale)[None, :, None, :]
     sin = (jnp.sin(angle) * scale)[None, :, None, :]
     x4 = x.reshape(B, T, H, D).astype(jnp.float32)
-    x1, x2, rest = x4[..., :half], x4[..., half:2 * half], x4[..., 2 * half:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest],
-                          axis=-1)
+    if attrs.get('interleaved', False):
+        pairs = x4[..., :2 * half].reshape(B, T, H, half, 2)
+        x1, x2, rest = pairs[..., 0], pairs[..., 1], x4[..., 2 * half:]
+        turned = [jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                            axis=-1).reshape(B, T, H, 2 * half)]
+    else:
+        x1, x2, rest = (x4[..., :half], x4[..., half:2 * half],
+                        x4[..., 2 * half:])
+        turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    out = jnp.concatenate(turned + [rest], axis=-1)
     return out.astype(x.dtype).reshape(B, T, HD)
 
 
@@ -180,6 +191,53 @@ def _gqa(attrs, q, k, v, gate=None):
 
 
 # ---------------------------------------------------------------------------
+# Latent attention, expanded form
+# ---------------------------------------------------------------------------
+
+def _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads):
+    """The plain form: one dense masked product, float32. For small shapes
+    off the TPU."""
+    B, T, _ = q_nope.shape
+    f32 = lambda x, n: x.reshape(B, T, n, -1).astype(jnp.float32)  # noqa
+    qn, qr, kn, v4 = (f32(q_nope, heads), f32(q_rope, heads),
+                      f32(k_nope, heads), f32(v, heads))
+    kr = k_rope.astype(jnp.float32)
+    s = (jnp.einsum('bqhd,bshd->bhqs', qn, kn)
+         + jnp.einsum('bqhd,bsd->bhqs', qr, kr)) \
+        * (qn.shape[-1] + qr.shape[-1]) ** -0.5
+    seen = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    out = jnp.einsum('bhqs,bshd->bqhd', p, v4)
+    return out.reshape(B, T, -1).astype(v.dtype)
+
+
+@register('LatentAttention',
+          input_names=['q_nope', 'q_rope', 'k_nope', 'k_rope', 'value'],
+          param_defaults={'num_heads': 1})
+def _latent_attention(attrs, q_nope, q_rope, k_nope, k_rope, v):
+    """Causal multi-head latent attention as training computes it, with the
+    keys and values expanded from the latent: q_nope and k_nope
+    [B, T, H * Dn], q_rope [B, T, H * Dr] and k_rope [B, T, Dr], the one
+    rotary key head every query head reads, value [B, T, H * Dv]; the head
+    sizes are read from the widths. Head h's score is
+    ``(q_nope_h k_nope_h^T + q_rope_h k_rope^T) / sqrt(Dn + Dr)``,
+    position t sees s <= t. Returns [B, T, H * Dv].
+
+    On a TPU the kernels ``attention_latent_fwd``, ``_dq`` and ``_dkv`` run
+    it: no [T, T] array exists and the rotary key is never broadcast."""
+    H = int(attrs['num_heads'])
+
+    def fused(*operands):
+        return pk.latent_attention(*operands, H, 512, 512,
+                                   'attention_latent')
+
+    def plain(*operands):
+        return _dense_latent_attention(*operands, H)
+
+    return pk.dispatch(fused, plain, q_nope, q_rope, k_nope, k_rope, v)
+
+
+# ---------------------------------------------------------------------------
 # Gated MLP
 # ---------------------------------------------------------------------------
 
@@ -202,6 +260,10 @@ def _gated_mlp_op(attrs, x, w1, w3, w2):
 # Routed experts
 # ---------------------------------------------------------------------------
 
+def _sigmoid_scoring(attrs):
+    return str(attrs.get('scoring', 'softmax')) == 'sigmoid'
+
+
 # what the layer writes into its ``stats`` auxiliary state each step
 MOE_STATS = ('pairs', 'tokens', 'dropped', 'load_max', 'load_max_over_mean')
 
@@ -210,9 +272,10 @@ def moe_stat_names(symbol):
     """Names of the auxiliary states that the MoE nodes of `symbol` write
     their per-step statistics into, in graph order."""
     out = []
+    at = registry_get('MoE').input_names.index('stats')
     for node in symbol._topo():
         if not node.is_variable() and node.op == 'MoE':
-            src, _ = node.inputs[-1]
+            src, _ = node.inputs[at]
             out.append(src.name)
     return out
 
@@ -359,26 +422,32 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
           input_names=['data', 'router_weight', 'experts_w1_weight',
                        'experts_w3_weight', 'experts_w2_weight',
                        'shared_w1_weight', 'shared_w3_weight',
-                       'shared_w2_weight', 'stats'],
+                       'shared_w2_weight', 'stats', 'select_bias'],
           param_defaults={'num_experts': 0, 'num_experts_per_tok': 1,
                           'experts_held': 0, 'expert_offset': 0,
                           'norm_topk_prob': True, 'routed_scaling': 1.0,
-                          'hidden': 0, 'shared_hidden': 0},
+                          'hidden': 0, 'shared_hidden': 0,
+                          'scoring': 'softmax'},
           num_outputs=2, num_visible_outputs=1, mutate_inputs={8: 1},
-          aux_inputs=('stats',))
-def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats):
+          aux_inputs=('stats',),
+          optional_inputs={'select_bias': _sigmoid_scoring})
+def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
     """A routed expert layer that holds ``experts_held`` of ``num_experts``
     experts, those from ``expert_offset`` on, and a shared expert.
 
     The router scores every token over all ``num_experts`` (softmax,
     float32), takes the ``num_experts_per_tok`` largest, divides their
     weights by their sum (``norm_topk_prob``) and multiplies by
-    ``routed_scaling``. Of a token's pairs only those on an expert held
-    here are computed: they are sorted by expert into a buffer of the
-    static worst-case length (every token on every expert held), each
-    expert's rows padded to whole tiles, and a grouped product whose work
-    follows the tiles present runs the gated MLP of each expert on its
-    rows. No pair is dropped, whatever the imbalance. What the experts
+    ``routed_scaling``. With ``scoring`` 'sigmoid' the scores are each
+    logit's sigmoid, the choice is of the largest ``score + select_bias``
+    (``select_bias`` (1, num_experts), one more input; it takes no
+    gradient: a trainer balances the load with it from outside) and the
+    weights are the chosen experts' bare scores. Of a token's pairs only
+    those on an expert held here are computed: they are sorted by expert
+    into a buffer of the static worst-case length (every token on every
+    expert held), each expert's rows padded to whole tiles, and a grouped
+    product whose work follows the tiles present runs the gated MLP of
+    each expert on its rows. No pair is dropped, whatever the imbalance. What the experts
     held elsewhere would add is left out; the shared expert is added once.
 
     Weights of the experts held: w1, w3 (held, in, hidden), w2 (held,
@@ -391,10 +460,19 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats):
     k = int(attrs['num_experts_per_tok'])
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
-    probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
-    w_pairs, idx = jax.lax.top_k(probs, k)
-    if attrs.get('norm_topk_prob', True):
-        w_pairs = w_pairs / jnp.sum(w_pairs, axis=-1, keepdims=True)
+    if _sigmoid_scoring(attrs):
+        scores = jax.nn.sigmoid(_matmul(x2, router))
+        bias = jax.lax.stop_gradient(select_bias).astype(jnp.float32)
+        _, idx = jax.lax.top_k(scores + bias.reshape(1, -1), k)
+        w_pairs = jnp.take_along_axis(scores, idx, axis=-1)
+        if attrs.get('norm_topk_prob', True):
+            w_pairs = w_pairs / (jnp.sum(w_pairs, axis=-1, keepdims=True)
+                                 + 1e-20)
+    else:
+        probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
+        w_pairs, idx = jax.lax.top_k(probs, k)
+        if attrs.get('norm_topk_prob', True):
+            w_pairs = w_pairs / jnp.sum(w_pairs, axis=-1, keepdims=True)
     w_pairs = w_pairs * float(attrs.get('routed_scaling', 1.0))
     dest, row_pair, tile_group, n_tiles, counts = _dispatch_plan(
         idx, held, offset)

@@ -138,7 +138,8 @@ def _moe_params(attrs, in_shapes):
             'experts_w3_weight': (held, d, h),
             'experts_w2_weight': (held, h, d),
             'shared_w1_weight': (sh, d), 'shared_w3_weight': (sh, d),
-            'shared_w2_weight': (d, sh), 'stats': (len(MOE_STATS),)}
+            'shared_w2_weight': (d, sh), 'stats': (len(MOE_STATS),),
+            'select_bias': (1, int(attrs['num_experts']))}
 
 
 @param_shape_hook('Embedding')

@@ -69,6 +69,11 @@ def _attention(q, k, v, g, heads, window, block):
                                  name='attention')
 
 
+def _latent_attention(qn, qr, kn, kr, v, g):
+    out, lse = pk.latent_attention_forward(qn, qr, kn, kr, v, 32)
+    return pk.latent_attention_backward(qn, qr, kn, kr, v, out, lse, g, 32)
+
+
 def _attention_specs(heads):
     return [((1, 8192, heads * 128), BF16), ((1, 8192, 1024), BF16),
             ((1, 8192, 1024), BF16), ((1, 8192, heads * 128), BF16)]
@@ -119,6 +124,24 @@ KERNELS = [
         x, y, t, n, 8),
      [((66560, 3072), BF16), ((66560, 1024), BF16), ((520,), I32),
       ((1,), I32)]),
+    # latent attention at kanana-2-30b-a3b's widths: 32 heads, keys of
+    # 128 + 64 (the 64 rotary ones shared by all heads), values of 128
+    ('attention_latent_fwd_bwd', _latent_attention,
+     [((1, 8192, 4096), BF16), ((1, 8192, 2048), BF16),
+      ((1, 8192, 4096), BF16), ((1, 8192, 64), BF16),
+      ((1, 8192, 4096), BF16), ((1, 8192, 4096), BF16)]),
+    # and its held experts: 16 of 2048 x 768, top 6, a buffer of
+    # 8192 x 6 + 16 x 128 rows
+    ('moe_expert_matmul_768x16', lambda x, w, t, n: pk.grouped_matmul(
+        x, w, t, n), [((51200, 2048), BF16), ((16, 2048, 768), BF16),
+                      ((400,), I32), ((1,), I32)]),
+    ('moe_expert_matmul_768x16_transposed',
+     lambda x, w, t, n: pk.grouped_matmul(x, w, t, n, transpose_w=True),
+     [((51200, 2048), BF16), ((16, 768, 2048), BF16), ((400,), I32),
+      ((1,), I32)]),
+    ('moe_expert_matmul_768x16_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
+        x, y, t, n, 16), [((51200, 2048), BF16), ((51200, 768), BF16),
+                          ((400,), I32), ((1,), I32)]),
 ]
 
 
