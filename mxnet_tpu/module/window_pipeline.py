@@ -131,14 +131,19 @@ def note_moe_window(rows, win=None):
     """The host side of :func:`moe_sentinel`: `rows` (W, layers, k) as
     fetched. Counters ``moe.pairs`` (token-expert pairs computed by the
     experts held here), ``moe.tokens`` (tokens routed, per layer) and
-    ``moe.dropped``; gauge ``moe.load_max_over_mean`` (the fullest held
-    expert's rows over the mean, worst layer and step of the window); and
-    one ``moe.window`` event with each step's pairs per layer."""
+    ``moe.dropped``; ``moe.passes`` (passes the expert layers made over
+    their sorted buffers) and ``moe.layer_steps`` (layers times steps), so
+    that their ratio is the mean and 1.0 says that one short pass always
+    held the rows present; gauge ``moe.load_max_over_mean`` (the fullest
+    held expert's rows over the mean, worst layer and step of the window);
+    and one ``moe.window`` event with each step's pairs per layer."""
     from ..ops.transformer import MOE_STATS
     col = {n: rows[..., i] for i, n in enumerate(MOE_STATS)}
     _tele.counter('moe.pairs').inc(int(col['pairs'].sum()))
     _tele.counter('moe.tokens').inc(int(col['tokens'].sum()))
     _tele.counter('moe.dropped').inc(int(col['dropped'].sum()))
+    _tele.counter('moe.passes').inc(int(col['passes'].sum()))
+    _tele.counter('moe.layer_steps').inc(col['passes'].size)
     _tele.gauge('moe.load_max_over_mean').set(
         float(col['load_max_over_mean'].max()))
     _tele.event('moe.window', win=win,

@@ -21,6 +21,7 @@ take it and others take the plain jnp form, unless MXTPU_FORCE_PALLAS=1
 routes every platform through the kernel (interpreted off the TPU), as for
 the other registry ops.
 """
+import functools
 import math
 
 import jax
@@ -264,8 +265,11 @@ def _sigmoid_scoring(attrs):
     return str(attrs.get('scoring', 'softmax')) == 'sigmoid'
 
 
-# what the layer writes into its ``stats`` auxiliary state each step
-MOE_STATS = ('pairs', 'tokens', 'dropped', 'load_max', 'load_max_over_mean')
+# what the layer writes into its ``stats`` auxiliary state each step: the
+# pairs computed here, the tokens routed, the pairs dropped (0), the fullest
+# held expert's rows, that over the mean, the passes made over the sorted rows
+MOE_STATS = ('pairs', 'tokens', 'dropped', 'load_max', 'load_max_over_mean',
+             'passes')
 
 
 def moe_stat_names(symbol):
@@ -343,73 +347,144 @@ def _rows(x, index):
     return jnp.take(x, index, axis=0, mode='fill', fill_value=0)
 
 
-def _live_rows(R, n_tiles):
-    return (jnp.arange(R) < n_tiles[0] * pk.GROUP_TILE)[:, None]
+# A pass of `_experts` takes this many times the rows that an even router
+# would send to the experts held here (plus a tile for each). Seeded routers
+# send a layer 0.5 to 2.2 times the even share in single steps (PERF.md,
+# section 5), so a second pass is rare and a pass is a fraction of the
+# worst-case buffer.
+_PASS_OVER_EVEN = 2
 
 
-def _experts_forward(x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
+def _pass_rows(R, T, k, held, num_experts):
+    """How many rows of the sorted buffer one pass of :func:`_experts`
+    takes: whole tiles, a function of the shapes alone, R at most (where
+    every expert is held it is R: one pass over the whole buffer)."""
+    tm = pk.GROUP_TILE
+    rows = -(-_PASS_OVER_EVEN * T * k * held // num_experts) + held * tm
+    return min(R, -(-rows // tm) * tm)
+
+
+def _num_passes(rp, n_tiles):
+    """Passes of rp rows that hold the n_tiles[0] tiles present."""
+    return -(-n_tiles[0] // (rp // pk.GROUP_TILE))
+
+
+def _whole_passes(rp, dest, row_pair, tile_group):
+    """The plan padded to whole passes (a slice of a pass's length would
+    otherwise be moved back inside the buffer), a pair of an expert held
+    elsewhere sent past the padded end."""
+    R = row_pair.shape[0]
+    pad = -R % rp
+    return (jnp.where(dest < R, dest, R + pad),
+            jnp.pad(row_pair, (0, pad), constant_values=dest.size),
+            jnp.pad(tile_group, (0, pad // pk.GROUP_TILE), mode='edge'))
+
+
+def _pass_forward(rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
+    """Pass p of the sorted buffer, rows [p * rp, (p + 1) * rp): its share
+    of the plan (at: dest as rows of this pass, rp for a pair of another
+    pass or of an expert held elsewhere) and the gated MLP of each expert
+    on its rows. Rows of tiles past the last present are left unwritten by
+    the grouped products; no `at` names one."""
+    k, tiles_pass = dest.shape[1], rp // pk.GROUP_TILE
+    start = p * rp
+    rows = jax.lax.dynamic_slice(row_pair, (start,), (rp,))
+    groups = jax.lax.dynamic_slice(tile_group, (p * tiles_pass,),
+                                   (tiles_pass,))
+    tiles = jnp.clip(n_tiles - p * tiles_pass, 0, tiles_pass)
+    # a negative index would wrap before it fills
+    at = jnp.where((dest >= start) & (dest < start + rp), dest - start, rp)
+    xs = _rows(x, rows // k)
+    h1 = _gmm(xs, w1, groups, tiles)
+    h3 = _gmm(xs, w3, groups, tiles)
+    act = (jax.nn.silu(h1.astype(jnp.float32))
+           * h3.astype(jnp.float32)).astype(x.dtype)
+    ys = _gmm(act, w2, groups, tiles)
+    return (rows, groups, tiles, at), (xs, h1, h3, act, ys)
+
+
+def _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
                      n_tiles):
     k = dest.shape[1]
-    xs = _rows(x, row_pair // k)
-    live = _live_rows(xs.shape[0], n_tiles)
-    h1 = _gmm(xs, w1, tile_group, n_tiles)
-    h3 = _gmm(xs, w3, tile_group, n_tiles)
-    act = jnp.where(live, jax.nn.silu(h1.astype(jnp.float32))
-                    * h3.astype(jnp.float32), 0.0).astype(x.dtype)
-    ys = jnp.where(live, _gmm(act, w2, tile_group, n_tiles), 0)
-    out = jnp.zeros(x.shape, jnp.float32)
-    for j in range(k):      # a token's pairs, one gather each
-        out += w_pairs[:, j:j + 1] * _rows(ys, dest[:, j]).astype(jnp.float32)
-    return out.astype(x.dtype), (xs, h1, h3, act, ys)
+
+    def one(p, out):
+        (_, _, _, at), (_, _, _, _, ys) = _pass_forward(
+            rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
+        for j in range(k):      # a token's pairs, one gather each
+            out += w_pairs[:, j:j + 1] * _rows(ys, at[:, j]).astype(
+                jnp.float32)
+        return out
+
+    out = jax.lax.fori_loop(0, _num_passes(rp, n_tiles), one,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype)
 
 
-@jax.custom_vjp
-def _experts(x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
     """sum over a token's pairs held here of w_pair * E(x): x [T, d],
-    w_pairs [T, k] float32, the rest from :func:`_dispatch_plan`. Both
-    directions gather; nothing scatters activations."""
-    return _experts_forward(x, w_pairs, w1, w3, w2, dest, row_pair,
-                            tile_group, n_tiles)[0]
+    w_pairs [T, k] float32, the rest from :func:`_dispatch_plan` through
+    :func:`_whole_passes`. The sorted buffer is walked in passes of rp rows
+    (:func:`_pass_rows`), as many as the rows present need, so that the
+    gathers, the gate and the sums follow the rows present as the grouped
+    products do; only the plan has the worst-case length. Both directions
+    gather; nothing scatters activations. The backward pass computes a
+    pass's forward again from x: what is kept for it is x, w_pairs, the
+    weights and the plan."""
+    return _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair,
+                            tile_group, n_tiles)
 
 
-def _experts_fwd(x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
+def _experts_fwd(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
                  n_tiles):
-    out, kept = _experts_forward(x, w_pairs, w1, w3, w2, dest, row_pair,
-                                 tile_group, n_tiles)
-    return out, (kept, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
-                 n_tiles)
+    out = _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair,
+                           tile_group, n_tiles)
+    return out, (x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
 
 
-def _experts_bwd(res, g):
-    (xs, h1, h3, act, ys), w_pairs, w1, w3, w2, dest, row_pair, \
-        tile_group, n_tiles = res
-    T, k = dest.shape
+def _experts_bwd(rp, res, g):
+    x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles = res
+    k = dest.shape[1]
     held = w1.shape[0]
-    live = _live_rows(xs.shape[0], n_tiles)
-    g32 = g.astype(jnp.float32)
-    d_pairs = jnp.stack(
-        [jnp.sum(_rows(ys, dest[:, j]).astype(jnp.float32) * g32, axis=-1)
-         for j in range(k)], axis=1)
-    w_row = _rows(w_pairs.reshape(-1), row_pair)
-    dys = (w_row[:, None] * _rows(g32, row_pair // k)).astype(g.dtype)
-    dact = _gmm(dys, w2, tile_group, n_tiles, transpose_w=True)
-    dw2 = _gmm_dw(act, dys, tile_group, n_tiles, held)
-    h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
-    dactf = jnp.where(live, dact.astype(jnp.float32), 0.0)
-    sig = jax.nn.sigmoid(h1f)
-    dh1 = jnp.where(live, dactf * h3f * sig * (1.0 + h1f * (1.0 - sig)),
-                    0.0).astype(g.dtype)
-    dh3 = jnp.where(live, dactf * h1f * sig, 0.0).astype(g.dtype)
-    dxs = _gmm(dh1, w1, tile_group, n_tiles, transpose_w=True) \
-        .astype(jnp.float32) \
-        + _gmm(dh3, w3, tile_group, n_tiles, transpose_w=True) \
-        .astype(jnp.float32)
-    dxs = jnp.where(live, dxs, 0.0)
-    dw1 = _gmm_dw(xs, dh1, tile_group, n_tiles, held)
-    dw3 = _gmm_dw(xs, dh3, tile_group, n_tiles, held)
-    dx = jnp.zeros((T, xs.shape[1]), jnp.float32)
-    for j in range(k):
-        dx += _rows(dxs, dest[:, j])
+    w_flat = w_pairs.reshape(-1)
+
+    def one(p, carry):
+        dx, d_pairs, dw1, dw3, dw2 = carry
+        (rows, groups, tiles, at), (xs, h1, h3, act, ys) = _pass_forward(
+            rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
+        g32 = g.astype(jnp.float32)
+        d_pairs += jnp.stack(
+            [jnp.sum(_rows(ys, at[:, j]).astype(jnp.float32) * g32, axis=-1)
+             for j in range(k)], axis=1)
+        dys = (_rows(w_flat, rows)[:, None]
+               * _rows(g, rows // k).astype(jnp.float32)).astype(g.dtype)
+        dact = _gmm(dys, w2, groups, tiles, transpose_w=True).astype(
+            jnp.float32)
+        h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
+        sig = jax.nn.sigmoid(h1f)
+        dh1 = (dact * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(g.dtype)
+        dh3 = (dact * h1f * sig).astype(g.dtype)
+        dxs = _gmm(dh1, w1, groups, tiles, transpose_w=True) \
+            .astype(jnp.float32) \
+            + _gmm(dh3, w3, groups, tiles, transpose_w=True) \
+            .astype(jnp.float32)
+        for j in range(k):
+            dx += _rows(dxs, at[:, j])
+        # the weight products leave a group without a tile here unwritten
+        present = jnp.arange(groups.shape[0]) < tiles[0]
+        named = jnp.any((groups[:, None] == jnp.arange(held)[None])
+                        & present[:, None], axis=0)[:, None, None]
+        dw1 += jnp.where(named, _gmm_dw(xs, dh1, groups, tiles, held), 0.0)
+        dw3 += jnp.where(named, _gmm_dw(xs, dh3, groups, tiles, held), 0.0)
+        dw2 += jnp.where(named, _gmm_dw(act, dys, groups, tiles, held), 0.0)
+        return dx, d_pairs, dw1, dw3, dw2
+
+    dx, d_pairs, dw1, dw3, dw2 = jax.lax.fori_loop(
+        0, _num_passes(rp, n_tiles), one,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(w_pairs.shape, jnp.float32),
+         jnp.zeros(w1.shape, jnp.float32), jnp.zeros(w3.shape, jnp.float32),
+         jnp.zeros(w2.shape, jnp.float32)))
     return (dx.astype(g.dtype), d_pairs.astype(w_pairs.dtype),
             dw1.astype(w1.dtype), dw3.astype(w3.dtype), dw2.astype(w2.dtype),
             None, None, None, None)
@@ -443,18 +518,21 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
     (``select_bias`` (1, num_experts), one more input; it takes no
     gradient: a trainer balances the load with it from outside) and the
     weights are the chosen experts' bare scores. Of a token's pairs only
-    those on an expert held here are computed: they are sorted by expert
-    into a buffer of the static worst-case length (every token on every
-    expert held), each expert's rows padded to whole tiles, and a grouped
-    product whose work follows the tiles present runs the gated MLP of
-    each expert on its rows. No pair is dropped, whatever the imbalance. What the experts
+    those on an expert held here are computed: a plan sorts them by expert
+    into rows, each expert's rows padded to whole tiles. The plan (int32
+    vectors) has the static worst-case length, every token on every
+    expert held; the activations do not: the rows are walked in passes of
+    a bounded length (:func:`_pass_rows`: twice an even router's share),
+    as many as the rows present need, and in each a grouped product runs
+    the gated MLP of each expert on its rows. One pass as a rule; an
+    imbalance costs further passes, never a pair. What the experts
     held elsewhere would add is left out; the shared expert is added once.
 
     Weights of the experts held: w1, w3 (held, in, hidden), w2 (held,
     hidden, in). ``stats`` is an auxiliary state that receives this step's
     MOE_STATS: pairs computed here, tokens routed, pairs dropped (the
-    pairs routed here less the rows placed: 0), the fullest expert's rows
-    and that over the mean.
+    pairs routed here less the rows placed: 0), the fullest expert's rows,
+    that over the mean, and the passes made over the rows.
     """
     held, offset = int(attrs['experts_held']), int(attrs['expert_offset'])
     k = int(attrs['num_experts_per_tok'])
@@ -476,13 +554,15 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
     w_pairs = w_pairs * float(attrs.get('routed_scaling', 1.0))
     dest, row_pair, tile_group, n_tiles, counts = _dispatch_plan(
         idx, held, offset)
-    out = _experts(x2, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
-                   n_tiles)
+    rp = _pass_rows(row_pair.shape[0], x2.shape[0], k, held, router.shape[0])
+    out = _experts(rp, x2, w_pairs, w1, w3, w2,
+                   *_whole_passes(rp, dest, row_pair, tile_group), n_tiles)
     out = out + _gated_mlp(x2, s1, s3, s2)
     pairs = jnp.sum(counts).astype(jnp.float32)
     placed = jnp.sum(row_pair < dest.size).astype(jnp.float32)
     load_max = jnp.max(counts).astype(jnp.float32)
     new_stats = jnp.stack([
         pairs, jnp.float32(x2.shape[0]), pairs - placed, load_max,
-        load_max * held / jnp.maximum(pairs, 1.0)]).astype(stats.dtype)
+        load_max * held / jnp.maximum(pairs, 1.0),
+        _num_passes(rp, n_tiles).astype(jnp.float32)]).astype(stats.dtype)
     return out.reshape(lead + (d,)), jax.lax.stop_gradient(new_stats)

@@ -395,14 +395,17 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # -- the other decoder configuration is left as it was -------------------------------------
 
 # sha256 of the lowered text of one training step of the Laguna builder's
-# symbol at test_transformer_ops.CFG's sizes, taken on the commit before
-# this family came (faf5f29), on the CPU, on each path. The text is this
-# jax's; a change of jax (or of Laguna's own ops) needs them taken again.
+# symbol at test_transformer_ops.CFG's sizes, on the CPU, on each path: what
+# a change to the other family's ops must leave as it is. Taken on the tree
+# of PR 33, which changed Laguna's own step by intent (the expert layer
+# walks its sorted rows in passes); before that they were those of the
+# commit before this family came (faf5f29). The text is this jax's; a change
+# of jax (or of Laguna's own ops) needs them taken again.
 LAGUNA_TEXT = {
     'plain':
-    'e5475902a99f3834d2ecc1decf51c222cc1840d62c21b665d5095b3bde1d1bce',
+    '96279909c65564df3a01041f79d6454f6965bcf790a16b9ba6aff482a6fb16e2',
     'kernel':
-    'fe6541e2595b6820642ade803e7ce85b31a4a0830dda0344ea5259e02303d295'}
+    'fc2cd27eb6754cf9b7bef8de050b793f475327fd5efaf41bf383ceb2fc927863'}
 
 
 def laguna_step_digest():

@@ -9,6 +9,8 @@ by path: there is one copy), at small widths on the CPU in float32:
 - the share test: the parts that four shares of four experts give, the
   shared expert counted once, add up to the uncut layer;
 - no pair dropped when every token picks the same experts;
+- the sorted buffer walked in as many passes as the rows present need, and
+  no activation of the lowered step with the buffer's worst-case length;
 - the whole model through ``Module.fit`` takes the fused window and after
   three steps matches the reference's losses and parameter change;
 - telemetry off leaves the lowered window unchanged, on yields ``moe.*``.
@@ -293,6 +295,119 @@ def test_dispatch_plan_buffer_holds_the_worst_case():
     assert len(set(rows.tolist())) == 3 * T and rows.max() < len(row_pair)
     assert int(n_tiles[0]) == 3 and sorted(set(
         np.asarray(tile_group)[:3].tolist())) == [0, 1, 2]
+
+
+# -- the sorted buffer is walked in passes -------------------------------------
+
+sigmoid_ref = _load('benchmark/reference/deepseek_v3.py',
+                    'deepseek_v3_reference')
+LONG = 512      # tokens: a pass takes 1280 rows of a buffer of 2048
+
+
+def _buffer_rows(tokens):
+    """The worst-case length of the sorted buffer of 4 held experts, 3 a
+    token, as `_dispatch_plan` sizes it."""
+    tm = pk.GROUP_TILE
+    return -(-(tokens * 3 + 4 * tm) // tm) * tm
+
+
+def _pass_case(scoring, routing):
+    """An expert layer that holds 4 of 16 experts, 3 a token, over LONG
+    tokens: (layer, reference, arguments, rows the tiles present take).
+    'same' sends every token to experts 0, 1 and 2."""
+    x, p = jnp.abs(_rand(60, LONG, d)) + 0.1, _moe_params(61, 4)
+    if routing == 'same':
+        router = np.zeros((16, d), np.float32)
+        router[0], router[1], router[2] = 0.03, 0.02, 0.01
+        p['m_router_weight'] = jnp.asarray(router)
+    names = ['m_%s_weight' % n for n in _MOE_ORDER]
+    stats = jnp.zeros((len(MOE_STATS),), jnp.float32)
+    attrs = dict(num_experts=16, num_experts_per_tok=3, experts_held=4,
+                 expert_offset=0, norm_topk_prob=True, routed_scaling=2.5)
+    if scoring == 'sigmoid':
+        bias = jnp.zeros((1, 16), jnp.float32)
+        fn = op('MoE', scoring='sigmoid', **attrs)
+        cfg = dict(num_experts_per_tok=3, routed_scaling_factor=2.5)
+
+        def layer(x, *w):
+            return fn(x, *w, stats, bias)
+
+        def want(x, *w):
+            return sigmoid_ref.moe_layer(
+                dict(zip(names, w), m_select_bias_weight=bias), 'm', x, cfg,
+                4, 0)[0]
+
+        idx = sigmoid_ref.route(x, p['m_router_weight'], bias, 3, 2.5)[0]
+    else:
+        fn = op('MoE', **attrs)
+
+        def layer(x, *w):
+            return fn(x, *w, stats)
+
+        def want(x, *w):
+            return ref.moe_layer(dict(zip(names, w)), 'm', x, CFG, 4, 0)[0]
+
+        idx = ref.route(x, p['m_router_weight'], 3, 2.5)[0]
+    counts = np.bincount(np.asarray(idx).reshape(-1), minlength=16)[:4]
+    tiles = np.maximum(-(-counts // pk.GROUP_TILE), 1).sum()
+    return layer, want, [x] + _moe_weights(p), int(tiles) * pk.GROUP_TILE
+
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+@pytest.mark.parametrize('scoring', ['softmax', 'sigmoid'])
+@pytest.mark.parametrize('routing,passes', [('even', 1), ('same', 2)])
+def test_passes_follow_the_rows_present(path, scoring, routing, passes):
+    """Where a pass is shorter than the worst-case buffer, an even routing
+    takes one pass and every token on the same experts takes as many as its
+    rows need; either way nothing is dropped, and the output, dx, the
+    router's gradient (d_pairs) and the three dw are the reference's."""
+    layer, want, args, rows = _pass_case(scoring, routing)
+    R = _buffer_rows(LONG)
+    rp = mx.ops.transformer._pass_rows(R, LONG, 3, 4, 16)
+    assert (R, rp) == (2048, 1280)
+    stats = dict(zip(MOE_STATS, np.asarray(layer(*args)[1])))
+    assert stats['passes'] == -(-rows // rp) == passes
+    assert stats['dropped'] == 0 and stats['tokens'] == LONG
+    if routing == 'same':
+        assert stats['pairs'] == 3 * LONG and rows == 13 * pk.GROUP_TILE
+    _both(lambda *a: layer(*a)[0], want, *args)
+
+
+def test_a_pass_is_the_buffer_where_every_expert_is_held():
+    for T_, k, held, experts in [(LONG, 3, 16, 16), (8192, 10, 256, 256)]:
+        R = T_ * k + held * pk.GROUP_TILE
+        assert mx.ops.transformer._pass_rows(R, T_, k, held, experts) == R
+    # the two decoder cells: 8192 tokens, 8 of 256 top 10, 16 of 128 top 6
+    assert mx.ops.transformer._pass_rows(66560, 8192, 10, 8, 256) == 6144
+    assert mx.ops.transformer._pass_rows(51200, 8192, 6, 16, 128) == 14336
+
+
+def _row_types(text, rows):
+    """Element types of the tensor types in a lowered text that have `rows`
+    leading rows."""
+    return set(re.findall(r'tensor<%dx(?:\d+x)*(\w+)>' % rows, text))
+
+
+def test_no_activation_has_the_buffers_worst_case_length(monkeypatch):
+    """One training step of the model, lowered: of the worst-case length are
+    the plan's int32 and pred vectors alone; the activations around the
+    grouped products have a pass's length. (With a pass as long as the
+    buffer, the same search finds them.)"""
+    cfg = dict(CFG, experts_held=4)
+    tokens = 2 * T
+    R = _buffer_rows(tokens)
+    rp = mx.ops.transformer._pass_rows(R, tokens, 3, 4, 16)
+    assert rp < R
+
+    def lowered():
+        step, wrt = _training_step(builder.get_symbol(cfg), **LM_IN)
+        return jax.jit(step).lower(wrt).as_text()
+
+    text = lowered()
+    assert _row_types(text, R) <= {'i32', 'i1'}, _row_types(text, R)
+    assert {'f32'} <= _row_types(text, rp)
+    monkeypatch.setattr(mx.ops.transformer, '_PASS_OVER_EVEN', 1000)
+    assert 'f32' in _row_types(lowered(), R)
 
 
 # -- the whole model ---------------------------------------------------------
@@ -648,6 +763,8 @@ def test_telemetry_off_leaves_the_window_unchanged_on_yields_moe_counters(
         c = snap_on['counters']
         assert c['moe.tokens'] == 3 * T * 4 and c['moe.dropped'] == 0
         assert 0 < c['moe.pairs'] <= 3 * T * 3 * 4
+        # three steps of four sparse layers, one pass each
+        assert c['moe.layer_steps'] == c['moe.passes'] == 3 * 4
         assert snap_on['gauges']['moe.load_max_over_mean'] >= 1.0
     finally:
         monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
