@@ -407,6 +407,63 @@ inline Symbol GroupedQueryAttention(const std::string &symbol_name,
   return op.CreateSymbol(symbol_name);
 }
 
+inline Symbol HyperCollapse(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &weight,
+    const Symbol &bias,
+    const Symbol &alpha,
+    int n = 4,
+    mx_float eps = 1e-06f) {
+  Operator op("HyperCollapse");
+  op.SetInput("data", data);
+  op.SetInput("weight", weight);
+  op.SetInput("bias", bias);
+  op.SetInput("alpha", alpha);
+  op.SetParam("n", detail::ToParam(n));
+  op.SetParam("eps", detail::ToParam(eps));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol HyperPost(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &update,
+    const Symbol &coef,
+    int n = 4) {
+  Operator op("HyperPost");
+  op.SetInput("data", data);
+  op.SetInput("update", update);
+  op.SetInput("coef", coef);
+  op.SetParam("n", detail::ToParam(n));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol HyperPre(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &weight,
+    const Symbol &bias,
+    const Symbol &alpha,
+    const Symbol &stats,
+    int n = 4,
+    mx_float eps = 1e-06f,
+    int sinkhorn_iters = 20,
+    mx_float sinkhorn_eps = 1e-06f,
+    mx_float clamp_min = -30.0f,
+    mx_float clamp_max = 30.0f) {
+  Operator op("HyperPre");
+  op.SetInput("data", data);
+  op.SetInput("weight", weight);
+  op.SetInput("bias", bias);
+  op.SetInput("alpha", alpha);
+  op.SetInput("stats", stats);
+  op.SetParam("n", detail::ToParam(n));
+  op.SetParam("eps", detail::ToParam(eps));
+  op.SetParam("sinkhorn_iters", detail::ToParam(sinkhorn_iters));
+  op.SetParam("sinkhorn_eps", detail::ToParam(sinkhorn_eps));
+  op.SetParam("clamp_min", detail::ToParam(clamp_min));
+  op.SetParam("clamp_max", detail::ToParam(clamp_max));
+  return op.CreateSymbol(symbol_name);
+}
+
 inline Symbol IdentityAttachKLSparseReg(const std::string &symbol_name,
     const Symbol &data,
     const Symbol &moving_avg,
@@ -467,7 +524,8 @@ inline Symbol LatentAttention(const std::string &symbol_name,
     const Symbol &k_nope,
     const Symbol &k_rope,
     const Symbol &value,
-    int num_heads = 1) {
+    int num_heads = 1,
+    mx_float scale = 0.0f) {
   Operator op("LatentAttention");
   op.SetInput("q_nope", q_nope);
   op.SetInput("q_rope", q_rope);
@@ -475,6 +533,7 @@ inline Symbol LatentAttention(const std::string &symbol_name,
   op.SetInput("k_rope", k_rope);
   op.SetInput("value", value);
   op.SetParam("num_heads", detail::ToParam(num_heads));
+  op.SetParam("scale", detail::ToParam(scale));
   return op.CreateSymbol(symbol_name);
 }
 

@@ -119,6 +119,16 @@ class CompositeEvalMetric(EvalMetric):
         for metric in self.metrics:
             metric.update(labels, preds)
 
+    def update_dict(self, labels, preds):
+        """Reference metric.py CompositeEvalMetric.update_dict: every
+        child picks what it names."""
+        if self.label_names is not None:
+            labels = {n: labels[n] for n in self.label_names}
+        if self.output_names is not None:
+            preds = {n: preds[n] for n in self.output_names}
+        for metric in self.metrics:
+            metric.update_dict(labels, preds)
+
     def reset(self):
         for metric in getattr(self, 'metrics', []):
             metric.reset()

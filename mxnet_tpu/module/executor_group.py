@@ -93,6 +93,20 @@ def _check_label_args(label_shapes, arg_dict, symbol):
                 'the iterator label' % (name, symbol.list_arguments()))
 
 
+def _update_metric(eval_metric, symbol, label_descs, labels, outputs):
+    """``eval_metric.update_dict`` with the graph's own names (reference
+    executor_group.py:update_metric), so that a metric that names the
+    output and label it reads (``output_names``, ``label_names``) gets
+    those and no others; one that names none gets them all, in order."""
+    names = [d if isinstance(d, str) else
+             d.name if isinstance(d, DataDesc) else d[0]
+             for d in (label_descs or [])]
+    if len(names) != len(labels):       # labels the bind never named
+        return eval_metric.update(labels, outputs)
+    eval_metric.update_dict(dict(zip(names, labels)),
+                            dict(zip(symbol.list_outputs(), outputs)))
+
+
 class DataParallelExecutorGroup:
     def __init__(self, symbol, contexts, workload, data_shapes, label_shapes,
                  param_names, for_training, inputs_need_grad,
@@ -336,7 +350,8 @@ class DataParallelExecutorGroup:
                     idx[axis] = islice
                     labels_slice.append(
                         nd.array(label.asnumpy()[tuple(idx)]))
-            eval_metric.update(labels_slice, texec.outputs)
+            _update_metric(eval_metric, self.symbol, self.label_shapes,
+                           labels_slice, texec.outputs)
 
     def install_monitor(self, mon):
         for e in self.execs:
@@ -560,7 +575,8 @@ class SPMDExecutorGroup:
         return grads if merge_multi_context else self.input_grad_arrays
 
     def update_metric(self, eval_metric, labels):
-        eval_metric.update(labels, self.execs[0].outputs)
+        _update_metric(eval_metric, self.symbol, self._label_names, labels,
+                       self.execs[0].outputs)
 
     def install_monitor(self, mon):
         for e in self.execs:

@@ -193,7 +193,8 @@ class FusedEvalLoop:
             # geometry; other geometries use stacked-output mode, whose
             # host-side eval_metric.update is reference-exact
             plan = plan_metric(eval_metric, out_shapes,
-                               module._label_names)
+                               module._label_names,
+                               module._symbol.list_outputs())
             if plan is not None:
                 children, fns = plan
         if fns is None:

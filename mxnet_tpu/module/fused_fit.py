@@ -63,10 +63,11 @@ from ..kvstore import _updater_key
 from ..ndarray.ndarray import from_jax
 from ..ops import registry as _reg
 from .window_pipeline import (WindowPipeline, dynamics_sentinel,
-                              health_sentinel, host_wrap, moe_sentinel,
+                              health_sentinel, host_wrap, hyper_sentinel,
+                              moe_sentinel, note_hyper_window,
                               note_moe_window, registered_jit,
                               window_bisect, window_size)
-from .window_pipeline import plan_metric as _metric_plan
+from .window_pipeline import plan_metric_or_reason as _metric_plan
 
 __all__ = ['FusedFitLoop']
 
@@ -444,6 +445,15 @@ def _opt_plan(opt):
 # metric plans (in-graph sufficient statistics) live in
 # window_pipeline.plan_metric — shared with the fused eval loop.
 
+_SAID = set()
+
+
+def _say_once(logger, msg):
+    """A warning a process gives once: why a fit left the fused window."""
+    if msg not in _SAID:
+        _SAID.add(msg)
+        logger.warning(msg)
+
 
 class FusedFitLoop:
     """One compiled W-step train window driving Module's state."""
@@ -496,6 +506,8 @@ class FusedFitLoop:
         # what the routed expert layers did, step by step (same contract:
         # None without telemetry or without such a layer)
         self._moe_fn = moe_sentinel(module._symbol, self._aux_names)
+        # ...and the residual streams' mixing matrices
+        self._hyper_fn = hyper_sentinel(module._symbol, self._aux_names)
         self._out_names = list(module._symbol.list_outputs())
         self._last_lr = None   # last sampled lr (run-ledger scalars)
         self._upd_keys = updater_keys(module, self._grad_names)
@@ -694,9 +706,11 @@ class FusedFitLoop:
         if out_shapes is None:
             return None
         window = _window_size(module)
-        # plan_metric also enforces the stat fns' output/label geometry;
+        # the plan also enforces the stat fns' output/label geometry;
         # other geometries use the host-fallback mode below
-        plan = _metric_plan(eval_metric, out_shapes, module._label_names)
+        plan, why = _metric_plan(eval_metric, out_shapes,
+                                 module._label_names,
+                                 module._symbol.list_outputs())
         if plan is not None:
             children, fns = plan
         else:
@@ -707,6 +721,12 @@ class FusedFitLoop:
             est = 4 * window * sum(
                 int(np.prod(s)) for s in out_shapes if s)
             if est > 256 * 1024 * 1024:
+                _say_once(logger, 'fused fit window not taken for %s: the '
+                          'metric has no in-graph plan (%s) and the '
+                          'host-metric mode would stack %.2f GB of outputs '
+                          'a window (cap 0.27): one step a dispatch'
+                          % (getattr(module._symbol, 'name', None)
+                             or 'the graph', why, est / 1e9))
                 return None
             children, fns = None, None
         # a previously-cached loop (about to be replaced) may hold the
@@ -798,6 +818,7 @@ class FusedFitLoop:
         health_fn = self._health_fn
         dyn_fn = self._dyn_fn
         moe_fn = self._moe_fn
+        hyper_fn = self._hyper_fn
         accum = self._accum
         W = self.window
         mesh = self._mesh
@@ -971,6 +992,8 @@ class FusedFitLoop:
                                          for i in grad_carry_idx)))
                 if moe_fn is not None:
                     extras.append(moe_fn(new_aux))
+                if hyper_fn is not None:
+                    extras.append(hyper_fn(new_aux))
                 if extras:
                     ys = (ys, *extras)
                 if compress:
@@ -1319,9 +1342,10 @@ class FusedFitLoop:
             (snapshotted at collection time — see below), the way the
             reference loop's update_metric would."""
             pieces, labels_w, win_snaps, win = pending
-            hrows = drows = mrows = None
+            hrows = drows = mrows = yrows = None
             if self._health_fn is not None or self._dyn_fn is not None \
-                    or self._moe_fn is not None:
+                    or self._moe_fn is not None \
+                    or self._hyper_fn is not None:
                 parts = list(pieces)
                 pieces = parts.pop(0)
                 if self._health_fn is not None:
@@ -1330,6 +1354,8 @@ class FusedFitLoop:
                     drows = parts.pop(0)
                 if self._moe_fn is not None:
                     mrows = parts.pop(0)
+                if self._hyper_fn is not None:
+                    yrows = parts.pop(0)
             with _tele.span('fused_fit.fetch', 'fused_fit', win=win):
                 # the window's one device->host fetch (everything
                 # after is host math) —
@@ -1347,8 +1373,12 @@ class FusedFitLoop:
                     dmat = np.asarray(drows)
                 if mrows is not None:
                     mmat = np.asarray(mrows)
+                if yrows is not None:
+                    ymat = np.asarray(yrows)
             if mrows is not None:
                 note_moe_window(mmat, win=win)
+            if yrows is not None:
+                note_hyper_window(ymat)
             if hrows is not None:
                 # mid-window NaN -> exact step attribution + (first
                 # incident) staged-path first-bad-layer bisect on the

@@ -41,6 +41,8 @@ consumers instead of two private copies:
   composites of them) that both loops compile into their scan bodies,
   packed so the host needs a single fetch per window.
 """
+import functools
+
 import numpy as np
 
 import jax
@@ -50,7 +52,8 @@ from .. import metric as metric_mod
 from .. import telemetry as _tele
 from ..ndarray.ndarray import _POOLED_FROM, _host_buffer, from_jax
 
-__all__ = ['WindowPipeline', 'window_size', 'module_platform', 'plan_metric', 'host_wrap',
+__all__ = ['WindowPipeline', 'window_size', 'module_platform', 'plan_metric',
+           'plan_metric_or_reason', 'host_wrap',
            'registered_jit', 'health_sentinel', 'dynamics_sentinel',
            'window_bisect']
 
@@ -151,6 +154,32 @@ def note_moe_window(rows, win=None):
                 dropped=int(col['dropped'].sum()))
 
 
+def hyper_sentinel(symbol, aux_names):
+    """:func:`moe_sentinel` for the ``HyperPre`` nodes of the graph:
+    ``fn(new_aux) -> (nodes, len(HYPER_STATS))``, each step's statistics as
+    the nodes left them in their auxiliary states; None while telemetry is
+    off or the graph has no such node."""
+    if not _tele.enabled():
+        return None
+    from ..ops.transformer import hyper_stat_names
+    idx = [aux_names.index(n) for n in hyper_stat_names(symbol)
+           if n in aux_names]
+    if not idx:
+        return None
+    return lambda new_aux: jnp.stack(
+        [new_aux[i].astype(jnp.float32) for i in idx])
+
+
+def note_hyper_window(rows):
+    """The host side of :func:`hyper_sentinel`: `rows` (W, nodes, k) as
+    fetched. Gauge ``hyper.res_dev_max``: the largest distance of a mixing
+    matrix's row and column sums from 1, over the window's steps, nodes
+    and tokens (0 is doubly stochastic)."""
+    from ..ops.transformer import HYPER_STATS
+    _tele.gauge('hyper.res_dev_max').set(
+        float(rows[..., HYPER_STATS.index('res_dev_max')].max()))
+
+
 def window_bisect(executor, data_names, label_names, snaps, is_train,
                   defer_fn=None):
     """First-bad-layer driver for a fused-window incident: returns
@@ -239,30 +268,86 @@ def _plan_one(m):
     return None
 
 
-def plan_metric(eval_metric, out_shapes=None, label_names=None):
-    """Returns (children, [stats_fn]) where children are the leaf
-    EvalMetric objects to update, or None if any leaf is unsupported.
-    When ``out_shapes``/``label_names`` are given, also enforces the
-    geometry every stat fn assumes — ONE 2-D (batch, classes) output
-    with classes >= 2 (reference Accuracy SKIPS the argmax on a
-    width-1 class dim and compares raw values) and one label — so the
-    fit and eval loops cannot drift on the eligibility condition."""
-    if out_shapes is not None and (
-            len(out_shapes) != 1 or len(out_shapes[0]) != 2
-            or out_shapes[0][1] < 2
-            or (label_names is not None and len(label_names) != 1)):
-        return None
+def _reads(m, output_names, label_names):
+    """(output index, label index) that leaf metric `m` reads, or a string
+    saying why the plan cannot tell: a metric names what it reads through
+    ``output_names`` / ``label_names`` (one of each), and may leave out a
+    name only where the graph has just one to choose from."""
+    at = []
+    for kind, named, have in (('output', m.output_names, output_names),
+                              ('label', m.label_names, label_names)):
+        if named is None:
+            if len(have) != 1:
+                return ('metric %r names no %s and the graph has %d (%s)'
+                        % (m.name, kind, len(have), ', '.join(have)))
+            at.append(0)
+        elif len(named) != 1 or named[0] not in have:
+            return ('metric %r reads %s %s; the graph has %s'
+                    % (m.name, kind, list(named), ', '.join(have)))
+        else:
+            at.append(list(have).index(named[0]))
+    return tuple(at)
+
+
+def plan_metric_or_reason(eval_metric, out_shapes=None, label_names=None,
+                          output_names=None):
+    """((children, [stats_fn]), None), or (None, why no plan was made).
+
+    children are the leaf EvalMetric objects to update. When
+    ``out_shapes``/``label_names`` are given, also enforces the geometry
+    every stat fn assumes, so the fit and eval loops cannot drift on the
+    eligibility condition: every output a metric reads is 2-D (batch,
+    classes) with classes >= 2 (reference Accuracy SKIPS the argmax on a
+    width-1 class dim and compares raw values). A graph with ONE output
+    and one label is planned as ever (its stat fns read ``outs[0]``,
+    ``labels[0]``). With several (``output_names``: the graph's), every
+    leaf metric has to name the one output and the one label it reads
+    (``EvalMetric(output_names=[...], label_names=[...])``); its stat fn
+    is handed just those, and an output no metric names is read by
+    nothing, so the compiled window neither stacks nor fetches it."""
     if isinstance(eval_metric, metric_mod.CompositeEvalMetric):
         children = list(eval_metric.metrics)
     else:
         children = [eval_metric]
+    several = out_shapes is not None and (
+        len(out_shapes) != 1
+        or (label_names is not None and len(label_names) != 1))
+    if several and (output_names is None or label_names is None
+                    or len(output_names) != len(out_shapes)):
+        return None, ('%d outputs and %s labels, unnamed'
+                      % (len(out_shapes), 'no' if label_names is None
+                         else len(label_names)))
     fns = []
     for m in children:
         fn = _plan_one(m)
         if fn is None:
-            return None
+            return None, ('metric %r (%s) has no in-graph statistics'
+                          % (m.name, type(m).__name__))
+        oi = 0
+        if several:
+            at = _reads(m, list(output_names), list(label_names))
+            if isinstance(at, str):
+                return None, at
+            oi, li = at
+            fn = functools.partial(_pick, fn, oi, li)
+        if out_shapes is not None and (
+                len(out_shapes[oi]) != 2 or out_shapes[oi][1] < 2):
+            return None, ('output %d of shape %s is not (batch, classes '
+                          '>= 2)' % (oi, tuple(out_shapes[oi])))
         fns.append(fn)
-    return children, fns
+    return (children, fns), None
+
+
+def _pick(fn, oi, li, outs, labels):
+    return fn((outs[oi],), (labels[li],))
+
+
+def plan_metric(eval_metric, out_shapes=None, label_names=None,
+                output_names=None):
+    """:func:`plan_metric_or_reason`'s plan alone: (children, [stats_fn])
+    or None."""
+    return plan_metric_or_reason(eval_metric, out_shapes, label_names,
+                                 output_names)[0]
 
 
 def place_replicated(mesh, *trees):

@@ -1164,11 +1164,12 @@ def _by_head(x, heads):
     return x.reshape(B, T, heads, HD // heads).transpose(0, 2, 1, 3)
 
 
-def _latent_dims(q_nope, k_rope, v, heads):
-    """(Dn, Dr, Dv, scale) from the operands' widths."""
+def _latent_dims(q_nope, k_rope, v, heads, scale=None):
+    """(Dn, Dr, Dv, scale) from the operands' widths; the scores' scale is
+    ``1 / sqrt(Dn + Dr)`` unless one is given."""
     Dn, Dr, Dv = q_nope.shape[2] // heads, k_rope.shape[2], \
         v.shape[2] // heads
-    return Dn, Dr, Dv, (Dn + Dr) ** -0.5
+    return Dn, Dr, Dv, scale or (Dn + Dr) ** -0.5
 
 
 def _latent_specs(blk_q, blk_k, q_block, k_block):
@@ -1209,14 +1210,14 @@ def _walked(blocks_of):
 
 def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
                              block_q=512, block_k=512,
-                             name='attention_latent'):
+                             name='attention_latent', scale=None):
     """(out [B, T, H * Dv], lse [B, H, T]) of causal attention whose score
     is ``(q_nope k_nope^T + q_rope k_rope^T) / sqrt(Dn + Dr)``: q_nope and
     k_nope [B, T, H * Dn], q_rope [B, T, H * Dr], k_rope [B, T, Dr] (one
     head, read by all), v [B, T, H * Dv]. The kernel is named
     ``<name>_fwd`` in a device trace."""
     B, T, _ = q_nope.shape
-    Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads)
+    Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads, scale)
     blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
     nq, _, keys_of, _, steps, _ = _attn_walk(
         T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
@@ -1243,14 +1244,14 @@ def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
 
 def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
                               g_out, heads, block_q=512, block_k=512,
-                              name='attention_latent'):
+                              name='attention_latent', scale=None):
     """(dq_nope, dq_rope, dk_nope, dk_rope, dv) of
     :func:`latent_attention_forward` from its output, its log-sum-exp and
     the output's cotangent: ``<name>_dq`` over the key blocks of a query
     block, ``<name>_dkv`` over the query blocks of a key block and head;
     the shared rotary key's gradient is the sum of the heads' parts."""
     B, T, _ = q_nope.shape
-    Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads)
+    Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads, scale)
     blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
     Tq, Tk = T + pad_q, T + pad_k
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
@@ -1302,26 +1303,311 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
     return dqn[:, :T], dqr[:, :T], dkn[:, :T], dkr[:, :T], dv[:, :T]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads, block_q=512,
-                     block_k=512, name='attention_latent'):
+                     block_k=512, name='attention_latent', scale=None):
     """Causal latent attention in its expanded (training) form, forward
     and backward by the kernels above; returns [B, T, H * Dv]."""
     return latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
-                                    block_q, block_k, name)[0]
+                                    block_q, block_k, name, scale)[0]
 
 
 def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, heads, block_q, block_k,
-                name):
+                name, scale):
     out, lse = latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v,
-                                        heads, block_q, block_k, name)
+                                        heads, block_q, block_k, name, scale)
     # as _blockwise_fwd: a mirrored stage keeps the kernel's two outputs
     out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
     return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse)
 
 
-def _latent_bwd(heads, block_q, block_k, name, res, g):
-    return latent_attention_backward(*res, g, heads, block_q, block_k, name)
+def _latent_bwd(heads, block_q, block_k, name, scale, res, g):
+    return latent_attention_backward(*res, g, heads, block_q, block_k, name,
+                                     scale)
 
 
 latent_attention.defvjp(_latent_fwd, _latent_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Hyper-connections: n residual streams, read and written by per-token
+# coefficients
+# ---------------------------------------------------------------------------
+# The residual of a token is X [n, d], carried as one row of [rows, n * d]
+# (stream j its columns [j * d, (j + 1) * d)). A sublayer reads
+# ``y = sum_j H_pre[j] X[j]`` and writes ``X'[i] = H_post[i] z + sum_j
+# M[i, j] X[j]``; the coefficients come from the row itself,
+# ``c = alpha * (x W^T) / rms(x) + bias``. Both passes are bound by the
+# bytes of X: each kernel reads a block of rows once, in the operands' own
+# precision, works stream by stream in float32 and writes its result; no
+# float32 [rows, n * d] array exists. Coefficients cross as float32
+# [rows, HYPER_COLS] arrays whose unused columns are zero; what lies
+# between the two kernels (two sigmoids, the exponential, the projection
+# onto the doubly stochastic matrices) is 24 numbers a token and XLA's.
+
+HYPER_COLS = 32                 # columns of a coefficient array
+_HYPER_BLOCK_BYTES = 6 << 20    # of row blocks in flight, per buffer set
+
+
+def _dot32(a, b, contract):
+    """float32 operands at full precision (the default would round them
+    to bfloat16 on the chip)."""
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _hyper_params():
+    from jax.experimental.pallas import tpu as pltpu
+    # a block of rows of X is megabytes wide; the scoped default of 16 MiB
+    # holds two in flight and little else
+    return pltpu.CompilerParams(dimension_semantics=('arbitrary',),
+                                vmem_limit_bytes=64 << 20)
+
+
+def _hyper_rows(rows, width, itemsize, wide_operands):
+    """(pad, block) of the row axis: blocks of `wide_operands` arrays
+    [block, width] stay under _HYPER_BLOCK_BYTES together."""
+    want = _HYPER_BLOCK_BYTES // (width * itemsize * wide_operands)
+    want = max(16, min(256, want - want % 16))
+    return _pad_and_block(want, rows)
+
+
+def _pad0(x, pad):
+    return x if not pad else jnp.pad(x, ((0, pad), (0, 0)))
+
+
+def _stream(ref, j, d):
+    return ref[:, j * d:(j + 1) * d]
+
+
+def _put_col(c, k, value):
+    """c with column k replaced by value [blk, 1]."""
+    col = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+    return jnp.where(col == k, value, c)
+
+
+def _hyper_u(x_ref, w_ref, n, d):
+    """(x W^T [blk, HYPER_COLS], sum of squares [blk, 1]) of a block."""
+    u = jnp.zeros((x_ref.shape[0], HYPER_COLS), jnp.float32)
+    ss = jnp.zeros((x_ref.shape[0], 1), jnp.float32)
+    for j in range(n):
+        xj = _stream(x_ref, j, d)
+        u += _dot(xj, _stream(w_ref, j, d), ((1,), (1,)))
+        x32 = xj.astype(jnp.float32)
+        ss += jnp.sum(x32 * x32, axis=-1, keepdims=True)
+    return u, ss
+
+
+def _hyper_pre_fwd_kernel(x_ref, w_ref, a_ref, b_ref, y_ref, c_ref, *, n, d,
+                          eps):
+    u, ss = _hyper_u(x_ref, w_ref, n, d)
+    r = jax.lax.rsqrt(ss / (n * d) + eps)
+    c = u * r * a_ref[...] + b_ref[...]
+    hp = jax.nn.sigmoid(c)
+    y = hp[:, 0:1] * _stream(x_ref, 0, d).astype(jnp.float32)
+    for j in range(1, n):
+        y += hp[:, j:j + 1] * _stream(x_ref, j, d).astype(jnp.float32)
+    y_ref[...] = y.astype(y_ref.dtype)
+    # the last column is free: it carries 1 / rms to the backward pass
+    c_ref[...] = _put_col(c, HYPER_COLS - 1, r)
+
+
+def _hyper_pre_bwd_kernel(x_ref, w_ref, a_ref, c_ref, dy_ref, dc_ref, gx_ref,
+                          dx_ref, dcf_ref, dcm_ref, dw_ref, *, n, d):
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.float32)
+
+    c = c_ref[...]
+    r = c[:, HYPER_COLS - 1:HYPER_COLS]
+    hp = jax.nn.sigmoid(c)
+    dy = dy_ref[...].astype(jnp.float32)
+    u, _ = _hyper_u(x_ref, w_ref, n, d)
+    dc = _put_col(dc_ref[...], HYPER_COLS - 1, 0.0)
+    for j in range(n):      # y = sum_j sigmoid(c_j) x_j
+        hj = hp[:, j:j + 1]
+        dh = jnp.sum(dy * _stream(x_ref, j, d).astype(jnp.float32), axis=-1,
+                     keepdims=True)
+        dc = _put_col(dc, j, dc[:, j:j + 1] + dh * hj * (1.0 - hj))
+    dm = dc * a_ref[...]                        # c = a (u r) + b
+    du = dm * r
+    # r = (ss / nd + eps)^-1/2:  dr/dx = -r^3 x / nd
+    s = jnp.sum(dm * u, axis=-1, keepdims=True) * (-(r * r * r) / (n * d))
+    dcf_ref[...] = dc
+    dcm_ref[...] = dc * (u * r)
+    for j in range(n):
+        x32 = _stream(x_ref, j, d).astype(jnp.float32)
+        w32 = _stream(w_ref, j, d).astype(jnp.float32)
+        dxj = (_dot32(du, w32, ((1,), (0,))) + s * x32
+               + hp[:, j:j + 1] * dy
+               + _stream(gx_ref, j, d).astype(jnp.float32))
+        dx_ref[:, j * d:(j + 1) * d] = dxj.astype(dx_ref.dtype)
+        dw_ref[:, j * d:(j + 1) * d] += _dot32(du, x32, ((0,), (0,)))
+
+
+def _hyper_post_fwd_kernel(x_ref, z_ref, c_ref, o_ref, *, n, d):
+    c = c_ref[...]
+    z = z_ref[...].astype(jnp.float32)
+    for i in range(n):
+        acc = c[:, i:i + 1] * z
+        for j in range(n):
+            k = n + i * n + j
+            acc += c[:, k:k + 1] * _stream(x_ref, j, d).astype(jnp.float32)
+        o_ref[:, i * d:(i + 1) * d] = acc.astype(o_ref.dtype)
+
+
+def _hyper_post_bwd_kernel(g_ref, x_ref, z_ref, c_ref, dx_ref, dz_ref, dc_ref,
+                           *, n, d):
+    c = c_ref[...]
+    z = z_ref[...].astype(jnp.float32)
+    dc = jnp.zeros(c.shape, jnp.float32)
+    dz = jnp.zeros(z.shape, jnp.float32)
+    for i in range(n):
+        gi = _stream(g_ref, i, d).astype(jnp.float32)
+        dz += c[:, i:i + 1] * gi
+        dc = _put_col(dc, i, jnp.sum(z * gi, axis=-1, keepdims=True))
+        for j in range(n):
+            xj = _stream(x_ref, j, d).astype(jnp.float32)
+            dc = _put_col(dc, n + i * n + j,
+                          jnp.sum(gi * xj, axis=-1, keepdims=True))
+    dz_ref[...] = dz.astype(dz_ref.dtype)
+    dc_ref[...] = dc
+    for j in range(n):
+        acc = c[:, n + j:n + j + 1] * _stream(g_ref, 0, d).astype(
+            jnp.float32)
+        for i in range(1, n):
+            k = n + i * n + j
+            acc += c[:, k:k + 1] * _stream(g_ref, i, d).astype(jnp.float32)
+        dx_ref[:, j * d:(j + 1) * d] = acc.astype(dx_ref.dtype)
+
+
+def _hyper_call(kernel, name, rows, blk, ins, outs, **static):
+    """One pass over the row blocks. `ins` / `outs`: (array or
+    ShapeDtypeStruct, 'rows' for an operand cut in row blocks or 'whole'
+    for one every block reads)."""
+    def spec(a, how):
+        if how == 'rows':
+            return pl.BlockSpec((blk, a.shape[1]), lambda i: (i, 0))
+        return pl.BlockSpec(a.shape, lambda i: (0, 0))
+
+    return run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(kernel, **static), grid=(rows // blk,),
+        in_specs=[spec(a, how) for a, how in ins],
+        out_specs=[spec(a, how) for a, how in outs],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a, _ in outs],
+        compiler_params=_hyper_params(), interpret=interpret, name=name),
+        *[a for a, _ in ins])
+
+
+def hyper_pre_forward(x, w, a_row, b_row, n, eps, name='hyper_pre'):
+    """(y [R, d], c [R, HYPER_COLS] float32) of rows x [R, n * d]:
+    ``c = a_row * (x w^T) / rms(x) + b_row`` (its last column holds
+    1 / rms instead) and ``y = sum_j sigmoid(c[:, j]) x_j``; w
+    [HYPER_COLS, n * d], a_row and b_row [1, HYPER_COLS] float32. The
+    kernel is named ``<name>_fwd`` in a device trace."""
+    R, nd = x.shape
+    d = nd // n
+    pad, blk = _hyper_rows(R, nd, x.dtype.itemsize, 2)
+    f32 = jnp.float32
+    y, c = _hyper_call(
+        _hyper_pre_fwd_kernel, name + '_fwd', R + pad, blk,
+        [(_pad0(x, pad), 'rows'), (w, 'whole'), (a_row, 'whole'),
+         (b_row, 'whole')],
+        [(jax.ShapeDtypeStruct((R + pad, d), x.dtype), 'rows'),
+         (jax.ShapeDtypeStruct((R + pad, HYPER_COLS), f32), 'rows')],
+        n=n, d=d, eps=eps)
+    return y[:R], c[:R]
+
+
+def hyper_pre_backward(x, w, a_row, c, dy, dc, gx, n, name='hyper_pre'):
+    """(dx, dw float32, da_row, db_row) of :func:`hyper_pre_forward`, with
+    gx, the cotangent that reaches x by other ways (the sublayer's own
+    write), added into dx in the same pass. ``<name>_bwd``."""
+    R, nd = x.shape
+    d = nd // n
+    pad, blk = _hyper_rows(R, nd, x.dtype.itemsize, 6)
+    f32 = jnp.float32
+    rows = lambda width, dt: (  # noqa: E731
+        jax.ShapeDtypeStruct((R + pad, width), dt), 'rows')
+    dx, dcf, dcm, dw = _hyper_call(
+        _hyper_pre_bwd_kernel, name + '_bwd', R + pad, blk,
+        [(_pad0(x, pad), 'rows'), (w, 'whole'), (a_row, 'whole'),
+         (_pad0(c, pad), 'rows'), (_pad0(dy, pad), 'rows'),
+         (_pad0(dc.astype(f32), pad), 'rows'), (_pad0(gx, pad), 'rows')],
+        [rows(nd, x.dtype), rows(HYPER_COLS, f32), rows(HYPER_COLS, f32),
+         (jax.ShapeDtypeStruct(w.shape, f32), 'whole')],
+        n=n, d=d)
+    return (dx[:R], dw, jnp.sum(dcm[:R], axis=0, keepdims=True),
+            jnp.sum(dcf[:R], axis=0, keepdims=True))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def hyper_pre(x, w, a_row, b_row, n, eps):
+    """(y, c, x) of :func:`hyper_pre_forward`: x is handed on untouched so
+    that what reads it next (:func:`hyper_post`) sends its cotangent back
+    through here, where the backward kernel adds it in its one pass."""
+    y, c = hyper_pre_forward(x, w, a_row, b_row, n, eps)
+    return y, c, x
+
+
+def _hyper_pre_fwd(x, w, a_row, b_row, n, eps):
+    y, c = hyper_pre_forward(x, w, a_row, b_row, n, eps)
+    return (y, c, x), (x, w, a_row, c)
+
+
+def _hyper_pre_bwd(n, eps, res, g):
+    x, w, a_row, c = res
+    dy, dc, gx = g
+    dx, dw, da, db = hyper_pre_backward(x, w, a_row, c, dy, dc, gx, n)
+    return dx, dw.astype(w.dtype), da, db
+
+
+hyper_pre.defvjp(_hyper_pre_fwd, _hyper_pre_bwd)
+
+
+def hyper_post_forward(x, z, coef, n, name='hyper_post'):
+    """X' [R, n * d]: ``X'_i = coef[:, i] z + sum_j coef[:, n + i n + j]
+    x_j``; x [R, n * d], z [R, d], coef [R, HYPER_COLS] float32.
+    ``<name>_fwd``."""
+    R, nd = x.shape
+    pad, blk = _hyper_rows(R, nd, x.dtype.itemsize, 4)
+    out, = _hyper_call(
+        _hyper_post_fwd_kernel, name + '_fwd', R + pad, blk,
+        [(_pad0(x, pad), 'rows'), (_pad0(z, pad), 'rows'),
+         (_pad0(coef, pad), 'rows')],
+        [(jax.ShapeDtypeStruct((R + pad, nd), x.dtype), 'rows')],
+        n=n, d=nd // n)
+    return out[:R]
+
+
+def hyper_post_backward(g, x, z, coef, n, name='hyper_post'):
+    """(dx, dz, dcoef) of :func:`hyper_post_forward`. ``<name>_bwd``."""
+    R, nd = x.shape
+    d = nd // n
+    pad, blk = _hyper_rows(R, nd, x.dtype.itemsize, 6)
+    dx, dz, dc = _hyper_call(
+        _hyper_post_bwd_kernel, name + '_bwd', R + pad, blk,
+        [(_pad0(g, pad), 'rows'), (_pad0(x, pad), 'rows'),
+         (_pad0(z, pad), 'rows'), (_pad0(coef, pad), 'rows')],
+        [(jax.ShapeDtypeStruct((R + pad, nd), x.dtype), 'rows'),
+         (jax.ShapeDtypeStruct((R + pad, d), z.dtype), 'rows'),
+         (jax.ShapeDtypeStruct((R + pad, HYPER_COLS), jnp.float32), 'rows')],
+        n=n, d=d)
+    return dx[:R], dz[:R], dc[:R]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def hyper_post(x, z, coef, n):
+    return hyper_post_forward(x, z, coef, n)
+
+
+def _hyper_post_fwd(x, z, coef, n):
+    return hyper_post_forward(x, z, coef, n), (x, z, coef)
+
+
+def _hyper_post_bwd(n, res, g):
+    return hyper_post_backward(g, *res, n)
+
+
+hyper_post.defvjp(_hyper_post_fwd, _hyper_post_bwd)

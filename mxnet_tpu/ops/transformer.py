@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from . import pallas_kernels as pk
 from .registry import get as registry_get, register
 
-__all__ = ['MOE_STATS', 'moe_stat_names']
+__all__ = ['MOE_STATS', 'moe_stat_names', 'HYPER_STATS', 'hyper_stat_names']
 
 
 def _matmul(x, w):
@@ -195,7 +195,8 @@ def _gqa(attrs, q, k, v, gate=None):
 # Latent attention, expanded form
 # ---------------------------------------------------------------------------
 
-def _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads):
+def _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads,
+                            scale=None):
     """The plain form: one dense masked product, float32. For small shapes
     off the TPU."""
     B, T, _ = q_nope.shape
@@ -205,7 +206,7 @@ def _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads):
     kr = k_rope.astype(jnp.float32)
     s = (jnp.einsum('bqhd,bshd->bhqs', qn, kn)
          + jnp.einsum('bqhd,bsd->bhqs', qr, kr)) \
-        * (qn.shape[-1] + qr.shape[-1]) ** -0.5
+        * (scale or (qn.shape[-1] + qr.shape[-1]) ** -0.5)
     seen = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
     p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
     out = jnp.einsum('bhqs,bshd->bqhd', p, v4)
@@ -214,7 +215,7 @@ def _dense_latent_attention(q_nope, q_rope, k_nope, k_rope, v, heads):
 
 @register('LatentAttention',
           input_names=['q_nope', 'q_rope', 'k_nope', 'k_rope', 'value'],
-          param_defaults={'num_heads': 1})
+          param_defaults={'num_heads': 1, 'scale': 0.0})
 def _latent_attention(attrs, q_nope, q_rope, k_nope, k_rope, v):
     """Causal multi-head latent attention as training computes it, with the
     keys and values expanded from the latent: q_nope and k_nope
@@ -222,20 +223,186 @@ def _latent_attention(attrs, q_nope, q_rope, k_nope, k_rope, v):
     rotary key head every query head reads, value [B, T, H * Dv]; the head
     sizes are read from the widths. Head h's score is
     ``(q_nope_h k_nope_h^T + q_rope_h k_rope^T) / sqrt(Dn + Dr)``,
-    position t sees s <= t. Returns [B, T, H * Dv].
+    position t sees s <= t; ``scale`` other than 0 takes the place of
+    ``1 / sqrt(Dn + Dr)`` (a family whose rotary scaling also scales the
+    scores). Returns [B, T, H * Dv].
 
     On a TPU the kernels ``attention_latent_fwd``, ``_dq`` and ``_dkv`` run
     it: no [T, T] array exists and the rotary key is never broadcast."""
     H = int(attrs['num_heads'])
+    scale = float(attrs.get('scale', 0.0)) or None
 
     def fused(*operands):
         return pk.latent_attention(*operands, H, 512, 512,
-                                   'attention_latent')
+                                   'attention_latent', scale)
 
     def plain(*operands):
-        return _dense_latent_attention(*operands, H)
+        return _dense_latent_attention(*operands, H, scale)
 
     return pk.dispatch(fused, plain, q_nope, q_rope, k_nope, k_rope, v)
+
+
+# ---------------------------------------------------------------------------
+# Hyper-connections: n residual streams mixed by per-token coefficients
+# ---------------------------------------------------------------------------
+# Manifold-constrained hyper-connections (arXiv:2512.24880). The residual of
+# a token is X [n, d], carried as [B, T, n * d]. A sublayer F reads
+# y = sum_j H_pre[j] X[j] and writes X'[i] = H_post[i] F(..y..) + sum_j
+# M[i, j] X[j], with H_pre = sigmoid(.), H_post = 2 sigmoid(.) and M the
+# projection of exp(clip(.)) onto the doubly stochastic matrices by
+# alternating column and row normalisation; the arguments are
+# alpha * (vec(X) W^T) / rms(vec(X)) + bias, float32.
+
+# what HyperPre writes into its ``stats`` auxiliary state each step
+HYPER_STATS = ('res_dev_max',)
+
+
+def hyper_stat_names(symbol):
+    """Names of the auxiliary states that the HyperPre nodes of `symbol`
+    write their per-step statistics into, in graph order."""
+    at = registry_get('HyperPre').input_names.index('stats')
+    return [node.inputs[at][0].name for node in symbol._topo()
+            if not node.is_variable() and node.op == 'HyperPre']
+
+
+def _hyper_rows_of(w, bias, alpha, groups):
+    """(w padded to HYPER_COLS rows, alpha's and bias's [1, HYPER_COLS]
+    float32 rows): coefficient k of group g is scaled by alpha[g]."""
+    K = w.shape[0]
+    pad = pk.HYPER_COLS - K
+    a = jnp.concatenate([jnp.broadcast_to(alpha[g].astype(jnp.float32),
+                                          (size,))
+                         for g, size in enumerate(groups)])
+    return (jnp.pad(w, ((0, pad), (0, 0))),
+            jnp.pad(a, (0, pad)).reshape(1, -1),
+            jnp.pad(bias.astype(jnp.float32).reshape(-1), (0, pad))
+            .reshape(1, -1))
+
+
+def _hyper_pre_plain(x, w, a_row, b_row, n, eps):
+    """pk.hyper_pre in plain jnp: (y, c, x)."""
+    d = x.shape[1] // n
+    x32 = x.astype(jnp.float32)
+    r = jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    c = _matmul(x, w) * r * a_row + b_row
+    hp = jax.nn.sigmoid(c)
+    y = sum(hp[:, j:j + 1] * x32[:, j * d:(j + 1) * d] for j in range(n))
+    c = c.at[:, -1].set(r[:, 0])        # as the kernel: 1 / rms rides here
+    return y.astype(x.dtype), c, x
+
+
+def _hyper_pre(x, w, bias, alpha, n, eps, groups):
+    """(y [R, d], c [R, HYPER_COLS], x) for rows x [R, n * d]."""
+    w, a_row, b_row = _hyper_rows_of(w, bias, alpha, groups)
+    return pk.dispatch(
+        lambda *ops: pk.hyper_pre(*ops, n, eps),
+        lambda *ops: _hyper_pre_plain(*ops, n, eps), x, w, a_row, b_row)
+
+
+def sinkhorn(m, iters, eps):
+    """m [n, n, R], positive: `iters` times divided by its column sums +
+    eps and then by its row sums + eps (a token a lane). One traced round
+    in a ``lax.scan``: unrolled, the 13 nodes of a six-block model took
+    its training step 145 s to lower and compile for a v5e, and 68 so."""
+    def one(m, _):
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+        return m / (jnp.sum(m, axis=1, keepdims=True) + eps), None
+
+    return jax.lax.scan(one, m, None, length=iters)[0]
+
+
+def _hyper_coefficients(c, n, iters, eps, lo, hi):
+    """(coef [R, HYPER_COLS]: H_post in columns [0, n), M_T row-major in
+    [n, n + n^2), zeros after; the largest distance of M_T's row and
+    column sums from 1) from the arguments c [R, HYPER_COLS]."""
+    ct = c.T                                    # a token a lane
+    h_post = 2.0 * jax.nn.sigmoid(ct[n:2 * n])
+    m = sinkhorn(jnp.exp(jnp.clip(ct[2 * n:2 * n + n * n], lo, hi))
+                 .reshape(n, n, -1), iters, eps)
+    dev = jnp.maximum(jnp.max(jnp.abs(jnp.sum(m, axis=0) - 1.0)),
+                      jnp.max(jnp.abs(jnp.sum(m, axis=1) - 1.0)))
+    coef = jnp.concatenate(
+        [h_post, m.reshape(n * n, -1),
+         jnp.zeros((pk.HYPER_COLS - n - n * n, c.shape[0]), jnp.float32)])
+    return coef.T, jax.lax.stop_gradient(dev)
+
+
+@register('HyperPre',
+          input_names=['data', 'weight', 'bias', 'alpha', 'stats'],
+          param_defaults={'n': 4, 'eps': 1e-6, 'sinkhorn_iters': 20,
+                          'sinkhorn_eps': 1e-6, 'clamp_min': -30.0,
+                          'clamp_max': 30.0},
+          num_outputs=4, num_visible_outputs=3, mutate_inputs={4: 3},
+          aux_inputs=('stats',))
+def _hyper_pre_op(attrs, x, w, bias, alpha, stats):
+    """What a sublayer reads of ``n`` residual streams, and the coefficients
+    it will write with. data [B, T, n * d]; weight (2n + n^2, n * d), bias
+    (1, 2n + n^2), alpha (3,): with ``m = (x weight^T) / sqrt(mean(x^2) +
+    eps)`` over the whole row, ``H_pre = sigmoid(alpha[0] m[:n] + bias)``,
+    ``H_post = 2 sigmoid(alpha[1] m[n:2n] + bias)`` and ``M`` the matrix
+    ``exp(clip(alpha[2] m[2n:] + bias, clamp_min, clamp_max))`` (row-major)
+    after ``sinkhorn_iters`` rounds of division by its column sums +
+    ``sinkhorn_eps`` and then by its row sums + ``sinkhorn_eps``; the
+    gradient runs through every round. Returns (``y = sum_j H_pre[j] X[j]``
+    [B, T, d]; the coefficients [B, T, HYPER_COLS] float32 that
+    ``HyperPost`` takes; data itself, for ``HyperPost`` to read: its
+    cotangent then comes back through this op, whose backward kernel adds
+    it in its one pass over the streams). ``stats`` is an auxiliary state
+    that receives HYPER_STATS: the largest distance of M's row and column
+    sums from 1 this step.
+
+    On a TPU the kernels ``hyper_pre_fwd`` and ``hyper_pre_bwd`` run it:
+    each reads the streams once in their own precision and accumulates in
+    float32."""
+    n, eps = int(attrs['n']), float(attrs.get('eps', 1e-6))
+    lead, nd = x.shape[:-1], x.shape[-1]
+    y, c, x2 = _hyper_pre(x.reshape(-1, nd), w, bias, alpha, n, eps,
+                          (n, n, n * n))
+    coef, dev = _hyper_coefficients(
+        c, n, int(attrs.get('sinkhorn_iters', 20)),
+        float(attrs.get('sinkhorn_eps', 1e-6)),
+        float(attrs.get('clamp_min', -30.0)),
+        float(attrs.get('clamp_max', 30.0)))
+    return (y.reshape(lead + (nd // n,)), coef.reshape(lead + (-1,)),
+            x2.reshape(x.shape), dev.reshape(1).astype(stats.dtype))
+
+
+@register('HyperCollapse', input_names=['data', 'weight', 'bias', 'alpha'],
+          param_defaults={'n': 4, 'eps': 1e-6})
+def _hyper_collapse_op(attrs, x, w, bias, alpha):
+    """The streams read once more, after the last block: ``sum_j
+    sigmoid(alpha[0] m + bias)[j] X[j]`` with ``m = (x weight^T) /
+    sqrt(mean(x^2) + eps)``; data [B, T, n * d], weight (n, n * d), bias
+    (1, n), alpha (1,). Returns [B, T, d]. ``HyperPre``'s kernels."""
+    n, eps = int(attrs['n']), float(attrs.get('eps', 1e-6))
+    lead, nd = x.shape[:-1], x.shape[-1]
+    y, _, _ = _hyper_pre(x.reshape(-1, nd), w, bias, alpha, n, eps, (n,))
+    return y.reshape(lead + (nd // n,))
+
+
+@register('HyperPost', input_names=['data', 'update', 'coef'],
+          param_defaults={'n': 4})
+def _hyper_post_op(attrs, x, z, coef):
+    """What a sublayer writes: ``X'[i] = H_post[i] update + sum_j M[i, j]
+    X[j]`` with data [B, T, n * d] and coef as ``HyperPre`` gave them,
+    update [B, T, d]. Returns [B, T, n * d]. On a TPU: ``hyper_post_fwd``
+    and ``hyper_post_bwd``."""
+    n = int(attrs['n'])
+    nd, d = x.shape[-1], z.shape[-1]
+
+    def plain(x, z, coef):
+        z32 = z.astype(jnp.float32)
+        xs = [x[:, j * d:(j + 1) * d].astype(jnp.float32) for j in range(n)]
+        return jnp.concatenate(
+            [coef[:, i:i + 1] * z32
+             + sum(coef[:, n + i * n + j:n + i * n + j + 1] * xs[j]
+                   for j in range(n)) for i in range(n)],
+            axis=-1).astype(x.dtype)
+
+    out = pk.dispatch(lambda *ops: pk.hyper_post(*ops, n), plain,
+                      x.reshape(-1, nd), z.reshape(-1, d),
+                      coef.reshape(-1, coef.shape[-1]))
+    return out.reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------

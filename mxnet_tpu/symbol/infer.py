@@ -142,6 +142,27 @@ def _moe_params(attrs, in_shapes):
             'select_bias': (1, int(attrs['num_experts']))}
 
 
+@param_shape_hook('HyperPre')
+def _hyper_pre_params(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None:
+        return {}
+    from ..ops.transformer import HYPER_STATS
+    n = int(attrs.get('n', 4))
+    k = 2 * n + n * n
+    return {'weight': (k, data[-1]), 'bias': (1, k), 'alpha': (3,),
+            'stats': (len(HYPER_STATS),)}
+
+
+@param_shape_hook('HyperCollapse')
+def _hyper_collapse_params(attrs, in_shapes):
+    data = in_shapes[0]
+    if data is None:
+        return {}
+    n = int(attrs.get('n', 4))
+    return {'weight': (n, data[-1]), 'bias': (1, n), 'alpha': (1,)}
+
+
 @param_shape_hook('Embedding')
 def _emb_params(attrs, in_shapes):
     return {'weight': (int(attrs['input_dim']), int(attrs['output_dim']))}

@@ -12,7 +12,9 @@ reference ``benchmark/reference/deepseek_v3.py`` (loaded by path):
   experts' weights the bare scores';
 - eight shares of two experts, the shared experts counted once, add up to
   the uncut reference's layer;
-- the builder's shapes, the whole model's loss and every gradient, a
+- the builder's shapes (a low-rank query path and YaRN scaling, which this
+  builder refused until ``xing4_0`` came, are ``test_hyper_ops.py``'s), the
+  whole model's loss and every gradient, a
   mirrored block that runs ``attention_latent_fwd`` once, three ``fit``
   steps through the fused window;
 - ``laguna_s_2_1``'s symbol lowers to the text it had before this family
@@ -278,8 +280,9 @@ def test_builder_shapes_are_the_references():
 
 
 @pytest.mark.parametrize('unbuilt', [
-    dict(q_lora_rank=1536), dict(n_group=8, topk_group=4),
-    dict(rope_scaling={'type': 'yarn', 'factor': 40}),
+    dict(rope_scaling={'rope_type': 'llama3', 'factor': 8}),
+    dict(n_group=8, topk_group=4),
+    dict(rope_scaling={'type': 'linear', 'factor': 40}),
     dict(scoring_func='softmax')], ids=lambda v: sorted(v)[0])
 def test_builder_refuses_what_it_does_not_build(unbuilt):
     with pytest.raises(ValueError, match='deepseek_v3'):

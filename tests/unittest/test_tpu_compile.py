@@ -79,6 +79,10 @@ def _attention_specs(heads):
             ((1, 8192, 1024), BF16), ((1, 8192, heads * 128), BF16)]
 
 
+_HX, _HY = ((4096, 4 * 3584), BF16), ((4096, 3584), BF16)
+_HW, _HR = ((pk.HYPER_COLS, 4 * 3584), BF16), ((1, pk.HYPER_COLS), F32)
+_HC = ((4096, pk.HYPER_COLS), F32)
+
 KERNELS = [
     ('flash_fwd_b8_t1024', lambda q, k, v: pk.flash_attention(q, k, v, True),
      [((8, 1024, 8, 128), BF16)] * 3),
@@ -142,6 +146,16 @@ KERNELS = [
     ('moe_expert_matmul_768x16_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
         x, y, t, n, 16), [((51200, 2048), BF16), ((51200, 768), BF16),
                           ((400,), I32), ((1,), I32)]),
+    # the stream-mixing kernels at Xing4.0-29B-A4B's widths: 4 streams of
+    # 3584, one 4096-token sequence, 32 coefficient columns
+    ('hyper_pre_fwd', lambda x, w, a, b: pk.hyper_pre_forward(
+        x, w, a, b, 4, 1e-6), [_HX, _HW, _HR, _HR]),
+    ('hyper_pre_bwd', lambda x, w, a, c, dy, dc, gx: pk.hyper_pre_backward(
+        x, w, a, c, dy, dc, gx, 4), [_HX, _HW, _HR, _HC, _HY, _HC, _HX]),
+    ('hyper_post_fwd', lambda x, z, c: pk.hyper_post_forward(x, z, c, 4),
+     [_HX, _HY, _HC]),
+    ('hyper_post_bwd', lambda g, x, z, c: pk.hyper_post_backward(
+        g, x, z, c, 4), [_HX, _HX, _HY, _HC]),
 ]
 
 
