@@ -44,8 +44,10 @@ __all__ = ['flash_attention', 'flash_attention_lse', 'fused_rmsnorm',
 
 _NEG = -1e30
 
-# Mosaic's default scoped-VMEM limit on v5e is 16 MiB (the rehearsal
-# compiles of tests/unittest/test_tpu_compile.py hold these to it).
+# Mosaic's default scoped-VMEM limit on v5e is 16 MiB of the core's 128
+# (the rehearsal compiles of tests/unittest/test_tpu_compile.py hold every
+# kernel to it but one: the one backward kernel of blockwise attention asks
+# for what _bwd_vmem counts, 32 MiB at 8192 tokens).
 # Row kernels keep one f32 [blk, D] working tile under _ROW_TILE_BYTES:
 # the pipeline double-buffers the input and output blocks and the body
 # holds a few f32 temporaries of the same shape. At 2 MiB every row
@@ -54,6 +56,11 @@ _NEG = -1e30
 _ROW_TILE_BYTES = 2 << 20
 # flash forward holds whole-axis K and V blocks, double-buffered
 _FLASH_KV_BYTES = 10 << 20
+# Mosaic's default scope, and what the one backward kernel of blockwise
+# attention may hold beside it for a whole sequence (_bwd_vmem): three
+# eighths of a v5e core's 128 MiB of VMEM (jax pallas/mosaic/tpu_info.py)
+_VMEM_SCOPE = 16 << 20
+_BWD_RESIDENT_BYTES = 48 << 20
 
 
 def _by_platform(operands, on_tpu, elsewhere):
@@ -599,10 +606,19 @@ softmax_xent.defvjp(_xent_fwd, _xent_bwd)
 # The grid's last axis walks only the key blocks a query block can see
 # (those under the diagonal, and inside the window): a key block outside is
 # neither fetched (the index map stays on the last block that was) nor
-# computed. The backward is two kernels of the same shape, one per output:
-# dq over the key blocks of a query block, dk/dv over the query blocks (and
-# the query heads of the group) of a key block. Nothing of size Tq x Tk
-# exists in either direction.
+# computed. The backward is one kernel: for each visible pair of a query
+# block and a key block it makes the scores, the mask, p, dp and ds once
+# (keys first, [blk_k, blk_q], so that dk and dv take them as they lie and
+# only dq's product transposes ds) and adds to all three gradients. Its grid
+# walks, inside one key/value head's cell, the group's query heads, their
+# query blocks and each block's key blocks, with dq's block in scratch; dk
+# and dv of the head's whole sequence stay in VMEM, added into by a row
+# slice and written when the cell ends. Whether they fit is a function of
+# the shapes (`_bwd_vmem`); for a sequence past it the backward is the two
+# kernels it was, one per side: dq over the key blocks of a query block,
+# dk/dv over the query blocks (and the query heads of the group) of a key
+# block, each making the scores for itself. Nothing of size Tq x Tk exists
+# in either direction.
 
 def _attn_geometry(Tq, Tk, blk_q, blk_k, causal, window):
     """Block counts, and for each side the (first, last) block of the other
@@ -648,15 +664,18 @@ def _imin(a, b):
     return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
 
 
-def _visible(qi, kj, blk_q, blk_k, Tk, offset, causal, window):
-    """[blk_q, blk_k] mask of query block qi against key block kj. Every
-    block pays for it: taking the mask only where the diagonal or the
-    window's edge crosses a block (``lax.cond``) made the kernels a
-    quarter slower on the chip, not faster (PERF.md, PR 27)."""
+def _visible(qi, kj, blk_q, blk_k, Tk, offset, causal, window,
+             keys_first=False):
+    """[blk_q, blk_k] mask of query block qi against key block kj
+    ([blk_k, blk_q] with `keys_first`). Every block pays for it: taking
+    the mask only where the diagonal or the window's edge crosses a block
+    (``lax.cond``) made the kernels a quarter slower on the chip, not
+    faster (PERF.md, PR 27)."""
+    shape, q_axis = ((blk_k, blk_q), 1) if keys_first else ((blk_q, blk_k), 0)
     rows = qi * blk_q + offset + jax.lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 0)
+        jnp.int32, shape, q_axis)
     cols = kj * blk_k + jax.lax.broadcasted_iota(
-        jnp.int32, (blk_q, blk_k), 1)
+        jnp.int32, shape, 1 - q_axis)
     seen = cols < Tk            # keys past the end are padding
     if causal:
         seen &= cols <= rows
@@ -763,6 +782,63 @@ def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
+def _bwd_pair(s, seen, lse, delta, do, v):
+    """(p, ds) [blk_k, blk_q] of one block pair from its scaled scores, in
+    the operands' precision: the part of a backward step that dq, dk and dv
+    share."""
+    p = jnp.where(seen, jnp.exp(s - lse), 0.0)
+    dp = _dot(v, do, ((1,), (1,)))
+    return p.astype(do.dtype), (p * (dp - delta)).astype(do.dtype)
+
+
+def _block_rows(block, blk):
+    return pl.ds(pl.multiple_of(block * blk, blk), blk)
+
+
+def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                     dk_ref, dv_ref, dq_s, dk_s, dv_s, *, geo, scale):
+    """One key/value head's cell of the grid walks its group's query heads,
+    their query blocks and each block's visible key blocks; dk and dv of
+    the whole sequence stay in VMEM until the cell ends."""
+    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    member, qi, step = (pl.program_id(axis) for axis in (2, 3, 4))
+    lo, hi = keys_of(qi)
+    first = (member == 0) & (qi == 0) & (step == 0)
+    last = (member == pl.num_programs(2) - 1) \
+        & (qi == pl.num_programs(3) - 1) & (step == steps - 1)
+
+    @pl.when(first)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(step == 0)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        s = _dot(k, q, ((1,), (1,))) * scale
+        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
+                        window, keys_first=True)
+        p, ds = _bwd_pair(s, seen, lse_ref[0, 0, 0], delta_ref[0, 0, 0], do,
+                          v_ref[0])
+        rows = _block_rows(lo + step, blk_k)
+        dv_s[rows, :] += _dot(p, do, ((1,), (0,)))
+        dk_s[rows, :] += _dot(ds, q, ((1,), (0,)))
+        dq_s[...] += _dot(ds, k, ((0,), (0,)))
+
+    @pl.when(step == steps - 1)
+    def _():
+        dq_ref[0] = (dq_s[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _():
+        dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
 def _attn_blocks(Tq, Tk, block_q, block_k):
     """(blk_q, blk_k, pad_q, pad_k): advisory sizes coerced to Mosaic-legal
     ones; an axis with no legal divisor near the request is padded to a
@@ -777,14 +853,44 @@ def _attn_blocks(Tq, Tk, block_q, block_k):
     return blk_q, blk_k, pad_q, pad_k
 
 
+def _bwd_vmem(rows, widths, dtype):
+    """What decides between the one backward kernel and the two: the VMEM
+    limit the one kernel needs, or None where it does not fit. Its grid
+    walks one side of the block pairs; the other side's gradients (`widths`
+    columns each, of a whole sequence of `rows`) stay in VMEM until every
+    pair of a head has added to them: a float32 accumulator and the
+    output's two pipeline buffers each, lanes padded to 128. They may take
+    _BWD_RESIDENT_BYTES; the blocks in flight and the [blk, blk] score
+    tiles keep Mosaic's default scope beside them."""
+    lanes = sum(-(-w // 128) * 128 for w in widths)
+    resident = rows * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+    return resident + _VMEM_SCOPE if resident <= _BWD_RESIDENT_BYTES else None
+
+
 def _pad_rows(x, pad):
     return x if not pad else jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
 
 
-def _attn_params(n_parallel):
+def _cols(x, pad):
+    """A per-row statistic [B, H, T] as float32 columns [B, H, T + pad, 1]
+    (a block of it lies along the scores' rows)."""
+    return jnp.pad(x.astype(jnp.float32),
+                   ((0, 0), (0, 0), (0, pad)))[..., None]
+
+
+def _rows(x, pad, blk):
+    """The same as float32 rows [B, H, blocks, 1, blk], a block of it along
+    the columns of scores that come keys first; the last two axes are a
+    block's whole, which is legal at any block size."""
+    B, H, T = x.shape
+    return _cols(x, pad).reshape(B, H, (T + pad) // blk, 1, blk)
+
+
+def _attn_params(n_parallel, n_arbitrary=1, vmem=None):
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(dimension_semantics=(
-        ('parallel',) * n_parallel + ('arbitrary',)))
+        ('parallel',) * n_parallel + ('arbitrary',) * n_arbitrary),
+        vmem_limit_bytes=vmem)
 
 
 def _vmem(shape):
@@ -836,8 +942,10 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
                        causal=True, window=0, scale=None, block_q=512,
                        block_k=512, g_lse=None, name='attention'):
     """(dq, dk, dv) of :func:`attention_forward` from its output, its
-    log-sum-exp [B, H, Tq] and the output's cotangent. The kernels are
-    named ``<name>_dq`` and ``<name>_dkv`` in a device trace."""
+    log-sum-exp [B, H, Tq] and the output's cotangent. One kernel, named
+    ``<name>_bwd`` in a device trace, where dk and dv of a whole sequence
+    fit VMEM (:func:`_bwd_vmem`); past that two, ``<name>_dq`` and
+    ``<name>_dkv``."""
     B, Tq, HD = q.shape
     Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
     scale = D ** -0.5 if scale is None else scale
@@ -847,14 +955,40 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
                     .reshape(B, Tq, heads, D), axis=-1).transpose(0, 2, 1)
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
-    col = lambda x: jnp.pad(  # noqa: E731
-        x.astype(jnp.float32), ((0, 0), (0, 0), (0, pad_q)))[..., None]
-    lse, delta = col(lse), col(delta)
     q, g_out = _pad_rows(q, pad_q), _pad_rows(g_out, pad_q)
     k, v = _pad_rows(k, pad_k), _pad_rows(v, pad_k)
     nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
         Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
     base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
+    vmem = _bwd_vmem(Tk + pad_k, (D, D), k.dtype)
+    if vmem is not None:
+        # grid (batch, key/value head g, its m-th query head, i, s)
+        k_block = _walked(keys_of)
+        q_spec = pl.BlockSpec((1, blk_q, D),
+                              lambda b, g, m, i, s: (b, i, g * group + m))
+        k_spec = pl.BlockSpec((1, blk_k, D),
+                              lambda b, g, m, i, s: (b, k_block(i, s), g))
+        row_spec = pl.BlockSpec((1, 1, 1, 1, blk_q), lambda b, g, m, i, s: (
+            b, g * group + m, i, 0, 0))
+        whole = pl.BlockSpec((1, Tk + pad_k, D),
+                             lambda b, g, m, i, s: (b, 0, g))
+        dq, dk, dv = run_kernel(lambda interpret: pl.pallas_call(
+            functools.partial(_attn_bwd_kernel, geo=base + (keys_of, ksteps),
+                              scale=scale),
+            grid=(B, kv_heads, group, nq, ksteps),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=[q_spec, whole, whole],
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
+            scratch_shapes=[_vmem((blk_q, D)), _vmem((Tk + pad_k, D)),
+                            _vmem((Tk + pad_k, D))],
+            compiler_params=_attn_params(2, 3, vmem),
+            interpret=interpret, name=name + '_bwd'),
+            q, k, v, g_out, _rows(lse, pad_q, blk_q),
+            _rows(delta, pad_q, blk_q))
+        return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
+    lse, delta = _cols(lse, pad_q), _cols(delta, pad_q)
 
     def kv_index(b, h, i, s):
         lo, hi = keys_of(i)
@@ -1053,8 +1187,12 @@ def grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
 # projections' layout [B, T, H * D]; a head's 64 rotary query columns are no
 # legal block of [B, T, H * 64] (the minor block is 128 lanes or the whole
 # axis), so q_rope and its gradient cross as [B, H, T, Dr]. The shared key's
-# gradient is a sum over heads: the dkv kernel writes each head's part in
-# float32 and the sum is one reduction outside.
+# gradient is a sum over heads: the backward kernel writes each head's part
+# in float32 and the sum is one reduction outside. The one backward kernel
+# walks the other way round from the grouped-query one: a head's cell walks
+# its key blocks and each one's query blocks with dk_nope, dk_rope and dv in
+# scratch, and holds the head's dq_nope and dq_rope of the whole sequence
+# (6 MiB of float32 at 8192 tokens, where dk and dv would be 10).
 
 def _latent_scores(qn, qr, kn, kr, scale):
     return (_dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))) * scale
@@ -1158,6 +1296,55 @@ def _latent_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
+def _latent_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                       delta_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                       dqn_s, dqr_s, dkn_s, dkr_s, dv_s, *, geo, scale):
+    """A head's cell of the grid walks its key blocks and each one's visible
+    query blocks; the head's dq_nope and dq_rope of the whole sequence stay
+    in VMEM until the cell ends."""
+    blk_q, blk_k, Tk, offset, causal, window, queries_of, steps = geo
+    kj, step = pl.program_id(2), pl.program_id(3)
+    lo, hi = queries_of(kj)
+
+    @pl.when((kj == 0) & (step == 0))
+    def _():
+        dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
+        dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
+
+    @pl.when(step == 0)
+    def _():
+        dkn_s[...] = jnp.zeros(dkn_s.shape, jnp.float32)
+        dkr_s[...] = jnp.zeros(dkr_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(lo + step <= hi)
+    def _():
+        qn, qr, kn, kr, do = qn_ref[0], qr_ref[0, 0], kn_ref[0], kr_ref[0], \
+            do_ref[0]
+        s = _latent_scores(kn, kr, qn, qr, scale)
+        seen = _visible(lo + step, kj, blk_q, blk_k, Tk, offset, causal,
+                        window, keys_first=True)
+        p, ds = _bwd_pair(s, seen, lse_ref[0, 0, 0], delta_ref[0, 0, 0], do,
+                          v_ref[0])
+        rows = _block_rows(lo + step, blk_q)
+        dv_s[...] += _dot(p, do, ((1,), (0,)))
+        dkn_s[...] += _dot(ds, qn, ((1,), (0,)))
+        dkr_s[...] += _dot(ds, qr, ((1,), (0,)))
+        dqn_s[rows, :] += _dot(ds, kn, ((0,), (0,)))
+        dqr_s[rows, :] += _dot(ds, kr, ((0,), (0,)))
+
+    @pl.when(step == steps - 1)
+    def _():
+        dkn_ref[0] = (dkn_s[...] * scale).astype(dkn_ref.dtype)
+        dkr_ref[0, 0] = dkr_s[...] * scale
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+    @pl.when((kj == pl.num_programs(2) - 1) & (step == steps - 1))
+    def _():
+        dqn_ref[0] = (dqn_s[...] * scale).astype(dqn_ref.dtype)
+        dqr_ref[0, 0] = (dqr_s[...] * scale).astype(dqr_ref.dtype)
+
+
 def _by_head(x, heads):
     """[B, T, H * D] as [B, H, T, D]."""
     B, T, HD = x.shape
@@ -1247,9 +1434,12 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
                               name='attention_latent', scale=None):
     """(dq_nope, dq_rope, dk_nope, dk_rope, dv) of
     :func:`latent_attention_forward` from its output, its log-sum-exp and
-    the output's cotangent: ``<name>_dq`` over the key blocks of a query
-    block, ``<name>_dkv`` over the query blocks of a key block and head;
-    the shared rotary key's gradient is the sum of the heads' parts."""
+    the output's cotangent. One kernel, ``<name>_bwd``, over the query
+    blocks of a key block and head, where a head's dq_nope and dq_rope of
+    the whole sequence fit VMEM (:func:`_bwd_vmem`); past that two:
+    ``<name>_dq`` over the key blocks of a query block, ``<name>_dkv`` as
+    the one. The shared rotary key's gradient is the sum of the heads'
+    parts."""
     B, T, _ = q_nope.shape
     Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads, scale)
     blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
@@ -1257,47 +1447,63 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
     delta = jnp.sum((g_out.astype(jnp.float32) * out.astype(jnp.float32))
                     .reshape(B, T, heads, Dv), axis=-1).transpose(0, 2, 1)
-    col = lambda x: jnp.pad(  # noqa: E731
-        x.astype(jnp.float32), ((0, 0), (0, 0), (0, pad_q)))[..., None]
     nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
         T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
     base = (blk_q, blk_k, T, 0, True, 0)
     operands = (_pad_rows(q_nope, pad_q),
                 _by_head(_pad_rows(q_rope, pad_q), heads),
                 _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
-                _pad_rows(v, pad_k), _pad_rows(g_out, pad_q), col(lse),
-                col(delta))
+                _pad_rows(v, pad_k), _pad_rows(g_out, pad_q))
+    dk_shapes = [jax.ShapeDtypeStruct((B, Tk, heads * Dn), k_nope.dtype),
+                 jax.ShapeDtypeStruct((B, heads, Tk, Dr), jnp.float32),
+                 jax.ShapeDtypeStruct((B, Tk, heads * Dv), v.dtype)]
+    dq_shapes = [jax.ShapeDtypeStruct((B, Tq, heads * Dn), q_nope.dtype),
+                 jax.ShapeDtypeStruct((B, heads, Tq, Dr), q_rope.dtype)]
+    dk_scratch = [_vmem((blk_k, Dn)), _vmem((blk_k, Dr)), _vmem((blk_k, Dv))]
 
-    def in_specs(q, k, qh, kr):
-        return [q(Dn), qh(Dr), k(Dn), kr(Dr), k(Dv), q(Dv), qh(1), qh(1)]
+    def in_specs(q, k, qh, kr, stat):
+        return [q(Dn), qh(Dr), k(Dn), kr(Dr), k(Dv), q(Dv), stat, stat]
 
-    q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
-                                    _walked(keys_of))
-    dqn, dqr = run_kernel(lambda interpret: pl.pallas_call(
-        functools.partial(_latent_dq_kernel, geo=base + (keys_of, ksteps),
-                          scale=scale),
-        grid=(B, heads, nq, ksteps),
-        in_specs=in_specs(q, k, qh, kr), out_specs=[q(Dn), qh(Dr)],
-        out_shape=[jax.ShapeDtypeStruct((B, Tq, heads * Dn), q_nope.dtype),
-                   jax.ShapeDtypeStruct((B, heads, Tq, Dr), q_rope.dtype)],
-        scratch_shapes=[_vmem((blk_q, Dn)), _vmem((blk_q, Dr))],
-        compiler_params=_attn_params(3),
-        interpret=interpret, name=name + '_dq'), *operands)
-
-    q, k, qh, kh, kr = _latent_specs(blk_q, blk_k, _walked(queries_of),
-                                     lambda j, s: j)
-    dkn, dkr, dv = run_kernel(lambda interpret: pl.pallas_call(
-        functools.partial(_latent_dkv_kernel,
-                          geo=base + (queries_of, qsteps), scale=scale),
-        grid=(B, heads, nk, qsteps),
-        in_specs=in_specs(q, k, qh, kr), out_specs=[k(Dn), kh(Dr), k(Dv)],
-        out_shape=[jax.ShapeDtypeStruct((B, Tk, heads * Dn), k_nope.dtype),
-                   jax.ShapeDtypeStruct((B, heads, Tk, Dr), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Tk, heads * Dv), v.dtype)],
-        scratch_shapes=[_vmem((blk_k, Dn)), _vmem((blk_k, Dr)),
-                        _vmem((blk_k, Dv))],
-        compiler_params=_attn_params(3),
-        interpret=interpret, name=name + '_dkv'), *operands)
+    vmem = _bwd_vmem(Tq, (Dn, Dr), q_nope.dtype)
+    q_block = _walked(queries_of)
+    q, k, qh, kh, kr = _latent_specs(blk_q, blk_k, q_block, lambda j, s: j)
+    if vmem is not None:
+        row = pl.BlockSpec((1, 1, 1, 1, blk_q), lambda b, h, j, s: (
+            b, h, q_block(j, s), 0, 0))
+        whole = [pl.BlockSpec((1, Tq, Dn), lambda b, h, j, s: (b, 0, h)),
+                 pl.BlockSpec((1, 1, Tq, Dr), lambda b, h, j, s: (b, h, 0, 0))]
+        dqn, dqr, dkn, dkr, dv = run_kernel(lambda interpret: pl.pallas_call(
+            functools.partial(_latent_bwd_kernel,
+                              geo=base + (queries_of, qsteps), scale=scale),
+            grid=(B, heads, nk, qsteps),
+            in_specs=in_specs(q, k, qh, kr, row),
+            out_specs=whole + [k(Dn), kh(Dr), k(Dv)],
+            out_shape=dq_shapes + dk_shapes,
+            scratch_shapes=[_vmem((Tq, Dn)), _vmem((Tq, Dr))] + dk_scratch,
+            compiler_params=_attn_params(2, 2, vmem),
+            interpret=interpret, name=name + '_bwd'),
+            *operands, _rows(lse, pad_q, blk_q), _rows(delta, pad_q, blk_q))
+    else:
+        operands += (_cols(lse, pad_q), _cols(delta, pad_q))
+        dkn, dkr, dv = run_kernel(lambda interpret: pl.pallas_call(
+            functools.partial(_latent_dkv_kernel,
+                              geo=base + (queries_of, qsteps), scale=scale),
+            grid=(B, heads, nk, qsteps),
+            in_specs=in_specs(q, k, qh, kr, qh(1)),
+            out_specs=[k(Dn), kh(Dr), k(Dv)], out_shape=dk_shapes,
+            scratch_shapes=dk_scratch, compiler_params=_attn_params(3),
+            interpret=interpret, name=name + '_dkv'), *operands)
+        q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
+                                        _walked(keys_of))
+        dqn, dqr = run_kernel(lambda interpret: pl.pallas_call(
+            functools.partial(_latent_dq_kernel, geo=base + (keys_of, ksteps),
+                              scale=scale),
+            grid=(B, heads, nq, ksteps),
+            in_specs=in_specs(q, k, qh, kr, qh(1)),
+            out_specs=[q(Dn), qh(Dr)], out_shape=dq_shapes,
+            scratch_shapes=[_vmem((blk_q, Dn)), _vmem((blk_q, Dr))],
+            compiler_params=_attn_params(3),
+            interpret=interpret, name=name + '_dq'), *operands)
     dqr = dqr.transpose(0, 2, 1, 3).reshape(B, Tq, heads * Dr)
     dkr = jnp.sum(dkr, axis=1).astype(k_rope.dtype)
     return dqn[:, :T], dqr[:, :T], dkn[:, :T], dkr[:, :T], dv[:, :T]

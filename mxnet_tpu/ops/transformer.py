@@ -227,8 +227,9 @@ def _latent_attention(attrs, q_nope, q_rope, k_nope, k_rope, v):
     ``1 / sqrt(Dn + Dr)`` (a family whose rotary scaling also scales the
     scores). Returns [B, T, H * Dv].
 
-    On a TPU the kernels ``attention_latent_fwd``, ``_dq`` and ``_dkv`` run
-    it: no [T, T] array exists and the rotary key is never broadcast."""
+    On a TPU the kernels ``attention_latent_fwd`` and ``_bwd`` run it
+    (``_dq`` and ``_dkv`` for a sequence whose gradients do not fit VMEM):
+    no [T, T] array exists and the rotary key is never broadcast."""
     H = int(attrs['num_heads'])
     scale = float(attrs.get('scale', 0.0)) or None
 

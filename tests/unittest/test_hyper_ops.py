@@ -298,7 +298,7 @@ KANANA_TEXT = {
     'plain':
     'aed1164f0b27c9cb07bedd346d71a158e01060813060bab0d911f52bad3defd6',
     'kernel':
-    'fd6ea2eacd04c894b04c4f205cf79721dca364c6dd92f3b6101fb57f61640c92'}
+    'c2c4e2e111717b52b540592a220458178135364c9ff5de47abf7971ad8eb8385'}
 
 
 def kanana_step_digest():
@@ -312,6 +312,33 @@ def kanana_step_digest():
 @pytest.mark.parametrize('path', PATHS, indirect=True)
 def test_kanana_lowers_to_the_text_it_had(path):
     assert kanana_step_digest() == KANANA_TEXT[path]
+
+
+# -- one backward kernel an attention layer, in every decoder -----------------------------------
+
+# builder, configuration, kernel name -> attention layers (Xing4.0's
+# prediction module is one more block)
+DECODERS = {
+    'laguna_full': (lambda: (cases.builder, cases.CFG), 'attention_full', 2),
+    'laguna_window': (lambda: (cases.builder, cases.CFG),
+                      'attention_window', 3),
+    'kanana': (lambda: (latent.builder, latent.CFG), 'attention_latent', 5),
+    'xing4': (lambda: (builder, CFG), 'attention_latent', 3),
+}
+
+
+@pytest.mark.parametrize('path', ['kernel'], indirect=True)
+@pytest.mark.parametrize('decoder', sorted(DECODERS))
+def test_a_training_step_holds_one_backward_kernel_an_attention_layer(
+        path, decoder):
+    """The kernel-path training step of each decoder builder: one
+    ``*_fwd`` and one ``*_bwd`` kernel an attention layer (each traced in
+    its compiled and its interpreted form), no ``_dq`` and no ``_dkv``."""
+    found, name, layers = DECODERS[decoder]
+    build, cfg = found()
+    step, wrt = _training_step(build.get_symbol(dict(cfg)), **LM_IN)
+    calls = cases._kernel_calls(str(jax.make_jaxpr(step)(wrt)), name)
+    assert calls == {'fwd': 2 * layers, 'bwd': 2 * layers, 'dq': 0, 'dkv': 0}
 
 
 # -- the benchmark's own files for this family ------------------------------------------------

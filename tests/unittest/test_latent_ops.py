@@ -61,6 +61,7 @@ cases = _load('tests/unittest/test_transformer_ops.py',
 path, PATHS, LM_IN = cases.path, cases.PATHS, cases.LM_IN
 _rand, _close, _both, op = cases._rand, cases._close, cases._both, cases.op
 _training_step, _kernel_calls = cases._training_step, cases._kernel_calls
+_one_backward_kernel = cases._one_backward_kernel
 _reload_telemetry = cases._reload_telemetry
 
 CFG = dict(
@@ -105,10 +106,81 @@ def test_latent_attention(path, length):
 @pytest.mark.parametrize('length,block', [
     (37, 8), (37, 16), (50, 16), (64, 16), (33, 32), (40, 8), (64, 512)])
 def test_latent_kernels_against_the_dense_mask(length, block):
-    """Forward, dq and dkv kernels, lengths that are and are not a multiple
-    of the block."""
+    """Forward and backward kernels, lengths that are and are not a
+    multiple of the block."""
     _both(lambda *o: pk.latent_attention(*o, H, block, block, 'test'),
           _reference_attention(length), *_latent_operands(length, seed=7))
+
+
+def _plain_latent(qn, qr, kn, kr, v, scale):
+    """(out, lse) in plain float32, the [T, T] scores formed whole."""
+    B, length, _ = qn.shape
+    by_head = lambda x, D: x.reshape(B, length, H, D)  # noqa: E731
+    s = (jnp.einsum('bqhd,bshd->bhqs', by_head(qn, Dn), by_head(kn, Dn),
+                    precision='highest')
+         + jnp.einsum('bqhd,bsd->bhqs', by_head(qr, Dr), kr,
+                      precision='highest')) * scale
+    seen = jnp.arange(length)[None, :] <= jnp.arange(length)[:, None]
+    s = jnp.where(seen, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum('bhqs,bshd->bqhd', jnp.exp(s - lse[..., None]),
+                     by_head(v, Dv), precision='highest')
+    return out.reshape(B, length, H * Dv), lse
+
+
+# name: length, block, the scores' scale (None: 1 / sqrt(Dn + Dr))
+LATENT_BACKWARD_CASES = {
+    'whole_blocks': (64, 16, None),
+    'padded_length': (37, 16, None),
+    'one_block': (40, 512, None),
+    'a_scale_of_its_own': (48, 16, 0.29),
+    'padded_with_a_scale': (50, 8, 0.29),
+}
+
+
+@pytest.mark.parametrize('case', sorted(LATENT_BACKWARD_CASES))
+def test_latent_backward_one_kernel(case, monkeypatch):
+    """All five gradients of the one backward kernel against ``jax.vjp`` of
+    the plain float32 formulation and against the two kernels."""
+    length, block, scale = LATENT_BACKWARD_CASES[case]
+    operands = _latent_operands(length, seed=30)
+    g_out = _rand(36, 1, length, H * Dv)
+    (out, lse), vjp = jax.vjp(lambda *o: _plain_latent(
+        *o, scale or (Dn + Dr) ** -0.5), *operands)
+    want = vjp((g_out, jnp.zeros_like(lse)))
+
+    def backward(name):
+        f = lambda *a: pk.latent_attention_backward(  # noqa: E731
+            *a, H, block, block, name, scale)
+        args = operands + (out, lse, g_out)
+        return cases._backward_kernels(str(jax.make_jaxpr(f)(*args)), name), \
+            f(*args)
+
+    which, one = backward('one')
+    assert which == 'one'
+    cases._two_kernels(monkeypatch)
+    which, two = backward('two')
+    assert which == 'two'
+    for a, b, c in zip(one, want, two):
+        _close(a, b)
+        _close(a, c)
+
+
+@pytest.mark.parametrize('length,fits', [
+    (4096, True), (8192, True), (16384, True), (65536, False)])
+def test_the_shapes_decide_between_one_latent_backward_kernel_and_two(
+        length, fits):
+    """[1, T, .] bfloat16 at the published widths (32 heads of 128 + 64 /
+    128): which kernels run is a function of the shapes alone."""
+    spec = lambda width, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, length, width), dtype)
+    text = str(jax.make_jaxpr(lambda *a: pk.latent_attention_backward(
+        *a, 32, name='rule'))(
+        spec(4096), spec(2048), spec(4096), spec(64), spec(4096), spec(4096),
+        jax.ShapeDtypeStruct((1, 32, length), jnp.float32), spec(4096)))
+    assert cases._backward_kernels(text, 'rule') == \
+        ('one' if fits else 'two')
+    assert (pk._bwd_vmem(length, (128, 64), jnp.bfloat16) is not None) == fits
 
 
 def test_latent_kernels_are_the_grouped_query_ones_where_both_apply():
@@ -324,7 +396,7 @@ def test_model_forward_and_gradient(remat):
 def test_a_mirrored_block_runs_the_latent_forward_kernel_once(
         path, monkeypatch):
     """In the gradient of the mirrored blocks ``attention_latent_fwd`` is
-    there as often as each backward kernel, once a block; under a bare
+    there as often as the backward kernel, once a block; under a bare
     checkpoint twice. ``executor.mirror_kept`` counts its output and its
     log-sum-exp, and their bytes follow from the shapes."""
     monkeypatch.setenv('MXTPU_TELEMETRY', '1')
@@ -339,7 +411,7 @@ def test_a_mirrored_block_runs_the_latent_forward_kernel_once(
         monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
         _reload_telemetry()
     layers = CFG['num_hidden_layers']
-    assert calls['fwd'] == calls['dq'] == calls['dkv'] > 0, calls
+    _one_backward_kernel(calls)
     assert gauges['executor.mirror_kept'] == 2 * layers
     assert gauges['executor.mirror_kept_bytes'] == layers * (
         2 * T * H * Dv * 4 + 2 * H * T * 4)
@@ -347,7 +419,7 @@ def test_a_mirrored_block_runs_the_latent_forward_kernel_once(
                         lambda f, kept: jax.checkpoint(f))
     step, wrt = _training_step(builder.get_symbol(CFG), **LM_IN)
     bare = _kernel_calls(str(jax.make_jaxpr(step)(wrt)), 'attention_latent')
-    assert bare['fwd'] == 2 * calls['fwd'] and bare['dq'] == calls['dq']
+    assert bare['fwd'] == 2 * calls['fwd'] and bare['bwd'] == calls['bwd']
 
 
 # -- Module.fit ---------------------------------------------------------------------------
@@ -408,7 +480,7 @@ LAGUNA_TEXT = {
     'plain':
     '96279909c65564df3a01041f79d6454f6965bcf790a16b9ba6aff482a6fb16e2',
     'kernel':
-    'fc2cd27eb6754cf9b7bef8de050b793f475327fd5efaf41bf383ceb2fc927863'}
+    '7ee68cc1722ac63d69b51677205adcacbfbe4c99ac7ab99253bf7fedb62d9899'}
 
 
 def laguna_step_digest():

@@ -12,6 +12,7 @@ not in a child process. Only the worker that is handed this file loads
 the TPU's library, and all such compiles live in this ONE file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -49,13 +50,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *specs):
+def _compile(fn, one_chip, *specs, kernels=()):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     # the kernel itself, not the interpreter's while loop and not jnp
     assert 'tpu_custom_call' in text, text[:2000]
+    if kernels:
+        found = set(re.findall(r'attention\w*_(?:fwd|bwd|dq|dkv)\b', text))
+        assert found == set(kernels), found
     return compiled
 
 
@@ -69,14 +73,29 @@ def _attention(q, k, v, g, heads, window, block):
                                  name='attention')
 
 
-def _latent_attention(qn, qr, kn, kr, v, g):
-    out, lse = pk.latent_attention_forward(qn, qr, kn, kr, v, 32)
-    return pk.latent_attention_backward(qn, qr, kn, kr, v, out, lse, g, 32)
+def _latent_attention(qn, qr, kn, kr, v, g, scale=None):
+    out, lse = pk.latent_attention_forward(qn, qr, kn, kr, v, 32,
+                                           scale=scale)
+    return pk.latent_attention_backward(qn, qr, kn, kr, v, out, lse, g, 32,
+                                        scale=scale)
 
 
-def _attention_specs(heads):
-    return [((1, 8192, heads * 128), BF16), ((1, 8192, 1024), BF16),
-            ((1, 8192, 1024), BF16), ((1, 8192, heads * 128), BF16)]
+def _attention_specs(heads, length=8192):
+    return [((1, length, heads * 128), BF16), ((1, length, 1024), BF16),
+            ((1, length, 1024), BF16), ((1, length, heads * 128), BF16)]
+
+
+def _latent_specs(length):
+    return [((1, length, 4096), BF16), ((1, length, 2048), BF16),
+            ((1, length, 4096), BF16), ((1, length, 64), BF16),
+            ((1, length, 4096), BF16), ((1, length, 4096), BF16)]
+
+
+_ONE = ('attention_fwd', 'attention_bwd')
+_TWO = ('attention_fwd', 'attention_dq', 'attention_dkv')
+_LATENT_ONE = ('attention_latent_fwd', 'attention_latent_bwd')
+_LATENT_TWO = ('attention_latent_fwd', 'attention_latent_dq',
+               'attention_latent_dkv')
 
 
 _HX, _HY = ((4096, 4 * 3584), BF16), ((4096, 3584), BF16)
@@ -110,11 +129,18 @@ KERNELS = [
      [((4096, 50304), BF16), ((4096,), I32)]),
     # the decoder block's kernels at Laguna-S-2.1's widths (head 128, 8
     # key/value heads, one 8192-token sequence): sliding layers have 72
-    # query heads and a window of 512, full layers 48
+    # query heads and a window of 512, full layers 48. The backward is the
+    # one kernel that holds a key/value head's dk and dv of the whole
+    # sequence in VMEM (32 MiB asked of Mosaic), as far as 16384 tokens;
+    # at 65536 they do not fit and the two kernels compile
     ('attention_window_fwd_bwd', lambda q, k, v, g: _attention(
-        q, k, v, g, 72, 512, 256), _attention_specs(72)),
+        q, k, v, g, 72, 512, 256), _attention_specs(72), _ONE),
     ('attention_full_fwd_bwd', lambda q, k, v, g: _attention(
-        q, k, v, g, 48, 0, 512), _attention_specs(48)),
+        q, k, v, g, 48, 0, 512), _attention_specs(48), _ONE),
+    ('attention_full_fwd_bwd_t16384', lambda q, k, v, g: _attention(
+        q, k, v, g, 48, 0, 512), _attention_specs(48, 16384), _ONE),
+    ('attention_full_fwd_bwd_t65536', lambda q, k, v, g: _attention(
+        q, k, v, g, 48, 0, 512), _attention_specs(48, 65536), _TWO),
     # the held experts' grouped product: 8 experts of 3072 x 1024, the
     # static worst-case buffer of 8192 x 8 + 8 x 128 rows
     ('moe_expert_matmul', lambda x, w, t, n: pk.grouped_matmul(x, w, t, n),
@@ -129,11 +155,16 @@ KERNELS = [
      [((66560, 3072), BF16), ((66560, 1024), BF16), ((520,), I32),
       ((1,), I32)]),
     # latent attention at kanana-2-30b-a3b's widths: 32 heads, keys of
-    # 128 + 64 (the 64 rotary ones shared by all heads), values of 128
-    ('attention_latent_fwd_bwd', _latent_attention,
-     [((1, 8192, 4096), BF16), ((1, 8192, 2048), BF16),
-      ((1, 8192, 4096), BF16), ((1, 8192, 64), BF16),
-      ((1, 8192, 4096), BF16), ((1, 8192, 4096), BF16)]),
+    # 128 + 64 (the 64 rotary ones shared by all heads), values of 128;
+    # the one backward kernel holds a head's dq_nope and dq_rope. At
+    # Xing4.0-29B-A4B's 4096 tokens with a scale of its own, and past the
+    # rule, where the two kernels run
+    ('attention_latent_fwd_bwd', _latent_attention, _latent_specs(8192),
+     _LATENT_ONE),
+    ('attention_latent_fwd_bwd_t4096_scale', lambda *a: _latent_attention(
+        *a, scale=0.1147), _latent_specs(4096), _LATENT_ONE),
+    ('attention_latent_fwd_bwd_t65536', _latent_attention,
+     _latent_specs(65536), _LATENT_TWO),
     # and its held experts: 16 of 2048 x 768, top 6, a buffer of
     # 8192 x 6 + 16 x 128 rows
     ('moe_expert_matmul_768x16', lambda x, w, t, n: pk.grouped_matmul(
@@ -159,10 +190,11 @@ KERNELS = [
 ]
 
 
-@pytest.mark.parametrize('name,fn,specs', KERNELS,
+@pytest.mark.parametrize('name,fn,specs,kernels',
+                         [k if len(k) == 4 else k + ((),) for k in KERNELS],
                          ids=[k[0] for k in KERNELS])
-def test_kernel_compiles_for_v5e(one_chip, name, fn, specs):
-    compiled = _compile(fn, one_chip, *specs)
+def test_kernel_compiles_for_v5e(one_chip, name, fn, specs, kernels):
+    compiled = _compile(fn, one_chip, *specs, kernels=kernels)
     mem = compiled.memory_analysis()
     assert mem is not None and mem.temp_size_in_bytes >= 0
 

@@ -520,7 +520,14 @@ def _training_step(sym, seed=1, **input_shapes):
 
 def _kernel_calls(text, name):
     return {k: text.count('name=%s_%s' % (name, k))
-            for k in ('fwd', 'dq', 'dkv')}
+            for k in ('fwd', 'bwd', 'dq', 'dkv')}
+
+
+def _one_backward_kernel(calls):
+    """As many ``_bwd`` kernels as forward ones, and neither of the two
+    that a sequence past the VMEM rule takes."""
+    assert calls['fwd'] == calls['bwd'] > 0 == calls['dq'] == calls['dkv'], \
+        calls
 
 
 def _bare_checkpoint(monkeypatch):
@@ -536,15 +543,15 @@ LM_IN = dict(data=(2, T), softmax_label=(2, T))
 
 def _forward_kernel_runs_once(kind, monkeypatch):
     """(a) in the gradient of a mirrored block the forward kernel is there
-    as often as each backward kernel, once; under a bare checkpoint twice."""
+    as often as the backward kernel, once; under a bare checkpoint twice."""
     kind, name = KINDS[kind]
     step, wrt = _training_step(_one_block(kind)[0], **LM_IN)
     calls = _kernel_calls(str(jax.make_jaxpr(step)(wrt)), name)
-    assert calls['fwd'] == calls['dq'] == calls['dkv'] > 0, calls
+    _one_backward_kernel(calls)
     _bare_checkpoint(monkeypatch)
     step, wrt = _training_step(_one_block(kind)[0], **LM_IN)
     bare = _kernel_calls(str(jax.make_jaxpr(step)(wrt)), name)
-    assert bare['fwd'] == 2 * calls['fwd'] and bare['dq'] == calls['dq']
+    assert bare['fwd'] == 2 * calls['fwd'] and bare['bwd'] == calls['bwd']
 
 
 def _gradients_are_the_bare_checkpoints(kind, monkeypatch):
@@ -626,7 +633,7 @@ def _the_whole_forward_mirror_keeps_them_too(kind, monkeypatch):
         flags.reload('MXTPU_BACKWARD_DO_MIRROR')
     calls = _kernel_calls(jaxpr, name)
     assert 'remat' in jaxpr
-    assert calls['fwd'] == calls['dq'] == calls['dkv'] > 0, calls
+    _one_backward_kernel(calls)
 
 
 def _the_gauge_is_the_bytes_of_out_and_lse(_, monkeypatch):
@@ -789,6 +796,117 @@ def test_flash_attention_backward_is_the_blockwise_one():
     for a, b in zip(jax.grad(f, (0, 1, 2))(q, k, v),
                     jax.grad(g, (0, 1, 2))(q, k, v)):
         _close(a, b, tol=1e-4)
+
+
+# -- the backward kernel: one where dk and dv of a sequence fit VMEM, else two ---------
+
+def _plain_attention(q, k, v, heads, kv_heads, causal, window, scale):
+    """(out, lse) of grouped-query attention in plain float32, the
+    [Tq, Tk] scores formed whole; the mask is bottom-right aligned."""
+    B, Tq, HD = q.shape
+    Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
+    q5 = q.reshape(B, Tq, kv_heads, group, D)
+    k4, v4 = k.reshape(B, Tk, kv_heads, D), v.reshape(B, Tk, kv_heads, D)
+    s = jnp.einsum('bqkgd,bskd->bkgqs', q5, k4,
+                   precision='highest') * scale
+    rows = jnp.arange(Tq)[:, None] + Tk - Tq
+    cols = jnp.arange(Tk)[None, :]
+    seen = jnp.ones((Tq, Tk), bool)
+    if causal:
+        seen &= cols <= rows
+    if window:
+        seen &= cols > rows - window
+    s = jnp.where(seen, s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum('bkgqs,bskd->bqkgd', jnp.exp(s - lse[..., None]), v4,
+                     precision='highest')
+    return out.reshape(B, Tq, HD), lse.reshape(B, heads, Tq)
+
+
+def _backward_kernels(text, name):
+    """'one' or 'two': which backward kernels a traced call holds (under a
+    trace each is there in its compiled and its interpreted form)."""
+    calls = _kernel_calls(text, name)
+    assert calls['dq'] == calls['dkv'] and (calls['bwd'] > 0) \
+        != (calls['dq'] > 0), calls
+    return 'one' if calls['bwd'] else 'two'
+
+
+def _two_kernels(monkeypatch):
+    """The rule as a sequence too long for VMEM meets it."""
+    monkeypatch.setattr(pk, '_BWD_RESIDENT_BYTES', 0)
+
+
+# name: Tq, Tk, heads, key/value heads, causal, window, block, g_lse given
+BACKWARD_CASES = {
+    'full_causal': (64, 64, 4, 4, True, 0, 16, False),
+    'window_off_the_block': (64, 64, 6, 2, True, 24, 16, False),
+    'group_of_three': (48, 48, 6, 2, True, 0, 16, False),
+    'non_causal': (48, 48, 4, 2, False, 0, 16, False),
+    'padded_length': (37, 37, 6, 2, True, 0, 16, False),
+    'padded_window': (37, 37, 6, 2, True, 8, 8, False),
+    'more_keys_than_queries': (24, 40, 4, 2, True, 0, 8, False),
+    'log_sum_exp_cotangent': (32, 32, 4, 2, True, 0, 8, True),
+    'one_block': (40, 40, 6, 2, True, 0, 512, False),
+}
+
+
+@pytest.mark.parametrize('case', sorted(BACKWARD_CASES))
+def test_attention_backward_one_kernel(case, monkeypatch):
+    """dq, dk and dv of the one backward kernel against ``jax.vjp`` of the
+    plain float32 formulation and against the two kernels."""
+    Tq, Tk, heads, kv, causal, window, block, with_lse = BACKWARD_CASES[case]
+    q, k, v = (_rand(60, 2, Tq, heads * D), _rand(61, 2, Tk, kv * D),
+               _rand(62, 2, Tk, kv * D))
+    g_out = _rand(63, 2, Tq, heads * D)
+    g_lse = _rand(64, 2, heads, Tq) if with_lse else None
+    scale = D ** -0.5
+    (out, lse), vjp = jax.vjp(lambda q, k, v: _plain_attention(
+        q, k, v, heads, kv, causal, window, scale), q, k, v)
+    want = vjp((g_out, jnp.zeros_like(lse) if g_lse is None else g_lse))
+
+    def backward(name):
+        f = lambda *a: pk.attention_backward(  # noqa: E731
+            *a, heads, kv, causal, window, None, block, block, g_lse=g_lse,
+            name=name)
+        args = (q, k, v, out, lse, g_out)
+        return _backward_kernels(str(jax.make_jaxpr(f)(*args)), name), \
+            f(*args)
+
+    which, one = backward('one')
+    assert which == 'one'
+    _two_kernels(monkeypatch)
+    which, two = backward('two')
+    assert which == 'two'
+    for a, b, c in zip(one, want, two):
+        _close(a, b)
+        _close(a, c)
+
+
+# operands [1, T, .] bfloat16 at Laguna's widths (head 128, 8 key/value
+# heads): whether the one kernel runs is a function of the shapes alone
+RULE_CASES = {
+    'full_8192': (8192, 48, 0, 512, True),
+    'window_8192': (8192, 72, 512, 256, True),
+    'full_16384': (16384, 48, 0, 512, True),
+    'full_65536': (65536, 48, 0, 512, False),
+    'window_65536': (65536, 72, 512, 256, False),
+}
+
+
+@pytest.mark.parametrize('case', sorted(RULE_CASES))
+def test_the_shapes_decide_between_one_backward_kernel_and_two(case):
+    length, heads, window, block, fits = RULE_CASES[case]
+    spec = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.bfloat16)
+    wide, narrow = spec(1, length, heads * 128), spec(1, length, 8 * 128)
+    text = str(jax.make_jaxpr(lambda *a: pk.attention_backward(
+        *a, heads, 8, True, window, None, block, block, name='rule'))(
+        wide, narrow, narrow, wide,
+        jax.ShapeDtypeStruct((1, heads, length), jnp.float32), wide))
+    assert _backward_kernels(text, 'rule') == ('one' if fits else 'two')
+    assert (pk._bwd_vmem(length, (128, 128), jnp.bfloat16) is not None) \
+        == fits
 
 
 def test_seeded_weights_are_bfloat16_values_whatever_the_threads():
