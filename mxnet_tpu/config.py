@@ -371,12 +371,12 @@ flags.declare('MXTPU_XPROF_DIR', str, 'xprof_trace',
 flags.declare('MXTPU_ROOFLINE', bool, False,
               'Roofline attribution (mxnet_tpu/telemetry/roofline.py, '
               'requires MXTPU_TELEMETRY=1): parse every registered '
-              "program's HLO into per-layer FLOPs/bytes, join measured "
-              'per-fusion device timings from the MXTPU_XPROF capture '
-              'by jax.named_scope layer name, classify each layer '
-              'compute-/memory-/overhead-bound against the chip peak '
-              'table, and account collective bytes/time/overlap per '
-              'step. Off = no HLO text is ever rendered or parsed (one '
+              "program's HLO into per-layer FLOPs/bytes by "
+              'jax.named_scope layer name, distribute the measured step '
+              'time by roofline-minimum times (modeled), classify each '
+              'layer compute-/memory-/overhead-bound against the chip '
+              'peak table, and account collective bytes and modeled '
+              'time per step. Off = no HLO text is ever rendered or parsed (one '
               'cached-bool check at the program registrar)')
 flags.declare('MXTPU_MEMORY', bool, False,
               'HBM attribution & forecast plane '
@@ -398,13 +398,6 @@ flags.declare('MXTPU_MEMORY_OOM_STEPS', int, 200,
               'steps trips the alarm (healthz mem_pressure + the '
               'flight-mem-pressure dump). Forecasts above it only '
               'publish the mem.steps_to_oom gauge', min_value=1)
-flags.declare('MXTPU_ROOFLINE_TRACE', str, '',
-              'Path to a jax.profiler capture (directory, or a '
-              '*.trace.json[.gz] file) supplying the roofline\'s '
-              'measured per-layer timings. Empty = use MXTPU_XPROF_DIR '
-              'when a capture exists there, else distribute the '
-              'registry-measured step time across layers by their '
-              'roofline-minimum times (source: modeled)')
 flags.declare('MXTPU_PEAK_TFLOPS', float, 0.0,
               'Override the device peak dense bf16 TFLOP/s used by the '
               'roofline denominators (for chips '

@@ -109,8 +109,12 @@ class _GraphProgram:
         # per-node jax.named_scope names: device traces, HLO dumps and
         # profiler output attribute ops to the SYMBOL's layer names
         # instead of anonymous fusion.123 clusters
-        from .telemetry.programs import scope_name
+        from .telemetry.programs import note_nodes, scope_name
         self.scope_names = [scope_name(n.name) for n in self.topo]
+        # the scope map of a compiled program (telemetry/programs.py)
+        # tells a node's segment by this table, and reads its op there
+        note_nodes({s: n.op for s, n in zip(self.scope_names, self.topo)
+                    if not n.is_variable()})
 
     def make_runner(self):
         """Build run(arg_arrays, aux_arrays, key, is_train) ->

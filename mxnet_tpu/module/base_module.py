@@ -259,72 +259,76 @@ class BaseModule:
             self.init_optimizer(kvstore=kvstore, optimizer=optimizer,
                                 optimizer_params=optimizer_params)
 
-        if validation_metric is None:
-            validation_metric = eval_metric
-        if not isinstance(eval_metric, metric_mod.EvalMetric):
-            eval_metric = metric_mod.create(eval_metric)
+        # the rest of the set-up, up to the first draw: the metric, the
+        # checkpointer's restore, the fused loop with its optimizer states
+        # (float32 masters and momentum are made here), the planes' flags
+        with _tele.span('fit.prepare_loop', 'fit'):
+            if validation_metric is None:
+                validation_metric = eval_metric
+            if not isinstance(eval_metric, metric_mod.EvalMetric):
+                eval_metric = metric_mod.create(eval_metric)
 
-        # resilience tier (module/checkpointing.py): periodic async
-        # sharded checkpoints + restore-from-last-good, built from the
-        # MXTPU_CKPT_* flags. Restore happens HERE — before the fused
-        # window programs are built — so a resumed run binds the same
-        # programs a fresh one would. Flags off = None, nothing runs.
-        from .checkpointing import TrainCheckpointer
-        ckpt = TrainCheckpointer.for_fit(self, eval_metric,
-                                         logger=self.logger)
-        # fault-injection harness (mxnet_tpu/faults.py): one cached
-        # bool; every seam below is dead code while the flag is unset
-        faults_on = _faults.enabled()
+            # resilience tier (module/checkpointing.py): periodic async
+            # sharded checkpoints + restore-from-last-good, built from the
+            # MXTPU_CKPT_* flags. Restore happens HERE — before the fused
+            # window programs are built — so a resumed run binds the same
+            # programs a fresh one would. Flags off = None, nothing runs.
+            from .checkpointing import TrainCheckpointer
+            ckpt = TrainCheckpointer.for_fit(self, eval_metric,
+                                             logger=self.logger)
+            # fault-injection harness (mxnet_tpu/faults.py): one cached
+            # bool; every seam below is dead code while the flag is unset
+            faults_on = _faults.enabled()
 
-        # TPU fast path: compile a window of N steps into one XLA call
-        # (lax.scan) when the module/optimizer/metric combination allows
-        # it — same numerics, one dispatch per window instead of four
-        # per batch (see module/fused_fit.py). Falls back silently.
-        fused = None
-        if monitor is None:
-            from .fused_fit import FusedFitLoop
-            fused = FusedFitLoop.build_cached(self, eval_metric,
-                                              logger=self.logger)
-        if fused is None:
-            # flag honesty: an explicitly-requested MXTPU_SHARDED_UPDATE
-            # can only engage inside the fused SPMD window — the
-            # per-batch reference loop below updates replicated
-            from .fused_fit import (_shard_update_requested,
-                                    note_replicated_update)
-            if _shard_update_requested():
-                note_replicated_update(
-                    'the per-batch reference loop is running '
-                    '(no fused window built)', site='fit')
-        # training-health sentinels (telemetry/health): the per-batch
-        # loop feeds the step-time spike detector; the in-graph
-        # finite/norm sentinels ride the executor's fwd+bwd program.
-        # One cached-bool check — zero overhead while off. The cluster
-        # sync hook (telemetry/cluster.py) is gated the same way.
-        health_on = _tele.health.enabled()
-        # per-layer dynamics (telemetry/dynamics): executor-level rows
-        # take their step index from the same note_batch context the
-        # health incidents use, so the batch context is fed when EITHER
-        # plane is on
-        dyn_on = _tele.dynamics.enabled()
-        cluster_on = _tele.cluster.enabled()
-        # run ledger (telemetry/ledger): every fit() emits a fresh
-        # run_seq-tagged manifest — a second in-process fit (or a
-        # resilient_fit retry) may run under different flags, and
-        # run_compare keys on the latest; the per-step scalars
-        # (loss/lr/throughput/grad stats) bank at MXTPU_SCALARS_EVERY
-        ledger_on = _tele.ledger.enabled()
-        _tele.ledger.begin_run(module=self)
-        # hang watchdog (telemetry/watchdog.py): per-step progress marks
-        # feed the stall monitor; off = one cached-bool check here and
-        # no call in the loop
-        wd_on = _tele.watchdog.enabled()
-        # live-bytes timeline (telemetry/memory): one cached-bool check
-        # here, a host-side allocator sample at the scalars cadence
-        mem_on = _tele.memory.enabled()
-        # pod step timeline (telemetry/timeline): the per-step counter
-        # behind the phase ledger's per-step normalization — the phase
-        # durations themselves ride the spans this loop already emits
-        tl_on = _tele.timeline.enabled()
+            # TPU fast path: compile a window of N steps into one XLA call
+            # (lax.scan) when the module/optimizer/metric combination allows
+            # it — same numerics, one dispatch per window instead of four
+            # per batch (see module/fused_fit.py). Falls back silently.
+            fused = None
+            if monitor is None:
+                from .fused_fit import FusedFitLoop
+                fused = FusedFitLoop.build_cached(self, eval_metric,
+                                                  logger=self.logger)
+            if fused is None:
+                # flag honesty: an explicitly-requested MXTPU_SHARDED_UPDATE
+                # can only engage inside the fused SPMD window — the
+                # per-batch reference loop below updates replicated
+                from .fused_fit import (_shard_update_requested,
+                                        note_replicated_update)
+                if _shard_update_requested():
+                    note_replicated_update(
+                        'the per-batch reference loop is running '
+                        '(no fused window built)', site='fit')
+            # training-health sentinels (telemetry/health): the per-batch
+            # loop feeds the step-time spike detector; the in-graph
+            # finite/norm sentinels ride the executor's fwd+bwd program.
+            # One cached-bool check — zero overhead while off. The cluster
+            # sync hook (telemetry/cluster.py) is gated the same way.
+            health_on = _tele.health.enabled()
+            # per-layer dynamics (telemetry/dynamics): executor-level rows
+            # take their step index from the same note_batch context the
+            # health incidents use, so the batch context is fed when EITHER
+            # plane is on
+            dyn_on = _tele.dynamics.enabled()
+            cluster_on = _tele.cluster.enabled()
+            # run ledger (telemetry/ledger): every fit() emits a fresh
+            # run_seq-tagged manifest — a second in-process fit (or a
+            # resilient_fit retry) may run under different flags, and
+            # run_compare keys on the latest; the per-step scalars
+            # (loss/lr/throughput/grad stats) bank at MXTPU_SCALARS_EVERY
+            ledger_on = _tele.ledger.enabled()
+            _tele.ledger.begin_run(module=self)
+            # hang watchdog (telemetry/watchdog.py): per-step progress marks
+            # feed the stall monitor; off = one cached-bool check here and
+            # no call in the loop
+            wd_on = _tele.watchdog.enabled()
+            # live-bytes timeline (telemetry/memory): one cached-bool check
+            # here, a host-side allocator sample at the scalars cadence
+            mem_on = _tele.memory.enabled()
+            # pod step timeline (telemetry/timeline): the per-step counter
+            # behind the phase ledger's per-step normalization — the phase
+            # durations themselves ride the spans this loop already emits
+            tl_on = _tele.timeline.enabled()
 
         try:
             for epoch in range(begin_epoch, num_epoch):

@@ -916,7 +916,8 @@ class FusedFitLoop:
 
                 new_params = list(params)
                 new_states = list(states)
-                for j, n in enumerate(grad_names):
+
+                def update_leaf(j, n):
                     ci = grad_carry_idx[j]
                     attrs = dict(static_attrs)
                     attrs['lr'] = lr_row[j]   # traced: scheduler-safe
@@ -961,39 +962,50 @@ class FusedFitLoop:
                     new_params[ci] = res[0]
                     if len(res) > 1:
                         new_states[j] = tuple(res[1:])
+
+                # trace-time names for what no symbol node covers, so
+                # that the compiled program's scope map (telemetry/
+                # programs.py: WINDOW_PARTS) can tell a capture's device
+                # time apart: 'update', 'metric', 'sentinel', and
+                # 'window' around the scan for whatever is left
+                with jax.named_scope('update'):
+                    for j, n in enumerate(grad_names):
+                        update_leaf(j, n)
                 if stat_fns is not None:
                     # all metric stats packed into ONE vector per step
                     # so the host needs a single fetch per window (each
                     # fetch is a host round trip to the device)
-                    ys = jnp.stack([v for fn in stat_fns
-                                    for v in fn(outs, labels)])
+                    with jax.named_scope('metric'):
+                        ys = jnp.stack([v for fn in stat_fns
+                                        for v in fn(outs, labels)])
                 else:
                     # host-fallback metric: ship the raw outputs; scan
                     # stacks them into (W, ...) per output
                     ys = outs
                 extras = []
-                if health_fn is not None:
-                    # per-step sentinel vector rides the scan ys — the
-                    # (W, k) stack comes home in the window's existing
-                    # fetch, so a mid-window NaN keeps its step index
-                    extras.append(health_fn(
-                        outs, grads=grads,
-                        params=tuple(params[i] for i in grad_carry_idx),
-                        new_params=tuple(new_params[i]
-                                         for i in grad_carry_idx)))
-                if dyn_fn is not None:
-                    # per-layer dynamics vector rides the same ys — the
-                    # (W, 3n+outs) matrix ships in the SAME single
-                    # fetch (no added syncs; counter-asserted in tests)
-                    extras.append(dyn_fn(
-                        outs, grads=grads,
-                        params=tuple(params[i] for i in grad_carry_idx),
-                        new_params=tuple(new_params[i]
-                                         for i in grad_carry_idx)))
-                if moe_fn is not None:
-                    extras.append(moe_fn(new_aux))
-                if hyper_fn is not None:
-                    extras.append(hyper_fn(new_aux))
+                with jax.named_scope('sentinel'):
+                    if health_fn is not None:
+                        # per-step sentinel vector rides the scan ys — the
+                        # (W, k) stack comes home in the window's existing
+                        # fetch, so a mid-window NaN keeps its step index
+                        extras.append(health_fn(
+                            outs, grads=grads,
+                            params=tuple(params[i] for i in grad_carry_idx),
+                            new_params=tuple(new_params[i]
+                                             for i in grad_carry_idx)))
+                    if dyn_fn is not None:
+                        # per-layer dynamics vector rides the same ys — the
+                        # (W, 3n+outs) matrix ships in the SAME single
+                        # fetch (no added syncs; counter-asserted in tests)
+                        extras.append(dyn_fn(
+                            outs, grads=grads,
+                            params=tuple(params[i] for i in grad_carry_idx),
+                            new_params=tuple(new_params[i]
+                                             for i in grad_carry_idx)))
+                    if moe_fn is not None:
+                        extras.append(moe_fn(new_aux))
+                    if hyper_fn is not None:
+                        extras.append(hyper_fn(new_aux))
                 if extras:
                     ys = (ys, *extras)
                 if compress:
@@ -1020,18 +1032,21 @@ class FusedFitLoop:
             # the ZeRO layout for the loop to hold between windows
             def window_fn(params, states, aux, gaccs, resids, data_stack,
                           label_stack, key, lr_arr, wd_arr):
-                step_idx, lr_xs, wd_xs = make_xs(lr_arr, wd_arr)
-                (p, s, a, g, r), ys = jax.lax.scan(
-                    make_body(key), (params, states, aux, gaccs, resids),
-                    (step_idx, data_stack, label_stack, lr_xs, wd_xs))
+                with jax.named_scope('window'):
+                    step_idx, lr_xs, wd_xs = make_xs(lr_arr, wd_arr)
+                    (p, s, a, g, r), ys = jax.lax.scan(
+                        make_body(key),
+                        (params, states, aux, gaccs, resids),
+                        (step_idx, data_stack, label_stack, lr_xs, wd_xs))
                 return p, s, a, g, r, ys
         else:
             def window_fn(params, states, aux, gaccs, data_stack,
                           label_stack, key, lr_arr, wd_arr):
-                step_idx, lr_xs, wd_xs = make_xs(lr_arr, wd_arr)
-                (p, s, a, g), ys = jax.lax.scan(
-                    make_body(key), (params, states, aux, gaccs),
-                    (step_idx, data_stack, label_stack, lr_xs, wd_xs))
+                with jax.named_scope('window'):
+                    step_idx, lr_xs, wd_xs = make_xs(lr_arr, wd_arr)
+                    (p, s, a, g), ys = jax.lax.scan(
+                        make_body(key), (params, states, aux, gaccs),
+                        (step_idx, data_stack, label_stack, lr_xs, wd_xs))
                 return p, s, a, g, ys
 
         # the train-step program of the fused path: its XLA cost

@@ -909,7 +909,9 @@ def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
     Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
     scale = D ** -0.5 if scale is None else scale
     blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
-    q, k, v = _pad_rows(q, pad_q), _pad_rows(k, pad_k), _pad_rows(v, pad_k)
+    with jax.named_scope('heads'):      # as in latent_attention_forward
+        q, k, v = (_pad_rows(q, pad_q), _pad_rows(k, pad_k),
+                   _pad_rows(v, pad_k))
     nq, _, keys_of, _, steps, _ = _attn_walk(
         Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
     geo = (blk_q, blk_k, Tk, Tk - Tq, causal, window, keys_of, steps)
@@ -935,7 +937,8 @@ def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
                         _vmem((blk_q, D))],
         compiler_params=_attn_params(3),
         interpret=interpret, name=name + '_fwd'), q, k, v)
-    return out[:, :Tq], lse[:, :, :Tq, 0]
+    with jax.named_scope('heads'):
+        return out[:, :Tq], lse[:, :, :Tq, 0]
 
 
 def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
@@ -951,12 +954,15 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
     scale = D ** -0.5 if scale is None else scale
     blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
-    delta = jnp.sum((g_out.astype(jnp.float32) * out.astype(jnp.float32))
-                    .reshape(B, Tq, heads, D), axis=-1).transpose(0, 2, 1)
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32)
-    q, g_out = _pad_rows(q, pad_q), _pad_rows(g_out, pad_q)
-    k, v = _pad_rows(k, pad_k), _pad_rows(v, pad_k)
+    with jax.named_scope('delta'):
+        delta = jnp.sum(
+            (g_out.astype(jnp.float32) * out.astype(jnp.float32))
+            .reshape(B, Tq, heads, D), axis=-1).transpose(0, 2, 1)
+        if g_lse is not None:
+            delta = delta - g_lse.astype(jnp.float32)
+    with jax.named_scope('heads'):
+        q, g_out = _pad_rows(q, pad_q), _pad_rows(g_out, pad_q)
+        k, v = _pad_rows(k, pad_k), _pad_rows(v, pad_k)
     nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
         Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
     base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
@@ -987,7 +993,8 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
             interpret=interpret, name=name + '_bwd'),
             q, k, v, g_out, _rows(lse, pad_q, blk_q),
             _rows(delta, pad_q, blk_q))
-        return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
+        with jax.named_scope('heads'):
+            return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
     lse, delta = _cols(lse, pad_q), _cols(delta, pad_q)
 
     def kv_index(b, h, i, s):
@@ -1036,7 +1043,8 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         compiler_params=_attn_params(3),
         interpret=interpret, name=name + '_dkv'),
         q, k, v, g_out, lse, delta)
-    return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
+    with jax.named_scope('heads'):
+        return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
@@ -1411,6 +1419,15 @@ def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
     geo = (blk_q, blk_k, T, 0, True, 0, keys_of, steps)
     q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
                                     _walked(keys_of))
+    # 'heads', 'delta': trace-time names for the work around a kernel
+    # (pads, per-head layouts, the softmax's own term), for the compiled
+    # program's scope map (telemetry/programs.py); the call itself stands
+    # under the kernel's name
+    with jax.named_scope('heads'):
+        operands = (_pad_rows(q_nope, pad_q),
+                    _by_head(_pad_rows(q_rope, pad_q), heads),
+                    _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
+                    _pad_rows(v, pad_k))
     out, lse = run_kernel(lambda interpret: pl.pallas_call(
         functools.partial(_latent_fwd_kernel, geo=geo, scale=scale),
         grid=(B, heads, nq, steps),
@@ -1422,11 +1439,9 @@ def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
         scratch_shapes=[_vmem((blk_q, 1)), _vmem((blk_q, 1)),
                         _vmem((blk_q, Dv))],
         compiler_params=_attn_params(3),
-        interpret=interpret, name=name + '_fwd'),
-        _pad_rows(q_nope, pad_q), _by_head(_pad_rows(q_rope, pad_q), heads),
-        _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
-        _pad_rows(v, pad_k))
-    return out[:, :T], lse[:, :, :T, 0]
+        interpret=interpret, name=name + '_fwd'), *operands)
+    with jax.named_scope('heads'):
+        return out[:, :T], lse[:, :, :T, 0]
 
 
 def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
@@ -1445,15 +1460,18 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
     blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
     Tq, Tk = T + pad_q, T + pad_k
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
-    delta = jnp.sum((g_out.astype(jnp.float32) * out.astype(jnp.float32))
-                    .reshape(B, T, heads, Dv), axis=-1).transpose(0, 2, 1)
+    with jax.named_scope('delta'):
+        delta = jnp.sum(
+            (g_out.astype(jnp.float32) * out.astype(jnp.float32))
+            .reshape(B, T, heads, Dv), axis=-1).transpose(0, 2, 1)
     nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
         T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
     base = (blk_q, blk_k, T, 0, True, 0)
-    operands = (_pad_rows(q_nope, pad_q),
-                _by_head(_pad_rows(q_rope, pad_q), heads),
-                _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
-                _pad_rows(v, pad_k), _pad_rows(g_out, pad_q))
+    with jax.named_scope('heads'):
+        operands = (_pad_rows(q_nope, pad_q),
+                    _by_head(_pad_rows(q_rope, pad_q), heads),
+                    _pad_rows(k_nope, pad_k), _pad_rows(k_rope, pad_k),
+                    _pad_rows(v, pad_k), _pad_rows(g_out, pad_q))
     dk_shapes = [jax.ShapeDtypeStruct((B, Tk, heads * Dn), k_nope.dtype),
                  jax.ShapeDtypeStruct((B, heads, Tk, Dr), jnp.float32),
                  jax.ShapeDtypeStruct((B, Tk, heads * Dv), v.dtype)]
@@ -1504,9 +1522,10 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
             scratch_shapes=[_vmem((blk_q, Dn)), _vmem((blk_q, Dr))],
             compiler_params=_attn_params(3),
             interpret=interpret, name=name + '_dq'), *operands)
-    dqr = dqr.transpose(0, 2, 1, 3).reshape(B, Tq, heads * Dr)
-    dkr = jnp.sum(dkr, axis=1).astype(k_rope.dtype)
-    return dqn[:, :T], dqr[:, :T], dkn[:, :T], dkr[:, :T], dv[:, :T]
+    with jax.named_scope('heads'):
+        dqr = dqr.transpose(0, 2, 1, 3).reshape(B, Tq, heads * Dr)
+        dkr = jnp.sum(dkr, axis=1).astype(k_rope.dtype)
+        return dqn[:, :T], dqr[:, :T], dkn[:, :T], dkr[:, :T], dv[:, :T]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))

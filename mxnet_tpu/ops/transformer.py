@@ -185,9 +185,10 @@ def _gqa(attrs, q, k, v, gate=None):
     out = pk.dispatch(fused, plain, q, k, v)
     if gate is not None and attrs.get('gated', False):
         B, T, HD = out.shape
-        g = jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
-        out = (out.reshape(B, T, H, HD // H).astype(jnp.float32) * g) \
-            .astype(out.dtype).reshape(B, T, HD)
+        with jax.named_scope('gate'):
+            g = jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+            out = (out.reshape(B, T, H, HD // H).astype(jnp.float32) * g) \
+                .astype(out.dtype).reshape(B, T, HD)
     return out
 
 
@@ -316,16 +317,21 @@ def _hyper_coefficients(c, n, iters, eps, lo, hi):
     """(coef [R, HYPER_COLS]: H_post in columns [0, n), M_T row-major in
     [n, n + n^2), zeros after; the largest distance of M_T's row and
     column sums from 1) from the arguments c [R, HYPER_COLS]."""
-    ct = c.T                                    # a token a lane
-    h_post = 2.0 * jax.nn.sigmoid(ct[n:2 * n])
-    m = sinkhorn(jnp.exp(jnp.clip(ct[2 * n:2 * n + n * n], lo, hi))
-                 .reshape(n, n, -1), iters, eps)
-    dev = jnp.maximum(jnp.max(jnp.abs(jnp.sum(m, axis=0) - 1.0)),
-                      jnp.max(jnp.abs(jnp.sum(m, axis=1) - 1.0)))
-    coef = jnp.concatenate(
-        [h_post, m.reshape(n * n, -1),
-         jnp.zeros((pk.HYPER_COLS - n - n * n, c.shape[0]), jnp.float32)])
-    return coef.T, jax.lax.stop_gradient(dev)
+    with jax.named_scope('coef'):
+        ct = c.T                                    # a token a lane
+        h_post = 2.0 * jax.nn.sigmoid(ct[n:2 * n])
+        m0 = jnp.exp(jnp.clip(ct[2 * n:2 * n + n * n], lo, hi)) \
+            .reshape(n, n, -1)
+    with jax.named_scope('sinkhorn'):
+        m = sinkhorn(m0, iters, eps)
+    with jax.named_scope('coef'):
+        dev = jnp.maximum(jnp.max(jnp.abs(jnp.sum(m, axis=0) - 1.0)),
+                          jnp.max(jnp.abs(jnp.sum(m, axis=1) - 1.0)))
+        coef = jnp.concatenate(
+            [h_post, m.reshape(n * n, -1),
+             jnp.zeros((pk.HYPER_COLS - n - n * n, c.shape[0]),
+                       jnp.float32)])
+        return coef.T, jax.lax.stop_gradient(dev)
 
 
 @register('HyperPre',
@@ -562,12 +568,14 @@ def _pass_forward(rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
     tiles = jnp.clip(n_tiles - p * tiles_pass, 0, tiles_pass)
     # a negative index would wrap before it fills
     at = jnp.where((dest >= start) & (dest < start + rp), dest - start, rp)
-    xs = _rows(x, rows // k)
-    h1 = _gmm(xs, w1, groups, tiles)
-    h3 = _gmm(xs, w3, groups, tiles)
-    act = (jax.nn.silu(h1.astype(jnp.float32))
-           * h3.astype(jnp.float32)).astype(x.dtype)
-    ys = _gmm(act, w2, groups, tiles)
+    with jax.named_scope('gather'):
+        xs = _rows(x, rows // k)
+    with jax.named_scope('experts'):
+        h1 = _gmm(xs, w1, groups, tiles)
+        h3 = _gmm(xs, w3, groups, tiles)
+        act = (jax.nn.silu(h1.astype(jnp.float32))
+               * h3.astype(jnp.float32)).astype(x.dtype)
+        ys = _gmm(act, w2, groups, tiles)
     return (rows, groups, tiles, at), (xs, h1, h3, act, ys)
 
 
@@ -578,9 +586,10 @@ def _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
     def one(p, out):
         (_, _, _, at), (_, _, _, _, ys) = _pass_forward(
             rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
-        for j in range(k):      # a token's pairs, one gather each
-            out += w_pairs[:, j:j + 1] * _rows(ys, at[:, j]).astype(
-                jnp.float32)
+        with jax.named_scope('combine'):
+            for j in range(k):      # a token's pairs, one gather each
+                out += w_pairs[:, j:j + 1] * _rows(ys, at[:, j]).astype(
+                    jnp.float32)
         return out
 
     out = jax.lax.fori_loop(0, _num_passes(rp, n_tiles), one,
@@ -620,31 +629,39 @@ def _experts_bwd(rp, res, g):
         dx, d_pairs, dw1, dw3, dw2 = carry
         (rows, groups, tiles, at), (xs, h1, h3, act, ys) = _pass_forward(
             rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
-        g32 = g.astype(jnp.float32)
-        d_pairs += jnp.stack(
-            [jnp.sum(_rows(ys, at[:, j]).astype(jnp.float32) * g32, axis=-1)
-             for j in range(k)], axis=1)
-        dys = (_rows(w_flat, rows)[:, None]
-               * _rows(g, rows // k).astype(jnp.float32)).astype(g.dtype)
-        dact = _gmm(dys, w2, groups, tiles, transpose_w=True).astype(
-            jnp.float32)
-        h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
-        sig = jax.nn.sigmoid(h1f)
-        dh1 = (dact * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(g.dtype)
-        dh3 = (dact * h1f * sig).astype(g.dtype)
-        dxs = _gmm(dh1, w1, groups, tiles, transpose_w=True) \
-            .astype(jnp.float32) \
-            + _gmm(dh3, w3, groups, tiles, transpose_w=True) \
-            .astype(jnp.float32)
-        for j in range(k):
-            dx += _rows(dxs, at[:, j])
+        with jax.named_scope('combine'):
+            g32 = g.astype(jnp.float32)
+            d_pairs += jnp.stack(
+                [jnp.sum(_rows(ys, at[:, j]).astype(jnp.float32) * g32,
+                         axis=-1) for j in range(k)], axis=1)
+            dys = (_rows(w_flat, rows)[:, None]
+                   * _rows(g, rows // k).astype(jnp.float32)).astype(g.dtype)
+        with jax.named_scope('experts'):
+            dact = _gmm(dys, w2, groups, tiles, transpose_w=True).astype(
+                jnp.float32)
+            h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
+            sig = jax.nn.sigmoid(h1f)
+            dh1 = (dact * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(
+                g.dtype)
+            dh3 = (dact * h1f * sig).astype(g.dtype)
+            dxs = _gmm(dh1, w1, groups, tiles, transpose_w=True) \
+                .astype(jnp.float32) \
+                + _gmm(dh3, w3, groups, tiles, transpose_w=True) \
+                .astype(jnp.float32)
+        with jax.named_scope('gather'):
+            for j in range(k):
+                dx += _rows(dxs, at[:, j])
         # the weight products leave a group without a tile here unwritten
-        present = jnp.arange(groups.shape[0]) < tiles[0]
-        named = jnp.any((groups[:, None] == jnp.arange(held)[None])
-                        & present[:, None], axis=0)[:, None, None]
-        dw1 += jnp.where(named, _gmm_dw(xs, dh1, groups, tiles, held), 0.0)
-        dw3 += jnp.where(named, _gmm_dw(xs, dh3, groups, tiles, held), 0.0)
-        dw2 += jnp.where(named, _gmm_dw(act, dys, groups, tiles, held), 0.0)
+        with jax.named_scope('dw_sum'):
+            present = jnp.arange(groups.shape[0]) < tiles[0]
+            named = jnp.any((groups[:, None] == jnp.arange(held)[None])
+                            & present[:, None], axis=0)[:, None, None]
+            dw1 += jnp.where(named,
+                             _gmm_dw(xs, dh1, groups, tiles, held), 0.0)
+            dw3 += jnp.where(named,
+                             _gmm_dw(xs, dh3, groups, tiles, held), 0.0)
+            dw2 += jnp.where(named,
+                             _gmm_dw(act, dys, groups, tiles, held), 0.0)
         return dx, d_pairs, dw1, dw3, dw2
 
     dx, d_pairs, dw1, dw3, dw2 = jax.lax.fori_loop(
@@ -659,6 +676,25 @@ def _experts_bwd(rp, res, g):
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def _route(attrs, x2, router, select_bias, k):
+    """(w_pairs [T, k] float32, idx [T, k]): each token's k experts and
+    their weights, as :func:`_moe` describes."""
+    if _sigmoid_scoring(attrs):
+        scores = jax.nn.sigmoid(_matmul(x2, router))
+        bias = jax.lax.stop_gradient(select_bias).astype(jnp.float32)
+        _, idx = jax.lax.top_k(scores + bias.reshape(1, -1), k)
+        w_pairs = jnp.take_along_axis(scores, idx, axis=-1)
+        if attrs.get('norm_topk_prob', True):
+            w_pairs = w_pairs / (jnp.sum(w_pairs, axis=-1, keepdims=True)
+                                 + 1e-20)
+    else:
+        probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
+        w_pairs, idx = jax.lax.top_k(probs, k)
+        if attrs.get('norm_topk_prob', True):
+            w_pairs = w_pairs / jnp.sum(w_pairs, axis=-1, keepdims=True)
+    return w_pairs * float(attrs.get('routed_scaling', 1.0)), idx
 
 
 @register('MoE',
@@ -706,26 +742,20 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
     k = int(attrs['num_experts_per_tok'])
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
-    if _sigmoid_scoring(attrs):
-        scores = jax.nn.sigmoid(_matmul(x2, router))
-        bias = jax.lax.stop_gradient(select_bias).astype(jnp.float32)
-        _, idx = jax.lax.top_k(scores + bias.reshape(1, -1), k)
-        w_pairs = jnp.take_along_axis(scores, idx, axis=-1)
-        if attrs.get('norm_topk_prob', True):
-            w_pairs = w_pairs / (jnp.sum(w_pairs, axis=-1, keepdims=True)
-                                 + 1e-20)
-    else:
-        probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
-        w_pairs, idx = jax.lax.top_k(probs, k)
-        if attrs.get('norm_topk_prob', True):
-            w_pairs = w_pairs / jnp.sum(w_pairs, axis=-1, keepdims=True)
-    w_pairs = w_pairs * float(attrs.get('routed_scaling', 1.0))
-    dest, row_pair, tile_group, n_tiles, counts = _dispatch_plan(
-        idx, held, offset)
-    rp = _pass_rows(row_pair.shape[0], x2.shape[0], k, held, router.shape[0])
-    out = _experts(rp, x2, w_pairs, w1, w3, w2,
-                   *_whole_passes(rp, dest, row_pair, tile_group), n_tiles)
-    out = out + _gated_mlp(x2, s1, s3, s2)
+    # trace-time names below the node's own, for the compiled program's
+    # scope map (telemetry/programs.py): router, plan, then per pass
+    # gather, experts, combine and dw_sum, and shared
+    with jax.named_scope('router'):
+        w_pairs, idx = _route(attrs, x2, router, select_bias, k)
+    with jax.named_scope('plan'):
+        dest, row_pair, tile_group, n_tiles, counts = _dispatch_plan(
+            idx, held, offset)
+        rp = _pass_rows(row_pair.shape[0], x2.shape[0], k, held,
+                        router.shape[0])
+        plan = _whole_passes(rp, dest, row_pair, tile_group)
+    out = _experts(rp, x2, w_pairs, w1, w3, w2, *plan, n_tiles)
+    with jax.named_scope('shared'):
+        out = out + _gated_mlp(x2, s1, s3, s2)
     pairs = jnp.sum(counts).astype(jnp.float32)
     placed = jnp.sum(row_pair < dest.size).astype(jnp.float32)
     load_max = jnp.max(counts).astype(jnp.float32)

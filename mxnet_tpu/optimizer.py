@@ -13,6 +13,7 @@ import math
 import pickle
 import logging
 
+import jax
 import numpy as np
 
 from . import ndarray as nd
@@ -516,8 +517,11 @@ class Updater:
             self.states[index] = self.optimizer.create_state_multi_precision(
                 index, weight)
             self.states_synced[index] = True
-        self.optimizer.update_multi_precision(index, weight, grad,
-                                              self.states[index])
+        # as the fused window names its update: the scope a compiled
+        # program's map (telemetry/programs.py) groups the step under
+        with jax.named_scope('update'):
+            self.optimizer.update_multi_precision(index, weight, grad,
+                                                  self.states[index])
 
     def set_states(self, states):
         states = pickle.loads(states)

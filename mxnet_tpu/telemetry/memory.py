@@ -152,6 +152,7 @@ def hlo_layer_buffers(hlo_text):
     parse inflation (a while carry counted at both the instruction and
     its body) cannot move the totals — only the relative shares."""
     from . import roofline as _r
+    from .programs import _layer_from_op_name
     no_buffer = _no_buffer_ops()
     layers = {}
     args_total = temp_total = out_total = 0.0
@@ -179,7 +180,7 @@ def hlo_layer_buffers(hlo_text):
         if out_bytes <= 0 or (opcode in no_buffer and not is_root):
             continue
         mo = _r._OP_NAME_RE.search(line)
-        layer = (_r._layer_from_op_name(mo.group(1)) if mo else None) \
+        layer = (_layer_from_op_name(mo.group(1)) if mo else None) \
             or '_unattributed'
         rec = layers.setdefault(layer, {'args': 0.0, 'temp': 0.0,
                                         'out': 0.0})

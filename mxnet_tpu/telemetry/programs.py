@@ -25,17 +25,33 @@ Julia->TPU arXiv:1810.09868):
   wrapper then dispatches through the AOT executable (ONE compile
   total, same numerics). With telemetry off it returns the jitted
   callable unchanged — the zero-overhead no-op contract;
+- :func:`scope_map` — the one walk of a compiled program's HLO text:
+  every instruction that can be a device event (entry, loop-body and
+  branch computations; not the insides of fusions) keyed by its name
+  to the symbol node or window part whose ``jax.named_scope`` its
+  ``op_name`` carries, the pass (``fwd``/``bwd``/``refwd``), the inner
+  scope an op planted below the node, and for a fusion how many nodes
+  its fused instructions name. With telemetry on it is written once a
+  compile to a file beside the telemetry log, named by the ``program``
+  record's ``scopes`` key; a reader joins a profiler capture's events
+  to it by instruction name (``benchmark/reduce/scopes.py``). The
+  ``op_name`` parser (:func:`_layer_from_op_name`) lives here and
+  :mod:`.roofline` and :mod:`.memory` import it;
 - :func:`maybe_oom_report` — on a ``RESOURCE_EXHAUSTED`` error, dump
   the per-program memory breakdown alongside ``memory_stats()`` so an
   OOM stops being a one-line crash: the report says which programs
   were resident and what XLA planned to allocate for each.
 """
+import json
 import logging
+import os
+import re
 import threading
 import time
 
 __all__ = ['analyze_compiled', 'note_program', 'register',
-           'snapshot_programs', 'maybe_oom_report']
+           'snapshot_programs', 'maybe_oom_report', 'note_nodes',
+           'scope_of', 'scope_map', 'WINDOW_PARTS']
 
 _lock = threading.Lock()
 _programs = {}          # name -> record dict (see note_program)
@@ -142,6 +158,7 @@ def note_program(name, compiled=None, analysis=None, step_flops=False,
             rec[f] = max(rec[f], analysis.get(f, 0))
         merged = {f: rec[f] for f in _ANALYSIS_FIELDS}
         rec['compiles'] += 1
+        compiles = rec['compiles']
     reg = st.registry
     reg.counter('program.compiles').inc()
     # gauges mirror the MERGED record so the two views never disagree
@@ -171,6 +188,12 @@ def note_program(name, compiled=None, analysis=None, step_flops=False,
         out.update({f: analysis.get(f, 0) for f in _ANALYSIS_FIELDS})
         if compile_s is not None:
             out['compile_s'] = round(float(compile_s), 3)
+        if compiled is not None:
+            # the instruction-to-scope map, once a compile, in a file of
+            # its own: megabytes that the flight recorder's ring (every
+            # emitted record passes it) is not to hold
+            out.update(_write_scope_map(name, compiled, compiles,
+                                        st.sink.path))
         st.sink.emit(out)
     return analysis
 
@@ -241,9 +264,15 @@ class _RegisteredProgram:
         return tuple(sig)
 
     def _compile(self, args, key):
+        from . import span
         t0 = time.time()
         try:
-            compiled = self.jitted.lower(*args).compile()
+            # the two halves of a first call, told apart in the log:
+            # tracing and lowering (the program's own Python), then XLA
+            with span('program.lower', 'program', program=self.name):
+                lowered = self.jitted.lower(*args)
+            with span('program.compile', 'program', program=self.name):
+                compiled = lowered.compile()
         except (TypeError, ValueError) as e:
             # what the ahead-of-time path itself cannot take (an
             # argument layout): the lazy jit handles it. An error of the
@@ -312,8 +341,361 @@ def register(name, jitted, static_argnums=(), step_flops=False):
 def scope_name(name):
     """Sanitize a symbol/layer name for ``jax.named_scope`` / HLO
     metadata (scopes join with '/', so strip everything exotic)."""
-    import re
     return re.sub(r'[^A-Za-z0-9_.\-]', '_', str(name)) or '_'
+
+
+# -- op_name paths: which node, which pass ----------------------------------
+#
+# Every HLO instruction carries ``metadata={op_name="..."}``: the jax name
+# stack at the point the primitive was traced, e.g.
+# ``jit(window_fn)/jit(main)/window/while/body/closed_call/
+# transpose(jvp(fc1))/dot_general``. The executor runs each symbol node
+# under ``jax.named_scope(<node name>)`` and the fused window plants the
+# WINDOW_PARTS where no node is; XLA keeps the path of a fusion's root.
+
+# scope segments that are tracing machinery, not layer names. jit()
+# segments are FUNCTION boundaries (jit(main), jit(relu)) — dropped
+# whole; AD/transform wrappers carry the layer name INSIDE
+# (jvp(fc1), transpose(jvp(fc1))) — peeled until the bare name appears
+_JIT_RE = re.compile(r'^(jit|pjit)\(')
+_XFORM_RE = re.compile(
+    r'^(jvp|vjp|transpose|vmap|pmap|xmap|shard_map|remat|'
+    r'checkpoint|custom_jvp|custom_vjp|named)\((.*)\)$')
+_WRAP_WORDS = frozenset(('while', 'body', 'cond', 'branch', 'scan',
+                         'closed_call', 'core_call', 'checkpoint',
+                         'rematted_computation', 'custom_vjp_call',
+                         'custom_jvp_call', 'custom_lin'))
+_BRANCH_RE = re.compile(r'^branch_\d+_fun$')   # lax.cond's, by index
+
+# what the fused window plants where no symbol node is: the optimizer's
+# update, the in-window metric plan, the sentinels' stacking, and the
+# window's own slicing, learning-rate read and carries
+WINDOW_PARTS = ('update', 'metric', 'sentinel', 'window')
+
+# a forward instruction under this segment is computed a second time, in
+# the backward pass of a mirrored stage or a ``jax.checkpoint`` (jax
+# 0.9: ``.../transpose(jvp(..))/checkpoint/rematted_computation/<node>/
+# <primitive>``; held by tests/unittest/test_scope_map.py)
+_REMAT_SEG = 'rematted_computation'
+
+
+def _unwrap_seg(seg):
+    """One scope segment -> the layer name it carries, or None.
+    ``transpose(jvp(fc1))`` -> ``fc1``; ``jit(relu)`` -> None (a
+    function boundary, not a layer); ``while``/``body`` -> None."""
+    while True:
+        if _JIT_RE.match(seg):
+            return None
+        m = _XFORM_RE.match(seg)
+        if not m:
+            break
+        seg = m.group(2)
+    if not seg or seg in _WRAP_WORDS or _BRANCH_RE.match(seg):
+        return None
+    return seg
+
+
+def _layer_from_op_name(op_name):
+    """The ``jax.named_scope`` layer in an HLO ``op_name`` path, or
+    None. ``jit(f)/jit(main)/fc1/dot_general`` -> ``fc1`` and
+    ``jit(f)/while/body/transpose(jvp(fc1))/dot_general`` -> ``fc1``:
+    function/loop wrappers are dropped, transform wrappers are peeled,
+    the last segment is the primitive, the first before it that is no
+    window part is the layer the framework planted (:func:`scope_of`
+    with no table of nodes)."""
+    sc = scope_of(op_name)
+    return sc[0] if sc and sc[1] != '-' else None
+
+
+_node_ops = {}      # scope name of a symbol node -> its registry op
+
+
+def note_nodes(table):
+    """``{node's scope name: registry op}`` of a symbol the executor is
+    about to run: how :func:`scope_map` tells a node's segment from any
+    other and names its op. Nothing is kept while telemetry is off."""
+    from . import enabled
+    if enabled():
+        with _lock:
+            _node_ops.update(table)
+
+
+def scope_of(op_name, nodes=None):
+    """``(node, phase, inner)`` of one ``op_name`` path, or None where it
+    carries no scope the framework planted.
+
+    ``node`` is the first segment that names a symbol node (a key of
+    ``nodes``; with no table, the first named segment), else the innermost
+    of the WINDOW_PARTS. ``phase``: ``refwd`` where the node lies under
+    ``rematted_computation`` (a mirrored stage's or a ``jax.checkpoint``'s
+    second forward, with the linearisation jax traces there), ``bwd``
+    where a segment up to the node is a ``transpose(..)``, else ``fwd``;
+    ``-`` for a window part. ``inner`` is the named segment below the
+    node, where an op planted one or a kernel's name stands (XLA joins
+    the paths of instructions it merged with ``;``: the first is read)."""
+    raw = str(op_name).split(';')[0].split('/')[:-1]    # less the primitive
+    named = []                              # (name, bwd so far, remat so far)
+    bwd = remat = False
+    for seg in raw:
+        bwd = bwd or 'transpose(' in seg
+        remat = remat or seg == _REMAT_SEG
+        u = _unwrap_seg(seg)
+        if u is not None:
+            named.append((u, bwd, remat))
+    part = None
+    for i, (u, b, r) in enumerate(named):
+        if u in WINDOW_PARTS:
+            part = u
+        elif nodes is None or u in nodes:
+            inner = named[i + 1][0] if i + 1 < len(named) else None
+            return u, 'refwd' if r else 'bwd' if b else 'fwd', inner
+    return (part, '-', None) if part else None
+
+
+# opcodes that never run as a device operation of their own
+_NO_EVENT = frozenset(('parameter', 'constant', 'tuple',
+                       'get-tuple-element', 'bitcast', 'after-all',
+                       'partition-id', 'replica-id'))
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLED_RE = re.compile(
+    r'\b(calls|body|condition|to_apply|true_computation|'
+    r'false_computation)=%?([\w.\-]+)')
+_BRANCHES_RE = re.compile(r'branch_computations=\{([^}]*)\}')
+_OPERAND_RE = re.compile(r'%([^\s,()]+)')
+# instructions whose called computations hold device operations of their
+# own (a fusion's, a reduce's or a sort's are part of the instruction)
+_HOLDS_EVENTS = frozenset(('while', 'conditional', 'call', 'async-start'))
+
+
+def _closing(text):
+    """Index of the parenthesis that closes the one `text` opens with."""
+    depth = 0
+    for i, ch in enumerate(text):
+        if ch == '(':
+            depth += 1
+        elif ch == ')':
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(text)
+
+
+def _split_instruction(line):
+    """(name, opcode, the text after the opcode) of one HLO instruction
+    line, or None."""
+    s = line.strip()
+    if s.startswith('ROOT '):
+        s = s[5:]
+    eq = s.find(' = ')
+    if eq <= 0 or ' ' in s[:eq]:
+        return None
+    rest = s[eq + 3:]
+    if rest.startswith('('):        # a tuple's type
+        rest = rest[_closing(rest) + 1:].lstrip()
+    else:
+        rest = rest[rest.find(' ') + 1:]
+    par = rest.find('(')
+    if par <= 0:
+        return None
+    return s[:eq].lstrip('%'), rest[:par], rest[par:]
+
+
+def _computations(hlo_text):
+    """({computation: [(name, opcode, rest)]}, the entry's name)."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            if line.endswith('{') and not line.startswith(' ') \
+                    and ('(' in line):
+                head = line.split('(', 1)[0].split()
+                if head:
+                    name = head[-1].lstrip('%')
+                    cur = comps.setdefault(name, [])
+                    if head[0] == 'ENTRY':
+                        entry = name
+            continue
+        if line.startswith('}'):
+            cur = None
+            continue
+        ins = _split_instruction(line)
+        if ins is not None:
+            cur.append(ins)
+    return comps, entry
+
+
+def _called(rest):
+    names = [m.group(2) for m in _CALLED_RE.finditer(rest)]
+    m = _BRANCHES_RE.search(rest)
+    if m:
+        names += [n.strip().lstrip('%') for n in m.group(1).split(',')
+                  if n.strip()]
+    return names
+
+
+def _operands(rest):
+    """Names of the instructions that `rest` (an instruction's text from
+    its opening parenthesis) takes as operands."""
+    return _OPERAND_RE.findall(rest[:_closing(rest)])
+
+
+def _lend(instrs, order, users, operands):
+    """Give each instruction of `order` that has no scope the scope of the
+    nearest instruction that uses its result, else of the nearest that
+    made an operand of it, through instructions that have none either: a
+    copy or a prefetch that XLA put in (it gives those no metadata)
+    belongs to what it feeds. Marked ``user`` / ``operand`` in ``via``."""
+    for name in order:
+        if instrs[name][0] is not None:
+            continue
+        for via, edges in (('user', users), ('operand', operands)):
+            seen, todo, found = {name}, [name], None
+            while todo and found is None:
+                nxt = []
+                for n in todo:
+                    for o in edges.get(n, ()):
+                        if o in seen or o not in instrs:
+                            continue
+                        seen.add(o)
+                        if instrs[o][0] is not None:
+                            found = found or o
+                        else:
+                            nxt.append(o)
+                todo = nxt
+            if found is not None:
+                instrs[name][:3] = instrs[found][:3]
+                instrs[name][5] = via
+                break
+
+
+def scope_map(hlo_text, nodes=None):
+    """The walk of a compiled program's HLO text.
+
+    Returns ``{'instrs': {instruction name: [node, phase, inner, opcode,
+    fused nodes, via]}, 'nodes': {node: op}, 'named': n, 'unscoped': n}``.
+    ``instrs`` holds every instruction of the entry computation and of
+    the computations that ``while``, ``conditional`` and ``call``
+    instructions run (a device event is named by one of these), with
+    :func:`scope_of` of its path (``node`` None where nothing names it;
+    for a window part ``node`` is the part and ``phase`` ``-``). For a
+    fusion, ``fused nodes`` counts the distinct nodes and parts its fused
+    instructions name: 1 is clean, more is a fusion across nodes, which is
+    charged to the node of its root (whose path XLA gives it). ``via``
+    says where the scope is from when not from the instruction's own path:
+    ``inside`` (a fusion whose path names nothing takes the node its
+    insides name most), ``user`` or ``operand`` (:func:`_lend`). ``named``
+    counts the instructions of ``instrs`` and of their fusions' insides
+    that carry an ``op_name``, ``unscoped`` those of them with no node or
+    part."""
+    if nodes is None:
+        with _lock:
+            nodes = dict(_node_ops)
+    table = nodes or None
+    comps, entry = _computations(hlo_text)
+    memo = {}       # thousands of instructions share a few hundred paths
+
+    def scope(rest):
+        """(whether `rest` carries a path, its scope)."""
+        m = _OP_NAME_RE.search(rest)
+        if not m:
+            return False, None
+        path = m.group(1)
+        if path not in memo:
+            memo[path] = scope_of(path, table)
+        return True, memo[path]
+
+    inside = {}     # computation -> ({node: instructions naming it}, named)
+    for cname, body in comps.items():
+        seen, n = {}, 0
+        for _name, opcode, rest in body:
+            has_path, sc = scope(rest)
+            if not has_path or opcode == 'parameter':
+                continue
+            n += 1
+            if sc is not None:
+                seen[sc[0]] = seen.get(sc[0], 0) + 1
+        inside[cname] = seen, n
+    instrs = {}
+    named = unscoped = 0
+    todo, done = [entry] if entry else [], set()
+    while todo:
+        cname = todo.pop()
+        if cname in done or cname not in comps:
+            continue
+        done.add(cname)
+        order, users, operands = [], {}, {}
+        for name, opcode, rest in comps[cname]:
+            if opcode in _HOLDS_EVENTS:
+                todo.extend(_called(rest))
+            ops = operands[name] = _operands(rest)
+            for o in ops:
+                users.setdefault(o, []).append(name)
+            has_path, sc = scope(rest)
+            if opcode not in _NO_EVENT:
+                named += has_path
+                unscoped += has_path and sc is None
+            fused, via = 0, ''
+            if opcode == 'fusion':
+                seen = {}
+                for c in _called(rest):
+                    in_c, n = inside.get(c, ({}, 0))
+                    named += n
+                    unscoped += n - sum(in_c.values())
+                    for k, v in in_c.items():
+                        seen[k] = seen.get(k, 0) + v
+                fused = len(seen)
+                if sc is None and seen:
+                    top = max(seen, key=lambda k: (seen[k], k))
+                    sc = (top, '-' if top in WINDOW_PARTS else 'fwd', None)
+                    via = 'inside'
+            node, phase, inner = sc or (None, None, None)
+            instrs[name] = [node, phase, inner, opcode, fused, via]
+            if opcode not in _NO_EVENT:
+                order.append(name)
+        _lend(instrs, order, users, operands)
+    instrs = {n: v for n, v in instrs.items() if v[3] not in _NO_EVENT}
+    used = {v[0] for v in instrs.values()}
+    return {'instrs': instrs,
+            'nodes': {n: op for n, op in (table or {}).items() if n in used},
+            'named': named, 'unscoped': unscoped}
+
+
+def _hlo_text(compiled):
+    """The compiled module's text, without the backend configurations (a
+    serialized kernel each) and large constants where this jaxlib can
+    leave them out."""
+    try:
+        from jaxlib import _jax
+        opts = _jax.HloPrintOptions()
+        opts.print_backend_config = False
+        opts.print_large_constants = False
+        return compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    except Exception:  # noqa: BLE001 — another jaxlib: the whole text
+        return compiled.as_text()
+
+
+def _write_scope_map(name, compiled, n, log_path):
+    """Walk `compiled`, the n-th program compiled under `name`, and write
+    its map beside the telemetry log. Returns the fields the ``program``
+    record names it by, {} on any failure: attribution is best-effort,
+    execution is not."""
+    t0 = time.time()
+    try:
+        out = scope_map(_hlo_text(compiled))
+        base = '%s.scopes.%s.%d.json' % (
+            os.path.splitext(os.path.basename(log_path))[0],
+            scope_name(name), n)
+        out['program'] = name
+        path = os.path.join(os.path.dirname(os.path.abspath(log_path)), base)
+        with open(path, 'w') as f:
+            json.dump(out, f, separators=(',', ':'))
+        return {'scopes': base, 'scopes_s': round(time.time() - t0, 3),
+                'scopes_bytes': os.path.getsize(path),
+                'scopes_instrs': len(out['instrs']),
+                'scopes_unscoped': out['unscoped'],
+                'scopes_named': out['named']}
+    except Exception as e:  # noqa: BLE001 — observability must not kill
+        logging.debug('telemetry: scope map of %s failed: %s', name, e)
+        return {}
 
 
 # -- OOM diagnostics ---------------------------------------------------------
@@ -398,4 +780,5 @@ def _reset_for_tests():
     with _lock:
         _programs.clear()
         _step_flops_seen.clear()
+        _node_ops.clear()
         _oom_reported = False

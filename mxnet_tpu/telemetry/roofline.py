@@ -1,11 +1,13 @@
 """Roofline attribution: per-layer achieved-vs-peak diagnosis.
 
 The registrar (:mod:`.programs`) knows each compiled program's total
-FLOPs and bytes; the MXTPU_XPROF capture knows where device time went;
-neither alone says *which layer to fix*. This module joins them the way
-cost-model-driven compiler stacks do (TVM, arXiv:1802.04799): place
-every layer on the device's roofline (Williams et al., the
-operational-intensity model) and classify what bounds it.
+FLOPs and bytes; which layer they belong to it does not say. This module
+splits them the way cost-model-driven compiler stacks do (TVM,
+arXiv:1802.04799): place every layer on the device's roofline (Williams
+et al., the operational-intensity model) and classify what bounds it.
+Every time in it is modeled. Measured device time by symbol node is the
+benchmark's to read, from a capture's events through the compiled
+program's scope map (:func:`.programs.scope_map`).
 
 Data flow, all host-side (the compiled programs are untouched — the
 lowered HLO is byte-identical with the flag on or off):
@@ -18,25 +20,20 @@ lowered HLO is byte-identical with the flag on or off):
    totals are calibrated against XLA's own ``cost_analysis()`` /
    ``memory_analysis()`` numbers so the per-layer split always sums to
    what XLA reports for the whole program.
-2. **measured timings** — a ``jax.profiler`` capture (the MXTPU_XPROF
-   trace, or MXTPU_ROOFLINE_TRACE) is parsed as chrome-trace JSON;
-   events are keyed back to layers through the HLO instruction names.
-   Without a capture the measured step time is *distributed* across
-   layers in proportion to each layer's roofline-minimum time
-   (``source: modeled`` — the CPU/best-effort fallback).
+2. **modeled timings** — the measured step time is *distributed*
+   across layers in proportion to each layer's roofline-minimum time
+   (``source: modeled``).
 3. **classification** — per layer: achieved FLOP/s, achieved bytes/s,
    arithmetic intensity, and the placement against the peak table
    (:func:`.xla.device_peaks`): the roofline-minimum time is
    ``max(flops/peak_flops, bytes/peak_hbm)``; a layer whose FLOPs term
    dominates is **compute-bound**, one whose bytes term dominates is
-   **memory-bound**, and one running far below both ceilings
-   (< ``OVERHEAD_UTIL_PCT`` of its roofline) — or carrying no cost at
-   all — is **overhead-bound**.
+   **memory-bound**, and one carrying no cost at all is
+   **overhead-bound**.
 4. **communication accounting** — all-reduce / all-gather /
    collective-permute / reduce-scatter / all-to-all instructions are
-   summed separately: bytes on the wire per step, measured (or
-   modeled) collective time, the comm share of the step, and the
-   fraction of collective time overlapped with compute — the
+   summed separately: bytes on the wire per step, the collective time
+   modeled at the HBM ceiling and its share of the step — the
    per-collective numbers the cluster straggler classifier's
    ``communication_bound`` verdict is grounded in.
 
@@ -51,24 +48,21 @@ zero-overhead no-op contract of the rest of the plane: no HLO text is
 ever rendered or parsed, no registry writes, one cached-bool check at
 the registrar hook.
 """
-import gzip
-import json
 import logging
-import os
 import re
 import threading
 
+from .programs import _layer_from_op_name
+
 __all__ = ['enabled', 'note_compiled', 'note_hlo', 'hlo_layer_costs',
-           'load_trace_events', 'analyze', 'summarize', 'republish',
+           'analyze', 'summarize', 'republish',
            'snapshot_roofline', 'comm_share',
            'comm_pct_of_step', 'suggest_action',
            'RECLAIM_ACTIONS', 'TOP_N',
-           'OVERHEAD_UTIL_PCT', 'CLASS_COMPUTE', 'CLASS_MEMORY',
+           'CLASS_COMPUTE', 'CLASS_MEMORY',
            'CLASS_OVERHEAD']
 
 TOP_N = 8                  # bottleneck rows rendered in the summary block
-OVERHEAD_UTIL_PCT = 10.0   # below this % of its roofline ceiling a
-                           # measured layer classifies overhead-bound
 CLASS_COMPUTE = 'compute-bound'
 CLASS_MEMORY = 'memory-bound'
 CLASS_OVERHEAD = 'overhead-bound'
@@ -152,17 +146,6 @@ _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _CONTRACT_RE = re.compile(r'lhs_contracting_dims=\{([0-9,]*)\}')
 _DIM_LABELS_RE = re.compile(r'dim_labels=([\w?]+)_([\w?]+)->([\w?]+)')
 
-# scope segments that are tracing machinery, not layer names. jit()
-# segments are FUNCTION boundaries (jit(main), jit(relu)) — dropped
-# whole; AD/transform wrappers carry the layer name INSIDE
-# (jvp(fc1), transpose(jvp(fc1))) — peeled until the bare name appears
-_JIT_RE = re.compile(r'^(jit|pjit)\(')
-_XFORM_RE = re.compile(
-    r'^(jvp|vjp|transpose|vmap|pmap|xmap|shard_map|remat|'
-    r'checkpoint|custom_jvp|custom_vjp|named)\((.*)\)$')
-_WRAP_WORDS = frozenset(('while', 'body', 'cond', 'branch', 'scan',
-                         'closed_call', 'core_call'))
-
 # opcodes that are pure data movement / bookkeeping: no FLOPs, and no
 # bytes either (a reshape/bitcast costs nothing at run time; counting
 # its shapes would double every real operand)
@@ -176,43 +159,9 @@ _FREE_OPS = frozenset((
 
 # wrapper instructions whose cost lives in a separately-parsed called
 # computation: contribute nothing here (their bodies' instructions are
-# parsed on their own lines), but their NAMES are what device-trace
-# events carry, so they are indexed for the trace join
+# parsed on their own lines)
 _CALL_OPS = frozenset(('fusion', 'while', 'call', 'conditional',
                        'async-start', 'async-done'))
-
-
-def _unwrap_seg(seg):
-    """One scope segment -> the layer name it carries, or None.
-    ``transpose(jvp(fc1))`` -> ``fc1``; ``jit(relu)`` -> None (a
-    function boundary, not a layer); ``while``/``body`` -> None."""
-    while True:
-        if _JIT_RE.match(seg):
-            return None
-        m = _XFORM_RE.match(seg)
-        if not m:
-            break
-        seg = m.group(2)
-    if not seg or seg in _WRAP_WORDS:
-        return None
-    return seg
-
-
-def _layer_from_op_name(op_name):
-    """The ``jax.named_scope`` layer in an HLO ``op_name`` path, or
-    None. ``jit(f)/jit(main)/fc1/dot_general`` -> ``fc1`` and
-    ``jit(f)/while/body/transpose(jvp(fc1))/dot_general`` -> ``fc1``:
-    function/loop wrappers are dropped, transform wrappers are peeled,
-    the last remaining segment is the primitive, the first before it
-    is the layer the framework planted."""
-    segs = []
-    for s in str(op_name).split('/'):
-        u = _unwrap_seg(s)
-        if u is not None:
-            segs.append(u)
-    if len(segs) >= 2:
-        return segs[0]
-    return None
 
 
 def _shape_bytes(dtype, dims):
@@ -270,8 +219,6 @@ def hlo_layer_costs(hlo_text):
     """Parse an HLO module's text into the per-layer cost store::
 
         {'layers':      {layer: {'flops': f, 'bytes': b}},
-         'instr_layer': {instruction_name: layer},
-         'comm_instrs': set(instruction names of collective ops),
          'comm_bytes':  total bytes written by collectives (per step),
          'comm_ops':    {opcode: bytes},
          'flops_total': parsed-FLOPs sum, 'bytes_total': parsed-bytes sum}
@@ -281,8 +228,6 @@ def hlo_layer_costs(hlo_text):
     body is parsed once — the same per-step convention XLA's own
     cost_analysis uses."""
     layers = {}
-    instr_layer = {}
-    comm_instrs = set()
     comm_ops = {}
     comm_bytes = 0.0
     flops_total = bytes_total = 0.0
@@ -290,7 +235,7 @@ def hlo_layer_costs(hlo_text):
         m = _INSTR_RE.match(line)
         if not m:
             continue
-        name, out_sig, opcode = m.groups()
+        _name, out_sig, opcode = m.groups()
         out_bytes = out_elems = 0
         for dt, dims in _SHAPE_RE.findall(out_sig):
             b, n = _shape_bytes(dt, dims)
@@ -307,33 +252,25 @@ def hlo_layer_costs(hlo_text):
             operands.append((b, dims_t))
         is_comm = any(opcode.startswith(c) for c in COMM_OPS)
         if is_comm:
-            comm_instrs.add(name)
             if not opcode.endswith('-done'):
                 comm_bytes += out_bytes
                 comm_ops[opcode] = comm_ops.get(opcode, 0.0) + out_bytes
             continue
         if opcode in _FREE_OPS:
             continue
-        mo = _OP_NAME_RE.search(line)
-        layer_hint = _layer_from_op_name(mo.group(1)) if mo else None
         if opcode in _CALL_OPS:
-            # zero cost (the called computation's lines carry it), but
-            # the name->layer index is what the trace join keys on —
-            # device events are fusion-granular
-            if layer_hint is not None:
-                instr_layer[name] = layer_hint
             continue
+        mo = _OP_NAME_RE.search(line)
+        layer = (_layer_from_op_name(mo.group(1)) if mo else None) \
+            or '_unattributed'
         flops = _instr_flops(opcode, line, out_elems, operands)
         nbytes = float(out_bytes + sum(b for b, _d in operands))
-        layer = layer_hint or '_unattributed'
         rec = layers.setdefault(layer, {'flops': 0.0, 'bytes': 0.0})
         rec['flops'] += flops
         rec['bytes'] += nbytes
-        instr_layer[name] = layer
         flops_total += flops
         bytes_total += nbytes
-    return {'layers': layers, 'instr_layer': instr_layer,
-            'comm_instrs': comm_instrs, 'comm_bytes': comm_bytes,
+    return {'layers': layers, 'comm_bytes': comm_bytes,
             'comm_ops': comm_ops, 'flops_total': flops_total,
             'bytes_total': bytes_total}
 
@@ -391,140 +328,7 @@ def _pick_step_program():
 
 
 # ---------------------------------------------------------------------------
-# profiler trace -> measured per-layer timings
-# ---------------------------------------------------------------------------
-
-def load_trace_events(path):
-    """Chrome-trace events from a ``jax.profiler`` capture. ``path`` is
-    the capture directory (``plugins/profile/<run>/*.trace.json.gz`` is
-    searched recursively) or a ``.trace.json``/``.json.gz`` file.
-    Returns the raw event dicts (empty list when nothing parses)."""
-    files = []
-    if os.path.isdir(path):
-        for root, _dirs, names in os.walk(path):
-            for n in sorted(names):
-                if n.endswith(('.trace.json', '.trace.json.gz')) or \
-                        n in ('trace.json', 'trace.json.gz'):
-                    files.append(os.path.join(root, n))
-    elif os.path.isfile(path):
-        files = [path]
-    events = []
-    for f in files:
-        opener = gzip.open if f.endswith('.gz') else open
-        try:
-            with opener(f, 'rt') as fh:
-                data = json.load(fh)
-        except Exception as e:  # noqa: BLE001 — a bad capture is skipped
-            logging.debug('roofline: cannot parse trace %s: %s', f, e)
-            continue
-        evs = data.get('traceEvents', data) if isinstance(data, dict) \
-            else data
-        if isinstance(evs, list):
-            events.extend(e for e in evs if isinstance(e, dict))
-    return events
-
-
-def _union(ivals):
-    """Merge (start, end) intervals; returns the disjoint sorted list."""
-    out = []
-    for s, e in sorted(ivals):
-        if out and s <= out[-1][1]:
-            out[-1] = (out[-1][0], max(out[-1][1], e))
-        else:
-            out.append((s, e))
-    return out
-
-
-def _intersection_us(a, b):
-    """Total overlap between two disjoint sorted interval lists."""
-    total = 0.0
-    i = j = 0
-    while i < len(a) and j < len(b):
-        s = max(a[i][0], b[j][0])
-        e = min(a[i][1], b[j][1])
-        if e > s:
-            total += e - s
-        if a[i][1] < b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def _join_trace(prog, events):
-    """Key trace events back to layers through the HLO instruction
-    names (fall back to op_name scope extraction from the event args).
-    Returns None when nothing matched — the caller then models instead
-    of pretending to have measured."""
-    per_layer_us = {}
-    per_instr_count = {}
-    comm_us = 0.0
-    comm_ivals, compute_ivals = [], []
-    instr_layer = prog['instr_layer']
-    comm_instrs = prog['comm_instrs']
-    for ev in events:
-        if ev.get('ph') != 'X':
-            continue
-        try:
-            dur = float(ev.get('dur') or 0.0)
-            ts = float(ev.get('ts') or 0.0)
-        except (TypeError, ValueError):
-            continue
-        if dur <= 0:
-            continue
-        nm = str(ev.get('name', '')).lstrip('%')
-        args = ev.get('args') or {}
-        layer = instr_layer.get(nm)
-        is_comm = nm in comm_instrs or \
-            any(nm.startswith(c) for c in COMM_OPS)
-        if layer is None and not is_comm:
-            for key in ('name', 'long_name', 'tf_op', 'op_name'):
-                v = args.get(key)
-                if not v:
-                    continue
-                cand = str(v).lstrip('%').split(' ', 1)[0]
-                layer = instr_layer.get(cand) \
-                    or _layer_from_op_name(str(v))
-                if layer is not None:
-                    break
-        if is_comm:
-            comm_us += dur
-            comm_ivals.append((ts, ts + dur))
-        elif layer is not None:
-            per_layer_us[layer] = per_layer_us.get(layer, 0.0) + dur
-            per_instr_count[nm] = per_instr_count.get(nm, 0) + 1
-            compute_ivals.append((ts, ts + dur))
-    if not per_layer_us and not comm_us:
-        return None
-    # the capture usually spans several steps: every instruction fires
-    # once per dispatch, so the modal per-instruction event count IS
-    # the number of steps captured
-    counts = sorted(per_instr_count.values())
-    steps = counts[len(counts) // 2] if counts else 1
-    overlap_us = _intersection_us(_union(comm_ivals),
-                                  _union(compute_ivals))
-    return {'per_layer_us': per_layer_us, 'comm_us': comm_us,
-            'overlap_us': overlap_us, 'steps': max(1, steps)}
-
-
-def _default_trace_path():
-    from ..config import flags
-    try:
-        p = flags.get('MXTPU_ROOFLINE_TRACE')
-    except Exception:  # noqa: BLE001
-        p = ''
-    if p:
-        return os.path.expanduser(p)
-    try:
-        d = flags.get('MXTPU_XPROF_DIR')
-    except Exception:  # noqa: BLE001
-        d = ''
-    d = os.path.expanduser(d or 'xprof_trace')
-    return d if os.path.isdir(d) else None
-
-
-# ---------------------------------------------------------------------------
-# the join: classification + communication accounting
+# classification + communication accounting
 # ---------------------------------------------------------------------------
 
 def _registry_step_ms(reg):
@@ -545,7 +349,7 @@ def _registry_step_ms(reg):
     return None
 
 
-def _classify(flops, nbytes, time_ms, peaks, measured):
+def _classify(flops, nbytes, time_ms, peaks):
     """(class, roof_ms, roof_pct) for one layer against the peaks."""
     if peaks['flops'] <= 0 or peaks['hbm_bytes_s'] <= 0:
         return CLASS_UNKNOWN, None, None
@@ -558,26 +362,21 @@ def _classify(flops, nbytes, time_ms, peaks, measured):
     roof_pct = None
     if time_ms and time_ms > 0:
         roof_pct = min(100.0, 100.0 * roof_ms / time_ms)
-        if measured and roof_pct < OVERHEAD_UTIL_PCT:
-            # far below BOTH ceilings: the time went to something the
-            # roofline cannot see (launch gaps, transposes, small-op
-            # scheduling) — overhead, not math
-            cls = CLASS_OVERHEAD
     return cls, roof_ms, roof_pct
 
 
-def analyze(step_time_ms=None, events=None, trace_path=None,
-            device=None, warn_unknown=True):
+def analyze(step_time_ms=None, device=None, warn_unknown=True):
     """Compute the roofline analysis dict (no publication — see
     :func:`summarize`). Returns None when roofline is off or no
     program has been ingested.
 
-    ``step_time_ms`` overrides the registry-derived per-step time;
-    ``events`` injects pre-parsed trace events (tests), else
-    ``trace_path`` / the MXTPU_ROOFLINE_TRACE / MXTPU_XPROF_DIR capture
-    is loaded when one exists. ``warn_unknown=False`` makes the call
-    truly read-only (the unknown-device peak lookup neither warns nor
-    writes the ``roofline.peaks_unknown`` gauge — the scrape path)."""
+    ``step_time_ms`` overrides the registry-derived per-step time.
+    ``warn_unknown=False`` makes the call truly read-only (the
+    unknown-device peak lookup neither warns nor writes the
+    ``roofline.peaks_unknown`` gauge — the scrape path). Every time in
+    it is modeled: device time by symbol node is read from a capture
+    through the compiled program's scope map (:func:`.programs.scope_map`,
+    ``benchmark/reduce/scopes.py``), not here."""
     if not enabled():
         return None
     prog = _pick_step_program()
@@ -585,11 +384,6 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
         return None
     from . import xla
     peaks = xla.device_peaks(device, warn=warn_unknown)
-    if events is None:
-        path = trace_path or _default_trace_path()
-        events = load_trace_events(path) if path else []
-    joined = _join_trace(prog, events) if events else None
-    measured = joined is not None and bool(joined['per_layer_us'])
 
     analysis = prog.get('analysis') or {}
     # calibrate the parsed split against XLA's own whole-program totals
@@ -604,7 +398,6 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
     if step_time_ms is None:
         step_time_ms = _registry_step_ms(reg)
 
-    trace_steps = joined['steps'] if joined else None
     rows = []
     roof_total_ms = 0.0
     layer_items = sorted(prog['layers'].items())
@@ -616,33 +409,26 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
                                  nbytes / peaks['hbm_bytes_s']) * 1e3
         rows.append([layer, flops, nbytes])
 
-    if measured:
-        source = 'measured'
-        layer_ms = {l: joined['per_layer_us'][l] / joined['steps'] / 1e3
-                    for l in joined['per_layer_us']}
-    else:
-        source = 'modeled'
-        # distribute the measured step time across layers in proportion
-        # to each one's roofline-minimum time (perfect execution would
-        # land exactly there); with no step time either, assume the
-        # roofline itself
-        layer_ms = {}
-        for layer, flops, nbytes in rows:
-            if peaks['flops'] > 0 and peaks['hbm_bytes_s'] > 0:
-                roof = max(flops / peaks['flops'],
-                           nbytes / peaks['hbm_bytes_s']) * 1e3
-            else:
-                roof = 0.0
-            if step_time_ms and roof_total_ms > 0:
-                layer_ms[layer] = step_time_ms * roof / roof_total_ms
-            else:
-                layer_ms[layer] = roof
+    # distribute the measured step time across layers in proportion
+    # to each one's roofline-minimum time (perfect execution would
+    # land exactly there); with no step time either, assume the
+    # roofline itself
+    layer_ms = {}
+    for layer, flops, nbytes in rows:
+        if peaks['flops'] > 0 and peaks['hbm_bytes_s'] > 0:
+            roof = max(flops / peaks['flops'],
+                       nbytes / peaks['hbm_bytes_s']) * 1e3
+        else:
+            roof = 0.0
+        if step_time_ms and roof_total_ms > 0:
+            layer_ms[layer] = step_time_ms * roof / roof_total_ms
+        else:
+            layer_ms[layer] = roof
 
     out_rows = []
     for layer, flops, nbytes in rows:
         t_ms = layer_ms.get(layer, 0.0)
-        cls, roof_ms, roof_pct = _classify(flops, nbytes, t_ms, peaks,
-                                           measured)
+        cls, roof_ms, roof_pct = _classify(flops, nbytes, t_ms, peaks)
         row = {'layer': layer, 'class': cls,
                'flops': round(flops, 1), 'bytes': round(nbytes, 1),
                'time_ms': round(t_ms, 4),
@@ -659,35 +445,27 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
     out_rows.sort(key=lambda r: (-(r['headroom_ms'] or 0.0),
                                  -r['time_ms'], r['layer']))
 
-    # communication accounting (bytes are per step by the scan-body
-    # convention; time measured from the capture, else modeled at the
-    # HBM ceiling — a deliberate lower bound, labeled as such)
+    # communication accounting: bytes are per step by the scan-body
+    # convention; the time is modeled at the HBM ceiling — a deliberate
+    # lower bound, labeled as such
     comm_bytes = prog['comm_bytes']
     comm = None
-    if comm_bytes > 0 or (joined and joined['comm_us'] > 0):
-        if joined and joined['comm_us'] > 0:
-            comm_ms = joined['comm_us'] / joined['steps'] / 1e3
-            overlap_pct = round(100.0 * joined['overlap_us']
-                                / joined['comm_us'], 1)
-            comm_src = 'measured'
-        else:
-            comm_ms = (comm_bytes / peaks['hbm_bytes_s'] * 1e3) \
-                if peaks['hbm_bytes_s'] > 0 else None
-            overlap_pct = None
-            comm_src = 'modeled'
+    if comm_bytes > 0:
+        comm_ms = (comm_bytes / peaks['hbm_bytes_s'] * 1e3) \
+            if peaks['hbm_bytes_s'] > 0 else None
         comm = {'bytes': round(comm_bytes, 1),
                 'time_ms': round(comm_ms, 4)
                 if comm_ms is not None else None,
-                'overlap_pct': overlap_pct,
+                'overlap_pct': None,
                 'pct_of_step': round(100.0 * comm_ms / step_time_ms, 1)
                 if comm_ms and step_time_ms else None,
                 'ops': {k: round(v, 1)
                         for k, v in sorted(prog['comm_ops'].items())},
-                'source': comm_src}
+                'source': 'modeled'}
 
     return {
         'program': prog['name'],
-        'source': source,
+        'source': 'modeled',
         'device': peaks['kind'],
         'peaks': peaks['source'],
         'peak_tflops': round(peaks['flops'] / 1e12, 3)
@@ -696,7 +474,6 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
         if peaks['hbm_bytes_s'] else None,
         'step_time_ms': round(step_time_ms, 4)
         if step_time_ms is not None else None,
-        'trace_steps': trace_steps,
         'layers': out_rows,
         'worst_action': suggest_action(out_rows[0]['class'])
         if out_rows else None,
@@ -706,9 +483,9 @@ def analyze(step_time_ms=None, events=None, trace_path=None,
 
 def comm_share():
     """``(pct, source)`` — the collective share of the step (%) with
-    its provenance attached: ``'measured'`` when the number comes from
-    a joined device trace, ``'modeled'`` when it is the HBM-ceiling
-    lower bound, ``(None, None)`` when there is nothing to report.
+    its provenance attached: ``'modeled'``, the HBM-ceiling lower
+    bound (nothing here measures it), or ``(None, None)`` when there is
+    nothing to report.
     The provenance travels with the number everywhere it is consumed
     (cluster records, /metrics, the goodput comm bucket) so a model is
     never laundered into a measurement. Uses the last published
@@ -818,8 +595,7 @@ def republish():
     global _last
     if not enabled():
         return None
-    d = analyze(step_time_ms=_explicit_step_ms, events=[],
-                warn_unknown=False)
+    d = analyze(step_time_ms=_explicit_step_ms, warn_unknown=False)
     if d is None:
         return None
     _publish_gauges(d, _tele().registry)

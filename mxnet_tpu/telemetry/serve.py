@@ -222,11 +222,9 @@ def summary_payload():
     # roofline (MXTPU_ROOFLINE): the last published analysis, else a
     # fresh read-only one (warn_unknown=False: analyze writes no
     # gauges — not even peaks_unknown — and emits no records; the
-    # scrape convention holds). events=[] forces the MODELED path: a
-    # scrape must never re-load and re-parse a multi-MB profiler
-    # capture from disk
+    # scrape convention holds)
     roof = roofline.snapshot_roofline() \
-        or roofline.analyze(events=[], warn_unknown=False)
+        or roofline.analyze(warn_unknown=False)
     # goodput: a fresh read-only attribution (no gauges, no record) so
     # a mid-run scrape sees live numbers, not the last summary's
     good = goodput.current()

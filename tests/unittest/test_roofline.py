@@ -4,9 +4,10 @@ Contracts under test:
 - HLO text -> per-layer cost parse (dot/convolution FLOPs from
   contraction dims, bytes from shapes, named-scope layer extraction
   through jvp/transpose wrappers, collective accounting, free ops);
-- the trace join: synthetic chrome-trace events keyed by HLO
-  instruction names -> measured per-layer times, step-count inference,
-  comm/compute overlap;
+- the scope map of the same synthetic module (telemetry/programs.py):
+  each instruction's name -> node and pass, the key a capture's events
+  are joined by (benchmark/reduce/scopes.py), beside the modeled
+  classification;
 - deterministic classification goldens against overridden peaks
   (compute-bound / memory-bound / overhead-bound);
 - MXTPU_ROOFLINE=0/1 parametrized fit acceptance: =1 puts a ranked
@@ -31,7 +32,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.config import flags
-from mxnet_tpu.telemetry import roofline
+from mxnet_tpu.telemetry import programs, roofline
 from mxnet_tpu.telemetry import xla as tele_xla
 
 REPO = os.path.dirname(os.path.dirname(
@@ -39,7 +40,7 @@ REPO = os.path.dirname(os.path.dirname(
 sys.path.insert(0, os.path.join(REPO, 'tools'))
 
 _FLAGS = ('MXTPU_TELEMETRY', 'MXTPU_TELEMETRY_PATH', 'MXTPU_ROOFLINE',
-          'MXTPU_ROOFLINE_TRACE', 'MXTPU_PEAK_TFLOPS',
+          'MXTPU_PEAK_TFLOPS',
           'MXTPU_PEAK_HBM_GBS')
 
 
@@ -98,7 +99,8 @@ _AR_BYTES = 64 * 64 * 4
 # ---------------------------------------------------------------------------
 
 def test_layer_from_op_name_unwraps():
-    f = roofline._layer_from_op_name
+    f = programs._layer_from_op_name
+    assert roofline._layer_from_op_name is f       # one parser, imported
     assert f('jit(f)/jit(main)/fc1/dot_general') == 'fc1'
     assert f('jit(window_fn)/jit(main)/while/body/jvp(fc1)/dot_general') \
         == 'fc1'
@@ -119,9 +121,6 @@ def test_hlo_layer_costs_golden():
     assert costs['layers']['tiny']['flops'] == 4.0
     # free ops (parameter/copy) and the collective cost nothing here
     assert set(costs['layers']) == {'fc1', 'relu1', 'tiny'}
-    assert costs['instr_layer'] == {'dot.1': 'fc1', 'add.2': 'relu1',
-                                    'multiply.5': 'tiny'}
-    assert costs['comm_instrs'] == {'all-reduce.3'}
     assert costs['comm_bytes'] == _AR_BYTES
     assert costs['comm_ops'] == {'all-reduce': float(_AR_BYTES)}
     assert costs['flops_total'] == _FC1_FLOPS + _ADD_FLOPS + 4.0
@@ -141,32 +140,14 @@ def test_analysis_calibrates_parsed_split(roof_on):
     parsed_total = _FC1_FLOPS + _ADD_FLOPS + 4.0
     roofline.note_hlo('p', _SYNTH_HLO,
                       analysis={'flops': 2 * parsed_total})
-    d = roofline.analyze(step_time_ms=1.0, events=[])
+    d = roofline.analyze(step_time_ms=1.0)
     assert sum(r['flops'] for r in d['layers']) \
         == pytest.approx(2 * parsed_total, rel=1e-6)
 
 
 # ---------------------------------------------------------------------------
-# trace join + classification goldens
+# scope map + classification goldens
 # ---------------------------------------------------------------------------
-
-def _synthetic_events():
-    """Two captured steps. Per step: dot.1 1000us, add.2 500us, the
-    tiny op 1000us (clear of the collective), all-reduce 500us of
-    which 300us overlap add.2 — 60% overall overlap."""
-    events = []
-    for step in range(2):
-        base = step * 10000.0
-        events += [
-            {'ph': 'X', 'name': 'dot.1', 'ts': base, 'dur': 1000.0},
-            {'ph': 'X', 'name': 'add.2', 'ts': base + 1000, 'dur': 500.0},
-            {'ph': 'X', 'name': 'multiply.5', 'ts': base + 3000,
-             'dur': 1000.0},
-            {'ph': 'X', 'name': 'all-reduce.3', 'ts': base + 1200,
-             'dur': 500.0},
-        ]
-    return events
-
 
 def _set_peaks(monkeypatch, tflops, gbs):
     monkeypatch.setenv('MXTPU_PEAK_TFLOPS', str(tflops))
@@ -175,39 +156,51 @@ def _set_peaks(monkeypatch, tflops, gbs):
     flags.reload('MXTPU_PEAK_HBM_GBS')
 
 
-def test_trace_join_classification_golden(roof_on, monkeypatch):
-    """The deterministic end-to-end golden: synthetic HLO + synthetic
-    trace + overridden peaks -> measured per-layer times, the three
-    classifications, and the comm/overlap accounting."""
+def test_scope_map_classification_golden(roof_on, monkeypatch):
+    """The deterministic end-to-end golden: synthetic HLO + overridden
+    peaks -> the three classifications and the collective's accounting,
+    and, from the same text, the map a capture's events are joined by:
+    each instruction of the entry computation under its own name with
+    the node its path names (the key the chrome-trace join had)."""
     _set_peaks(monkeypatch, 0.001, 0.1)    # 1e9 FLOP/s, 1e8 B/s
     roofline.note_hlo('p', _SYNTH_HLO)
-    d = roofline.analyze(step_time_ms=3.0, events=_synthetic_events())
-    assert d['source'] == 'measured'
+    d = roofline.analyze(step_time_ms=3.0)
+    assert d['source'] == 'modeled'
     assert d['peaks'] == 'override'
-    assert d['trace_steps'] == 2
+    assert 'trace_steps' not in d
     rows = {r['layer']: r for r in d['layers']}
-    # fc1: roofline min = max(1048576/1e9, 81920/1e8)s = 1.049ms over
-    # 1.0ms measured -> compute-bound at ~100% of roof
+    # fc1: roofline min = max(1048576/1e9, 81920/1e8)s = 1.049ms:
+    # the FLOPs term dominates
     assert rows['fc1']['class'] == 'compute-bound'
-    assert rows['fc1']['time_ms'] == pytest.approx(1.0)
-    assert rows['fc1']['roof_pct'] == pytest.approx(100.0)
-    assert rows['fc1']['achieved_flops_s'] == pytest.approx(_FC1_FLOPS
-                                                            / 1e-3)
-    # relu1: bytes term dominates -> memory-bound (0.492ms roof over
-    # 0.5ms measured)
+    # relu1: the bytes term dominates (0.492ms)
     assert rows['relu1']['class'] == 'memory-bound'
-    assert rows['relu1']['roof_pct'] == pytest.approx(98.3, abs=0.1)
-    # tiny: 1ms measured for a 4-flop op -> far below both ceilings
-    assert rows['tiny']['class'] == 'overhead-bound'
-    assert rows['tiny']['roof_pct'] < 10.0
-    # comm: 500us/step measured, 600/1000 overlapped, 16 KiB on wire
+    assert rows['tiny']['class'] == 'memory-bound'
+    # the step's 3 ms go to the layers by their roofline minimum
+    assert sum(r['time_ms'] for r in d['layers']) \
+        == pytest.approx(3.0, abs=1e-3)
+    assert rows['fc1']['time_ms'] / rows['relu1']['time_ms'] \
+        == pytest.approx(1.048576 / 0.49152, rel=1e-3)
+    # comm: 16 KiB on the wire, its time modeled at the HBM ceiling
     comm = d['comm']
-    assert comm['source'] == 'measured'
+    assert comm['source'] == 'modeled'
     assert comm['bytes'] == _AR_BYTES
-    assert comm['time_ms'] == pytest.approx(0.5)
-    assert comm['overlap_pct'] == pytest.approx(60.0)
-    assert comm['pct_of_step'] == pytest.approx(100.0 * 0.5 / 3.0, abs=0.1)
+    assert comm['time_ms'] == pytest.approx(_AR_BYTES / 1e8 * 1e3, abs=1e-4)
+    assert comm['overlap_pct'] is None
+    assert comm['pct_of_step'] == pytest.approx(
+        100.0 * comm['time_ms'] / 3.0, abs=0.1)
     assert comm['ops'] == {'all-reduce': float(_AR_BYTES)}
+    # the same text's map: name -> [node, phase, inner, opcode, fused
+    # nodes, via]; what no path names takes its operand's node
+    m = programs.scope_map(_SYNTH_HLO, {'fc1': 'FullyConnected',
+                                        'relu1': 'Activation'})
+    assert m['instrs'] == {
+        'dot.1': ['fc1', 'fwd', None, 'dot', 0, ''],
+        'add.2': ['relu1', 'fwd', None, 'add', 0, ''],
+        'multiply.5': [None, None, None, 'multiply', 0, ''],  # no such node
+        'all-reduce.3': ['relu1', 'fwd', None, 'all-reduce', 0, 'operand'],
+        'copy.4': ['relu1', 'fwd', None, 'copy', 0, 'operand']}
+    assert m['nodes'] == {'fc1': 'FullyConnected', 'relu1': 'Activation'}
+    assert (m['named'], m['unscoped']) == (4, 2)
 
 
 def test_modeled_fallback_without_trace(roof_on, monkeypatch):
@@ -216,7 +209,7 @@ def test_modeled_fallback_without_trace(roof_on, monkeypatch):
     measurement)."""
     _set_peaks(monkeypatch, 0.001, 0.1)
     roofline.note_hlo('p', _SYNTH_HLO)
-    d = roofline.analyze(step_time_ms=10.0, events=[])
+    d = roofline.analyze(step_time_ms=10.0)
     assert d['source'] == 'modeled'
     assert sum(r['time_ms'] for r in d['layers']) == pytest.approx(10.0)
     assert d['comm']['source'] == 'modeled'
