@@ -127,7 +127,9 @@ flags.declare('MXTPU_BACKWARD_DO_MIRROR', str, '0',
               "Gradient-memory tradeoff: '1' (or any truthy value) = "
               "rematerialization of the forward under jax.checkpoint, "
               "all but the values an op named as dear to recompute "
-              "(an attention kernel's output and log-sum-exp), "
+              "(what an attention kernel's backward pass reads, a "
+              "contracting FullyConnected's output, an expert layer's "
+              "routing and plan), "
               "'dots' = keep matmul results (checkpoint_dots policy), "
               "'0'/''/'false' = off (legacy spellings honored)",
               aliases=('MXNET_BACKWARD_DO_MIRROR',))

@@ -39,7 +39,8 @@ def _note_kept(kept):
     for its backward pass (0 where it was traced without one)."""
     from . import telemetry as _tele
     _tele.gauge('executor.mirror_kept').set(len(kept))
-    _tele.gauge('executor.mirror_kept_bytes').set(sum(kept))
+    _tele.gauge('executor.mirror_kept_bytes').set(
+        sum(x.size * x.dtype.itemsize for x in kept))
 
 
 def mirror_wrap(f):
@@ -126,10 +127,16 @@ class _GraphProgram:
         of stored: consecutive marked op nodes with the same value form one
         stage, run as ``ops.registry.mirrored`` when training. It keeps
         what it reads, what leaves it, and the values an op inside it named
-        as dear to recompute (``ops.registry.dear``); everything else it
-        computes again. The gauges ``executor.mirror_kept`` and ``_bytes`` say how
-        many such values the traced program keeps, and their size. A
-        builder gives each block of a deep network its own value."""
+        as dear to recompute (``ops.registry.dear``): what an attention
+        kernel's backward pass reads (its output and log-sum-exp, query,
+        key and value), the output of a ``FullyConnected`` that contracts,
+        and an expert layer's routing and plan. Everything else it
+        computes again: norms, expanding projections, the MLPs' hidden
+        activations. The gauges ``executor.mirror_kept`` and ``_bytes`` say
+        how many arrays the ops of the traced program named so, each once,
+        and their size (one that no backward rule reads, a key's projection
+        before its rotary turn, is counted and not held). A builder gives
+        each block of a deep network its own value."""
         topo = self.topo
         arg_index = {n: i for i, n in enumerate(self.arg_names)}
         aux_index = {n: i for i, n in enumerate(self.aux_names)}

@@ -19,7 +19,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
-from .registry import register, register_alias
+from .registry import dear, register, register_alias
 
 
 # ---------------------------------------------------------------------------
@@ -37,6 +37,12 @@ def _fully_connected(attrs, data, weight, bias=None):
     y = y.astype(x.dtype)
     if bias is not None and not attrs.get('no_bias', False):
         y = y + bias
+    if weight.shape[0] <= weight.shape[1]:
+        # a contraction: no larger than what it was made from, behind a
+        # product at least as deep as it is wide, so a mirrored stage
+        # keeps it (an attention's output, key, value or latent
+        # projection; not a query's, an MLP's or a head's)
+        y = dear(y, 'fully_connected_out')
     return y
 
 

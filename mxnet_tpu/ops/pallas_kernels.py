@@ -1062,8 +1062,10 @@ def _blockwise_fwd(q, k, v, heads, kv_heads, causal, window, scale, block_q,
                    block_k, name):
     out, lse = attention_forward(q, k, v, heads, kv_heads, causal, window,
                                  scale, block_q, block_k, name)
-    # what attention_backward needs of the forward kernel: a mirrored
-    # stage keeps the two and recomputes q, k and v, not the kernel
+    # what attention_backward reads that is made here: a mirrored stage
+    # keeps the two, so the kernel runs once. q, k and v are made outside,
+    # where the policy judges them: the op that calls names them
+    # (ops/transformer.py)
     out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
     return out, (q, k, v, out, lse)
 
@@ -1541,7 +1543,8 @@ def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, heads, block_q, block_k,
                 name, scale):
     out, lse = latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v,
                                         heads, block_q, block_k, name, scale)
-    # as _blockwise_fwd: a mirrored stage keeps the kernel's two outputs
+    # as _blockwise_fwd: a mirrored stage keeps the kernel's two outputs;
+    # of the operands the op names the queries and the rotary key
     out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
     return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse)
 

@@ -177,8 +177,20 @@ if _COVERING:
 
 # -- values dear to recompute -------------------------------------------------
 # A mirrored stage recomputes in the backward pass everything but what an
-# op has named here: a value that costs far more to make again than to
-# hold (a kernel's output against a projection's).
+# op has named here: a value that its backward pass reads anyway and that
+# costs more to make again than to hold. Three rules name values, each
+# from shapes and the op's own structure alone:
+#   - an attention op names its kernel's output and log-sum-exp, and the
+#     operands that the backward kernel reads: query, key and value, the
+#     projections, per-head splits and rotary turns behind them. (Latent
+#     attention leaves out the keys and values it expands from the latent:
+#     the expansion is cheap and eight times the latent's size);
+#   - ``FullyConnected`` names its output where it contracts (no more
+#     output features than input features): the value is no larger than
+#     the one it was made from, behind a product as deep as it is wide;
+#   - ``MoE`` names its routing and its plan: a few small vectors behind
+#     a top-k, a gather and a sort's worth of scans and scatters.
+# An MLP's hidden activations stay recomputed: large, and one product deep.
 
 _DEAR = set()                   # every name `dear` was given
 _MIRROR = threading.local()     # .kept: the list of the stage being traced
@@ -186,15 +198,23 @@ _MIRROR = threading.local()     # .kept: the list of the stage being traced
 
 def dear(x, name):
     """Name ``x`` as dear to recompute: the identity, except that a
-    mirrored stage keeps the value for its backward pass. Inside a
-    ``jax.custom_vjp`` forward rule, name the residuals themselves: the
-    policy judges each value where it is made, and a value left unnamed
-    there brings back the whole computation behind it."""
+    mirrored stage keeps the value for its backward pass. The policy
+    judges a value where it is made, and a backward rule reads the values
+    its forward rule saw: so name a ``jax.custom_vjp``'s operands before
+    the call and, of what its forward rule makes, the residuals themselves
+    inside it (a value left unnamed there brings back the whole computation
+    behind it); a primitive whose own rule reads its raw result
+    (``top_k``'s indices) needs a rule that reads the named one. A name may
+    be shared by call sites. Naming a named value again keeps, and counts,
+    one array."""
     _DEAR.add(name)
     kept = getattr(_MIRROR, 'kept', None)
+    named = checkpoint_name(x, name)
     if kept is not None:
-        kept.append(x.size * x.dtype.itemsize)
-    return checkpoint_name(x, name)
+        # named before: one array, under its last name
+        at = next((i for i, k in enumerate(kept) if k is x), len(kept))
+        kept[at:at + 1] = [named]
+    return named
 
 
 def keeps_dear(prim, *avals, **params):
@@ -209,10 +229,9 @@ def keeps_dear(prim, *avals, **params):
 def mirrored(f, kept):
     """``f`` as a mirrored stage: its backward pass recomputes ``f`` from
     its inputs, except the values an op inside named as :func:`dear`
-    (an attention kernel's output and log-sum-exp), which are kept. With
-    no value named that is a bare ``jax.checkpoint``. Called under
-    ``jax.vjp``, the result appends the bytes of each value it keeps to
-    ``kept``."""
+    (the rules are above), which are kept. With no value named that is a
+    bare ``jax.checkpoint``. Called under ``jax.vjp``, the result appends
+    each value it names to ``kept``."""
     stage = jax.checkpoint(f, policy=keeps_dear)
 
     def g(*args):

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from . import pallas_kernels as pk
-from .registry import get as registry_get, register
+from .registry import dear, get as registry_get, register
 
 __all__ = ['MOE_STATS', 'moe_stat_names', 'HYPER_STATS', 'hyper_stat_names']
 
@@ -182,6 +182,11 @@ def _gqa(attrs, q, k, v, gate=None):
     def plain(q, k, v):
         return _dense_attention(q, k, v, H, KV, window)
 
+    # the operands are what the backward kernel reads beside the forward
+    # kernel's output (residuals of its custom_vjp, live in the backward
+    # pass whatever happens): a mirrored stage that keeps them runs the
+    # projections and the rotary turns behind them once
+    q, k, v = dear(q, name + '_q'), dear(k, name + '_k'), dear(v, name + '_v')
     out = pk.dispatch(fused, plain, q, k, v)
     if gate is not None and attrs.get('gated', False):
         B, T, HD = out.shape
@@ -241,6 +246,12 @@ def _latent_attention(attrs, q_nope, q_rope, k_nope, k_rope, v):
     def plain(*operands):
         return _dense_latent_attention(*operands, H, scale)
 
+    # as GroupedQueryAttention, for the queries and the one rotary key
+    # head. k_nope and value stay recomputed: they are the op's expansion
+    # of a latent an eighth their size, one projection behind them
+    q_nope, q_rope, k_rope = (dear(q_nope, 'attention_latent_q_nope'),
+                              dear(q_rope, 'attention_latent_q_rope'),
+                              dear(k_rope, 'attention_latent_k_rope'))
     return pk.dispatch(fused, plain, q_nope, q_rope, k_nope, k_rope, v)
 
 
@@ -678,23 +689,55 @@ def _experts_bwd(rp, res, g):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(x, k):
+    """``jax.lax.top_k`` on the last axis of x [T, n], with a backward
+    rule that reads the indices as a mirrored stage keeps them
+    (``top_k``'s own reads them as the sort made them, unnamed, and would
+    run the sort again for it)."""
+    return tuple(jax.lax.top_k(x, k))
+
+
+def _top_k_fwd(x, k):
+    w, idx = jax.lax.top_k(x, k)
+    idx = dear(idx, 'moe_route')
+    # a zero-row array tells the backward rule x's width and dtype
+    return (w, idx), (idx, jnp.zeros((0, x.shape[1]), x.dtype))
+
+
+def _top_k_bwd(k, res, g):
+    idx, like = res
+    rows = jnp.arange(idx.shape[0])[:, None]
+    return (jnp.zeros((idx.shape[0], like.shape[1]), like.dtype)
+            .at[rows, idx].add(g[0]),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def _route(attrs, x2, router, select_bias, k):
     """(w_pairs [T, k] float32, idx [T, k]): each token's k experts and
-    their weights, as :func:`_moe` describes."""
+    their weights, as :func:`_moe` describes. The choice and the chosen
+    scores are named for a mirrored stage (``registry.dear``): what the
+    backward pass reads of the top-k and the gather."""
     if _sigmoid_scoring(attrs):
         scores = jax.nn.sigmoid(_matmul(x2, router))
         bias = jax.lax.stop_gradient(select_bias).astype(jnp.float32)
         _, idx = jax.lax.top_k(scores + bias.reshape(1, -1), k)
-        w_pairs = jnp.take_along_axis(scores, idx, axis=-1)
+        idx = dear(idx, 'moe_route')
+        w_pairs = dear(jnp.take_along_axis(scores, idx, axis=-1),
+                       'moe_route')
         if attrs.get('norm_topk_prob', True):
             w_pairs = w_pairs / (jnp.sum(w_pairs, axis=-1, keepdims=True)
                                  + 1e-20)
     else:
         probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
-        w_pairs, idx = jax.lax.top_k(probs, k)
+        w_pairs, idx = _top_k(probs, k)
+        w_pairs = dear(w_pairs, 'moe_route')
         if attrs.get('norm_topk_prob', True):
             w_pairs = w_pairs / jnp.sum(w_pairs, axis=-1, keepdims=True)
-    return w_pairs * float(attrs.get('routed_scaling', 1.0)), idx
+    w_pairs = w_pairs * float(attrs.get('routed_scaling', 1.0))
+    return dear(w_pairs, 'moe_route'), idx
 
 
 @register('MoE',
@@ -752,8 +795,11 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
             idx, held, offset)
         rp = _pass_rows(row_pair.shape[0], x2.shape[0], k, held,
                         router.shape[0])
-        plan = _whole_passes(rp, dest, row_pair, tile_group)
-    out = _experts(rp, x2, w_pairs, w1, w3, w2, *plan, n_tiles)
+        # what _experts carries to its backward pass (with w_pairs, named
+        # by _route): int32 vectors behind scans, scatters and a search
+        plan = [dear(v, 'moe_plan') for v in _whole_passes(
+            rp, dest, row_pair, tile_group) + (n_tiles,)]
+    out = _experts(rp, x2, w_pairs, w1, w3, w2, *plan)
     with jax.named_scope('shared'):
         out = out + _gated_mlp(x2, s1, s3, s2)
     pairs = jnp.sum(counts).astype(jnp.float32)

@@ -392,13 +392,29 @@ def test_model_forward_and_gradient(remat):
 
 # -- what a mirrored block keeps -------------------------------------------------------
 
+def _named_bytes(layers, sparse, held):
+    """The arrays that the ops of `layers` blocks, `sparse` of them with an
+    expert layer, name for a mirrored stage, as (count, bytes): float32."""
+    rows, k = 2 * T, CFG['num_experts_per_tok']
+    block = [rows * H * Dv, 2 * H * T,                  # out, lse
+             rows * H * Dn, rows * H * Dr, rows * Dr,   # queries, rotary key
+             rows * (CFG['kv_lora_rank'] + Dr), rows * d]   # kv_a, attn_o
+    R = -(-(rows * k + held * pk.GROUP_TILE) // pk.GROUP_TILE) \
+        * pk.GROUP_TILE
+    R += -R % mx.ops.transformer._pass_rows(R, rows, k, held, 16)
+    # the choice, its scores, the weights, dest; row_pair, tile_group, n_tiles
+    moe = [rows * k] * 4 + [R, R // pk.GROUP_TILE, 1]
+    return (layers * len(block) + sparse * len(moe),
+            4 * (layers * sum(block) + sparse * sum(moe)))
+
+
 @pytest.mark.parametrize('path', ['kernel'], indirect=True)
 def test_a_mirrored_block_runs_the_latent_forward_kernel_once(
         path, monkeypatch):
     """In the gradient of the mirrored blocks ``attention_latent_fwd`` is
     there as often as the backward kernel, once a block; under a bare
-    checkpoint twice. ``executor.mirror_kept`` counts its output and its
-    log-sum-exp, and their bytes follow from the shapes."""
+    checkpoint twice. ``executor.mirror_kept`` counts what the ops named,
+    each array once, and their bytes follow from the shapes."""
     monkeypatch.setenv('MXTPU_TELEMETRY', '1')
     monkeypatch.setenv('MXTPU_TELEMETRY_PATH', os.devnull)
     _reload_telemetry()
@@ -412,14 +428,69 @@ def test_a_mirrored_block_runs_the_latent_forward_kernel_once(
         _reload_telemetry()
     layers = CFG['num_hidden_layers']
     _one_backward_kernel(calls)
-    assert gauges['executor.mirror_kept'] == 2 * layers
-    assert gauges['executor.mirror_kept_bytes'] == layers * (
-        2 * T * H * Dv * 4 + 2 * H * T * 4)
+    count, size = _named_bytes(layers, layers - 1, 16)
+    assert gauges['executor.mirror_kept'] == count
+    assert gauges['executor.mirror_kept_bytes'] == size
     monkeypatch.setattr(registry, 'mirrored',
                         lambda f, kept: jax.checkpoint(f))
     step, wrt = _training_step(builder.get_symbol(CFG), **LM_IN)
     bare = _kernel_calls(str(jax.make_jaxpr(step)(wrt)), 'attention_latent')
     assert bare['fwd'] == 2 * calls['fwd'] and bare['bwd'] == calls['bwd']
+
+
+def _sparse_block(**more):
+    return builder.get_symbol(dict(
+        CFG, num_hidden_layers=1, first_k_dense_replace=0, experts_held=4,
+        **more))
+
+
+@pytest.mark.parametrize('path', ['kernel'], indirect=True)
+@pytest.mark.parametrize('q_lora_rank', [None, 48])
+def test_the_second_forward_leaves_out_what_the_latent_block_named(
+        path, q_lora_rank, monkeypatch):
+    """The queries (their projection, the per-head split and the rotary
+    turn behind them), the rotary key head, the two down-projections and
+    the output projection are kept; ``k_nope`` and ``value`` are not: the
+    second forward expands them from the latent again (``attn_kv_b``), and
+    nothing else of the attention sublayer multiplies. The sigmoid
+    router's top-k and gather and the plan run once."""
+    step, wrt = _training_step(_sparse_block(q_lora_rank=q_lora_rank),
+                               **LM_IN)
+    text = str(jax.make_jaxpr(step)(wrt))
+    names = set(re.findall(r'name\[name=(attention_latent_\w+)\]', text))
+    assert names == {'attention_latent_' + n for n in (
+        'q_nope', 'q_rope', 'k_rope', 'out', 'lse')}
+    again = cases._computed_again
+    assert again(step, wrt, 'dot_general', 'attn_q', 'attn_q_a', 'attn_q_b',
+                 'attn_kv_a', 'attn_kv_b', 'attn_o') == {'layer0_attn_kv_b'}
+    for prim in ('top_k', 'gather', 'cumsum', 'scatter', 'concatenate'):
+        assert not again(step, wrt, prim), prim
+    cases._bare_checkpoint(monkeypatch)
+    step, wrt = _training_step(_sparse_block(q_lora_rank=q_lora_rank),
+                               **LM_IN)
+    assert len(again(step, wrt, 'dot_general', 'attn_q', 'attn_q_a',
+                     'attn_q_b', 'attn_kv_a', 'attn_kv_b', 'attn_o')) \
+        == (5 if q_lora_rank else 4)
+    for prim in ('top_k', 'gather', 'cumsum', 'scatter'):
+        assert again(step, wrt, prim), prim
+
+
+@pytest.mark.parametrize('path', ['kernel'], indirect=True)
+def test_a_mirrored_latent_blocks_gradients_are_the_bare_checkpoints(
+        path, monkeypatch):
+    """The kept values are the ones a recomputation makes: loss and every
+    gradient of a block with the low-rank query path and the expert layer
+    bit-equal to a bare ``jax.checkpoint`` of the same block."""
+    sym = _sparse_block(q_lora_rank=48)
+    step, wrt = _training_step(sym, **LM_IN)
+    outs, grads = jax.jit(step)(wrt)
+    cases._bare_checkpoint(monkeypatch)
+    step, wrt = _training_step(sym, **LM_IN)
+    for a, b in zip(outs + grads, sum(jax.jit(step)(wrt), ())):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the selection bias alone takes no gradient
+    assert sum(np.abs(np.asarray(g)).max() > 0 for g in grads) \
+        == len(grads) - 1
 
 
 # -- Module.fit ---------------------------------------------------------------------------
@@ -472,15 +543,17 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # sha256 of the lowered text of one training step of the Laguna builder's
 # symbol at test_transformer_ops.CFG's sizes, on the CPU, on each path: what
 # a change to the other family's ops must leave as it is. Taken on the tree
-# of PR 33, which changed Laguna's own step by intent (the expert layer
-# walks its sorted rows in passes); before that they were those of the
-# commit before this family came (faf5f29). The text is this jax's; a change
-# of jax (or of Laguna's own ops) needs them taken again.
+# of PR 41, which changed what a mirrored stage of either family keeps (the
+# policy, not the mathematics: loss and gradients are bit-equal to a bare
+# checkpoint's, above and in test_transformer_ops.py); before that they were
+# PR 33's, which changed Laguna's own step by intent, and before that those
+# of the commit before this family came (faf5f29). The text is this jax's; a
+# change of jax (or of Laguna's own ops) needs them taken again.
 LAGUNA_TEXT = {
     'plain':
-    '96279909c65564df3a01041f79d6454f6965bcf790a16b9ba6aff482a6fb16e2',
+    'abf041a63246c6055f6eb9d8c7372830fd1bbb4552e1d8f08c4cc6079d283e26',
     'kernel':
-    '7ee68cc1722ac63d69b51677205adcacbfbe4c99ac7ab99253bf7fedb62d9899'}
+    '993e6341b8fad791c0aa386af845eedf0426d27d7b93746f003e98c466f04a01'}
 
 
 def laguna_step_digest():

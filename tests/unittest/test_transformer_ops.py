@@ -472,10 +472,12 @@ def test_mirrored_blocks_are_stages_of_their_own():
 
 # -- what a mirrored stage keeps ----------------------------------------------
 
-def _one_block(kind, **kw):
-    """The model cut to one block of `kind` attention and a dense MLP."""
+def _one_block(kind, mlp='dense', **kw):
+    """The model cut to one block of `kind` attention and a dense MLP (or
+    a 'sparse' one: the expert layer, four experts held)."""
     cfg = dict(CFG, num_hidden_layers=1, layer_types=[kind],
-               mlp_layer_types=['dense'], num_attention_heads_per_layer=[6])
+               mlp_layer_types=[mlp], num_attention_heads_per_layer=[6],
+               experts_held=4)
     return builder.get_symbol(cfg, **kw), cfg
 
 
@@ -530,6 +532,44 @@ def _one_backward_kernel(calls):
         calls
 
 
+def _subjaxprs(eqn):
+    for v in eqn.params.values():
+        for u in v if isinstance(v, (tuple, list)) else (v,):
+            u = getattr(u, 'jaxpr', u)
+            if hasattr(u, 'eqns'):
+                yield u
+
+
+def _second_forward(jaxpr, scope=None, inside=False, out=None):
+    """{(primitive, scopes below the stage)} of the equations that the
+    backward pass of a mirrored stage computes again: those of the
+    checkpoint equations of a gradient's jaxpr that carry the recomputed
+    forward's name stack, and what lies below them."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        here = None
+        if scope is not None:
+            here = (scope + '/' + stack).strip('/')
+        elif inside and 'rematted_computation' in stack:
+            here = stack.split('rematted_computation', 1)[1].strip(')/')
+        if here is not None:
+            out.add((eqn.primitive.name, here))
+        for sub in _subjaxprs(eqn):
+            _second_forward(sub, here,
+                            inside or eqn.primitive.name == 'remat2', out)
+    return out
+
+
+def _computed_again(step, wrt, prim, *scopes):
+    """The scopes among `scopes` (all there are, if none is given) under
+    which the second forward of `step`'s gradient holds a `prim`."""
+    found = {scope for p, scope in
+             _second_forward(jax.make_jaxpr(step)(wrt).jaxpr) if p == prim}
+    return {s for s in found if not scopes
+            or any(s.split('/')[0].endswith(w) for w in scopes)}
+
+
 def _bare_checkpoint(monkeypatch):
     """The stage as it was before an op could name a value."""
     monkeypatch.setattr(registry, 'mirrored',
@@ -556,8 +596,10 @@ def _forward_kernel_runs_once(kind, monkeypatch):
 
 def _gradients_are_the_bare_checkpoints(kind, monkeypatch):
     """(b) the kept values are the ones a recomputation makes: loss and
-    every gradient bit-equal."""
-    sym = _one_block(KINDS[kind][0])[0]
+    every gradient bit-equal. (The windowed block has the expert layer:
+    its routing and its plan are kept too.)"""
+    sym = _one_block(KINDS[kind][0],
+                     'sparse' if kind == 'window' else 'dense')[0]
     step, wrt = _training_step(sym, **LM_IN)
     outs, grads = jax.jit(step)(wrt)
     _bare_checkpoint(monkeypatch)
@@ -587,7 +629,8 @@ def _no_stage_lowers_as_before(which, monkeypatch):
     assert 'checkpoint' not in jaxpr and 'remat' not in jaxpr
     assert ('name[' in jaxpr) == (which != 'resnet_unit')
     text = jax.jit(step).lower(wrt).as_text()
-    monkeypatch.setattr(pk, 'dear', lambda x, name: x)
+    for module in (pk, mx.ops.transformer, mx.ops.nn):
+        monkeypatch.setattr(module, 'dear', lambda x, name: x)
     step, wrt = _training_step(sym, **shapes)
     assert 'name[' not in str(jax.make_jaxpr(step)(wrt))
     unnamed = jax.jit(step).lower(wrt).as_text()
@@ -636,22 +679,65 @@ def _the_whole_forward_mirror_keeps_them_too(kind, monkeypatch):
     _one_backward_kernel(calls)
 
 
-def _the_gauge_is_the_bytes_of_out_and_lse(_, monkeypatch):
-    """(e) executor.mirror_kept_bytes: each block's attention output in the
-    model's dtype and its log-sum-exp in float32, from the shapes."""
+PROJECTIONS = ('attn_q', 'attn_k', 'attn_v', 'attn_g', 'attn_o')
+
+
+def _the_second_forward_leaves_out_what_was_named(kind, monkeypatch):
+    """What the three rules name is not made a second time: no projection
+    of the attention sublayer, no rotary turn of a query or key (the
+    angles' table alone), no top-k, and none of the plan's scans, scatters
+    and searches; the router's product, the shared expert and the experts
+    are. Under a bare checkpoint all of it is there twice."""
+    kind, name = KINDS[kind]
+    step, wrt = _training_step(_one_block(kind, 'sparse')[0], **LM_IN)
+    assert not _computed_again(step, wrt, 'dot_general', *PROJECTIONS)
+    assert _computed_again(step, wrt, 'dot_general') == {
+        'layer0_moe/router', 'layer0_moe/shared'}
+    for prim in ('top_k', 'cumsum', 'scatter', 'sort', 'concatenate'):
+        assert not _computed_again(step, wrt, prim), prim
+    assert _computed_again(step, wrt, 'cos') == {
+        'layer0_attn_q_rope', 'layer0_attn_k_rope'}
+    _bare_checkpoint(monkeypatch)
+    step, wrt = _training_step(_one_block(kind, 'sparse')[0], **LM_IN)
+    assert _computed_again(step, wrt, 'dot_general', *PROJECTIONS) == {
+        'layer0_' + p for p in PROJECTIONS}
+    for prim in ('top_k', 'cumsum', 'scatter', 'concatenate'):
+        assert _computed_again(step, wrt, prim), prim
+
+
+def _the_gauges_count_each_named_array_once(_, monkeypatch):
+    """(e) executor.mirror_kept and _bytes of a dense and a sparse block:
+    the arrays the ops named, from the shapes, the value projection that is
+    also the kernel's value once."""
     monkeypatch.setenv('MXTPU_TELEMETRY', '1')
     monkeypatch.setenv('MXTPU_TELEMETRY_PATH', os.devnull)
     _reload_telemetry()
     try:
-        step, wrt = _training_step(builder.get_symbol(CFG), **LM_IN)
+        heads = [4, 6]
+        cfg = dict(CFG, num_hidden_layers=2, layer_types=CFG['layer_types'][:2],
+                   mlp_layer_types=['dense', 'sparse'],
+                   num_attention_heads_per_layer=heads, experts_held=4)
+        step, wrt = _training_step(builder.get_symbol(cfg), **LM_IN)
         jax.make_jaxpr(step)(wrt)
         gauges = telemetry.snapshot()['gauges']
-        heads = CFG['num_attention_heads_per_layer']
-        assert gauges['executor.mirror_kept'] == 2 * len(heads)
+        rows, k = 2 * T, cfg['num_experts_per_tok']
+        named = []      # (elements, bytes each)
+        for H in heads:
+            named += [(rows * H * D, 4)] * 2        # out, q
+            named += [(2 * H * T, 4)]               # lse
+            named += [(rows * KV * D, 4)] * 3       # k, v, k before rotary
+            named += [(rows * H, 4), (rows * d, 4)]     # gate, attn_o
+            if H * D <= d:
+                named += [(rows * H * D, 4)]        # q before rotary
+        R = _buffer_rows(rows)
+        R += -R % mx.ops.transformer._pass_rows(R, rows, k, 4, 16)
+        named += [(rows * k, 4)] * 4        # choice, scores, weights, dest
+        named += [(R, 4), (R // 128, 4), (1, 4)]
+        assert gauges['executor.mirror_kept'] == len(named)
         assert gauges['executor.mirror_kept_bytes'] == sum(
-            2 * T * H * D * 4 + 2 * H * T * 4 for H in heads)
+            n * b for n, b in named)
         # a symbol without a mirrored stage keeps nothing
-        step, wrt = _training_step(builder.get_symbol(CFG, remat=False),
+        step, wrt = _training_step(builder.get_symbol(cfg, remat=False),
                                    **LM_IN)
         jax.make_jaxpr(step)(wrt)
         gauges = telemetry.snapshot()['gauges']
@@ -672,13 +758,15 @@ def _the_gauge_is_the_bytes_of_out_and_lse(_, monkeypatch):
     (_no_stage_lowers_as_before, 'laguna_without_remat'),
     (_a_stage_with_no_name_keeps_nothing, 'resnet_unit'),
     (_the_whole_forward_mirror_keeps_them_too, 'full'),
-    (_the_gauge_is_the_bytes_of_out_and_lse, 'five_blocks'),
+    (_the_gauges_count_each_named_array_once, 'two_blocks'),
+    (_the_second_forward_leaves_out_what_was_named, 'full'),
 ], ids=lambda v: v if isinstance(v, str) else v.__name__.strip('_'))
 def test_a_mirrored_stage_keeps_what_an_op_named_as_dear(
         path, case, arg, monkeypatch):
     """A mirrored stage recomputes its block in the backward pass except
-    the values an op named as dear (``registry.dear``: the attention
-    kernel's output and log-sum-exp), on the kernels' path."""
+    the values an op named as dear (``registry.dear``: what the attention
+    kernel's backward pass reads, a contracting projection's output, the
+    expert layer's routing and plan), on the kernels' path."""
     case(arg, monkeypatch)
 
 
