@@ -375,6 +375,17 @@ inline Symbol GatedMLP(const std::string &symbol_name,
   return op.CreateSymbol(symbol_name);
 }
 
+inline Symbol GatedShortConv(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &weight,
+    int kernel = 3) {
+  Operator op("GatedShortConv");
+  op.SetInput("data", data);
+  op.SetInput("weight", weight);
+  op.SetParam("kernel", detail::ToParam(kernel));
+  return op.CreateSymbol(symbol_name);
+}
+
 inline Symbol GridGenerator(const std::string &symbol_name,
     const Symbol &data,
     const std::string &transform_type = "affine",
@@ -634,7 +645,8 @@ inline Symbol MoE(const std::string &symbol_name,
     mx_float routed_scaling = 1.0f,
     int hidden = 0,
     int shared_hidden = 0,
-    const std::string &scoring = "softmax") {
+    const std::string &scoring = "softmax",
+    mx_float norm_eps = 1e-20f) {
   Operator op("MoE");
   op.SetInput("data", data);
   op.SetInput("router_weight", router_weight);
@@ -655,6 +667,7 @@ inline Symbol MoE(const std::string &symbol_name,
   op.SetParam("hidden", detail::ToParam(hidden));
   op.SetParam("shared_hidden", detail::ToParam(shared_hidden));
   if (!scoring.empty()) op.SetParam("scoring", detail::ToParam(scoring));
+  op.SetParam("norm_eps", detail::ToParam(norm_eps));
   return op.CreateSymbol(symbol_name);
 }
 

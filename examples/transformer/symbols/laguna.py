@@ -19,7 +19,12 @@ MLP, and ``num_experts`` routed experts of which this program holds
 the shared expert. The ops are ``mxnet_tpu/ops/transformer.py``; the plain
 reference that the tests and the benchmark compare with is
 ``benchmark/reference/laguna.py``, which also lists what the config leaves
-open.
+open. This family has a shared expert in every sparse layer and heads of
+128 columns; neither is the ops' limit: ``MoE`` with ``shared_hidden`` 0
+has no shared expert and no inputs for one, and heads narrower than 128
+lanes are handled below ``GroupedQueryAttention``, where the compiled
+attention kernels cross them as ``[B, H, T, D]``
+(``ops/pallas_kernels.py``; ``lfm2_moe.py`` beside this file builds both).
 
 ``data`` and ``softmax_label`` are ``(batch, seq_len)`` token ids; the one
 output is ``(batch * seq_len, vocab_size)`` probabilities, which is what

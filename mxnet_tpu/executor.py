@@ -169,7 +169,7 @@ class _GraphProgram:
             for i, o in enumerate(outs):
                 env[_entry_key(node, i)] = o
             # collect aux updates (BatchNorm moving stats)
-            for in_idx, out_idx in op.mutate_inputs.items():
+            for in_idx, out_idx in op.mutated(node.attrs).items():
                 if in_idx < len(node.inputs):
                     src, _ = node.inputs[in_idx]
                     if src.is_variable() and src.name in aux_index:
@@ -691,7 +691,7 @@ class Executor:
                               '%s_output%d' % (node.name, i),
                               from_jax(outs[i], self._ctx))
         if new_aux is not None:
-            for in_idx, out_idx in op.mutate_inputs.items():
+            for in_idx, out_idx in op.mutated(node.attrs).items():
                 if in_idx < len(node.inputs):
                     src, _ = node.inputs[in_idx]
                     if src.is_variable() and src.name in self.aux_dict:

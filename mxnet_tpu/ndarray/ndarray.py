@@ -583,7 +583,7 @@ def _invoke_impl(op_name, inputs, attrs=None, out=None):
 
     # write mutated aux outputs back into their input NDArrays
     # (reference: FMutateInputs / aux states, op_attr_types.h)
-    for in_idx, out_idx in op.mutate_inputs.items():
+    for in_idx, out_idx in op.mutated(attrs).items():
         if out_idx < len(outs_t):
             inputs[in_idx]._data = outs_t[out_idx]
 

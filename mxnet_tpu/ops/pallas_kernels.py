@@ -29,6 +29,7 @@ Attention has kernels in both directions (``attention_forward``,
 ``flash_attention`` / ``flash_attention_lse`` take the same backward.
 """
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -898,6 +899,64 @@ def _vmem(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
+# A head's D columns of [B, T, H * D] are a legal block on the chip only
+# where D is a multiple of 128 lanes. Narrower heads (64) cross the compiled
+# kernels as [B, H, T, D], where D is the whole minor axis: a transpose each
+# way around the call, the kernels and their grids as they are. The
+# interpreter has no such rule and keeps the projections' layout (and the
+# text it lowered to) unless a test sets this.
+_BY_HEAD_INTERPRETED = False
+
+
+def _crosses_by_head(D, interpret):
+    return D % 128 != 0 and (not interpret or _BY_HEAD_INTERPRETED)
+
+
+def _head_blocks(D, interpret):
+    """(whether heads of D columns cross by head in this build, spec): with
+    ``spec(rows, index)`` the block of `rows` rows of one head, `index`
+    giving (batch, row block, head) from the grid's indices."""
+    by_head = _crosses_by_head(D, interpret)
+
+    def spec(rows, index):
+        if not by_head:
+            return pl.BlockSpec((1, rows, D), index)
+
+        def head_first(*grid):
+            b, r, h = index(*grid)
+            return b, h, r, 0
+
+        return pl.BlockSpec((1, None, rows, D), head_first)
+
+    return by_head, spec
+
+
+def _from_heads(x):
+    """[B, H, T, D] as [B, T, H * D]."""
+    B, H, T, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
+def _head_layout(call, by_head, heads, n_in, n_out):
+    """`call` on operands [B, T, H * D], whose first `n_in` it reads, and
+    whose first `n_out` results it writes, as [B, H, T, D] (`heads`: the
+    heads of each such operand and result)."""
+    if not by_head:
+        return call
+
+    def crossed(*operands):
+        with jax.named_scope('heads'):
+            operands = [_by_head(x, h) for x, h in zip(operands, heads)] \
+                + list(operands[n_in:])
+        results = call(*operands)
+        with jax.named_scope('heads'):
+            return type(results)(
+                _from_heads(x) if i < n_out else x
+                for i, x in enumerate(results))
+
+    return crossed
+
+
 def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
                       scale=None, block_q=512, block_k=512,
                       name='attention'):
@@ -921,22 +980,28 @@ def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
         return b, _imin(lo + s, hi), h // group
 
     kernel = functools.partial(_attn_fwd_kernel, geo=geo, scale=scale)
-    out, lse = run_kernel(lambda interpret: pl.pallas_call(
-        kernel,
-        grid=(B, heads, nq, steps),
-        in_specs=[pl.BlockSpec((1, blk_q, D), lambda b, h, i, s: (b, i, h)),
-                  pl.BlockSpec((1, blk_k, D), kv_index),
-                  pl.BlockSpec((1, blk_k, D), kv_index)],
-        out_specs=[pl.BlockSpec((1, blk_q, D), lambda b, h, i, s: (b, i, h)),
-                   pl.BlockSpec((1, 1, blk_q, 1),
-                                lambda b, h, i, s: (b, h, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, heads, Tq + pad_q, 1),
-                                        jnp.float32)],
-        scratch_shapes=[_vmem((blk_q, 1)), _vmem((blk_q, 1)),
-                        _vmem((blk_q, D))],
-        compiler_params=_attn_params(3),
-        interpret=interpret, name=name + '_fwd'), q, k, v)
+
+    def build(interpret):
+        by_head, spec = _head_blocks(D, interpret)
+        q_spec = spec(blk_q, lambda b, h, i, s: (b, i, h))
+        return _head_layout(pl.pallas_call(
+            kernel,
+            grid=(B, heads, nq, steps),
+            in_specs=[q_spec, spec(blk_k, kv_index), spec(blk_k, kv_index)],
+            out_specs=[q_spec,
+                       pl.BlockSpec((1, 1, blk_q, 1),
+                                    lambda b, h, i, s: (b, h, i, 0))],
+            out_shape=[jax.ShapeDtypeStruct(
+                (B, heads, Tq + pad_q, D) if by_head else q.shape, q.dtype),
+                jax.ShapeDtypeStruct((B, heads, Tq + pad_q, 1),
+                                     jnp.float32)],
+            scratch_shapes=[_vmem((blk_q, 1)), _vmem((blk_q, 1)),
+                            _vmem((blk_q, D))],
+            compiler_params=_attn_params(3),
+            interpret=interpret, name=name + '_fwd'),
+            by_head, (heads, kv_heads, kv_heads), 3, 1)
+
+    out, lse = run_kernel(build, q, k, v)
     with jax.named_scope('heads'):
         return out[:, :Tq], lse[:, :, :Tq, 0]
 
@@ -967,32 +1032,40 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
     base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
     vmem = _bwd_vmem(Tk + pad_k, (D, D), k.dtype)
+    crossing = ((heads, kv_heads, kv_heads, heads), 4)
+
+    def shapes(by_head, *like):
+        return [jax.ShapeDtypeStruct(
+            (B, x.shape[2] // D, x.shape[1], D) if by_head else x.shape,
+            x.dtype) for x in like]
+
     if vmem is not None:
         # grid (batch, key/value head g, its m-th query head, i, s)
         k_block = _walked(keys_of)
-        q_spec = pl.BlockSpec((1, blk_q, D),
-                              lambda b, g, m, i, s: (b, i, g * group + m))
-        k_spec = pl.BlockSpec((1, blk_k, D),
-                              lambda b, g, m, i, s: (b, k_block(i, s), g))
         row_spec = pl.BlockSpec((1, 1, 1, 1, blk_q), lambda b, g, m, i, s: (
             b, g * group + m, i, 0, 0))
-        whole = pl.BlockSpec((1, Tk + pad_k, D),
-                             lambda b, g, m, i, s: (b, 0, g))
-        dq, dk, dv = run_kernel(lambda interpret: pl.pallas_call(
-            functools.partial(_attn_bwd_kernel, geo=base + (keys_of, ksteps),
-                              scale=scale),
-            grid=(B, kv_heads, group, nq, ksteps),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=[q_spec, whole, whole],
-            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                       jax.ShapeDtypeStruct(k.shape, k.dtype),
-                       jax.ShapeDtypeStruct(v.shape, v.dtype)],
-            scratch_shapes=[_vmem((blk_q, D)), _vmem((Tk + pad_k, D)),
-                            _vmem((Tk + pad_k, D))],
-            compiler_params=_attn_params(2, 3, vmem),
-            interpret=interpret, name=name + '_bwd'),
-            q, k, v, g_out, _rows(lse, pad_q, blk_q),
-            _rows(delta, pad_q, blk_q))
+
+        def build(interpret):
+            by_head, spec = _head_blocks(D, interpret)
+            q_spec = spec(blk_q, lambda b, g, m, i, s: (b, i, g * group + m))
+            k_spec = spec(blk_k, lambda b, g, m, i, s: (b, k_block(i, s), g))
+            whole = spec(Tk + pad_k, lambda b, g, m, i, s: (b, 0, g))
+            return _head_layout(pl.pallas_call(
+                functools.partial(_attn_bwd_kernel,
+                                  geo=base + (keys_of, ksteps), scale=scale),
+                grid=(B, kv_heads, group, nq, ksteps),
+                in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+                out_specs=[q_spec, whole, whole],
+                out_shape=shapes(by_head, q, k, v),
+                scratch_shapes=[_vmem((blk_q, D)), _vmem((Tk + pad_k, D)),
+                                _vmem((Tk + pad_k, D))],
+                compiler_params=_attn_params(2, 3, vmem),
+                interpret=interpret, name=name + '_bwd'),
+                by_head, *crossing, 3)
+
+        dq, dk, dv = run_kernel(build, q, k, v, g_out,
+                                _rows(lse, pad_q, blk_q),
+                                _rows(delta, pad_q, blk_q))
         with jax.named_scope('heads'):
             return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
     lse, delta = _cols(lse, pad_q), _cols(delta, pad_q)
@@ -1001,21 +1074,25 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         lo, hi = keys_of(i)
         return b, _imin(lo + s, hi), h // group
 
-    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, h, i, s: (b, i, h))
     col_spec = pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, i, s: (b, h, i, 0))
-    dq = run_kernel(lambda interpret: pl.pallas_call(
-        functools.partial(_attn_dq_kernel, geo=base + (keys_of, ksteps),
-                          scale=scale),
-        grid=(B, heads, nq, ksteps),
-        in_specs=[q_spec, pl.BlockSpec((1, blk_k, D), kv_index),
-                  pl.BlockSpec((1, blk_k, D), kv_index), q_spec, col_spec,
-                  col_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[_vmem((blk_q, D))],
-        compiler_params=_attn_params(3),
-        interpret=interpret, name=name + '_dq'),
-        q, k, v, g_out, lse, delta)
+
+    def build_dq(interpret):
+        by_head, spec = _head_blocks(D, interpret)
+        q_spec = spec(blk_q, lambda b, h, i, s: (b, i, h))
+        return _head_layout(pl.pallas_call(
+            functools.partial(_attn_dq_kernel, geo=base + (keys_of, ksteps),
+                              scale=scale),
+            grid=(B, heads, nq, ksteps),
+            in_specs=[q_spec, spec(blk_k, kv_index), spec(blk_k, kv_index),
+                      q_spec, col_spec, col_spec],
+            out_specs=[q_spec],
+            out_shape=shapes(by_head, q),
+            scratch_shapes=[_vmem((blk_q, D))],
+            compiler_params=_attn_params(3),
+            interpret=interpret, name=name + '_dq'),
+            by_head, *crossing, 1)
+
+    dq, = run_kernel(build_dq, q, k, v, g_out, lse, delta)
 
     def q_block(j, s):
         lo, hi = queries_of(j)
@@ -1027,22 +1104,26 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
     def qcol_index(b, g, j, s):
         return b, g * group + s // qsteps, q_block(j, s), 0
 
-    k_spec = pl.BlockSpec((1, blk_k, D), lambda b, g, j, s: (b, j, g))
-    qw_spec = pl.BlockSpec((1, blk_q, D), q_index)
     qcol_spec = pl.BlockSpec((1, 1, blk_q, 1), qcol_index)
-    dk, dv = run_kernel(lambda interpret: pl.pallas_call(
-        functools.partial(_attn_dkv_kernel,
-                          geo=base + (queries_of, qsteps), scale=scale,
-                          group=group),
-        grid=(B, kv_heads, nk, group * qsteps),
-        in_specs=[qw_spec, k_spec, k_spec, qw_spec, qcol_spec, qcol_spec],
-        out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[_vmem((blk_k, D)), _vmem((blk_k, D))],
-        compiler_params=_attn_params(3),
-        interpret=interpret, name=name + '_dkv'),
-        q, k, v, g_out, lse, delta)
+
+    def build_dkv(interpret):
+        by_head, spec = _head_blocks(D, interpret)
+        k_spec = spec(blk_k, lambda b, g, j, s: (b, j, g))
+        qw_spec = spec(blk_q, q_index)
+        return _head_layout(pl.pallas_call(
+            functools.partial(_attn_dkv_kernel,
+                              geo=base + (queries_of, qsteps), scale=scale,
+                              group=group),
+            grid=(B, kv_heads, nk, group * qsteps),
+            in_specs=[qw_spec, k_spec, k_spec, qw_spec, qcol_spec, qcol_spec],
+            out_specs=[k_spec, k_spec],
+            out_shape=shapes(by_head, k, v),
+            scratch_shapes=[_vmem((blk_k, D)), _vmem((blk_k, D))],
+            compiler_params=_attn_params(3),
+            interpret=interpret, name=name + '_dkv'),
+            by_head, *crossing, 2)
+
+    dk, dv = run_kernel(build_dkv, q, k, v, g_out, lse, delta)
     with jax.named_scope('heads'):
         return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
 
@@ -1839,3 +1920,200 @@ def _hyper_post_bwd(n, res, g):
 
 
 hyper_post.defvjp(_hyper_post_fwd, _hyper_post_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Gated short convolution: y = C * conv(B * x), causal, depthwise, L taps
+# ---------------------------------------------------------------------------
+# The operand is a projection's output [B, T, 3 C], its thirds B, C and x in
+# this order; the taps come as rows, [SHORT_CONV_ROWS, C] float32 (row j is
+# tap j, which weighs u_{t - (L - 1 - j)}; rows from L on are 0). A grid
+# step takes a block of rows at its whole width, so every array crosses
+# once a pass; the L - 1 rows of u = B * x before the block (and, backward,
+# the rows of dc = dy * C after it) come with one more block of 8 rows of
+# the same arrays, 3% of a block of 256. Inside, the columns are walked in
+# chunks, in float32, and written back in the operands' precision: no
+# float32 [T, C] array exists.
+
+SHORT_CONV_ROWS = 8     # rows of the taps' array and of a halo block
+_SHORT_CONV_BLOCK = 256
+
+
+def _short_conv_chunk(C):
+    return next((c for c in (512, 256, 128) if C % c == 0), C)
+
+
+def _third_cols(third, c0, chunk, C):
+    """Columns [c0, c0 + chunk) of a [.., 3 C] block's `third`."""
+    return slice(third * C + c0, third * C + c0 + chunk)
+
+
+def _third(ref, third, c0, chunk, C):
+    return ref[0, :, _third_cols(third, c0, chunk, C)].astype(jnp.float32)
+
+
+def _moved_down(before, u, k):
+    """Row t holds u_{t-k}, the first k rows `before`'s last."""
+    at = SHORT_CONV_ROWS - k
+    return jnp.concatenate([before, u], axis=0)[at:at + u.shape[0]]
+
+
+def _moved_up(u, after, k):
+    """Row t holds u_{t+k}, the last k rows `after`'s first."""
+    return jnp.concatenate([u, after], axis=0)[k:k + u.shape[0]]
+
+
+def _short_conv_fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, C, L, chunk):
+    first = pl.program_id(1) == 0
+    for c0 in range(0, C, chunk):
+        part = lambda ref, third: _third(ref, third, c0, chunk, C)  # noqa
+        u = part(x_ref, 0) * part(x_ref, 2)
+        before = jnp.where(first, 0.0, part(prev_ref, 0) * part(prev_ref, 2))
+        w = w_ref[:, c0:c0 + chunk]
+        c = w[L - 1:L] * u
+        for k in range(1, L):
+            c += w[L - 1 - k:L - k] * _moved_down(before, u, k)
+        o_ref[0, :, c0:c0 + chunk] = (part(x_ref, 1) * c).astype(o_ref.dtype)
+
+
+def _short_conv_bwd_kernel(x_ref, prev_ref, next_ref, g_ref, gnext_ref, w_ref,
+                           dx_ref, dw_ref, *, C, L, chunk):
+    b, i = pl.program_id(0), pl.program_id(1)
+    first, last = i == 0, i == pl.num_programs(1) - 1
+
+    @pl.when((b == 0) & first)
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.float32)
+
+    for c0 in range(0, C, chunk):
+        part = lambda ref, third: _third(ref, third, c0, chunk, C)  # noqa
+        cols = lambda third: _third_cols(third, c0, chunk, C)  # noqa: E731
+        xb, xc, xx = part(x_ref, 0), part(x_ref, 1), part(x_ref, 2)
+        u = xb * xx
+        before = jnp.where(first, 0.0, part(prev_ref, 0) * part(prev_ref, 2))
+        dy = g_ref[0, :, c0:c0 + chunk].astype(jnp.float32)
+        dc = dy * xc
+        after = jnp.where(
+            last, 0.0, gnext_ref[0, :, c0:c0 + chunk].astype(jnp.float32)
+            * part(next_ref, 1))
+        w = w_ref[:, c0:c0 + chunk]
+        c, du = w[L - 1:L] * u, w[L - 1:L] * dc
+        dw_ref[L - 1:L, c0:c0 + chunk] += jnp.sum(dc * u, axis=0,
+                                                  keepdims=True)
+        for k in range(1, L):
+            u_k = _moved_down(before, u, k)
+            c += w[L - 1 - k:L - k] * u_k
+            du += w[L - 1 - k:L - k] * _moved_up(dc, after, k)
+            dw_ref[L - 1 - k:L - k, c0:c0 + chunk] += jnp.sum(
+                dc * u_k, axis=0, keepdims=True)
+        dx_ref[0, :, cols(0)] = (du * xx).astype(dx_ref.dtype)
+        dx_ref[0, :, cols(1)] = (dy * c).astype(dx_ref.dtype)
+        dx_ref[0, :, cols(2)] = (du * xb).astype(dx_ref.dtype)
+
+
+def _short_conv_rows(T):
+    """(block, pad) of the row axis: whole blocks of SHORT_CONV_ROWS's
+    multiple; rows added at the end are zeros that nothing reads back."""
+    blk = min(_SHORT_CONV_BLOCK, -(-T // SHORT_CONV_ROWS) * SHORT_CONV_ROWS)
+    return blk, -T % blk
+
+
+def _short_conv_setup(bcx, w, name):
+    B, T, C3 = bcx.shape
+    C, L = w.shape
+    if C3 != 3 * C or not 1 <= L <= SHORT_CONV_ROWS:
+        raise ValueError('%s: operand %s against taps %s'
+                         % (name, tuple(bcx.shape), tuple(w.shape)))
+    blk, pad = _short_conv_rows(T)
+    halo = blk // SHORT_CONV_ROWS           # halo blocks a row block
+    n_halo = (T + pad) // SHORT_CONV_ROWS
+
+    def rows(width):
+        return pl.BlockSpec((1, blk, width), lambda b, i: (b, i, 0))
+
+    def before(width):
+        return pl.BlockSpec((1, SHORT_CONV_ROWS, width), lambda b, i: (
+            b, jnp.maximum(i * halo - 1, 0), 0))
+
+    def after(width):
+        return pl.BlockSpec((1, SHORT_CONV_ROWS, width), lambda b, i: (
+            b, jnp.minimum((i + 1) * halo, n_halo - 1), 0))
+
+    taps = jnp.pad(w.astype(jnp.float32).T, ((0, SHORT_CONV_ROWS - L), (0, 0)))
+    whole = pl.BlockSpec((SHORT_CONV_ROWS, C), lambda b, i: (0, 0))
+    # a lane offset of C or 2 C into a block has to be a whole tile's
+    too_big = None if C % 128 == 0 else (
+        '%s: %d channels (operand %s %s) are no multiple of 128 lanes'
+        % (name, C, tuple(bcx.shape), bcx.dtype.name))
+    return types.SimpleNamespace(
+        x=_pad_rows(bcx, pad), taps=taps, grid=(B, (T + pad) // blk),
+        rows=rows, before=before, after=after, whole=whole,
+        too_big=too_big)
+
+
+def _short_conv_params():
+    from jax.experimental.pallas import tpu as pltpu
+    # blocks of [256, 3 C] in flight twice over, in and out
+    return pltpu.CompilerParams(
+        dimension_semantics=('arbitrary', 'arbitrary'),
+        vmem_limit_bytes=64 << 20)
+
+
+def short_conv_forward(bcx, w, name='short_conv'):
+    """y [B, T, C] = C * (sum_j w[:, j] u_{t - (L - 1 - j)}) with u = B * x
+    (zero before the sequence's start) for bcx [B, T, 3 C] = [B | C | x] and
+    taps w [C, L]. The kernel is named ``<name>_fwd`` in a device trace."""
+    B, T, _ = bcx.shape
+    C, L = w.shape
+    cut = _short_conv_setup(bcx, w, name)
+    out = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_short_conv_fwd_kernel, C=C, L=L,
+                          chunk=_short_conv_chunk(C)),
+        grid=cut.grid,
+        in_specs=[cut.rows(3 * C), cut.before(3 * C), cut.whole],
+        out_specs=cut.rows(C),
+        out_shape=jax.ShapeDtypeStruct((B, cut.x.shape[1], C), bcx.dtype),
+        compiler_params=_short_conv_params(), interpret=interpret,
+        name=name + '_fwd'), cut.x, cut.x, cut.taps, too_big=cut.too_big)
+    return out[:, :T]
+
+
+def short_conv_backward(bcx, w, g, name='short_conv'):
+    """(d_bcx [B, T, 3 C], dw [C, L] float32) of :func:`short_conv_forward`
+    from the output's cotangent g [B, T, C]. ``<name>_bwd``."""
+    B, T, _ = bcx.shape
+    C, L = w.shape
+    cut = _short_conv_setup(bcx, w, name)
+    g = _pad_rows(g, cut.x.shape[1] - T)
+    dx, dw = run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_short_conv_bwd_kernel, C=C, L=L,
+                          chunk=_short_conv_chunk(C)),
+        grid=cut.grid,
+        in_specs=[cut.rows(3 * C), cut.before(3 * C), cut.after(3 * C),
+                  cut.rows(C), cut.after(C), cut.whole],
+        out_specs=[cut.rows(3 * C), cut.whole],
+        out_shape=[jax.ShapeDtypeStruct(cut.x.shape, bcx.dtype),
+                   jax.ShapeDtypeStruct((SHORT_CONV_ROWS, C), jnp.float32)],
+        compiler_params=_short_conv_params(), interpret=interpret,
+        name=name + '_bwd'), cut.x, cut.x, cut.x, g, g, cut.taps,
+        too_big=cut.too_big)
+    return dx[:, :T], dw[:L].T
+
+
+@jax.custom_vjp
+def short_conv(bcx, w):
+    """The gated short convolution by the two kernels above."""
+    return short_conv_forward(bcx, w)
+
+
+def _short_conv_fwd(bcx, w):
+    return short_conv_forward(bcx, w), (bcx, w)
+
+
+def _short_conv_bwd(res, g):
+    bcx, w = res
+    dx, dw = short_conv_backward(bcx, w, g)
+    return dx, dw.astype(w.dtype)
+
+
+short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)

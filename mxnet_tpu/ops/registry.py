@@ -99,6 +99,23 @@ class OpDef:
                      or _on(self.optional_inputs[n])]
         return names
 
+    def names_present(self, attrs=None):
+        """The declared name of each input of a node with `attrs`, by
+        place: ``input_names`` less the optional inputs that are off."""
+        return self.arg_names(attrs) if self.optional_inputs \
+            else self.input_names
+
+    def mutated(self, attrs=None):
+        """``mutate_inputs`` by an input's place among the inputs present
+        (its keys count every declared input: an optional input that is
+        absent moves the ones after it up)."""
+        if not (self.optional_inputs and self.mutate_inputs):
+            return self.mutate_inputs
+        present = self.names_present(attrs)
+        return {present.index(self.input_names[i]): out
+                for i, out in self.mutate_inputs.items()
+                if self.input_names[i] in present}
+
 
 def register(name, **kwargs):
     """Decorator registering ``fn(attrs, *arrays)`` as operator ``name``."""
@@ -191,6 +208,11 @@ if _COVERING:
 #   - ``MoE`` names its routing and its plan: a few small vectors behind
 #     a top-k, a gather and a sort's worth of scans and scatters.
 # An MLP's hidden activations stay recomputed: large, and one product deep.
+# So does a gated short convolution with the projection that feeds it: the
+# projection expands (its output, the op's operand, is three times what it
+# was made from), and the op behind it is one pass over those bytes; the
+# projection that reads the op's result keeps its own output by the second
+# rule (as wide as its input), so `GatedShortConv` names nothing.
 
 _DEAR = set()                   # every name `dear` was given
 _MIRROR = threading.local()     # .kept: the list of the stage being traced

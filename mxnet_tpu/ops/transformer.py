@@ -1,7 +1,8 @@
 """Decoder-block ops: RMSNorm, rotary positions, grouped-query attention
 (causal, optionally windowed, with a per-head output gate), latent
 attention in its expanded form (keys wider than values, one rotary key
-head for all heads), the gated MLP and a routed expert layer with a shared
+head for all heads), the gated short convolution of a conv-attention
+hybrid, the gated MLP and a routed expert layer with or without a shared
 expert (softmax router, or sigmoid scores with a selection bias).
 
 Each is a pure JAX function like every op of the registry; gradients come
@@ -16,7 +17,9 @@ RMSNorm statistics, rotary angles, attention's softmax, the gate's
 sigmoid, router logits and router softmax are float32.
 
 Where an op has a kernel (``ops/pallas_kernels.py``: attention forward and
-backward by blocks, the grouped product of the experts), operands on a TPU
+backward by blocks, at any head size: heads narrower than 128 lanes cross
+the compiled kernels as ``[B, H, T, D]``; the grouped product of the
+experts; the short convolution's two passes), operands on a TPU
 take it and others take the plain jnp form, unless MXTPU_FORCE_PALLAS=1
 routes every platform through the kernel (interpreted off the TPU), as for
 the other registry ops.
@@ -424,6 +427,49 @@ def _hyper_post_op(attrs, x, z, coef):
 
 
 # ---------------------------------------------------------------------------
+# Gated short convolution
+# ---------------------------------------------------------------------------
+
+def _short_conv_plain(bcx, w):
+    """pk.short_conv in plain jnp: shifted slices, float32."""
+    C, L = w.shape
+    T = bcx.shape[-2]
+    gate_in, gate_out, x = (bcx[..., i * C:(i + 1) * C].astype(jnp.float32)
+                            for i in range(3))
+    u = gate_in * x
+    w32 = w.astype(jnp.float32)
+    c = w32[:, L - 1] * u
+    for k in range(1, L):       # tap L - 1 - k weighs u_{t-k}
+        c = c + w32[:, L - 1 - k] * jnp.pad(
+            u, ((0, 0), (k, 0), (0, 0)))[:, :T]
+    return (gate_out * c).astype(bcx.dtype)
+
+
+@register('GatedShortConv', input_names=['data', 'weight'],
+          param_defaults={'kernel': 3})
+def _gated_short_conv(attrs, bcx, w):
+    """The gated short convolution of a conv-attention hybrid's operator,
+    in the layout its input projection leaves: data [B, T, 3 C] holds the
+    thirds ``[B | C | x]``, weight (C, kernel) the taps of a causal
+    depthwise convolution without bias. With ``u = B * x`` (elementwise,
+    zero before the sequence's start) and ``c_t = sum_j weight[:, j]
+    u_{t - (kernel - 1 - j)}``, returns ``C * c`` [B, T, C]. float32
+    inside, data's dtype out.
+
+    On a TPU the kernels ``short_conv_fwd`` and ``short_conv_bwd`` run it:
+    each reads its arrays once, a block of rows at the whole width, and
+    writes no float32 array; the cotangent of data comes back as one
+    [B, T, 3 C] array and the taps' summed over rows in float32. A
+    mirrored stage keeps nothing of it: data is an expanding projection's
+    output, three times the size of what it was made from, and the
+    output is read by a projection that keeps its own."""
+    if int(attrs.get('kernel', 3)) != w.shape[1]:
+        raise ValueError('GatedShortConv: kernel %s against taps %s'
+                         % (attrs.get('kernel'), tuple(w.shape)))
+    return pk.dispatch(pk.short_conv, _short_conv_plain, bcx, w)
+
+
+# ---------------------------------------------------------------------------
 # Gated MLP
 # ---------------------------------------------------------------------------
 
@@ -450,6 +496,10 @@ def _sigmoid_scoring(attrs):
     return str(attrs.get('scoring', 'softmax')) == 'sigmoid'
 
 
+def _has_shared(attrs):
+    return int(attrs.get('shared_hidden', 0) or 0) > 0
+
+
 # what the layer writes into its ``stats`` auxiliary state each step: the
 # pairs computed here, the tokens routed, the pairs dropped (0), the fullest
 # held expert's rows, that over the mean, the passes made over the sorted rows
@@ -461,10 +511,11 @@ def moe_stat_names(symbol):
     """Names of the auxiliary states that the MoE nodes of `symbol` write
     their per-step statistics into, in graph order."""
     out = []
-    at = registry_get('MoE').input_names.index('stats')
+    op = registry_get('MoE')
     for node in symbol._topo():
         if not node.is_variable() and node.op == 'MoE':
-            src, _ = node.inputs[at]
+            # a layer without a shared expert has three inputs fewer
+            src, _ = node.inputs[op.names_present(node.attrs).index('stats')]
             out.append(src.name)
     return out
 
@@ -729,7 +780,7 @@ def _route(attrs, x2, router, select_bias, k):
                        'moe_route')
         if attrs.get('norm_topk_prob', True):
             w_pairs = w_pairs / (jnp.sum(w_pairs, axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + float(attrs.get('norm_eps', 1e-20)))
     else:
         probs = jax.nn.softmax(_matmul(x2, router), axis=-1)
         w_pairs, idx = _top_k(probs, k)
@@ -749,13 +800,20 @@ def _route(attrs, x2, router, select_bias, k):
                           'experts_held': 0, 'expert_offset': 0,
                           'norm_topk_prob': True, 'routed_scaling': 1.0,
                           'hidden': 0, 'shared_hidden': 0,
-                          'scoring': 'softmax'},
+                          'scoring': 'softmax', 'norm_eps': 1e-20},
           num_outputs=2, num_visible_outputs=1, mutate_inputs={8: 1},
           aux_inputs=('stats',),
-          optional_inputs={'select_bias': _sigmoid_scoring})
-def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
+          optional_inputs={'shared_w1_weight': _has_shared,
+                           'shared_w3_weight': _has_shared,
+                           'shared_w2_weight': _has_shared,
+                           'select_bias': _sigmoid_scoring})
+def _moe(attrs, x, router, w1, w3, w2, *rest):
     """A routed expert layer that holds ``experts_held`` of ``num_experts``
-    experts, those from ``expert_offset`` on, and a shared expert.
+    experts, those from ``expert_offset`` on, and, where ``shared_hidden``
+    is not 0, a shared expert of that width. With ``shared_hidden`` 0 the
+    node has no ``shared_*`` inputs and nothing is added to the routed
+    experts' sum (called with arrays, the layer has a shared expert where
+    its three weights are handed to it).
 
     The router scores every token over all ``num_experts`` (softmax,
     float32), takes the ``num_experts_per_tok`` largest, divides their
@@ -773,7 +831,10 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
     as many as the rows present need, and in each a grouped product runs
     the gated MLP of each expert on its rows. One pass as a rule; an
     imbalance costs further passes, never a pair. What the experts
-    held elsewhere would add is left out; the shared expert is added once.
+    held elsewhere would add is left out; the shared expert, where there is
+    one, is added once. ``norm_eps`` is what is added to the sum of the
+    chosen sigmoid scores before the division (1e-20 unless given; a family
+    publishes its own).
 
     Weights of the experts held: w1, w3 (held, in, hidden), w2 (held,
     hidden, in). ``stats`` is an auxiliary state that receives this step's
@@ -783,6 +844,10 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
     """
     held, offset = int(attrs['experts_held']), int(attrs['expert_offset'])
     k = int(attrs['num_experts_per_tok'])
+    # after the experts' weights: [shared w1, w3, w2,] stats [, select_bias]
+    shared = list(rest)
+    select_bias = shared.pop() if _sigmoid_scoring(attrs) else None
+    stats = shared.pop()
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
     # trace-time names below the node's own, for the compiled program's
@@ -800,8 +865,9 @@ def _moe(attrs, x, router, w1, w3, w2, s1, s3, s2, stats, select_bias=None):
         plan = [dear(v, 'moe_plan') for v in _whole_passes(
             rp, dest, row_pair, tile_group) + (n_tiles,)]
     out = _experts(rp, x2, w_pairs, w1, w3, w2, *plan)
-    with jax.named_scope('shared'):
-        out = out + _gated_mlp(x2, s1, s3, s2)
+    if shared:
+        with jax.named_scope('shared'):
+            out = out + _gated_mlp(x2, *shared)
     pairs = jnp.sum(counts).astype(jnp.float32)
     placed = jnp.sum(row_pair < dest.size).astype(jnp.float32)
     load_max = jnp.max(counts).astype(jnp.float32)

@@ -124,6 +124,13 @@ def _gated_mlp_params(attrs, in_shapes):
     return {'w1_weight': (h, d), 'w3_weight': (h, d), 'w2_weight': (d, h)}
 
 
+@param_shape_hook('GatedShortConv')
+def _gated_short_conv_params(attrs, in_shapes):
+    data = in_shapes[0]
+    return {'weight': (data[-1] // 3, int(attrs.get('kernel', 3)))} \
+        if data else {}
+
+
 @param_shape_hook('MoE')
 def _moe_params(attrs, in_shapes):
     data = in_shapes[0]
@@ -131,7 +138,7 @@ def _moe_params(attrs, in_shapes):
         return {}
     from ..ops.transformer import MOE_STATS
     d, h = data[-1], int(attrs['hidden'])
-    sh = int(attrs.get('shared_hidden') or h)
+    sh = int(attrs.get('shared_hidden') or 0)   # 0: no shared expert
     held = int(attrs['experts_held'])
     return {'router_weight': (int(attrs['num_experts']), d),
             'experts_w1_weight': (held, d, h),
@@ -228,7 +235,7 @@ for _name in ('LinearRegressionOutput', 'MAERegressionOutput',
 
 def _node_arg_name(node, i):
     op = node.opdef()
-    names = op.input_names
+    names = op.names_present(node.attrs)
     return names[i] if i < len(names) else 'arg%d' % i
 
 

@@ -140,7 +140,7 @@ class Symbol:
                 continue
             op = n.opdef()
             if op.aux_inputs:
-                names = op.input_names
+                names = op.names_present(n.attrs)
                 for i, (p, _) in enumerate(n.inputs):
                     if i < len(names) and names[i] in op.aux_inputs and p.is_variable():
                         aux.add(id(p))

@@ -73,6 +73,10 @@ def _attention(q, k, v, g, heads, window, block):
                                  name='attention')
 
 
+def _short_conv(x, w, g):
+    return pk.short_conv_backward(x, w, g) + (pk.short_conv_forward(x, w),)
+
+
 def _latent_attention(qn, qr, kn, kr, v, g, scale=None):
     out, lse = pk.latent_attention_forward(qn, qr, kn, kr, v, 32,
                                            scale=scale)
@@ -80,9 +84,9 @@ def _latent_attention(qn, qr, kn, kr, v, g, scale=None):
                                         scale=scale)
 
 
-def _attention_specs(heads, length=8192):
-    return [((1, length, heads * 128), BF16), ((1, length, 1024), BF16),
-            ((1, length, 1024), BF16), ((1, length, heads * 128), BF16)]
+def _attention_specs(heads, length=8192, head=128):
+    return [((1, length, heads * head), BF16), ((1, length, 8 * head), BF16),
+            ((1, length, 8 * head), BF16), ((1, length, heads * head), BF16)]
 
 
 def _latent_specs(length):
@@ -141,6 +145,19 @@ KERNELS = [
         q, k, v, g, 48, 0, 512), _attention_specs(48, 16384), _ONE),
     ('attention_full_fwd_bwd_t65536', lambda q, k, v, g: _attention(
         q, k, v, g, 48, 0, 512), _attention_specs(48, 65536), _TWO),
+    # LFM2-24B-A2B's attention: 32 heads of 64 on 8 key/value heads. A
+    # head's 64 columns of [B, T, H * 64] are no legal block (the minor
+    # block is 128 lanes or the whole axis), which the interpreter does not
+    # check: the compiled kernels take such heads as [B, H, T, 64], the one
+    # backward kernel as far as the rule allows and the two past it
+    ('attention_full_fwd_bwd_head64', lambda q, k, v, g: _attention(
+        q, k, v, g, 32, 0, 512), _attention_specs(32, head=64), _ONE),
+    ('attention_full_fwd_bwd_head64_t65536', lambda q, k, v, g: _attention(
+        q, k, v, g, 32, 0, 512), _attention_specs(32, 65536, 64), _TWO),
+    # and its gated short convolution: a step's [8192, 3 x 2048] projection
+    # in blocks of 256 rows at the whole width, the taps (2048, 3)
+    ('short_conv_fwd_bwd', _short_conv,
+     [((1, 8192, 6144), BF16), ((2048, 3), BF16), ((1, 8192, 2048), BF16)]),
     # the held experts' grouped product: 8 experts of 3072 x 1024, the
     # static worst-case buffer of 8192 x 8 + 8 x 128 rows
     ('moe_expert_matmul', lambda x, w, t, n: pk.grouped_matmul(x, w, t, n),
@@ -177,6 +194,14 @@ KERNELS = [
     ('moe_expert_matmul_768x16_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
         x, y, t, n, 16), [((51200, 2048), BF16), ((51200, 768), BF16),
                           ((400,), I32), ((1,), I32)]),
+    # LFM2-24B-A2B's held experts: 16 of 2048 x 1536, top 4, a buffer of
+    # 8192 x 4 + 16 x 128 rows
+    ('moe_expert_matmul_1536x16', lambda x, w, t, n: pk.grouped_matmul(
+        x, w, t, n), [((34816, 2048), BF16), ((16, 2048, 1536), BF16),
+                      ((272,), I32), ((1,), I32)]),
+    ('moe_expert_matmul_1536x16_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
+        x, y, t, n, 16), [((34816, 2048), BF16), ((34816, 1536), BF16),
+                          ((272,), I32), ((1,), I32)]),
     # the stream-mixing kernels at Xing4.0-29B-A4B's widths: 4 streams of
     # 3584, one 4096-token sequence, 32 coefficient columns
     ('hyper_pre_fwd', lambda x, w, a, b: pk.hyper_pre_forward(
@@ -241,6 +266,20 @@ def test_row_too_wide_for_vmem_raises_with_shapes(one_chip):
             _compile(fn, one_chip, *sp)
         args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in sp]
         assert 'tpu_custom_call' not in jax.jit(fn).lower(*args).as_text()
+
+
+def test_short_conv_off_the_lanes_raises_with_shapes(one_chip):
+    """64 channels: the thirds of a block would start inside a tile of 128
+    lanes. Refused with the shapes when lowered for the chip; the
+    interpreter runs it (tests/unittest/test_hybrid_ops.py)."""
+    fn = lambda x, w: pk.short_conv_forward(x, w)  # noqa: E731
+    with pytest.raises(ValueError, match=r'short_conv: 64 channels '
+                                         r'\(operand \(1, 256, 192\) '
+                                         r'bfloat16\)'):
+        _compile(fn, one_chip, ((1, 256, 192), BF16), ((64, 3), BF16))
+    args = [jax.ShapeDtypeStruct((1, 256, 192), BF16),
+            jax.ShapeDtypeStruct((64, 3), BF16)]
+    assert 'tpu_custom_call' not in jax.jit(fn).lower(*args).as_text()
 
 
 def test_row_block_follows_width():
