@@ -1169,7 +1169,9 @@ blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
 # `tile_group[t]` is tile t's group and `n_tiles[0]` how many tiles hold
 # rows: the tiles past them are neither fetched nor computed (their index
 # maps stay on the last tile that was), so the work follows the rows
-# present, not the buffer. Their rows of the output are not written.
+# present, not the buffer. Their rows of the output are not written; of the
+# weight gradient, which adds into the array it is given, a group without a
+# tile among those present keeps what the array held.
 
 GROUP_TILE = 128
 
@@ -1181,14 +1183,24 @@ def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, transpose_w):
         o_ref[...] = _dot(x_ref[...], w_ref[0], contract).astype(o_ref.dtype)
 
 
-def _tgmm_kernel(tile_group, n_tiles, x_ref, y_ref, o_ref):
-    t = pl.program_id(1)
+def _tgmm_kernel(tile_group, n_tiles, pass_index, x_ref, y_ref, acc_ref,
+                 o_ref):
+    from jax.experimental.pallas import tpu as pltpu
+    n, t = pl.program_id(0), pl.program_id(1)
     live = t < n_tiles[0]
-    first = (t == 0) | (tile_group[t] != tile_group[jnp.maximum(t - 1, 0)])
+    g = tile_group[t]
+    first = live & ((t == 0) | (g != tile_group[jnp.maximum(t - 1, 0)]))
 
-    @pl.when(live & first)
+    # a group's block starts from the accumulator's, which is the output's
+    # own array and holds zeros in pass 0: not worth a read then
+    @pl.when(first & (pass_index[0] == 0))
     def _():
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(first & (pass_index[0] > 0))
+    def _():
+        tn = o_ref.shape[2]
+        pltpu.sync_copy(acc_ref.at[pl.ds(g, 1), :, pl.ds(n * tn, tn)], o_ref)
 
     @pl.when(live)
     def _():
@@ -1242,10 +1254,14 @@ def grouped_matmul(x, w, tile_group, n_tiles, transpose_w=False,
         tile_group, n_tiles, x, w)
 
 
-def grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
+def grouped_matmul_dw(x, y, tile_group, n_tiles, pass_index, acc,
                       name='grouped_matmul_dw'):
-    """out[g] = sum over the rows r of group g of x[r]^T y[r]: x [R, K],
-    y [R, N], out [G, K, N] float32. Every group owns at least one tile."""
+    """out[g] = acc[g] + sum over the rows r of group g among the tiles
+    present of x[r]^T y[r]: x [R, K], y [R, N], acc and out [G, K, N]
+    float32 in one array (the kernel adds into `acc` in place). A group
+    without a tile among the ``n_tiles[0]`` present is not visited and keeps
+    acc's values. ``pass_index[0]`` is the caller's count of calls into this
+    acc so far: at 0 acc holds zeros, by contract, and is not read."""
     R, K = x.shape
     N = y.shape[1]
     tm, tn = GROUP_TILE, _col_block(N, 256)
@@ -1255,14 +1271,16 @@ def grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
 
     return run_kernel(lambda interpret: pl.pallas_call(
         _tgmm_kernel,
-        out_shape=jax.ShapeDtypeStruct((groups, K, N), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={5: 0},
         interpret=interpret, name=name,
-        **_gmm_grid(2, (N // tn, R // tm), [
-            pl.BlockSpec((tm, K), lambda n, t, tg, nt: (tile(t, nt), 0)),
-            pl.BlockSpec((tm, tn), lambda n, t, tg, nt: (tile(t, nt), n))],
+        **_gmm_grid(3, (N // tn, R // tm), [
+            pl.BlockSpec((tm, K), lambda n, t, tg, nt, p: (tile(t, nt), 0)),
+            pl.BlockSpec((tm, tn), lambda n, t, tg, nt, p: (tile(t, nt), n)),
+            pl.BlockSpec(memory_space=pl.ANY)],
             pl.BlockSpec((1, K, tn),
-                         lambda n, t, tg, nt: (tg[tile(t, nt)], 0, n)))),
-        tile_group, n_tiles, x, y)
+                         lambda n, t, tg, nt, p: (tg[tile(t, nt)], 0, n)))),
+        tile_group, n_tiles, pass_index, x, y, acc)
 
 
 # ---------------------------------------------------------------------------

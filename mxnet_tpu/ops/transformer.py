@@ -534,19 +534,26 @@ def _gmm(x, w, tile_group, n_tiles, transpose_w=False):
     return pk.dispatch(fused, plain, x, w, tile_group, n_tiles)
 
 
-def _gmm_dw(x, y, tile_group, n_tiles, groups):
-    def plain(x, y, tile_group, n_tiles):
+def _gmm_dw(x, y, tile_group, n_tiles, pass_index, acc):
+    """acc [G, K, N] float32 plus each group's x^T y over its rows among
+    the tiles present (``pk.grouped_matmul_dw``, which adds in place);
+    `pass_index` [1] counts the calls into this acc so far, and at 0 acc
+    is zeros."""
+    def plain(x, y, tile_group, n_tiles, pass_index, acc):
         rows = jnp.repeat(tile_group, pk.GROUP_TILE)
         live = jnp.arange(x.shape[0]) < n_tiles[0] * pk.GROUP_TILE
-        onehot = (rows[:, None] == jnp.arange(groups)[None]) & live[:, None]
-        return jnp.einsum('rk,rn,rg->gkn', x, y, onehot.astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+        onehot = ((rows[:, None] == jnp.arange(acc.shape[0])[None])
+                  & live[:, None])
+        return acc + jnp.einsum('rk,rn,rg->gkn', x, y,
+                                onehot.astype(x.dtype),
+                                preferred_element_type=jnp.float32)
 
-    def fused(x, y, tile_group, n_tiles):
-        return pk.grouped_matmul_dw(x, y, tile_group, n_tiles, groups,
-                                    name='moe_expert_matmul_dw')
+    def fused(x, y, tile_group, n_tiles, pass_index, acc):
+        return pk.grouped_matmul_dw(x, y, tile_group, n_tiles, pass_index,
+                                    acc, name='moe_expert_matmul_dw')
 
-    return pk.dispatch(fused, plain, x, y, tile_group, n_tiles)
+    return pk.dispatch(fused, plain, x, y, tile_group, n_tiles, pass_index,
+                       acc)
 
 
 def _dispatch_plan(idx, held, offset):
@@ -669,7 +676,8 @@ def _experts(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
     products do; only the plan has the worst-case length. Both directions
     gather; nothing scatters activations. The backward pass computes a
     pass's forward again from x: what is kept for it is x, w_pairs, the
-    weights and the plan."""
+    weights and the plan. Its three weight gradients are float32 arrays
+    that each pass's grouped products add into in place."""
     return _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair,
                             tile_group, n_tiles)
 
@@ -684,7 +692,6 @@ def _experts_fwd(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
 def _experts_bwd(rp, res, g):
     x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles = res
     k = dest.shape[1]
-    held = w1.shape[0]
     w_flat = w_pairs.reshape(-1)
 
     def one(p, carry):
@@ -713,17 +720,13 @@ def _experts_bwd(rp, res, g):
         with jax.named_scope('gather'):
             for j in range(k):
                 dx += _rows(dxs, at[:, j])
-        # the weight products leave a group without a tile here unwritten
+        # the weight products add into the sums in place; a group without
+        # a tile in this pass keeps what it has
         with jax.named_scope('dw_sum'):
-            present = jnp.arange(groups.shape[0]) < tiles[0]
-            named = jnp.any((groups[:, None] == jnp.arange(held)[None])
-                            & present[:, None], axis=0)[:, None, None]
-            dw1 += jnp.where(named,
-                             _gmm_dw(xs, dh1, groups, tiles, held), 0.0)
-            dw3 += jnp.where(named,
-                             _gmm_dw(xs, dh3, groups, tiles, held), 0.0)
-            dw2 += jnp.where(named,
-                             _gmm_dw(act, dys, groups, tiles, held), 0.0)
+            nth = jnp.reshape(p, (1,))
+            dw1 = _gmm_dw(xs, dh1, groups, tiles, nth, dw1)
+            dw3 = _gmm_dw(xs, dh3, groups, tiles, nth, dw3)
+            dw2 = _gmm_dw(act, dys, groups, tiles, nth, dw2)
         return dx, d_pairs, dw1, dw3, dw2
 
     dx, d_pairs, dw1, dw3, dw2 = jax.lax.fori_loop(

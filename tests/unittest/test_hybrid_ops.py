@@ -335,14 +335,15 @@ def test_the_second_forward_of_a_conv_block(path, monkeypatch):
 # under pytest on the commit before this family came (5e95e14): ``MoE``'s
 # shared expert became optional, the attention kernels took a second layout
 # and the registry learnt an optional input's place, and Xing4.0's step is
-# to lower as it did. (Laguna's and Kanana's digests are in
-# test_latent_ops.py and test_hyper_ops.py and are checked there.) The text
-# is this jax's.
+# to lower as it did. Taken again on the tree of PR 43, which changed the
+# expert layer's backward pass by intent. (Laguna's and Kanana's digests are
+# in test_latent_ops.py and test_hyper_ops.py and are checked there.) The
+# text is this jax's.
 XING4_TEXT = {
     'plain':
-    'c226ac7c5d8cfebcc623dec3375b9c6bc24b6ae79088e3389facb0e16711aa24',
+    'cbd2669c6d5aecb993d66e630db20523aa104b8c0635a54d54f5e9cea1989881',
     'kernel':
-    '11683726206b46c4bed09d93b4de70248d6262298f85aa3e971fa9490d28a890'}
+    '6c7264d3ab4b2a76dfc66f2a3c9876c8c66910d0f5bb6f0e02b719eb4ebe3eec'}
 
 
 @pytest.mark.parametrize('path', PATHS, indirect=True)

@@ -296,12 +296,13 @@ def test_plan_metric(case):
 # attribute, and Kanana's step is to lower as it did. Taken again on the
 # tree of PR 41, which changed what a mirrored stage keeps (the names in the
 # text and the checkpoint's policy; loss and gradients bit-equal to a bare
-# checkpoint's, test_latent_ops.py).
+# checkpoint's, test_latent_ops.py), and on that of PR 43, which changed
+# the expert layer's backward pass by intent (test_latent_ops.py says how).
 KANANA_TEXT = {
     'plain':
-    'd9650f50cb8669d119c6ca72cf9b55deb75c05a4855608b6e700cf4ebcfb67fa',
+    '45edb7b1f7ce1c88832be1ed49fe74c5fd71ff962589b7d0291c50736a35518e',
     'kernel':
-    '56da6958e4fded36f1f26fcbaa732d56f56032f3dc1b3c29b229f752e811fa61'}
+    '1a5b6953adc63bf8e922fa12f8a5b0cdd0d20a18af4c9604e6e4bd3adb9b4465'}
 
 
 def kanana_step_digest():

@@ -543,17 +543,18 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # sha256 of the lowered text of one training step of the Laguna builder's
 # symbol at test_transformer_ops.CFG's sizes, on the CPU, on each path: what
 # a change to the other family's ops must leave as it is. Taken on the tree
-# of PR 41, which changed what a mirrored stage of either family keeps (the
-# policy, not the mathematics: loss and gradients are bit-equal to a bare
-# checkpoint's, above and in test_transformer_ops.py); before that they were
-# PR 33's, which changed Laguna's own step by intent, and before that those
-# of the commit before this family came (faf5f29). The text is this jax's; a
-# change of jax (or of Laguna's own ops) needs them taken again.
+# of PR 43, which changed the expert layer's backward pass in every decoder
+# by intent (the weight gradients' kernel adds into the array it is given;
+# the three dw bit-equal to the select-and-add they replace in a one-pass
+# step, test_transformer_ops.py); before that they were PR 41's (what a
+# mirrored stage keeps), PR 33's, and those of the commit before this family
+# came (faf5f29). The text is this jax's; a change of jax (or of Laguna's
+# own ops) needs them taken again.
 LAGUNA_TEXT = {
     'plain':
-    'abf041a63246c6055f6eb9d8c7372830fd1bbb4552e1d8f08c4cc6079d283e26',
+    '8c8a14a283316bc68654299b28c0a2e44567034384be4621a145cbffae7ce7cc',
     'kernel':
-    '993e6341b8fad791c0aa386af845eedf0426d27d7b93746f003e98c466f04a01'}
+    '9c8a87273b39e2bb4be92fc3065155cf91851142653f2c18a89706382585b2ba'}
 
 
 def laguna_step_digest():

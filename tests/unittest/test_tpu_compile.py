@@ -167,10 +167,10 @@ KERNELS = [
         x, w, t, n, transpose_w=True),
      [((66560, 3072), BF16), ((8, 1024, 3072), BF16), ((520,), I32),
       ((1,), I32)]),
-    ('moe_expert_matmul_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
-        x, y, t, n, 8),
+    # the weight gradient adds into the float32 array it is given
+    ('moe_expert_matmul_dw', pk.grouped_matmul_dw,
      [((66560, 3072), BF16), ((66560, 1024), BF16), ((520,), I32),
-      ((1,), I32)]),
+      ((1,), I32), ((1,), I32), ((8, 3072, 1024), F32)]),
     # latent attention at kanana-2-30b-a3b's widths: 32 heads, keys of
     # 128 + 64 (the 64 rotary ones shared by all heads), values of 128;
     # the one backward kernel holds a head's dq_nope and dq_rope. At
@@ -191,17 +191,22 @@ KERNELS = [
      lambda x, w, t, n: pk.grouped_matmul(x, w, t, n, transpose_w=True),
      [((51200, 2048), BF16), ((16, 768, 2048), BF16), ((400,), I32),
       ((1,), I32)]),
-    ('moe_expert_matmul_768x16_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
-        x, y, t, n, 16), [((51200, 2048), BF16), ((51200, 768), BF16),
-                          ((400,), I32), ((1,), I32)]),
+    ('moe_expert_matmul_768x16_dw', pk.grouped_matmul_dw,
+     [((51200, 2048), BF16), ((51200, 768), BF16), ((400,), I32),
+      ((1,), I32), ((1,), I32), ((16, 2048, 768), F32)]),
     # LFM2-24B-A2B's held experts: 16 of 2048 x 1536, top 4, a buffer of
     # 8192 x 4 + 16 x 128 rows
     ('moe_expert_matmul_1536x16', lambda x, w, t, n: pk.grouped_matmul(
         x, w, t, n), [((34816, 2048), BF16), ((16, 2048, 1536), BF16),
                       ((272,), I32), ((1,), I32)]),
-    ('moe_expert_matmul_1536x16_dw', lambda x, y, t, n: pk.grouped_matmul_dw(
-        x, y, t, n, 16), [((34816, 2048), BF16), ((34816, 1536), BF16),
-                          ((272,), I32), ((1,), I32)]),
+    ('moe_expert_matmul_1536x16_dw', pk.grouped_matmul_dw,
+     [((34816, 2048), BF16), ((34816, 1536), BF16), ((272,), I32),
+      ((1,), I32), ((1,), I32), ((16, 2048, 1536), F32)]),
+    # Xing4.0-29B-A4B's, the widest float32 block: 8 of 3584 x 1024, top 4,
+    # a buffer of 4096 x 4 + 8 x 128 rows
+    ('moe_expert_matmul_3584x8_dw', pk.grouped_matmul_dw,
+     [((17408, 3584), BF16), ((17408, 1024), BF16), ((136,), I32),
+      ((1,), I32), ((1,), I32), ((8, 3584, 1024), F32)]),
     # the stream-mixing kernels at Xing4.0-29B-A4B's widths: 4 streams of
     # 3584, one 4096-token sequence, 32 coefficient columns
     ('hyper_pre_fwd', lambda x, w, a, b: pk.hyper_pre_forward(

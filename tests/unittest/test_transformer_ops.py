@@ -11,6 +11,8 @@ by path: there is one copy), at small widths on the CPU in float32:
 - no pair dropped when every token picks the same experts;
 - the sorted buffer walked in as many passes as the rows present need, and
   no activation of the lowered step with the buffer's worst-case length;
+- the weight gradients' kernel adds into the array it is given, and the
+  step lowered for the chip sums them in place;
 - the whole model through ``Module.fit`` takes the fused window and after
   three steps matches the reference's losses and parameter change;
 - telemetry off leaves the lowered window unchanged, on yields ``moe.*``.
@@ -311,11 +313,11 @@ def _buffer_rows(tokens):
     return -(-(tokens * 3 + 4 * tm) // tm) * tm
 
 
-def _pass_case(scoring, routing):
-    """An expert layer that holds 4 of 16 experts, 3 a token, over LONG
+def _pass_case(scoring, routing, tokens=LONG):
+    """An expert layer that holds 4 of 16 experts, 3 a token, over `tokens`
     tokens: (layer, reference, arguments, rows the tiles present take).
     'same' sends every token to experts 0, 1 and 2."""
-    x, p = jnp.abs(_rand(60, LONG, d)) + 0.1, _moe_params(61, 4)
+    x, p = jnp.abs(_rand(60, tokens, d)) + 0.1, _moe_params(61, 4)
     if routing == 'same':
         router = np.zeros((16, d), np.float32)
         router[0], router[1], router[2] = 0.03, 0.02, 0.01
@@ -353,24 +355,127 @@ def _pass_case(scoring, routing):
     return layer, want, [x] + _moe_weights(p), int(tiles) * pk.GROUP_TILE
 
 
+def _select_and_add(gmm_dw):
+    """The sum as it was before the kernel took the accumulator: the kernel's
+    result from zeros, selected by the groups with a tile present, added."""
+    def parents(x, y, tile_group, n_tiles, pass_index, acc):
+        out = gmm_dw(x, y, tile_group, n_tiles, jnp.zeros_like(pass_index),
+                     jnp.zeros_like(acc))
+        present = jnp.arange(tile_group.shape[0]) < n_tiles[0]
+        named = jnp.any((tile_group[:, None] == jnp.arange(acc.shape[0]))
+                        & present[:, None], axis=0)[:, None, None]
+        return acc + jnp.where(named, out, 0.0)
+
+    return parents
+
+
+def _expert_weight_gradients(layer, args):
+    out, vjp = jax.vjp(lambda *a: layer(*a)[0], *args)
+    return vjp(_rand(99, *out.shape))[2:5]
+
+
 @pytest.mark.parametrize('path', PATHS, indirect=True)
 @pytest.mark.parametrize('scoring', ['softmax', 'sigmoid'])
-@pytest.mark.parametrize('routing,passes', [('even', 1), ('same', 2)])
-def test_passes_follow_the_rows_present(path, scoring, routing, passes):
+@pytest.mark.parametrize('routing,passes', [('even', 1), ('same', 2),
+                                            ('same', 3)])
+def test_passes_follow_the_rows_present(path, scoring, routing, passes,
+                                        monkeypatch):
     """Where a pass is shorter than the worst-case buffer, an even routing
     takes one pass and every token on the same experts takes as many as its
     rows need; either way nothing is dropped, and the output, dx, the
-    router's gradient (d_pairs) and the three dw are the reference's."""
-    layer, want, args, rows = _pass_case(scoring, routing)
-    R = _buffer_rows(LONG)
-    rp = mx.ops.transformer._pass_rows(R, LONG, 3, 4, 16)
-    assert (R, rp) == (2048, 1280)
+    router's gradient (d_pairs) and the three dw are the reference's. (Three
+    passes: 1024 tokens and a pass of the even share, 10 tiles, so that
+    experts 1 and 2, tiles 8-15 and 16-23, each straddle a boundary.) The
+    three dw are what selecting and adding a kernel's result gave: to the
+    bit in one pass, where a group's sum starts from zero either way."""
+    tokens = LONG if passes < 3 else 2 * LONG
+    if passes == 3:
+        monkeypatch.setattr(mx.ops.transformer, '_PASS_OVER_EVEN', 1)
+    layer, want, args, rows = _pass_case(scoring, routing, tokens)
+    R = _buffer_rows(tokens)
+    rp = mx.ops.transformer._pass_rows(R, tokens, 3, 4, 16)
+    assert (R, rp) == ((2048, 1280) if passes < 3 else (3584, 1280))
     stats = dict(zip(MOE_STATS, np.asarray(layer(*args)[1])))
     assert stats['passes'] == -(-rows // rp) == passes
-    assert stats['dropped'] == 0 and stats['tokens'] == LONG
+    assert stats['dropped'] == 0 and stats['tokens'] == tokens
     if routing == 'same':
-        assert stats['pairs'] == 3 * LONG and rows == 13 * pk.GROUP_TILE
+        assert stats['pairs'] == 3 * tokens
+        assert rows == (3 * tokens // pk.GROUP_TILE + 1) * pk.GROUP_TILE
     _both(lambda *a: layer(*a)[0], want, *args)
+    got = _expert_weight_gradients(layer, args)
+    monkeypatch.setattr(mx.ops.transformer, '_gmm_dw',
+                        _select_and_add(mx.ops.transformer._gmm_dw))
+    for a, b in zip(got, _expert_weight_gradients(layer, args)):
+        if passes == 1:
+            np.testing.assert_array_equal(a, b)
+        else:
+            _close(a, b)
+
+
+# name: tile_group of six tiles, tiles present, pass index
+DW_CASES = {
+    'every_group_present': ([0, 0, 1, 2, 3, 3], 6, 1),
+    'a_group_without_a_tile': ([0, 0, 2, 2, 3, 3], 6, 1),
+    'tiles_past_the_last_present': ([0, 0, 1, 2, 3, 3], 3, 2),
+    'pass_0_reads_no_accumulator': ([0, 0, 2, 2, 3, 3], 6, 0),
+}
+
+
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+@pytest.mark.parametrize('case', sorted(DW_CASES))
+def test_weight_gradient_adds_into_its_accumulator(path, case):
+    """out[g] = acc[g] + x^T y over g's rows among the tiles present: a
+    group without one keeps acc's values to the bit, tiles past the last
+    present touch nothing, and in pass 0, where acc is zeros by contract,
+    the kernel starts a group from zero whatever acc holds."""
+    tile_group, n_tiles, nth = DW_CASES[case]
+    tm = pk.GROUP_TILE
+    x, y = _rand(70, 6 * tm, d), _rand(71, 6 * tm, 512)
+    acc = _rand(72, 4, d, 512)
+    if nth == 0 and path == 'plain':
+        acc = jnp.zeros_like(acc)       # the plain form adds what it is given
+    got = np.asarray(mx.ops.transformer._gmm_dw(
+        x, y, jnp.asarray(tile_group, jnp.int32),
+        jnp.asarray([n_tiles], jnp.int32), jnp.asarray([nth], jnp.int32),
+        acc))
+    x, y, acc = np.asarray(x), np.asarray(y), np.asarray(acc)
+    for g in range(4):
+        rows = [r for t in range(n_tiles) if tile_group[t] == g
+                for r in range(t * tm, (t + 1) * tm)]
+        if not rows:
+            np.testing.assert_array_equal(got[g], acc[g])
+        else:
+            _close(got[g], x[rows].T @ y[rows] + (acc[g] if nth else 0.0))
+
+
+def _under_scope(text, scope):
+    """The operations of a text lowered with ``debug_info=True`` whose
+    location lies under the named scope."""
+    named = set(re.findall(r'^(#loc\d+) = loc\("[^"]*/%s/' % scope, text,
+                           re.M))
+    return [line for line in text.splitlines()
+            if (at := re.search(r'loc\((#loc\d+)\)$', line))
+            and at.group(1) in named]
+
+
+def test_the_step_lowered_for_the_chip_sums_weight_gradients_in_place():
+    """One training step of the model, lowered for the TPU: under `dw_sum`
+    are the three kernel calls a sparse layer, each with its accumulator
+    (operand 5) aliased to its output, and neither a select nor an add; no
+    select of the experts' weights' shapes is left anywhere."""
+    cfg = dict(CFG, experts_held=4)
+    step, wrt = _training_step(builder.get_symbol(cfg), **LM_IN)
+    text = jax.jit(step).trace(wrt).lower(lowering_platforms=('tpu',)) \
+        .as_text(debug_info=True)
+    ops = _under_scope(text, 'dw_sum')
+    calls = [o for o in ops if 'kernel_name = "moe_expert_matmul_dw"' in o]
+    assert len(calls) == 3 * cfg['mlp_layer_types'].count('sparse')
+    alias = ('output_operand_alias<output_tuple_indices = [], '
+             'operand_index = 5, operand_tuple_indices = []>')
+    assert all(alias in c for c in calls)
+    assert not [o for o in ops if re.search(r'stablehlo\.(select|add)\b', o)]
+    assert not re.search(r'stablehlo\.select.*-> tensor<4x(64x32|32x64)xf32>',
+                         text)
 
 
 def test_a_pass_is_the_buffer_where_every_expert_is_held():
