@@ -405,7 +405,9 @@ inline Symbol GroupedQueryAttention(const std::string &symbol_name,
     int num_heads = 1,
     int num_kv_heads = 1,
     int window = 0,
-    bool gated = false) {
+    bool gated = false,
+    const std::string &mask = "causal",
+    int block_length = 0) {
   Operator op("GroupedQueryAttention");
   op.SetInput("query", query);
   op.SetInput("key", key);
@@ -415,6 +417,8 @@ inline Symbol GroupedQueryAttention(const std::string &symbol_name,
   op.SetParam("num_kv_heads", detail::ToParam(num_kv_heads));
   op.SetParam("window", detail::ToParam(window));
   op.SetParam("gated", detail::ToParam(gated));
+  if (!mask.empty()) op.SetParam("mask", detail::ToParam(mask));
+  op.SetParam("block_length", detail::ToParam(block_length));
   return op.CreateSymbol(symbol_name);
 }
 
@@ -805,7 +809,8 @@ inline Symbol RotaryEmbedding(const std::string &symbol_name,
     mx_float beta_fast = 32.0f,
     mx_float beta_slow = 1.0f,
     mx_float attention_factor = 0.0f,
-    bool interleaved = false) {
+    bool interleaved = false,
+    int period = 0) {
   Operator op("RotaryEmbedding");
   op.SetInput("data", data);
   op.SetParam("num_heads", detail::ToParam(num_heads));
@@ -818,6 +823,7 @@ inline Symbol RotaryEmbedding(const std::string &symbol_name,
   op.SetParam("beta_slow", detail::ToParam(beta_slow));
   op.SetParam("attention_factor", detail::ToParam(attention_factor));
   op.SetParam("interleaved", detail::ToParam(interleaved));
+  op.SetParam("period", detail::ToParam(period));
   return op.CreateSymbol(symbol_name);
 }
 
@@ -986,6 +992,23 @@ inline Symbol UpSampling(const std::string &symbol_name,
   op.SetParam("scale", detail::ToParam(scale));
   if (!sample_type.empty()) op.SetParam("sample_type", detail::ToParam(sample_type));
   op.SetParam("num_filter", detail::ToParam(num_filter));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol WeightedSoftmaxOutput(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &label,
+    const Symbol &weight,
+    mx_float grad_scale = 1.0f,
+    mx_float ignore_label = -1.0f,
+    const std::string &normalization = "batch") {
+  Operator op("WeightedSoftmaxOutput");
+  op.SetInput("data", data);
+  op.SetInput("label", label);
+  op.SetInput("weight", weight);
+  op.SetParam("grad_scale", detail::ToParam(grad_scale));
+  op.SetParam("ignore_label", detail::ToParam(ignore_label));
+  if (!normalization.empty()) op.SetParam("normalization", detail::ToParam(normalization));
   return op.CreateSymbol(symbol_name);
 }
 

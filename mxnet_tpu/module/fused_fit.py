@@ -1338,6 +1338,11 @@ class FusedFitLoop:
             if self.stat_fns is not None and (
                 self._health_fn is not None or _tele.ledger.enabled()) \
             else []
+        # rows that carried loss under a label-ignoring metric: its count
+        labelled_idx = [j for j, c in enumerate(self.children or ())
+                        if type(c) is metric_mod.Perplexity
+                        and c.ignore_label is not None] \
+            if self.stat_fns is not None and _tele.enabled() else []
 
         # wall stamp of the previous apply_stats fetch: the ledger's
         # per-step timestamps amortize over the inter-window wall so
@@ -1392,6 +1397,9 @@ class FusedFitLoop:
                     ymat = np.asarray(yrows)
             if mrows is not None:
                 note_moe_window(mmat, win=win)
+            for j in labelled_idx:
+                _tele.counter('fit.labelled_rows').inc(
+                    int(host[:, 2 * j + 1].sum()))
             if yrows is not None:
                 note_hyper_window(ymat)
             if hrows is not None:

@@ -265,6 +265,22 @@ def _plan_one(m):
             return jnp.sum(-jnp.log(p + eps)).astype(jnp.float32), \
                 jnp.float32(lab.size)
         return stats
+    if type(m) is metric_mod.Perplexity and m.axis in (-1, 1):
+        ignore = m.ignore_label
+
+        def stats(outs, labels, ignore=ignore):
+            pred = outs[0]
+            lab = labels[0].reshape(-1).astype(jnp.int32)
+            # an ignored label (-1) is no class: it reads class 0 and
+            # counts for nothing, as metric.Perplexity has it
+            kept = jnp.ones_like(lab, bool) if ignore is None \
+                else lab != ignore
+            p = jnp.take_along_axis(
+                pred, jnp.where(kept, lab, 0)[:, None], axis=-1)[:, 0]
+            p = jnp.where(kept, p, 1.0)
+            return jnp.sum(-jnp.log(jnp.maximum(1e-10, p))) \
+                .astype(jnp.float32), jnp.sum(kept).astype(jnp.float32)
+        return stats
     return None
 
 

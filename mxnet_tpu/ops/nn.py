@@ -673,6 +673,51 @@ _softmax_output_cvjp.defvjp(_softmax_output_fwd, _softmax_output_bwd)
 register_alias('Softmax', 'SoftmaxOutput')
 
 
+@register('WeightedSoftmaxOutput', input_names=['data', 'label', 'weight'],
+          param_defaults={'grad_scale': 1.0, 'ignore_label': -1.0,
+                          'normalization': 'batch'})
+def _weighted_softmax_output(attrs, data, label, weight):
+    """``SoftmaxOutput`` whose rows carry a weight that arrives as an
+    input: data [N, classes], label and weight [N]. Forward = softmax(data),
+    so metrics read it as they read ``SoftmaxOutput``. The gradient of row
+    i is ``weight_i [label_i != ignore_label] (softmax - one_hot(label_i))
+    grad_scale / N`` (``normalization='batch'``: a fixed row count, N, so
+    that the objective is ``(1 / N) sum_i weight_i CE_i`` over the rows
+    that carry a label; ``'null'``: no division). Label and weight take
+    no gradient."""
+    scale = float(attrs.get('grad_scale', 1.0))
+    if attrs.get('normalization', 'batch') == 'batch':
+        scale /= data.shape[0]
+    return _weighted_softmax_cvjp(data, label, weight,
+                                  (scale, float(attrs.get('ignore_label',
+                                                          -1.0))))
+
+
+@_partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _weighted_softmax_cvjp(data, label, weight, cfg):
+    return jax.nn.softmax(data, axis=-1)
+
+
+def _weighted_softmax_fwd(data, label, weight, cfg):
+    out = jax.nn.softmax(data, axis=-1)
+    return out, (out, label, weight)
+
+
+def _weighted_softmax_bwd(cfg, res, g):
+    scale, ignore = cfg
+    out, label, weight = res
+    kept = label != ignore
+    # an ignored label may be no class at all: it takes class 0's place
+    # and a weight of zero
+    onehot = jax.nn.one_hot(jnp.where(kept, label, 0).astype(jnp.int32),
+                            out.shape[-1], dtype=out.dtype)
+    w = jnp.where(kept, weight.astype(out.dtype), 0) * scale
+    return (out - onehot) * w[:, None], None, None
+
+
+_weighted_softmax_cvjp.defvjp(_weighted_softmax_fwd, _weighted_softmax_bwd)
+
+
 # ---------------------------------------------------------------------------
 # Regression outputs & MakeLoss — reference regression_output-inl.h,
 # make_loss-inl.h. Same custom-gradient trick.

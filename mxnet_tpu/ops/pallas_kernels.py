@@ -868,6 +868,255 @@ def _bwd_vmem(rows, widths, dtype):
     return resident + _VMEM_SCOPE if resident <= _BWD_RESIDENT_BYTES else None
 
 
+# -- the block-diffusion mask --------------------------------------------------
+# A sequence of two halves of L rows, [noisy ; clean], each in blocks of B
+# positions (arXiv:2503.09573): a noisy row sees its own noisy block, both
+# ways, and the clean blocks strictly before it; a clean row sees the clean
+# blocks up to and including its own; nothing sees another block's noise.
+# Seen from either side a kernel block therefore touches up to TWO runs of
+# the other side's kernel blocks (a noisy query block: its own noisy key
+# blocks, and the clean ones from the half's start; a clean key block: the
+# clean query blocks from its own on, and the noisy ones after it), and
+# the grid's walking axis takes the first run and then the second. The
+# kernels below are the ones above with that walk and that mask; they share
+# `_bwd_pair`, the wrappers, the layouts and `_bwd_vmem`'s rule.
+
+def _iwhere(c, a, b):
+    return (a if c else b) if isinstance(c, (bool, int)) else \
+        jnp.where(c, a, b)
+
+
+def _diffusion_geometry(L, B, blk):
+    """For kernel blocks of `blk` rows that tile a half (blk divides L, so
+    2 L / blk blocks a side): ``keys_of(i)`` and ``queries_of(j)``, each
+    the two runs ``(first, count, first, count)`` of the other side's
+    blocks that block i (j) touches, the second possibly empty, as functions
+    of a (traced or Python) block index; and the static length of each
+    side's walk."""
+    n = L // blk
+
+    def half(i):            # (clean?, first and last diffusion block)
+        clean = i >= n
+        j = i - _iwhere(clean, n, 0)
+        return clean, (j * blk) // B, ((j + 1) * blk - 1) // B
+
+    def run(p0, p1):        # kernel blocks of positions p0..p1 of a half
+        lo = p0 // blk
+        return lo, _imin(p1 // blk, n - 1) - lo + 1
+
+    def keys_of(i):
+        clean, b0, b1 = half(i)
+        own = run(b0 * B, (b1 + 1) * B - 1)
+        # clean keys: up to the last row's block, strictly before it for
+        # a noisy row (none at all before block 0)
+        lo, count = run(0, _iwhere(clean, b1 + 1, b1) * B - 1)
+        return (_iwhere(clean, n + lo, own[0]), _iwhere(clean, count, own[1]),
+                n + lo, _iwhere(clean, 0, count))
+
+    def queries_of(j):
+        clean, b0, b1 = half(j)
+        own = run(b0 * B, (b1 + 1) * B - 1)
+        # of a clean key block: the clean rows from its first block on,
+        # the noisy rows of the blocks after that one
+        lo, after = (b0 * B) // blk, _imin(((b0 + 1) * B) // blk, n)
+        return (_iwhere(clean, n + lo, own[0]),
+                _iwhere(clean, n - lo, own[1]),
+                after, _iwhere(clean, n - after, 0))
+
+    steps = lambda f: max(  # noqa: E731
+        f(i)[1] + f(i)[3] for i in range(2 * n))
+    return keys_of, queries_of, steps(keys_of), steps(queries_of)
+
+
+def _diffusion_walk(L, B, blk, pad):
+    """(blocks a side, block_of for the keys of a query block, the same for
+    the queries of a key block, steps of each walk): ``block_of(i, s)`` is
+    (the s-th block that block i walks, held on the last one when the walk
+    has ended; whether the walk has not). Where a half is not whole blocks
+    (tests and odd lengths) every block walks every block; the mask, which
+    keys off the true rows, stays exact."""
+    if L % blk:
+        n = (2 * L + pad) // blk
+        every = lambda i, s: (s, s < n)    # noqa: E731
+        return n, every, every, n, n
+    keys_of, queries_of, ksteps, qsteps = _diffusion_geometry(L, B, blk)
+
+    def walked(runs_of):
+        def block_of(i, s):
+            lo1, n1, lo2, n2 = runs_of(i)
+            second = _iwhere(n2 > 0, lo2 + _imin(s - n1, n2 - 1),
+                             lo1 + n1 - 1)
+            return _iwhere(s < n1, lo1 + s, second), s < n1 + n2
+        return block_of
+
+    return 2 * L // blk, walked(keys_of), walked(queries_of), ksteps, qsteps
+
+
+def _diffusion_blocks(L, block):
+    """(rows of a kernel block, rows of padding after 2 L): a block that
+    tiles a half where the half's length allows one, else the blocks of
+    `_attn_blocks` over both halves and every block walking every block."""
+    blk, pad = _attn_blocks(L, L, block, block)[::2]
+    if not pad:
+        return blk, 0
+    return _attn_blocks(2 * L, 2 * L, block, block)[::2]
+
+
+def _diffusion_visible(qi, kj, blk, L, B, keys_first=False):
+    """`_visible` under the block-diffusion mask. A key's block number is
+    made one comparable number, clean blocks below `past` and noisy ones
+    from it on, so that a tile costs two comparisons of a column of row
+    numbers against a row of key numbers: a clean key is seen up to the
+    row's bound, a noisy key where it is the row's own block."""
+    q_shape, k_shape = ((1, blk), (blk, 1)) if keys_first \
+        else ((blk, 1), (1, blk))
+
+    def rows_of(block, shape):
+        r = block * blk + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0 if shape[1] == 1 else 1)
+        clean = r >= L
+        pos = r - jnp.where(clean, L, 0)
+        if B & (B - 1):
+            return r, clean, jax.lax.div(pos, jnp.int32(B))
+        return r, clean, jax.lax.shift_right_logical(
+            pos, jnp.int32(B.bit_length() - 1))
+
+    _, q_clean, qb = rows_of(qi, q_shape)
+    cols, k_clean, kb = rows_of(kj, k_shape)
+    past = L // B + 1
+    key = jnp.where(cols < 2 * L, jnp.where(k_clean, kb, past + kb),
+                    4 * past)           # keys past the end are padding
+    upto = qb - jnp.where(q_clean, 0, 1)
+    own = jnp.where(q_clean, -1, past + qb)
+    return (key <= upto) | (key == own)
+
+
+def _diffusion_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s,
+                          acc_s, *, geo, scale):
+    block_of, seen_of, steps = geo
+    qi, step = pl.program_id(2), pl.program_id(3)
+    kj, live = block_of(qi, step)
+
+    @pl.when(step == 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        seen = seen_of(qi, kj)
+        s = jnp.where(seen, _dot(q, k, ((1,), (1,))) * scale, _NEG)
+        m = m_s[...]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + _dot(p.astype(v.dtype), v,
+                                              ((1,), (0,)))
+        m_s[...] = m_new
+
+    @pl.when(step == steps - 1)
+    def _():
+        l = jnp.maximum(l_s[...], 1e-30)
+        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_s[...] + jnp.log(l)
+
+
+def _diffusion_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, acc_s, *, geo, scale):
+    block_of, seen_of, steps = geo
+    qi, step = pl.program_id(2), pl.program_id(3)
+    kj, live = block_of(qi, step)
+
+    @pl.when(step == 0)
+    def _():
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        p = jnp.where(seen_of(qi, kj), jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = p * (dp - delta_ref[0, 0])
+        acc_s[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
+
+    @pl.when(step == steps - 1)
+    def _():
+        dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
+
+
+def _diffusion_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_s, dv_s, *, geo, scale, group):
+    block_of, seen_of, steps = geo
+    kj, step = pl.program_id(2), pl.program_id(3)
+    qi, live = block_of(kj, step % steps)
+
+    @pl.when(step == 0)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = _dot(q, k, ((1,), (1,))) * scale
+        p = jnp.where(seen_of(qi, kj), jnp.exp(s - lse_ref[0, 0]), 0.0)
+        dv_s[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = p * (dp - delta_ref[0, 0])
+        dk_s[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
+
+    @pl.when(step == group * steps - 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _diffusion_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s, *, geo,
+                          scale):
+    """`_attn_bwd_kernel`'s cell of the grid, its key blocks by the two
+    runs."""
+    block_of, seen_of, steps, blk = geo
+    member, qi, step = (pl.program_id(axis) for axis in (2, 3, 4))
+    kj, live = block_of(qi, step)
+    first = (member == 0) & (qi == 0) & (step == 0)
+    last = (member == pl.num_programs(2) - 1) \
+        & (qi == pl.num_programs(3) - 1) & (step == steps - 1)
+
+    @pl.when(first)
+    def _():
+        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
+        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
+
+    @pl.when(step == 0)
+    def _():
+        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        s = _dot(k, q, ((1,), (1,))) * scale
+        p, ds = _bwd_pair(s, seen_of(qi, kj, keys_first=True),
+                          lse_ref[0, 0, 0], delta_ref[0, 0, 0], do, v_ref[0])
+        rows = _block_rows(kj, blk)
+        dv_s[rows, :] += _dot(p, do, ((1,), (0,)))
+        dk_s[rows, :] += _dot(ds, q, ((1,), (0,)))
+        dq_s[...] += _dot(ds, k, ((0,), (0,)))
+
+    @pl.when(step == steps - 1)
+    def _():
+        dq_ref[0] = (dq_s[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _():
+        dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
 def _pad_rows(x, pad):
     return x if not pad else jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
 
@@ -959,27 +1208,44 @@ def _head_layout(call, by_head, heads, n_in, n_out):
 
 def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
                       scale=None, block_q=512, block_k=512,
-                      name='attention'):
+                      name='attention', block_length=0):
     """(out [B, Tq, H * D], lse [B, H, Tq]) of grouped-query attention;
     q [B, Tq, H * D], k and v [B, Tk, KV * D]. `window` w > 0: a query sees
-    only the w keys up to its own position. The kernel is named
-    ``<name>_fwd`` in a device trace."""
+    only the w keys up to its own position. `block_length` > 0: the
+    block-diffusion mask over a sequence of two halves in blocks of that
+    many positions (above; `causal`, `window` and `block_k` are not read).
+    The kernel is named ``<name>_fwd`` in a device trace."""
     B, Tq, HD = q.shape
     Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
     scale = D ** -0.5 if scale is None else scale
-    blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    if block_length:
+        blk_q, pad_q = blk_k, pad_k = _diffusion_blocks(Tq // 2, block_q)
+    else:
+        blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
     with jax.named_scope('heads'):      # as in latent_attention_forward
         q, k, v = (_pad_rows(q, pad_q), _pad_rows(k, pad_k),
                    _pad_rows(v, pad_k))
-    nq, _, keys_of, _, steps, _ = _attn_walk(
-        Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
-    geo = (blk_q, blk_k, Tk, Tk - Tq, causal, window, keys_of, steps)
+    if block_length:
+        nq, key_block, _, steps, _ = _diffusion_walk(
+            Tq // 2, block_length, blk_q, pad_q)
+        seen = functools.partial(_diffusion_visible, blk=blk_q, L=Tq // 2,
+                                 B=block_length)
 
-    def kv_index(b, h, i, s):
-        lo, hi = keys_of(i)
-        return b, _imin(lo + s, hi), h // group
+        def kv_index(b, h, i, s):
+            return b, key_block(i, s)[0], h // group
 
-    kernel = functools.partial(_attn_fwd_kernel, geo=geo, scale=scale)
+        kernel = functools.partial(_diffusion_fwd_kernel, scale=scale,
+                                   geo=(key_block, seen, steps))
+    else:
+        nq, _, keys_of, _, steps, _ = _attn_walk(
+            Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
+        geo = (blk_q, blk_k, Tk, Tk - Tq, causal, window, keys_of, steps)
+
+        def kv_index(b, h, i, s):
+            lo, hi = keys_of(i)
+            return b, _imin(lo + s, hi), h // group
+
+        kernel = functools.partial(_attn_fwd_kernel, geo=geo, scale=scale)
 
     def build(interpret):
         by_head, spec = _head_blocks(D, interpret)
@@ -1008,7 +1274,8 @@ def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
 
 def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
                        causal=True, window=0, scale=None, block_q=512,
-                       block_k=512, g_lse=None, name='attention'):
+                       block_k=512, g_lse=None, name='attention',
+                       block_length=0):
     """(dq, dk, dv) of :func:`attention_forward` from its output, its
     log-sum-exp [B, H, Tq] and the output's cotangent. One kernel, named
     ``<name>_bwd`` in a device trace, where dk and dv of a whole sequence
@@ -1017,7 +1284,10 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
     B, Tq, HD = q.shape
     Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
     scale = D ** -0.5 if scale is None else scale
-    blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    if block_length:
+        blk_q, pad_q = blk_k, pad_k = _diffusion_blocks(Tq // 2, block_q)
+    else:
+        blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
     with jax.named_scope('delta'):
         delta = jnp.sum(
@@ -1028,9 +1298,32 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
     with jax.named_scope('heads'):
         q, g_out = _pad_rows(q, pad_q), _pad_rows(g_out, pad_q)
         k, v = _pad_rows(k, pad_k), _pad_rows(v, pad_k)
-    nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
-        Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
-    base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
+    if block_length:
+        nq, key_block, query_block, ksteps, qsteps = _diffusion_walk(
+            Tq // 2, block_length, blk_q, pad_q)
+        nk = nq
+        seen = functools.partial(_diffusion_visible, blk=blk_q, L=Tq // 2,
+                                 B=block_length)
+        k_block = lambda i, s: key_block(i, s)[0]           # noqa: E731
+        q_block = lambda j, s: query_block(j, s % qsteps)[0]    # noqa: E731
+        kernels = [functools.partial(fn, geo=geo) for fn, geo in (
+            (_diffusion_bwd_kernel, (key_block, seen, ksteps, blk_k)),
+            (_diffusion_dq_kernel, (key_block, seen, ksteps)),
+            (_diffusion_dkv_kernel, (query_block, seen, qsteps)))]
+    else:
+        nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
+            Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
+        base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
+        k_block = _walked(keys_of)
+
+        def q_block(j, s):
+            lo, hi = queries_of(j)
+            return _imin(lo + s % qsteps, hi)
+
+        kernels = [functools.partial(fn, geo=base + geo) for fn, geo in (
+            (_attn_bwd_kernel, (keys_of, ksteps)),
+            (_attn_dq_kernel, (keys_of, ksteps)),
+            (_attn_dkv_kernel, (queries_of, qsteps)))]
     vmem = _bwd_vmem(Tk + pad_k, (D, D), k.dtype)
     crossing = ((heads, kv_heads, kv_heads, heads), 4)
 
@@ -1041,7 +1334,6 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
 
     if vmem is not None:
         # grid (batch, key/value head g, its m-th query head, i, s)
-        k_block = _walked(keys_of)
         row_spec = pl.BlockSpec((1, 1, 1, 1, blk_q), lambda b, g, m, i, s: (
             b, g * group + m, i, 0, 0))
 
@@ -1051,8 +1343,7 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
             k_spec = spec(blk_k, lambda b, g, m, i, s: (b, k_block(i, s), g))
             whole = spec(Tk + pad_k, lambda b, g, m, i, s: (b, 0, g))
             return _head_layout(pl.pallas_call(
-                functools.partial(_attn_bwd_kernel,
-                                  geo=base + (keys_of, ksteps), scale=scale),
+                functools.partial(kernels[0], scale=scale),
                 grid=(B, kv_heads, group, nq, ksteps),
                 in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
                 out_specs=[q_spec, whole, whole],
@@ -1071,8 +1362,7 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
     lse, delta = _cols(lse, pad_q), _cols(delta, pad_q)
 
     def kv_index(b, h, i, s):
-        lo, hi = keys_of(i)
-        return b, _imin(lo + s, hi), h // group
+        return b, k_block(i, s), h // group
 
     col_spec = pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, i, s: (b, h, i, 0))
 
@@ -1080,8 +1370,7 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         by_head, spec = _head_blocks(D, interpret)
         q_spec = spec(blk_q, lambda b, h, i, s: (b, i, h))
         return _head_layout(pl.pallas_call(
-            functools.partial(_attn_dq_kernel, geo=base + (keys_of, ksteps),
-                              scale=scale),
+            functools.partial(kernels[1], scale=scale),
             grid=(B, heads, nq, ksteps),
             in_specs=[q_spec, spec(blk_k, kv_index), spec(blk_k, kv_index),
                       q_spec, col_spec, col_spec],
@@ -1093,10 +1382,6 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
             by_head, *crossing, 1)
 
     dq, = run_kernel(build_dq, q, k, v, g_out, lse, delta)
-
-    def q_block(j, s):
-        lo, hi = queries_of(j)
-        return _imin(lo + s % qsteps, hi)
 
     def q_index(b, g, j, s):
         return b, q_block(j, s), g * group + s // qsteps
@@ -1111,9 +1396,7 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         k_spec = spec(blk_k, lambda b, g, j, s: (b, j, g))
         qw_spec = spec(blk_q, q_index)
         return _head_layout(pl.pallas_call(
-            functools.partial(_attn_dkv_kernel,
-                              geo=base + (queries_of, qsteps), scale=scale,
-                              group=group),
+            functools.partial(kernels[2], scale=scale, group=group),
             grid=(B, kv_heads, nk, group * qsteps),
             in_specs=[qw_spec, k_spec, k_spec, qw_spec, qcol_spec, qcol_spec],
             out_specs=[k_spec, k_spec],
@@ -1159,6 +1442,49 @@ def _blockwise_bwd(heads, kv_heads, causal, window, scale, block_q, block_k,
 
 
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def block_diffusion_attention(q, k, v, heads, kv_heads, block_length,
+                              scale=None, block=512,
+                              name='attention_blockdiff'):
+    """:func:`blockwise_attention` under the block-diffusion mask: q
+    [B, 2 L, H * D], k and v [B, 2 L, KV * D] hold a noisy and a clean copy
+    of L positions, in blocks of `block_length`."""
+    return attention_forward(q, k, v, heads, kv_heads, scale=scale,
+                             block_q=block, name=name,
+                             block_length=block_length)[0]
+
+
+def _block_diffusion_fwd(q, k, v, heads, kv_heads, block_length, scale,
+                         block, name):
+    out, lse = attention_forward(q, k, v, heads, kv_heads, scale=scale,
+                                 block_q=block, name=name,
+                                 block_length=block_length)
+    out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
+    return out, (q, k, v, out, lse)
+
+
+def _block_diffusion_bwd(heads, kv_heads, block_length, scale, block, name,
+                         res, g):
+    q, k, v, out, lse = res
+    return attention_backward(q, k, v, out, lse, g, heads, kv_heads,
+                              scale=scale, block_q=block, name=name,
+                              block_length=block_length)
+
+
+block_diffusion_attention.defvjp(_block_diffusion_fwd, _block_diffusion_bwd)
+
+
+def block_diffusion_pairs(L, block_length, block=512):
+    """(query-key pairs a head that the mask leaves, pairs in the kernel
+    blocks the forward walk visits) for two halves of L rows."""
+    blk, pad = _diffusion_blocks(L, block)
+    n, key_block, _, steps, _ = _diffusion_walk(L, block_length, blk, pad)
+    visited = sum(bool(key_block(i, s)[1])
+                  for i in range(n) for s in range(steps))
+    blocks = L // block_length
+    return block_length ** 2 * blocks * (blocks + 1), visited * blk * blk
 
 
 # ---------------------------------------------------------------------------

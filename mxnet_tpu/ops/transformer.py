@@ -98,12 +98,14 @@ def rope_inv_freq(head_dim, base, rotary_dim, scaling, factor, original_len,
                           'scaling': 'default', 'factor': 1.0,
                           'original_max_position': 0, 'beta_fast': 32.0,
                           'beta_slow': 1.0, 'attention_factor': 0.0,
-                          'interleaved': False})
+                          'interleaved': False, 'period': 0})
 def _rotary(attrs, x):
     """Rotary positions 0..T-1 on x [B, T, num_heads * D]: the first
     ``rotary_dim`` dimensions of each head (all of them if 0) are rotated,
     half against half, or with ``interleaved`` dimension 2i against 2i + 1;
-    the rest pass through."""
+    the rest pass through. ``period`` p > 0: positions restart every p
+    rows (row r sits at r mod p: the copies of one sequence laid end to
+    end share their positions)."""
     B, T, HD = x.shape
     H = int(attrs.get('num_heads', 1))
     D = HD // H
@@ -115,7 +117,14 @@ def _rotary(attrs, x):
         float(attrs.get('beta_slow', 1.0)),
         float(attrs.get('attention_factor', 0.0)))
     half = inv.shape[0]
-    angle = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None]
+    period = int(attrs.get('period', 0))
+    if period:
+        with jax.named_scope('blockdiff_mask'):
+            pos = (jnp.arange(T, dtype=jnp.int32) % period) \
+                .astype(jnp.float32)
+    else:
+        pos = jnp.arange(T, dtype=jnp.float32)
+    angle = pos[:, None] * inv[None]
     cos = (jnp.cos(angle) * scale)[None, :, None, :]
     sin = (jnp.sin(angle) * scale)[None, :, None, :]
     x4 = x.reshape(B, T, H, D).astype(jnp.float32)
@@ -136,7 +145,18 @@ def _rotary(attrs, x):
 # Grouped-query attention
 # ---------------------------------------------------------------------------
 
-def _dense_attention(q, k, v, heads, kv_heads, window):
+def block_diffusion_mask(L, B):
+    """[2 L, 2 L] mask over [noisy ; clean], blocks of B positions: a
+    noisy row sees its own noisy block and the clean blocks strictly before
+    it, a clean row the clean blocks up to and including its own."""
+    r = jnp.arange(2 * L)
+    clean, blk = r >= L, (r % L) // B
+    return (clean[None, :] & (blk[None, :] < blk[:, None])) \
+        | ((clean[None, :] == clean[:, None])
+           & (blk[None, :] == blk[:, None]))
+
+
+def _dense_attention(q, k, v, heads, kv_heads, window, block_length=0):
     """The plain form: one dense masked product. For small shapes off the
     TPU."""
     B, T, HD = q.shape
@@ -145,10 +165,13 @@ def _dense_attention(q, k, v, heads, kv_heads, window):
     k4 = k.reshape(B, T, kv_heads, D).astype(jnp.float32)
     v4 = v.reshape(B, T, kv_heads, D).astype(jnp.float32)
     s = jnp.einsum('bqkgd,bskd->bkgqs', q5, k4) * D ** -0.5
-    rows, cols = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
-    seen = cols <= rows
-    if window:
-        seen &= cols > rows - window
+    if block_length:
+        seen = block_diffusion_mask(T // 2, block_length)
+    else:
+        rows, cols = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+        seen = cols <= rows
+        if window:
+            seen &= cols > rows - window
     p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
     out = jnp.einsum('bkgqs,bskd->bqkgd', p, v4)
     return out.reshape(B, T, HD).astype(q.dtype)
@@ -157,7 +180,8 @@ def _dense_attention(q, k, v, heads, kv_heads, window):
 @register('GroupedQueryAttention',
           input_names=['query', 'key', 'value', 'gate'],
           param_defaults={'num_heads': 1, 'num_kv_heads': 1, 'window': 0,
-                          'gated': False},
+                          'gated': False, 'mask': 'causal',
+                          'block_length': 0},
           optional_inputs={'gate': 'gated'})
 def _gqa(attrs, q, k, v, gate=None):
     """Causal attention of query [B, T, H * D] over key and value
@@ -166,11 +190,25 @@ def _gqa(attrs, q, k, v, gate=None):
     w > 0, s > t - w. ``gated``: head i's output is multiplied by
     sigmoid(gate[..., i]), gate [B, T, H]. Returns [B, T, H * D].
 
+    ``mask='block_diffusion'`` with ``block_length`` B: the T = 2 L rows
+    are a noisy and a clean copy of L positions, [noisy ; clean], in blocks
+    of B (B divides L); a noisy row sees its own noisy block in both
+    directions and the clean blocks strictly before it, a clean row the
+    clean blocks up to and including its own, nothing another block's noise
+    (:func:`block_diffusion_mask`; arXiv:2503.09573). No window.
+
     On a TPU the blockwise kernels run it, forward and backward, named
-    ``attention_window_*`` or ``attention_full_*`` in a device trace; no
-    [T, T] array exists and a windowed layer walks only the blocks inside
-    its window."""
+    ``attention_window_*``, ``attention_full_*`` or
+    ``attention_blockdiff_*`` in a device trace; no [T, T] array exists, a
+    windowed layer walks only the blocks inside its window and a
+    block-diffusion layer only the kernel blocks its mask does not empty
+    (gauges ``attention.blockdiff.pairs_needed`` / ``pairs_visited``)."""
     H, KV = int(attrs['num_heads']), int(attrs['num_kv_heads'])
+    mask = str(attrs.get('mask', 'causal'))
+    if mask == 'block_diffusion':
+        return _block_diffusion_attention(attrs, q, k, v, H, KV)
+    if mask != 'causal':
+        raise ValueError('GroupedQueryAttention: mask %r' % (mask,))
     window = int(attrs.get('window', 0))
     # blocks: a window is walked in blocks of half its size (so that the
     # blocks outside it are at most a third of those walked), full
@@ -198,6 +236,32 @@ def _gqa(attrs, q, k, v, gate=None):
             out = (out.reshape(B, T, H, HD // H).astype(jnp.float32) * g) \
                 .astype(out.dtype).reshape(B, T, HD)
     return out
+
+
+def _block_diffusion_attention(attrs, q, k, v, H, KV):
+    T, B = q.shape[1], int(attrs.get('block_length', 0))
+    if B < 1 or T % 2 or (T // 2) % B or int(attrs.get('window', 0)) \
+            or attrs.get('gated', False):
+        raise ValueError(
+            'GroupedQueryAttention(mask=block_diffusion): %d rows are not '
+            'two halves of whole blocks of block_length %d, or a window or '
+            'a gate was asked for' % (T, B))
+    name = 'attention_blockdiff'
+    from .. import telemetry as _tele
+    if _tele.enabled():
+        needed, visited = pk.block_diffusion_pairs(T // 2, B)
+        _tele.gauge('attention.blockdiff.pairs_needed').set(needed)
+        _tele.gauge('attention.blockdiff.pairs_visited').set(visited)
+
+    def fused(q, k, v):
+        return pk.block_diffusion_attention(q, k, v, H, KV, B, None, 512,
+                                            name)
+
+    def plain(q, k, v):
+        return _dense_attention(q, k, v, H, KV, 0, B)
+
+    q, k, v = dear(q, name + '_q'), dear(k, name + '_k'), dear(v, name + '_v')
+    return pk.dispatch(fused, plain, q, k, v)
 
 
 # ---------------------------------------------------------------------------

@@ -73,6 +73,18 @@ def _attention(q, k, v, g, heads, window, block):
                                  name='attention')
 
 
+def _blockdiff_attention(q, k, v, g):
+    out, lse = pk.attention_forward(q, k, v, 32, 4, name='attention',
+                                    block_length=4)
+    return pk.attention_backward(q, k, v, out, lse, g, 32, 4,
+                                 name='attention', block_length=4)
+
+
+def _blockdiff_specs(length):
+    return [((1, length, 4096), BF16), ((1, length, 512), BF16),
+            ((1, length, 512), BF16), ((1, length, 4096), BF16)]
+
+
 def _short_conv(x, w, g):
     return pk.short_conv_backward(x, w, g) + (pk.short_conv_forward(x, w),)
 
@@ -158,6 +170,14 @@ KERNELS = [
     # in blocks of 256 rows at the whole width, the taps (2048, 3)
     ('short_conv_fwd_bwd', _short_conv,
      [((1, 8192, 6144), BF16), ((2048, 3), BF16), ((1, 8192, 2048), BF16)]),
+    # SDAR-30B-A3B-Chat's attention under the block-diffusion mask: 32
+    # heads on 4 key/value heads, two halves of 4096 rows in blocks of 4
+    # positions, walked in two runs of kernel blocks; the one backward
+    # kernel as far as the rule allows and the two past it
+    ('attention_blockdiff_fwd_bwd', _blockdiff_attention,
+     _blockdiff_specs(8192), _ONE),
+    ('attention_blockdiff_fwd_bwd_t65536', _blockdiff_attention,
+     _blockdiff_specs(65536), _TWO),
     # the held experts' grouped product: 8 experts of 3072 x 1024, the
     # static worst-case buffer of 8192 x 8 + 8 x 128 rows
     ('moe_expert_matmul', lambda x, w, t, n: pk.grouped_matmul(x, w, t, n),
