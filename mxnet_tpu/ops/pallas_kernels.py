@@ -1509,24 +1509,51 @@ def _gmm_kernel(tile_group, n_tiles, x_ref, w_ref, o_ref, *, transpose_w):
         o_ref[...] = _dot(x_ref[...], w_ref[0], contract).astype(o_ref.dtype)
 
 
+_ZERO_ROWS = 256
+
+
+def _zero(o_ref):
+    """Zeros into a block, _ZERO_ROWS of its leading axis at a time where
+    they are many: one store over [8192, 1024] unrolls into 8192 stores,
+    most of such a kernel's code and of its compile."""
+    rows = o_ref.shape[0]
+    if rows % _ZERO_ROWS or rows == _ZERO_ROWS:
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        return
+
+    def some(i, carry):
+        at = pl.multiple_of(i * _ZERO_ROWS, _ZERO_ROWS)
+        o_ref[pl.ds(at, _ZERO_ROWS)] = jnp.zeros(
+            (_ZERO_ROWS,) + o_ref.shape[1:], o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, rows // _ZERO_ROWS, some, 0)
+
+
+def _start_from(acc_block, o_ref, first, pass_index):
+    """Where `first`, the output block starts from the accumulator's, which
+    is the output's own array and holds zeros in pass 0: not worth a read
+    then."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    @pl.when(first & (pass_index[0] == 0))
+    def _():
+        _zero(o_ref)
+
+    @pl.when(first & (pass_index[0] > 0))
+    def _():
+        pltpu.sync_copy(acc_block, o_ref)
+
+
 def _tgmm_kernel(tile_group, n_tiles, pass_index, x_ref, y_ref, acc_ref,
                  o_ref):
-    from jax.experimental.pallas import tpu as pltpu
     n, t = pl.program_id(0), pl.program_id(1)
     live = t < n_tiles[0]
     g = tile_group[t]
     first = live & ((t == 0) | (g != tile_group[jnp.maximum(t - 1, 0)]))
-
-    # a group's block starts from the accumulator's, which is the output's
-    # own array and holds zeros in pass 0: not worth a read then
-    @pl.when(first & (pass_index[0] == 0))
-    def _():
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-
-    @pl.when(first & (pass_index[0] > 0))
-    def _():
-        tn = o_ref.shape[2]
-        pltpu.sync_copy(acc_ref.at[pl.ds(g, 1), :, pl.ds(n * tn, tn)], o_ref)
+    tn = o_ref.shape[2]
+    _start_from(acc_ref.at[pl.ds(g, 1), :, pl.ds(n * tn, tn)], o_ref, first,
+                pass_index)
 
     @pl.when(live)
     def _():
@@ -1540,14 +1567,21 @@ def _col_block(n, want=512):
     return n
 
 
-def _gmm_grid(num_scalars, grid, in_specs, out_specs):
+def _gmm_grid(num_scalars, grid, in_specs, out_specs, scratch=(),
+              vmem=None):
     from jax.experimental.pallas import tpu as pltpu
     return dict(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=num_scalars, grid=grid, in_specs=in_specs,
-            out_specs=out_specs),
+            out_specs=out_specs, scratch_shapes=scratch),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'arbitrary')))
+            dimension_semantics=('parallel', 'arbitrary'),
+            vmem_limit_bytes=vmem))
+
+
+def _last_tile(t, n_tiles):
+    """Tile t, or the last present for a tile past it (not fetched again)."""
+    return jnp.minimum(t, n_tiles[0] - 1)
 
 
 def grouped_matmul(x, w, tile_group, n_tiles, transpose_w=False,
@@ -1558,14 +1592,10 @@ def grouped_matmul(x, w, tile_group, n_tiles, transpose_w=False,
     R, K = x.shape
     N = w.shape[1] if transpose_w else w.shape[2]
     tm, tn = GROUP_TILE, _col_block(N)
-
-    def tile(t, n_tiles):
-        return jnp.minimum(t, n_tiles[0] - 1)
-
     w_block = (1, tn, K) if transpose_w else (1, K, tn)
 
     def w_index(n, t, tile_group, n_tiles):
-        g = tile_group[tile(t, n_tiles)]
+        g = tile_group[_last_tile(t, n_tiles)]
         return (g, n, 0) if transpose_w else (g, 0, n)
 
     kernel = functools.partial(_gmm_kernel, transpose_w=transpose_w)
@@ -1574,9 +1604,11 @@ def grouped_matmul(x, w, tile_group, n_tiles, transpose_w=False,
         out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
         interpret=interpret, name=name,
         **_gmm_grid(2, (N // tn, R // tm), [
-            pl.BlockSpec((tm, K), lambda n, t, tg, nt: (tile(t, nt), 0)),
+            pl.BlockSpec((tm, K),
+                         lambda n, t, tg, nt: (_last_tile(t, nt), 0)),
             pl.BlockSpec(w_block, w_index)],
-            pl.BlockSpec((tm, tn), lambda n, t, tg, nt: (tile(t, nt), n)))),
+            pl.BlockSpec((tm, tn),
+                         lambda n, t, tg, nt: (_last_tile(t, nt), n)))),
         tile_group, n_tiles, x, w)
 
 
@@ -1591,22 +1623,95 @@ def grouped_matmul_dw(x, y, tile_group, n_tiles, pass_index, acc,
     R, K = x.shape
     N = y.shape[1]
     tm, tn = GROUP_TILE, _col_block(N, 256)
-
-    def tile(t, n_tiles):
-        return jnp.minimum(t, n_tiles[0] - 1)
-
     return run_kernel(lambda interpret: pl.pallas_call(
         _tgmm_kernel,
         out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
         input_output_aliases={5: 0},
         interpret=interpret, name=name,
         **_gmm_grid(3, (N // tn, R // tm), [
-            pl.BlockSpec((tm, K), lambda n, t, tg, nt, p: (tile(t, nt), 0)),
-            pl.BlockSpec((tm, tn), lambda n, t, tg, nt, p: (tile(t, nt), n)),
+            pl.BlockSpec((tm, K),
+                         lambda n, t, tg, nt, p: (_last_tile(t, nt), 0)),
+            pl.BlockSpec((tm, tn),
+                         lambda n, t, tg, nt, p: (_last_tile(t, nt), n)),
             pl.BlockSpec(memory_space=pl.ANY)],
-            pl.BlockSpec((1, K, tn),
-                         lambda n, t, tg, nt, p: (tg[tile(t, nt)], 0, n)))),
+            pl.BlockSpec((1, K, tn), lambda n, t, tg, nt, p:
+                         (tg[_last_tile(t, nt)], 0, n)))),
         tile_group, n_tiles, pass_index, x, y, acc)
+
+
+# The way back from the sorted rows to the tokens: each row of the tiles
+# present is added into its token's row of a float32 [T, d] sum. A block of
+# columns of the sum is resident over the walk of the row tiles, and a row is
+# one load, one add and one store of a dynamic row of it, row after row, so
+# that rows of one token add up in the order they come. A padding row adds
+# zero to the last token.
+
+_ROWS_TO_TOKENS_BYTES = 32 << 20    # of the resident block of the sum
+_ROWS_UNROLLED = 8
+
+
+def _rows_to_tokens_kernel(token, n_tiles, pass_index, *refs, scaled):
+    scale = refs[0] if scaled else None
+    src_ref, acc_ref, o_ref, *wide = refs[1:] if scaled else refs
+    n, t = pl.program_id(0), pl.program_id(1)
+    tm, td = src_ref.shape
+    T = o_ref.shape[0]
+    _start_from(acc_ref.at[:, pl.ds(n * td, td)], o_ref, t == 0, pass_index)
+
+    @pl.when(t < n_tiles[0])
+    def _():
+        rows_ref = src_ref
+        if wide:    # one row of a packed dtype is no whole sublane
+            rows_ref, = wide
+            rows_ref[...] = src_ref[...].astype(jnp.float32)
+
+        def some(i, carry):
+            for j in range(_ROWS_UNROLLED):
+                r = i * _ROWS_UNROLLED + j
+                at = token[t * tm + r]
+                row = rows_ref[pl.ds(r, 1), :]
+                if scaled:
+                    row = row * scale[t * tm + r]
+                o_ref[pl.ds(jnp.minimum(at, T - 1), 1), :] += jnp.where(
+                    at < T, row, 0.0)
+            return carry
+
+        jax.lax.fori_loop(0, tm // _ROWS_UNROLLED, some, 0)
+
+
+def rows_to_tokens(src, token, n_tiles, pass_index, acc, scale=None,
+                   name='rows_to_tokens'):
+    """out[token[r]] = acc[token[r]] + scale[r] * src[r] summed over the
+    real rows r (token[r] < T) of the ``n_tiles[0]`` tiles present: src
+    [R, d] sorted rows, token [R] int32 (T for a padding row, which adds
+    nothing whatever it holds), scale [R] float32 or None for 1, acc and out
+    [T, d] float32 in one array (the kernel adds into `acc` in place). Rows
+    of tiles past the last present are not read. ``pass_index[0]`` is the
+    caller's count of calls into this acc so far: at 0 acc holds zeros, by
+    contract, and is not read."""
+    R, d = src.shape
+    T = acc.shape[0]
+    tm = GROUP_TILE
+    # the widest block of columns, whole lanes, that the sum's rows fit
+    td = next((b for b in range(d - d % 128, 0, -128)
+               if d % b == 0 and T * b * 4 <= _ROWS_TO_TOKENS_BYTES), d)
+    scaled = scale is not None
+    prefetched = (token, n_tiles, pass_index) + ((scale,) if scaled else ())
+    wide = [] if src.dtype == jnp.float32 else [_vmem((tm, td))]
+    kernel = functools.partial(_rows_to_tokens_kernel, scaled=scaled)
+    return run_kernel(lambda interpret: pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={len(prefetched) + 1: 0},
+        interpret=interpret, name=name,
+        # the resident block is written back while the next is walked
+        **_gmm_grid(len(prefetched), (d // td, R // tm), [
+            pl.BlockSpec((tm, td), lambda n, t, tok, nt, *_:
+                         (_last_tile(t, nt), n)),
+            pl.BlockSpec(memory_space=pl.ANY)],
+            pl.BlockSpec((T, td), lambda n, t, *_: (0, n)),
+            scratch=wide, vmem=2 * T * td * 4 + (16 << 20))),
+        *prefetched, src, acc)
 
 
 # ---------------------------------------------------------------------------

@@ -620,6 +620,28 @@ def _gmm_dw(x, y, tile_group, n_tiles, pass_index, acc):
                        acc)
 
 
+def _rows_to_tokens(src, token, n_tiles, pass_index, acc, scale=None):
+    """acc [T, d] float32 plus each sorted row among the tiles present,
+    times its scale where one is given, added into its token's row
+    (``pk.rows_to_tokens``, which adds in place): src [R, d], token [R] the
+    row's token, T for a padding row, scale [R] float32. `pass_index` [1]
+    counts the calls into this acc so far, and at 0 acc is zeros."""
+    def plain(src, token, n_tiles, pass_index, acc, *scale):
+        live = jnp.arange(src.shape[0]) < n_tiles[0] * pk.GROUP_TILE
+        rows = src.astype(jnp.float32)
+        if scale:
+            rows = rows * scale[0][:, None]
+        return acc.at[jnp.where(live, token, acc.shape[0])].add(
+            rows, mode='drop')
+
+    def fused(src, token, n_tiles, pass_index, acc, *scale):
+        return pk.rows_to_tokens(src, token, n_tiles, pass_index, acc,
+                                 *scale, name='moe_rows_to_tokens')
+
+    return pk.dispatch(fused, plain, src, token, n_tiles, pass_index, acc,
+                       *(() if scale is None else (scale,)))
+
+
 def _dispatch_plan(idx, held, offset):
     """Where each token-expert pair that lands on an expert held here goes
     in the sorted buffer. idx [T, k] expert ids. Returns (dest [T, k] row
@@ -687,43 +709,41 @@ def _whole_passes(rp, dest, row_pair, tile_group):
             jnp.pad(tile_group, (0, pad // pk.GROUP_TILE), mode='edge'))
 
 
-def _pass_forward(rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
+def _pass_forward(rp, p, x, w1, w3, w2, row_pair, tile_group, n_tiles, k):
     """Pass p of the sorted buffer, rows [p * rp, (p + 1) * rp): its share
-    of the plan (at: dest as rows of this pass, rp for a pair of another
-    pass or of an expert held elsewhere) and the gated MLP of each expert
-    on its rows. Rows of tiles past the last present are left unwritten by
-    the grouped products; no `at` names one."""
-    k, tiles_pass = dest.shape[1], rp // pk.GROUP_TILE
-    start = p * rp
-    rows = jax.lax.dynamic_slice(row_pair, (start,), (rp,))
+    of the plan (rows: the flat pair of each row, T * k for a padding row;
+    token: the row's token, T for a padding row) and the gated MLP of each
+    expert on its rows. Rows of tiles past the last present are left
+    unwritten by the grouped products: nothing may fold them into a sum
+    across rows."""
+    tiles_pass = rp // pk.GROUP_TILE
+    rows = jax.lax.dynamic_slice(row_pair, (p * rp,), (rp,))
+    token = rows // k
     groups = jax.lax.dynamic_slice(tile_group, (p * tiles_pass,),
                                    (tiles_pass,))
     tiles = jnp.clip(n_tiles - p * tiles_pass, 0, tiles_pass)
-    # a negative index would wrap before it fills
-    at = jnp.where((dest >= start) & (dest < start + rp), dest - start, rp)
     with jax.named_scope('gather'):
-        xs = _rows(x, rows // k)
+        xs = _rows(x, token)
     with jax.named_scope('experts'):
         h1 = _gmm(xs, w1, groups, tiles)
         h3 = _gmm(xs, w3, groups, tiles)
         act = (jax.nn.silu(h1.astype(jnp.float32))
                * h3.astype(jnp.float32)).astype(x.dtype)
         ys = _gmm(act, w2, groups, tiles)
-    return (rows, groups, tiles, at), (xs, h1, h3, act, ys)
+    return (rows, token, groups, tiles), (xs, h1, h3, act, ys)
 
 
 def _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group,
                      n_tiles):
     k = dest.shape[1]
+    w_flat = w_pairs.reshape(-1)
 
     def one(p, out):
-        (_, _, _, at), (_, _, _, _, ys) = _pass_forward(
-            rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
-        with jax.named_scope('combine'):
-            for j in range(k):      # a token's pairs, one gather each
-                out += w_pairs[:, j:j + 1] * _rows(ys, at[:, j]).astype(
-                    jnp.float32)
-        return out
+        (rows, token, _, tiles), (_, _, _, _, ys) = _pass_forward(
+            rp, p, x, w1, w3, w2, row_pair, tile_group, n_tiles, k)
+        with jax.named_scope('combine'):    # each row into its token's sum
+            return _rows_to_tokens(ys, token, tiles, jnp.reshape(p, (1,)),
+                                   out, _rows(w_flat, rows))
 
     out = jax.lax.fori_loop(0, _num_passes(rp, n_tiles), one,
                             jnp.zeros(x.shape, jnp.float32))
@@ -737,11 +757,15 @@ def _experts(rp, x, w_pairs, w1, w3, w2, dest, row_pair, tile_group, n_tiles):
     :func:`_whole_passes`. The sorted buffer is walked in passes of rp rows
     (:func:`_pass_rows`), as many as the rows present need, so that the
     gathers, the gate and the sums follow the rows present as the grouped
-    products do; only the plan has the worst-case length. Both directions
-    gather; nothing scatters activations. The backward pass computes a
-    pass's forward again from x: what is kept for it is x, w_pairs, the
-    weights and the plan. Its three weight gradients are float32 arrays
-    that each pass's grouped products add into in place."""
+    products do; only the plan has the worst-case length. Tokens go to the
+    buffer by a gather, one lookup a sorted row; the way back, in both
+    directions, is walked once by sorted row too: a kernel adds each row of
+    the tiles present into its token's row of a float32 [T, d] sum
+    (:func:`_rows_to_tokens`: the output, and dx), so a token's terms are
+    added in expert order. The backward pass computes a pass's forward
+    again from x: what is kept for it is x, w_pairs, the weights and the
+    plan. Its three weight gradients are float32 arrays that each pass's
+    grouped products add into in place."""
     return _experts_forward(rp, x, w_pairs, w1, w3, w2, dest, row_pair,
                             tile_group, n_tiles)
 
@@ -760,15 +784,19 @@ def _experts_bwd(rp, res, g):
 
     def one(p, carry):
         dx, d_pairs, dw1, dw3, dw2 = carry
-        (rows, groups, tiles, at), (xs, h1, h3, act, ys) = _pass_forward(
-            rp, p, x, w1, w3, w2, dest, row_pair, tile_group, n_tiles)
+        (rows, token, groups, tiles), (xs, h1, h3, act, ys) = _pass_forward(
+            rp, p, x, w1, w3, w2, row_pair, tile_group, n_tiles, k)
+        nth = jnp.reshape(p, (1,))
         with jax.named_scope('combine'):
-            g32 = g.astype(jnp.float32)
-            d_pairs += jnp.stack(
-                [jnp.sum(_rows(ys, at[:, j]).astype(jnp.float32) * g32,
-                         axis=-1) for j in range(k)], axis=1)
-            dys = (_rows(w_flat, rows)[:, None]
-                   * _rows(g, rows // k).astype(jnp.float32)).astype(g.dtype)
+            # a row's share of its pair's weight gradient, then each pair's
+            # by its row (rp for a pair of another pass or of an expert held
+            # elsewhere; a negative index would wrap before it fills)
+            gs = _rows(g, token).astype(jnp.float32)
+            rowdot = jnp.sum(ys.astype(jnp.float32) * gs, axis=-1)
+            at = jnp.where((dest >= p * rp) & (dest < (p + 1) * rp),
+                           dest - p * rp, rp)
+            d_pairs += _rows(rowdot, at)
+            dys = (_rows(w_flat, rows)[:, None] * gs).astype(g.dtype)
         with jax.named_scope('experts'):
             dact = _gmm(dys, w2, groups, tiles, transpose_w=True).astype(
                 jnp.float32)
@@ -782,12 +810,10 @@ def _experts_bwd(rp, res, g):
                 + _gmm(dh3, w3, groups, tiles, transpose_w=True) \
                 .astype(jnp.float32)
         with jax.named_scope('gather'):
-            for j in range(k):
-                dx += _rows(dxs, at[:, j])
+            dx = _rows_to_tokens(dxs, token, tiles, nth, dx)
         # the weight products add into the sums in place; a group without
         # a tile in this pass keeps what it has
         with jax.named_scope('dw_sum'):
-            nth = jnp.reshape(p, (1,))
             dw1 = _gmm_dw(xs, dh1, groups, tiles, nth, dw1)
             dw3 = _gmm_dw(xs, dh3, groups, tiles, nth, dw3)
             dw2 = _gmm_dw(act, dys, groups, tiles, nth, dw2)

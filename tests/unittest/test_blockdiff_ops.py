@@ -342,19 +342,21 @@ def test_the_iterator_yields_a_noisy_and_a_clean_copy():
 # under pytest on the commit before block diffusion came (3390d65), before
 # any op was touched: ``GroupedQueryAttention`` learnt a mask, ``RotaryEmbedding`` a
 # period and the attention wrappers a second walk, and LFM2's step is to
-# lower as it did. The text is this jax's.
+# lower as it did. Taken again on the tree of PR 46, which changed the way
+# back from the expert layer's sorted rows to the tokens by intent
+# (test_latent_ops.py says how). The text is this jax's.
 LFM2_TEXT = {
     'plain':
-    '07f289d6167b7e284cb6b51da4daddd7e002f30463ba2199e1b76d35cedd879f',
+    'af86a33285446cf1b1baf13d2ca5f60932955251e0c50675b8338bc88b55b53e',
     'kernel':
-    '4ff982440bbe7d8f3fb3fdaa6491d9e46c71fc360107b5fc657e21917136fb8c'}
+    'e47a943b78fde416d23dc4e37f0a60a4cfb6cd5e4606ad6ebf3ebd86c1c034b5'}
 # the same of this family's own step, at CFG's sizes, taken on the tree
-# that brought it
+# that brought it and again on that of PR 46, as above
 SDAR_TEXT = {
     'plain':
-    '0b21d2138ad71ee093f0140ca3aac93f32a68135959072b65f1329aa35eb86ab',
+    'e0b556f21f64b9c73e3d4da275e279152c94dc95072f5f5478cf115dc92dff40',
     'kernel':
-    '83a728d1001ebc6824f8c48dc7b80608b4e0db8e1e4e02b5bf1428b0577b240b'}
+    '5c32fdaba456f99cef44b482fc4d7863c118a92b2336bf9cf0759c31092c681e'}
 
 
 def _digest(sym, **inputs):

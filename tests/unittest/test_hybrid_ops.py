@@ -336,14 +336,16 @@ def test_the_second_forward_of_a_conv_block(path, monkeypatch):
 # shared expert became optional, the attention kernels took a second layout
 # and the registry learnt an optional input's place, and Xing4.0's step is
 # to lower as it did. Taken again on the tree of PR 43, which changed the
-# expert layer's backward pass by intent. (Laguna's and Kanana's digests are
+# expert layer's backward pass by intent, and on that of PR 46, which changed
+# the way back from the sorted rows to the tokens by intent (test_latent_ops.py
+# says how). (Laguna's and Kanana's digests are
 # in test_latent_ops.py and test_hyper_ops.py and are checked there.) The
 # text is this jax's.
 XING4_TEXT = {
     'plain':
-    'cbd2669c6d5aecb993d66e630db20523aa104b8c0635a54d54f5e9cea1989881',
+    '7de7239f07f15f82eebf86e2b393e55a749f7ac4a5b197b7886cff3ba1669cad',
     'kernel':
-    '6c7264d3ab4b2a76dfc66f2a3c9876c8c66910d0f5bb6f0e02b719eb4ebe3eec'}
+    '37693fa0996dffaf45c7ed21712a75760950a651ddc23c8ecf020f848813c3c9'}
 
 
 @pytest.mark.parametrize('path', PATHS, indirect=True)

@@ -297,12 +297,14 @@ def test_plan_metric(case):
 # tree of PR 41, which changed what a mirrored stage keeps (the names in the
 # text and the checkpoint's policy; loss and gradients bit-equal to a bare
 # checkpoint's, test_latent_ops.py), and on that of PR 43, which changed
-# the expert layer's backward pass by intent (test_latent_ops.py says how).
+# the expert layer's backward pass by intent, and on that of PR 46, which
+# changed the way back from the sorted rows to the tokens by intent
+# (test_latent_ops.py says how).
 KANANA_TEXT = {
     'plain':
-    '45edb7b1f7ce1c88832be1ed49fe74c5fd71ff962589b7d0291c50736a35518e',
+    '2526a27d6cd2d0aa0ed3a1c06b03358270fccfda29103acb89ca841b2fb5c460',
     'kernel':
-    '1a5b6953adc63bf8e922fa12f8a5b0cdd0d20a18af4c9604e6e4bd3adb9b4465'}
+    '900cc8601a52aafe9349c5205883c93191c35fb1e0c4d6dd55928392f5cfcae1'}
 
 
 def kanana_step_digest():

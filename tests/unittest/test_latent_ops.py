@@ -546,15 +546,19 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # of PR 43, which changed the expert layer's backward pass in every decoder
 # by intent (the weight gradients' kernel adds into the array it is given;
 # the three dw bit-equal to the select-and-add they replace in a one-pass
-# step, test_transformer_ops.py); before that they were PR 41's (what a
+# step, test_transformer_ops.py), and again on that of PR 46, which changed
+# the way back from the sorted rows to the tokens by intent (a kernel adds
+# each row into its token's sum where k gathers a direction walked the whole
+# sequence; output, dx and d_pairs are the float32 reference's to 1e-5,
+# test_transformer_ops.py); before that they were PR 41's (what a
 # mirrored stage keeps), PR 33's, and those of the commit before this family
 # came (faf5f29). The text is this jax's; a change of jax (or of Laguna's
 # own ops) needs them taken again.
 LAGUNA_TEXT = {
     'plain':
-    '8c8a14a283316bc68654299b28c0a2e44567034384be4621a145cbffae7ce7cc',
+    '39a7a0a2bf354847c208eb600bcea396eeec80f4cbd01278c69a5eabc9805577',
     'kernel':
-    '9c8a87273b39e2bb4be92fc3065155cf91851142653f2c18a89706382585b2ba'}
+    'aabc1a13d961b21cde412dbad0e4b5f0e83a6b9800a8c47afe5d4a44e7777a34'}
 
 
 def laguna_step_digest():

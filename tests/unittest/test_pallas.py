@@ -351,3 +351,64 @@ def test_rows_wider_than_the_chips_vmem_bind_on_the_cpu(monkeypatch, force):
     out = ex.forward()[0].asnumpy()
     np.testing.assert_allclose(out.mean(-1), 0, atol=1e-5)
     np.testing.assert_allclose(out.std(-1), 1, rtol=1e-3)
+
+
+# name: (tokens, real rows of each of six tiles of 128, tiles present,
+# passes). A tile's real rows come first and name different tokens, as the
+# rows of one expert do; every tile is another expert's, so a token can own
+# a row in each.
+ROWS_TO_TOKENS_CASES = {
+    'padding_rows_inside_a_present_tile': (256, [128, 70, 1, 0, 128, 9], 6, 1),
+    'tiles_past_the_last_present_hold_nan': (
+        256, [128, 70, 128, 128, 128, 128], 2, 1),
+    'a_token_in_every_tile_and_tokens_in_none': (64, [32, 32, 32, 32, 32, 32],
+                                                 6, 1),
+    'a_second_pass_adds_into_the_first': (128, [128, 37, 128, 5, 0, 101], 6,
+                                          2),
+}
+
+
+@pytest.mark.parametrize('scaled', [True, False], ids=['scale', 'no_scale'])
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bfloat16', 'float32'])
+@pytest.mark.parametrize('case', sorted(ROWS_TO_TOKENS_CASES))
+def test_rows_to_tokens_adds_sorted_rows_into_their_tokens(case, dtype,
+                                                           scaled):
+    """out[token[r]] = acc[token[r]] + scale[r] * src[r] over the real rows
+    of the tiles present, against ``jnp.zeros(...).at[token].add(...)``:
+    padding rows (token T) add nothing whatever they hold, tiles past the
+    last present are not read (they hold NaN), a token may own a row in
+    every tile or in none, and a later pass adds into what the first left
+    where pass 0 starts from zeros without reading its accumulator."""
+    T, real, present, passes = ROWS_TO_TOKENS_CASES[case]
+    tm, d = pk.GROUP_TILE, 256
+    rng = np.random.RandomState(len(case))
+    want = jnp.zeros((T, d), jnp.float32)
+    # pass 0 must not read what it is handed
+    acc = jnp.full((T, d), np.nan, jnp.float32)
+    for nth in range(passes):
+        token = np.full((len(real), tm), T, np.int32)
+        for t, n in enumerate(real):
+            # the first 32 tokens only, where every tile has room for them:
+            # those own a row in each tile, the others in none
+            pool = 32 if case.startswith('a_token') else T
+            token[t, :n] = rng.permutation(pool)[:n]
+        token = token.reshape(-1)
+        src = rng.standard_normal((len(token), d)).astype(np.float32)
+        src[present * tm:] = np.nan
+        src = jnp.asarray(src, dtype)
+        scale = jnp.asarray(rng.standard_normal(len(token)), jnp.float32)
+        acc = pk.rows_to_tokens(
+            src, jnp.asarray(token), jnp.asarray([present], jnp.int32),
+            jnp.asarray([nth], jnp.int32), acc, scale if scaled else None)
+        live = token[:present * tm]
+        rows = src[:present * tm].astype(jnp.float32)
+        if scaled:
+            rows = rows * scale[:present * tm, None]
+        want = want + jnp.zeros((T + 1, d), jnp.float32).at[live].add(
+            rows)[:T]
+    assert acc.dtype == jnp.float32 and acc.shape == (T, d)
+    np.testing.assert_allclose(np.asarray(acc), np.asarray(want), rtol=1e-6,
+                               atol=1e-5)
+    if case.startswith('a_token'):
+        assert not np.asarray(acc)[32:].any()

@@ -227,6 +227,23 @@ KERNELS = [
     ('moe_expert_matmul_3584x8_dw', pk.grouped_matmul_dw,
      [((17408, 3584), BF16), ((17408, 1024), BF16), ((136,), I32),
       ((1,), I32), ((1,), I32), ((8, 3584, 1024), F32)]),
+    # the way back from the sorted rows to the tokens, a pass's rows into the
+    # float32 sum of the whole sequence: SDAR-30B-A3B-Chat's 18432 rows a
+    # pass into 8192 x 2048 (bfloat16 rows with their weights: the output;
+    # float32 rows: dx), Laguna's 6144 into 8192 x 3072, Xing4.0's 5120 into
+    # 4096 x 3584
+    ('moe_rows_to_tokens_2048_scaled', pk.rows_to_tokens,
+     [((18432, 2048), BF16), ((18432,), I32), ((1,), I32), ((1,), I32),
+      ((8192, 2048), F32), ((18432,), F32)]),
+    ('moe_rows_to_tokens_2048', pk.rows_to_tokens,
+     [((18432, 2048), F32), ((18432,), I32), ((1,), I32), ((1,), I32),
+      ((8192, 2048), F32)]),
+    ('moe_rows_to_tokens_3072_scaled', pk.rows_to_tokens,
+     [((6144, 3072), BF16), ((6144,), I32), ((1,), I32), ((1,), I32),
+      ((8192, 3072), F32), ((6144,), F32)]),
+    ('moe_rows_to_tokens_3584', pk.rows_to_tokens,
+     [((5120, 3584), F32), ((5120,), I32), ((1,), I32), ((1,), I32),
+      ((4096, 3584), F32)]),
     # the stream-mixing kernels at Xing4.0-29B-A4B's widths: 4 streams of
     # 3584, one 4096-token sequence, 32 coefficient columns
     ('hyper_pre_fwd', lambda x, w, a, b: pk.hyper_pre_forward(

@@ -17,6 +17,7 @@ by path: there is one copy), at small widths on the CPU in float32:
   three steps matches the reference's losses and parameter change;
 - telemetry off leaves the lowered window unchanged, on yields ``moe.*``.
 """
+import functools
 import importlib.util
 import os
 import re
@@ -412,6 +413,43 @@ def test_passes_follow_the_rows_present(path, scoring, routing, passes,
             _close(a, b)
 
 
+@pytest.mark.parametrize('path', PATHS, indirect=True)
+def test_experts_sum_by_sorted_row_is_the_float32_reference(path):
+    """`_experts` alone, every token on experts 0, 1 and 2 of the four held
+    (each takes every row: 13 tiles, two passes of 10): the output, dx and
+    d_pairs are the float32 sum over a token's pairs of w_pair * E(x) and
+    its gradients to 1e-5, whatever order the kernel adds a token's terms
+    in."""
+    ops = mx.ops.transformer
+    tokens, k, held = LONG, 3, 4
+    x, p = _rand(80, tokens, d), _moe_params(81, held)
+    w1, w3, w2 = _moe_weights(p)[1:4]
+    w_pairs = jnp.abs(_rand(82, tokens, k)) + 0.1
+    idx = jnp.tile(jnp.arange(k)[None], (tokens, 1))
+    dest, row_pair, tile_group, n_tiles, _ = ops._dispatch_plan(idx, held, 0)
+    rp = ops._pass_rows(row_pair.shape[0], tokens, k, held, 16)
+    assert int(ops._num_passes(rp, n_tiles)) == 2
+    plan = ops._whole_passes(rp, dest, row_pair, tile_group) + (n_tiles,)
+
+    def want(x, w_pairs):
+        hi = jax.lax.Precision.HIGHEST
+        h1 = jnp.einsum('td,edh->teh', x, w1[:k], precision=hi)
+        h3 = jnp.einsum('td,edh->teh', x, w3[:k], precision=hi)
+        y = jnp.einsum('teh,ehd->ted', jax.nn.silu(h1) * h3, w2[:k],
+                       precision=hi)
+        return jnp.sum(w_pairs[:, :, None] * y, axis=1)
+
+    def got(x, w_pairs):
+        return ops._experts(rp, x, w_pairs, w1, w3, w2, *plan)
+
+    (out, vjp), (ref_out, ref_vjp) = jax.vjp(got, x, w_pairs), jax.vjp(
+        want, x, w_pairs)
+    _close(out, ref_out, 1e-5)
+    g = _rand(83, tokens, d)
+    for a, b in zip(vjp(g), ref_vjp(g)):
+        _close(a, b, 1e-5)
+
+
 # name: tile_group of six tiles, tiles present, pass index
 DW_CASES = {
     'every_group_present': ([0, 0, 1, 2, 3, 3], 6, 1),
@@ -458,24 +496,48 @@ def _under_scope(text, scope):
             and at.group(1) in named]
 
 
+_ALIAS = ('output_operand_alias<output_tuple_indices = [], '
+          'operand_index = %d, operand_tuple_indices = []>')
+
+
+@functools.lru_cache(maxsize=None)
+def _step_lowered_for_the_chip():
+    step, wrt = _training_step(
+        builder.get_symbol(dict(CFG, experts_held=4)), **LM_IN)
+    return jax.jit(step).trace(wrt).lower(lowering_platforms=('tpu',)) \
+        .as_text(debug_info=True)
+
+
 def test_the_step_lowered_for_the_chip_sums_weight_gradients_in_place():
     """One training step of the model, lowered for the TPU: under `dw_sum`
     are the three kernel calls a sparse layer, each with its accumulator
     (operand 5) aliased to its output, and neither a select nor an add; no
     select of the experts' weights' shapes is left anywhere."""
-    cfg = dict(CFG, experts_held=4)
-    step, wrt = _training_step(builder.get_symbol(cfg), **LM_IN)
-    text = jax.jit(step).trace(wrt).lower(lowering_platforms=('tpu',)) \
-        .as_text(debug_info=True)
+    text = _step_lowered_for_the_chip()
     ops = _under_scope(text, 'dw_sum')
     calls = [o for o in ops if 'kernel_name = "moe_expert_matmul_dw"' in o]
-    assert len(calls) == 3 * cfg['mlp_layer_types'].count('sparse')
-    alias = ('output_operand_alias<output_tuple_indices = [], '
-             'operand_index = 5, operand_tuple_indices = []>')
-    assert all(alias in c for c in calls)
+    assert len(calls) == 3 * CFG['mlp_layer_types'].count('sparse')
+    assert all(_ALIAS % 5 in c for c in calls)
     assert not [o for o in ops if re.search(r'stablehlo\.(select|add)\b', o)]
     assert not re.search(r'stablehlo\.select.*-> tensor<4x(64x32|32x64)xf32>',
                          text)
+
+
+def test_the_step_lowered_for_the_chip_sums_tokens_by_sorted_row():
+    """The same step: the way back from the sorted rows to the tokens is one
+    kernel call a sparse layer under `combine` (the output: rows, their
+    weights, the sum as operand 5) and one under `gather` (dx: operand 4),
+    each sum aliased to its output; no row of a whole sequence is looked up
+    a pair at a time (the parent's `k` gathers a loop made `k` float32
+    [T, d] adds under each scope: none is left)."""
+    text = _step_lowered_for_the_chip()
+    sparse = CFG['mlp_layer_types'].count('sparse')
+    for scope, acc in (('combine', 5), ('gather', 4)):
+        ops = _under_scope(text, scope)
+        calls = [o for o in ops if 'kernel_name = "moe_rows_to_tokens"' in o]
+        assert len(calls) == sparse and all(_ALIAS % acc in c for c in calls)
+        assert not [o for o in ops if re.search(
+            r'stablehlo\.add.*tensor<%dx%dxf32>$' % (2 * T, d), o)]
 
 
 def test_a_pass_is_the_buffer_where_every_expert_is_held():
