@@ -9,6 +9,9 @@ optimally on the MXU/VMEM hierarchy:
   K/V tiles streamed through VMEM; no [Tq, Tk] score matrix in HBM.
 - :func:`fused_rmsnorm` / :func:`fused_layernorm` — one pass over the
   feature dim in VMEM (XLA emits separate reduce+scale passes).
+- :func:`fused_rmsnorm_bwd` — RMSNorm's backward rule: one pass over x
+  and dy that writes dx and sums the gain's gradient in float32 (XLA's
+  form writes float32 copies of the rows and reduces them again).
 - :func:`softmax_xent` — fused logsumexp + gather loss for LM heads,
   avoiding the [N, V] softmax materialization.
 
@@ -22,8 +25,9 @@ chip's compiler would refuse raises here, with its shapes, where it is
 bound for a TPU: at once for operands on one, and under a trace when the
 program is lowered for one (a program lowered for the CPU never meets
 the limit, and the interpreter has none). Nothing gives way to the jnp
-formulation quietly. The row kernels (norms, softmax, cross-entropy) are
-forward-only: their backward passes are jax.custom_vjp rules in plain jnp.
+formulation quietly. The row kernels (softmax, cross-entropy, LayerNorm)
+are forward-only: their backward passes are jax.custom_vjp rules in plain
+jnp; RMSNorm, which every decoder block runs, has a backward kernel too.
 Attention has kernels in both directions (``attention_forward``,
 ``attention_backward``): no [Tq, Tk] array exists in either, and
 ``flash_attention`` / ``flash_attention_lse`` take the same backward.
@@ -40,7 +44,8 @@ from jax.interpreters import batching, mlir
 from .registry import dear
 
 __all__ = ['flash_attention', 'flash_attention_lse', 'fused_rmsnorm',
-           'fused_layernorm', 'fused_softmax', 'softmax_xent']
+           'fused_rmsnorm_bwd', 'fused_layernorm', 'fused_softmax',
+           'softmax_xent']
 
 
 _NEG = -1e30
@@ -55,6 +60,18 @@ _NEG = -1e30
 # kernel still compiles at the widest row that leaves the 8-row minimum
 # (65536 elements, f32 and bf16); a 50k-word LM head fits with room.
 _ROW_TILE_BYTES = 2 << 20
+# RMSNorm's two kernels take their rows by bytes alone, so that a head's
+# narrow row gets a tall block (2048 rows of 128, 128 of 2048: a grid step
+# costs half a microsecond whatever it moves): half the tile, because the
+# backward kernel reads two arrays and holds twice the temporaries
+_RMS_TILE_BYTES = 1 << 20
+# rows narrower than this take the backward kernel (a head's 64 or 128, a
+# latent's 512 or 1536); from here on XLA's form of the backward pass runs
+# at the bandwidth alone on the chip (2048: 0.096 ms where the kernel takes
+# 0.108; 3072: 0.151 and 0.163) and inside a training step its reductions
+# fuse into the neighbouring products, which a kernel's operands cannot
+# (lfm2_fit_8k lost 1.9% with kernels as its block norms: PERF.md, PR 47)
+_RMS_BWD_WIDTHS = 2048
 # flash forward holds whole-axis K and V blocks, double-buffered
 _FLASH_KV_BYTES = 10 << 20
 # Mosaic's default scope, and what the one backward kernel of blockwise
@@ -461,7 +478,8 @@ def fused_rmsnorm(x, gamma, eps=1e-6):
     """RMSNorm in one VMEM pass over the feature dim."""
     def kern(x_ref, g_ref, o_ref):
         _rmsnorm_kernel(x_ref, g_ref, o_ref, eps)
-    return _norm_call('fused_rmsnorm', kern, (gamma,), x)
+    return _norm_call('fused_rmsnorm', kern, (gamma,), x,
+                      block_rows=_RMS_TILE_BYTES // (4 * x.shape[-1]))
 
 
 def _rms_ref(x, gamma, eps):
@@ -474,10 +492,67 @@ def _rms_fwd(x, gamma, eps):
     return fused_rmsnorm(x, gamma, eps), (x, gamma)
 
 
+def _rmsnorm_bwd_kernel(x_ref, dy_ref, g_ref, dx_ref, dg_ref, eps):
+    """One block of rows: dx, and the block's part of the gain's gradient
+    added into ``dg_ref`` [8, D], which every grid step revisits (eight
+    partial rows, so that the sum over a block's rows is whole-tile adds;
+    the grid runs in order, so the same inputs give the same bits)."""
+    x = x_ref[:].astype(jnp.float32)
+    dy = dy_ref[:].astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    xh = x * inv
+    t = dy * g_ref[:].astype(jnp.float32)
+    dx_ref[:] = (inv * (t - xh * jnp.mean(t * xh, axis=-1, keepdims=True))
+                 ).astype(dx_ref.dtype)
+    part = (dy * xh).reshape(-1, 8, x.shape[-1]).sum(axis=0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        dg_ref[:] = part
+
+    @pl.when(pl.program_id(0) > 0)
+    def _():
+        dg_ref[:] += part
+
+
+def fused_rmsnorm_bwd(x, gamma, dy, eps=1e-6):
+    """(dx, dgamma) of :func:`fused_rmsnorm` in one pass over x and dy:
+    statistics, row sums and dgamma in float32, dx in x's dtype, dgamma
+    float32 [D]. Rows go by bytes (a narrow row gets a tall block); rows
+    padded up to a block are zeros and add nothing to dgamma."""
+    D = x.shape[-1]
+    x2, dy2 = x.reshape(-1, D), dy.reshape(-1, D)
+    N = x2.shape[0]
+    if N == 0:                       # empty batch: nothing to launch
+        return jnp.zeros_like(x), jnp.zeros((D,), jnp.float32)
+    name = 'fused_rmsnorm_bwd'
+    want, too_big = _row_block(name, max(8, _RMS_TILE_BYTES // (4 * D)), x2)
+    pad = (-N) % 8                   # whole tiles for the eight partial rows
+    blk = _pick_block(want, N + pad)
+    if pad:
+        x2, dy2 = _pad0(x2, pad), _pad0(dy2, pad)
+
+    def kern(x_ref, dy_ref, g_ref, dx_ref, dg_ref):
+        _rmsnorm_bwd_kernel(x_ref, dy_ref, g_ref, dx_ref, dg_ref, eps)
+    rows = pl.BlockSpec((blk, D), lambda i: (i, 0))
+    dx, dg = run_kernel(lambda interpret: pl.pallas_call(
+        kern,
+        grid=((N + pad) // blk,),
+        in_specs=[rows, rows, pl.BlockSpec((D,), lambda i: (0,))],
+        out_specs=[rows, pl.BlockSpec((8, D), lambda i: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((N + pad, D), x.dtype),
+                   jax.ShapeDtypeStruct((8, D), jnp.float32)],
+        interpret=interpret, name=name), x2, dy2, gamma, too_big=too_big)
+    return dx[:N].reshape(x.shape), dg.sum(axis=0)
+
+
 def _rms_bwd(eps, res, g):
     x, gamma = res
-    _, vjp = jax.vjp(lambda x, gm: _rms_ref(x, gm, eps), x, gamma)
-    return vjp(g)
+    if x.shape[-1] >= _RMS_BWD_WIDTHS:
+        _, vjp = jax.vjp(lambda x, gm: _rms_ref(x, gm, eps), x, gamma)
+        return vjp(g)
+    dx, dg = fused_rmsnorm_bwd(x, gamma, g, eps)
+    return dx, dg.astype(gamma.dtype)
 
 
 fused_rmsnorm.defvjp(_rms_fwd, _rms_bwd)

@@ -299,12 +299,13 @@ def test_plan_metric(case):
 # checkpoint's, test_latent_ops.py), and on that of PR 43, which changed
 # the expert layer's backward pass by intent, and on that of PR 46, which
 # changed the way back from the sorted rows to the tokens by intent
-# (test_latent_ops.py says how).
+# (test_latent_ops.py says how, and why 'kernel' was taken again on that of
+# PR 47).
 KANANA_TEXT = {
     'plain':
     '2526a27d6cd2d0aa0ed3a1c06b03358270fccfda29103acb89ca841b2fb5c460',
     'kernel':
-    '900cc8601a52aafe9349c5205883c93191c35fb1e0c4d6dd55928392f5cfcae1'}
+    '1c092675e8efc2d2c0915adf12e6d24f80174df4e4c97526f5bfb896b3c03208'}
 
 
 def kanana_step_digest():

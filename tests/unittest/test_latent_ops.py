@@ -550,15 +550,19 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # the way back from the sorted rows to the tokens by intent (a kernel adds
 # each row into its token's sum where k gathers a direction walked the whole
 # sequence; output, dx and d_pairs are the float32 reference's to 1e-5,
-# test_transformer_ops.py); before that they were PR 41's (what a
-# mirrored stage keeps), PR 33's, and those of the commit before this family
-# came (faf5f29). The text is this jax's; a change of jax (or of Laguna's
+# test_transformer_ops.py). 'kernel' alone was taken again on the tree of PR
+# 47, which changed RMSNorm's two kernels by intent: the backward rule of rows
+# under 2048 elements is the kernel fused_rmsnorm_bwd (the gradients are jax.vjp's of the plain formula
+# to float32 rounding, test_pallas.py) and both take their rows by bytes;
+# 'plain', the path the CPU takes, did not move.
+# Before that they were PR 41's (what a mirrored stage keeps), PR 33's, and
+# those of the commit before this family came (faf5f29). The text is this jax's; a change of jax (or of Laguna's
 # own ops) needs them taken again.
 LAGUNA_TEXT = {
     'plain':
     '39a7a0a2bf354847c208eb600bcea396eeec80f4cbd01278c69a5eabc9805577',
     'kernel':
-    'aabc1a13d961b21cde412dbad0e4b5f0e83a6b9800a8c47afe5d4a44e7777a34'}
+    '1b6e6ccdd90431441a36630704f7031df227daa7009fb6ee12613f69ff493e38'}
 
 
 def laguna_step_digest():

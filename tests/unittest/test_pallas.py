@@ -69,6 +69,55 @@ def test_fused_rmsnorm():
                                    rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['f32', 'bf16'])
+@pytest.mark.parametrize('lead', [(3, 1368), (1037,), (0,)],
+                         ids=['blocks', 'padded', 'empty'])
+@pytest.mark.parametrize('D', [64, 128, 2048])
+def test_fused_rmsnorm_backward_kernel(monkeypatch, D, lead, dtype):
+    """RMSNorm's backward rule is the kernel ``fused_rmsnorm_bwd``
+    (interpreted here, through the registry op under MXTPU_FORCE_PALLAS=1):
+    its gradients are ``jax.vjp``'s of the plain formula to float32
+    rounding, over several row blocks (4104 rows), a row count with no
+    legal divisor (1037: padded) and an empty batch; dgamma is float32 and
+    the same bits call after call."""
+    from mxnet_tpu.ops.registry import get
+    monkeypatch.setenv('MXTPU_FORCE_PALLAS', '1')
+    x = _rand(*lead, D, seed=D).astype(dtype)
+    dy = _rand(*lead, D, seed=D + 1).astype(dtype)
+    g = 1 + 0.1 * _rand(D, seed=D + 2)
+    _, vjp = jax.vjp(lambda x, g: get('RMSNorm').fn({}, x, g), x, g)
+    _, ref_vjp = jax.vjp(lambda x, g: pk._rms_ref(x, g, 1e-6), x, g)
+    # an empty batch launches nothing; rows of 2048 and more keep XLA's form
+    assert ('fused_rmsnorm_bwd' in str(jax.make_jaxpr(vjp)(dy))) == (
+        x.size > 0 and D < pk._RMS_BWD_WIDTHS)
+    (dx, dg), (rdx, rdg) = vjp(dy), ref_vjp(dy)
+    assert dx.dtype == dtype and dx.shape == x.shape
+    assert dg.dtype == jnp.float32 and dg.shape == (D,)
+    rdx = np.asarray(rdx.astype(jnp.float32))
+    x32, dy32 = x.astype(jnp.float32), dy.astype(jnp.float32)
+    terms = np.asarray(jnp.abs(dy32 * x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, -1, keepdims=True) + 1e-6)).reshape(-1, D).sum(0))
+
+    def check(dx, dg):
+        # one unit in bfloat16's last place is 2^-7 of the value at most
+        np.testing.assert_allclose(
+            np.asarray(dx.astype(jnp.float32)), rdx, atol=1e-6,
+            rtol=1e-5 if dtype == jnp.float32 else 2.0 ** -7)
+        # a sum over the rows: to 1e-5 of the sum of its terms' sizes
+        assert np.all(np.abs(np.asarray(dg - rdg)) <= 1e-5 * terms)
+
+    check(dx, dg)
+    # the kernel itself, at any width: right, and the same bits again
+    dx, dg = pk.fused_rmsnorm_bwd(x, g, dy)
+    assert dg.dtype == jnp.float32
+    check(dx, dg)
+    dx2, dg2 = pk.fused_rmsnorm_bwd(x, g, dy)
+    np.testing.assert_array_equal(np.asarray(dg2), np.asarray(dg))
+    np.testing.assert_array_equal(np.asarray(dx2.astype(jnp.float32)),
+                                  np.asarray(dx.astype(jnp.float32)))
+
+
 def test_fused_layernorm():
     x = _rand(8, 32, seed=5)
     g = _rand(32, seed=6)

@@ -301,6 +301,50 @@ def test_a_mirrored_stage_reads_refwd(tele_on, monkeypatch):
     assert any(v[4] > 1 for v in m['instrs'].values())
 
 
+def norm_block_step():
+    """(step, parameters, nodes) of a small block whose RMSNorm lies in a
+    mirrored stage: step(parameters) -> (outputs, gradients), traced as
+    the fused window traces a symbol's runner. (tests/unittest/
+    test_tpu_compile.py compiles the same step for a described v5e.)"""
+    from mxnet_tpu.executor import _GraphProgram
+    from test_transformer_ops import _training_step
+    fc = lambda x, n, name: mx.sym.FullyConnected(  # noqa: E731
+        x, num_hidden=n, no_bias=True, flatten=False, name=name)
+    h = fc(mx.sym.Variable('data'), 128, 'fc0')
+    with mx.AttrScope(__force_mirroring__='stage1'):
+        h = mx.sym.RMSNorm(h, mx.sym.Variable('norm_gamma'), name='norm')
+        h = mx.sym.Activation(fc(h, 128, 'fc1'), act_type='tanh',
+                              name='tanh1')
+    sym = mx.sym.SoftmaxOutput(mx.sym.Reshape(fc(h, 8, 'fc2'),
+                                              shape=(-1, 8)), name='softmax')
+    step, wrt = _training_step(sym, data=(2, 16, 64), softmax_label=(32,))
+    prog = _GraphProgram(sym)
+    nodes = {s: n.op for s, n in zip(prog.scope_names, prog.topo)
+             if not n.is_variable()}
+    return step, wrt, nodes
+
+
+def test_rmsnorm_backward_kernel_lies_under_bwd():
+    """The block's training step lowered for the TPU: the backward kernel's
+    one call has a path that reads as RMSNorm `bwd` with the kernel's name
+    below the node, though the node lies in a mirrored stage, whose second
+    forward runs the forward kernel again (`refwd`)."""
+    import jax
+    step, wrt, nodes = norm_block_step()
+    text = jax.jit(step).trace(wrt).lower(lowering_platforms=('tpu',)) \
+        .as_text(debug_info=True)
+    paths = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = [(kernel, programs.scope_of(paths[at], nodes))
+             for kernel, at in re.findall(
+                 r'kernel_name = "(fused_rmsnorm\w*)".*loc\((#loc\d+)\)$',
+                 text, re.M)]
+    assert sorted(calls) == [
+        ('fused_rmsnorm', ('norm', 'fwd', 'fused_rmsnorm')),
+        ('fused_rmsnorm', ('norm', 'refwd', 'fused_rmsnorm')),
+        ('fused_rmsnorm_bwd', ('norm', 'bwd', 'fused_rmsnorm_bwd'))]
+    assert nodes['norm'] == 'RMSNorm'
+
+
 def test_set_up_spans(tele_on):
     _fit(_net())
     spans = [r for r in _records(str(tele_on)) if r.get('type') == 'span']

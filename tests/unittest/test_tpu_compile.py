@@ -127,6 +127,16 @@ KERNELS = [
      [((8192, 1024), BF16), ((1024,), F32), ((1024,), F32)]),
     ('rmsnorm_8192x4096', pk.fused_rmsnorm,
      [((8192, 4096), BF16), ((4096,), F32)]),
+    # RMSNorm's backward kernel at the decoder cells' shapes: the per-head
+    # norms (heads 128 and 64 wide on 8192 rows) and the block norms
+    ('rmsnorm_bwd_262144x128', pk.fused_rmsnorm_bwd,
+     [((262144, 128), BF16), ((128,), BF16), ((262144, 128), BF16)]),
+    ('rmsnorm_bwd_262144x64', pk.fused_rmsnorm_bwd,
+     [((262144, 64), BF16), ((64,), BF16), ((262144, 64), BF16)]),
+    ('rmsnorm_bwd_8192x2048', pk.fused_rmsnorm_bwd,
+     [((8192, 2048), BF16), ((2048,), BF16), ((8192, 2048), BF16)]),
+    ('rmsnorm_bwd_8192x3584', pk.fused_rmsnorm_bwd,
+     [((8192, 3584), BF16), ((3584,), BF16), ((8192, 3584), BF16)]),
     ('softmax_8192x1024', pk.fused_softmax, [((8192, 1024), BF16)]),
     ('softmax_32x1000', pk.fused_softmax, [((32, 1000), F32)]),
     ('xent_32x1000', pk.softmax_xent, [((32, 1000), F32), ((32,), I32)]),
@@ -322,6 +332,27 @@ def test_short_conv_off_the_lanes_raises_with_shapes(one_chip):
     args = [jax.ShapeDtypeStruct((1, 256, 192), BF16),
             jax.ShapeDtypeStruct((64, 3), BF16)]
     assert 'tpu_custom_call' not in jax.jit(fn).lower(*args).as_text()
+
+
+def test_rmsnorm_backward_kernel_in_a_compiled_step_scope_map(one_chip):
+    """A small block's training step compiled for the v5e, walked by the
+    scope map: the call of `fused_rmsnorm_bwd` is an instruction of that
+    name under RMSNorm `bwd`; under `refwd` (the node lies in a mirrored
+    stage) and `fwd` stands the forward kernel alone."""
+    from mxnet_tpu.telemetry import programs
+    from test_scope_map import norm_block_step
+    step, wrt, nodes = norm_block_step()
+    specs = tuple(jax.ShapeDtypeStruct(w.shape, w.dtype, sharding=one_chip)
+                  for w in wrt)
+    compiled = jax.jit(step).lower(specs).compile()
+    m = programs.scope_map(programs._hlo_text(compiled), nodes)
+    calls = sorted((name.rsplit('.', 1)[0], v[0], v[1])
+                   for name, v in m['instrs'].items()
+                   if v[3] == 'custom-call' and 'rmsnorm' in name)
+    assert calls == [('fused_rmsnorm', 'norm', 'fwd'),
+                     ('fused_rmsnorm', 'norm', 'refwd'),
+                     ('fused_rmsnorm_bwd', 'norm', 'bwd')], calls
+    assert m['nodes']['norm'] == 'RMSNorm'
 
 
 def test_row_block_follows_width():
