@@ -406,7 +406,6 @@ inline Symbol GroupedQueryAttention(const std::string &symbol_name,
     int num_kv_heads = 1,
     int window = 0,
     bool gated = false,
-    const std::string &mask = "causal",
     int block_length = 0) {
   Operator op("GroupedQueryAttention");
   op.SetInput("query", query);
@@ -417,7 +416,6 @@ inline Symbol GroupedQueryAttention(const std::string &symbol_name,
   op.SetParam("num_kv_heads", detail::ToParam(num_kv_heads));
   op.SetParam("window", detail::ToParam(window));
   op.SetParam("gated", detail::ToParam(gated));
-  if (!mask.empty()) op.SetParam("mask", detail::ToParam(mask));
   op.SetParam("block_length", detail::ToParam(block_length));
   return op.CreateSymbol(symbol_name);
 }

@@ -29,7 +29,7 @@ over a head's columns (one gain for all heads of q, one for k) before the
 rotary turn (all of a head's dimensions, halves paired, ``rope_theta``),
 whose positions restart at the clean half (``RotaryEmbedding(period=L)``:
 both copies of position p sit at p). The attention is
-``GroupedQueryAttention(mask='block_diffusion', block_length=B)``: a noisy
+``GroupedQueryAttention(block_length=B)``: a noisy
 row sees its own noisy block in both directions and the clean blocks
 strictly before it; a clean row the clean blocks up to and including its
 own; nothing sees another block's noise (Block Diffusion,
@@ -120,7 +120,7 @@ def get_symbol(config, dtype='float32', remat=True, seq_len=None, **kwargs):
             query=head_norm_rope(linear(a, p + '_q', H * D), p + '_q', H, L),
             key=head_norm_rope(linear(a, p + '_k', KV * D), p + '_k', KV, L),
             value=linear(a, p + '_v', KV * D), num_heads=H, num_kv_heads=KV,
-            mask='block_diffusion', block_length=block_length, name=p)
+            block_length=block_length, name=p)
         return linear(o, p + '_o', d)
 
     def experts_layer(b, p):

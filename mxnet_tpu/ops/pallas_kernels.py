@@ -5,8 +5,9 @@ cudnn wrappers — SURVEY.md N6); on TPU, XLA's fusion already covers
 most of that, and these kernels target what XLA does NOT schedule
 optimally on the MXU/VMEM hierarchy:
 
-- :func:`flash_attention` — O(T) VMEM attention: online-softmax over
-  K/V tiles streamed through VMEM; no [Tq, Tk] score matrix in HBM.
+- :func:`blockwise_attention` — attention, forward and backward, over
+  the blocks a mask leaves (a :class:`Walk`): online softmax over K/V
+  blocks streamed through VMEM; no [Tq, Tk] score matrix in HBM.
 - :func:`fused_rmsnorm` / :func:`fused_layernorm` — one pass over the
   feature dim in VMEM (XLA emits separate reduce+scale passes).
 - :func:`fused_rmsnorm_bwd` — RMSNorm's backward rule: one pass over x
@@ -30,10 +31,12 @@ are forward-only: their backward passes are jax.custom_vjp rules in plain
 jnp; RMSNorm, which every decoder block runs, has a backward kernel too.
 Attention has kernels in both directions (``attention_forward``,
 ``attention_backward``): no [Tq, Tk] array exists in either, and
-``flash_attention`` / ``flash_attention_lse`` take the same backward.
+``flash_attention`` / ``flash_attention_lse`` are the same kernels behind
+[B, T, H, D] operands.
 """
 import functools
 import types
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -72,8 +75,6 @@ _RMS_TILE_BYTES = 1 << 20
 # fuse into the neighbouring products, which a kernel's operands cannot
 # (lfm2_fit_8k lost 1.9% with kernels as its block norms: PERF.md, PR 47)
 _RMS_BWD_WIDTHS = 2048
-# flash forward holds whole-axis K and V blocks, double-buffered
-_FLASH_KV_BYTES = 10 << 20
 # Mosaic's default scope, and what the one backward kernel of blockwise
 # attention may hold beside it for a whole sequence (_bwd_vmem): three
 # eighths of a v5e core's 128 MiB of VMEM (jax pallas/mosaic/tpu_info.py)
@@ -167,8 +168,7 @@ def _pick_block(want, n):
     to the whole axis (always legal, but only sensible when the full
     block fits VMEM — the row kernels pre-pad ``n`` to a multiple of 8
     via :func:`_pad_and_block` so they never take the fallback on awkward
-    sizes; flash q tiles share the fallback with the by-design
-    full-axis K/V blocks)."""
+    sizes)."""
     for b in range(min(want, n), 0, -1):
         if n % b == 0 and _block_ok(b, n):
             return b
@@ -204,232 +204,6 @@ def _row_block(name, want, x2):
             % (name, D, tuple(x2.shape), x2.dtype.name, 8 * 4 * D,
                _ROW_TILE_BYTES))
     return min(want, rows - rows % 8), None
-
-
-# ---------------------------------------------------------------------------
-# Flash attention
-# ---------------------------------------------------------------------------
-
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, causal, scale, blk_q,
-                  blk_k, offset):
-    """Grid: (batch*heads, Tq/blk_q). K/V streamed in blk_k tiles.
-
-    `offset` = Tk - Tq aligns the causal mask bottom-right (decode
-    convention): query row i may see key cols <= i + offset — identical
-    to the oracle's tril(ones(Tq, Tk), Tk - Tq) in _flash_ref.
-    """
-    q = q_ref[0].astype(jnp.float32) * scale          # [blk_q, D]
-    Tk = k_ref.shape[1]
-    qi = pl.program_id(1)
-
-    def body(start, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(start * blk_k, blk_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(start * blk_k, blk_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            mask = (qi * blk_q + rows + offset) >= (start * blk_k + cols)
-            s = jnp.where(mask, s, _NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        acc_new = acc * corr + jnp.dot(p, v,
-                                       preferred_element_type=jnp.float32)
-        return acc_new, m_new, l_new
-
-    total = Tk // blk_k
-    if causal:
-        # K blocks strictly after this q block's last visible col are
-        # fully masked: last visible col = (qi+1)*blk_q - 1 + offset
-        n_blocks = jnp.clip(pl.cdiv((qi + 1) * blk_q + offset, blk_k),
-                            0, total)
-    else:
-        n_blocks = total
-    acc = jnp.zeros((blk_q, v_ref.shape[2]), jnp.float32)
-    m = jnp.full((blk_q, 1), _NEG, jnp.float32)
-    l = jnp.zeros((blk_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, n_blocks, body, (acc, m, l))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    # log-sum-exp of the scaled scores per query row — lets callers (ring
-    # attention) merge normalized per-chunk outputs exactly. Kept as a
-    # [blk_q, 1] column: a (1, blk_q, 1) block is Mosaic-legal (minor dim
-    # equals the array's), a (1, blk_q) one is not (second-to-minor 1).
-    lse_ref[0] = (m + jnp.log(jnp.maximum(l, 1e-30)))
-
-
-def _flash_fwd_impl(q, k, v, causal, scale, blk_q, blk_k):
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    if causal and Tq > Tk:
-        # bottom-right alignment gives the first Tq-Tk query rows zero
-        # visible keys (softmax over empty set — NaN in the oracle);
-        # reject rather than return silently-wrong finite values
-        raise ValueError('causal attention requires Tq <= Tk '
-                         '(got Tq=%d, Tk=%d)' % (Tq, Tk))
-    if Tk == 0:
-        # softmax over an empty key set is undefined (NaN in the
-        # oracle); fail loudly instead of tracing a 0-size block
-        raise ValueError('attention requires at least one key (Tk=0)')
-    if B * H == 0 or Tq == 0:        # empty batch/seq: nothing to launch
-        return (jnp.zeros((B, Tq, H, D), q.dtype),
-                jnp.zeros((B, H, Tq), jnp.float32))
-    # block_q/block_k are advisory: coerced to the largest Mosaic-legal
-    # divisor of the axis (<= requested). The q axis is PADDED (zeros,
-    # sliced off below) when it has no small legal divisor — a
-    # whole-axis blk_q would put an O(Tq x blk_k) score tile in VMEM.
-    # blk_k may fall back to Tk: the K/V blocks are full-axis by design,
-    # and the score tile stays bounded by blk_q rows.
-    pad_q, blk_q = _pad_and_block(min(blk_q, Tq), Tq)
-    blk_k = _pick_block(blk_k, Tk)
-    kv_bytes = 2 * 2 * Tk * D * k.dtype.itemsize
-    too_big = None
-    if kv_bytes > _FLASH_KV_BYTES:
-        # the chip's compiler would answer "Ran out of memory in memory
-        # space vmem"; a blockwise K loop is ROADMAP S7
-        too_big = (
-            'flash_attention: keys/values %s %s need %d bytes of VMEM as '
-            'whole-axis blocks (q %s), more than the %d this kernel '
-            'allows; shorten Tk or shard the sequence (ring_attention)'
-            % (tuple(k.shape), k.dtype.name, kv_bytes, tuple(q.shape),
-               _FLASH_KV_BYTES))
-    # [B, T, H, D] -> [B*H, T, D] for a clean 2-d grid
-    qh = q.transpose(0, 2, 1, 3).reshape(B * H, Tq, D)
-    kh = k.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    vh = v.transpose(0, 2, 1, 3).reshape(B * H, Tk, D)
-    if pad_q:
-        # zero q rows appended past Tq: their scores are 0 -> a uniform
-        # finite softmax; the causal offset keys off the ORIGINAL Tq and
-        # the rows are sliced off below, so real rows are untouched
-        qh = jnp.concatenate(
-            [qh, jnp.zeros((B * H, pad_q, D), qh.dtype)], axis=1)
-    Tq_p = Tq + pad_q
-
-    kernel = functools.partial(_flash_kernel, causal=causal, scale=scale,
-                               blk_q=blk_q, blk_k=blk_k, offset=Tk - Tq)
-    out, lse = run_kernel(lambda interpret: pl.pallas_call(
-        kernel,
-        grid=(B * H, Tq_p // blk_q),
-        in_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Tk, D), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, blk_q, D), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, blk_q, 1), lambda b, i: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B * H, Tq_p, D), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, Tq_p, 1), jnp.float32)],
-        interpret=interpret, name='flash_attention_fwd'), qh, kh, vh,
-        too_big=too_big)
-    out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
-    lse = lse[:, :Tq].reshape(B, H, Tq)
-    return out, lse
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128):
-    """Memory-efficient attention; shapes [B, T, H, D] like
-    ring_attention.attention_reference (its numeric oracle).
-
-    ``block_q``/``block_k`` are advisory tile sizes: they are coerced to
-    the largest Mosaic-legal divisor of the respective sequence axis
-    (so non-dividing or non-8-multiple requests silently shrink/grow
-    rather than erroring)."""
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)[0]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_lse(q, k, v, causal=False, scale=None, block_q=128,
-                        block_k=128):
-    """flash_attention that also returns the per-row log-sum-exp
-    [B, H, Tq] — the merge statistic ring attention needs to combine
-    normalized chunk outputs exactly. Backward recomputes via the
-    reference formulation (flash-paper strategy), with the lse cotangent
-    folded in (ring attention's merge weights depend on lse)."""
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)
-
-
-def _flash_lse_ref(q, k, v, causal, scale):
-    """(out, lse) in plain jnp: the dense oracle of the tests."""
-    s = jnp.einsum('bqhd,bkhd->bhqk', q * scale, k)
-    if causal:
-        Tq, Tk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
-        s = jnp.where(mask, s, _NEG)
-    lse = jax.nn.logsumexp(s, axis=-1)
-    p = jnp.exp(s - lse[..., None])
-    return jnp.einsum('bhqk,bkhd->bqhd', p, v), lse
-
-
-def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k):
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    out, lse = _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)
-    return (out, lse), (q, k, v, out, lse)
-
-
-def _flash_lse_bwd(causal, scale, block_q, block_k, res, g):
-    q, k, v, out, lse = res
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash_bwd_blockwise(q, k, v, out, lse, g[0], g[1], causal, s,
-                                block_q, block_k)
-
-
-flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
-
-
-def _flash_ref(q, k, v, causal, scale):
-    s = jnp.einsum('bqhd,bkhd->bhqk', q * scale, k)
-    if causal:
-        Tq, Tk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
-        s = jnp.where(mask, s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum('bhqk,bkhd->bqhd', p, v)
-
-
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    out, lse = _flash_fwd_impl(q, k, v, causal, s, block_q, block_k)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd(causal, scale, block_q, block_k, res, g):
-    q, k, v, out, lse = res
-    s = scale if scale is not None else q.shape[-1] ** -0.5
-    return _flash_bwd_blockwise(q, k, v, out, lse, g, None, causal, s,
-                                block_q, block_k)
-
-
-def _flash_bwd_blockwise(q, k, v, out, lse, g_out, g_lse, causal, scale,
-                         block_q, block_k):
-    """The backward of both flash entry points: the blockwise kernels of
-    :func:`attention_backward` (no [Tq, Tk] array in HBM), each head a
-    row of the batch. A cotangent of the log-sum-exp (ring attention's
-    merge weights depend on it) enters as a shift of the rows' ``delta``:
-    d lse_i / d s_ij = p_ij."""
-    B, Tq, H, D = q.shape
-    Tk = k.shape[1]
-    if B * H == 0 or Tq == 0:
-        return jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
-    rows = lambda x: x.transpose(0, 2, 1, 3).reshape(  # noqa: E731
-        B * H, x.shape[1], D)
-    dq, dk, dv = attention_backward(
-        rows(q), rows(k), rows(v), rows(out), lse.reshape(B * H, 1, Tq),
-        rows(g_out), heads=1, kv_heads=1, causal=causal, window=0,
-        scale=scale, block_q=block_q, block_k=block_k,
-        g_lse=None if g_lse is None else g_lse.reshape(B * H, 1, Tq),
-        name='flash_attention')
-    back = lambda x, T: x.reshape(B, H, T, D).transpose(  # noqa: E731
-        0, 2, 1, 3)
-    return back(dq, Tq), back(dk, Tk), back(dv, Tk)
-
-
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -674,62 +448,55 @@ softmax_xent.defvjp(_xent_fwd, _xent_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Blockwise attention: forward and backward, causal, windowed, grouped-query
+# Blockwise attention: forward and backward, grouped-query, under any mask
+# that is a Walk
 # ---------------------------------------------------------------------------
 # q is [B, Tq, H * D] and k, v are [B, Tk, KV * D], the layout a projection
 # leaves them in: a block (1, blk, D) of head h is the column block h, so
 # no transpose is made and query head h reads key/value head h // (H / KV).
-# The grid's last axis walks only the key blocks a query block can see
-# (those under the diagonal, and inside the window): a key block outside is
-# neither fetched (the index map stays on the last block that was) nor
-# computed. The backward is one kernel: for each visible pair of a query
-# block and a key block it makes the scores, the mask, p, dp and ds once
-# (keys first, [blk_k, blk_q], so that dk and dv take them as they lie and
-# only dq's product transposes ds) and adds to all three gradients. Its grid
-# walks, inside one key/value head's cell, the group's query heads, their
-# query blocks and each block's key blocks, with dq's block in scratch; dk
-# and dv of the head's whole sequence stay in VMEM, added into by a row
-# slice and written when the cell ends. Whether they fit is a function of
-# the shapes (`_bwd_vmem`); for a sequence past it the backward is the two
-# kernels it was, one per side: dq over the key blocks of a query block,
-# dk/dv over the query blocks (and the query heads of the group) of a key
-# block, each making the scores for itself. Nothing of size Tq x Tk exists
-# in either direction.
+# The grid's last axis walks only the key blocks a query block can see (the
+# mask's `Walk`, below): a key block outside is neither fetched (the index
+# map stays on the last block that was) nor computed. The backward is one
+# kernel: for each visible pair of a query block and a key block it makes
+# the scores, the mask, p, dp and ds once (keys first, [blk_k, blk_q], so
+# that dk and dv take them as they lie and only dq's product transposes ds)
+# and adds to all three gradients. Its grid walks, inside one key/value
+# head's cell, the group's query heads, their query blocks and each block's
+# key blocks, with dq's block in scratch; dk and dv of the head's whole
+# sequence stay in VMEM, added into by a row slice and written when the cell
+# ends. Whether they fit is a function of the shapes (`_bwd_vmem`); for a
+# sequence past it the backward is the two kernels it was, one per side: dq
+# over the key blocks of a query block, dk/dv over the query blocks (and the
+# query heads of the group) of a key block, each making the scores for
+# itself. Nothing of size Tq x Tk exists in either direction.
 
-def _attn_geometry(Tq, Tk, blk_q, blk_k, causal, window):
-    """Block counts, and for each side the (first, last) block of the other
-    side that it touches, as functions of a (traced or Python) block index;
-    `steps_*` is the static length of the grid's walking axis."""
-    offset = Tk - Tq
-    nq, nk = Tq // blk_q, Tk // blk_k
+class Walk(typing.NamedTuple):
+    """What a mask means to a blockwise kernel: which blocks of the other
+    side a block touches, in what order, and the mask of a pair.
 
-    def keys_of(i):                         # key blocks of query block i
-        hi = ((i + 1) * blk_q - 1 + offset) // blk_k if causal else nk - 1
-        lo = (i * blk_q + offset - window + 1) if window else 0
-        lo = _imax(lo, 0) // blk_k
-        return lo, _imin(_imax(hi, 0), nk - 1)
+    ``key_block(i, s)`` is (the s-th key block that query block i touches,
+    held on the last one once the walk has ended; whether it has not), and
+    ``query_block(j, s)`` the same of the query blocks of key block j, as
+    functions of traced or Python indices. `key_steps` and `query_steps` are
+    the static lengths of the two walks (a grid's walking axis), `nq` and
+    `nk` the blocks a side. ``seen(qi, kj, keys_first=False)`` is the mask
+    of query block qi against key block kj, [blk_q, blk_k] ([blk_k, blk_q]
+    with `keys_first`).
 
-    def queries_of(j):                      # query blocks of key block j
-        lo = _imax(j * blk_k - offset, 0) // blk_q if causal else 0
-        hi = ((j + 1) * blk_k - 1 - offset + window - 1) // blk_q \
-            if window else nq - 1
-        return _imin(lo, nq - 1), _imin(_imax(hi, 0), nq - 1)
-
-    span = lambda f, n: max(  # noqa: E731
-        1, max(f(i)[1] - f(i)[0] + 1 for i in range(n)))
-    return nq, nk, keys_of, queries_of, span(keys_of, nq), \
-        span(queries_of, nk)
-
-
-def _attn_walk(Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window):
-    """_attn_geometry of the lengths as the kernels see them. Where an
-    axis had to be padded (a length that is no multiple of 8: tests and odd
-    shapes) every block walks every block of the other side; the mask,
-    which keys off the true lengths, stays exact."""
-    if not (pad_q or pad_k):
-        return _attn_geometry(Tq, Tk, blk_q, blk_k, causal, window)
-    nq, nk = (Tq + pad_q) // blk_q, (Tk + pad_k) // blk_k
-    return (nq, nk, lambda i: (0, nk - 1), lambda j: (0, nq - 1), nk, nq)
+    An index map reads the held block, so that a step past a walk's end
+    fetches nothing; a kernel body takes both and works under
+    ``pl.when(live)``. Every block pair that holds a seen element is walked
+    once from either side (tests/unittest/test_transformer_ops.py holds every
+    walk to it). A new mask is one more function that returns this record:
+    the kernels, their wrappers and the latent family read nothing else of
+    a mask."""
+    nq: int
+    nk: int
+    key_block: typing.Callable
+    query_block: typing.Callable
+    key_steps: int
+    query_steps: int
+    seen: typing.Callable
 
 
 def _imax(a, b):
@@ -738,6 +505,11 @@ def _imax(a, b):
 
 def _imin(a, b):
     return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _iwhere(c, a, b):
+    return (a if c else b) if isinstance(c, (bool, int)) else \
+        jnp.where(c, a, b)
 
 
 def _visible(qi, kj, blk_q, blk_k, Tk, offset, causal, window,
@@ -760,159 +532,46 @@ def _visible(qi, kj, blk_q, blk_k, Tk, offset, causal, window,
     return seen
 
 
-def _dot(a, b, contract):
-    # the operands' own precision (one bf16 pass for bf16), whatever
-    # default the process has set: Mosaic refuses 'highest' on bf16
-    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
-                               precision=jax.lax.Precision.DEFAULT,
-                               preferred_element_type=jnp.float32)
+def _causal_walk(Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window):
+    """The :class:`Walk` of Tq queries over Tk keys, the mask aligned bottom
+    right: with `causal` a query sees the keys up to its own position, with
+    `window` w > 0 only the last w of them, and a block walks the one run of
+    blocks between the first and the last it touches. Where an axis had to
+    be padded (a length that is no multiple of 8: tests and odd shapes)
+    every block walks every block of the other side; the mask, which keys
+    off the true lengths, stays exact."""
+    offset = Tk - Tq
+    nq, nk = (Tq + pad_q) // blk_q, (Tk + pad_k) // blk_k
 
+    if pad_q or pad_k:
+        keys_of, queries_of = lambda i: (0, nk - 1), lambda j: (0, nq - 1)
+    else:
+        def keys_of(i):       # (first, last) key block of query block i
+            hi = ((i + 1) * blk_q - 1 + offset) // blk_k if causal else nk - 1
+            lo = (i * blk_q + offset - window + 1) if window else 0
+            lo = _imax(lo, 0) // blk_k
+            return lo, _imin(_imax(hi, 0), nk - 1)
 
-def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
-                     geo, scale):
-    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
-    qi, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = keys_of(qi)
+        def queries_of(j):    # (first, last) query block of key block j
+            lo = _imax(j * blk_k - offset, 0) // blk_q if causal else 0
+            hi = ((j + 1) * blk_k - 1 - offset + window - 1) // blk_q \
+                if window else nq - 1
+            return _imin(lo, nq - 1), _imin(_imax(hi, 0), nq - 1)
 
-    @pl.when(step == 0)
-    def _():
-        m_s[...] = jnp.full(m_s.shape, _NEG, jnp.float32)
-        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
-        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+    def one_run(blocks_of, n):
+        def block_of(i, s):
+            lo, hi = blocks_of(i)
+            at = lo + s
+            return _imin(at, hi), at <= hi
+        spans = (blocks_of(i) for i in range(n))
+        return block_of, max(1, max(hi - lo + 1 for lo, hi in spans))
 
-    @pl.when(lo + step <= hi)
-    def _():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
-                        window)
-        s = jnp.where(seen, _dot(q, k, ((1,), (1,))) * scale, _NEG)
-        m = m_s[...]
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        corr = jnp.exp(m - m_new)
-        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        l_s[...] = l_s[...] * corr + p.sum(axis=-1, keepdims=True)
-        acc_s[...] = acc_s[...] * corr + _dot(p.astype(v.dtype), v,
-                                              ((1,), (0,)))
-        m_s[...] = m_new
-
-    @pl.when(step == steps - 1)
-    def _():
-        l = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0] = (acc_s[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_s[...] + jnp.log(l)
-
-
-def _attn_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                    acc_s, *, geo, scale):
-    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
-    qi, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = keys_of(qi)
-
-    @pl.when(step == 0)
-    def _():
-        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
-
-    @pl.when(lo + step <= hi)
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = _dot(q, k, ((1,), (1,))) * scale
-        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
-                        window)
-        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
-        dp = _dot(do, v, ((1,), (1,)))
-        ds = p * (dp - delta_ref[0, 0])
-        acc_s[...] += _dot(ds.astype(k.dtype), k, ((1,), (0,))) * scale
-
-    @pl.when(step == steps - 1)
-    def _():
-        dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
-
-
-def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dk_ref, dv_ref, dk_s, dv_s, *, geo, scale, group):
-    blk_q, blk_k, Tk, offset, causal, window, queries_of, steps = geo
-    kj, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = queries_of(kj)
-    walk = step % steps
-
-    @pl.when(step == 0)
-    def _():
-        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
-        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
-
-    @pl.when(lo + walk <= hi)
-    def _():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = _dot(q, k, ((1,), (1,))) * scale
-        seen = _visible(lo + walk, kj, blk_q, blk_k, Tk, offset, causal,
-                        window)
-        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
-        dv_s[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
-        dp = _dot(do, v, ((1,), (1,)))
-        ds = p * (dp - delta_ref[0, 0])
-        dk_s[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
-
-    @pl.when(step == group * steps - 1)
-    def _():
-        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
-
-
-def _bwd_pair(s, seen, lse, delta, do, v):
-    """(p, ds) [blk_k, blk_q] of one block pair from its scaled scores, in
-    the operands' precision: the part of a backward step that dq, dk and dv
-    share."""
-    p = jnp.where(seen, jnp.exp(s - lse), 0.0)
-    dp = _dot(v, do, ((1,), (1,)))
-    return p.astype(do.dtype), (p * (dp - delta)).astype(do.dtype)
-
-
-def _block_rows(block, blk):
-    return pl.ds(pl.multiple_of(block * blk, blk), blk)
-
-
-def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                     dk_ref, dv_ref, dq_s, dk_s, dv_s, *, geo, scale):
-    """One key/value head's cell of the grid walks its group's query heads,
-    their query blocks and each block's visible key blocks; dk and dv of
-    the whole sequence stay in VMEM until the cell ends."""
-    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
-    member, qi, step = (pl.program_id(axis) for axis in (2, 3, 4))
-    lo, hi = keys_of(qi)
-    first = (member == 0) & (qi == 0) & (step == 0)
-    last = (member == pl.num_programs(2) - 1) \
-        & (qi == pl.num_programs(3) - 1) & (step == steps - 1)
-
-    @pl.when(first)
-    def _():
-        dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
-        dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
-
-    @pl.when(step == 0)
-    def _():
-        dq_s[...] = jnp.zeros(dq_s.shape, jnp.float32)
-
-    @pl.when(lo + step <= hi)
-    def _():
-        q, k, do = q_ref[0], k_ref[0], do_ref[0]
-        s = _dot(k, q, ((1,), (1,))) * scale
-        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
-                        window, keys_first=True)
-        p, ds = _bwd_pair(s, seen, lse_ref[0, 0, 0], delta_ref[0, 0, 0], do,
-                          v_ref[0])
-        rows = _block_rows(lo + step, blk_k)
-        dv_s[rows, :] += _dot(p, do, ((1,), (0,)))
-        dk_s[rows, :] += _dot(ds, q, ((1,), (0,)))
-        dq_s[...] += _dot(ds, k, ((0,), (0,)))
-
-    @pl.when(step == steps - 1)
-    def _():
-        dq_ref[0] = (dq_s[...] * scale).astype(dq_ref.dtype)
-
-    @pl.when(last)
-    def _():
-        dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+    key_block, key_steps = one_run(keys_of, nq)
+    query_block, query_steps = one_run(queries_of, nk)
+    return Walk(nq, nk, key_block, query_block, key_steps, query_steps,
+                functools.partial(_visible, blk_q=blk_q, blk_k=blk_k, Tk=Tk,
+                                  offset=offset, causal=causal,
+                                  window=window))
 
 
 def _attn_blocks(Tq, Tk, block_q, block_k):
@@ -929,20 +588,6 @@ def _attn_blocks(Tq, Tk, block_q, block_k):
     return blk_q, blk_k, pad_q, pad_k
 
 
-def _bwd_vmem(rows, widths, dtype):
-    """What decides between the one backward kernel and the two: the VMEM
-    limit the one kernel needs, or None where it does not fit. Its grid
-    walks one side of the block pairs; the other side's gradients (`widths`
-    columns each, of a whole sequence of `rows`) stay in VMEM until every
-    pair of a head has added to them: a float32 accumulator and the
-    output's two pipeline buffers each, lanes padded to 128. They may take
-    _BWD_RESIDENT_BYTES; the blocks in flight and the [blk, blk] score
-    tiles keep Mosaic's default scope beside them."""
-    lanes = sum(-(-w // 128) * 128 for w in widths)
-    resident = rows * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
-    return resident + _VMEM_SCOPE if resident <= _BWD_RESIDENT_BYTES else None
-
-
 # -- the block-diffusion mask --------------------------------------------------
 # A sequence of two halves of L rows, [noisy ; clean], each in blocks of B
 # positions (arXiv:2503.09573): a noisy row sees its own noisy block, both
@@ -952,14 +597,7 @@ def _bwd_vmem(rows, widths, dtype):
 # the other side's kernel blocks (a noisy query block: its own noisy key
 # blocks, and the clean ones from the half's start; a clean key block: the
 # clean query blocks from its own on, and the noisy ones after it), and
-# the grid's walking axis takes the first run and then the second. The
-# kernels below are the ones above with that walk and that mask; they share
-# `_bwd_pair`, the wrappers, the layouts and `_bwd_vmem`'s rule.
-
-def _iwhere(c, a, b):
-    return (a if c else b) if isinstance(c, (bool, int)) else \
-        jnp.where(c, a, b)
-
+# the walk takes the first run and then the second.
 
 def _diffusion_geometry(L, B, blk):
     """For kernel blocks of `blk` rows that tile a half (blk divides L, so
@@ -1004,19 +642,18 @@ def _diffusion_geometry(L, B, blk):
 
 
 def _diffusion_walk(L, B, blk, pad):
-    """(blocks a side, block_of for the keys of a query block, the same for
-    the queries of a key block, steps of each walk): ``block_of(i, s)`` is
-    (the s-th block that block i walks, held on the last one when the walk
-    has ended; whether the walk has not). Where a half is not whole blocks
-    (tests and odd lengths) every block walks every block; the mask, which
-    keys off the true rows, stays exact."""
+    """The :class:`Walk` of the block-diffusion mask over two halves of L
+    rows in blocks of B positions, in kernel blocks of `blk` rows. Where a
+    half is not whole blocks (tests and odd lengths) every block walks every
+    block; the mask, which keys off the true rows, stays exact."""
+    seen = functools.partial(_diffusion_visible, blk=blk, L=L, B=B)
     if L % blk:
         n = (2 * L + pad) // blk
         every = lambda i, s: (s, s < n)    # noqa: E731
-        return n, every, every, n, n
+        return Walk(n, n, every, every, n, n, seen)
     keys_of, queries_of, ksteps, qsteps = _diffusion_geometry(L, B, blk)
 
-    def walked(runs_of):
+    def two_runs(runs_of):
         def block_of(i, s):
             lo1, n1, lo2, n2 = runs_of(i)
             second = _iwhere(n2 > 0, lo2 + _imin(s - n1, n2 - 1),
@@ -1024,7 +661,9 @@ def _diffusion_walk(L, B, blk, pad):
             return _iwhere(s < n1, lo1 + s, second), s < n1 + n2
         return block_of
 
-    return 2 * L // blk, walked(keys_of), walked(queries_of), ksteps, qsteps
+    n = 2 * L // blk
+    return Walk(n, n, two_runs(keys_of), two_runs(queries_of), ksteps,
+                qsteps, seen)
 
 
 def _diffusion_blocks(L, block):
@@ -1066,11 +705,51 @@ def _diffusion_visible(qi, kj, blk, L, B, keys_first=False):
     return (key <= upto) | (key == own)
 
 
-def _diffusion_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s,
-                          acc_s, *, geo, scale):
-    block_of, seen_of, steps = geo
+def _walk_of(Tq, Tk, block_q, block_k, causal=True, window=0,
+             block_length=0):
+    """(blk_q, blk_k, pad_q, pad_k, the :class:`Walk`) of one attention
+    call: the one place where a mask is chosen. `block_length` > 0 is the
+    block-diffusion mask over two halves of Tq / 2 rows (`causal`, `window`
+    and `block_k` are not read), else the causal and windowed one."""
+    if block_length:
+        blk, pad = _diffusion_blocks(Tq // 2, block_q)
+        return blk, blk, pad, pad, _diffusion_walk(Tq // 2, block_length,
+                                                   blk, pad)
+    blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    return blk_q, blk_k, pad_q, pad_k, _causal_walk(
+        Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
+
+
+def block_diffusion_pairs(L, block_length, block=512):
+    """(query-key pairs a head that the mask leaves, pairs in the kernel
+    blocks the forward walk visits) for two halves of L rows."""
+    blk, _, _, _, walk = _walk_of(2 * L, 2 * L, block, block,
+                                  block_length=block_length)
+    visited = sum(bool(walk.key_block(i, s)[1])
+                  for i in range(walk.nq) for s in range(walk.key_steps))
+    blocks = L // block_length
+    return block_length ** 2 * blocks * (blocks + 1), visited * blk * blk
+
+
+# -- the four bodies ----------------------------------------------------------
+# `geo` is (block_of, seen, steps[, blk]) of the side a grid walks: a
+# Walk's key_block (query_block for dk/dv), its mask, the walk's static
+# length and, for the one backward kernel, the rows of a block of the side
+# whose gradients stay resident.
+
+def _dot(a, b, contract):
+    # the operands' own precision (one bf16 pass for bf16), whatever
+    # default the process has set: Mosaic refuses 'highest' on bf16
+    return jax.lax.dot_general(a, b, ((contract[0], contract[1]), ((), ())),
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
+
+
+def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s, *,
+                     geo, scale):
+    key_block, seen_of, steps = geo
     qi, step = pl.program_id(2), pl.program_id(3)
-    kj, live = block_of(qi, step)
+    kj, live = key_block(qi, step)
 
     @pl.when(step == 0)
     def _():
@@ -1099,11 +778,11 @@ def _diffusion_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s,
         lse_ref[0, 0] = m_s[...] + jnp.log(l)
 
 
-def _diffusion_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_s, *, geo, scale):
-    block_of, seen_of, steps = geo
+def _attn_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    acc_s, *, geo, scale):
+    key_block, seen_of, steps = geo
     qi, step = pl.program_id(2), pl.program_id(3)
-    kj, live = block_of(qi, step)
+    kj, live = key_block(qi, step)
 
     @pl.when(step == 0)
     def _():
@@ -1123,11 +802,11 @@ def _diffusion_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = acc_s[...].astype(dq_ref.dtype)
 
 
-def _diffusion_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_s, dv_s, *, geo, scale, group):
-    block_of, seen_of, steps = geo
+def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dk_ref, dv_ref, dk_s, dv_s, *, geo, scale, group):
+    query_block, seen_of, steps = geo
     kj, step = pl.program_id(2), pl.program_id(3)
-    qi, live = block_of(kj, step % steps)
+    qi, live = query_block(kj, step % steps)
 
     @pl.when(step == 0)
     def _():
@@ -1150,14 +829,27 @@ def _diffusion_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
-def _diffusion_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s, *, geo,
-                          scale):
-    """`_attn_bwd_kernel`'s cell of the grid, its key blocks by the two
-    runs."""
-    block_of, seen_of, steps, blk = geo
+def _bwd_pair(s, seen, lse, delta, do, v):
+    """(p, ds) [blk_k, blk_q] of one block pair from its scaled scores, in
+    the operands' precision: the part of a backward step that dq, dk and dv
+    share."""
+    p = jnp.where(seen, jnp.exp(s - lse), 0.0)
+    dp = _dot(v, do, ((1,), (1,)))
+    return p.astype(do.dtype), (p * (dp - delta)).astype(do.dtype)
+
+
+def _block_rows(block, blk):
+    return pl.ds(pl.multiple_of(block * blk, blk), blk)
+
+
+def _attn_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                     dk_ref, dv_ref, dq_s, dk_s, dv_s, *, geo, scale):
+    """One key/value head's cell of the grid walks its group's query heads,
+    their query blocks and each block's visible key blocks; dk and dv of
+    the whole sequence stay in VMEM until the cell ends."""
+    key_block, seen_of, steps, blk_k = geo
     member, qi, step = (pl.program_id(axis) for axis in (2, 3, 4))
-    kj, live = block_of(qi, step)
+    kj, live = key_block(qi, step)
     first = (member == 0) & (qi == 0) & (step == 0)
     last = (member == pl.num_programs(2) - 1) \
         & (qi == pl.num_programs(3) - 1) & (step == steps - 1)
@@ -1177,7 +869,7 @@ def _diffusion_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = _dot(k, q, ((1,), (1,))) * scale
         p, ds = _bwd_pair(s, seen_of(qi, kj, keys_first=True),
                           lse_ref[0, 0, 0], delta_ref[0, 0, 0], do, v_ref[0])
-        rows = _block_rows(kj, blk)
+        rows = _block_rows(kj, blk_k)
         dv_s[rows, :] += _dot(p, do, ((1,), (0,)))
         dk_s[rows, :] += _dot(ds, q, ((1,), (0,)))
         dq_s[...] += _dot(ds, k, ((0,), (0,)))
@@ -1190,6 +882,20 @@ def _diffusion_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _bwd_vmem(rows, widths, dtype):
+    """What decides between the one backward kernel and the two: the VMEM
+    limit the one kernel needs, or None where it does not fit. Its grid
+    walks one side of the block pairs; the other side's gradients (`widths`
+    columns each, of a whole sequence of `rows`) stay in VMEM until every
+    pair of a head has added to them: a float32 accumulator and the
+    output's two pipeline buffers each, lanes padded to 128. They may take
+    _BWD_RESIDENT_BYTES; the blocks in flight and the [blk, blk] score
+    tiles keep Mosaic's default scope beside them."""
+    lanes = sum(-(-w // 128) * 128 for w in widths)
+    resident = rows * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+    return resident + _VMEM_SCOPE if resident <= _BWD_RESIDENT_BYTES else None
 
 
 def _pad_rows(x, pad):
@@ -1288,46 +994,30 @@ def attention_forward(q, k, v, heads, kv_heads, causal=True, window=0,
     q [B, Tq, H * D], k and v [B, Tk, KV * D]. `window` w > 0: a query sees
     only the w keys up to its own position. `block_length` > 0: the
     block-diffusion mask over a sequence of two halves in blocks of that
-    many positions (above; `causal`, `window` and `block_k` are not read).
-    The kernel is named ``<name>_fwd`` in a device trace."""
+    many positions (`_walk_of`). The kernel is named ``<name>_fwd`` in a
+    device trace."""
     B, Tq, HD = q.shape
     Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
     scale = D ** -0.5 if scale is None else scale
-    if block_length:
-        blk_q, pad_q = blk_k, pad_k = _diffusion_blocks(Tq // 2, block_q)
-    else:
-        blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    blk_q, blk_k, pad_q, pad_k, walk = _walk_of(
+        Tq, Tk, block_q, block_k, causal, window, block_length)
     with jax.named_scope('heads'):      # as in latent_attention_forward
         q, k, v = (_pad_rows(q, pad_q), _pad_rows(k, pad_k),
                    _pad_rows(v, pad_k))
-    if block_length:
-        nq, key_block, _, steps, _ = _diffusion_walk(
-            Tq // 2, block_length, blk_q, pad_q)
-        seen = functools.partial(_diffusion_visible, blk=blk_q, L=Tq // 2,
-                                 B=block_length)
 
-        def kv_index(b, h, i, s):
-            return b, key_block(i, s)[0], h // group
+    def kv_index(b, h, i, s):
+        return b, walk.key_block(i, s)[0], h // group
 
-        kernel = functools.partial(_diffusion_fwd_kernel, scale=scale,
-                                   geo=(key_block, seen, steps))
-    else:
-        nq, _, keys_of, _, steps, _ = _attn_walk(
-            Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
-        geo = (blk_q, blk_k, Tk, Tk - Tq, causal, window, keys_of, steps)
-
-        def kv_index(b, h, i, s):
-            lo, hi = keys_of(i)
-            return b, _imin(lo + s, hi), h // group
-
-        kernel = functools.partial(_attn_fwd_kernel, geo=geo, scale=scale)
+    kernel = functools.partial(
+        _attn_fwd_kernel, scale=scale,
+        geo=(walk.key_block, walk.seen, walk.key_steps))
 
     def build(interpret):
         by_head, spec = _head_blocks(D, interpret)
         q_spec = spec(blk_q, lambda b, h, i, s: (b, i, h))
         return _head_layout(pl.pallas_call(
             kernel,
-            grid=(B, heads, nq, steps),
+            grid=(B, heads, walk.nq, walk.key_steps),
             in_specs=[q_spec, spec(blk_k, kv_index), spec(blk_k, kv_index)],
             out_specs=[q_spec,
                        pl.BlockSpec((1, 1, blk_q, 1),
@@ -1352,17 +1042,19 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
                        block_k=512, g_lse=None, name='attention',
                        block_length=0):
     """(dq, dk, dv) of :func:`attention_forward` from its output, its
-    log-sum-exp [B, H, Tq] and the output's cotangent. One kernel, named
+    log-sum-exp [B, H, Tq] and the output's cotangent; `g_lse` is the
+    log-sum-exp's own cotangent, where it has one (it enters as a shift of
+    the rows' ``delta``: d lse_i / d s_ij = p_ij). One kernel, named
     ``<name>_bwd`` in a device trace, where dk and dv of a whole sequence
     fit VMEM (:func:`_bwd_vmem`); past that two, ``<name>_dq`` and
     ``<name>_dkv``."""
     B, Tq, HD = q.shape
     Tk, D, group = k.shape[1], HD // heads, heads // kv_heads
     scale = D ** -0.5 if scale is None else scale
-    if block_length:
-        blk_q, pad_q = blk_k, pad_k = _diffusion_blocks(Tq // 2, block_q)
-    else:
-        blk_q, blk_k, pad_q, pad_k = _attn_blocks(Tq, Tk, block_q, block_k)
+    blk_q, blk_k, pad_q, pad_k, walk = _walk_of(
+        Tq, Tk, block_q, block_k, causal, window, block_length)
+    nq, nk, ksteps, qsteps = (walk.nq, walk.nk, walk.key_steps,
+                              walk.query_steps)
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
     with jax.named_scope('delta'):
         delta = jnp.sum(
@@ -1373,32 +1065,9 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
     with jax.named_scope('heads'):
         q, g_out = _pad_rows(q, pad_q), _pad_rows(g_out, pad_q)
         k, v = _pad_rows(k, pad_k), _pad_rows(v, pad_k)
-    if block_length:
-        nq, key_block, query_block, ksteps, qsteps = _diffusion_walk(
-            Tq // 2, block_length, blk_q, pad_q)
-        nk = nq
-        seen = functools.partial(_diffusion_visible, blk=blk_q, L=Tq // 2,
-                                 B=block_length)
-        k_block = lambda i, s: key_block(i, s)[0]           # noqa: E731
-        q_block = lambda j, s: query_block(j, s % qsteps)[0]    # noqa: E731
-        kernels = [functools.partial(fn, geo=geo) for fn, geo in (
-            (_diffusion_bwd_kernel, (key_block, seen, ksteps, blk_k)),
-            (_diffusion_dq_kernel, (key_block, seen, ksteps)),
-            (_diffusion_dkv_kernel, (query_block, seen, qsteps)))]
-    else:
-        nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
-            Tq, Tk, pad_q, pad_k, blk_q, blk_k, causal, window)
-        base = (blk_q, blk_k, Tk, Tk - Tq, causal, window)
-        k_block = _walked(keys_of)
-
-        def q_block(j, s):
-            lo, hi = queries_of(j)
-            return _imin(lo + s % qsteps, hi)
-
-        kernels = [functools.partial(fn, geo=base + geo) for fn, geo in (
-            (_attn_bwd_kernel, (keys_of, ksteps)),
-            (_attn_dq_kernel, (keys_of, ksteps)),
-            (_attn_dkv_kernel, (queries_of, qsteps)))]
+    k_block = lambda i, s: walk.key_block(i, s)[0]              # noqa: E731
+    q_block = lambda j, s: walk.query_block(j, s % qsteps)[0]   # noqa: E731
+    keys = (walk.key_block, walk.seen, ksteps)
     vmem = _bwd_vmem(Tk + pad_k, (D, D), k.dtype)
     crossing = ((heads, kv_heads, kv_heads, heads), 4)
 
@@ -1418,7 +1087,8 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
             k_spec = spec(blk_k, lambda b, g, m, i, s: (b, k_block(i, s), g))
             whole = spec(Tk + pad_k, lambda b, g, m, i, s: (b, 0, g))
             return _head_layout(pl.pallas_call(
-                functools.partial(kernels[0], scale=scale),
+                functools.partial(_attn_bwd_kernel, geo=keys + (blk_k,),
+                                  scale=scale),
                 grid=(B, kv_heads, group, nq, ksteps),
                 in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
                 out_specs=[q_spec, whole, whole],
@@ -1445,7 +1115,7 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         by_head, spec = _head_blocks(D, interpret)
         q_spec = spec(blk_q, lambda b, h, i, s: (b, i, h))
         return _head_layout(pl.pallas_call(
-            functools.partial(kernels[1], scale=scale),
+            functools.partial(_attn_dq_kernel, geo=keys, scale=scale),
             grid=(B, heads, nq, ksteps),
             in_specs=[q_spec, spec(blk_k, kv_index), spec(blk_k, kv_index),
                       q_spec, col_spec, col_spec],
@@ -1471,7 +1141,9 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         k_spec = spec(blk_k, lambda b, g, j, s: (b, j, g))
         qw_spec = spec(blk_q, q_index)
         return _head_layout(pl.pallas_call(
-            functools.partial(kernels[2], scale=scale, group=group),
+            functools.partial(
+                _attn_dkv_kernel, scale=scale, group=group,
+                geo=(walk.query_block, walk.seen, qsteps)),
             grid=(B, kv_heads, nk, group * qsteps),
             in_specs=[qw_spec, k_spec, k_spec, qw_spec, qcol_spec, qcol_spec],
             out_specs=[k_spec, k_spec],
@@ -1486,21 +1158,24 @@ def attention_backward(q, k, v, out, lse, g_out, heads, kv_heads,
         return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def blockwise_attention(q, k, v, heads, kv_heads, causal=True, window=0,
                         scale=None, block_q=512, block_k=512,
-                        name='attention'):
-    """Grouped-query attention, causal and optionally windowed, forward
-    and backward by the blockwise kernels above. q [B, Tq, H * D], k and
-    v [B, Tk, KV * D]; returns [B, Tq, H * D]."""
+                        name='attention', block_length=0):
+    """Grouped-query attention, forward and backward by the blockwise
+    kernels above: causal and optionally windowed, or with `block_length`
+    > 0 under the block-diffusion mask (q, k and v then hold a noisy and a
+    clean copy of Tq / 2 positions, in blocks of that many). q
+    [B, Tq, H * D], k and v [B, Tk, KV * D]; returns [B, Tq, H * D]."""
     return attention_forward(q, k, v, heads, kv_heads, causal, window,
-                             scale, block_q, block_k, name)[0]
+                             scale, block_q, block_k, name, block_length)[0]
 
 
 def _blockwise_fwd(q, k, v, heads, kv_heads, causal, window, scale, block_q,
-                   block_k, name):
+                   block_k, name, block_length):
     out, lse = attention_forward(q, k, v, heads, kv_heads, causal, window,
-                                 scale, block_q, block_k, name)
+                                 scale, block_q, block_k, name, block_length)
     # what attention_backward reads that is made here: a mirrored stage
     # keeps the two, so the kernel runs once. q, k and v are made outside,
     # where the policy judges them: the op that calls names them
@@ -1510,56 +1185,106 @@ def _blockwise_fwd(q, k, v, heads, kv_heads, causal, window, scale, block_q,
 
 
 def _blockwise_bwd(heads, kv_heads, causal, window, scale, block_q, block_k,
-                   name, res, g):
+                   name, block_length, res, g):
     q, k, v, out, lse = res
     return attention_backward(q, k, v, out, lse, g, heads, kv_heads, causal,
-                              window, scale, block_q, block_k, name=name)
+                              window, scale, block_q, block_k, name=name,
+                              block_length=block_length)
 
 
 blockwise_attention.defvjp(_blockwise_fwd, _blockwise_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def block_diffusion_attention(q, k, v, heads, kv_heads, block_length,
-                              scale=None, block=512,
-                              name='attention_blockdiff'):
-    """:func:`blockwise_attention` under the block-diffusion mask: q
-    [B, 2 L, H * D], k and v [B, 2 L, KV * D] hold a noisy and a clean copy
-    of L positions, in blocks of `block_length`."""
-    return attention_forward(q, k, v, heads, kv_heads, scale=scale,
-                             block_q=block, name=name,
-                             block_length=block_length)[0]
+# -- the [B, T, H, D] entry points --------------------------------------------
+# ring attention's (parallel/ring_attention.py), chip_smoke.py's and the
+# tests': every head its own key/value head, causal or not, Tq <= Tk, the
+# mask aligned bottom right (decode convention). The kernels are the ones
+# above under the name ``flash_attention``; [B, T, H, D] is [B, T, H * D]
+# by a reshape.
+
+def _flash_lse_ref(q, k, v, causal, scale):
+    """(out, lse) in plain jnp: the dense oracle of the tests."""
+    s = jnp.einsum('bqhd,bkhd->bhqk', q * scale, k)
+    if causal:
+        Tq, Tk = q.shape[1], k.shape[1]
+        mask = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
+        s = jnp.where(mask, s, _NEG)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    return jnp.einsum('bhqk,bkhd->bqhd', p, v), lse
 
 
-def _block_diffusion_fwd(q, k, v, heads, kv_heads, block_length, scale,
-                         block, name):
-    out, lse = attention_forward(q, k, v, heads, kv_heads, scale=scale,
-                                 block_q=block, name=name,
-                                 block_length=block_length)
-    out, lse = dear(out, name + '_out'), dear(lse, name + '_lse')
-    return out, (q, k, v, out, lse)
+def _flash_ref(q, k, v, causal, scale):
+    s = jnp.einsum('bqhd,bkhd->bhqk', q * scale, k)
+    if causal:
+        Tq, Tk = q.shape[1], k.shape[1]
+        mask = jnp.tril(jnp.ones((Tq, Tk), bool), Tk - Tq)
+        s = jnp.where(mask, s, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum('bhqk,bkhd->bqhd', p, v)
 
 
-def _block_diffusion_bwd(heads, kv_heads, block_length, scale, block, name,
-                         res, g):
+def _merged_heads(x):
+    B, T, H, D = x.shape
+    return x.reshape(B, T, H * D)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention_lse(q, k, v, causal=False, scale=None, block_q=128,
+                        block_k=128):
+    """(out [B, Tq, H, D], lse [B, H, Tq]) of attention over q
+    [B, Tq, H, D], k and v [B, Tk, H, D], shapes like
+    ring_attention.attention_reference (its numeric oracle). The per-row
+    log-sum-exp is the merge statistic ring attention needs to combine
+    normalized chunk outputs exactly; its cotangent is taken in the
+    backward pass (the merge weights depend on it). ``block_q``/``block_k``
+    are advisory tile sizes (`_attn_blocks`)."""
+    return _flash_fwd(q, k, v, causal, scale, block_q, block_k)[0]
+
+
+def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
+                    block_k=128):
+    """:func:`flash_attention_lse` without the log-sum-exp."""
+    return flash_attention_lse(q, k, v, causal, scale, block_q, block_k)[0]
+
+
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+    B, Tq, H, D = q.shape
+    Tk = k.shape[1]
+    if causal and Tq > Tk:
+        # bottom-right alignment gives the first Tq-Tk query rows zero
+        # visible keys (softmax over empty set — NaN in the oracle);
+        # reject rather than return silently-wrong finite values
+        raise ValueError('causal attention requires Tq <= Tk '
+                         '(got Tq=%d, Tk=%d)' % (Tq, Tk))
+    if Tk == 0:
+        # softmax over an empty key set is undefined (NaN in the
+        # oracle); fail loudly instead of tracing a 0-size block
+        raise ValueError('attention requires at least one key (Tk=0)')
+    if q.size == 0:                  # empty batch/seq: nothing to launch
+        out, lse = jnp.zeros(q.shape, q.dtype), \
+            jnp.zeros((B, H, Tq), jnp.float32)
+    else:
+        out, lse = attention_forward(
+            _merged_heads(q), _merged_heads(k), _merged_heads(v), H, H,
+            causal, 0, scale, block_q, block_k, 'flash_attention')
+        out = out.reshape(q.shape)
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _flash_bwd(causal, scale, block_q, block_k, res, g):
     q, k, v, out, lse = res
-    return attention_backward(q, k, v, out, lse, g, heads, kv_heads,
-                              scale=scale, block_q=block, name=name,
-                              block_length=block_length)
+    if q.size == 0:
+        return jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v)
+    H = q.shape[2]
+    grads = attention_backward(
+        *map(_merged_heads, (q, k, v, out)), lse, _merged_heads(g[0]), H, H,
+        causal, 0, scale, block_q, block_k, g_lse=g[1],
+        name='flash_attention')
+    return tuple(dx.reshape(x.shape) for dx, x in zip(grads, (q, k, v)))
 
 
-block_diffusion_attention.defvjp(_block_diffusion_fwd, _block_diffusion_bwd)
-
-
-def block_diffusion_pairs(L, block_length, block=512):
-    """(query-key pairs a head that the mask leaves, pairs in the kernel
-    blocks the forward walk visits) for two halves of L rows."""
-    blk, pad = _diffusion_blocks(L, block)
-    n, key_block, _, steps, _ = _diffusion_walk(L, block_length, blk, pad)
-    visited = sum(bool(key_block(i, s)[1])
-                  for i in range(n) for s in range(steps))
-    blocks = L // block_length
-    return block_length ** 2 * blocks * (blocks + 1), visited * blk * blk
+flash_attention_lse.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1796,9 +1521,9 @@ def rows_to_tokens(src, token, n_tiles, pass_index, acc, scale=None,
 # ``q_rope k_rope^T`` against the one rotary key head that every head shares
 # (k_rope [B, T, Dr], never broadcast). Values are narrower than keys
 # (Dn + Dr against Dv), so the kernels above, which take one head size for
-# all three, do not fit; these are a family of their own over the same walk
-# (`_attn_walk`), mask (`_visible`) and blocks (`_attn_blocks`), and the
-# grouped-query kernels stay as they were. q_nope, k_nope and v keep the
+# all three, do not fit; these are a family of their own that reads the same
+# record of a mask (`Walk`, from `_walk_of`) in the same form (`geo`), and
+# the grouped-query kernels stay as they were. q_nope, k_nope and v keep the
 # projections' layout [B, T, H * D]; a head's 64 rotary query columns are no
 # legal block of [B, T, H * 64] (the minor block is 128 lanes or the whole
 # axis), so q_rope and its gradient cross as [B, H, T, Dr]. The shared key's
@@ -1815,9 +1540,9 @@ def _latent_scores(qn, qr, kn, kr, scale):
 
 def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
                        m_s, l_s, acc_s, *, geo, scale):
-    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    key_block, seen_of, steps = geo
     qi, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = keys_of(qi)
+    kj, live = key_block(qi, step)
 
     @pl.when(step == 0)
     def _():
@@ -1825,11 +1550,10 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    @pl.when(lo + step <= hi)
+    @pl.when(live)
     def _():
         v = v_ref[0]
-        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
-                        window)
+        seen = seen_of(qi, kj)
         s = jnp.where(seen, _latent_scores(qn_ref[0], qr_ref[0, 0],
                                            kn_ref[0], kr_ref[0], scale), _NEG)
         m = m_s[...]
@@ -1851,22 +1575,20 @@ def _latent_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref,
 def _latent_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
                       delta_ref, dqn_ref, dqr_ref, dqn_s, dqr_s, *, geo,
                       scale):
-    blk_q, blk_k, Tk, offset, causal, window, keys_of, steps = geo
+    key_block, seen_of, steps = geo
     qi, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = keys_of(qi)
+    kj, live = key_block(qi, step)
 
     @pl.when(step == 0)
     def _():
         dqn_s[...] = jnp.zeros(dqn_s.shape, jnp.float32)
         dqr_s[...] = jnp.zeros(dqr_s.shape, jnp.float32)
 
-    @pl.when(lo + step <= hi)
+    @pl.when(live)
     def _():
         kn, kr, v, do = kn_ref[0], kr_ref[0], v_ref[0], do_ref[0]
         s = _latent_scores(qn_ref[0], qr_ref[0, 0], kn, kr, scale)
-        seen = _visible(qi, lo + step, blk_q, blk_k, Tk, offset, causal,
-                        window)
-        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        p = jnp.where(seen_of(qi, kj), jnp.exp(s - lse_ref[0, 0]), 0.0)
         dp = _dot(do, v, ((1,), (1,)))
         ds = (p * (dp - delta_ref[0, 0])).astype(kn.dtype)
         dqn_s[...] += _dot(ds, kn, ((1,), (0,))) * scale
@@ -1881,9 +1603,9 @@ def _latent_dq_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
 def _latent_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
                        lse_ref, delta_ref, dkn_ref, dkr_ref, dv_ref, dkn_s,
                        dkr_s, dv_s, *, geo, scale):
-    blk_q, blk_k, Tk, offset, causal, window, queries_of, steps = geo
+    query_block, seen_of, steps = geo
     kj, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = queries_of(kj)
+    qi, live = query_block(kj, step)
 
     @pl.when(step == 0)
     def _():
@@ -1891,13 +1613,11 @@ def _latent_dkv_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref,
         dkr_s[...] = jnp.zeros(dkr_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    @pl.when(lo + step <= hi)
+    @pl.when(live)
     def _():
         qn, qr, do = qn_ref[0], qr_ref[0, 0], do_ref[0]
         s = _latent_scores(qn, qr, kn_ref[0], kr_ref[0], scale)
-        seen = _visible(lo + step, kj, blk_q, blk_k, Tk, offset, causal,
-                        window)
-        p = jnp.where(seen, jnp.exp(s - lse_ref[0, 0]), 0.0)
+        p = jnp.where(seen_of(qi, kj), jnp.exp(s - lse_ref[0, 0]), 0.0)
         dv_s[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
         dp = _dot(do, v_ref[0], ((1,), (1,)))
         ds = (p * (dp - delta_ref[0, 0])).astype(qn.dtype)
@@ -1917,9 +1637,9 @@ def _latent_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
     """A head's cell of the grid walks its key blocks and each one's visible
     query blocks; the head's dq_nope and dq_rope of the whole sequence stay
     in VMEM until the cell ends."""
-    blk_q, blk_k, Tk, offset, causal, window, queries_of, steps = geo
+    query_block, seen_of, steps, blk_q = geo
     kj, step = pl.program_id(2), pl.program_id(3)
-    lo, hi = queries_of(kj)
+    qi, live = query_block(kj, step)
 
     @pl.when((kj == 0) & (step == 0))
     def _():
@@ -1932,16 +1652,14 @@ def _latent_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
         dkr_s[...] = jnp.zeros(dkr_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    @pl.when(lo + step <= hi)
+    @pl.when(live)
     def _():
         qn, qr, kn, kr, do = qn_ref[0], qr_ref[0, 0], kn_ref[0], kr_ref[0], \
             do_ref[0]
         s = _latent_scores(kn, kr, qn, qr, scale)
-        seen = _visible(lo + step, kj, blk_q, blk_k, Tk, offset, causal,
-                        window, keys_first=True)
-        p, ds = _bwd_pair(s, seen, lse_ref[0, 0, 0], delta_ref[0, 0, 0], do,
-                          v_ref[0])
-        rows = _block_rows(lo + step, blk_q)
+        p, ds = _bwd_pair(s, seen_of(qi, kj, keys_first=True),
+                          lse_ref[0, 0, 0], delta_ref[0, 0, 0], do, v_ref[0])
+        rows = _block_rows(qi, blk_q)
         dv_s[...] += _dot(p, do, ((1,), (0,)))
         dkn_s[...] += _dot(ds, qn, ((1,), (0,)))
         dkr_s[...] += _dot(ds, qr, ((1,), (0,)))
@@ -2002,14 +1720,6 @@ def _latent_specs(blk_q, blk_k, q_block, k_block):
     return q, k, qh, kh, kr
 
 
-def _walked(blocks_of):
-    """Index of the s-th block a block i walks, held on its last one."""
-    def block(i, s):
-        lo, hi = blocks_of(i)
-        return _imin(lo + s, hi)
-    return block
-
-
 def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
                              block_q=512, block_k=512,
                              name='attention_latent', scale=None):
@@ -2020,12 +1730,10 @@ def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
     ``<name>_fwd`` in a device trace."""
     B, T, _ = q_nope.shape
     Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads, scale)
-    blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
-    nq, _, keys_of, _, steps, _ = _attn_walk(
-        T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
-    geo = (blk_q, blk_k, T, 0, True, 0, keys_of, steps)
-    q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
-                                    _walked(keys_of))
+    blk_q, blk_k, pad_q, pad_k, walk = _walk_of(T, T, block_q, block_k)
+    geo = (walk.key_block, walk.seen, walk.key_steps)
+    q, k, qh, _, kr = _latent_specs(
+        blk_q, blk_k, lambda i, s: i, lambda i, s: walk.key_block(i, s)[0])
     # 'heads', 'delta': trace-time names for the work around a kernel
     # (pads, per-head layouts, the softmax's own term), for the compiled
     # program's scope map (telemetry/programs.py); the call itself stands
@@ -2037,7 +1745,7 @@ def latent_attention_forward(q_nope, q_rope, k_nope, k_rope, v, heads,
                     _pad_rows(v, pad_k))
     out, lse = run_kernel(lambda interpret: pl.pallas_call(
         functools.partial(_latent_fwd_kernel, geo=geo, scale=scale),
-        grid=(B, heads, nq, steps),
+        grid=(B, heads, walk.nq, walk.key_steps),
         in_specs=[q(Dn), qh(Dr), k(Dn), kr(Dr), k(Dv)],
         out_specs=[q(Dv), qh(1)],
         out_shape=[jax.ShapeDtypeStruct((B, T + pad_q, heads * Dv), v.dtype),
@@ -2064,16 +1772,14 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
     parts."""
     B, T, _ = q_nope.shape
     Dn, Dr, Dv, scale = _latent_dims(q_nope, k_rope, v, heads, scale)
-    blk_q, blk_k, pad_q, pad_k = _attn_blocks(T, T, block_q, block_k)
+    blk_q, blk_k, pad_q, pad_k, walk = _walk_of(T, T, block_q, block_k)
     Tq, Tk = T + pad_q, T + pad_k
+    queries = (walk.query_block, walk.seen, walk.query_steps)
     # delta_i = sum_d dO_id O_id, per head: the softmax's own term
     with jax.named_scope('delta'):
         delta = jnp.sum(
             (g_out.astype(jnp.float32) * out.astype(jnp.float32))
             .reshape(B, T, heads, Dv), axis=-1).transpose(0, 2, 1)
-    nq, nk, keys_of, queries_of, ksteps, qsteps = _attn_walk(
-        T, T, pad_q, pad_k, blk_q, blk_k, True, 0)
-    base = (blk_q, blk_k, T, 0, True, 0)
     with jax.named_scope('heads'):
         operands = (_pad_rows(q_nope, pad_q),
                     _by_head(_pad_rows(q_rope, pad_q), heads),
@@ -2090,7 +1796,7 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
         return [q(Dn), qh(Dr), k(Dn), kr(Dr), k(Dv), q(Dv), stat, stat]
 
     vmem = _bwd_vmem(Tq, (Dn, Dr), q_nope.dtype)
-    q_block = _walked(queries_of)
+    q_block = lambda j, s: walk.query_block(j, s)[0]    # noqa: E731
     q, k, qh, kh, kr = _latent_specs(blk_q, blk_k, q_block, lambda j, s: j)
     if vmem is not None:
         row = pl.BlockSpec((1, 1, 1, 1, blk_q), lambda b, h, j, s: (
@@ -2098,9 +1804,9 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
         whole = [pl.BlockSpec((1, Tq, Dn), lambda b, h, j, s: (b, 0, h)),
                  pl.BlockSpec((1, 1, Tq, Dr), lambda b, h, j, s: (b, h, 0, 0))]
         dqn, dqr, dkn, dkr, dv = run_kernel(lambda interpret: pl.pallas_call(
-            functools.partial(_latent_bwd_kernel,
-                              geo=base + (queries_of, qsteps), scale=scale),
-            grid=(B, heads, nk, qsteps),
+            functools.partial(_latent_bwd_kernel, geo=queries + (blk_q,),
+                              scale=scale),
+            grid=(B, heads, walk.nk, walk.query_steps),
             in_specs=in_specs(q, k, qh, kr, row),
             out_specs=whole + [k(Dn), kh(Dr), k(Dv)],
             out_shape=dq_shapes + dk_shapes,
@@ -2111,19 +1817,20 @@ def latent_attention_backward(q_nope, q_rope, k_nope, k_rope, v, out, lse,
     else:
         operands += (_cols(lse, pad_q), _cols(delta, pad_q))
         dkn, dkr, dv = run_kernel(lambda interpret: pl.pallas_call(
-            functools.partial(_latent_dkv_kernel,
-                              geo=base + (queries_of, qsteps), scale=scale),
-            grid=(B, heads, nk, qsteps),
+            functools.partial(_latent_dkv_kernel, geo=queries, scale=scale),
+            grid=(B, heads, walk.nk, walk.query_steps),
             in_specs=in_specs(q, k, qh, kr, qh(1)),
             out_specs=[k(Dn), kh(Dr), k(Dv)], out_shape=dk_shapes,
             scratch_shapes=dk_scratch, compiler_params=_attn_params(3),
             interpret=interpret, name=name + '_dkv'), *operands)
-        q, k, qh, _, kr = _latent_specs(blk_q, blk_k, lambda i, s: i,
-                                        _walked(keys_of))
+        q, k, qh, _, kr = _latent_specs(
+            blk_q, blk_k, lambda i, s: i,
+            lambda i, s: walk.key_block(i, s)[0])
         dqn, dqr = run_kernel(lambda interpret: pl.pallas_call(
-            functools.partial(_latent_dq_kernel, geo=base + (keys_of, ksteps),
-                              scale=scale),
-            grid=(B, heads, nq, ksteps),
+            functools.partial(
+                _latent_dq_kernel, scale=scale,
+                geo=(walk.key_block, walk.seen, walk.key_steps)),
+            grid=(B, heads, walk.nq, walk.key_steps),
             in_specs=in_specs(q, k, qh, kr, qh(1)),
             out_specs=[q(Dn), qh(Dr)], out_shape=dq_shapes,
             scratch_shapes=[_vmem((blk_q, Dn)), _vmem((blk_q, Dr))],

@@ -180,8 +180,7 @@ def _dense_attention(q, k, v, heads, kv_heads, window, block_length=0):
 @register('GroupedQueryAttention',
           input_names=['query', 'key', 'value', 'gate'],
           param_defaults={'num_heads': 1, 'num_kv_heads': 1, 'window': 0,
-                          'gated': False, 'mask': 'causal',
-                          'block_length': 0},
+                          'gated': False, 'block_length': 0},
           optional_inputs={'gate': 'gated'})
 def _gqa(attrs, q, k, v, gate=None):
     """Causal attention of query [B, T, H * D] over key and value
@@ -190,12 +189,13 @@ def _gqa(attrs, q, k, v, gate=None):
     w > 0, s > t - w. ``gated``: head i's output is multiplied by
     sigmoid(gate[..., i]), gate [B, T, H]. Returns [B, T, H * D].
 
-    ``mask='block_diffusion'`` with ``block_length`` B: the T = 2 L rows
-    are a noisy and a clean copy of L positions, [noisy ; clean], in blocks
-    of B (B divides L); a noisy row sees its own noisy block in both
-    directions and the clean blocks strictly before it, a clean row the
-    clean blocks up to and including its own, nothing another block's noise
-    (:func:`block_diffusion_mask`; arXiv:2503.09573). No window.
+    ``block_length`` B > 0 is the block-diffusion mask in place of the
+    causal one: the T = 2 L rows are a noisy and a clean copy of L
+    positions, [noisy ; clean], in blocks of B (B divides L); a noisy row
+    sees its own noisy block in both directions and the clean blocks
+    strictly before it, a clean row the clean blocks up to and including its
+    own, nothing another block's noise (:func:`block_diffusion_mask`;
+    arXiv:2503.09573). No window, no gate.
 
     On a TPU the blockwise kernels run it, forward and backward, named
     ``attention_window_*``, ``attention_full_*`` or
@@ -204,24 +204,35 @@ def _gqa(attrs, q, k, v, gate=None):
     block-diffusion layer only the kernel blocks its mask does not empty
     (gauges ``attention.blockdiff.pairs_needed`` / ``pairs_visited``)."""
     H, KV = int(attrs['num_heads']), int(attrs['num_kv_heads'])
-    mask = str(attrs.get('mask', 'causal'))
-    if mask == 'block_diffusion':
-        return _block_diffusion_attention(attrs, q, k, v, H, KV)
-    if mask != 'causal':
-        raise ValueError('GroupedQueryAttention: mask %r' % (mask,))
     window = int(attrs.get('window', 0))
-    # blocks: a window is walked in blocks of half its size (so that the
-    # blocks outside it are at most a third of those walked), full
-    # attention in blocks of 512
-    block = max(128, min(512, window // 2)) if window else 512
-    name = 'attention_window' if window else 'attention_full'
+    block_length = int(attrs.get('block_length', 0))
+    if block_length:
+        T = q.shape[1]
+        if block_length < 1 or T % 2 or (T // 2) % block_length or window \
+                or attrs.get('gated', False):
+            raise ValueError(
+                'GroupedQueryAttention(block_length=%d): %d rows are not '
+                'two halves of whole blocks of that length, or a window or '
+                'a gate was asked for' % (block_length, T))
+        block, name = 512, 'attention_blockdiff'
+        from .. import telemetry as _tele
+        if _tele.enabled():
+            needed, visited = pk.block_diffusion_pairs(T // 2, block_length)
+            _tele.gauge('attention.blockdiff.pairs_needed').set(needed)
+            _tele.gauge('attention.blockdiff.pairs_visited').set(visited)
+    else:
+        # blocks: a window is walked in blocks of half its size (so that
+        # the blocks outside it are at most a third of those walked), full
+        # attention in blocks of 512
+        block = max(128, min(512, window // 2)) if window else 512
+        name = 'attention_window' if window else 'attention_full'
 
     def fused(q, k, v):
         return pk.blockwise_attention(q, k, v, H, KV, True, window, None,
-                                      block, block, name)
+                                      block, block, name, block_length)
 
     def plain(q, k, v):
-        return _dense_attention(q, k, v, H, KV, window)
+        return _dense_attention(q, k, v, H, KV, window, block_length)
 
     # the operands are what the backward kernel reads beside the forward
     # kernel's output (residuals of its custom_vjp, live in the backward
@@ -236,32 +247,6 @@ def _gqa(attrs, q, k, v, gate=None):
             out = (out.reshape(B, T, H, HD // H).astype(jnp.float32) * g) \
                 .astype(out.dtype).reshape(B, T, HD)
     return out
-
-
-def _block_diffusion_attention(attrs, q, k, v, H, KV):
-    T, B = q.shape[1], int(attrs.get('block_length', 0))
-    if B < 1 or T % 2 or (T // 2) % B or int(attrs.get('window', 0)) \
-            or attrs.get('gated', False):
-        raise ValueError(
-            'GroupedQueryAttention(mask=block_diffusion): %d rows are not '
-            'two halves of whole blocks of block_length %d, or a window or '
-            'a gate was asked for' % (T, B))
-    name = 'attention_blockdiff'
-    from .. import telemetry as _tele
-    if _tele.enabled():
-        needed, visited = pk.block_diffusion_pairs(T // 2, B)
-        _tele.gauge('attention.blockdiff.pairs_needed').set(needed)
-        _tele.gauge('attention.blockdiff.pairs_visited').set(visited)
-
-    def fused(q, k, v):
-        return pk.block_diffusion_attention(q, k, v, H, KV, B, None, 512,
-                                            name)
-
-    def plain(q, k, v):
-        return _dense_attention(q, k, v, H, KV, 0, B)
-
-    q, k, v = dear(q, name + '_q'), dear(k, name + '_k'), dear(v, name + '_v')
-    return pk.dispatch(fused, plain, q, k, v)
 
 
 # ---------------------------------------------------------------------------

@@ -112,8 +112,9 @@ def ring_attention(q, k, v, axis='sp', causal=False, scale=None,
     sp-1 ppermutes of the local K/V — bandwidth-optimal and overlapped
     with compute by XLA (latency hiding via the ring schedule).
 
-    The local q×chunk block runs on the Pallas flash kernel
-    (ops/pallas_kernels.flash_attention_lse — online softmax in VMEM);
+    The local q×chunk block runs on the blockwise attention kernels
+    (ops/pallas_kernels.flash_attention_lse, forward and backward —
+    online softmax over K/V blocks in VMEM, a chunk of any length);
     per-chunk normalized outputs are merged exactly via the kernel's
     log-sum-exp. Pass ``use_flash=False`` for the plain-jnp accumulator
     (used as the cross-check oracle in tests).

@@ -148,7 +148,7 @@ def _faulty(fault):
 
     def attention(**kw):
         if fault == 'causal':
-            kw.pop('mask'), kw.pop('block_length')
+            kw.pop('block_length')
         return made['GroupedQueryAttention'](**kw)
 
     def rotary(x, **kw):

@@ -15,12 +15,12 @@ CASES = [
     ('numpy-ops/custom_softmax.py', ['--epochs', '8']),
     ('svm_mnist/svm_mnist.py', ['--epochs', '10']),
     ('autoencoder/mnist_sae.py',
-     ['--pretrain-epochs', '4', '--finetune-epochs', '6']),
+     ['--pretrain-epochs', '2', '--finetune-epochs', '4']),
     ('vae/vae.py', ['--epochs', '8', '--samples', '256']),
     ('multi-task/example_multi_task.py', ['--epochs', '8']),
     ('sparse/linear_classification.py', []),
     ('stochastic-depth/sd_mnist.py', []),
-    ('dec/dec.py', ['--pretrain-epochs', '4', '--dec-iters', '25']),
+    ('dec/dec.py', ['--pretrain-epochs', '4', '--dec-iters', '10']),
 ]
 
 

@@ -18,7 +18,7 @@ CASES = [
     ('gluon/super_resolution.py',
      ['--epochs', '9', '--samples', '96', '--min-psnr', '18']),
     ('gluon/actor_critic.py',
-     ['--episodes', '40', '--max-steps', '100', '--target', '30']),
+     ['--episodes', '24', '--max-steps', '100', '--target', '30']),
     ('reinforcement-learning/dqn.py',
      ['--episodes', '8', '--train-freq', '4']),
 ]

@@ -28,7 +28,7 @@ CASES = [
     ('gluon/image_classification.py',
      ['--model', 'resnet18_v1', '--epochs', '1', '--samples', '64',
       '--image-size', '16', '--batch-size', '16']),
-    ('fcn-xs/fcn_xs.py', ['--epochs', '9']),
+    ('fcn-xs/fcn_xs.py', ['--epochs', '8']),
     ('neural-style/neural_style.py', ['--steps', '120']),
 ]
 

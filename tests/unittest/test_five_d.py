@@ -66,6 +66,6 @@ def test_train_step_learns_and_syncs():
     # both experts, and the embedding behind the schedule masking)
     mesh1 = full_mesh({'dp': 1})
     params1 = init_params(CFG, mesh1, seed=3)
-    grads = jax.grad(make_loss_fn(CFG, mesh1))(params1, toks, tgts)
+    grads = jax.jit(jax.grad(make_loss_fn(CFG, mesh1)))(params1, toks, tgts)
     for name, g in grads.items():
         assert float(jnp.max(jnp.abs(g))) > 0, name

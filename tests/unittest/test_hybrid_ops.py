@@ -338,14 +338,14 @@ def test_the_second_forward_of_a_conv_block(path, monkeypatch):
 # to lower as it did. Taken again on the tree of PR 43, which changed the
 # expert layer's backward pass by intent, and on that of PR 46, which changed
 # the way back from the sorted rows to the tokens by intent (test_latent_ops.py
-# says how); 'kernel' again on that of PR 47 (there too). (Laguna's and
+# says how); 'kernel' again on those of PR 47 and PR 48 (there too). (Laguna's and
 # Kanana's digests are in test_latent_ops.py and test_hyper_ops.py and are
 # checked there.) The text is this jax's.
 XING4_TEXT = {
     'plain':
     '7de7239f07f15f82eebf86e2b393e55a749f7ac4a5b197b7886cff3ba1669cad',
     'kernel':
-    '06bd7d03fba2cf2da128590171487a8d767a6954cc2e5dd7af8c670071477306'}
+    '057fb95a1ce23abcab9911161068c95a1ad8733d042499647d2ce33eaf7f2ea7'}
 
 
 @pytest.mark.parametrize('path', PATHS, indirect=True)

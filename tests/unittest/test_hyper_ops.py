@@ -299,13 +299,13 @@ def test_plan_metric(case):
 # checkpoint's, test_latent_ops.py), and on that of PR 43, which changed
 # the expert layer's backward pass by intent, and on that of PR 46, which
 # changed the way back from the sorted rows to the tokens by intent
-# (test_latent_ops.py says how, and why 'kernel' was taken again on that of
-# PR 47).
+# (test_latent_ops.py says how, and why 'kernel' was taken again on those of
+# PR 47 and PR 48).
 KANANA_TEXT = {
     'plain':
     '2526a27d6cd2d0aa0ed3a1c06b03358270fccfda29103acb89ca841b2fb5c460',
     'kernel':
-    '1c092675e8efc2d2c0915adf12e6d24f80174df4e4c97526f5bfb896b3c03208'}
+    '9ed5dbc4a8992d31d4cf925c02fb729388dbced74189a82840f2e40716f1cb1e'}
 
 
 def kanana_step_digest():

@@ -554,7 +554,14 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # 47, which changed RMSNorm's two kernels by intent: the backward rule of rows
 # under 2048 elements is the kernel fused_rmsnorm_bwd (the gradients are jax.vjp's of the plain formula
 # to float32 rounding, test_pallas.py) and both take their rows by bytes;
-# 'plain', the path the CPU takes, did not move.
+# 'plain', the path the CPU takes, did not move. 'kernel' alone again on the
+# tree of PR 48, which made the causal and windowed walk one instance of the
+# record every blockwise attention kernel reads (pallas_kernels.Walk): the two
+# lowered texts differ in scalar int32 operations alone (a kernel body takes
+# the held block index, one `minimum`, and computes `lo + step` once; an index
+# map computes the walk's unused `live`, one `compare`), no vector operation
+# among them (the count by operation is in CHANGES.md, PR 48); Kanana's,
+# Xing4.0's and LFM2's 'kernel' digests moved with it, SDAR's did not.
 # Before that they were PR 41's (what a mirrored stage keeps), PR 33's, and
 # those of the commit before this family came (faf5f29). The text is this jax's; a change of jax (or of Laguna's
 # own ops) needs them taken again.
@@ -562,7 +569,7 @@ LAGUNA_TEXT = {
     'plain':
     '39a7a0a2bf354847c208eb600bcea396eeec80f4cbd01278c69a5eabc9805577',
     'kernel':
-    '1b6e6ccdd90431441a36630704f7031df227daa7009fb6ee12613f69ff493e38'}
+    '9519e0f6858ef89144d80159a3b0b52788c4d5c6c512845541a64f471dbc3b60'}
 
 
 def laguna_step_digest():

@@ -280,15 +280,17 @@ def test_block_choosers_mosaic_legal():
 
 
 def test_flash_lse_block_spec_is_mosaic_legal():
-    """The LSE output is carried as [B*H, Tq, 1]: its (1, blk_q, 1)
-    block has minor dim == array dim and second-to-minor divisible by 8
-    (or == Tq). The pre-fix (1, blk_q) spec violated the rule on real
-    TPU (bench_transformer_20260731T111706Z.log)."""
-    from mxnet_tpu.ops.pallas_kernels import (_block_ok, _pick_block,
+    """The LSE output is carried as [B, H, Tq, 1]: its (1, 1, blk_q, 1)
+    block has minor dim == array dim and second-to-minor divisible by 8,
+    at any length (an awkward one is padded to whole blocks). The pre-fix
+    (1, blk_q) spec violated the rule on real TPU
+    (bench_transformer_20260731T111706Z.log)."""
+    from mxnet_tpu.ops.pallas_kernels import (_attn_blocks, _block_ok,
                                               flash_attention_lse)
-    for Tq in [64, 96, 128, 1024]:
-        blk_q = _pick_block(128, Tq)
-        assert _block_ok(blk_q, Tq)
+    for Tq in [64, 96, 100, 128, 1024]:
+        blk_q, _, pad_q, _ = _attn_blocks(Tq, Tq, 128, 128)
+        assert blk_q % 8 == 0 and _block_ok(blk_q, Tq + pad_q) \
+            and (Tq + pad_q) % blk_q == 0
         assert _block_ok(1, 1)          # minor dim of the [.., Tq, 1] lse
     # numerics unchanged by the layout change
     rng = np.random.RandomState(11)
@@ -328,13 +330,12 @@ def test_norm_and_xent_odd_row_counts():
 
 @pytest.mark.parametrize('causal', [False, True])
 def test_flash_awkward_seq_pads_q(causal):
-    """Tq=28 with block_q=8 has no multiple-of-8 divisor: the q axis is
+    """Tq=28 with block_q=8 has no multiple-of-8 divisor: both axes are
     zero-padded to 32 and tiled at 8 (a whole-axis fallback would put an
-    O(Tq x blk_k) score tile in VMEM on real TPU). Numerics must match
-    the oracle exactly on the real rows."""
-    from mxnet_tpu.ops.pallas_kernels import (_pad_and_block,
-                                              flash_attention)
-    assert _pad_and_block(8, 28) == (4, 8)
+    O(Tq x blk_k) score tile in VMEM on real TPU; padded keys are masked).
+    Numerics must match the oracle exactly on the real rows."""
+    from mxnet_tpu.ops.pallas_kernels import _attn_blocks, flash_attention
+    assert _attn_blocks(28, 28, 8, 8) == (8, 8, 4, 4)
     q = _rand(2, 28, 2, 16, seed=40)
     k = _rand(2, 28, 2, 16, seed=41)
     v = _rand(2, 28, 2, 16, seed=42)
@@ -347,7 +348,7 @@ def test_flash_awkward_seq_pads_q(causal):
 def test_empty_and_tiny_block_requests():
     """Review regressions: zero-row inputs must not divide by zero, and
     a sub-8 block request must not trigger a whole-axis VMEM block."""
-    from mxnet_tpu.ops.pallas_kernels import (_pad_and_block,
+    from mxnet_tpu.ops.pallas_kernels import (_attn_blocks, _pad_and_block,
                                               flash_attention,
                                               fused_rmsnorm, softmax_xent)
     # empty batches launch nothing and return empty results
@@ -363,6 +364,7 @@ def test_empty_and_tiny_block_requests():
                         jnp.zeros((1, 0, 2, 4)))
     # block_q=4 at Tq=1024: want clamps to 8, never the 1024 whole axis
     assert _pad_and_block(4, 1024) == (0, 8)
+    assert _attn_blocks(1024, 1024, 4, 4) == (8, 8, 0, 0)
     q = _rand(1, 64, 1, 8, seed=50)
     out = flash_attention(q, q, q, True, None, 4, 4)
     ref = attention_reference(q, q, q, causal=True)

@@ -119,10 +119,14 @@ _HW, _HR = ((pk.HYPER_COLS, 4 * 3584), BF16), ((1, pk.HYPER_COLS), F32)
 _HC = ((4096, pk.HYPER_COLS), F32)
 
 KERNELS = [
+    # the [B, T, H, D] entry points are the blockwise forward kernel: no
+    # key length is refused (32768 keys were, as whole-axis blocks)
     ('flash_fwd_b8_t1024', lambda q, k, v: pk.flash_attention(q, k, v, True),
-     [((8, 1024, 8, 128), BF16)] * 3),
+     [((8, 1024, 8, 128), BF16)] * 3, ('attention_fwd',)),
     ('flash_fwd_b1_t8192', lambda q, k, v: pk.flash_attention(q, k, v, True),
-     [((1, 8192, 8, 128), BF16)] * 3),
+     [((1, 8192, 8, 128), BF16)] * 3, ('attention_fwd',)),
+    ('flash_fwd_b1_t32768', lambda q, k, v: pk.flash_attention(q, k, v, True),
+     [((1, 32768, 8, 128), BF16)] * 3, ('attention_fwd',)),
     ('layernorm_8192x1024', pk.fused_layernorm,
      [((8192, 1024), BF16), ((1024,), F32), ((1024,), F32)]),
     ('rmsnorm_8192x4096', pk.fused_rmsnorm,
@@ -289,19 +293,6 @@ def test_registry_ops_take_the_kernel_when_lowered_for_tpu(one_chip):
     cpu_text = jax.jit(fn).lower(x, jnp.ones(32), jnp.zeros(32)) \
         .compile().as_text()
     assert 'tpu_custom_call' not in cpu_text
-
-
-def test_flash_forward_past_vmem_raises_with_shapes(one_chip):
-    """[1, 32768, 8, 128]: whole-axis K/V blocks cannot fit; a clear
-    error when the program is lowered for the chip, not a compiler dump.
-    A program lowered for the CPU never meets the chip's limit."""
-    fn = jax.jit(lambda q, k, v: pk.flash_attention(q, k, v, True))
-    spec = jax.ShapeDtypeStruct((1, 32768, 8, 128), BF16, sharding=one_chip)
-    with pytest.raises(ValueError, match=r'flash_attention: keys/values '
-                                         r'\(1, 32768, 8, 128\) bfloat16'):
-        fn.lower(spec, spec, spec)
-    cpu = jax.ShapeDtypeStruct((1, 32768, 8, 128), BF16)
-    assert 'tpu_custom_call' not in fn.lower(cpu, cpu, cpu).as_text()
 
 
 def test_row_too_wide_for_vmem_raises_with_shapes(one_chip):

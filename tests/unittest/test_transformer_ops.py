@@ -33,7 +33,8 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.config import flags
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops import registry
-from mxnet_tpu.ops.transformer import MOE_STATS, moe_stat_names
+from mxnet_tpu.ops.transformer import (MOE_STATS, block_diffusion_mask,
+                                       moe_stat_names)
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -195,9 +196,77 @@ def test_reference_attention_over_the_span_is_the_dense_masked_one(
 def test_windowed_attention_walks_only_its_window():
     """The grid's walking axis is as long as the window needs, not as the
     sequence: blocks outside are never visited."""
-    *_, full_steps, _ = pk._attn_geometry(8192, 8192, 512, 512, True, 0)
-    *_, win_steps, win_q = pk._attn_geometry(8192, 8192, 256, 256, True, 512)
-    assert full_steps == 16 and win_steps == 3 and win_q == 3
+    full = pk._walk_of(8192, 8192, 512, 512)[4]
+    win = pk._walk_of(8192, 8192, 256, 256, True, 512)[4]
+    assert full.key_steps == 16 and win.key_steps == 3 \
+        and win.query_steps == 3
+
+
+# name: Tq, Tk, block_q, block_k, causal, window, block_length
+WALKS = {
+    'causal': (64, 64, 16, 16, True, 0, 0),
+    'window': (128, 128, 16, 16, True, 40, 0),
+    'window_off_the_block': (96, 96, 32, 16, True, 24, 0),
+    'more_keys_than_queries': (32, 80, 16, 16, True, 0, 0),
+    'more_keys_than_queries_window': (32, 80, 8, 16, True, 24, 0),
+    'not_causal': (48, 80, 16, 16, False, 0, 0),
+    'padded': (37, 37, 16, 16, True, 0, 0),
+    'padded_window': (37, 53, 8, 8, True, 8, 0),
+    'block_diffusion': (128, 128, 16, 16, True, 0, 4),
+    'block_diffusion_long_blocks': (128, 128, 8, 8, True, 0, 32),
+    'block_diffusion_padded': (60, 60, 16, 16, True, 0, 5),
+}
+
+
+@pytest.mark.parametrize('case', sorted(WALKS))
+def test_a_walk_visits_every_seen_block_pair_once_from_either_side(case):
+    """What the index maps and ``pl.when(live)`` of every blockwise kernel
+    rely on, whatever the mask (``pk.Walk``): the tiles of ``seen`` are the
+    dense mask's; a block pair that holds a seen element is walked, once,
+    by ``key_block`` and by ``query_block``; the live steps of a walk come
+    first, and past them the held index is the last live block (nothing
+    new is fetched)."""
+    Tq, Tk, block_q, block_k, causal, window, block_length = WALKS[case]
+    blk_q, blk_k, pad_q, pad_k, walk = pk._walk_of(
+        Tq, Tk, block_q, block_k, causal, window, block_length)
+    assert (walk.nq, walk.nk) == ((Tq + pad_q) // blk_q, (Tk + pad_k) // blk_k)
+    if block_length:
+        dense = np.asarray(block_diffusion_mask(Tq // 2, block_length))
+    else:
+        rows = np.arange(Tq)[:, None] + Tk - Tq
+        cols = np.arange(Tk)[None, :]
+        dense = np.ones((Tq, Tk), bool)
+        if causal:
+            dense &= cols <= rows
+        if window:
+            dense &= cols > rows - window
+    tiles = [[np.asarray(walk.seen(i, j)) for j in range(walk.nk)]
+             for i in range(walk.nq)]
+    np.testing.assert_array_equal(np.block(tiles)[:Tq, :Tk], dense)
+    np.testing.assert_array_equal(
+        np.asarray(walk.seen(1, 0, keys_first=True)), tiles[1][0].T)
+    seen = {(i, j) for i in range(walk.nq) for j in range(walk.nk)
+            if tiles[i][j][:max(0, Tq - i * blk_q)].any()}
+    one_run = not (pad_q or pad_k or block_length)
+    for block_of, steps, n, pair in (
+            (walk.key_block, walk.key_steps, walk.nq, lambda i, j: (i, j)),
+            (walk.query_block, walk.query_steps, walk.nk,
+             lambda j, i: (i, j))):
+        visited, longest = [], 0
+        for i in range(n):
+            walked = [block_of(i, s) for s in range(steps)]
+            live = [int(b) for b, ok in walked if ok]
+            assert [bool(ok) for _, ok in walked] \
+                == [True] * len(live) + [False] * (steps - len(live))
+            assert all(int(b) == live[-1] for b, ok in walked if not ok)
+            if one_run and any(pair(i, j) not in seen for j in live):
+                # nothing else is walked, but for the one step of a block
+                # that sees nothing (its output is zeros, and written)
+                assert len(live) == 1
+            longest = max(longest, len(live))
+            visited += [pair(i, j) for j in live]
+        assert longest == steps
+        assert len(set(visited)) == len(visited) and set(visited) >= seen
 
 
 def test_gated_mlp():
@@ -1002,7 +1071,14 @@ def _reload_telemetry():
 
 def test_telemetry_off_leaves_the_window_unchanged_on_yields_moe_counters(
         tmp_path, monkeypatch):
-    cfg = dict(CFG, experts_held=4)
+    # a dense layer and two sparse ones, two steps: what is asserted is
+    # sums over layers and steps, at any number of either
+    steps, sparse = 2, 2
+    cfg = dict(CFG, experts_held=4, num_hidden_layers=1 + sparse,
+               num_attention_heads_per_layer=[4, 6, 4],
+               layer_types=['full_attention', 'sliding_attention',
+                            'full_attention'],
+               mlp_layer_types=['dense'] + ['sparse'] * sparse)
 
     def run(on):
         telemetry._reset_for_tests()
@@ -1013,7 +1089,7 @@ def test_telemetry_off_leaves_the_window_unchanged_on_yields_moe_counters(
         else:
             monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
         _reload_telemetry()
-        mod = _fit(cfg, 3, monkeypatch)[0]
+        mod = _fit(cfg, steps, monkeypatch)[0]
         return _window_text(mod), telemetry.snapshot()
 
     try:
@@ -1023,10 +1099,11 @@ def test_telemetry_off_leaves_the_window_unchanged_on_yields_moe_counters(
         assert off == off_again and on != off
         assert not [k for k in snap_off['counters'] if k.startswith('moe.')]
         c = snap_on['counters']
-        assert c['moe.tokens'] == 3 * T * 4 and c['moe.dropped'] == 0
-        assert 0 < c['moe.pairs'] <= 3 * T * 3 * 4
-        # three steps of four sparse layers, one pass each
-        assert c['moe.layer_steps'] == c['moe.passes'] == 3 * 4
+        assert c['moe.tokens'] == steps * T * sparse \
+            and c['moe.dropped'] == 0
+        assert 0 < c['moe.pairs'] <= steps * T * 3 * sparse
+        # every step of every sparse layer, one pass each
+        assert c['moe.layer_steps'] == c['moe.passes'] == steps * sparse
         assert snap_on['gauges']['moe.load_max_over_mean'] >= 1.0
     finally:
         monkeypatch.delenv('MXTPU_TELEMETRY', raising=False)
