@@ -360,6 +360,25 @@ inline Symbol FullyConnected(const std::string &symbol_name,
   return op.CreateSymbol(symbol_name);
 }
 
+inline Symbol GatedDeltaRule(const std::string &symbol_name,
+    const Symbol &query,
+    const Symbol &key,
+    const Symbol &value,
+    const Symbol &g,
+    const Symbol &beta,
+    const Symbol &stats,
+    int num_heads = 1) {
+  Operator op("GatedDeltaRule");
+  op.SetInput("query", query);
+  op.SetInput("key", key);
+  op.SetInput("value", value);
+  op.SetInput("g", g);
+  op.SetInput("beta", beta);
+  op.SetInput("stats", stats);
+  op.SetParam("num_heads", detail::ToParam(num_heads));
+  return op.CreateSymbol(symbol_name);
+}
+
 inline Symbol GatedMLP(const std::string &symbol_name,
     const Symbol &data,
     const Symbol &w1_weight,
@@ -878,6 +897,17 @@ inline Symbol SequenceReverse(const std::string &symbol_name,
   op.SetInput("sequence_length", sequence_length);
   op.SetParam("use_sequence_length", detail::ToParam(use_sequence_length));
   op.SetParam("axis", detail::ToParam(axis));
+  return op.CreateSymbol(symbol_name);
+}
+
+inline Symbol ShortConv(const std::string &symbol_name,
+    const Symbol &data,
+    const Symbol &weight,
+    int kernel = 4) {
+  Operator op("ShortConv");
+  op.SetInput("data", data);
+  op.SetInput("weight", weight);
+  op.SetParam("kernel", detail::ToParam(kernel));
   return op.CreateSymbol(symbol_name);
 }
 

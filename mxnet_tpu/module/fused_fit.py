@@ -62,11 +62,11 @@ from ..executor import mirror_wrap
 from ..kvstore import _updater_key
 from ..ndarray.ndarray import from_jax
 from ..ops import registry as _reg
-from .window_pipeline import (WindowPipeline, dynamics_sentinel,
-                              health_sentinel, host_wrap, hyper_sentinel,
-                              moe_sentinel, note_hyper_window,
-                              note_moe_window, registered_jit,
-                              window_bisect, window_size)
+from .window_pipeline import (WindowPipeline, delta_sentinel,
+                              dynamics_sentinel, health_sentinel, host_wrap,
+                              hyper_sentinel, moe_sentinel, note_delta_window,
+                              note_hyper_window, note_moe_window,
+                              registered_jit, window_bisect, window_size)
 from .window_pipeline import plan_metric_or_reason as _metric_plan
 
 __all__ = ['FusedFitLoop']
@@ -503,11 +503,16 @@ class FusedFitLoop:
         # contract — captured at build, traced into the window, rides
         # the existing single fetch; None = byte-identical program
         self._dyn_fn = dynamics_sentinel()
-        # what the routed expert layers did, step by step (same contract:
-        # None without telemetry or without such a layer)
-        self._moe_fn = moe_sentinel(module._symbol, self._aux_names)
-        # ...and the residual streams' mixing matrices
-        self._hyper_fn = hyper_sentinel(module._symbol, self._aux_names)
+        # what the ops that keep statistics in an auxiliary state did, step
+        # by step: routed expert layers, the residual streams' mixing
+        # matrices, the linear-attention layers' recurrent states (same
+        # contract: none without telemetry or without such a node)
+        self._aux_stats = [(fn, note) for fn, note in (
+            (sentinel(module._symbol, self._aux_names), note)
+            for sentinel, note in ((moe_sentinel, note_moe_window),
+                                   (hyper_sentinel, note_hyper_window),
+                                   (delta_sentinel, note_delta_window)))
+            if fn is not None]
         self._out_names = list(module._symbol.list_outputs())
         self._last_lr = None   # last sampled lr (run-ledger scalars)
         self._upd_keys = updater_keys(module, self._grad_names)
@@ -817,8 +822,7 @@ class FusedFitLoop:
         stat_fns = self.stat_fns
         health_fn = self._health_fn
         dyn_fn = self._dyn_fn
-        moe_fn = self._moe_fn
-        hyper_fn = self._hyper_fn
+        aux_fns = [fn for fn, _ in self._aux_stats]
         accum = self._accum
         W = self.window
         mesh = self._mesh
@@ -1002,10 +1006,7 @@ class FusedFitLoop:
                             params=tuple(params[i] for i in grad_carry_idx),
                             new_params=tuple(new_params[i]
                                              for i in grad_carry_idx)))
-                    if moe_fn is not None:
-                        extras.append(moe_fn(new_aux))
-                    if hyper_fn is not None:
-                        extras.append(hyper_fn(new_aux))
+                    extras.extend(fn(new_aux) for fn in aux_fns)
                 if extras:
                     ys = (ys, *extras)
                 if compress:
@@ -1362,20 +1363,17 @@ class FusedFitLoop:
             (snapshotted at collection time — see below), the way the
             reference loop's update_metric would."""
             pieces, labels_w, win_snaps, win = pending
-            hrows = drows = mrows = yrows = None
+            hrows = drows = None
+            aux_rows = []
             if self._health_fn is not None or self._dyn_fn is not None \
-                    or self._moe_fn is not None \
-                    or self._hyper_fn is not None:
+                    or self._aux_stats:
                 parts = list(pieces)
                 pieces = parts.pop(0)
                 if self._health_fn is not None:
                     hrows = parts.pop(0)
                 if self._dyn_fn is not None:
                     drows = parts.pop(0)
-                if self._moe_fn is not None:
-                    mrows = parts.pop(0)
-                if self._hyper_fn is not None:
-                    yrows = parts.pop(0)
+                aux_rows = [parts.pop(0) for _ in self._aux_stats]
             with _tele.span('fused_fit.fetch', 'fused_fit', win=win):
                 # the window's one device->host fetch (everything
                 # after is host math) —
@@ -1391,17 +1389,12 @@ class FusedFitLoop:
                     hmat = np.asarray(hrows)
                 if drows is not None:
                     dmat = np.asarray(drows)
-                if mrows is not None:
-                    mmat = np.asarray(mrows)
-                if yrows is not None:
-                    ymat = np.asarray(yrows)
-            if mrows is not None:
-                note_moe_window(mmat, win=win)
+                aux_mats = [np.asarray(r) for r in aux_rows]
+            for (_, note), mat in zip(self._aux_stats, aux_mats):
+                note(mat, win=win)
             for j in labelled_idx:
                 _tele.counter('fit.labelled_rows').inc(
                     int(host[:, 2 * j + 1].sum()))
-            if yrows is not None:
-                note_hyper_window(ymat)
             if hrows is not None:
                 # mid-window NaN -> exact step attribution + (first
                 # incident) staged-path first-bad-layer bisect on the

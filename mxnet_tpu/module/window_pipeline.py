@@ -111,6 +111,19 @@ def dynamics_sentinel():
     return _dynamics.step_stats if _dynamics.enabled() else None
 
 
+def _aux_sentinel(symbol, aux_names, stat_names):
+    """``fn(new_aux) -> (nodes, k)``: this step's rows of the auxiliary
+    states that ``stat_names(symbol)`` names, stacked; None while telemetry
+    is off or the graph has no such node."""
+    if not _tele.enabled():
+        return None
+    idx = [aux_names.index(n) for n in stat_names(symbol) if n in aux_names]
+    if not idx:
+        return None
+    return lambda new_aux: jnp.stack(
+        [new_aux[i].astype(jnp.float32) for i in idx])
+
+
 def moe_sentinel(symbol, aux_names):
     """For a compiled window body whose graph holds routed expert layers:
     ``fn(new_aux) -> (layers, len(MOE_STATS))``, this step's statistics as
@@ -119,15 +132,8 @@ def moe_sentinel(symbol, aux_names):
     :func:`note_moe_window` turns it into the ``moe.*`` counters. None
     while telemetry is off or the graph has no such layer, leaving the
     traced window byte-identical to the plain form."""
-    if not _tele.enabled():
-        return None
     from ..ops.transformer import moe_stat_names
-    idx = [aux_names.index(n) for n in moe_stat_names(symbol)
-           if n in aux_names]
-    if not idx:
-        return None
-    return lambda new_aux: jnp.stack(
-        [new_aux[i].astype(jnp.float32) for i in idx])
+    return _aux_sentinel(symbol, aux_names, moe_stat_names)
 
 
 def note_moe_window(rows, win=None):
@@ -159,18 +165,11 @@ def hyper_sentinel(symbol, aux_names):
     ``fn(new_aux) -> (nodes, len(HYPER_STATS))``, each step's statistics as
     the nodes left them in their auxiliary states; None while telemetry is
     off or the graph has no such node."""
-    if not _tele.enabled():
-        return None
     from ..ops.transformer import hyper_stat_names
-    idx = [aux_names.index(n) for n in hyper_stat_names(symbol)
-           if n in aux_names]
-    if not idx:
-        return None
-    return lambda new_aux: jnp.stack(
-        [new_aux[i].astype(jnp.float32) for i in idx])
+    return _aux_sentinel(symbol, aux_names, hyper_stat_names)
 
 
-def note_hyper_window(rows):
+def note_hyper_window(rows, win=None):
     """The host side of :func:`hyper_sentinel`: `rows` (W, nodes, k) as
     fetched. Gauge ``hyper.res_dev_max``: the largest distance of a mixing
     matrix's row and column sums from 1, over the window's steps, nodes
@@ -178,6 +177,26 @@ def note_hyper_window(rows):
     from ..ops.transformer import HYPER_STATS
     _tele.gauge('hyper.res_dev_max').set(
         float(rows[..., HYPER_STATS.index('res_dev_max')].max()))
+
+
+def delta_sentinel(symbol, aux_names):
+    """:func:`moe_sentinel` for the ``GatedDeltaRule`` nodes of the graph:
+    ``fn(new_aux) -> (nodes, len(DELTA_STATS))``."""
+    from ..ops.transformer import delta_stat_names
+    return _aux_sentinel(symbol, aux_names, delta_stat_names)
+
+
+def note_delta_window(rows, win=None):
+    """The host side of :func:`delta_sentinel`: `rows` (W, nodes, k) as
+    fetched. Counter ``delta_rule.rows`` (rows handed to the nodes, summed
+    over nodes and steps); gauge ``delta_rule.state_abs_max``: the largest magnitude
+    of a recurrent state after a step's last row, over the window's steps,
+    nodes and heads."""
+    from ..ops.transformer import DELTA_STATS
+    col = {n: rows[..., i] for i, n in enumerate(DELTA_STATS)}
+    _tele.counter('delta_rule.rows').inc(int(col['rows'].sum()))
+    _tele.gauge('delta_rule.state_abs_max').set(
+        float(col['state_abs_max'].max()))
 
 
 def window_bisect(executor, data_names, label_names, snaps, is_train,

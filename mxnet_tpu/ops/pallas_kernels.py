@@ -2164,7 +2164,12 @@ hyper_post.defvjp(_hyper_post_fwd, _hyper_post_bwd)
 # the rows of dc = dy * C after it) come with one more block of 8 rows of
 # the same arrays, 3% of a block of 256. Inside, the columns are walked in
 # chunks, in float32, and written back in the operands' precision: no
-# float32 [T, C] array exists.
+# float32 [T, C] array exists. With their gates off (``gated=False``) the
+# same two bodies run y = silu(conv(x)) over one array [B, T, C], a
+# linear-attention layer's convolution (:func:`silu_conv`): the halo block
+# of 8 rows covers its 3 rows back, and backward the next block's first
+# rows of dc = dy silu'(c) are made from that block's rows and this one's
+# last.
 
 SHORT_CONV_ROWS = 8     # rows of the taps' array and of a halo block
 _SHORT_CONV_BLOCK = 256
@@ -2194,21 +2199,39 @@ def _moved_up(u, after, k):
     return jnp.concatenate([u, after], axis=0)[k:k + u.shape[0]]
 
 
-def _short_conv_fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, C, L, chunk):
+def _taps_sum(w, before, u, L):
+    """c_t = sum_j w[j] u_{t - (L - 1 - j)}, the rows before the block
+    `before`'s last."""
+    c = w[L - 1:L] * u
+    for k in range(1, L):
+        c += w[L - 1 - k:L - k] * _moved_down(before, u, k)
+    return c
+
+
+def _short_conv_fwd_kernel(x_ref, prev_ref, w_ref, o_ref, *, C, L, chunk,
+                           gated=True):
     first = pl.program_id(1) == 0
     for c0 in range(0, C, chunk):
         part = lambda ref, third: _third(ref, third, c0, chunk, C)  # noqa
-        u = part(x_ref, 0) * part(x_ref, 2)
-        before = jnp.where(first, 0.0, part(prev_ref, 0) * part(prev_ref, 2))
-        w = w_ref[:, c0:c0 + chunk]
-        c = w[L - 1:L] * u
-        for k in range(1, L):
-            c += w[L - 1 - k:L - k] * _moved_down(before, u, k)
-        o_ref[0, :, c0:c0 + chunk] = (part(x_ref, 1) * c).astype(o_ref.dtype)
+        if gated:
+            u = part(x_ref, 0) * part(x_ref, 2)
+            before = jnp.where(first, 0.0,
+                               part(prev_ref, 0) * part(prev_ref, 2))
+        else:               # y = silu(conv(x)): one array in, no gates
+            u, before = part(x_ref, 0), jnp.where(first, 0.0,
+                                                  part(prev_ref, 0))
+        c = _taps_sum(w_ref[:, c0:c0 + chunk], before, u, L)
+        y = part(x_ref, 1) * c if gated else jax.nn.silu(c)
+        o_ref[0, :, c0:c0 + chunk] = y.astype(o_ref.dtype)
+
+
+def _silu_slope(c):
+    s = jax.nn.sigmoid(c)
+    return s * (1.0 + c * (1.0 - s))
 
 
 def _short_conv_bwd_kernel(x_ref, prev_ref, next_ref, g_ref, gnext_ref, w_ref,
-                           dx_ref, dw_ref, *, C, L, chunk):
+                           dx_ref, dw_ref, *, C, L, chunk, gated=True):
     b, i = pl.program_id(0), pl.program_id(1)
     first, last = i == 0, i == pl.num_programs(1) - 1
 
@@ -2219,27 +2242,44 @@ def _short_conv_bwd_kernel(x_ref, prev_ref, next_ref, g_ref, gnext_ref, w_ref,
     for c0 in range(0, C, chunk):
         part = lambda ref, third: _third(ref, third, c0, chunk, C)  # noqa
         cols = lambda third: _third_cols(third, c0, chunk, C)  # noqa: E731
-        xb, xc, xx = part(x_ref, 0), part(x_ref, 1), part(x_ref, 2)
-        u = xb * xx
-        before = jnp.where(first, 0.0, part(prev_ref, 0) * part(prev_ref, 2))
-        dy = g_ref[0, :, c0:c0 + chunk].astype(jnp.float32)
-        dc = dy * xc
-        after = jnp.where(
-            last, 0.0, gnext_ref[0, :, c0:c0 + chunk].astype(jnp.float32)
-            * part(next_ref, 1))
-        w = w_ref[:, c0:c0 + chunk]
-        c, du = w[L - 1:L] * u, w[L - 1:L] * dc
+        own = lambda ref: ref[0, :, c0:c0 + chunk].astype(  # noqa: E731
+            jnp.float32)
+        if gated:
+            xb, xc, xx = part(x_ref, 0), part(x_ref, 1), part(x_ref, 2)
+            u = xb * xx
+            before = jnp.where(first, 0.0,
+                               part(prev_ref, 0) * part(prev_ref, 2))
+            dy = own(g_ref)
+            dc = dy * xc
+            after = jnp.where(last, 0.0, own(gnext_ref) * part(next_ref, 1))
+            w = w_ref[:, c0:c0 + chunk]
+        else:
+            # y = silu(conv(x)): dc = dy silu'(c) with c made again, for
+            # the block and for the next block's first rows (their c reads
+            # this block's last)
+            w = w_ref[:, c0:c0 + chunk]
+            u = own(x_ref)
+            before = jnp.where(first, 0.0, own(prev_ref))
+            dc = own(g_ref) * _silu_slope(_taps_sum(w, before, u, L))
+            after = jnp.where(last, 0.0, own(gnext_ref) * _silu_slope(
+                _taps_sum(w, u[-SHORT_CONV_ROWS:], own(next_ref), L)))
+        c = w[L - 1:L] * u if gated else None
+        du = w[L - 1:L] * dc
         dw_ref[L - 1:L, c0:c0 + chunk] += jnp.sum(dc * u, axis=0,
                                                   keepdims=True)
         for k in range(1, L):
             u_k = _moved_down(before, u, k)
-            c += w[L - 1 - k:L - k] * u_k
+            if gated:
+                c += w[L - 1 - k:L - k] * u_k
             du += w[L - 1 - k:L - k] * _moved_up(dc, after, k)
             dw_ref[L - 1 - k:L - k, c0:c0 + chunk] += jnp.sum(
                 dc * u_k, axis=0, keepdims=True)
-        dx_ref[0, :, cols(0)] = (du * xx).astype(dx_ref.dtype)
-        dx_ref[0, :, cols(1)] = (dy * c).astype(dx_ref.dtype)
-        dx_ref[0, :, cols(2)] = (du * xb).astype(dx_ref.dtype)
+        if gated:
+            dx_ref[0, :, cols(0)] = (du * xx).astype(dx_ref.dtype)
+            dx_ref[0, :, cols(1)] = (dy * c).astype(dx_ref.dtype)
+            dx_ref[0, :, cols(2)] = (du * xb).astype(dx_ref.dtype)
+        else:
+            dx_ref[0, :, c0:c0 + chunk] = du.astype(dx_ref.dtype)
 
 
 def _short_conv_rows(T):
@@ -2249,10 +2289,10 @@ def _short_conv_rows(T):
     return blk, -T % blk
 
 
-def _short_conv_setup(bcx, w, name):
+def _short_conv_setup(bcx, w, name, gated=True):
     B, T, C3 = bcx.shape
     C, L = w.shape
-    if C3 != 3 * C or not 1 <= L <= SHORT_CONV_ROWS:
+    if C3 != (3 * C if gated else C) or not 1 <= L <= SHORT_CONV_ROWS:
         raise ValueError('%s: operand %s against taps %s'
                          % (name, tuple(bcx.shape), tuple(w.shape)))
     blk, pad = _short_conv_rows(T)
@@ -2290,18 +2330,21 @@ def _short_conv_params():
         vmem_limit_bytes=64 << 20)
 
 
-def short_conv_forward(bcx, w, name='short_conv'):
+def short_conv_forward(bcx, w, name='short_conv', gated=True):
     """y [B, T, C] = C * (sum_j w[:, j] u_{t - (L - 1 - j)}) with u = B * x
     (zero before the sequence's start) for bcx [B, T, 3 C] = [B | C | x] and
-    taps w [C, L]. The kernel is named ``<name>_fwd`` in a device trace."""
-    B, T, _ = bcx.shape
+    taps w [C, L]; with `gated` false, y = silu(sum_j w[:, j] x_{t - (L - 1
+    - j)}) for bcx = x [B, T, C]. The kernel is named ``<name>_fwd`` in a
+    device trace."""
+    B, T, width = bcx.shape
     C, L = w.shape
-    cut = _short_conv_setup(bcx, w, name)
+    cut = _short_conv_setup(bcx, w, name, gated)
+    static = {} if gated else {'gated': False}
     out = run_kernel(lambda interpret: pl.pallas_call(
         functools.partial(_short_conv_fwd_kernel, C=C, L=L,
-                          chunk=_short_conv_chunk(C)),
+                          chunk=_short_conv_chunk(C), **static),
         grid=cut.grid,
-        in_specs=[cut.rows(3 * C), cut.before(3 * C), cut.whole],
+        in_specs=[cut.rows(width), cut.before(width), cut.whole],
         out_specs=cut.rows(C),
         out_shape=jax.ShapeDtypeStruct((B, cut.x.shape[1], C), bcx.dtype),
         compiler_params=_short_conv_params(), interpret=interpret,
@@ -2309,20 +2352,21 @@ def short_conv_forward(bcx, w, name='short_conv'):
     return out[:, :T]
 
 
-def short_conv_backward(bcx, w, g, name='short_conv'):
-    """(d_bcx [B, T, 3 C], dw [C, L] float32) of :func:`short_conv_forward`
+def short_conv_backward(bcx, w, g, name='short_conv', gated=True):
+    """(d_bcx, as bcx, and dw [C, L] float32) of :func:`short_conv_forward`
     from the output's cotangent g [B, T, C]. ``<name>_bwd``."""
-    B, T, _ = bcx.shape
+    B, T, width = bcx.shape
     C, L = w.shape
-    cut = _short_conv_setup(bcx, w, name)
+    cut = _short_conv_setup(bcx, w, name, gated)
+    static = {} if gated else {'gated': False}
     g = _pad_rows(g, cut.x.shape[1] - T)
     dx, dw = run_kernel(lambda interpret: pl.pallas_call(
         functools.partial(_short_conv_bwd_kernel, C=C, L=L,
-                          chunk=_short_conv_chunk(C)),
+                          chunk=_short_conv_chunk(C), **static),
         grid=cut.grid,
-        in_specs=[cut.rows(3 * C), cut.before(3 * C), cut.after(3 * C),
+        in_specs=[cut.rows(width), cut.before(width), cut.after(width),
                   cut.rows(C), cut.after(C), cut.whole],
-        out_specs=[cut.rows(3 * C), cut.whole],
+        out_specs=[cut.rows(width), cut.whole],
         out_shape=[jax.ShapeDtypeStruct(cut.x.shape, bcx.dtype),
                    jax.ShapeDtypeStruct((SHORT_CONV_ROWS, C), jnp.float32)],
         compiler_params=_short_conv_params(), interpret=interpret,
@@ -2348,3 +2392,291 @@ def _short_conv_bwd(res, g):
 
 
 short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
+@jax.custom_vjp
+def silu_conv(x, w):
+    """silu of the causal depthwise convolution of x [B, T, C] with taps w
+    [C, L]: the two kernels above with their gates off."""
+    return short_conv_forward(x, w, gated=False)
+
+
+def _silu_conv_fwd(x, w):
+    return silu_conv(x, w), (x, w)
+
+
+def _silu_conv_bwd(res, g):
+    x, w = res
+    dx, dw = short_conv_backward(x, w, g, gated=False)
+    return dx, dw.astype(w.dtype)
+
+
+silu_conv.defvjp(_silu_conv_fwd, _silu_conv_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Gated delta rule: a scan whose grid carries state along the sequence
+# ---------------------------------------------------------------------------
+# Per head, with S in R^{dk x dv} and S_0 = 0:
+#   S_t = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S_t^T k_t);
+#   S_t = S_t + k_t u_t^T;   o_t = S_t^T q_t.
+# In chunks of C rows (gamma: the running sum of g inside a chunk, Gamma_ij
+# = exp(gamma_i - gamma_j) for i >= j) this is, exactly,
+#   A = strict_lower(diag(beta) (K K^T * Gamma));  T = (I + A)^-1 diag(beta)
+#   W = T (K * exp(gamma));  U = T V;  U' = U - W S_0
+#   O = (Q * exp(gamma)) S_0 + lower((Q K^T) * Gamma) U'
+#   S_C = exp(gamma_C) S_0 + (K * exp(gamma_C - gamma))^T U'.
+# What does not depend on S_0 (A, the solve, W, U, the masked Q K^T) is the
+# same work for every chunk and is left to XLA, batched over chunks, under
+# autodiff (:func:`_delta_chunks`): the solve and every exp in float32,
+# Gamma from the difference. What does depend on it is the chain of chunks:
+# the two kernels walk it, forward and from the end, with the state (or its
+# cotangent) of `heads` heads in VMEM across the grid steps of one
+# sequence. Their operands are by head, [B, H, T, D], because neither 96
+# nor 192 columns are a multiple of the 128 lanes. The forward kernel
+# writes the state at each chunk's start, which is what the backward
+# kernel needs of it.
+
+DELTA_CHUNK = 64
+# heads walked by one grid step: their chains are independent, so the
+# scheduler has one's products to issue while another's drain
+_DELTA_HEADS = 2
+
+
+def _delta_dot(dtype):
+    return _dot32 if dtype == jnp.float32 else _dot
+
+
+def _delta_fwd_kernel(qg_ref, kd_ref, w_ref, u_ref, p_ref, decay_ref, o_ref,
+                      s0_ref, smax_ref, s_s, *, heads):
+    c = pl.program_id(2)
+    dot = _delta_dot(w_ref.dtype)
+
+    @pl.when(c == 0)
+    def _():
+        s_s[...] = jnp.zeros(s_s.shape, jnp.float32)
+
+    for j in range(heads):
+        S = s_s[j]
+        s0_ref[j] = S
+        Sd = S.astype(w_ref.dtype)
+        u1 = u_ref[j].astype(jnp.float32) - dot(w_ref[j], Sd, ((1,), (0,)))
+        u1d = u1.astype(u_ref.dtype)
+        o_ref[j] = (dot(qg_ref[j], Sd, ((1,), (0,)))
+                    + dot(p_ref[j], u1d, ((1,), (0,)))).astype(o_ref.dtype)
+        s_s[j] = decay_ref[j] * S + dot(kd_ref[j], u1d, ((0,), (0,)))
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _():
+        for j in range(heads):
+            smax_ref[j] = jnp.max(jnp.abs(s_s[j]), axis=0, keepdims=True)
+
+
+def _delta_bwd_kernel(qg_ref, kd_ref, w_ref, u_ref, p_ref, decay_ref, s0_ref,
+                      do_ref, dqg_ref, dkd_ref, dw_ref, du_ref, dp_ref,
+                      ddecay_ref, ds_s, *, heads):
+    dtype = w_ref.dtype
+    dot = _delta_dot(dtype)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_s[...] = jnp.zeros(ds_s.shape, jnp.float32)
+
+    for j in range(heads):
+        S, dS1 = s0_ref[j], ds_s[j]      # state before, cotangent after
+        Sd, dS1d, do = S.astype(dtype), dS1.astype(dtype), do_ref[j]
+        u1d = (u_ref[j].astype(jnp.float32)
+               - dot(w_ref[j], Sd, ((1,), (0,)))).astype(dtype)
+        du1 = dot(p_ref[j], do, ((0,), (0,))) \
+            + dot(kd_ref[j], dS1d, ((1,), (0,)))
+        du1d = du1.astype(dtype)
+        dp_ref[j] = dot(do, u1d, ((1,), (1,))).astype(dp_ref.dtype)
+        dqg_ref[j] = dot(do, Sd, ((1,), (1,))).astype(dqg_ref.dtype)
+        dkd_ref[j] = dot(u1d, dS1d, ((1,), (1,))).astype(dkd_ref.dtype)
+        dw_ref[j] = (-dot(du1d, Sd, ((1,), (1,)))).astype(dw_ref.dtype)
+        du_ref[j] = du1d
+        ddecay_ref[j] = jnp.sum(S * dS1, axis=0, keepdims=True)
+        ds_s[j] = dot(qg_ref[j], do, ((0,), (0,))) + decay_ref[j] * dS1 \
+            - dot(w_ref[j], du1d, ((0,), (0,)))
+
+
+def _delta_specs(C, dk, dv, heads, at):
+    """Block specs of the scan's arrays for `heads` heads a grid step, the
+    chunk taken being ``at(c)``: (rows of width D, a chunk's own
+    [.., 1, dv] row, a chunk's state)."""
+    def rows(D):
+        return pl.BlockSpec((None, heads, C, D),
+                            lambda b, h, c: (b, h, at(c), 0))
+    row = pl.BlockSpec((None, heads, None, 1, dv),
+                       lambda b, h, c: (b, h, at(c), 0, 0))
+    state = pl.BlockSpec((None, heads, None, dk, dv),
+                         lambda b, h, c: (b, h, at(c), 0, 0))
+    return rows, row, state
+
+
+def _delta_heads(H):
+    return next(h for h in (_DELTA_HEADS, 1) if H % h == 0)
+
+
+def delta_scan_forward(qg, kd, w, u, p, decay, name='delta_rule'):
+    """(O [B, H, T, dv], the state at each chunk's start [B, H, n, dk, dv]
+    float32, the last state's largest magnitudes by column [B, H, 1, dv])
+    of the chain ``U' = U - W S; O = Qg S + P U'; S = decay S + Kd^T U'``
+    over the n chunks of T rows, S = 0 before the first: qg, kd, w
+    [B, H, T, dk], u [B, H, T, dv], p [B, H, T, C] (a chunk's rows against
+    its own), decay [B, H, n, 1, dv] float32. ``<name>_fwd``."""
+    B, H, T, dk = qg.shape
+    dv, C = u.shape[3], p.shape[3]
+    n, heads = T // C, _delta_heads(H)
+    rows, row, state = _delta_specs(C, dk, dv, heads, lambda c: c)
+    last = pl.BlockSpec((None, heads, 1, dv), lambda b, h, c: (b, h, 0, 0))
+    return run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_delta_fwd_kernel, heads=heads),
+        grid=(B, H // heads, n),
+        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(C), row],
+        out_specs=[rows(dv), state, last],
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), u.dtype),
+                   jax.ShapeDtypeStruct((B, H, n, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, 1, dv), jnp.float32)],
+        scratch_shapes=[_vmem((heads, dk, dv))],
+        compiler_params=_attn_params(2), interpret=interpret,
+        name=name + '_fwd'), qg, kd, w, u, p, decay)
+
+
+def delta_scan_backward(qg, kd, w, u, p, decay, states, do,
+                        name='delta_rule'):
+    """The cotangents of :func:`delta_scan_forward`'s six operands from
+    O's, the chunks walked from the end with the state's cotangent
+    carried; `states` as the forward kernel wrote them. ``<name>_bwd``."""
+    B, H, T, dk = qg.shape
+    dv, C = u.shape[3], p.shape[3]
+    n, heads = T // C, _delta_heads(H)
+    rows, row, state = _delta_specs(C, dk, dv, heads, lambda c: n - 1 - c)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)     # noqa: E731
+    return run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(_delta_bwd_kernel, heads=heads),
+        grid=(B, H // heads, n),
+        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(C), row,
+                  state, rows(dv)],
+        out_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(C), row],
+        out_shape=[like(qg), like(kd), like(w), like(u), like(p),
+                   like(decay)],
+        scratch_shapes=[_vmem((heads, dk, dv))],
+        compiler_params=_attn_params(2), interpret=interpret,
+        name=name + '_bwd'), qg, kd, w, u, p, decay, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def delta_scan(qg, kd, w, u, p, decay, name='delta_rule'):
+    """(O, the last state's largest magnitudes by column) by the two
+    kernels above; the second takes no cotangent."""
+    o, _, smax = delta_scan_forward(qg, kd, w, u, p, decay, name)
+    return o, smax
+
+
+def _delta_scan_fwd(qg, kd, w, u, p, decay, name):
+    o, states, smax = delta_scan_forward(qg, kd, w, u, p, decay, name)
+    # the chain is the one part of the op that cannot be made again in
+    # parallel: a mirrored stage keeps what it gave, so it runs once
+    o, states = dear(o, name + '_out'), dear(states, name + '_states')
+    return (o, smax), (qg, kd, w, u, p, decay, states)
+
+
+def _delta_scan_bwd(name, res, g):
+    return delta_scan_backward(*res, g[0], name=name)
+
+
+delta_scan.defvjp(_delta_scan_fwd, _delta_scan_bwd)
+
+
+_mm32 = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a [..., C, C] strictly lower triangular, C a power
+    of two, float32: the inverses of the diagonal blocks of 1, 2, 4, ..
+    rows, each from the two of half its size (``[[X1, 0], [-X2 A21 X1,
+    X2]]``), which is forward substitution by blocks and as stable. Its
+    cotangent is ``-X^T g X^T``, two products, where autodiff would walk
+    the blocks back."""
+    C = a.shape[-1]
+    i = jnp.arange(C)
+    x = jnp.broadcast_to(jnp.eye(C, dtype=jnp.float32), a.shape)
+    size = 1
+    while size < C:
+        lower_left = ((i[:, None] // (2 * size) == i[None, :] // (2 * size))
+                      & (i[:, None] // size % 2 == 1)
+                      & (i[None, :] // size % 2 == 0))
+        x = x - _mm32(_mm32(x, jnp.where(lower_left, a, 0.0)), x)
+        size *= 2
+    return x
+
+
+def _unit_lower_inverse_fwd(a):
+    x = _unit_lower_inverse(a)
+    return x, x
+
+
+def _unit_lower_inverse_bwd(x, g):
+    xt = jnp.swapaxes(x, -1, -2)
+    return (-_mm32(_mm32(xt, g), xt),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _delta_chunks(q, k, v, g, beta, C):
+    """The operands of :func:`delta_scan` from the op's, by head and in
+    float32 but for the last cast: q and k [B, H, T, dk] as the scan takes
+    them (normalised, q scaled), v [B, H, T, dv], g and beta [B, H, T], T
+    whole chunks of C."""
+    B, H, T, dk = q.shape
+    n, dtype = T // C, v.dtype
+    hi = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          precision=hi, preferred_element_type=jnp.float32)
+
+    q, k, v = (x.reshape(B, H, n, C, -1) for x in (q, k, v))
+    beta = beta.reshape(B, H, n, C)
+    gamma = jnp.cumsum(g.reshape(B, H, n, C), axis=-1)
+    i = jnp.arange(C)
+    lower = i[:, None] >= i[None, :]
+    # exp of the difference, and of nothing above the diagonal
+    Gamma = jnp.exp(jnp.where(lower, gamma[..., :, None]
+                              - gamma[..., None, :], -jnp.inf))
+    a = jnp.where(i[:, None] > i[None, :],
+                  beta[..., :, None] * mm('bhnid,bhnjd->bhnij', k, k) * Gamma,
+                  0.0)
+    t = _unit_lower_inverse(a) * beta[..., None, :]
+    e = jnp.exp(gamma)[..., None]
+    w = mm('bhnij,bhnjd->bhnid', t, k * e)
+    u = mm('bhnij,bhnjd->bhnid', t, v)
+    p = mm('bhnid,bhnjd->bhnij', q, k) * Gamma
+    last = gamma[..., -1:]
+    kd = k * jnp.exp(last - gamma)[..., None]
+    decay = jnp.broadcast_to(jnp.exp(last)[..., None],
+                             (B, H, n, 1, v.shape[-1]))
+    flat = lambda x: x.astype(dtype).reshape(B, H, T, -1)       # noqa: E731
+    return flat(q * e), flat(kd), flat(w), flat(u), flat(p), decay
+
+
+def delta_rule(q, k, v, g, beta, chunk=DELTA_CHUNK, name='delta_rule'):
+    """(o [B, H, T, dv] in v's dtype, the largest magnitude of a state
+    after the last row [B] float32) of the gated delta rule for q and k
+    [B, H, T, dk] float32 (normalised, q scaled), v [B, H, T, dv], g (the
+    log of the decay, <= 0) and beta [B, H, T] float32. A sequence that
+    is no whole number of chunks is padded with g = 0, beta = 0, k = 0,
+    which leaves the state as it is."""
+    T = q.shape[2]
+    pad = -T % chunk
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
+    with jax.named_scope('chunks'):
+        operands = _delta_chunks(q, k, v, g, beta, chunk)
+    o, smax = delta_scan(*operands, name)
+    return o[:, :, :T], jnp.max(smax, axis=(1, 2, 3))

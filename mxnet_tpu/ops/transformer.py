@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from . import pallas_kernels as pk
 from .registry import dear, get as registry_get, register
 
-__all__ = ['MOE_STATS', 'moe_stat_names', 'HYPER_STATS', 'hyper_stat_names']
+__all__ = ['MOE_STATS', 'moe_stat_names', 'HYPER_STATS', 'hyper_stat_names',
+           'DELTA_STATS', 'delta_stat_names']
 
 
 def _matmul(x, w):
@@ -479,19 +480,24 @@ def _hyper_post_op(attrs, x, z, coef):
 # Gated short convolution
 # ---------------------------------------------------------------------------
 
-def _short_conv_plain(bcx, w):
-    """pk.short_conv in plain jnp: shifted slices, float32."""
-    C, L = w.shape
-    T = bcx.shape[-2]
-    gate_in, gate_out, x = (bcx[..., i * C:(i + 1) * C].astype(jnp.float32)
-                            for i in range(3))
-    u = gate_in * x
+def _causal_taps(u, w):
+    """c_t = sum_j w[:, j] u_{t - (L - 1 - j)} for u [B, T, C] float32 and
+    taps w (C, L), zero before the sequence's start: shifted slices."""
+    L, T = w.shape[1], u.shape[-2]
     w32 = w.astype(jnp.float32)
     c = w32[:, L - 1] * u
     for k in range(1, L):       # tap L - 1 - k weighs u_{t-k}
         c = c + w32[:, L - 1 - k] * jnp.pad(
             u, ((0, 0), (k, 0), (0, 0)))[:, :T]
-    return (gate_out * c).astype(bcx.dtype)
+    return c
+
+
+def _short_conv_plain(bcx, w):
+    """pk.short_conv in plain jnp: shifted slices, float32."""
+    C = w.shape[0]
+    gate_in, gate_out, x = (bcx[..., i * C:(i + 1) * C].astype(jnp.float32)
+                            for i in range(3))
+    return (gate_out * _causal_taps(gate_in * x, w)).astype(bcx.dtype)
 
 
 @register('GatedShortConv', input_names=['data', 'weight'],
@@ -516,6 +522,122 @@ def _gated_short_conv(attrs, bcx, w):
         raise ValueError('GatedShortConv: kernel %s against taps %s'
                          % (attrs.get('kernel'), tuple(w.shape)))
     return pk.dispatch(pk.short_conv, _short_conv_plain, bcx, w)
+
+
+@register('ShortConv', input_names=['data', 'weight'],
+          param_defaults={'kernel': 4})
+def _short_conv_op(attrs, x, w):
+    """A causal depthwise convolution under SiLU, as a linear-attention
+    layer runs over its projections: data [B, T, C], weight (C, kernel)
+    the taps, no bias, zero before the sequence's start. With ``c_t =
+    sum_j weight[:, j] data_{t - (kernel - 1 - j)}`` returns ``silu(c)``,
+    [B, T, C]; float32 inside, data's dtype out.
+
+    On a TPU it takes ``GatedShortConv``'s two kernels with their gates
+    off (``short_conv_fwd`` / ``short_conv_bwd``: each reads its arrays
+    once, a block of rows at the whole width); XLA's form reads the
+    operand once a tap."""
+    if int(attrs.get('kernel', 4)) != w.shape[1]:
+        raise ValueError('ShortConv: kernel %s against taps %s'
+                         % (attrs.get('kernel'), tuple(w.shape)))
+
+    def plain(x, w):
+        c = _causal_taps(x.astype(jnp.float32), w)
+        return jax.nn.silu(c).astype(x.dtype)
+
+    return pk.dispatch(pk.silu_conv, plain, x, w)
+
+
+# ---------------------------------------------------------------------------
+# Gated delta rule
+# ---------------------------------------------------------------------------
+
+# what GatedDeltaRule writes into its ``stats`` auxiliary state each step
+DELTA_STATS = ('rows', 'state_abs_max')
+_DELTA_NORM_EPS = 1e-6      # under the root of q's and k's squared length
+
+
+def delta_stat_names(symbol):
+    """Names of the auxiliary states that the GatedDeltaRule nodes of
+    `symbol` write their per-step statistics into, in graph order."""
+    at = registry_get('GatedDeltaRule').input_names.index('stats')
+    return [node.inputs[at][0].name for node in symbol._topo()
+            if not node.is_variable() and node.op == 'GatedDeltaRule']
+
+
+def _delta_rule_plain(q, k, v, g, beta):
+    """pk.delta_rule in plain jnp: the recurrence row by row under
+    ``lax.scan`` (and autodiff, which keeps a state a row: small shapes
+    off the TPU). Operands by head, as the kernels take them."""
+    hi = jax.lax.Precision.HIGHEST
+
+    def step(S, row):
+        q_t, k_t, v_t, g_t, b_t = row
+        S = jnp.exp(g_t)[..., None, None] * S
+        u = b_t[..., None] * (v_t - jnp.einsum('bhkv,bhk->bhv', S, k_t,
+                                               precision=hi))
+        S = S + k_t[..., :, None] * u[..., None, :]
+        return S, jnp.einsum('bhkv,bhk->bhv', S, q_t, precision=hi)
+
+    B, H, _, dk = q.shape
+    rows = tuple(jnp.moveaxis(x.astype(jnp.float32), 2, 0)
+                 for x in (q, k, v, g, beta))
+    S, o = jax.lax.scan(step, jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32),
+                        rows)
+    return jnp.moveaxis(o, 0, 2).astype(v.dtype), \
+        jnp.max(jnp.abs(S), axis=(1, 2, 3))
+
+
+@register('GatedDeltaRule',
+          input_names=['query', 'key', 'value', 'g', 'beta', 'stats'],
+          param_defaults={'num_heads': 1}, num_outputs=2,
+          num_visible_outputs=1, mutate_inputs={5: 1}, aux_inputs=('stats',))
+def _gated_delta_rule(attrs, q, k, v, g, beta, stats):
+    """The gated delta rule of a linear-attention layer: per head, with a
+    state S in R^{dk x dv} that is zero before the sequence's start,
+
+        S_t = exp(g_t) S_{t-1};  u_t = beta_t (v_t - S_t^T k_t)
+        S_t = S_t + k_t u_t^T;   o_t = S_t^T q_t
+
+    (so ``S_t = exp(g_t) (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t
+    v_t^T``). query and key [B, T, H * dk], value [B, T, H * dv], g (the
+    log of the decay, <= 0) and beta [B, T, H]; a head's q and k are
+    divided by their length here (the root of the squares' sum + 1e-6) and
+    q by ``sqrt(dk)``. Returns [B, T, H * dv]; float32 inside, value's
+    dtype out. ``stats`` is an auxiliary state that receives DELTA_STATS:
+    the rows it was handed this step (B * T, written here and not by the
+    kernels) and the largest magnitude of a state after the last row (with beta up to 2 a step's eigenvalue can be -1).
+
+    On a TPU the chunked form runs it (``ops/pallas_kernels.py``): what a
+    chunk of 64 rows needs that does not depend on the state (the
+    triangular solve among it) is XLA's, batched over chunks, and the
+    chain of chunks is the kernels' ``delta_rule_fwd`` and
+    ``delta_rule_bwd``, which carry the state, or its cotangent, along the
+    sequence. A mirrored stage keeps the chain's output and the states at
+    the chunks' starts, so the chain runs once a step and direction; the
+    rest is made again from the operands. Elsewhere: the recurrence itself
+    under ``lax.scan``."""
+    H = int(attrs['num_heads'])
+    B, T, _ = q.shape
+    dk, dv = q.shape[2] // H, v.shape[2] // H
+
+    def heads(x, D):
+        return x.reshape(B, T, H, D).transpose(0, 2, 1, 3)
+
+    def unit(x, scale):
+        x = heads(x, dk).astype(jnp.float32)
+        return x * (jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                  + _DELTA_NORM_EPS) * scale)
+
+    with jax.named_scope('heads'):
+        operands = (unit(q, dk ** -0.5), unit(k, 1.0), heads(v, dv),
+                    g.astype(jnp.float32).transpose(0, 2, 1),
+                    beta.astype(jnp.float32).transpose(0, 2, 1))
+    o, smax = pk.dispatch(pk.delta_rule, _delta_rule_plain, *operands)
+    with jax.named_scope('heads'):
+        out = o.transpose(0, 2, 1, 3).reshape(B, T, H * dv)
+    return out, jnp.stack([jnp.float32(B * T), jnp.max(smax)]) \
+        .astype(stats.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -131,6 +131,19 @@ def _gated_short_conv_params(attrs, in_shapes):
         if data else {}
 
 
+@param_shape_hook('ShortConv')
+def _short_conv_params(attrs, in_shapes):
+    data = in_shapes[0]
+    return {'weight': (data[-1], int(attrs.get('kernel', 4)))} \
+        if data else {}
+
+
+@param_shape_hook('GatedDeltaRule')
+def _gated_delta_rule_params(attrs, in_shapes):
+    from ..ops.transformer import DELTA_STATS
+    return {'stats': (len(DELTA_STATS),)}
+
+
 @param_shape_hook('MoE')
 def _moe_params(attrs, in_shapes):
     data = in_shapes[0]

@@ -89,6 +89,11 @@ def _short_conv(x, w, g):
     return pk.short_conv_backward(x, w, g) + (pk.short_conv_forward(x, w),)
 
 
+def _silu_conv(x, w, g):
+    return pk.short_conv_backward(x, w, g, gated=False) \
+        + (pk.short_conv_forward(x, w, gated=False),)
+
+
 def _latent_attention(qn, qr, kn, kr, v, g, scale=None):
     out, lse = pk.latent_attention_forward(qn, qr, kn, kr, v, 32,
                                            scale=scale)
@@ -184,6 +189,11 @@ KERNELS = [
     # in blocks of 256 rows at the whole width, the taps (2048, 3)
     ('short_conv_fwd_bwd', _short_conv,
      [((1, 8192, 6144), BF16), ((2048, 3), BF16), ((1, 8192, 2048), BF16)]),
+    # the same two bodies with their gates off, Olmo-Hybrid-7B's convolution
+    # over [q | k | v]: 4096 rows of 11520 channels, 4 taps
+    ('short_conv_fwd_bwd_ungated', _silu_conv,
+     [((1, 4096, 11520), BF16), ((11520, 4), BF16),
+      ((1, 4096, 11520), BF16)]),
     # SDAR-30B-A3B-Chat's attention under the block-diffusion mask: 32
     # heads on 4 key/value heads, two halves of 4096 rows in blocks of 4
     # positions, walked in two runs of kernel blocks; the one backward
@@ -278,6 +288,26 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, specs, kernels):
     compiled = _compile(fn, one_chip, *specs, kernels=kernels)
     mem = compiled.memory_analysis()
     assert mem is not None and mem.temp_size_in_bytes >= 0
+
+
+def test_delta_rule_kernels_compile_for_v5e(one_chip):
+    """Olmo-Hybrid-7B's linear-attention layer: 30 heads of 96 key and 192
+    value columns (neither a multiple of the 128 lanes: the operands are by
+    head, [B, H, T, D]), one 4096-row sequence in chunks of 64, the state
+    [96, 192] float32 of two heads in VMEM across a sequence's grid steps;
+    forward and, the chunks walked from the end, backward, with XLA's part
+    of a chunk (the solve among it) beside them."""
+    def fwd_bwd(q, k, v, g, beta, do):
+        (o, smax), vjp = jax.vjp(pk.delta_rule, q, k, v, g, beta)
+        return o, smax, vjp((do, jnp.zeros_like(smax)))
+
+    B, H, L, dk, dv = 1, 30, 4096, 96, 192
+    compiled = _compile(
+        fwd_bwd, one_chip, ((B, H, L, dk), F32), ((B, H, L, dk), F32),
+        ((B, H, L, dv), BF16), ((B, H, L), F32), ((B, H, L), F32),
+        ((B, H, L, dv), BF16))
+    assert set(re.findall(r'delta_rule_(?:fwd|bwd)\b', compiled.as_text())) \
+        == {'delta_rule_fwd', 'delta_rule_bwd'}
 
 
 def test_registry_ops_take_the_kernel_when_lowered_for_tpu(one_chip):
