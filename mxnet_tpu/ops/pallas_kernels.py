@@ -2426,10 +2426,17 @@ silu_conv.defvjp(_silu_conv_fwd, _silu_conv_bwd)
 #   W = T (K * exp(gamma));  U = T V;  U' = U - W S_0
 #   O = (Q * exp(gamma)) S_0 + lower((Q K^T) * Gamma) U'
 #   S_C = exp(gamma_C) S_0 + (K * exp(gamma_C - gamma))^T U'.
-# What does not depend on S_0 (A, the solve, W, U, the masked Q K^T) is the
-# same work for every chunk and is left to XLA, batched over chunks, under
-# autodiff (:func:`_delta_chunks`): the solve and every exp in float32,
-# Gamma from the difference. What does depend on it is the chain of chunks:
+# What does not depend on S_0 is the same work for every chunk, and three
+# kernels whose grid is parallel over chunks do it, a chunk of up to ten
+# heads in VMEM a grid step: ``delta_rule_solve`` makes A and the inverse
+# X = (I + A)^-1 (Gamma from the difference of gamma, every exp and the
+# solve's arithmetic in float32), ``delta_rule_chunk_fwd`` makes from X
+# the chain's six operands (the products take the operands' dtype), and
+# ``delta_rule_chunk_bwd`` takes their cotangents back to q, k, v, gamma
+# and beta, the inverse's own (-X^T dX X^T) among them. Only gamma, the
+# running sum of g, is XLA's. X is named dear: a mirrored stage solves
+# once a step and makes W, U and P again from the X it kept. What does
+# depend on S_0 is the chain of chunks:
 # the two kernels walk it, forward and from the end, with the state (or its
 # cotangent) of `heads` heads in VMEM across the grid steps of one
 # sequence. Their operands are by head, [B, H, T, D], because neither 96
@@ -2500,22 +2507,22 @@ def _delta_bwd_kernel(qg_ref, kd_ref, w_ref, u_ref, p_ref, decay_ref, s0_ref,
             - dot(w_ref[j], du1d, ((0,), (0,)))
 
 
-def _delta_specs(C, dk, dv, heads, at):
-    """Block specs of the scan's arrays for `heads` heads a grid step, the
-    chunk taken being ``at(c)``: (rows of width D, a chunk's own
-    [.., 1, dv] row, a chunk's state)."""
+def _delta_specs(C, heads, at):
+    """Block specs of the delta rule's arrays for `heads` heads a grid
+    step, the chunk taken being ``at(c)``: (C rows of width D of
+    [B, H, T, D], a chunk's own [r, c] of [B, H, n, r, c])."""
     def rows(D):
         return pl.BlockSpec((None, heads, C, D),
                             lambda b, h, c: (b, h, at(c), 0))
-    row = pl.BlockSpec((None, heads, None, 1, dv),
-                       lambda b, h, c: (b, h, at(c), 0, 0))
-    state = pl.BlockSpec((None, heads, None, dk, dv),
-                         lambda b, h, c: (b, h, at(c), 0, 0))
-    return rows, row, state
+
+    def tile(r, c):
+        return pl.BlockSpec((None, heads, None, r, c),
+                            lambda b, h, c_: (b, h, at(c_), 0, 0))
+    return rows, tile
 
 
-def _delta_heads(H):
-    return next(h for h in (_DELTA_HEADS, 1) if H % h == 0)
+def _delta_heads(H, most=_DELTA_HEADS):
+    return next(h for h in range(most, 0, -1) if H % h == 0)
 
 
 def delta_scan_forward(qg, kd, w, u, p, decay, name='delta_rule'):
@@ -2528,7 +2535,8 @@ def delta_scan_forward(qg, kd, w, u, p, decay, name='delta_rule'):
     B, H, T, dk = qg.shape
     dv, C = u.shape[3], p.shape[3]
     n, heads = T // C, _delta_heads(H)
-    rows, row, state = _delta_specs(C, dk, dv, heads, lambda c: c)
+    rows, tile = _delta_specs(C, heads, lambda c: c)
+    row, state = tile(1, dv), tile(dk, dv)
     last = pl.BlockSpec((None, heads, 1, dv), lambda b, h, c: (b, h, 0, 0))
     return run_kernel(lambda interpret: pl.pallas_call(
         functools.partial(_delta_fwd_kernel, heads=heads),
@@ -2551,7 +2559,8 @@ def delta_scan_backward(qg, kd, w, u, p, decay, states, do,
     B, H, T, dk = qg.shape
     dv, C = u.shape[3], p.shape[3]
     n, heads = T // C, _delta_heads(H)
-    rows, row, state = _delta_specs(C, dk, dv, heads, lambda c: n - 1 - c)
+    rows, tile = _delta_specs(C, heads, lambda c: n - 1 - c)
+    row, state = tile(1, dv), tile(dk, dv)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)     # noqa: E731
     return run_kernel(lambda interpret: pl.pallas_call(
         functools.partial(_delta_bwd_kernel, heads=heads),
@@ -2589,78 +2598,248 @@ def _delta_scan_bwd(name, res, g):
 delta_scan.defvjp(_delta_scan_fwd, _delta_scan_bwd)
 
 
-_mm32 = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+# rows of the diagonal blocks that the solve clears by forward substitution
+# on the VPU; from there up it merges blocks by pairs on the MXU, two
+# six-pass products a level that wait for one another (a layer's solves at
+# olmo_hybrid_fit_4k: 2.03 ms from 8 rows, 1.68 from 16, 1.42 from 32)
+_DELTA_SOLVE_ROWS = 32
+# heads a grid step of the kernels that carry nothing from chunk to chunk
+# (at most: a divisor of H), where the chain takes _DELTA_HEADS: their
+# steps cost as much to start as to run (chunk_fwd 0.79 ms a layer at 2
+# heads a step, 0.53 at 6, 0.51 at 10)
+_DELTA_CHUNK_HEADS = 10
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
 
-@jax.custom_vjp
-def _unit_lower_inverse(a):
-    """(I + a)^-1 for a [..., C, C] strictly lower triangular, C a power
-    of two, float32: the inverses of the diagonal blocks of 1, 2, 4, ..
-    rows, each from the two of half its size (``[[X1, 0], [-X2 A21 X1,
-    X2]]``), which is forward substitution by blocks and as stable. Its
-    cotangent is ``-X^T g X^T``, two products, where autodiff would walk
-    the blocks back."""
-    C = a.shape[-1]
-    i = jnp.arange(C)
-    x = jnp.broadcast_to(jnp.eye(C, dtype=jnp.float32), a.shape)
-    size = 1
-    while size < C:
-        lower_left = ((i[:, None] // (2 * size) == i[None, :] // (2 * size))
-                      & (i[:, None] // size % 2 == 1)
-                      & (i[None, :] // size % 2 == 0))
-        x = x - _mm32(_mm32(x, jnp.where(lower_left, a, 0.0)), x)
-        size *= 2
+def _grid_of(C):
+    """Row and column numbers of a [C, C] block."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (C, C), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+def _as_col(row, eye):
+    """A [1, C] row as a [C, 1] column."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _as_row(col, eye):
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _decay_between(seen, col, gamma):
+    """Gamma: exp(gamma_i - gamma_j) where `seen` and 0 elsewhere, from the
+    difference (exp(gamma_i) exp(-gamma_j) would be inf times 0 at a fast
+    decay); `col` is the row `gamma` as a column."""
+    return jnp.exp(jnp.where(seen, col - gamma, -jnp.inf))
+
+
+def _unit_lower_inverse(a, i, j):
+    """(I + a)^-1 of a [C, C] strictly lower triangular float32 block, C a
+    power of two, i and j its row and column numbers. The diagonal blocks
+    of _DELTA_SOLVE_ROWS rows by forward substitution, all at once and a
+    column a step (row s of a block is final when its column s is
+    cleared; a strictly lower `a` leaves the rows above it alone); then
+    the blocks of twice the rows from the two of half their size
+    (``[[X1, 0], [-X2 A21 X1, X2]]``), which is forward substitution by
+    blocks and as stable."""
+    C = a.shape[0]
+    rows = min(_DELTA_SOLVE_ROWS, C)
+    x = [(i == j).astype(jnp.float32)[r:r + 8] for r in range(0, C, 8)]
+    for s in range(rows - 1):
+        for b in range(0, C, rows):
+            at = b + s
+            row = x[at // 8][at % 8:at % 8 + 1]
+            for t in range((at + 1) // 8, (b + rows) // 8):
+                x[t] = x[t] - a[8 * t:8 * t + 8, at:at + 1] * row
+    x = jnp.concatenate(x, axis=0)
+    while rows < C:
+        lower_left = (((i ^ j) < 2 * rows) & ((i & rows) != 0)
+                      & ((j & rows) == 0))
+        x = x - _dot32(_dot32(x, jnp.where(lower_left, a, 0.0), _NN), x, _NN)
+        rows *= 2
     return x
 
 
-def _unit_lower_inverse_fwd(a):
-    x = _unit_lower_inverse(a)
-    return x, x
+def _each_head(heads, body):
+    """``body(h)`` for each of a grid step's heads: a loop over pairs, so
+    that the scheduler has one head's products to issue while the other's
+    drain and the body is written out twice, not `heads` times (ten heads
+    unrolled took 20 s more to trace and lower than the two they are)."""
+    pair = 2 - heads % 2
+
+    def pairs(at, _):
+        for h in range(pair):
+            body(pair * at + h)
+
+    jax.lax.fori_loop(0, heads // pair, pairs, None)
 
 
-def _unit_lower_inverse_bwd(x, g):
-    xt = jnp.swapaxes(x, -1, -2)
-    return (-_mm32(_mm32(xt, g), xt),)
+def _delta_solve_kernel(k_ref, gamma_ref, beta_ref, x_ref, *, heads, dtype):
+    i, j = _grid_of(x_ref.shape[-1])
+
+    def head(h):
+        k = k_ref[h].astype(dtype)
+        gamma = gamma_ref[h]
+        a = _as_col(beta_ref[h], i == j) * _delta_dot(dtype)(k, k, _NT) \
+            * _decay_between(i > j, _as_col(gamma, i == j), gamma)
+        x_ref[h] = _unit_lower_inverse(a, i, j)
+
+    _each_head(heads, head)
 
 
-_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+def _delta_chunk_fwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, x_ref,
+                            qg_ref, kd_ref, w_ref, u_ref, p_ref, decay_ref,
+                            *, heads):
+    C, dtype = x_ref.shape[-1], v_ref.dtype
+    dot = _delta_dot(dtype)
+    i, j = _grid_of(C)
+
+    def head(h):
+        q, k, gamma = q_ref[h], k_ref[h], gamma_ref[h]
+        col, last = _as_col(gamma, i == j), gamma[:, C - 1:]
+        e = jnp.exp(col)
+        t = (x_ref[h] * beta_ref[h]).astype(dtype)
+        w_ref[h] = dot(t, (k * e).astype(dtype), _NN).astype(dtype)
+        u_ref[h] = dot(t, v_ref[h], _NN).astype(dtype)
+        p_ref[h] = (dot(q.astype(dtype), k.astype(dtype), _NT)
+                    * _decay_between(i >= j, col, gamma)).astype(dtype)
+        qg_ref[h] = (q * e).astype(dtype)
+        kd_ref[h] = (k * jnp.exp(last - col)).astype(dtype)
+        decay_ref[h] = jnp.broadcast_to(jnp.exp(last), decay_ref.shape[1:])
+
+    _each_head(heads, head)
 
 
-def _delta_chunks(q, k, v, g, beta, C):
-    """The operands of :func:`delta_scan` from the op's, by head and in
-    float32 but for the last cast: q and k [B, H, T, dk] as the scan takes
-    them (normalised, q scaled), v [B, H, T, dv], g and beta [B, H, T], T
-    whole chunks of C."""
-    B, H, T, dk = q.shape
-    n, dtype = T // C, v.dtype
-    hi = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+def _delta_chunk_bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, x_ref,
+                            dqg_ref, dkd_ref, dw_ref, du_ref, dp_ref,
+                            ddecay_ref, dq_ref, dk_ref, dv_ref, dgamma_ref,
+                            dbeta_ref, *, heads):
+    C, dtype = x_ref.shape[-1], v_ref.dtype
+    dot = _delta_dot(dtype)
+    i, j = _grid_of(C)
+    eye = i == j
+    at_last = j[:1] == C - 1
 
-    def mm(spec, a, b):
-        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
-                          precision=hi, preferred_element_type=jnp.float32)
+    def head(h):
+        q, k, gamma, beta, x = (q_ref[h], k_ref[h], gamma_ref[h],
+                                beta_ref[h], x_ref[h])
+        col, last = _as_col(gamma, eye), gamma[:, C - 1:]
+        e, f = jnp.exp(col), jnp.exp(last - col)
+        qc, kc, t = q.astype(dtype), k.astype(dtype), \
+            (x * beta).astype(dtype)
+        below = _decay_between(i > j, col, gamma)
+        kk, qk = dot(kc, kc, _NT), dot(qc, kc, _NT)
+        dw, du = dw_ref[h], du_ref[h]
+        dqg, dkd, dp = (r[h].astype(jnp.float32)
+                        for r in (dqg_ref, dkd_ref, dp_ref))
+        # P = Q K^T * Gamma (1 on the diagonal); W = T (K * e); U = T V
+        dqk = dp * jnp.where(eye, 1.0, below)
+        dke = dot(t, dw, _TN)
+        dv_ref[h] = dot(t, du, _TN).astype(dv_ref.dtype)
+        dt = dot(dw, (k * e).astype(dtype), _NT) + dot(du, v_ref[h], _NT)
+        # T = X diag(beta), and the inverse's own cotangent, -X^T dX X^T,
+        # of which A takes what lies below the diagonal
+        da = -_dot32(_dot32(x, dt * beta, _TN), x, _NT) * below
+        dkk = da * _as_col(beta, eye)
+        dqk_d, dkk_d = dqk.astype(dtype), dkk.astype(dtype)
+        dq_ref[h] = dqg * e + dot(dqk_d, kc, _NN)
+        dk_ref[h] = dke * e + dkd * f + dot(dqk_d, qc, _TN) \
+            + dot(dkk_d, kc, _NN) + dot(dkk_d, kc, _TN)
+        dbeta_ref[h] = jnp.sum(dt * x, axis=0, keepdims=True) \
+            + _as_row(jnp.sum(da * kk, axis=1, keepdims=True), eye)
+        # gamma: in e, in the decay to the chunk's end, and row less column
+        # in both Gammas (A's and P's cotangents times A and P themselves)
+        to_end = jnp.sum(dkd * k, axis=1, keepdims=True) * f
+        d_diff = dkk * kk + dqk * qk
+        d_col = jnp.sum(dqg * q + dke * k, axis=1, keepdims=True) * e \
+            - to_end + jnp.sum(d_diff, axis=1, keepdims=True)
+        d_last = jnp.sum(to_end, axis=0, keepdims=True) + jnp.exp(last) \
+            * jnp.sum(ddecay_ref[h], axis=1, keepdims=True)
+        dgamma_ref[h] = _as_row(d_col, eye) + jnp.where(at_last, d_last, 0.0) \
+            - jnp.sum(d_diff, axis=0, keepdims=True)
 
-    q, k, v = (x.reshape(B, H, n, C, -1) for x in (q, k, v))
-    beta = beta.reshape(B, H, n, C)
-    gamma = jnp.cumsum(g.reshape(B, H, n, C), axis=-1)
-    i = jnp.arange(C)
-    lower = i[:, None] >= i[None, :]
-    # exp of the difference, and of nothing above the diagonal
-    Gamma = jnp.exp(jnp.where(lower, gamma[..., :, None]
-                              - gamma[..., None, :], -jnp.inf))
-    a = jnp.where(i[:, None] > i[None, :],
-                  beta[..., :, None] * mm('bhnid,bhnjd->bhnij', k, k) * Gamma,
-                  0.0)
-    t = _unit_lower_inverse(a) * beta[..., None, :]
-    e = jnp.exp(gamma)[..., None]
-    w = mm('bhnij,bhnjd->bhnid', t, k * e)
-    u = mm('bhnij,bhnjd->bhnid', t, v)
-    p = mm('bhnid,bhnjd->bhnij', q, k) * Gamma
-    last = gamma[..., -1:]
-    kd = k * jnp.exp(last - gamma)[..., None]
-    decay = jnp.broadcast_to(jnp.exp(last)[..., None],
-                             (B, H, n, 1, v.shape[-1]))
-    flat = lambda x: x.astype(dtype).reshape(B, H, T, -1)       # noqa: E731
-    return flat(q * e), flat(kd), flat(w), flat(u), flat(p), decay
+    _each_head(heads, head)
+
+
+def _delta_chunk_call(kernel, arrays, outs, name):
+    """The results (a tuple) of `kernel` over the grid of chunks, `heads`
+    heads a step: each array and each of `outs` (ShapeDtypeStruct) is by
+    row, [B, H, T, D], or by chunk, [B, H, n, r, c]."""
+    B, H, n = next(x.shape[:3] for x in arrays if x.ndim == 5)
+    C = arrays[0].shape[2] // n
+    heads = _delta_heads(H, _DELTA_CHUNK_HEADS)
+    rows, tile = _delta_specs(C, heads, lambda c: c)
+    specs = lambda xs: [rows(x.shape[3]) if x.ndim == 4         # noqa: E731
+                        else tile(*x.shape[3:]) for x in xs]
+    return tuple(run_kernel(lambda interpret: pl.pallas_call(
+        functools.partial(kernel, heads=heads), grid=(B, H // heads, n),
+        in_specs=specs(arrays), out_specs=specs(outs), out_shape=outs,
+        compiler_params=_attn_params(3, 0), interpret=interpret, name=name),
+        *arrays))
+
+
+def delta_solve(k, gamma, beta, dtype, name='delta_rule'):
+    """X = (I + A)^-1 [B, H, n, C, C] float32 of every chunk, A =
+    strict_lower(diag(beta) (K K^T * Gamma)): k [B, H, T, dk] float32,
+    gamma (the running sum of g inside a chunk) and beta [B, H, n, 1, C];
+    K K^T takes operands of `dtype`. ``<name>_solve``."""
+    B, H, n, _, C = gamma.shape
+    return _delta_chunk_call(
+        functools.partial(_delta_solve_kernel, dtype=dtype),
+        (k, gamma, beta),
+        [jax.ShapeDtypeStruct((B, H, n, C, C), jnp.float32)],
+        name + '_solve')[0]
+
+
+def delta_chunk_forward(q, k, v, gamma, beta, x, name='delta_rule'):
+    """:func:`delta_scan`'s six operands from the op's, by head (q and k
+    [B, H, T, dk] float32 as the scan takes them, normalised, q scaled; v
+    [B, H, T, dv]), and the chunks' inverses. ``<name>_chunk_fwd``."""
+    B, H, n, _, C = gamma.shape
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, v.dtype)     # noqa: E731
+    return _delta_chunk_call(
+        _delta_chunk_fwd_kernel, (q, k, v, gamma, beta, x),
+        [like(q), like(k), like(k), like(v),
+         jax.ShapeDtypeStruct(q.shape[:3] + (C,), v.dtype),
+         jax.ShapeDtypeStruct((B, H, n, 1, v.shape[3]), jnp.float32)],
+        name + '_chunk_fwd')
+
+
+def delta_chunk_backward(q, k, v, gamma, beta, x, dqg, dkd, dw, du, dp,
+                         ddecay, name='delta_rule'):
+    """The cotangents of q, k, v, gamma and beta from those of
+    :func:`delta_chunk_forward`'s six results, through the inverse too.
+    ``<name>_chunk_bwd``."""
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)     # noqa: E731
+    return _delta_chunk_call(
+        _delta_chunk_bwd_kernel,
+        (q, k, v, gamma, beta, x, dqg, dkd, dw, du, dp, ddecay),
+        [like(q), like(k), like(v), like(gamma), like(beta)],
+        name + '_chunk_bwd')
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def delta_chunks(q, k, v, gamma, beta, name='delta_rule'):
+    """What a chunk needs that does not depend on the carried state: the
+    solve, then :func:`delta_chunk_forward`."""
+    x = delta_solve(k, gamma, beta, v.dtype, name)
+    return delta_chunk_forward(q, k, v, gamma, beta, x, name)
+
+
+def _delta_chunks_fwd(q, k, v, gamma, beta, name):
+    # 4 C^2 bytes a chunk behind the one sequential part of a chunk's
+    # work: a mirrored stage keeps the inverse, and its second forward is
+    # the products alone
+    x = dear(delta_solve(k, gamma, beta, v.dtype, name), name + '_inverse')
+    return delta_chunk_forward(q, k, v, gamma, beta, x, name), \
+        (q, k, v, gamma, beta, x)
+
+
+def _delta_chunks_bwd(name, res, g):
+    return delta_chunk_backward(*res, *g, name=name)
+
+
+delta_chunks.defvjp(_delta_chunks_fwd, _delta_chunks_bwd)
 
 
 def delta_rule(q, k, v, g, beta, chunk=DELTA_CHUNK, name='delta_rule'):
@@ -2675,8 +2854,9 @@ def delta_rule(q, k, v, g, beta, chunk=DELTA_CHUNK, name='delta_rule'):
     if pad:
         q, k, v = (jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for x in (q, k, v))
-        g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad))) for x in (g, beta))
     with jax.named_scope('chunks'):
-        operands = _delta_chunks(q, k, v, g, beta, chunk)
+        operands = delta_chunks(
+            q, k, v, jnp.cumsum(_rows(g, pad, chunk), axis=-1),
+            _rows(beta, pad, chunk), name)
     o, smax = delta_scan(*operands, name)
     return o[:, :, :T], jnp.max(smax, axis=(1, 2, 3))

@@ -195,7 +195,7 @@ if _COVERING:
 # -- values dear to recompute -------------------------------------------------
 # A mirrored stage recomputes in the backward pass everything but what an
 # op has named here: a value that its backward pass reads anyway and that
-# costs more to make again than to hold. Three rules name values, each
+# costs more to make again than to hold. Four rules name values, each
 # from shapes and the op's own structure alone:
 #   - an attention op names its kernel's output and log-sum-exp, and the
 #     operands that the backward kernel reads: query, key and value, the
@@ -206,13 +206,13 @@ if _COVERING:
 #     output features than input features): the value is no larger than
 #     the one it was made from, behind a product as deep as it is wide;
 #   - ``MoE`` names its routing and its plan: a few small vectors behind
-#     a top-k, a gather and a sort's worth of scans and scatters.
+#     a top-k, a gather and a sort's worth of scans and scatters;
+#   - ``GatedDeltaRule`` names what is sequential in it: the chain of
+#     chunks' output and states, and each chunk's inverse (4 C^2 bytes
+#     behind a solve; W, U and P are five products away from it).
 # An MLP's hidden activations stay recomputed: large, and one product deep.
-# So does a gated short convolution with the projection that feeds it: the
-# projection expands (its output, the op's operand, is three times what it
-# was made from), and the op behind it is one pass over those bytes; the
-# projection that reads the op's result keeps its own output by the second
-# rule (as wide as its input), so `GatedShortConv` names nothing.
+# So does `GatedShortConv` with the projection that feeds it: one pass over
+# three times that projection's input (the next keeps its own by rule two).
 
 _DEAR = set()                   # every name `dear` was given
 _MIRROR = threading.local()     # .kept: the list of the stage being traced

@@ -609,14 +609,14 @@ def _gated_delta_rule(attrs, q, k, v, g, beta, stats):
     kernels) and the largest magnitude of a state after the last row (with beta up to 2 a step's eigenvalue can be -1).
 
     On a TPU the chunked form runs it (``ops/pallas_kernels.py``): what a
-    chunk of 64 rows needs that does not depend on the state (the
-    triangular solve among it) is XLA's, batched over chunks, and the
-    chain of chunks is the kernels' ``delta_rule_fwd`` and
-    ``delta_rule_bwd``, which carry the state, or its cotangent, along the
-    sequence. A mirrored stage keeps the chain's output and the states at
-    the chunks' starts, so the chain runs once a step and direction; the
-    rest is made again from the operands. Elsewhere: the recurrence itself
-    under ``lax.scan``."""
+    chunk of 64 rows needs that does not depend on the state is three
+    kernels over all chunks at once (``delta_rule_solve``, the triangular
+    solve in float32, ``delta_rule_chunk_fwd`` and ``_chunk_bwd``), and the
+    chain of chunks is ``delta_rule_fwd`` and ``delta_rule_bwd``, which
+    carry the state, or its cotangent, along the sequence. A mirrored stage
+    keeps the chain's output, the states at the chunks' starts and the
+    chunks' inverses: chain and solve run once a step and direction, the
+    rest is made again. Elsewhere: the recurrence under ``lax.scan``."""
     H = int(attrs['num_heads'])
     B, T, _ = q.shape
     dk, dv = q.shape[2] // H, v.shape[2] // H

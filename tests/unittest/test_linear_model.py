@@ -20,7 +20,21 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.config import flags
+from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.transformer import DELTA_STATS
+
+
+@pytest.fixture(autouse=True)
+def small_kernel_bodies(monkeypatch):
+    """The interpreter compiles a kernel's body op by op, and the chunk
+    kernels' bodies are unrolled over heads and over the substitution's
+    steps: one head a grid step and blocks of 16 rows here keep a model's
+    trace at the time it took when XLA had a chunk's state-free part.
+    tests/unittest/test_linear_ops.py holds the kernels at the program's
+    own numbers."""
+    monkeypatch.setattr(pk, '_DELTA_CHUNK_HEADS', 1)
+    monkeypatch.setattr(pk, '_DELTA_SOLVE_ROWS', 16)
+
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -226,7 +240,6 @@ TWO = dict(CFG, num_hidden_layers=2,
 def case():
     """(parameters, tokens, labels, the reference's gradient) at T = 2
     chunks and a part."""
-    from mxnet_tpu.ops import pallas_kernels as pk
     length = 2 * pk.DELTA_CHUNK + 16
     p = _model(TWO, seed=2)
     rng = np.random.RandomState(2)
@@ -245,7 +258,6 @@ def test_a_program_that_got_the_mechanism_wrong_fails(wrong, case, path,
     or puts the norm before the sub-layer stands outside the distance's
     limit of ``drivers/fit_tokens_linear.py``, twice over."""
     from benchmark.drivers import fit_tokens_linear
-    from mxnet_tpu.ops import pallas_kernels as pk
     limit = fit_tokens_linear.LIMITS['grad_distance']
     p, tok, lab, want = case
     cfg = dict(TWO)

@@ -11,11 +11,14 @@ its Pallas kernels, interpreted):
 - two derivations of the same thing: the kernels' state at the second
   chunk's start is the reference's state after the first chunk's last row,
   handed on by hand;
-- the inverse by blocks against a solve;
+- the kernels of a chunk's state-free part (the solve, the chain's six
+  operands, the five cotangents) against the same algebra in plain jnp
+  under autodiff, and the inverse made inside the kernel against a solve;
 - ``ShortConv`` against the reference's shifted sums, on both paths (the
   kernels are ``GatedShortConv``'s with their gates off);
 - what a mirrored linear-attention block keeps (the chain of chunks' output
-  and states) and that kept or made again they give the same gradients.
+  and states, and the chunks' inverses) and that kept or made again they
+  give the same gradients.
 """
 import importlib.util
 import os
@@ -69,6 +72,16 @@ C = pk.DELTA_CHUNK
 
 # -- the gated delta rule --------------------------------------------------------------------
 
+@pytest.fixture
+def small_kernel_bodies(monkeypatch):
+    """The interpreter compiles a kernel's body op by op, and the chunk
+    kernels' bodies are unrolled over heads and over the substitution's
+    steps: one head a grid step and blocks of 16 rows for the tests that
+    are not about those bodies."""
+    monkeypatch.setattr(pk, '_DELTA_CHUNK_HEADS', 1)
+    monkeypatch.setattr(pk, '_DELTA_SOLVE_ROWS', 16)
+
+
 def _operands(B, length, H, decay, beta_at, seed=0):
     """q, k, v [B, T, H * D], g and beta [B, T, H]: the decay a row about
     `decay`, beta about `beta_at`."""
@@ -111,6 +124,7 @@ RULE_CASES = {
     'short_slow': (40, 2, 0.999, 0.5)}          # less than a chunk
 
 
+@pytest.mark.usefixtures('small_kernel_bodies')
 @pytest.mark.parametrize('case,path', [
     ('one', 'kernel'), ('one', 'plain'), ('two_beta2', 'kernel'),
     ('ragged_fast', 'kernel'), ('ragged_fast', 'plain'),
@@ -121,6 +135,7 @@ def test_gated_delta_rule(case, path):
     _both(_rule(heads), _reference_rule(heads), *args)
 
 
+@pytest.mark.usefixtures('small_kernel_bodies')
 def test_gated_delta_rule_writes_its_statistics(monkeypatch):
     """rows scanned, and the largest magnitude of a state after the last
     row, on both paths."""
@@ -136,6 +151,72 @@ def test_gated_delta_rule_writes_its_statistics(monkeypatch):
         np.testing.assert_allclose(float(stats[1]), want, rtol=1e-5)
 
 
+def _by_head(H, q, k, v, g, beta):
+    """pk.delta_chunks' operands from the op's, whole chunks (the last
+    padded as pk.delta_rule pads it): q, k, v [B, H, T, D], gamma and
+    beta [B, H, n, 1, C]."""
+    B, length = g.shape[:2]
+    pad = -length % C
+    heads = lambda x, D: jnp.pad(                               # noqa: E731
+        x.reshape(B, length, H, D).transpose(0, 2, 1, 3),
+        ((0, 0), (0, 0), (0, pad), (0, 0)))
+    by_chunk = lambda x: jnp.pad(                               # noqa: E731
+        x.transpose(0, 2, 1), ((0, 0), (0, 0), (0, pad))).reshape(
+            B, H, -1, 1, C)
+    return (ref.unit(heads(q, dk), dk ** -0.5), ref.unit(heads(k, dk)),
+            heads(v, dv), jnp.cumsum(by_chunk(g), axis=-1), by_chunk(beta))
+
+
+def _chunks_plain(q, k, v, gamma, beta):
+    """pk.delta_chunks in plain jnp with a solve for the inverse: (A, X,
+    the chain's six operands)."""
+    B, H, rows, _ = q.shape
+    mm = lambda spec, a, b: jnp.einsum(spec, a, b,              # noqa: E731
+                                       precision='highest')
+    q, k, v = (x.reshape(B, H, rows // C, C, -1) for x in (q, k, v))
+    gamma, beta = gamma[..., 0, :], beta[..., 0, :]
+    i = jnp.arange(C)
+    Gamma = jnp.exp(jnp.where(i[:, None] >= i[None, :], gamma[..., :, None]
+                              - gamma[..., None, :], -jnp.inf))
+    a = jnp.where(i[:, None] > i[None, :], beta[..., :, None]
+                  * mm('bhnid,bhnjd->bhnij', k, k) * Gamma, 0.0)
+    x = jnp.linalg.inv(jnp.eye(C) + a)
+    t, e = x * beta[..., None, :], jnp.exp(gamma)[..., None]
+    last = gamma[..., -1:]
+    flat = lambda x: x.reshape(B, H, rows, -1)                  # noqa: E731
+    return a, x, (
+        flat(q * e), flat(k * jnp.exp(last - gamma)[..., None]),
+        flat(mm('bhnij,bhnjd->bhnid', t, k * e)),
+        flat(mm('bhnij,bhnjd->bhnid', t, v)),
+        flat(mm('bhnid,bhnjd->bhnij', q, k) * Gamma),
+        jnp.broadcast_to(jnp.exp(last)[..., None],
+                         last.shape[:3] + (1, v.shape[-1])))
+
+
+# (few heads: a grid step takes all of them, and its body is unrolled)
+CHUNK_CASES = {'two_beta2': (2 * C, 2, 0.95, 1.9),  # two chunks, beta near 2
+               'ragged_fast': (2 * C - 10, 1, 0.5, 1.0)}    # a padded chunk
+
+
+@pytest.mark.parametrize('case', sorted(CHUNK_CASES))
+def test_the_kernels_of_a_chunk_are_its_algebra(case):
+    """``delta_rule_solve``'s inverse and ``delta_rule_chunk_fwd``'s six
+    results against the jnp form, and ``delta_rule_chunk_bwd``'s five
+    cotangents (the inverse's own inside it) against autodiff of that
+    form."""
+    length, heads, decay, beta_at = CHUNK_CASES[case]
+    args = _by_head(heads, *_operands(1, length, heads, decay, beta_at))
+    q, k, v, gamma, beta = args
+    _close(pk.delta_solve(k, gamma, beta, jnp.float32),
+           _chunks_plain(*args)[1], tol=1e-4)
+    got, vjp = jax.vjp(pk.delta_chunks, *args)
+    want, want_vjp = jax.vjp(lambda *a: _chunks_plain(*a)[2], *args)
+    cot = tuple(_rand(90 + at, *o.shape) for at, o in enumerate(want))
+    for a, b in zip(got + vjp(cot), want + want_vjp(cot)):
+        _close(a, b, tol=1e-4)
+
+
+@pytest.mark.usefixtures('small_kernel_bodies')
 def test_the_state_at_a_chunks_start_is_the_recurrences():
     """Two derivations: the forward kernel's state at the second chunk's
     start is the reference's state after row C - 1 of the recurrence, and
@@ -143,11 +224,7 @@ def test_the_state_at_a_chunks_start_is_the_recurrences():
     done here by hand."""
     H = 2
     q, k, v, g, beta = _operands(1, 2 * C, H, 0.97, 1.6, seed=3)
-    heads = lambda x, D: x.reshape(1, 2 * C, H, D).transpose(0, 2, 1, 3)  # noqa
-    qn = ref.unit(heads(q, dk), dk ** -0.5)
-    kn = ref.unit(heads(k, dk))
-    gh, bh = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)
-    operands = pk._delta_chunks(qn, kn, heads(v, dv), gh, bh, C)
+    operands = pk.delta_chunks(*_by_head(H, q, k, v, g, beta))
     o, states, smax = pk.delta_scan_forward(*operands)
     want_o, want_s = _reference_rule(H, True)(q[0], k[0], v[0], g[0],
                                               beta[0])
@@ -167,12 +244,25 @@ def test_the_state_at_a_chunks_start_is_the_recurrences():
     _close(decay * S + jnp.einsum('hik,hiv->hkv', kd, u1), want_s[-1])
 
 
-def test_the_inverse_by_blocks_is_a_solve():
-    a = jnp.tril(_rand(5, 3, C, C, scale=0.5), -1)
-    want = np.linalg.inv(np.eye(C) + np.asarray(a, np.float64))
-    _close(pk._unit_lower_inverse(a), want.astype(np.float32), tol=1e-4)
+@pytest.mark.parametrize('rows', [8, pk._DELTA_SOLVE_ROWS, C])
+def test_the_inverse_in_the_kernel_is_a_solve(rows, monkeypatch):
+    """With beta near 2 and keys close to one another A's entries are near
+    2: ``delta_rule_solve``'s X against float64's inverse of I + A, with
+    the diagonal blocks that forward substitution clears at the length
+    the program has, at a shorter one (three merges by pairs) and at the
+    whole chunk (no merge)."""
+    monkeypatch.setattr(pk, '_DELTA_SOLVE_ROWS', rows)
+    H = 1
+    q, k, v, g, beta = _operands(1, C, H, 0.98, 1.95, seed=5)
+    k = k.reshape(1, C, H, dk)[:, :1] + 0.3 * k.reshape(1, C, H, dk)
+    _, k, _, gamma, beta = _by_head(H, q, k.reshape(1, C, H * dk), v, g, beta)
+    a = np.asarray(_chunks_plain(k, k, k, gamma, beta)[0], np.float64)
+    assert np.abs(a).max() > 1.5
+    _close(pk.delta_solve(k, gamma, beta, jnp.float32),
+           np.linalg.inv(np.eye(C) + a).astype(np.float32), tol=1e-4)
 
 
+@pytest.mark.usefixtures('small_kernel_bodies')
 @pytest.mark.parametrize('path', ['kernel'], indirect=True)
 def test_a_fast_decay_does_not_overflow(path):
     """Gamma is formed from the difference: with 40 a row in the exponent
@@ -214,18 +304,19 @@ def _linear_block():
 
 
 def _kernels(text):
-    return {k: text.count('name=delta_rule_%s' % k) for k in ('fwd', 'bwd')}
+    return {k: text.count('name=delta_rule_%s' % k)
+            for k in ('fwd', 'bwd', 'solve', 'chunk_fwd', 'chunk_bwd')}
 
 
+@pytest.mark.usefixtures('small_kernel_bodies')
 @pytest.mark.parametrize('path', ['kernel'], indirect=True)
 def test_the_chain_of_chunks_runs_once_a_direction(path, monkeypatch):
     """The stage keeps the chain's output and the states at the chunks'
     starts (``delta_rule_out``, ``delta_rule_states``): the forward kernel
     is in the step as often as the backward one; under a bare checkpoint
-    it runs again in the
-    backward pass, and the gradients are the same (to rounding: XLA fuses
-    the two programs differently): the states kept are the states made
-    again."""
+    it, and the solve, run again in the backward pass, and the gradients
+    are the same (to rounding: XLA fuses the two programs differently):
+    what was kept is what is made again."""
     step, wrt = _training_step(_linear_block(), **LM_IN)
     text = str(jax.make_jaxpr(step)(wrt))
     # (each call is in the text twice: compiled for a TPU, interpreted here)
@@ -235,10 +326,25 @@ def test_the_chain_of_chunks_runs_once_a_direction(path, monkeypatch):
     outs, grads = jax.jit(step)(wrt)
     cases._bare_checkpoint(monkeypatch)
     step, wrt = _training_step(_linear_block(), **LM_IN)
-    assert _kernels(str(jax.make_jaxpr(step)(wrt))) \
-        == {'fwd': 2 * kept['fwd'], 'bwd': kept['bwd']}
+    assert _kernels(str(jax.make_jaxpr(step)(wrt))) == dict(
+        kept, fwd=2 * kept['fwd'], solve=2 * kept['solve'])
     for a, b in zip(outs + grads, sum(jax.jit(step)(wrt), ())):
         _close(a, b, tol=1e-6)
+
+
+@pytest.mark.usefixtures('small_kernel_bodies')
+@pytest.mark.parametrize('path', ['kernel'], indirect=True)
+def test_the_solve_runs_once_a_direction(path):
+    """The stage keeps the chunks' inverses too (``delta_rule_inverse``):
+    ``delta_rule_solve`` is in the step as often as the backward kernels,
+    and the stage's second forward of a chunk's state-free part is
+    ``delta_rule_chunk_fwd`` alone."""
+    step, wrt = _training_step(_linear_block(), **LM_IN)
+    text = str(jax.make_jaxpr(step)(wrt))
+    kept = _kernels(text)
+    assert kept['solve'] == kept['chunk_bwd'] == kept['bwd'] > 0
+    assert kept['chunk_fwd'] == 2 * kept['solve']
+    assert 'name=delta_rule_inverse' in text
 
 
 def test_the_nodes_name_their_statistics():

@@ -293,21 +293,25 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, specs, kernels):
 def test_delta_rule_kernels_compile_for_v5e(one_chip):
     """Olmo-Hybrid-7B's linear-attention layer: 30 heads of 96 key and 192
     value columns (neither a multiple of the 128 lanes: the operands are by
-    head, [B, H, T, D]), one 4096-row sequence in chunks of 64, the state
-    [96, 192] float32 of two heads in VMEM across a sequence's grid steps;
-    forward and, the chunks walked from the end, backward, with XLA's part
-    of a chunk (the solve among it) beside them."""
+    head, [B, H, T, D]), one 4096-row sequence in chunks of 64. The chain:
+    the state [96, 192] float32 of two heads in VMEM across a sequence's
+    grid steps, forward and, the chunks walked from the end, backward.
+    Beside it the three kernels of a chunk's state-free part (the solve,
+    the chain's operands, their cotangents), which leave XLA no product
+    of a chunk's [64, 64] blocks (the parent's program had 18)."""
     def fwd_bwd(q, k, v, g, beta, do):
         (o, smax), vjp = jax.vjp(pk.delta_rule, q, k, v, g, beta)
         return o, smax, vjp((do, jnp.zeros_like(smax)))
 
     B, H, L, dk, dv = 1, 30, 4096, 96, 192
-    compiled = _compile(
+    text = _compile(
         fwd_bwd, one_chip, ((B, H, L, dk), F32), ((B, H, L, dk), F32),
         ((B, H, L, dv), BF16), ((B, H, L), F32), ((B, H, L), F32),
-        ((B, H, L, dv), BF16))
-    assert set(re.findall(r'delta_rule_(?:fwd|bwd)\b', compiled.as_text())) \
-        == {'delta_rule_fwd', 'delta_rule_bwd'}
+        ((B, H, L, dv), BF16)).as_text()
+    assert set(re.findall(r'delta_rule_((?:chunk_)?(?:solve|fwd|bwd))\b',
+                          text)) == {'solve', 'chunk_fwd', 'chunk_bwd',
+                                     'fwd', 'bwd'}
+    assert not re.findall(r'\[[\d,]*64,64\]\S* (?:convolution|dot)\(', text)
 
 
 def test_registry_ops_take_the_kernel_when_lowered_for_tpu(one_chip):
