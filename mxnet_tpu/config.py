@@ -129,7 +129,7 @@ flags.declare('MXTPU_BACKWARD_DO_MIRROR', str, '0',
               "all but the values an op named as dear to recompute "
               "(what an attention kernel's backward pass reads, a "
               "contracting FullyConnected's output, an expert layer's "
-              "routing and plan), "
+              "routing and plan, a gated MLP's two hidden products), "
               "'dots' = keep matmul results (checkpoint_dots policy), "
               "'0'/''/'false' = off (legacy spellings honored)",
               aliases=('MXNET_BACKWARD_DO_MIRROR',))

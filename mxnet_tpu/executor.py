@@ -130,9 +130,9 @@ class _GraphProgram:
         as dear to recompute (``ops.registry.dear``): what an attention
         kernel's backward pass reads (its output and log-sum-exp, query,
         key and value), the output of a ``FullyConnected`` that contracts,
-        and an expert layer's routing and plan. Everything else it
-        computes again: norms, expanding projections, the MLPs' hidden
-        activations. The gauges ``executor.mirror_kept`` and ``_bytes`` say
+        an expert layer's routing and plan, a gated MLP's two hidden
+        products. Everything else it computes again: norms, expanding
+        projections. The gauges ``executor.mirror_kept`` and ``_bytes`` say
         how many arrays the ops of the traced program named so, each once,
         and their size (one that no backward rule reads, a key's projection
         before its rotary turn, is counted and not held). A builder gives

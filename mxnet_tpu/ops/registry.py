@@ -193,10 +193,9 @@ if _COVERING:
 
 
 # -- values dear to recompute -------------------------------------------------
-# A mirrored stage recomputes in the backward pass everything but what an
-# op has named here: a value that its backward pass reads anyway and that
-# costs more to make again than to hold. Four rules name values, each
-# from shapes and the op's own structure alone:
+# A mirrored stage recomputes in the backward pass everything but what an op
+# has named here: a value that its backward pass reads anyway and that costs
+# more to make again than to hold. Five rules, from shapes and structure alone:
 #   - an attention op names its kernel's output and log-sum-exp, and the
 #     operands that the backward kernel reads: query, key and value, the
 #     projections, per-head splits and rotary turns behind them. (Latent
@@ -209,10 +208,11 @@ if _COVERING:
 #     a top-k, a gather and a sort's worth of scans and scatters;
 #   - ``GatedDeltaRule`` names what is sequential in it: the chain of
 #     chunks' output and states, and each chunk's inverse (4 C^2 bytes
-#     behind a solve; W, U and P are five products away from it).
-# An MLP's hidden activations stay recomputed: large, and one product deep.
-# So does `GatedShortConv` with the projection that feeds it: one pass over
-# three times that projection's input (the next keeps its own by rule two).
+#     behind a solve; W, U and P are five products away from it);
+#   - a gated MLP names its two hidden products, float32 as made: 2 d_in
+#     operations an element for 8 bytes written and read, T / 3.75 d_in of
+#     its weights, masters and momentum together; silu(g) * u is made again.
+# `GatedShortConv` and the projection that feeds it are made again too.
 
 _DEAR = set()                   # every name `dear` was given
 _MIRROR = threading.local()     # .kept: the list of the stage being traced

@@ -645,9 +645,9 @@ def _gated_delta_rule(attrs, q, k, v, g, beta, stats):
 # ---------------------------------------------------------------------------
 
 def _gated_mlp(x, w1, w3, w2):
-    """(silu(x w1^T) * (x w3^T)) w2^T, weights (out, in)."""
-    a = jax.nn.silu(_matmul(x, w1)) * _matmul(x, w3)
-    return _matmul(a.astype(x.dtype), w2).astype(x.dtype)
+    """(silu(x w1^T) * (x w3^T)) w2^T, weights (out, in); g, u are dear."""
+    g, u = dear(_matmul(x, w1), 'mlp_gate'), dear(_matmul(x, w3), 'mlp_up')
+    return _matmul((jax.nn.silu(g) * u).astype(x.dtype), w2).astype(x.dtype)
 
 
 @register('GatedMLP', input_names=['data', 'w1_weight', 'w3_weight',

@@ -114,8 +114,8 @@ fetch|build`` (the same set, from the shared window pipeline) + counter
 ``fused_eval.windows`` + gauge ``fused_eval.steps_per_call`` (compiled
 eval window loop), ``executor.forward|backward`` + gauges
 ``executor.mirror_kept`` / ``executor.mirror_kept_bytes`` (set while a
-training program is traced: the values its mirrored stages keep for the
-backward pass because an op named them as dear to recompute, and their
+training program is traced: what its mirrored stages keep for the backward
+pass by the five rules of ``ops/registry.py``'s ``dear``, and their
 bytes), ``exec_group.forward|backward``, ``module.update``, histogram
 ``io.prefetch_wait`` + counter ``io.batches``, ``kvstore.push|pull``
 spans + ``kvstore.push_bytes`` / ``kvstore.pull_bytes`` counters,

@@ -345,16 +345,21 @@ def test_the_iterator_yields_a_noisy_and_a_clean_copy():
 # period and the attention wrappers a second walk, and LFM2's step is to
 # lower as it did. Taken again on the tree of PR 46, which changed the way
 # back from the expert layer's sorted rows to the tokens by intent
-# (test_latent_ops.py says how). The text is this jax's.
+# (test_latent_ops.py says how). Both paths again on the tree of PR 51: the
+# one dense layer's MLP saves its two hidden products for its mirrored stage
+# and makes them once (two values more, two products fewer in the backward
+# text; LFM2 has no shared expert). The text is this jax's.
 LFM2_TEXT = {
     'plain':
-    'af86a33285446cf1b1baf13d2ca5f60932955251e0c50675b8338bc88b55b53e',
+    'fdf74cf84bf521d4b00607ef5644b0c222623ee11e4d6182e5dd630a2b58954e',
     'kernel':
-    '5737bd2122d751dca22d2a4bd8f852eb516d5488d0817db620a681089529bb0c'}
+    '72038768d47f0e51aef07ee4c76adea94d68f90a7593d959c06c49223dc24d72'}
 # the same of this family's own step, at CFG's sizes, taken on the tree
 # that brought it and again on that of PR 46, as above; both families'
 # 'kernel' texts again on that of PR 47, and LFM2's on that of PR 48, which
-# left this family's own as it was (test_latent_ops.py says how)
+# left this family's own as it was (test_latent_ops.py says how). PR 51 left
+# both of this family's texts as they were: it has no gated MLP, dense or
+# shared, and that change reaches nothing else
 SDAR_TEXT = {
     'plain':
     'e0b556f21f64b9c73e3d4da275e279152c94dc95072f5f5478cf115dc92dff40',

@@ -4,6 +4,9 @@
 - ``FullyConnected`` names its output where it contracts (4 -> 2 and 4 -> 4
   features) and not where it expands (2 -> 4); a mirrored stage keeps the
   named value and counts it once;
+- the gated MLP names its two hidden products, as the float32 results of
+  the products and before ``silu``, under both of its callers (``GatedMLP``
+  and ``MoE``'s shared expert), and nothing else of itself;
 - outside a stage a name is nothing: the fused window of a small residual
   network with a contracting head lowers to the text it had before the rule;
 - the router's ``_top_k`` gives ``jax.lax.top_k``'s values, indices and
@@ -21,7 +24,8 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import random as _random
 from mxnet_tpu.ops import registry
-from mxnet_tpu.ops.transformer import _top_k
+from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops.transformer import MOE_STATS, _top_k
 
 
 def _fc(features_in, features_out, bias):
@@ -56,6 +60,68 @@ def test_fully_connected_names_its_output_where_it_contracts(
     for a, b in zip(pull(jnp.ones_like(out)),
                     plain_pull(jnp.ones_like(out))):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+ROWS, WIDTH, HIDDEN = 6, 8, 16
+
+
+def _mlp_caller(caller):
+    """(f, arguments): the gated MLP at 8 -> 16 -> 8 over 6 bfloat16 rows,
+    as the ``GatedMLP`` op or as the shared expert of a ``MoE`` layer."""
+    rng = np.random.RandomState(3)
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape) * 0.3, jnp.bfloat16)
+
+    x = rand(ROWS, WIDTH)
+    mlp = [rand(HIDDEN, WIDTH), rand(HIDDEN, WIDTH), rand(WIDTH, HIDDEN)]
+    if caller == 'gated_mlp':
+        fn = registry.get('GatedMLP').fn
+        return (lambda x, *w: fn({}, x, *w)), [x] + mlp
+    fn = registry.get('MoE').fn
+    attrs = dict(num_experts=4, num_experts_per_tok=2, experts_held=4,
+                 expert_offset=0, norm_topk_prob=True)
+    experts = [rand(4, WIDTH), rand(4, WIDTH, 4), rand(4, WIDTH, 4),
+               rand(4, 4, WIDTH)]
+    stats = jnp.zeros((len(MOE_STATS),), jnp.float32)
+    return (lambda x, *w: fn(attrs, x, *w, stats)[0]), [x] + experts + mlp
+
+
+@pytest.mark.parametrize('caller', ['gated_mlp', 'moe_shared'])
+def test_gated_mlp_names_its_two_hidden_products(caller, monkeypatch):
+    f, args = _mlp_caller(caller)
+    named = [(e.params['name'], e.outvars[0].aval)
+             for e in jax.make_jaxpr(f)(*args).jaxpr.eqns
+             if e.primitive.name == 'name'
+             and e.params['name'].startswith('mlp_')]
+    # g = x W1^T and u = x W3^T as `_matmul` gives them: not silu(g) u, not
+    # the input, not the result
+    assert [n for n, _ in named] == ['mlp_gate', 'mlp_up']
+    assert all(a.shape == (ROWS, HIDDEN) and a.dtype == jnp.float32
+               for _, a in named)
+    # a mirrored stage keeps the two, once each, and what it gives is what
+    # the bare checkpoint gives, bit for bit
+    kept = []
+    out, pull = jax.vjp(registry.mirrored(f, kept), *args)
+    hidden = [k for k in kept if k.shape == (ROWS, HIDDEN)]
+    assert len(hidden) == 2 and all(k.dtype == jnp.float32 for k in hidden)
+    if caller == 'gated_mlp':
+        assert len(kept) == 2
+    bare, bare_pull = jax.vjp(jax.checkpoint(f), *args)
+    for a, b in zip((out,) + pull(jnp.ones_like(out)),
+                    (bare,) + bare_pull(jnp.ones_like(bare))):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    # outside a stage the lowered text is the unnamed function's
+    text = jax.jit(f).lower(*args).as_text()
+    for module in (mx.ops.transformer, pk):
+        monkeypatch.setattr(module, 'dear', lambda x, name: x)
+    f, args = _mlp_caller(caller)
+    assert 'name[' not in str(jax.make_jaxpr(f)(*args))
+    unnamed = jax.jit(f).lower(*args).as_text()
+    text, unnamed = (re.sub(r'(@\w+?)_\d+\b', r'\1', t)
+                     for t in (text, unnamed))
+    assert unnamed == text
 
 
 # sha256 of the lowered fused window of the network below, taken under

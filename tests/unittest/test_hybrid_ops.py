@@ -338,14 +338,18 @@ def test_the_second_forward_of_a_conv_block(path, monkeypatch):
 # to lower as it did. Taken again on the tree of PR 43, which changed the
 # expert layer's backward pass by intent, and on that of PR 46, which changed
 # the way back from the sorted rows to the tokens by intent (test_latent_ops.py
-# says how); 'kernel' again on those of PR 47 and PR 48 (there too). (Laguna's and
+# says how); 'kernel' again on those of PR 47 and PR 48 (there too). Both paths
+# again on the tree of PR 51: the dense layer's MLP and the shared experts of
+# the sparse layer and of the MTP module's each save their two hidden products
+# for their mirrored stage (six values more) and
+# make them once (six products fewer in the backward text). (Laguna's and
 # Kanana's digests are in test_latent_ops.py and test_hyper_ops.py and are
 # checked there.) The text is this jax's.
 XING4_TEXT = {
     'plain':
-    '7de7239f07f15f82eebf86e2b393e55a749f7ac4a5b197b7886cff3ba1669cad',
+    '06ac3e9344fe757baf2be06858fab561f348086f205a9ef1f64e8b82b1a654e1',
     'kernel':
-    '057fb95a1ce23abcab9911161068c95a1ad8733d042499647d2ce33eaf7f2ea7'}
+    '850170f3a15747ee166f4672bf841caef6bc2e2e1dbe3f8a366206df75422edb'}
 
 
 @pytest.mark.parametrize('path', PATHS, indirect=True)

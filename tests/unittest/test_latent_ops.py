@@ -404,8 +404,13 @@ def _named_bytes(layers, sparse, held):
     R += -R % mx.ops.transformer._pass_rows(R, rows, k, held, 16)
     # the choice, its scores, the weights, dest; row_pair, tile_group, n_tiles
     moe = [rows * k] * 4 + [R, R // pk.GROUP_TILE, 1]
-    return (layers * len(block) + sparse * len(moe),
-            4 * (layers * sum(block) + sparse * sum(moe)))
+    # a gated MLP's two hidden products: the shared experts', the dense one's
+    moe += [rows * CFG['n_shared_experts'] * CFG['moe_intermediate_size']] * 2
+    dense = [rows * CFG['intermediate_size']] * 2
+    return (layers * len(block) + sparse * len(moe)
+            + (layers - sparse) * len(dense),
+            4 * (layers * sum(block) + sparse * sum(moe)
+                 + (layers - sparse) * sum(dense)))
 
 
 @pytest.mark.parametrize('path', ['kernel'], indirect=True)
@@ -562,14 +567,22 @@ def test_fit_takes_the_fused_window_and_follows_the_reference(monkeypatch):
 # map computes the walk's unused `live`, one `compare`), no vector operation
 # among them (the count by operation is in CHANGES.md, PR 48); Kanana's,
 # Xing4.0's and LFM2's 'kernel' digests moved with it, SDAR's did not.
+# Both paths again on the tree of PR 51, which changed what a mirrored stage
+# keeps by intent: a gated MLP (the dense layer's and each shared expert's)
+# names its two hidden products, so each of the five saves two float32 values
+# more and its backward text has two products fewer (10 `dot_general` fewer
+# in all, and one `reduce_precision` of float32 to float32 more an MLP, jax's
+# own mark on a saved value that the forward pass goes on to use; the count
+# by operation is in CHANGES.md, PR 51). Kanana's, Xing4.0's and LFM2's moved
+# with it on both paths; SDAR's, which has no gated MLP, moved on neither.
 # Before that they were PR 41's (what a mirrored stage keeps), PR 33's, and
 # those of the commit before this family came (faf5f29). The text is this jax's; a change of jax (or of Laguna's
 # own ops) needs them taken again.
 LAGUNA_TEXT = {
     'plain':
-    '39a7a0a2bf354847c208eb600bcea396eeec80f4cbd01278c69a5eabc9805577',
+    '9b3873d08859c8bddb61398f13e9e19cc39aa39cedcb498fb5bc0c3c347b147a',
     'kernel':
-    '9519e0f6858ef89144d80159a3b0b52788c4d5c6c512845541a64f471dbc3b60'}
+    'ba43c05f1240078ddccc4b4de6eedcd2cf42878b4a7a1e45cbde8ed4096e10f6'}
 
 
 def laguna_step_digest():

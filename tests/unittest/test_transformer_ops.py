@@ -832,8 +832,10 @@ def _forward_kernel_runs_once(kind, monkeypatch):
 
 def _gradients_are_the_bare_checkpoints(kind, monkeypatch):
     """(b) the kept values are the ones a recomputation makes: loss and
-    every gradient bit-equal. (The windowed block has the expert layer:
-    its routing and its plan are kept too.)"""
+    every gradient bit-equal. (The full block has the dense MLP, whose two
+    hidden products are kept in float32, as they were made; the windowed
+    one the expert layer: its routing, its plan and its shared expert's
+    two.)"""
     sym = _one_block(KINDS[kind][0],
                      'sparse' if kind == 'window' else 'dense')[0]
     step, wrt = _training_step(sym, **LM_IN)
@@ -919,32 +921,40 @@ PROJECTIONS = ('attn_q', 'attn_k', 'attn_v', 'attn_g', 'attn_o')
 
 
 def _the_second_forward_leaves_out_what_was_named(kind, monkeypatch):
-    """What the three rules name is not made a second time: no projection
-    of the attention sublayer, no rotary turn of a query or key (the
-    angles' table alone), no top-k, and none of the plan's scans, scatters
-    and searches; the router's product, the shared expert and the experts
-    are. Under a bare checkpoint all of it is there twice."""
+    """What the rules name is not made a second time: no projection of the
+    attention sublayer, no rotary turn of a query or key (the angles' table
+    alone), no top-k, none of the plan's scans, scatters and searches, and
+    no product of a gated MLP, dense or shared (its ``silu(g) * u`` is: the
+    one elementwise pass behind the two kept values); the router's product
+    and the experts are. Under a bare checkpoint all of it is there twice."""
     kind, name = KINDS[kind]
     step, wrt = _training_step(_one_block(kind, 'sparse')[0], **LM_IN)
     assert not _computed_again(step, wrt, 'dot_general', *PROJECTIONS)
-    assert _computed_again(step, wrt, 'dot_general') == {
-        'layer0_moe/router', 'layer0_moe/shared'}
+    assert _computed_again(step, wrt, 'dot_general') == {'layer0_moe/router'}
+    assert 'layer0_moe/shared' in _computed_again(step, wrt, 'logistic')
     for prim in ('top_k', 'cumsum', 'scatter', 'sort', 'concatenate'):
         assert not _computed_again(step, wrt, prim), prim
     assert _computed_again(step, wrt, 'cos') == {
         'layer0_attn_q_rope', 'layer0_attn_k_rope'}
+    dense, wrt_dense = _training_step(_one_block(kind)[0], **LM_IN)
+    assert not _computed_again(dense, wrt_dense, 'dot_general')
+    assert 'layer0_mlp' in _computed_again(dense, wrt_dense, 'logistic')
     _bare_checkpoint(monkeypatch)
     step, wrt = _training_step(_one_block(kind, 'sparse')[0], **LM_IN)
     assert _computed_again(step, wrt, 'dot_general', *PROJECTIONS) == {
         'layer0_' + p for p in PROJECTIONS}
+    assert 'layer0_moe/shared' in _computed_again(step, wrt, 'dot_general')
     for prim in ('top_k', 'cumsum', 'scatter', 'concatenate'):
         assert _computed_again(step, wrt, prim), prim
+    dense, wrt_dense = _training_step(_one_block(kind)[0], **LM_IN)
+    assert 'layer0_mlp' in _computed_again(dense, wrt_dense, 'dot_general')
 
 
 def _the_gauges_count_each_named_array_once(_, monkeypatch):
     """(e) executor.mirror_kept and _bytes of a dense and a sparse block:
     the arrays the ops named, from the shapes, the value projection that is
-    also the kernel's value once."""
+    also the kernel's value once, a gated MLP's two hidden products (the
+    dense block's and the shared expert's) in float32."""
     monkeypatch.setenv('MXTPU_TELEMETRY', '1')
     monkeypatch.setenv('MXTPU_TELEMETRY_PATH', os.devnull)
     _reload_telemetry()
@@ -969,6 +979,9 @@ def _the_gauges_count_each_named_array_once(_, monkeypatch):
         R += -R % mx.ops.transformer._pass_rows(R, rows, k, 4, 16)
         named += [(rows * k, 4)] * 4        # choice, scores, weights, dest
         named += [(R, 4), (R // 128, 4), (1, 4)]
+        for hidden in (cfg['intermediate_size'],
+                       cfg['shared_expert_intermediate_size']):
+            named += [(rows * hidden, 4)] * 2       # x W1^T, x W3^T
         assert gauges['executor.mirror_kept'] == len(named)
         assert gauges['executor.mirror_kept_bytes'] == sum(
             n * b for n, b in named)
@@ -1002,7 +1015,8 @@ def test_a_mirrored_stage_keeps_what_an_op_named_as_dear(
     """A mirrored stage recomputes its block in the backward pass except
     the values an op named as dear (``registry.dear``: what the attention
     kernel's backward pass reads, a contracting projection's output, the
-    expert layer's routing and plan), on the kernels' path."""
+    expert layer's routing and plan, a gated MLP's two hidden products), on
+    the kernels' path."""
     case(arg, monkeypatch)
 
 

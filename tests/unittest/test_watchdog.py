@@ -106,9 +106,13 @@ def test_stall_trips_incident_and_healthz_flips(wd_on):
     ok, body = serve.healthz_payload()
     assert not ok and body['status'] == 'hung'
     assert body['hang']['last_progress'] == 'fit.step'
-    # the JSONL record landed (the trip flushes the sink)
-    recs = [json.loads(ln) for ln in open(wd_on['tele_path'])
-            if ln.strip()]
+    # the JSONL record landed (the trip flushes the sink; the monitor
+    # thread sets hang_info first, so wait for the record's last byte)
+    def whole_lines():
+        return [ln for ln in open(wd_on['tele_path'])
+                if ln.strip() and ln.endswith('\n')]
+    assert _wait_for(lambda: any('"hang"' in ln for ln in whole_lines()))
+    recs = [json.loads(ln) for ln in whole_lines()]
     hangs = [r for r in recs if r['type'] == 'hang']
     assert len(hangs) == 1
     assert hangs[0]['stacks'] and hangs[0]['action'] == 'warn'
